@@ -4,7 +4,7 @@ Three passes (docs/ANALYSIS.md is the rule catalog):
 
   * **Pass 1 — AST lint** (`analysis.lint`, no JAX import): walks package
     source and flags the compilation-behavior footguns that CLAUDE.md and
-    RESULTS.md record as hard-won gotchas — control flow in Pallas kernel
+    docs/ANALYSIS.md record as hard-won gotchas — control flow in Pallas kernel
     bodies, host syncs inside jitted scopes, untiled BlockSpec literals,
     use-after-donate, wall-clock/np.random reachable from traced code, and
     uncited parity claims. Rules GC001-GC006, suppressible inline with

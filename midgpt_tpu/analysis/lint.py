@@ -1,7 +1,7 @@
 """graftcheck pass 1: repo-specific AST lint. Deliberately JAX-free.
 
 Every rule encodes a gotcha this repo has already paid for (rationale and
-the CLAUDE.md / RESULTS.md citations live in docs/ANALYSIS.md):
+the citations live in docs/ANALYSIS.md):
 
   GC001  lax.cond / lax.while_loop / lax.fori_loop inside a Pallas kernel
          body (kills Mosaic pipelining — use straight-line selects).
@@ -25,7 +25,7 @@ the CLAUDE.md / RESULTS.md citations live in docs/ANALYSIS.md):
          `time.monotonic()` ...) in a `sampling/` or `robustness/` module:
          those hot paths measure latency through the injectable clock
          (`clock=` ctor param threaded to `self._clock`), which is what
-         keeps round decomposition tunnel-consistent and lets tests fake
+         keeps round decomposition on ONE clock and lets tests fake
          time. Default-arg REFERENCES (`clock=time.perf_counter`) are the
          plumbing itself, not a read — only Call nodes are flagged, and
          `time.sleep()` is not a clock read (observability PR).
@@ -774,7 +774,7 @@ def _rule_gc012(mod: _Module) -> tp.Iterator[Finding]:
                 f"`{name}()` bypasses the injected clock in a serving/"
                 "robustness hot path — read `self._clock()` (or the "
                 "module's `clock` parameter) so tests can fake time and "
-                "round decomposition stays tunnel-consistent "
+                "round decomposition stays on one clock "
                 "(docs/OBSERVABILITY.md); suppress with justification "
                 "for genuinely wall-anchored timestamps",
             )

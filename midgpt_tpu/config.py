@@ -129,7 +129,7 @@ class ExperimentConfig:
     # Hung-step watchdog (robustness/watchdog.py): deadline in seconds armed
     # around each of the train loop's device syncs (the t_land force points).
     # 0.0 (default) disables the guard entirely — the sync is a plain call,
-    # no thread, no clock read. Production tunnel runs want ~300s (a few
+    # no thread, no clock read. Production runs want ~300s (a few
     # compiles' worth of slack above the longest healthy step).
     watchdog_deadline_s: float = 0.0
     # What an expired watchdog does after dumping the flight recorder:

@@ -4,9 +4,9 @@ The full openwebtext recipe (configs/openwebtext.py; reference
 configs/openwebtext.py:4-21) scaled to a single v5e chip and a ~2h horizon:
 identical model shape (GPT-2-small, vocab padded to 50304), identical
 optimizer constants (lr 1e-3 cosine to 1e-5, beta2 0.95, wd 1e-4 with
-wd/lr decoupling), the full fast path (flash attention, remat off —
-it fits at this scale, RESULTS.md §1 — fused CE) and the G=16
-accumulation schedule — with effective batch 256
+wd/lr decoupling), the full fast path (flash attention, remat off — it
+fits at this scale, measured on an earlier toolchain, not re-measured —
+fused CE) and the G=16 accumulation schedule — with effective batch 256
 (16 x 16) instead of 2048 and the warmup/decay horizon scaled to 3000
 steps. Data comes from data/local_text/prepare.py (offline-trained
 byte-level BPE over local text trees).
@@ -45,8 +45,9 @@ config = ExperimentConfig(
         dropout=0.0,
         attn_impl="flash",
         # 124M at microbatch 16 fits the 15.75 GB chip WITHOUT per-block
-        # remat (measured: 51.4% MFU remat-off vs 47.5% with the 'flash'
-        # policy at G=16 — RESULTS.md §1); keep the policy name so
+        # remat (51.4% MFU remat-off vs 47.5% with the 'flash' policy at
+        # G=16, measured on an earlier toolchain, not re-measured);
+        # keep the policy name so
         # `--set model_config.remat=True` restores it for tighter chips.
         remat=False,
         remat_policy="flash",

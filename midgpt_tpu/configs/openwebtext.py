@@ -25,7 +25,8 @@ config = ExperimentConfig(
         dropout=0.0,
         # Same function as the reference rotation via the in-graph q/k row
         # permutation (models/gpt.py _qkv_weights, exactness test-pinned):
-        # +2.1 MFU measured on the v5e 124M bench (RESULTS §4a r5).
+        # +2.1 MFU on the v5e 124M bench (measured on an earlier toolchain, not
+        # re-measured).
         rope_style="split",
     ),
 )

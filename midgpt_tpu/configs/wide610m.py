@@ -5,7 +5,8 @@ fp32 master params + Adam state + remat-free activations fit one v5e chip
 (15.75 GB). C=128 fills the MXU's 128-wide systolic array on QK^T/PV where
 the GPT-2-small C=64 runs it half-utilized; measured 63.8% MFU sustained at
 per-chip batch 12 — the repo's ≥55% target with 8 points to spare, 1.34×
-the reference's published 47.8% (reference README.md:55; RESULTS.md §1).
+the reference's published 47.8% (reference README.md:55); the figures here
+were measured on an earlier toolchain, not re-measured.
 
 This file is the single source of truth for the shape: `bench.py --shape
 wide` loads it, so the number is reproducible both ways —
@@ -47,7 +48,8 @@ config = ExperimentConfig(
         dropout=0.0,
         attn_impl="flash",
         # Remat OFF is what fits-and-flies at batch 12 (63.8%); +remat OOMs
-        # at batch 16 and loses ~10 points at 12 (RESULTS.md §1 wide table).
+        # at batch 16 and loses ~10 points at 12 (measured on an earlier
+        # toolchain, not re-measured).
         remat=False,
         remat_policy="flash",
         # Like the 124M recipe: remat-off only FITS with the layer scan
@@ -56,7 +58,8 @@ config = ExperimentConfig(
         scan_unroll=8,
         rope_style="split",
         # At C=128 the head-major end-to-end layout wins (+1.2 MFU, 63.9%
-        # measured r5); at C=64 it loses — keep 'seq' there (RESULTS §4a).
+        # measured r5); at C=64 it loses — keep 'seq' there (measured on an
+        # earlier toolchain, not re-measured).
         attn_layout="head",
     ),
 )

@@ -33,7 +33,8 @@ one online-softmax block sweeps each, and partials merge with the SAME
 ops/online_softmax.merge_partials math as the kernel path. Deliberately so:
 a host core executes partitions sequentially either way, so the gather
 split lowering aims for structure-neutrality (measured within noise of the
-unsplit pass, RESULTS.md §5) while the kernel's parallel grid dimension
+unsplit pass; measured on an earlier toolchain, not re-measured) while the
+kernel's parallel grid dimension
 carries the actual long-T win on hardware (tools/bench_serve.py
 --long-ctx). The kernels themselves run in interpret mode only under
 their parity tests (tests/test_decode_attention.py, tests/test_split_k.py
@@ -48,7 +49,7 @@ import typing as tp
 
 import jax
 import jax.numpy as jnp
-
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from midgpt_tpu.kernels.attention_template import (
@@ -59,7 +60,6 @@ from midgpt_tpu.kernels.flash_attention import M_INIT, MASK
 from midgpt_tpu.ops.attention import visible_mask
 from midgpt_tpu.ops.online_softmax import finalize, merge_partials, online_block
 from midgpt_tpu.ops.quant import dequantize_q8
-from midgpt_tpu.utils.compat import shard_map
 
 Array = jax.Array
 
@@ -149,7 +149,8 @@ def paged_attention_gather(
     merge math exactly. No scan, and no partitioned score matmul either:
     on a single host core a sequential partition loop only adds loop
     overhead and a partition-shaped dot defeats XLA's fusion of the long
-    masked-softmax axis (both measured, RESULTS.md §5 — the parallel win
+    masked-softmax axis (both measured on an earlier toolchain, not re-measured
+    — the parallel win
     belongs to the kernel's grid dimension on real hardware), while the
     stats-only split is within noise of the unsplit pass; greedy decode
     streams stay token-identical to it (tests/test_split_k.py)."""
@@ -216,9 +217,7 @@ def _tp_shard_map(fn, mesh: Mesh, in_specs, out_specs):
     """Full-MANUAL shard_map over the serving mesh: every named axis is
     manual (only 'tp' exceeds size 1 on a serve mesh, parallel/serve_tp.py),
     so the body is a plain per-shard trace — exactly what a Pallas kernel
-    needs, and the one shard_map form the 0.4.37 CPU backend lowers (the
-    partial-manual form aborts there; utils/compat.shard_map docstring).
-    check_vma off: paged attention is pointwise in heads, there is no
+    needs. check_vma off: paged attention is pointwise in heads, there is no
     replication to certify."""
     return shard_map(
         fn,
@@ -228,6 +227,16 @@ def _tp_shard_map(fn, mesh: Mesh, in_specs, out_specs):
         axis_names=frozenset(mesh.axis_names),
         check_vma=False,
     )
+
+
+def resolve_paged_impl(impl: str) -> str:
+    """'auto' -> the Pallas template ('kernel') on a TPU backend, the XLA
+    gather lowering ('gather') elsewhere; explicit names pass through. The
+    serving engine resolves ONCE at construction and says which one it will
+    compile, so the choice is never invisible (sampling/serve.py)."""
+    if impl == "auto":
+        return "kernel" if jax.default_backend() == "tpu" else "gather"
+    return impl
 
 
 def paged_attention(
@@ -263,8 +272,7 @@ def paged_attention(
     split-K all compose with zero new collectives. The gather lowering
     ignores `mesh`: it is plain jnp, and GSPMD partitions it from the
     operand shardings alone."""
-    if impl == "auto":
-        impl = "kernel" if jax.default_backend() == "tpu" else "gather"
+    impl = resolve_paged_impl(impl)
     if impl == "kernel":
         if mesh is not None and mesh.shape["tp"] > 1:
             quantized = k_scale is not None
@@ -443,8 +451,7 @@ def paged_verify_attention(
     lowering elsewhere; on a tp>1 mesh the kernel runs per shard over
     H_q/tp query heads and H_kv/tp pool heads via the same full-manual
     shard_map, collective-free, with split_k riding inside each shard."""
-    if impl == "auto":
-        impl = "kernel" if jax.default_backend() == "tpu" else "gather"
+    impl = resolve_paged_impl(impl)
     if impl == "kernel":
         if mesh is not None and mesh.shape["tp"] > 1:
             quantized = k_scale is not None

@@ -44,11 +44,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
-if not hasattr(pltpu, "CompilerParams"):
-    # jax < 0.5 names the Mosaic params class TPUCompilerParams; same fields
-    # (midgpt_tpu.utils.compat documents the shim policy).
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 # Finite stand-ins for -inf (see module docstring), re-exported from the
 # canonical home of the shared online-softmax math. Kept as module names
 # because the kernel-template/decode/ring modules import them from here
@@ -78,6 +73,14 @@ RUN_INTERPRET_OFF_TPU = False
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _out_struct(shape, dtype, like: Array) -> jax.ShapeDtypeStruct:
+    """A kernel output's type, varying over the same manual mesh axes as
+    the input it is computed from: inside a shard_map that checks varying
+    axes (the explicit ZeRO-3 body, parallel/shard_map_fsdp.py) pallas_call
+    refuses an output that does not say; outside one the set is empty."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _block_sizes(T: int, block_q: int, block_k: int) -> tp.Tuple[int, int]:
@@ -247,8 +250,8 @@ def _flash_forward(
             pl.BlockSpec((1, bq, _STATS_LANES), idx_q, memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, T, C), q.dtype),
-            jax.ShapeDtypeStruct((B * H, T, _STATS_LANES), jnp.float32),
+            _out_struct((B * H, T, C), q.dtype, q),
+            _out_struct((B * H, T, _STATS_LANES), jnp.float32, q),
         ],
         scratch_shapes=scratch,
         compiler_params=params,
@@ -448,9 +451,9 @@ def _flash_backward(block_q, block_k, residuals, g, causal=True):
             in_specs=[full_spec] * 5 + [stat_spec],
             out_specs=[full_spec] * 3,
             out_shape=[
-                jax.ShapeDtypeStruct((B * H, T, C), q.dtype),
-                jax.ShapeDtypeStruct((B * H, T, C), k.dtype),
-                jax.ShapeDtypeStruct((B * H, T, C), v.dtype),
+                _out_struct((B * H, T, C), q.dtype, q),
+                _out_struct((B * H, T, C), k.dtype, q),
+                _out_struct((B * H, T, C), v.dtype, q),
             ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)
@@ -474,7 +477,7 @@ def _flash_backward(block_q, block_k, residuals, g, causal=True):
             grid=(B * H, T // bq),
             in_specs=[q_spec, k_spec, k_spec, q_spec, q_spec, stat_q_spec],
             out_specs=[q_spec],
-            out_shape=[jax.ShapeDtypeStruct((B * H, T, C), q.dtype)],
+            out_shape=[_out_struct((B * H, T, C), q.dtype, q)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")
             ),
@@ -491,7 +494,7 @@ def _flash_backward(block_q, block_k, residuals, g, causal=True):
             grid=(B * H, T // bq, T // bk),
             in_specs=[q_spec, k_spec, k_spec, q_spec, q_spec, stat_q_spec],
             out_specs=[q_spec],
-            out_shape=[jax.ShapeDtypeStruct((B * H, T, C), q.dtype)],
+            out_shape=[_out_struct((B * H, T, C), q.dtype, q)],
             scratch_shapes=[
                 pltpu.VMEM((bq, C), jnp.float32),
                 pltpu.VMEM((bq, _STATS_LANES), jnp.float32),
@@ -515,8 +518,8 @@ def _flash_backward(block_q, block_k, residuals, g, causal=True):
         in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, q_spec2, stat_q_spec2],
         out_specs=[k_spec2, k_spec2],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, T, C), k.dtype),
-            jax.ShapeDtypeStruct((B * H, T, C), v.dtype),
+            _out_struct((B * H, T, C), k.dtype, q),
+            _out_struct((B * H, T, C), v.dtype, q),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, C), jnp.float32),

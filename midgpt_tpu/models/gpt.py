@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from midgpt_tpu.ops.attention import multihead_attention
+from midgpt_tpu.ops.attention import flash_or_blockwise, multihead_attention
 from midgpt_tpu.ops.dropout import dropout
 from midgpt_tpu.ops.norms import head_layer_norm, rms_norm
 from midgpt_tpu.ops.quant import dequantize_q8, quantize_q8
@@ -65,8 +65,9 @@ class GPTConfig:
     # mesh-bound implementation via the attn_fn hook on GPT.hidden.
     attn_impl: str = "naive"  # 'naive' | 'blockwise' | 'flash' | 'ring' | 'ulysses'
     # Tile size for the blockwise/flash/ring/ulysses paths. 1024 measured 7
-    # MFU points faster than 512 on the 124M flash training step (v5e,
-    # RESULTS §4a) and matches the ring's tuned per-pair tile.
+    # MFU points faster than 512 on the 124M flash training step (v5e;
+    # measured on an earlier toolchain, not re-measured) and matches the ring's
+    # tuned per-pair tile.
     attn_block_size: int = 1024
     remat: bool = True  # checkpoint each block inside the layer scan
     # What the per-block checkpoint may keep instead of recomputing in bwd:
@@ -93,8 +94,9 @@ class GPTConfig:
     # (checkpoints stay in reference convention) + the contiguous
     # rotate-half form — mathematically identical (QK^T is invariant under
     # a shared permutation of the C axis; pinned by test_rope/test_model),
-    # and measured 12.3 ms/step faster on the 124M v5e bench (RESULTS §4a
-    # r5: the interleaved form's stride-2 gathers cost copy passes in fwd
+    # and measured 12.3 ms/step faster on the 124M v5e bench (measured on an
+    # earlier toolchain, not re-measured:
+    #  the interleaved form's stride-2 gathers cost copy passes in fwd
     # AND bwd). Per-run choice recorded in config.json, so restores and
     # sampling stay consistent.
     rope_style: str = "interleaved"
@@ -108,7 +110,8 @@ class GPTConfig:
     #            (bhtc,dhc->btd). Same math, same params, same checkpoints —
     #            only the einsum axis order changes; kills the per-layer
     #            head-transpose copies the profiler showed (~12% of the r5
-    #            124M step was relayout copies, RESULTS §4a).
+    #            124M step was relayout copies; measured on an earlier
+    #            toolchain, not re-measured).
     # The naive/blockwise reference paths always use 'seq'.
     attn_layout: str = "seq"
     # Mixture-of-experts MLP (MoEParams): 0 = dense (reference semantics);
@@ -124,7 +127,8 @@ class GPTConfig:
     #           compile per chunk length at the 32-layer 7B shapes.
     #   True  — rolled lax.scan over layers: O(1) compile in depth, at the
     #           measured cost of 2 full-cache copies per decode step at the
-    #           inner/outer carry boundary (RESULTS §1 r5). The deep
+    #           inner/outer carry boundary (measured on an earlier toolchain,
+    #           not re-measured). The deep
     #           llama7b configs set this.
     decode_layer_scan: bool = False
     # Grouped-query attention (GQA/MQA): number of K/V heads. None = MHA
@@ -209,7 +213,8 @@ class AttentionParams:
     #   * at tp=1 it reshapes (free: contiguous) to the flat stacked (3D, D)
     #     for ONE full-width matmul + contiguous split — the fast MXU path
     #     (a head-major interleaved flat layout costs ~1.7 MFU points at
-    #     C=64, measured, RESULTS §4: its (B,T,H,3,C) unpack slices leave
+    #     C=64, measured on an earlier toolchain, not re-measured: its
+    #     (B,T,H,3,C) unpack slices leave
     #     64-element lane runs);
     #   * Megatron TP shards axis 1 (output features, parallel/tp.py): each
     #     of q, k, v is column-sharded independently, so shard boundaries
@@ -1083,7 +1088,10 @@ class GPT:
             kr = apply_rope_bthc(k, rope[0], rope[1], style=config.rope_style)
             att = multihead_attention(
                 qr, _repeat_kv(config, kr, 2), _repeat_kv(config, v, 2),
-                impl=config.attn_impl, inference=True,
+                impl=flash_or_blockwise(
+                    config.attn_impl, T, config.attn_block_size
+                ),
+                inference=True,
                 block_size=config.attn_block_size, layout="bthc",
                 sliding_window=config.sliding_window,
                 attn_sinks=config.attn_sinks,
@@ -1132,7 +1140,8 @@ class GPT:
         # xs, new cache re-stacked from per-layer ys) forced XLA to copy
         # BOTH full (L, B, H, S, C) buffers every decode step inside the
         # chunked decode loop — measured 2.5 ms/token of pure copy at
-        # 124M/B=8 on v5e, a third of the whole step (RESULTS §, r5) —
+        # 124M/B=8 on v5e, a third of the whole step (measured on an earlier
+        # toolchain, not re-measured) —
         # plus per-layer stacked-slot rebuilds. A rolled scan still pays 2
         # full-cache copies/step at the inner/outer carry boundary
         # (verified on compiled HLO); the unrolled DUS chain rides the
@@ -1208,7 +1217,8 @@ class GPT:
             length costs noticeably more trace+compile time.
           * Rolled `lax.scan` — O(1) program size in depth (one traced
             block), at the measured cost of 2 full-cache copies per decode
-            step at the inner/outer scan carry boundary (RESULTS §1 r5:
+            step at the inner/outer scan carry boundary (measured on an earlier
+            toolchain, not re-measured:
             XLA cannot alias a while-loop carry into an enclosing loop's
             carry slot). The deep llama7b configs set this: for them,
             compile latency dominates interactive use and the copies are

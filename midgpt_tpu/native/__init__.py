@@ -5,19 +5,24 @@ native code earns its keep. Currently: the data batcher (batcher.c) — the
 only host-side work on the training hot loop.
 
 The shared library is built on demand with the system C compiler into this
-package directory (`_batcher.so`), once, at first use. No pybind11 and no
+package directory, once, at first use, under a name keyed by the hash of
+batcher.c (`_batcher.<sha12>.so`): a library built from another source —
+or copied in from another machine — is never loaded. No pybind11 and no
 build-system hook: ctypes + cc keeps the extension working from a plain
 checkout (and cross-compiles trivially on TPU-VM hosts via setup_hosts.sh).
 Every entry point falls back to the numpy implementation when the toolchain
-or the build is unavailable — the native path is an accelerator, never a
-requirement. Parity is asserted bit-for-bit in tests/test_native_batcher.py.
+or the build is unavailable — host code, bit-identical (parity is asserted
+in tests/test_native_batcher.py) — and the first use says on stderr which
+of the two is serving, so the fallback is never silent.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import sys
 import sysconfig
 import threading
 import typing as tp
@@ -26,7 +31,6 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "batcher.c")
-_LIB = os.path.join(_DIR, "_batcher.so")
 
 _lock = threading.Lock()
 _lib: tp.Optional[ctypes.CDLL] = None
@@ -35,6 +39,12 @@ _build_failed = False
 
 def _compiler() -> str:
     return os.environ.get("CC") or sysconfig.get_config_var("CC") or "cc"
+
+
+def _lib_path() -> str:
+    with open(_SRC, "rb") as fh:
+        digest = hashlib.sha256(fh.read()).hexdigest()[:12]
+    return os.path.join(_DIR, f"_batcher.{digest}.so")
 
 
 def _load() -> tp.Optional[ctypes.CDLL]:
@@ -46,20 +56,21 @@ def _load() -> tp.Optional[ctypes.CDLL]:
         if _lib is not None or _build_failed:
             return _lib
         try:
-            if not os.path.exists(_LIB) or os.path.getmtime(_LIB) < os.path.getmtime(_SRC):
+            lib_path = _lib_path()
+            if not os.path.exists(lib_path):
                 cc = _compiler().split()[0]
                 # Build to a per-process temp name, then publish atomically:
                 # concurrent importers (pytest -n, parallel launches) must
                 # never dlopen a half-written library.
-                tmp = f"{_LIB}.{os.getpid()}.tmp"
+                tmp = f"{lib_path}.{os.getpid()}.tmp"
                 subprocess.run(
                     [cc, "-O3", "-shared", "-fPIC", "-pthread", _SRC, "-o", tmp],
                     check=True,
                     capture_output=True,
                     timeout=120,
                 )
-                os.replace(tmp, _LIB)
-            lib = ctypes.CDLL(_LIB)
+                os.replace(tmp, lib_path)
+            lib = ctypes.CDLL(lib_path)
             lib.sample_windows.argtypes = [
                 ctypes.c_void_p,  # data (uint16*)
                 ctypes.c_int64,  # n_windows
@@ -71,8 +82,14 @@ def _load() -> tp.Optional[ctypes.CDLL]:
             ]
             lib.sample_windows.restype = None
             _lib = lib
-        except Exception:
+            print(f"data batcher: native ({lib_path})", file=sys.stderr)
+        except (OSError, subprocess.SubprocessError) as e:
             _build_failed = True
+            print(
+                f"data batcher: numpy (native build unavailable: "
+                f"{type(e).__name__}: {e})",
+                file=sys.stderr,
+            )
     return _lib
 
 

@@ -6,9 +6,9 @@ cast to float32, scaled by 1/sqrt(head_dim), masked with -inf below the
 diagonal, softmaxed in float32, then cast back for the PV matmul.
 
 The blockwise path (`impl='blockwise'`) is a pure-jnp online-softmax
-(flash-style) formulation with O(T) memory — the long-context fallback for
-platforms where the Pallas kernel (midgpt_tpu.kernels.flash_attention,
-`impl='flash'`) is unavailable, and the parity oracle for testing it.
+(flash-style) formulation with O(T) memory — the long-context route for
+platforms without the Pallas kernel (midgpt_tpu.kernels.flash_attention,
+`impl='flash'`), selected by name, and the parity oracle for testing it.
 
 All impls take q, k, v of shape (B, H, T, C) and return (B, H, T, C).
 """
@@ -178,13 +178,27 @@ def blockwise_causal_attention(
 
 
 def flash_kernel_usable(T: int, block_size: int) -> bool:
-    """True when the Pallas kernel can serve this shape on this backend
-    (callers needing arbitrary T or non-TPU hosts get the blockwise path)."""
+    """True when the Pallas kernel can serve this shape on this backend:
+    the block tiles T, and the backend is a TPU (or a test has switched the
+    kernels' interpret mode on, kernels/flash_attention.RUN_INTERPRET_OFF_TPU)."""
     import importlib
 
     fa = importlib.import_module("midgpt_tpu.kernels.flash_attention")
     blk = min(block_size, T)
     return T % blk == 0 and (jax.default_backend() == "tpu" or fa.RUN_INTERPRET_OFF_TPU)
+
+
+def flash_or_blockwise(impl: str, T: int, block_size: int) -> str:
+    """`impl`, except that a 'flash' which cannot serve T here becomes
+    'blockwise' — the same online softmax in plain jnp — BY NAME. For the
+    two callers whose shapes the model config does not fix: KV-cache
+    prefill (prompts come in lengths no tile divides) and the dense
+    attention inside Ulysses (T is the gathered sequence of whatever mesh
+    it runs on). multihead_attention itself never trades a configured
+    'flash' for another impl: the train step raises instead."""
+    if impl == "flash" and not flash_kernel_usable(T, block_size):
+        return "blockwise"
+    return impl
 
 
 def flash_block_sizes(T: int, block_size: int) -> tp.Tuple[int, int]:
@@ -253,14 +267,21 @@ def multihead_attention(
         # the real module (the package re-exports a same-named function)
         fa = importlib.import_module("midgpt_tpu.kernels.flash_attention")
 
-        if flash_kernel_usable(T, block_size):
-            bq, bk = flash_block_sizes(T, block_size)
-            if layout == "bthc":
-                return fa.flash_attention_bthc(q, k, v, bq, bk)
-            return fa.flash_attention(q, k, v, bq, bk)
-        # Arbitrary prompt lengths (KV-cache prefill) and non-TPU backends
-        # take the equivalent blockwise path — same online softmax, plain jnp.
-        impl = "blockwise"
+        if not flash_kernel_usable(T, block_size):
+            # A configured kernel that cannot be served is an error, not a
+            # quiet downgrade: a run that believes it measures the Pallas
+            # kernel must not be timing plain jnp instead.
+            raise ValueError(
+                f"attn_impl='flash' cannot serve T={T} with block {blk} on "
+                f"the {jax.default_backend()!r} backend: the Pallas kernel "
+                "needs a block that divides T and a TPU. Configure "
+                "attn_impl='blockwise' (same online softmax, plain jnp) to "
+                "run this shape or backend."
+            )
+        bq, bk = flash_block_sizes(T, block_size)
+        if layout == "bthc":
+            return fa.flash_attention_bthc(q, k, v, bq, bk)
+        return fa.flash_attention(q, k, v, bq, bk)
 
     if layout == "bthc":  # naive/blockwise math is head-major
         q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
@@ -275,3 +296,37 @@ def multihead_attention(
             sliding_window=sliding_window, attn_sinks=attn_sinks,
         )
     return out.transpose(0, 2, 1, 3) if layout == "bthc" else out
+
+
+def flash_attention_sharded(
+    q: Array,  # (B, H, T, C) global arrays
+    k: Array,
+    v: Array,
+    mesh: jax.sharding.Mesh,
+    block_size: int,
+    batch_axes: tp.Tuple[str, ...] = ("data", "fsdp"),
+    head_axis: tp.Optional[str] = None,
+) -> Array:
+    """impl='flash' under a multi-device GSPMD program. A Mosaic kernel
+    cannot be partitioned by the compiler ("wrap the call in a shard_map"),
+    so the batch axis is mapped over `batch_axes` (and heads over
+    `head_axis`, e.g. 'tp') by hand: every device runs the kernel on its own
+    (B/n, H/t, T, C) block. Causal attention is independent per (sequence,
+    head), so the body needs no collective. Same contract as the ring and
+    Ulysses wrappers: head-major in, head-major out, bound to the mesh by
+    the training runtime (training/train.py)."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    spec = P(batch_axes, head_axis, None, None)
+    fn = shard_map(
+        lambda q, k, v: multihead_attention(
+            q, k, v, impl="flash", inference=True, block_size=block_size,
+            layout="bhtc",
+        ),
+        mesh=mesh,
+        in_specs=(spec, spec, spec),
+        out_specs=spec,
+        check_vma=False,
+    )
+    return fn(q, k, v)

@@ -139,7 +139,8 @@ def apply_rope_bthc(
     style='split' expects the C axis pre-permuted by `split_permutation`
     (models/gpt.py permutes the q/k projection rows in-graph) and applies
     the mathematically-identical rotate-half form — measured 12.3 ms/step
-    cheaper on the 124M v5e bench (RESULTS §4a r5): the interleaved form's
+    cheaper on the 124M v5e bench (measured on an earlier toolchain, not
+    re-measured): the interleaved form's
     stride-2 pair gathers cost real copy passes in forward AND backward."""
     if positions is not None:
         sin = jnp.take(sin, positions, axis=0)

@@ -14,6 +14,7 @@ size 1 unless enabled.
 
 from __future__ import annotations
 
+import dataclasses
 import typing as tp
 
 import jax
@@ -29,6 +30,27 @@ AXES = ("data", "fsdp", "sp", "tp", "pp", "ep")
 BATCH_AXES = ("data", "fsdp")
 
 
+def _axis(size: int) -> int:
+    return size if size != -1 else 1
+
+
+def fit_mesh_config(cfg: MeshConfig, n_devices: int) -> MeshConfig:
+    """`cfg` re-derived for a device count it was not written for: the data
+    axis inferred, fsdp lowered to its largest divisor of what the other
+    axes leave. This is the EXPLICIT topology change of elastic resume
+    (training/train.py make_runtime(devices=...)) — make_mesh itself never
+    resizes an axis."""
+    rest_axes = _axis(cfg.sp) * _axis(cfg.tp) * _axis(cfg.pp) * _axis(cfg.ep)
+    if n_devices % rest_axes != 0:
+        raise ValueError(
+            f"{n_devices} devices not divisible by sp={_axis(cfg.sp)} * "
+            f"tp={_axis(cfg.tp)} * pp={_axis(cfg.pp)} * ep={_axis(cfg.ep)}"
+        )
+    rest = n_devices // rest_axes
+    fsdp = max(d for d in range(1, rest + 1) if rest % d == 0 and d <= _axis(cfg.fsdp))
+    return dataclasses.replace(cfg, data=-1, fsdp=fsdp)
+
+
 def make_mesh(
     cfg: tp.Optional[MeshConfig] = None,
     *,
@@ -37,23 +59,20 @@ def make_mesh(
     cfg = cfg or MeshConfig()
     devices = list(devices if devices is not None else jax.devices())
     n = len(devices)
-    fsdp = cfg.fsdp if cfg.fsdp != -1 else 1
-    sp = cfg.sp if cfg.sp != -1 else 1
-    tp_ = cfg.tp if cfg.tp != -1 else 1
-    pp = cfg.pp if cfg.pp != -1 else 1
-    ep = cfg.ep if cfg.ep != -1 else 1
-    rest_axes = sp * tp_ * pp * ep
-    if n % (fsdp * rest_axes) != 0:
-        # Degrade gracefully on small device counts (e.g. 1-chip dev boxes):
-        # clamp fsdp to the largest divisor of n // (sp * tp * pp * ep).
-        if n % rest_axes != 0:
-            raise ValueError(
-                f"{n} devices not divisible by sp={sp} * tp={tp_} * pp={pp} * ep={ep}"
-            )
-        rest = n // rest_axes
-        fsdp = max(d for d in range(1, rest + 1) if rest % d == 0 and d <= fsdp)
-    data = cfg.data if cfg.data != -1 else n // (fsdp * rest_axes)
-    if data * fsdp * rest_axes != n:
+    fsdp, sp, tp_, pp, ep = (
+        _axis(cfg.fsdp), _axis(cfg.sp), _axis(cfg.tp), _axis(cfg.pp), _axis(cfg.ep)
+    )
+    model_axes = fsdp * sp * tp_ * pp * ep
+    if n % model_axes != 0:
+        # Never shrink an axis to fit: on a four-chip host a silently
+        # clamped fsdp is exactly how "everything on the first chip" hides.
+        raise ValueError(
+            f"mesh fsdp={fsdp} * sp={sp} * tp={tp_} * pp={pp} * ep={ep} = "
+            f"{model_axes} does not divide the {n} device(s) found; set the "
+            "mesh for this topology (e.g. --set mesh.fsdp=N)"
+        )
+    data = cfg.data if cfg.data != -1 else n // model_axes
+    if data * model_axes != n:
         raise ValueError(f"mesh {data}x{fsdp}x{sp}x{tp_}x{pp}x{ep} != {n} devices")
     mesh_devices = mesh_utils.create_device_mesh(
         (data, fsdp, sp, tp_, pp, ep), devices=np.asarray(devices)

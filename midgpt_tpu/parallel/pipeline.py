@@ -68,6 +68,7 @@ import typing as tp
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from midgpt_tpu.models.gpt import GPT, GPTConfig, GPTParams, _remat_policy
@@ -75,7 +76,6 @@ from midgpt_tpu.ops.norms import rms_norm
 from midgpt_tpu.ops.rope import rope_table
 from midgpt_tpu.ops.loss import fused_linear_cross_entropy
 from midgpt_tpu.parallel.mesh import BATCH_AXES
-from midgpt_tpu.utils.compat import shard_map
 
 Array = jax.Array
 

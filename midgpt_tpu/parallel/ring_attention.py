@@ -52,6 +52,8 @@ import typing as tp
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
 import importlib
@@ -66,7 +68,6 @@ from midgpt_tpu.ops.online_softmax import (
     merge_normalized,
     online_block,
 )
-from midgpt_tpu.utils.compat import axis_size, shard_map
 
 Array = jax.Array
 

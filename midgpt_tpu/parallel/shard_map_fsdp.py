@@ -31,7 +31,8 @@ Gather/compute overlap is pinned, not assumed (r5):
     the body's weight gathers become async (annotated
     async_collective_name="all-gather-start") or are continuation-FUSED
     into the block matmul kernels (gather windows streamed inside the dots,
-    forward and backward). Measured result in RESULTS.md §3a. NOTE: that
+    forward and backward). The result was measured on an earlier toolchain, not
+    re-measured. NOTE: that
     requires xla_tpu_enable_latency_hiding_scheduler=true — NOT default-on
     in this toolchain; real-pod launches should set it (docs/PARALLELISM.md).
 
@@ -46,12 +47,13 @@ import typing as tp
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
 from midgpt_tpu.models.gpt import GPT, GPTParams
 from midgpt_tpu.ops.loss import fused_linear_cross_entropy
 from midgpt_tpu.parallel.mesh import BATCH_AXES
-from midgpt_tpu.utils.compat import axis_size, shard_map
 
 Array = jax.Array
 

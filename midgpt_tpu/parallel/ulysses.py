@@ -35,10 +35,11 @@ from __future__ import annotations
 import typing as tp
 
 import jax
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
-from midgpt_tpu.ops.attention import multihead_attention
-from midgpt_tpu.utils.compat import axis_size, shard_map
+from midgpt_tpu.ops.attention import flash_or_blockwise, multihead_attention
 
 Array = jax.Array
 
@@ -77,7 +78,9 @@ def ulysses_attention(
     # validation rejects attn_impl='ulysses' + dropout up front. Three
     # guards, so this flag is not load-bearing for train/eval semantics.
     out = multihead_attention(
-        q, k, v, impl=impl, inference=True, block_size=block_size, layout="bhtc"
+        q, k, v,
+        impl=flash_or_blockwise(impl, q.shape[2], block_size),
+        inference=True, block_size=block_size, layout="bhtc",
     )
     if n > 1:
         # restore the sequence sharding: (B, H, Tl, C)

@@ -653,7 +653,7 @@ def _proc_reference_pass(port, trace):
     already-spawned worker (same spec, same pinned CPU backend as the
     fleet workers) -> {trace index: full token array}. Running the
     reference in-parent would compare across BACKENDS whenever the parent
-    sits on the real TPU (chaos_run.py without MIDGPT_PLATFORM) —
+    sits on the real TPU (chaos_run.py without JAX_PLATFORMS=cpu) —
     worker-vs-worker keeps the parity claim about the process boundary,
     not about TPU-vs-CPU matmul bit patterns. Upfront submission (vs the
     fleet drive's trickle) is fine: greedy streams are

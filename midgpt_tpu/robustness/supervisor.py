@@ -235,7 +235,7 @@ def supervise(
                     f"budget ({max_restarts}) exhausted. A recurring hang "
                     "at the SAME step suggests a wedged compile or input "
                     "pipeline; across different steps, a flaky device or "
-                    f"tunnel. Underlying: {e}"
+                    f"interconnect. Underlying: {e}"
                 ) from e
             restarts += 1
             flight_recorder().tracer.instant(

@@ -10,9 +10,9 @@ router already speaks (`submit`/`step`/`idle`/`finished`/counter attrs),
 so the in-process path stays bit-identical and every r18 fleet test passes
 unchanged. Deliberately NO `jax.distributed`: replicas share no arrays and
 no collectives — everything that crosses the boundary is plain host data
-over a socket (the GC015 wire contract, now literal), which is why this
-works on jax 0.4.37 where multi-process CPU collectives do not
-(tests/test_multiprocess.py pins that env gap).
+over a socket (the GC015 wire contract, now literal). Workers are
+CPU-only (tools/fleet_worker.py pins the platform): the spawning parent
+holds JAX, and a chip belongs to one process at a time.
 
 Wire format — length-prefixed, crc32-framed JSON + binary blobs:
 

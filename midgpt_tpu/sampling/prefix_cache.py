@@ -4,7 +4,8 @@ KV pool.
 Production traffic is dominated by shared system prompts and few-shot
 templates, yet a paged engine without this module re-prefills every request
 from token 0 — including preemption victims re-prefilling their OWN prompt
-(RESULTS.md §5 r10). The fix needs no device-side machinery at all: the
+(measured on an earlier toolchain, not re-measured). The fix needs no
+device-side machinery at all: the
 page table is already a plain jit input (sampling/serve.py), so two slots
 whose page-table rows contain the same physical page READ the same K/V.
 Sharing is therefore purely a host-allocator question — which this trie

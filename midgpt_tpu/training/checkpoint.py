@@ -391,7 +391,16 @@ class CheckpointManager:
             d = self._step_dir(step)
             if d is None:
                 return
-            write_manifest(d, step)
+            # A local step directory under several processes is a SHARED
+            # filesystem: one writer commits the manifest (two would race on
+            # the same temp file — one os.replace wins, the other raises),
+            # and everyone waits for the commit before verifying against it.
+            if jax.process_index() == 0:
+                write_manifest(d, step)
+            if jax.process_count() > 1:
+                from jax.experimental import multihost_utils
+
+                multihost_utils.sync_global_devices(f"midgpt_ckpt_manifest_{step}")
             if faults.should_fire("truncate_ckpt_item", step=step):
                 # Corruption AFTER the manifest committed (bit rot / bad
                 # copy): the recorded hashes no longer match the bytes.

@@ -43,7 +43,9 @@ def flops_per_token(cfg: GPTConfig, seq_len: tp.Optional[int] = None) -> float:
     return 6.0 * n_params + 12.0 * L * D * T
 
 
-# Peak bf16 TFLOP/s per chip by TPU generation (public figures).
+# Peak dense bf16 FLOP/s per chip, keyed by a substring of `device_kind`
+# (Google Cloud TPU documentation, per-generation system pages; v5e:
+# "TPU v5e", 197 TFLOP/s — jax reports its kind as "TPU v5 lite").
 _PEAK_FLOPS = {
     "v6": 918e12,
     "v5p": 459e12,
@@ -55,20 +57,32 @@ _PEAK_FLOPS = {
 }
 
 
-def device_peak_flops(device: tp.Optional[jax.Device] = None) -> tp.Optional[float]:
+def device_peak_flops(device: tp.Optional[jax.Device] = None) -> float:
+    """Published peak of `device` (default: the first one). A device that
+    is not in the table is an error, not a default: a utilization against
+    an assumed peak is not a measurement."""
     device = device or jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
+    kind = device.device_kind.lower()
     for name, flops in _PEAK_FLOPS.items():
         if name in kind:
             return flops
-    return None
+    raise ValueError(
+        f"no published peak FLOP/s for device_kind {device.device_kind!r} "
+        f"(platform {device.platform!r}); add it to "
+        "training/metrics.py _PEAK_FLOPS with its source"
+    )
 
 
 def mfu(tokens_per_sec: float, cfg: GPTConfig, n_devices: int) -> tp.Optional[float]:
-    peak = device_peak_flops()
-    if peak is None:
+    """Model FLOP/s utilization of the attached accelerators; None on the
+    host CPU (tests, rehearsals), which has no peak to be utilized against
+    — the train loop then logs no MFU at all rather than a made-up one."""
+    device = jax.devices()[0]
+    if device.platform == "cpu":
         return None
-    return tokens_per_sec * flops_per_token(cfg) / (peak * n_devices)
+    return tokens_per_sec * flops_per_token(cfg) / (
+        device_peak_flops(device) * n_devices
+    )
 
 
 class MetricLogger:

@@ -72,8 +72,11 @@ def is_forward_body(lines: tp.Sequence[str]) -> bool:
     return any(is_forward_shmap_line(l) and "while" in l for l in lines)
 
 
-def lower_abstract_train_step(config, mesh=None):
-    """Lower the full training step against ABSTRACT sharded inputs.
+def lower_abstract_train_step(config, mesh=None, eval_program=False):
+    """Lower the full training step against ABSTRACT sharded inputs — or,
+    with `eval_program`, the batched eval the train loop runs beside it
+    (`eval_loss_many` over a stacked (N, B, T) set: the same model under the
+    same mesh, through the implicit-GSPMD forward whatever `fsdp_mode` is).
 
     No buffers are materialized, so this works for 7B-class configs on a
     CPU test host and for AOT device topologies (tools/check_overlap_tpu.py
@@ -125,9 +128,11 @@ def lower_abstract_train_step(config, mesh=None):
         named_shardings(opt_specs, mesh),
     )
 
-    step, _, _ = make_train_step(config, optimizer, mesh, param_specs)
+    step, _, eval_loss_many = make_train_step(config, optimizer, mesh, param_specs)
     G, B, T = config.g_accum_iters, config.batch_size, mc.block_size
     data_sh = NamedSharding(mesh, batch_spec(shard_seq=mesh.shape["sp"] > 1))
     x_abs = jax.ShapeDtypeStruct((G, B, T), jnp.int32, sharding=data_sh)
+    if eval_program:
+        return eval_loss_many.lower(params_abs, x_abs, x_abs)
     key_abs = jax.ShapeDtypeStruct((2,), jnp.uint32)
     return step.lower(params_abs, opt_abs, x_abs, x_abs, key_abs)

@@ -13,6 +13,7 @@ reconstruction (reference sample.py:111-137) thanks to the named-item layout.
 from __future__ import annotations
 
 import argparse
+import json
 import os
 import pickle
 
@@ -72,8 +73,9 @@ def main() -> None:
 
     import jax
 
-    if os.environ.get("MIDGPT_PLATFORM"):
-        jax.config.update("jax_platforms", os.environ["MIDGPT_PLATFORM"])
+    from midgpt_tpu.utils import compile_cache
+
+    cache_stats = compile_cache.enable()
 
     import jax.numpy as jnp
     import numpy as np
@@ -138,7 +140,16 @@ def main() -> None:
     else:
         import tiktoken
 
-        enc = tiktoken.get_encoding("gpt2")
+        try:
+            # fetches the GPT-2 vocabulary over the network unless cached
+            enc = tiktoken.get_encoding("gpt2")
+        except Exception as e:
+            raise SystemExit(
+                f"no tokenizer: data_dir={config.data_dir!r} holds no "
+                "meta.pkl and the GPT-2 vocabulary could not be fetched "
+                f"({type(e).__name__}); put the dataset's meta.pkl (and "
+                "tokenizer.json) there"
+            ) from None
         encode = lambda s: enc.encode(s, allowed_special={"<|endoftext|>"})
         decode = enc.decode
 
@@ -223,6 +234,10 @@ def main() -> None:
     for i in range(args.num_samples):
         print(decode(np.asarray(out[i]).tolist()))
         print("---------------")
+    # machine-readable: the ids after the prompt, one list per sample
+    new_tokens = [np.asarray(o).tolist()[len(start_ids):] for o in out]
+    print("new_tokens: " + json.dumps(new_tokens))
+    print(cache_stats.summary())
 
 
 if __name__ == "__main__":

@@ -24,9 +24,17 @@ Usage: python multiproc_worker.py <coordinator> <n_proc> <proc_id> <data_dir>
            [mode] [rundir]
 """
 
+import faulthandler
 import sys
 
 import jax
+
+# A worker that is still running after this many seconds has hung (a healthy
+# one finishes in ~20 s): dump every thread's stack to the parent's pipe and
+# exit non-zero, so the parent test fails with the place it hung instead of
+# waiting out its own, longer, limit.
+WORKER_DEADLINE_S = 150
+faulthandler.dump_traceback_later(WORKER_DEADLINE_S, exit=True)
 
 coordinator, n_proc, proc_id, data_dir = (
     sys.argv[1],
@@ -38,9 +46,7 @@ mode = sys.argv[5] if len(sys.argv) > 5 else "train"
 rundir = sys.argv[6] if len(sys.argv) > 6 else ""
 
 jax.config.update("jax_platforms", "cpu")
-from midgpt_tpu.utils.compat import set_cpu_device_count
-
-set_cpu_device_count(2)
+jax.config.update("jax_num_cpu_devices", 2)
 jax.distributed.initialize(
     coordinator_address=coordinator, num_processes=n_proc, process_id=proc_id
 )

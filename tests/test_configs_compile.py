@@ -10,17 +10,24 @@ stops short of backend codegen).
 
 import dataclasses
 
+import jax
 import pytest
 
+from midgpt_tpu.parallel.mesh import fit_mesh_config
 from midgpt_tpu.utils.hlo import lower_abstract_train_step as _lower_train_step
 
 
 @pytest.mark.parametrize(
     "name", ["llama7b_long", "llama7b_32k", "openwebtext_xl", "wide610m"]
 )
-def test_at_scale_config_train_step_lowers(name):
+def test_at_scale_config_train_step_lowers(name, monkeypatch):
     import importlib
 
+    # attn_impl='flash' configs lower their real Pallas kernels (interpret
+    # mode off-TPU); without this the train step refuses to trace on CPU —
+    # a configured flash is never quietly swapped for blockwise.
+    fa = importlib.import_module("midgpt_tpu.kernels.flash_attention")
+    monkeypatch.setattr(fa, "RUN_INTERPRET_OFF_TPU", True)
     config = importlib.import_module(f"midgpt_tpu.configs.{name}").config
     # Shrink only what tracing doesn't need big: steps/batch stay as-is,
     # layer count drops (the scan makes depth O(1) for tracing anyway, but
@@ -35,5 +42,8 @@ def test_at_scale_config_train_step_lowers(name):
         # it) and is irrelevant to the train step being lowered here
         spec_layers=min(config.spec_layers, 1),
     )
+    # the pod meshes (fsdp=16, sp=8) are re-derived for the 8-device test
+    # mesh explicitly; make_mesh itself never resizes an axis
+    config = config.replace(mesh=fit_mesh_config(config.mesh, jax.device_count()))
     lowered = _lower_train_step(config)
     assert "main" in lowered.as_text()[:2000]

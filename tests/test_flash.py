@@ -100,14 +100,25 @@ def test_indivisible_blocks_adjust_not_raise():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
-def test_dispatch_falls_back_on_indivisible_len():
-    """multihead_attention(impl='flash') must handle arbitrary T (KV-cache
-    prefill) by taking the blockwise path instead of crashing."""
-    from midgpt_tpu.ops.attention import multihead_attention
+def test_dispatch_never_downgrades_a_configured_flash():
+    """multihead_attention(impl='flash') on a length no block tiles is an
+    error, not a quiet blockwise run; arbitrary-T callers (KV-cache
+    prefill) pick their blockwise route BY NAME through
+    flash_or_blockwise, and that route matches the naive oracle."""
+    import pytest
+
+    from midgpt_tpu.ops.attention import flash_or_blockwise, multihead_attention
 
     q, k, v = make_qkv(jax.random.PRNGKey(4), 1, 2, 90, 32)
+    with pytest.raises(ValueError, match="attn_impl='flash' cannot serve T=90"):
+        multihead_attention(q, k, v, impl="flash", inference=True, block_size=64)
+    assert flash_or_blockwise("flash", 90, 64) == "blockwise"
+    assert flash_or_blockwise("naive", 90, 64) == "naive"
     ref = naive_causal_attention(q, k, v)
-    out = multihead_attention(q, k, v, impl="flash", inference=True, block_size=64)
+    out = multihead_attention(
+        q, k, v, impl=flash_or_blockwise("flash", 90, 64), inference=True,
+        block_size=64,
+    )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 

@@ -1,7 +1,7 @@
 """Unified observability layer (midgpt_tpu/obs/): fake-clock tracer and
 metrics units, the Chrome-trace export contract that tools/trace_view.py
 and Perfetto consume, round-decomposition arithmetic, the engine-level
-span taxonomy on a CPU mesh, the obs-on == obs-off greedy bit-parity
+span catalog on a CPU mesh, the obs-on == obs-off greedy bit-parity
 pin, and the chaos-path flight-recorder dump.
 
 Pool geometry note: engine tests use num_pages=33 — disjoint from the
@@ -272,7 +272,7 @@ def test_global_flight_recorder_lazy_and_dump_none(tmp_path, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# Engine-level: span taxonomy, nesting, and the obs-toggle parity pin
+# Engine-level: span catalog, nesting, and the obs-toggle parity pin
 # ---------------------------------------------------------------------------
 
 CFG = GPTConfig(block_size=64, vocab_size=96, n_layer=2, n_head=2, n_embd=32)
@@ -305,8 +305,8 @@ def _run(params, obs):
     return eng, [done[u].tokens.tolist() for u in uids]
 
 
-def test_engine_emits_span_taxonomy_and_rounds_contain_decode(params):
-    """A served trace carries the documented span taxonomy
+def test_engine_emits_span_catalog_and_rounds_contain_decode(params):
+    """A served trace carries the documented span catalog
     (docs/OBSERVABILITY.md) and every decode phase span is time-contained
     in an engine.round envelope — one shared clock, four boundary reads."""
     obs = Observability()

@@ -274,7 +274,7 @@ def test_watchdog_armed_through_overlap_is_invisible(params):
 
 
 def test_watchdog_bounds_a_hung_overlapped_settle(params):
-    """A settle that never lands (dead-tunnel model: the in-flight
+    """A settle that never lands (the hung-device model: the in-flight
     handle's device arrays hang on materialization) must end in
     StepHangError via the armed watchdog — labeled as the overlap sync —
     not in a wedged server. The hang is injected by swapping the handle's

@@ -17,17 +17,6 @@ from midgpt_tpu.ops.loss import fused_linear_cross_entropy
 from midgpt_tpu.parallel.data import make_global_batch
 from midgpt_tpu.parallel.fsdp import constrain
 from midgpt_tpu.parallel.mesh import batch_spec, make_mesh
-
-# The tp>1 composition runs the shard_map body partial-manual (GSPMD 'auto'
-# axes); on this container's old jax the XLA CPU backend aborts in a CHECK
-# on that combination, so utils/compat.py refuses it up front — skip
-# cleanly here (runs on TPU backends / newer jax).
-_JAX = tuple(int(x) for x in jax.__version__.split(".")[:2])
-requires_partial_manual_cpu = pytest.mark.skipif(
-    _JAX < (0, 5) and jax.default_backend() == "cpu",
-    reason=f"partial-manual shard_map aborts XLA CPU on jax {jax.__version__}",
-)
-
 from midgpt_tpu.parallel.pipeline import make_pipeline_loss, pipeline_param_specs
 from midgpt_tpu.training.train import init_state, make_train_step
 
@@ -191,7 +180,6 @@ def test_pipeline_fsdp_composition_train_step_matches_oracle():
     np.testing.assert_allclose(evals["pp_fsdp"], evals["oracle"], rtol=1e-5)
 
 
-@requires_partial_manual_cpu
 def test_pipeline_tp_composition_train_step_matches_oracle():
     """r5 composition: Megatron 'tp' rides a GSPMD auto axis INSIDE the
     pipeline shard_map (manual axes: data/fsdp/sp/pp only) — the stage

@@ -39,7 +39,11 @@ from midgpt_tpu.sampling.serve import (
     _serve_decode_chunk,
     _serve_prefill_chunk,
 )
-from midgpt_tpu.training.train import init_state, make_train_step
+from midgpt_tpu.training.train import (
+    describe_step_program,
+    init_state,
+    make_train_step,
+)
 
 CFG = GPTConfig(block_size=64, vocab_size=96, n_layer=2, n_head=2, n_embd=32)
 
@@ -481,7 +485,15 @@ def test_train_step_compiles_exactly_once():
         jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec()),
     )
     x, y = batch(0)
-    p, opt, loss = step(p, opt, x, y, jax.random.fold_in(key, 0), loss)
+    k0 = jax.random.fold_in(key, 0)
+    # The loop's one-time "what did the step compile to" line
+    # (describe_step_program) lowers+compiles ahead of the first call; the
+    # call must then REUSE that executable — described and run, one compile.
+    with CompileCounter() as cc0:
+        line = describe_step_program(step, (p, opt, x, y, k0, loss), CFG.attn_impl)
+        p, opt, loss = step(p, opt, x, y, k0, loss)
+    assert cc0.count == 1, f"describing the step cost a second compile: {cc0.count}"
+    assert line.startswith("train step program: 0 Mosaic kernel call(s)"), line
     assert jit_cache_size(step) == 1
     with CompileCounter() as cc:
         for i in (1, 2):

@@ -17,16 +17,6 @@ from midgpt_tpu.parallel.mesh import batch_spec, make_mesh
 from midgpt_tpu.parallel.shard_map_fsdp import make_shard_map_loss
 
 import pytest
-# The tp>1 composition runs the shard_map body partial-manual (GSPMD 'auto'
-# axes); on this container's old jax the XLA CPU backend aborts in a CHECK
-# on that combination, so utils/compat.py refuses it up front — skip
-# cleanly here (runs on TPU backends / newer jax).
-_JAX = tuple(int(x) for x in jax.__version__.split(".")[:2])
-requires_partial_manual_cpu = pytest.mark.skipif(
-    _JAX < (0, 5) and jax.default_backend() == "cpu",
-    reason=f"partial-manual shard_map aborts XLA CPU on jax {jax.__version__}",
-)
-
 
 CHUNK = 1 << 30  # no loss chunking: keeps the comparison single-variable
 
@@ -168,8 +158,8 @@ def test_zero3_gathers_schedulable_ahead_of_compute():
     depend on activations (serializing the stream), this fails. The actual
     async overlap (all-gather-start/-done split around compute) is a TPU
     scheduler behavior — asserted against the real backend by
-    tools/check_overlap_tpu.py, whose measured result is recorded in
-    RESULTS.md; the CPU backend emits synchronous all-gathers.
+    tools/check_overlap_tpu.py (an AOT compile for described TPU devices);
+    the CPU backend emits synchronous all-gathers.
 
     Also pins that unroll=2 exposes BOTH layers' gathers in one body (the
     precondition for cross-layer overlap): 2 layers x 6 block leaves = 12."""
@@ -259,7 +249,6 @@ def test_zero3_gathers_schedulable_ahead_of_compute():
     )
 
 
-@requires_partial_manual_cpu
 def test_train_step_shard_map_tp_matches_gspmd():
     """r5: the explicit ZeRO-3 body composes with Megatron tp — 'tp' rides
     a GSPMD auto axis inside the shard_map (parallel/shard_map_fsdp.py)

@@ -28,10 +28,22 @@ def test_make_mesh_shapes():
     assert dict(mesh.shape) == {"data": 2, "fsdp": 2, "sp": 1, "tp": 2, "pp": 1, "ep": 1}
 
 
-def test_make_mesh_clamps_fsdp_on_small_counts():
-    # 8 devices, fsdp=16 requested -> clamp to 8
-    mesh = make_mesh(MeshConfig(data=-1, fsdp=16, sp=1))
-    assert dict(mesh.shape)["fsdp"] == 8
+def test_make_mesh_refuses_an_fsdp_that_does_not_fit():
+    """8 devices, fsdp=16 configured: make_mesh raises (a silently clamped
+    axis is how "everything on the first chip" hides); only the explicit
+    elastic-resume re-derivation, fit_mesh_config, resizes it."""
+    import pytest
+
+    from midgpt_tpu.parallel.mesh import fit_mesh_config
+
+    cfg = MeshConfig(data=-1, fsdp=16, sp=1)
+    with pytest.raises(ValueError, match="fsdp=16"):
+        make_mesh(cfg)
+    fitted = fit_mesh_config(cfg, 8)
+    assert (fitted.data, fitted.fsdp) == (-1, 8)
+    assert dict(make_mesh(fitted).shape)["fsdp"] == 8
+    # 6 devices' worth of room for fsdp=4: largest divisor <= 4 is 3
+    assert fit_mesh_config(MeshConfig(fsdp=4), 6).fsdp == 3
 
 
 def test_fsdp_specs_shard_large_replicate_small():

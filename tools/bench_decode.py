@@ -2,7 +2,7 @@
 
 Measures ms/token of the sampling engine's chunked decode
 (sampling/engine.py) on the 124M shape with random bf16 weights —
-the RESULTS.md inference table's methodology — plus an estimated
+the methodology of the earlier rounds' inference table — plus an estimated
 KV-cache HBM bytes/token column so cache-dtype wins are attributable:
 decode is HBM-bandwidth-bound, and the cache read is the dominant stream,
 so ms/token should track this column across dtypes far more closely than

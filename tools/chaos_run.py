@@ -63,8 +63,8 @@ MIDGPT_FAULTS env works too). Serving step keys: engine round for
 kill_mid_decode/poisoned_page, victim uid for slow_client, arrival index
 for submit_storm.
 
-Platform selection follows launch.py: set MIDGPT_PLATFORM=cpu (and
-MIDGPT_CPU_DEVICES=8) to drive recovery scenarios on the virtual CPU mesh.
+Platform selection is JAX's own: JAX_PLATFORMS=cpu JAX_NUM_CPU_DEVICES=8
+drives recovery scenarios on the virtual CPU mesh.
 """
 
 from __future__ import annotations
@@ -191,13 +191,6 @@ def main() -> int:
     _validate_fault_specs(parser, args.fault)
 
     import jax
-
-    if os.environ.get("MIDGPT_PLATFORM"):
-        jax.config.update("jax_platforms", os.environ["MIDGPT_PLATFORM"])
-        if os.environ.get("MIDGPT_CPU_DEVICES"):
-            from midgpt_tpu.utils.compat import set_cpu_device_count
-
-            set_cpu_device_count(int(os.environ["MIDGPT_CPU_DEVICES"]))
 
     if args.serve:
         return _serve_main(args)

@@ -4,7 +4,7 @@ The tensorboard profile UI is rarely available on TPU-VM hosts; this reads
 the xplane protobuf a `jax.profiler.start_trace` capture writes (e.g.
 `python bench.py --profile /tmp/trace` or `launch.py --debug`) and prints
 the top ops by exclusive time plus a category rollup — the exact workflow
-that drove the round-2 MFU work (RESULTS.md §1).
+that drove the round-2 MFU work.
 
 Usage:
     python tools/profile_summary.py <trace-dir-or-xplane.pb> [--steps N] [--top K]
