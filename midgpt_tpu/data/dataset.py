@@ -23,6 +23,8 @@ import typing as tp
 
 import numpy as np
 
+from midgpt_tpu.obs import flight_recorder
+
 _SPLIT_IDS = {"train": 0, "val": 1}
 
 
@@ -124,12 +126,15 @@ class TokenDataset:
         g_accum_iters: tp.Optional[int] = None,
         accum_slice: tp.Optional[tp.Tuple[int, int]] = None,
     ) -> tp.Tuple[np.ndarray, np.ndarray]:
-        """Deterministic batch for (split, step): resumable by construction."""
-        rng = np.random.default_rng([self.seed, _SPLIT_IDS[split], step])
-        return sample_batch(
-            self.splits[split], block_size, batch_size, g_accum_iters, rng=rng,
-            accum_slice=accum_slice,
-        )
+        """Deterministic batch for (split, step): resumable by construction.
+        Host batch assembly is the `data.batch` span of the flight recorder:
+        opened here, so every loop that feeds a step passes through it."""
+        with flight_recorder().tracer.span("data.batch", "data", "train"):
+            rng = np.random.default_rng([self.seed, _SPLIT_IDS[split], step])
+            return sample_batch(
+                self.splits[split], block_size, batch_size, g_accum_iters,
+                rng=rng, accum_slice=accum_slice,
+            )
 
     def meta(self) -> tp.Optional[dict]:
         """Char-codec metadata if present (shakespeare_char)."""

@@ -37,6 +37,7 @@ serving chaos) call `dump_flight_recorder(rundir)` for postmortems.
 
 from __future__ import annotations
 
+import collections
 import os
 import time
 import typing as tp
@@ -54,7 +55,30 @@ __all__ = [
     "Histogram",
     "flight_recorder",
     "dump_flight_recorder",
+    "live",
+    "STEP_SCOPES",
 ]
+
+# The `jax.named_scope`s the training step program opens around its phases
+# (training/train.py make_train_step; `lm_head_loss` inside ops/loss.py so
+# every loss variant carries it), beside the model's own embed / block /
+# attn / mlp / final_norm (models/gpt.py). Scopes are metadata: they name
+# the ops in a device trace and change no instruction. Named once, here:
+# docs/OBSERVABILITY.md lists them and tests/test_tracing.py pins them.
+STEP_SCOPES = ("cast_params", "lm_head_loss", "grad_accum", "optimizer", "health")
+
+# The recorders most recently built in this process, oldest first. TEMPORARY
+# seam: benchmarks/metrics/*.py readers get only what the cell's `run` dict
+# holds, and run after the cell's engine (its recorder's only owner) has gone
+# out of scope, so the last few stay reachable here. It goes when a
+# `benchmark` issue lets the cells hand `obs` to the readers. Bounded: a
+# server that builds a recorder per engine keeps four rings at most.
+_LIVE: tp.Deque["Observability"] = collections.deque(maxlen=4)
+
+
+def live() -> tp.List["Observability"]:
+    """The Observability objects last built in this process, newest last."""
+    return list(_LIVE)
 
 
 class Observability:
@@ -89,9 +113,22 @@ class Observability:
             "round_overlap_hidden_s", "host work overlapped under an "
             "in-flight dispatch (round-overlap dispatch; 0 when off)"
         )
-        self._rounds = self.metrics.counter(
-            "rounds_decomposed", "rounds with timing decomposition recorded"
+        # A request's life and a round's load, observed by the engine at the
+        # boundaries where they happen (sampling/serve.py `_req_phase`, `step`)
+        h = self.metrics.histogram
+        self.req_phase_s = {
+            "req.queue": h("req_queue_s", "submit (or preemption) to admission"),
+            "req.prefill": h("req_prefill_s", "admission to first token appended"),
+            "req.decode": h("req_decode_s", "first to last token"),
+        }
+        self._h_round = h("round_s", "one scheduler round, envelope")
+        self._h_round_chunks = h(
+            "round_prefill_chunks", "prefill chunks that rode the round"
         )
+        self._h_round_slots = h(
+            "round_decode_slots", "slots holding a decoding request after the round"
+        )
+        _LIVE.append(self)
 
     # -- round timing ---------------------------------------------------
 
@@ -112,7 +149,6 @@ class Observability:
         self._h_device.observe(t_land - t1)
         self._h_post.observe(t_post - t_land)
         self._h_hidden.observe(hidden_s)
-        self._rounds.inc()
         self.tracer.complete(f"{kind}.dispatch", "round", tid, t0, t1 - t0)
         self.tracer.complete(
             f"{kind}.device_wait", "round", tid, t1, t_land - t1
@@ -120,6 +156,14 @@ class Observability:
         self.tracer.complete(
             f"{kind}.host_post", "round", tid, t_land, t_post - t_land
         )
+
+    def record_engine_round(self, dur_s: float, prefill_chunks: int,
+                            decode_slots: int) -> None:
+        """One scheduler round's envelope (the `engine.round` span's own
+        duration) and what rode it."""
+        self._h_round.observe(dur_s)
+        self._h_round_chunks.observe(prefill_chunks)
+        self._h_round_slots.observe(decode_slots)
 
     def round_decomp(self) -> tp.Dict[str, tp.Any]:
         """p50/p95/mean per phase, milliseconds (stats() schema)."""
@@ -134,7 +178,7 @@ class Observability:
             }
 
         return {
-            "rounds": int(self._rounds.value),
+            "rounds": self._h_dispatch.n,
             "dispatch": _ms(self._h_dispatch),
             "device_wait": _ms(self._h_device),
             "host_post": _ms(self._h_post),

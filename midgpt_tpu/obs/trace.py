@@ -17,6 +17,14 @@ pairs (ph "b"/"e") keyed by id so overlapping requests render as separate
 tracks. Thread names ("engine", "server", "train", ...) become tid lanes
 via metadata events (ph "M", name "thread_name").
 
+Causality and identity: every event carries the request id it was given
+(`rid=`; spans of one request share it) and its parent — the sequence
+number of the innermost `span()` open on the same thread and lane when it
+was recorded, which the tracer tracks by itself. A layer's self time is its
+span's duration minus its children's. Ring tuple, positions fixed:
+`(kind, name, cat, tid, t, dur, ident, args, rid, parent, seq)`; export
+writes `rid` / `parent` / `seq` into `args`.
+
 The off switch is `NULL_TRACER`: a shared singleton whose `span()` returns
 one reusable no-op context manager and whose record methods are `pass`.
 Instrumented code calls the tracer unconditionally and stays branch-free;
@@ -27,6 +35,7 @@ empty function body — sub-microsecond, zero clock reads, zero allocation.
 from __future__ import annotations
 
 import json
+import threading
 import time
 import typing as tp
 from collections import deque
@@ -49,24 +58,35 @@ class _SpanHandle:
     deque.append is atomic under the GIL).
     """
 
-    __slots__ = ("_tracer", "_name", "_cat", "_tid", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_tid", "_rid", "_t0", "_seq",
+                 "_stack", "dur")
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str, tid: str):
+    def __init__(self, tracer: "Tracer", name: str, cat: str, tid: str,
+                 rid: tp.Optional[int] = None):
         self._tracer = tracer
         self._name = name
         self._cat = cat
         self._tid = tid
+        self._rid = rid
         self._t0 = 0.0
+        self.dur = 0.0  # set on exit, for callers that also feed a histogram
 
     def __enter__(self) -> "_SpanHandle":
-        self._t0 = self._tracer._clock()
+        tr = self._tracer
+        self._seq = tr._next_seq()
+        self._stack = tr._open_stack(self._tid)
+        self._stack.append(self._seq)
+        self._t0 = tr._clock()
         return self
 
     def __exit__(self, *exc) -> None:
-        t1 = self._tracer._clock()
-        self._tracer._push(
-            (_COMPLETE, self._name, self._cat, self._tid, self._t0,
-             t1 - self._t0, None, None)
+        tr = self._tracer
+        self.dur = tr._clock() - self._t0
+        stack = self._stack
+        stack.pop()
+        tr._push(
+            (_COMPLETE, self._name, self._cat, self._tid, self._t0, self.dur,
+             None, None, self._rid, stack[-1] if stack else None, self._seq)
         )
 
 
@@ -102,6 +122,10 @@ class Tracer:
         self._capacity = capacity
         self._t_base = clock()
         self.dropped = 0
+        self._seq = 0
+        # open `span()`s per (thread, lane): the top is the parent of
+        # whatever that thread records on that lane next
+        self._open: tp.Dict[tp.Tuple[int, str], tp.List[int]] = {}
 
     # -- recording -------------------------------------------------------
 
@@ -110,43 +134,59 @@ class Tracer:
             self.dropped += 1
         self._ring.append(ev)
 
-    def span(self, name: str, cat: str = "", tid: str = "main") -> _SpanHandle:
+    def _next_seq(self) -> int:
+        self._seq += 1
+        return self._seq
+
+    def _open_stack(self, tid: str) -> tp.List[int]:
+        return self._open.setdefault((threading.get_ident(), tid), [])
+
+    def _record(self, kind, name, cat, tid, t, dur, ident, args, rid) -> None:
+        stack = self._open.get((threading.get_ident(), tid))
+        self._push((kind, name, cat, tid, t, dur, ident, args, rid,
+                    stack[-1] if stack else None, self._next_seq()))
+
+    def span(self, name: str, cat: str = "", tid: str = "main",
+             rid: tp.Optional[int] = None) -> _SpanHandle:
         """Context manager measuring one host-side phase."""
-        return _SpanHandle(self, name, cat, tid)
+        return _SpanHandle(self, name, cat, tid, rid)
 
     def complete(
         self, name: str, cat: str, tid: str, start: float, dur: float,
-        args: tp.Optional[dict] = None,
+        args: tp.Optional[dict] = None, rid: tp.Optional[int] = None,
     ) -> None:
         """Record a span from explicit clock readings — for phases whose
         boundaries were already captured (the round decomposition reads
         the clock once per boundary and derives several spans)."""
-        self._push((_COMPLETE, name, cat, tid, start, dur, None, args))
+        self._record(_COMPLETE, name, cat, tid, start, dur, None, args, rid)
 
     def instant(
         self, name: str, cat: str = "", tid: str = "main",
-        args: tp.Optional[dict] = None,
+        args: tp.Optional[dict] = None, rid: tp.Optional[int] = None,
     ) -> None:
         """Point event (admission, eviction, shed, rollback, ...)."""
-        self._push((_INSTANT, name, cat, tid, self._clock(), 0.0, None, args))
+        self._record(_INSTANT, name, cat, tid, self._clock(), 0.0, None, args, rid)
 
     def async_begin(
-        self, name: str, ident: str, cat: str = "", tid: str = "main",
-        args: tp.Optional[dict] = None,
+        self, name: str, ident: tp.Union[str, int], cat: str = "",
+        tid: str = "main", args: tp.Optional[dict] = None,
+        t: tp.Optional[float] = None,
     ) -> None:
         """Open one track of a long-lived overlapping lifecycle (a request
-        from submit to finish). `ident` pairs it with async_end."""
-        self._push(
-            (_ASYNC_BEGIN, name, cat, tid, self._clock(), 0.0, ident, args)
-        )
+        from submit to finish). `ident` pairs it with async_end and is the
+        event's request id. `t` is a reading the caller already took from
+        the same clock (the engine stamps request phases with the readings
+        it hands its clients); absent, the tracer reads its own."""
+        self._record(_ASYNC_BEGIN, name, cat, tid,
+                     self._clock() if t is None else t, 0.0, ident, args, ident)
 
     def async_end(
-        self, name: str, ident: str, cat: str = "", tid: str = "main",
-        args: tp.Optional[dict] = None,
+        self, name: str, ident: tp.Union[str, int], cat: str = "",
+        tid: str = "main", args: tp.Optional[dict] = None,
+        t: tp.Optional[float] = None,
     ) -> None:
-        self._push(
-            (_ASYNC_END, name, cat, tid, self._clock(), 0.0, ident, args)
-        )
+        self._record(_ASYNC_END, name, cat, tid,
+                     self._clock() if t is None else t, 0.0, ident, args, ident)
 
     # -- introspection / export -----------------------------------------
 
@@ -158,7 +198,9 @@ class Tracer:
         self.dropped = 0
 
     def events(self) -> tp.List[tuple]:
-        """Raw ring contents, oldest first (tests introspect these)."""
+        """Raw ring contents, oldest first: (kind, name, cat, tid, t, dur,
+        ident, args, rid, parent, seq) — tests and the benchmark index the
+        first eight."""
         return list(self._ring)
 
     def export(self) -> tp.List[dict]:
@@ -167,7 +209,7 @@ class Tracer:
         `thread_name` metadata events so Perfetto labels them."""
         tids: tp.Dict[str, int] = {}
         out: tp.List[dict] = []
-        for kind, name, cat, tid, t, dur, ident, args in self._ring:
+        for kind, name, cat, tid, t, dur, ident, args, rid, parent, seq in self._ring:
             lane = tids.setdefault(tid, len(tids) + 1)
             ev: tp.Dict[str, tp.Any] = {
                 "name": name,
@@ -183,6 +225,13 @@ class Tracer:
                 ev["s"] = "t"  # thread-scoped instant
             if ident is not None:
                 ev["id"] = ident
+            args = dict(args) if args else {}
+            if kind == _COMPLETE:
+                args["seq"] = seq  # what a child's `parent` points at
+            if parent is not None:
+                args["parent"] = parent
+            if rid is not None:
+                args["rid"] = rid
             if args:
                 ev["args"] = args
             out.append(ev)
@@ -212,7 +261,8 @@ class _NullTracer:
 
     dropped = 0
 
-    def span(self, name: str, cat: str = "", tid: str = "main") -> _NullSpan:
+    def span(self, name: str, cat: str = "", tid: str = "main",
+             rid: tp.Optional[int] = None) -> _NullSpan:
         return _NULL_SPAN
 
     def complete(self, *a, **k) -> None:

@@ -14,7 +14,10 @@ import typing as tp
 import jax
 import jax.numpy as jnp
 
+from midgpt_tpu.obs import STEP_SCOPES
+
 Array = jax.Array
+_LM_HEAD_LOSS = STEP_SCOPES[1]
 
 
 def cross_entropy_loss(logits: Array, labels: Array) -> Array:
@@ -31,6 +34,23 @@ def fused_linear_cross_entropy(
     labels: Array,
     chunk_tokens: int = 8192,
     remat_chunks: tp.Optional[bool] = None,
+) -> Array:
+    """`_fused_linear_cross_entropy` under the `lm_head_loss` named scope
+    (obs.STEP_SCOPES): opened here, not at the call sites, so the gspmd,
+    shard_map, pipeline and eval losses all carry it, forward and backward
+    (no custom backward rule: jvp / transpose / remat keep the scope)."""
+    with jax.named_scope(_LM_HEAD_LOSS):
+        return _fused_linear_cross_entropy(
+            hidden, lm_head, labels, chunk_tokens, remat_chunks
+        )
+
+
+def _fused_linear_cross_entropy(
+    hidden: Array,
+    lm_head: Array,
+    labels: Array,
+    chunk_tokens: int,
+    remat_chunks: tp.Optional[bool],
 ) -> Array:
     """Mean CE of `hidden @ lm_head.T` against integer labels WITHOUT ever
     materializing the full (B*T, V) float32 logits.

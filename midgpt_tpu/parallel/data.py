@@ -15,16 +15,20 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from midgpt_tpu.obs import flight_recorder
+
 
 def make_global_batch(arr: np.ndarray, mesh: Mesh, spec: P) -> jax.Array:
     """Assemble a global array from this process's local slice of the batch.
 
     `arr` is the process-local chunk: its batch axis is 1/n_proc of the
     global batch. make_array_from_process_local_data infers the global shape
-    from the sharding.
+    from the sharding. The host-to-device put is the `data.put` span of the
+    flight recorder (one per array: x and y of a step make two).
     """
-    sharding = NamedSharding(mesh, spec)
-    return jax.make_array_from_process_local_data(sharding, arr)
+    with flight_recorder().tracer.span("data.put", "data", "train"):
+        sharding = NamedSharding(mesh, spec)
+        return jax.make_array_from_process_local_data(sharding, arr)
 
 
 def replicate(x: tp.Any, mesh: Mesh) -> tp.Any:

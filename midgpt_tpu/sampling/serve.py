@@ -544,6 +544,7 @@ class Request:
     max_new_tokens: int
     eos_id: tp.Optional[int] = None
     deadline: tp.Optional[float] = None  # absolute time.perf_counter() expiry
+    t_submit: float = 0.0  # engine clock at submit(); kept across preemptions
 
 
 @dataclasses.dataclass
@@ -567,6 +568,10 @@ class _Slot:
     # evidence exists.
     spec_k: int = 1
     accept_ema: float = 1.0
+    # obs only (the `req.prefill` end args): prompt tokens the prefix cache
+    # skipped at admission, and the round the slot was admitted in
+    skipped: int = 0
+    admit_round: int = 0
 
     @property
     def prefilling(self) -> bool:
@@ -714,6 +719,8 @@ class ServeEngine:
         self.obs = obs
         self._trace = obs.tracer if obs is not None else NULL_TRACER
         self._obs_tid = obs_tid
+        # uid -> (open request phase, its start): obs-on only (_req_phase)
+        self._req_open: tp.Dict[int, tp.Tuple[str, float]] = {}
         # Hung-dispatch watchdog (robustness/watchdog.py), same injection
         # discipline as clock/obs: None (default) leaves the decode round's
         # force a plain np.asarray — no thread, no event, nothing for the
@@ -812,6 +819,7 @@ class ServeEngine:
         self.cow_pages = 0
         self.prefix_evictions = 0
         self.prefilled_tokens = 0
+        self.prefill_chunks = 0
         # Host-RAM KV spill tier (sampling/fleet.py SpillTier), wired by
         # attach_spill: evicted trie pages land there instead of being
         # discarded, and _admit re-adopts resident runs past the trie
@@ -1003,8 +1011,39 @@ class ServeEngine:
             )
         uid = self._uid
         self._uid += 1
-        self.queue.append(Request(uid, prompt, max_new_tokens, eos_id, deadline))
+        self.queue.append(
+            Request(uid, prompt, max_new_tokens, eos_id, deadline, now)
+        )
+        if self.obs is not None:
+            self._req_phase(uid, "req.queue", now)
         return uid
+
+    def _req_phase(
+        self, uid: int, phase: tp.Optional[str], t: float,
+        end_args: tp.Optional[dict] = None,
+        begin_args: tp.Optional[dict] = None,
+    ) -> None:
+        """A request's life as async tracks (id = uid) and histograms:
+        `req.queue` (submit or preemption -> admitted), `req.prefill`
+        (admitted -> first token appended), `req.decode` (first -> last
+        token). Closes the request's open phase at `t` and opens `phase`
+        (None: the request is over). Called with obs ON only, with clock
+        readings the engine took anyway — `t` is what `on_token` hands the
+        client, so queue + prefill IS the client's time to first token.
+        Async, never "X": a seconds-long complete span per request would
+        own every idle gap under it (benchmarks/reduce.py attribute_gaps)."""
+        was = self._req_open.pop(uid, None)
+        if was is not None:
+            name, t0 = was
+            self.obs.req_phase_s[name].observe(t - t0)
+            self._trace.async_end(
+                name, uid, "request", self._obs_tid, end_args, t
+            )
+        if phase is not None:
+            self._req_open[uid] = (phase, t)
+            self._trace.async_begin(
+                phase, uid, "request", self._obs_tid, begin_args, t
+            )
 
     def _backlog_pages(self) -> int:
         """Worst-case page demand committed to live (queued + running)
@@ -1344,13 +1383,26 @@ class ServeEngine:
         round's decode group FIRST, then settle the previous round and run
         every host phase while the new group computes on the device.
         With overlap="group" the order below is unchanged — only the
-        decode call fuses `round_group` rounds into one dispatch."""
-        if self.overlap == "double" and self.draft_params is None:
-            self._step_overlapped()
-            return
+        decode call fuses `round_group` rounds into one dispatch.
+
+        Either order runs inside one `engine.round` span: the phases and
+        the decode decomposition nest under it (the tracer records the
+        parent), so its self time is the scheduler's own cost."""
+        chunks0 = self.prefill_chunks
+        with self._trace.span("engine.round", "round", self._obs_tid) as sp:
+            if self.overlap == "double" and self.draft_params is None:
+                self._step_overlapped()
+            else:
+                self._step_classic()
+        if self.obs is not None:
+            self.obs.record_engine_round(
+                sp.dur, self.prefill_chunks - chunks0,
+                sum(s is not None and not s.prefilling for s in self.slots),
+            )
+
+    def _step_classic(self) -> None:
         self.rounds += 1
         tr = self._trace
-        t_round = 0.0 if self.obs is None else self._clock()
         if faults.should_fire("poisoned_page", step=self.rounds):
             tr.instant("fault.poisoned_page", "fault", self._obs_tid)
             self._poison_page()
@@ -1384,11 +1436,6 @@ class ServeEngine:
             self._decode_round_grouped()
         else:
             self._decode_round()
-        if self.obs is not None:
-            tr.complete(
-                "engine.round", "round", self._obs_tid, t_round,
-                self._clock() - t_round, args={"round": self.rounds},
-            )
 
     def _step_overlapped(self) -> None:
         """One DOUBLE-BUFFERED round (overlap="double"): dispatch round
@@ -1411,7 +1458,6 @@ class ServeEngine:
         group is drained before any of them strike."""
         self.rounds += 1
         tr = self._trace
-        t_round = 0.0 if self.obs is None else self._clock()
         if self._inflight is not None and self._fault_needs_drain():
             self._settle_inflight()
         if self._inflight is not None and faults.should_fire(
@@ -1458,11 +1504,6 @@ class ServeEngine:
             self._admit()
         with tr.span("engine.prefill", "phase", self._obs_tid):
             self._prefill_round()
-        if self.obs is not None:
-            tr.complete(
-                "engine.round", "round", self._obs_tid, t_round,
-                self._clock() - t_round, args={"round": self.rounds},
-            )
 
     def _kill_decode_round(self) -> None:
         """The `kill_mid_decode` fault: this round's decode dispatch died
@@ -1866,7 +1907,9 @@ class ServeEngine:
                     # len(prompt) - 1 cap guarantees the final prompt token
                     # is always re-prefilled, so first-token logits come
                     # from a live chunk (never from a skipped one).
-                    with self._trace.span("trie.match", "prefix", self._obs_tid):
+                    with self._trace.span(
+                        "trie.match", "prefix", self._obs_tid, req.uid
+                    ):
                         mr = self.prefix_cache.match(
                             req.prompt, max_tokens=len(req.prompt) - 1
                         )
@@ -1889,6 +1932,9 @@ class ServeEngine:
                     "admitted", "lifecycle", self._obs_tid,
                     args={"uid": req.uid, "slot": i},
                 )
+                if self.obs is not None:
+                    slot.skipped, slot.admit_round = slot.prompt_pos, self.rounds
+                    self._req_phase(req.uid, "req.prefill", now)
 
     def _ensure_pages(self, slot: _Slot, upto_tokens: int) -> bool:
         """Grow slot's page list to cover positions [0, upto_tokens);
@@ -1958,6 +2004,7 @@ class ServeEngine:
                 req.max_new_tokens - len(victim.generated),
                 req.eos_id,
                 req.deadline,  # the clock keeps running across preemptions
+                req.t_submit,
             ),
         )
         self._release_slot(victim)
@@ -1967,6 +2014,11 @@ class ServeEngine:
         self._trace.instant(
             "preempt", "lifecycle", self._obs_tid, args={"uid": req.uid}
         )
+        if self.obs is not None:
+            self._req_phase(
+                req.uid, "req.queue", self._clock(),
+                end_args={"status": "preempted"}, begin_args={"resumed": True},
+            )
 
     def _release_slot(self, slot: _Slot) -> None:
         """The ONE funnel a departing slot's pages go through (finish,
@@ -2095,7 +2147,9 @@ class ServeEngine:
         # Span covers host assembly + async ENQUEUE only — prefill logits
         # are not forced here (mid-prompt chunks never sync; the final
         # chunk's force happens in the first-token block below).
-        with self._trace.span("prefill.chunk", "prefill", self._obs_tid):
+        with self._trace.span(
+            "prefill.chunk", "prefill", self._obs_tid, slot.request.uid
+        ):
             logits, self.cache = _serve_prefill_chunk(
                 self.config,
                 self.params,
@@ -2128,6 +2182,7 @@ class ServeEngine:
         slot.length = slot.prompt_pos
         self._reclaim_window(slot)  # long prompts free behind-window pages
         self.prefilled_tokens += n_valid
+        self.prefill_chunks += 1
         if not slot.prefilling:
             if self.prefix_cache is not None:
                 # The prompt's complete pages are immutable from here on
@@ -2143,7 +2198,8 @@ class ServeEngine:
             # The np.asarray is the force/sync — the span holds the device
             # wait for the final prefill chunk plus the host sample.
             with self._trace.span(
-                "prefill.first_token", "prefill", self._obs_tid
+                "prefill.first_token", "prefill", self._obs_tid,
+                slot.request.uid,
             ):
                 last = np.asarray(logits)[0, n_valid - 1]
                 if self.temperature == 0.0:
@@ -2472,11 +2528,17 @@ class ServeEngine:
             "reclaimed_pages": self.prefix_evictions,
         }
 
-    def _finish(self, fr: FinishedRequest) -> None:
+    def _finish(self, fr: FinishedRequest, t: tp.Optional[float] = None) -> None:
         """Record a terminal transition (ok/EOS/timeout/cancelled) and fire
         the streaming hook — the ONE funnel every path to `finished` goes
-        through, so the async server never misses an ending."""
+        through, so the async server never misses an ending. `t` is the
+        last token's time where the caller has it (obs only)."""
         self.finished[fr.uid] = fr
+        if self.obs is not None:
+            self._req_phase(
+                fr.uid, None, self._clock() if t is None else t,
+                end_args={"tokens_out": len(fr.token_times), "status": fr.status},
+            )
         self._trace.instant(
             "finish", "lifecycle", self._obs_tid,
             args={"uid": fr.uid, "status": fr.status},
@@ -2493,6 +2555,17 @@ class ServeEngine:
         req = slot.request
         if self.on_token is not None:
             self.on_token(req.uid, tok, t)
+        if self.obs is not None and len(slot.generated) == 1:
+            todo = len(req.prompt) - slot.skipped
+            self._req_phase(
+                req.uid, "req.decode", t,
+                end_args={
+                    "prompt_tokens": len(req.prompt),
+                    "prefix_skipped": slot.skipped,
+                    "chunks": -(-todo // self.prefill_chunk),
+                    "rounds": self.rounds - slot.admit_round + 1,
+                },
+            )
         hit_eos = req.eos_id is not None and tok == req.eos_id
         if hit_eos or len(slot.generated) >= req.max_new_tokens:
             self._finish(
@@ -2502,7 +2575,8 @@ class ServeEngine:
                         [req.prompt, np.asarray(slot.generated, np.int32)]
                     ),
                     token_times=slot.token_times,
-                )
+                ),
+                t,
             )
             self._release_slot(slot)
             self.slots[slot_i] = None

@@ -17,6 +17,7 @@ import jax
 
 from midgpt_tpu.config import ExperimentConfig
 from midgpt_tpu.models.gpt import GPTConfig
+from midgpt_tpu.obs import flight_recorder
 
 try:  # wandb is an optional dependency
     import wandb as _wandb
@@ -203,6 +204,12 @@ class Profiler:
     def maybe_start(self, step: int, at_step: int = 0) -> None:
         if self.enabled and step == at_step:
             jax.profiler.start_trace(self.rundir or "/tmp/midgpt_trace")
+            # One mark on both clocks (what benchmarks/run.py does with
+            # `bench.sync`): the annotation lands in the profile, the
+            # instant in the flight recorder, and their difference moves
+            # the recorder's host spans onto the profile's timeline.
+            with jax.profiler.TraceAnnotation("obs.sync"):
+                flight_recorder().tracer.instant("obs.sync", "train", "train")
             self._active = True
 
     def maybe_stop(self, wait_for: tp.Any = None) -> None:

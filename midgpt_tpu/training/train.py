@@ -32,7 +32,7 @@ import optax
 from midgpt_tpu.config import ExperimentConfig
 from midgpt_tpu.data.dataset import TokenDataset
 from midgpt_tpu.models.gpt import GPT, GPTParams
-from midgpt_tpu.obs import dump_flight_recorder, flight_recorder
+from midgpt_tpu.obs import STEP_SCOPES, dump_flight_recorder, flight_recorder
 from midgpt_tpu.ops.loss import fused_linear_cross_entropy
 from midgpt_tpu.parallel.data import make_global_batch
 from midgpt_tpu.parallel.fsdp import constrain, named_shardings
@@ -83,6 +83,9 @@ def health_flag(grad, loss: Array, prev_loss: Array) -> Array:
     )
     healthy = grads_ok & jnp.isfinite(loss) & jnp.isfinite(prev_loss)
     return jnp.where(healthy, loss, jnp.nan)
+
+
+_CAST_PARAMS, _, _GRAD_ACCUM, _OPTIMIZER, _HEALTH = STEP_SCOPES
 
 
 def make_train_step(
@@ -222,7 +225,15 @@ def make_train_step(
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def step(params: GPTParams, opt_state, x_GBT: Array, y_GBT: Array, key,
              prev_loss=0.0):
-        params_c = cast_compute(params)
+        # The step's phases carry jax.named_scopes (obs.STEP_SCOPES; the
+        # model opens embed / block / attn / mlp / final_norm, ops/loss.py
+        # lm_head_loss): metadata that names the ops in a device trace for
+        # benchmarks/metrics/step_phases.py. They change no instruction
+        # (tests/test_tracing.py pins the count). None may be opened around a
+        # Pallas kernel call: the TPU compiler names a custom call after its
+        # innermost scope, and the kernel readers match `attn.<n>`.
+        with jax.named_scope(_CAST_PARAMS):
+            params_c = cast_compute(params)
         keys = jax.random.split(key, G)
 
         value_and_grad = (
@@ -234,8 +245,9 @@ def make_train_step(
             # No accumulation machinery: skip the zeros-init + add + divide
             # passes over a full parameter-sized buffer (~3 HBM sweeps).
             loss, grad = value_and_grad(params_c, x_GBT[0], y_GBT[0], keys[0])
-            grad = constrain(grad, param_specs, mesh)
-            grad = jax.tree.map(lambda g, p: g.astype(p.dtype), grad, params)
+            with jax.named_scope(_GRAD_ACCUM):
+                grad = constrain(grad, param_specs, mesh)
+                grad = jax.tree.map(lambda g, p: g.astype(p.dtype), grad, params)
         else:
 
             # The /G rides each accumulate as a fused elementwise scale, so
@@ -252,18 +264,21 @@ def make_train_step(
             def microstep(grad_acc, xyk):
                 x, y, k = xyk
                 loss, grad = value_and_grad(params_c, x, y, k)
-                grad = constrain(grad, param_specs, mesh)
-                grad_acc = jax.tree.map(
-                    lambda a, g: a + g.astype(a.dtype) * inv_G, grad_acc, grad
-                )
+                with jax.named_scope(_GRAD_ACCUM):
+                    grad = constrain(grad, param_specs, mesh)
+                    grad_acc = jax.tree.map(
+                        lambda a, g: a + g.astype(a.dtype) * inv_G, grad_acc, grad
+                    )
                 return grad_acc, loss
 
-            grad_init = jax.tree.map(jnp.zeros_like, params)
+            with jax.named_scope(_GRAD_ACCUM):
+                grad_init = jax.tree.map(jnp.zeros_like, params)
             grad, losses = jax.lax.scan(microstep, grad_init, (x_GBT, y_GBT, keys))
             loss = jnp.mean(losses)
-        updates, opt_state = optimizer.update(grad, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        params = constrain(params, param_specs, mesh)
+        with jax.named_scope(_OPTIMIZER):
+            updates, opt_state = optimizer.update(grad, opt_state, params)
+            params = optax.apply_updates(params, updates)
+            params = constrain(params, param_specs, mesh)
         # Post-UPDATE health, folded into the reported loss: the scalar loss
         # is computed from the PRE-update params, so on its own it shows
         # divergence one step after the poisoned state could already have
@@ -271,7 +286,8 @@ def make_train_step(
         # Callers that thread the previous reported loss back in (the train
         # loop) get sticky poisoning; one-shot callers (benches, parity
         # tests) pass nothing and get the per-step check.
-        loss = health_flag(grad, loss, prev_loss)
+        with jax.named_scope(_HEALTH):
+            loss = health_flag(grad, loss, prev_loss)
         return params, opt_state, loss
 
     def _eval_loss_one(params_c: GPTParams, x: Array, y: Array) -> Array:
@@ -467,6 +483,22 @@ class TrainRuntime:
     finite_check: tp.Callable
     n_params: int
     _initial: tp.Optional[tp.Tuple[tp.Any, tp.Any]] = None
+    # The step's arguments as the loop passes them, abstract (shapes and
+    # shardings; the key and the loss carrier concrete): what
+    # `step_program_text` lowers with.
+    step_avals: tp.Tuple = ()
+
+    def step_program_text(self) -> str:
+        """Optimized HLO of the compiled step program, with each
+        instruction's `metadata={op_name="jit(step)/.../<scope>/<op>"}`: the
+        named scopes of obs.STEP_SCOPES and models/gpt.py by instruction
+        name. A device trace names its ops by instruction (`fusion.2826`)
+        and, on the v5e, carries no scope path of its own, so this text is
+        what maps a traced op to its phase
+        (benchmarks/metrics/step_phases.py). Same avals as the loop's call,
+        so the compile is the persistent cache's entry of the running
+        program (a load, not a compile)."""
+        return self.step.lower(*self.step_avals).compile().as_text()
 
     def take_initial(self, config: ExperimentConfig) -> tp.Tuple[tp.Any, tp.Any]:
         """Hand out the freshly initialized state (once); re-init if a later
@@ -528,7 +560,20 @@ def make_runtime(
     step, eval_loss, eval_loss_many = make_train_step(
         config, optimizer, mesh, param_specs
     )
-    return TrainRuntime(
+    global _LAST_RUNTIME
+    abstract_params, abstract_opt = _abstract_like(params), _abstract_like(opt_state)
+    batch = jax.ShapeDtypeStruct(
+        (config.g_accum_iters, config.batch_size, config.model_config.block_size),
+        jnp.int32,
+        sharding=jax.sharding.NamedSharding(
+            mesh, batch_spec(with_accum=True, shard_seq=mesh.shape["sp"] > 1)
+        ),
+    )
+    loss_carrier = jax.ShapeDtypeStruct(
+        (), jnp.float32,
+        sharding=jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec()),
+    )
+    _LAST_RUNTIME = TrainRuntime(
         mesh=mesh,
         dataset=dataset,
         optimizer=optimizer,
@@ -537,14 +582,27 @@ def make_runtime(
         step=step,
         eval_loss=eval_loss,
         eval_loss_many=eval_loss_many,
-        abstract_state={
-            "params": _abstract_like(params),
-            "opt_state": _abstract_like(opt_state),
-        },
+        abstract_state={"params": abstract_params, "opt_state": abstract_opt},
         finite_check=jax.jit(_all_finite),
         n_params=GPT.count_params(params),
         _initial=(params, opt_state),
+        step_avals=(
+            abstract_params, abstract_opt, batch, batch,
+            jax.random.fold_in(jax.random.PRNGKey(config.seed), 0), loss_carrier,
+        ),
     )
+    return _LAST_RUNTIME
+
+
+# TEMPORARY seam, like obs.live(): benchmarks/metrics/*.py readers get no
+# handle on the runtime the cell built, so the newest one stays reachable
+# here until a `benchmark` issue lets the cells hand it to the readers.
+_LAST_RUNTIME: tp.Optional[TrainRuntime] = None
+
+
+def last_runtime() -> tp.Optional[TrainRuntime]:
+    """The TrainRuntime `make_runtime` last built in this process."""
+    return _LAST_RUNTIME
 
 
 def train(
@@ -667,9 +725,10 @@ def train(
                 threading.Event().wait()
             return float(arr)
 
-        if wd is None:
-            return force()
-        return wd.sync(force, step=itr, label="train.loss_sync")
+        with _tr.span("train.loss_sync", "train", "train"):
+            if wd is None:
+                return force()
+            return wd.sync(force, step=itr, label="train.loss_sync")
 
     try:
         for itr in range(first_step, config.max_steps):
@@ -697,11 +756,10 @@ def train(
                     )
                 )
             profiler.maybe_start(itr, at_step=first_step + 1)
-            # Span covers host-side batch feed + async ENQUEUE of the one
-            # step program — device time shows up at the log-interval float
-            # sync, not here (dispatch is asynchronous;
-            # tools/profile_summary.py --correlate lines host spans up
-            # against xplane device time).
+            # Span covers the async ENQUEUE of the one step program — the
+            # feed has its own spans (`data.batch` in Dataset.batch,
+            # `data.put` in make_global_batch) and device time shows up at
+            # the log-interval sync (`train.loss_sync`), not here.
             with _tr.span("train.step", "train", "train"):
                 params, opt_state, loss = step(params, opt_state, xg, yg, step_key, loss)
             profiler.maybe_stop(wait_for=loss)
@@ -750,47 +808,48 @@ def train(
                         last_good_step=last_good,
                         rundir=config.rundir,
                     )
-                dt = _time.time() - t_last
-                tok_s = tokens_since / dt if dt > 0 else 0.0
-                t_last, tokens_since = _time.time(), 0
-                # Recompile watch (graftcheck pass-2 hook): the whole step is
-                # ONE XLA program, so its jit cache must stay at exactly one
-                # entry. Growth means some input's shape/dtype is unstable
-                # across steps — the silent per-step-recompile failure mode
-                # CLAUDE.md warns about, easily >10x wall-clock, invisible in
-                # the loss. Warn at the already-paid log sync; pinned in
-                # tests/test_recompile_pins.py.
-                n_programs = step_cache_size()
-                if n_programs is not None and n_programs > 1 and not warned_recompile:
-                    warned_recompile = True
-                    if jax.process_index() == 0:
-                        print(
-                            f"WARNING: train step has compiled {n_programs} distinct "
-                            "programs — input shapes/dtypes are unstable across "
-                            "steps and every recompile stalls the device "
-                            "(run graftcheck --audit / check batch shapes)"
+                with _tr.span("train.log", "train", "train"):
+                    dt = _time.time() - t_last
+                    tok_s = tokens_since / dt if dt > 0 else 0.0
+                    t_last, tokens_since = _time.time(), 0
+                    # Recompile watch (graftcheck pass-2 hook): the whole step is
+                    # ONE XLA program, so its jit cache must stay at exactly one
+                    # entry. Growth means some input's shape/dtype is unstable
+                    # across steps — the silent per-step-recompile failure mode
+                    # CLAUDE.md warns about, easily >10x wall-clock, invisible in
+                    # the loss. Warn at the already-paid log sync; pinned in
+                    # tests/test_recompile_pins.py.
+                    n_programs = step_cache_size()
+                    if n_programs is not None and n_programs > 1 and not warned_recompile:
+                        warned_recompile = True
+                        if jax.process_index() == 0:
+                            print(
+                                f"WARNING: train step has compiled {n_programs} distinct "
+                                "programs — input shapes/dtypes are unstable across "
+                                "steps and every recompile stalls the device "
+                                "(run graftcheck --audit / check batch shapes)"
+                            )
+                    metrics.update(
+                        {
+                            "loss/optimized": loss_f,
+                            "lr": float(schedule(itr)),
+                            "throughput/tokens_per_sec": tok_s,
+                        }
+                    )
+                    m = mfu(tok_s, config.model_config, jax.device_count())
+                    if m is not None:
+                        metrics["throughput/mfu"] = m
+                    logger.log(itr, dict(metrics))
+                    if progress.active:
+                        progress.update(
+                            0, loss=f"{loss_f:.4f}", lr=f"{metrics['lr']:.2e}",
+                            tok_s=f"{tok_s:,.0f}",
                         )
-                metrics.update(
-                    {
-                        "loss/optimized": loss_f,
-                        "lr": float(schedule(itr)),
-                        "throughput/tokens_per_sec": tok_s,
-                    }
-                )
-                m = mfu(tok_s, config.model_config, jax.device_count())
-                if m is not None:
-                    metrics["throughput/mfu"] = m
-                logger.log(itr, dict(metrics))
-                if progress.active:
-                    progress.update(
-                        0, loss=f"{loss_f:.4f}", lr=f"{metrics['lr']:.2e}",
-                        tok_s=f"{tok_s:,.0f}",
-                    )
-                elif jax.process_index() == 0:
-                    print(
-                        f"step {itr}: loss {loss_f:.4f} lr {metrics['lr']:.2e} "
-                        f"tok/s {tok_s:,.0f}"
-                    )
+                    elif jax.process_index() == 0:
+                        print(
+                            f"step {itr}: loss {loss_f:.4f} lr {metrics['lr']:.2e} "
+                            f"tok/s {tok_s:,.0f}"
+                        )
             progress.update(1)
             if mngr is not None and mngr.should_save(itr):
                 # One device sync per SAVE interval (not per step): never let

@@ -253,7 +253,7 @@ def test_observability_dump_writes_trace_and_prom(tmp_path):
         "decode.dispatch", "decode.device_wait", "decode.host_post",
     }
     prom = (tmp_path / "flight_recorder.prom").read_text()
-    assert "rounds_decomposed 1" in prom
+    assert "round_dispatch_s_count 1" in prom
 
 
 def test_global_flight_recorder_lazy_and_dump_none(tmp_path, monkeypatch):

@@ -1,0 +1,506 @@
+"""The measurement inside the program (PR 24): causality and identity in the
+tracer, a request's life as async tracks in the serving engine, named phases
+in the training step, seconds per compiled program in set-up — and the
+benchmark readers (benchmarks/metrics/) that turn each into a per-layer
+metric, run here on hand-made `run` dicts and in a CPU rehearsal of two cells.
+CPU runs give counts and structure, never a time."""
+
+import contextlib
+import importlib.util
+import json
+import os
+import re
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from midgpt_tpu import obs as obs_mod
+from midgpt_tpu.models.gpt import GPT, GPTConfig
+from midgpt_tpu.obs import STEP_SCOPES, Observability
+from midgpt_tpu.obs.trace import NULL_TRACER, Tracer
+from midgpt_tpu.sampling.serve import ServeEngine
+from midgpt_tpu.utils import compile_cache
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CFG = GPTConfig(block_size=64, vocab_size=64, n_layer=2, n_head=2, n_embd=32)
+
+
+class StepClock:
+    """Each read returns the time, then advances it: every read is visible."""
+
+    def __init__(self, step=0.001):
+        self.t, self.step, self.calls = 0.0, step, 0
+
+    def __call__(self):
+        self.calls += 1
+        self.t += self.step
+        return self.t
+
+
+@pytest.fixture(scope="module")
+def params():
+    return GPT.init(CFG, jax.random.PRNGKey(0))
+
+
+def _engine(params, num_pages, clock, obs=None, on_token=None):
+    return ServeEngine(
+        CFG, params, max_slots=2, page_size=8, num_pages=num_pages,
+        prefill_chunk=8, decode_chunk=4, temperature=0.0,
+        cache_dtype=jnp.float32, clock=clock, obs=obs, on_token=on_token,
+    )
+
+
+def _serve(eng):
+    uids = [eng.submit(np.arange(1, 12 + 3 * i, dtype=np.int32), 10 + i) for i in range(4)]
+    while not eng.idle:
+        eng.step()
+    return uids, [eng.finished[u].tokens.tolist() for u in uids]
+
+
+# ---------------------------------------------------------------------------
+# A. tracer: the ring tuple keeps its head; rid and parent are recorded
+# ---------------------------------------------------------------------------
+
+
+def test_ring_tuple_head_unchanged_and_rid_parent_appended():
+    tr = Tracer(capacity=16, clock=StepClock(1.0))
+    with tr.span("engine.round", "round", "engine"):
+        with tr.span("prefill.chunk", "prefill", "engine", rid=7):
+            tr.instant("admitted", "lifecycle", "engine", args={"uid": 7})
+        tr.complete("decode.dispatch", "round", "engine", 2.5, 0.5)
+        tr.async_begin("req.queue", 7, "request", "engine", t=1.25)
+        tr.instant("elsewhere", "x", "server")  # another lane: no parent
+    evs = {e[1]: e for e in tr.events()}
+    # positions 0-7 as before: kind, name, cat, tid, start, dur, ident, args
+    assert evs["prefill.chunk"][:8] == ("X", "prefill.chunk", "prefill", "engine", 3.0, 2.0, None, None)
+    assert evs["decode.dispatch"][:8] == ("X", "decode.dispatch", "round", "engine", 2.5, 0.5, None, None)
+    assert evs["req.queue"][:8] == ("b", "req.queue", "request", "engine", 1.25, 0.0, 7, None)
+    assert all(len(e) == 11 for e in evs.values())
+    rnd, chunk = evs["engine.round"], evs["prefill.chunk"]
+    assert rnd[9] is None and chunk[8] == 7
+    assert chunk[9] == rnd[10]  # the chunk's parent is the round's sequence number
+    assert evs["admitted"][9] == chunk[10]  # innermost open span wins
+    assert evs["decode.dispatch"][9] == rnd[10] and evs["req.queue"][9] == rnd[10]
+    assert evs["req.queue"][8] == 7  # an async track's id is its request id
+    assert evs["elsewhere"][9] is None
+    exported = {e["name"]: e for e in tr.export() if e["ph"] != "M"}
+    assert exported["prefill.chunk"]["args"] == {"seq": chunk[10], "parent": rnd[10], "rid": 7}
+    assert exported["admitted"]["args"] == {"uid": 7, "parent": chunk[10]}
+    assert exported["engine.round"]["args"] == {"seq": rnd[10]}
+
+
+def test_null_tracer_takes_the_new_arguments_and_stays_empty():
+    with NULL_TRACER.span("prefill.chunk", "prefill", "engine", 7):
+        NULL_TRACER.async_begin("req.queue", 7, "request", "engine", None, 1.0)
+        NULL_TRACER.async_end("req.queue", 7, "request", "engine", t=2.0)
+        NULL_TRACER.complete("a", "b", "c", 0.0, 1.0, rid=7)
+    assert len(NULL_TRACER) == 0 and NULL_TRACER.events() == []
+
+
+def test_live_keeps_the_last_recorders_reachable_for_a_late_reader():
+    a = Observability()
+    b = Observability()
+    assert obs_mod.live()[-2:] == [a, b]
+    ident = id(b)
+    del a, b  # their owner is gone; a reader that runs afterwards still finds them
+    assert id(obs_mod.live()[-1]) == ident
+    for _ in range(6):
+        Observability()
+    assert len(obs_mod.live()) == 4  # bounded
+
+
+# ---------------------------------------------------------------------------
+# B. serving engine: a request's life, and nothing when obs is off
+# ---------------------------------------------------------------------------
+
+
+def _legs(events):
+    """{uid: [(name, begin, end, end_args, begin_args)]} from async pairs."""
+    open_, out = {}, {}
+    for e in events:
+        if e[0] == "b":
+            open_[(e[6], e[1])] = (e[4], e[7])
+        elif e[0] == "e":
+            t0, bargs = open_.pop((e[6], e[1]))
+            out.setdefault(e[6], []).append((e[1], t0, e[4], e[7], bargs))
+    assert not open_, f"async tracks left open: {open_}"
+    return out
+
+
+def test_request_queue_plus_prefill_is_time_to_first_token(params):
+    clock = StepClock()
+    obs = Observability(clock=clock)
+    first, submit = {}, {}
+    eng = _engine(params, 17, clock, obs, on_token=lambda uid, tok, t: first.setdefault(uid, t))
+    real_submit = eng.submit
+
+    def stamped(prompt, n):
+        uid = real_submit(prompt, n)
+        submit[uid] = eng.queue[-1].t_submit  # the reading submit() took
+        return uid
+
+    eng.submit = stamped
+    uids, _ = _serve(eng)
+    legs = _legs(obs.tracer.events())
+    for uid in uids:
+        names = [leg[0] for leg in legs[uid]]
+        assert names == ["req.queue", "req.prefill", "req.decode"]
+        (_, q0, q1, _, _), (_, p0, p1, pargs, _), (_, d0, d1, dargs, _) = legs[uid]
+        assert q0 == submit[uid] and q1 == p0 and p1 == d0 == first[uid]
+        assert (q1 - q0) + (p1 - p0) == pytest.approx(first[uid] - submit[uid], abs=1e-12)
+        n_prompt = 11 + 3 * uids.index(uid)
+        assert pargs["prompt_tokens"] == n_prompt and pargs["prefix_skipped"] == 0
+        assert pargs["chunks"] == -(-n_prompt // 8) and pargs["rounds"] >= pargs["chunks"]
+        assert dargs == {"tokens_out": 10 + uids.index(uid), "status": "ok"}
+    hist = eng.stats()["obs"]["histograms"]
+    assert hist["req_queue_s"]["n"] == hist["req_prefill_s"]["n"] == hist["req_decode_s"]["n"] == 4
+    assert hist["round_s"]["n"] == eng.rounds
+    assert hist["round_prefill_chunks"]["n"] == eng.rounds and hist["round_decode_slots"]["max"] <= 2
+    assert eng._req_open == {}
+    # spans of one request share its id
+    chunk_rids = {e[8] for e in obs.tracer.events() if e[1] in ("prefill.chunk", "prefill.first_token")}
+    assert chunk_rids == set(uids)
+
+
+def test_preempted_request_gets_a_second_queue_leg(params):
+    clock = StepClock()
+    obs = Observability(clock=clock)
+    eng = _engine(params, 7, clock, obs)  # 6 usable pages: the younger slot is evicted
+    uids, _ = _serve(eng)
+    assert eng.preemptions >= 1
+    legs = _legs(obs.tracer.events())
+    victims = [u for u in uids if [leg[0] for leg in legs[u]].count("req.queue") > 1]
+    assert len(victims) == eng.preemptions
+    for u in victims:
+        second = [leg for leg in legs[u] if leg[0] == "req.queue"][1]
+        assert second[4] == {"resumed": True}
+        cut = [leg for leg in legs[u] if (leg[3] or {}).get("status") == "preempted"]
+        assert len(cut) == 1 and cut[0][2] == second[1]  # one leg ends where the queue leg begins
+    assert eng._req_open == {}
+
+
+@pytest.mark.parametrize("num_pages,parent_reads", [(17, 42), (7, 50)])
+def test_obs_off_reads_the_clock_as_before_and_emits_the_same_tokens(params, num_pages, parent_reads):
+    """With obs off the request spans cost no clock read: the engine reads its
+    injected clock exactly as often as the commit before PR 24 did over the
+    same rounds (42 / 50 reads: counted there with this scenario, the second
+    with one preemption), and obs on changes no token."""
+    clock = StepClock()
+    eng = _engine(params, num_pages, clock)
+    _, toks_off = _serve(eng)
+    assert clock.calls == parent_reads
+    _, toks_on = _serve(_engine(params, num_pages, StepClock(), Observability(clock=StepClock())))
+    assert toks_on == toks_off
+
+
+# ---------------------------------------------------------------------------
+# C. training: scopes are metadata; the feed has spans
+# ---------------------------------------------------------------------------
+
+
+def _lower_step(tmp_path):
+    from midgpt_tpu.parallel.data import make_global_batch
+    from midgpt_tpu.parallel.mesh import batch_spec, make_mesh
+    from midgpt_tpu.training.train import init_state, make_train_step
+    from test_train import tiny_config
+
+    cfg = tiny_config(tmp_path, g_accum_iters=2, compute_dtype="bfloat16")
+    mesh = make_mesh(cfg.mesh)
+    params, opt_state, specs, optimizer = init_state(cfg, mesh)
+    step, *_ = make_train_step(cfg, optimizer, mesh, specs)
+    x = make_global_batch(np.zeros((2, 8, 32), np.int32), mesh, batch_spec())
+    return step.lower(params, opt_state, x, x, jax.random.PRNGKey(0))
+
+
+def test_step_program_names_every_scope_and_gains_no_operation(tmp_path, monkeypatch):
+    sys.path.insert(0, os.path.join(ROOT, "tests"))
+    lowered = _lower_step(tmp_path)
+    n_ops = sum(" = " in line for line in lowered.as_text().splitlines())
+    locs = set(re.findall(r'loc\("([^"]+)"', lowered.as_text(debug_info=True)))
+    step_phases = _reader("step_phases.py")
+    seen = {step_phases.innermost_scope(loc) for loc in locs}
+    assert set(STEP_SCOPES) <= seen, f"scopes the lowered step does not name: {set(STEP_SCOPES) - seen}"
+    assert {"attn", "mlp", "embed", "final_norm"} <= seen
+    # backward ops of the loss keep the scope (no custom backward rule drops it)
+    assert any("transpose(jvp(lm_head_loss))" in loc for loc in locs)
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    bare = sum(" = " in line for line in _lower_step(tmp_path).as_text().splitlines())
+    assert n_ops == bare
+
+
+def test_feed_spans_are_opened_where_the_work_is(tmp_path):
+    from midgpt_tpu.data.dataset import TokenDataset
+    from midgpt_tpu.parallel.data import make_global_batch
+    from midgpt_tpu.parallel.mesh import batch_spec, make_mesh
+    from test_train import tiny_config
+
+    (np.arange(4000) % 17).astype(np.uint16).tofile(tmp_path / "train.bin")
+    (np.arange(400) % 17).astype(np.uint16).tofile(tmp_path / "val.bin")
+    tr = obs_mod.flight_recorder().tracer
+    n0 = len(tr.events())
+    x, _ = TokenDataset(str(tmp_path), seed=1).batch("train", 0, 32, 8, 1)
+    make_global_batch(x, make_mesh(tiny_config(tmp_path).mesh), batch_spec())
+    names = [e[1] for e in tr.events()[n0:]]
+    assert names == ["data.batch", "data.put"]
+
+
+# ---------------------------------------------------------------------------
+# D. set-up: seconds per compiled program
+# ---------------------------------------------------------------------------
+
+
+def test_compile_cache_stats_show_a_program_under_its_name():
+    import jax.monitoring
+    from jax._src import monitoring as _mon
+
+    stats = compile_cache.CompileCacheStats(dir="unused")
+    listener = stats._on_duration  # one bound-method object: unregister finds it by identity
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        @jax.jit
+        def tiny_named_program_pr24(x):
+            return x * 2 + 1
+
+        tiny_named_program_pr24(jnp.ones((3,))).block_until_ready()
+    finally:
+        _mon.unregister_event_duration_listener(listener)
+    cost = stats.programs["tiny_named_program_pr24"]  # jit(f) and f fold into one row
+    assert cost.calls == 1 and cost.compile_or_load_s > 0 and cost.trace_s > 0 and cost.lower_s > 0
+    t = stats.totals()
+    assert t["programs"] >= 1 and t["compile_or_load_s"] >= cost.compile_or_load_s
+    lines = stats.summary().splitlines()
+    assert lines[0] == "compile_cache: dir=unused requests=0 hits=0 writes=0"  # chip_smoke.py parses this
+    assert any("tiny_named_program_pr24" in ln and "x1" in ln for ln in lines[2:])
+
+
+# ---------------------------------------------------------------------------
+# E. readers, on hand-made run dicts
+# ---------------------------------------------------------------------------
+
+
+def _load(rel):
+    path = os.path.join(ROOT, "benchmarks", rel)
+    spec = importlib.util.spec_from_file_location("pr24_" + os.path.basename(rel)[:-3], path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _reader(name):
+    return _load(os.path.join("metrics", name))
+
+
+def _run_dict(kind, **over):
+    logs = []
+    run = {"kind": kind, "spans": [], "window_s": 10.0, "counters": {"traced_steps": 2},
+           "samples": {"ttft_s": [0.3]}, "trace_summary": None, "log": lambda *a: logs.append(" ".join(map(str, a))),
+           "load": lambda fn: _load(fn), "logs": logs}
+    run.update(over)
+    return run
+
+
+def _trace_summary(ops, infos):
+    """One device; ops = [(name, start_ns, dur_ns)], infos = {name: info dict}."""
+    names = sorted({n for n, _, _ in ops})
+    idx = {n: i for i, n in enumerate(names)}
+    dev_ops = sorted(([idx[n], s, d] for n, s, d in ops), key=lambda o: (o[1], -o[2]))
+    busy = sum(d for n, _, d in ops if not n.startswith("while"))
+    return {"trace": {"names": names, "info": {str(idx[n]): v for n, v in infos.items()}},
+            "devices": [{"ops": dev_ops}], "n_devices": 1, "busy_ns_mean": busy, "window_ns": 10_000_000}
+
+
+STEP_OPS = [("while.1", 0, 9_000_000), ("fusion.1", 0, 2_000_000), ("attn.3", 2_000_000, 1_000_000),
+            ("fusion.2", 3_000_000, 3_000_000), ("fusion.3", 6_000_000, 1_000_000),
+            ("fusion.4", 7_000_000, 1_500_000), ("copy.1", 8_500_000, 500_000),
+            ("fusion.5", 9_000_000, 1_000_000)]
+STEP_TEXT = """HloModule jit_step, is_scheduled=true
+%body (p: f32[8]) -> f32[8] {
+  %fusion.1 = bf16[8]{0} fusion(%p), kind=kOutput, metadata={op_name="jit(step)/jit(main)/while/body/jvp(block)/mlp/dot_general" source_file="x.py" source_line=1}
+  %attn.3 = bf16[8]{0} custom-call(%fusion.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jit(main)/while/body/transpose(jvp(block))/attn/pallas_call"}
+  %fusion.2 = bf16[8]{0} fusion(%attn.3), kind=kOutput, metadata={op_name="jit(step)/jit(main)/while/body/transpose(jvp(lm_head_loss))/dot_general"}
+  %fusion.3 = f32[8]{0} fusion(%fusion.2), kind=kLoop, metadata={op_name="jit(step)/jit(main)/while/body/jvp(lm_head_loss)/checkpoint/rematted_computation/exp"}
+  %fusion.4 = f32[8]{0} fusion(%fusion.3), kind=kLoop, metadata={op_name="jit(step)/jit(main)/while/body/grad_accum/add"}
+  ROOT %copy.1 = f32[8]{0} copy(%fusion.4)
+}
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %while.1 = f32[8]{0} while(%a), condition=%cond, body=%body
+  ROOT %fusion.5 = f32[8]{0} fusion(%while.1), kind=kLoop, metadata={op_name="jit(step)/jit(main)/optimizer/mul"}
+}
+"""
+
+
+class _FakeRuntime:
+    def __init__(self, text):
+        self.text = text
+
+    def step_program_text(self):
+        return self.text
+
+
+def _with_runtime(monkeypatch, text):
+    train = importlib.import_module("midgpt_tpu.training.train")
+    monkeypatch.setattr(train, "_LAST_RUNTIME", None if text is None else _FakeRuntime(text))
+
+
+def test_step_phases_reader_splits_exclusive_time_by_innermost_scope(monkeypatch):
+    _with_runtime(monkeypatch, STEP_TEXT)
+    run = _run_dict("train", trace_summary=_trace_summary(STEP_OPS, {}))
+    out = _reader("step_phases.py").read(run)
+    assert out == pytest.approx({"step.attn_ms": 0.5, "step.mlp_ms": 1.0, "step.lm_head_loss_ms": 2.0,
+                                 "step.optimizer_ms": 0.5, "step.unattributed_ms": 1.0})
+    assert sum(out.values()) == pytest.approx(run["trace_summary"]["busy_ns_mean"] / 1e6 / 2)
+
+
+@pytest.mark.parametrize("text,says", [
+    (re.sub(r"lm_head_loss|optimizer", "mlp", STEP_TEXT), "stale cache?"),
+    (STEP_TEXT.replace("%fusion.", "%other_fusion."), "is not the traced program"),
+    (None, "no last_runtime()"),
+])
+def test_step_phases_reader_reports_nothing_and_says_why(monkeypatch, text, says):
+    _with_runtime(monkeypatch, text)
+    run = _run_dict("train", trace_summary=_trace_summary(STEP_OPS, {}))
+    assert _reader("step_phases.py").read(run) is None
+    assert any(says in line for line in run["logs"])
+
+
+def test_step_program_text_names_the_running_programs_ops(tmp_path):
+    """The runtime's own text: instruction names with the scope path in their
+    metadata, from the same avals as the loop's call."""
+    from test_train import tiny_config
+
+    train = importlib.import_module("midgpt_tpu.training.train")
+    (np.arange(4000) % 17).astype(np.uint16).tofile(tmp_path / "train.bin")
+    (np.arange(400) % 17).astype(np.uint16).tofile(tmp_path / "val.bin")
+    rt = train.make_runtime(tiny_config(tmp_path, g_accum_iters=2))
+    assert train.last_runtime() is rt
+    reader = _reader("step_phases.py")
+    scopes = {reader.innermost_scope(v) for v in dict(reader._INSTRUCTION.findall(rt.step_program_text())).values()}
+    assert {"attn", "mlp", "lm_head_loss", "optimizer"} <= scopes
+
+
+def test_engine_requests_reader_on_a_hand_made_window():
+    reader = _reader("engine_requests.py")
+    obs = Observability(clock=StepClock())
+    tr = obs.tracer
+    tr.complete("engine.round", "round", "engine", 99.0, 0.9)  # the last round before the window
+    spans = [("engine.round", 100.0, 1.0), ("engine.admit", 100.0, 0.1), ("engine.prefill", 100.1, 0.3),
+             ("prefill.chunk", 100.15, 0.1), ("decode.dispatch", 100.5, 0.1), ("decode.device_wait", 100.6, 0.2),
+             ("engine.round", 101.0, 2.0), ("engine.prefill", 101.5, 1.0)]
+    for n, s, d in spans:
+        tr.complete(n, "x", "engine", s, d)
+    tr.async_begin("req.queue", 1, t=99.95)     # submitted as the window opened (after the last round)
+    tr.async_end("req.queue", 1, t=100.0)
+    tr.async_begin("req.prefill", 1, t=100.0)
+    tr.async_end("req.prefill", 1, t=101.0, args={"prompt_tokens": 5})
+    tr.async_begin("req.queue", 2, t=98.0)      # submitted before the window: not in the set
+    tr.async_end("req.queue", 2, t=100.0)
+    tr.async_begin("req.prefill", 2, t=100.0)
+    tr.async_end("req.prefill", 2, t=100.4, args={"prompt_tokens": 5})
+    tr.async_begin("req.queue", 3, t=100.2)     # preempted before its first token: legs are summed
+    tr.async_end("req.queue", 3, t=100.3)
+    tr.async_begin("req.prefill", 3, t=100.3)
+    tr.async_end("req.prefill", 3, t=100.5, args={"status": "preempted"})
+    tr.async_begin("req.queue", 3, t=100.5, args={"resumed": True})
+    tr.async_end("req.queue", 3, t=100.9)
+    tr.async_begin("req.prefill", 3, t=100.9)
+    tr.async_end("req.prefill", 3, t=102.0, args={"prompt_tokens": 5})
+    tr.async_begin("req.queue", 4, t=101.0)     # first token after the window: not in the set
+    tr.async_end("req.queue", 4, t=101.1)
+    tr.async_begin("req.prefill", 4, t=101.1)
+    tr.async_end("req.prefill", 4, t=111.0, args={"prompt_tokens": 5})
+    out = reader.read(_run_dict("serve", spans=spans, window_s=10.0))
+    assert out["engine.round_ms_p50"] == pytest.approx(1500.0)
+    # self time: 1.0 - (0.1 + 0.3 + 0.1 + 0.2) = 0.3 and 2.0 - 1.0 = 1.0
+    assert out["engine.round_self_ms_p50"] == pytest.approx(650.0)
+    assert out["req.queue_ms_mean"] == pytest.approx(1e3 * (0.05 + 0.5) / 2)
+    assert out["req.prefill_ms_mean"] == pytest.approx(1e3 * (1.0 + 1.3) / 2)
+
+
+def test_engine_requests_reader_without_tracks_reports_rounds_only():
+    for _ in range(4):
+        Observability()  # none of the recorders within reach holds a req.* track
+    run = _run_dict("serve", spans=[("engine.round", 1.0, 0.5)])
+    out = _reader("engine_requests.py").read(run)
+    assert set(out) == {"engine.round_ms_p50", "engine.round_self_ms_p50"}
+    assert any("req.* left out" in line for line in run["logs"])
+    run = _run_dict("serve")
+    assert _reader("engine_requests.py").read(run) == {}
+    assert any("no engine.round span" in line for line in run["logs"])
+
+
+def test_serve_prefill_reader_shares():
+    reader = _reader("serve_prefill.py")
+    spans = [("prefill.first_token", 1.0, 0.25), ("prefill.first_token", 2.0, 0.25), ("prefill.chunk", 3.0, 9.0)]
+    events = [("/device:TPU:0", "jit__serve_prefill_chunk(123)", 0, 3_000), ("/device:TPU:0", "jit__serve_decode_chunk(9)", 3_000, 5_000),
+              ("/device:TPU:0", "jit__serve_prefill_chunk(123)", 9_000, 4_000)]  # the last one is half outside
+    assert reader.prefill_share(events, 0, 11_000) == pytest.approx(100.0 * 5_000 / 10_000)
+    assert reader.prefill_share([], 0, 10) is None
+    # a run with no xplane file on disk (or no TPU plane in it): the span share only, and a log line
+    run = _run_dict("serve", spans=spans, trace_summary={"lo": 0, "hi": 10})
+    reader.TRACE_DIR = "/nonexistent/trace"
+    assert reader.read(run) == pytest.approx({"prefill.first_token_sync_share": 5.0})
+    assert any("no XLA Modules line" in line for line in run["logs"])
+    assert reader.read(_run_dict("train")) is None
+
+
+def test_train_feed_reader_sums_a_steps_batch_and_puts():
+    reader = _reader("train_feed.py")
+    tr = obs_mod.flight_recorder().tracer
+    base = 5_000_000.0  # far from any real perf_counter reading in this ring
+    for i, (b, p1, p2) in enumerate([(0.010, 0.001, 0.001), (0.020, 0.002, 0.002), (0.030, 0.003, 0.003)]):
+        t = base + i
+        tr.complete("data.batch", "data", "train", t, b)
+        tr.complete("data.put", "data", "train", t + 0.1, p1)
+        tr.complete("data.put", "data", "train", t + 0.2, p2)
+    tr.complete("data.batch", "data", "train", base + 50.0, 9.0)  # outside the window
+    out = reader.read(_run_dict("train", spans=[("bench.data", base, 0.01)], window_s=10.0))
+    assert out == pytest.approx({"train.feed_ms_p50": 24.0})
+    run = _run_dict("train", spans=[("bench.data", base + 1000.0, 0.01)])
+    assert reader.read(run) is None and any("left out" in line for line in run["logs"])
+
+
+def test_setup_programs_reader(monkeypatch):
+    reader = _reader("setup_programs.py")
+    stats = compile_cache.CompileCacheStats(dir="d")
+    stats._on_duration("/jax/core/compile/jaxpr_trace_duration", 0.5, fun_name="step")
+    stats._on_duration("/jax/core/compile/jaxpr_to_mlir_module_duration", 0.25, fun_name="jit(step)")
+    stats._on_duration("/jax/core/compile/backend_compile_duration", 4.0, fun_name="jit(step)")
+    stats._on_duration("/jax/core/compile/backend_compile_duration", 1.0, fun_name="jit(_serve_decode_chunk)")
+    stats._on_duration("/jax/compilation_cache/cache_retrieval_time_sec", 0.75)
+    monkeypatch.setattr(compile_cache, "_CURRENT", stats)
+    run = _run_dict("train")
+    assert reader.read(run) == {"setup.compile_or_load_s": 5.0, "setup.trace_lower_s": 0.75, "setup.programs": 2.0}
+    assert any("4.75 s  step  x1" in line for line in run["logs"])
+    monkeypatch.setattr(compile_cache, "_CURRENT", None)
+    run = _run_dict("serve")
+    assert reader.read(run) is None and any("left out" in line for line in run["logs"])
+
+
+# ---------------------------------------------------------------------------
+# the harness lists the new names where a CPU can produce them
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("cell,names", [
+    ("serve_124m_sample", {"req.queue_ms_mean", "req.prefill_ms_mean", "engine.round_ms_p50",
+                           "engine.round_self_ms_p50", "prefill.first_token_sync_share",
+                           "setup.compile_or_load_s",
+                           "setup.trace_lower_s", "setup.programs"}),
+    ("train_124m", {"train.feed_ms_p50", "setup.compile_or_load_s", "setup.trace_lower_s", "setup.programs",
+                    "step.attn_ms", "step.mlp_ms", "step.lm_head_loss_ms", "step.optimizer_ms",
+                    "step.unattributed_ms"}),
+])
+def test_rehearsal_lists_the_new_metrics(cell, names, tmp_path):
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"), JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"), "--workload", cell, "--seed",
+         "3000000019", "--seconds", "2", "--trace", "1", "--rehearse-cpu"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
+    last = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert last["rehearsal"] and last["correct"]
+    assert names <= set(last["would_report"]), sorted(names - set(last["would_report"]))

@@ -8,15 +8,11 @@ that drove the round-2 MFU work.
 
 Usage:
     python tools/profile_summary.py <trace-dir-or-xplane.pb> [--steps N] [--top K]
-        [--correlate <flight-recorder.json-or-dir>]
 
 `--steps` divides totals by the number of profiled steps so numbers read as
-per-step costs. `--correlate` lines the flight recorder's host-side
-`train.step` spans (midgpt_tpu/obs/, dumped to the rundir) up against the
-xplane's device ms/step: host span minus device time is host overhead
-(feed + enqueue) when positive; a host span much SHORTER than device time
-means dispatch ran ahead and the wall cost surfaces at the log-interval
-sync instead (docs/OBSERVABILITY.md).
+per-step costs. To line host spans up with the device timeline, use the
+`obs.sync` mark `launch.py --debug` leaves in both the profile and the
+flight recorder (docs/OBSERVABILITY.md).
 """
 
 from __future__ import annotations
@@ -67,47 +63,11 @@ def _categorize(full_name: str) -> str:
     return "other"
 
 
-def correlate_flight_recorder(path: str, device_ms_per_step: float) -> None:
-    """Print host-side train.step span stats from a flight-recorder dump
-    next to the xplane's device ms/step (module docstring on reading the
-    difference). JAX-free: reuses tools/trace_view.py's loader."""
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from trace_view import find_trace, load_trace
-
-    evs = load_trace(find_trace(path))
-    spans = [
-        e["dur"] / 1e3
-        for e in evs
-        if e.get("ph") == "X" and e.get("name") == "train.step"
-    ]
-    print("\n== flight-recorder correlation ==")
-    if not spans:
-        print("no train.step spans in the dump — was the recorder on "
-              "during the profiled steps?")
-        return
-    spans.sort()
-    host_ms = sum(spans) / len(spans)
-    print(f"host train.step spans: n={len(spans)}  mean={host_ms:.2f} ms  "
-          f"p50={spans[len(spans) // 2]:.2f} ms  max={spans[-1]:.2f} ms")
-    if device_ms_per_step > 0:
-        print(f"device (xplane):       {device_ms_per_step:.2f} ms/step")
-        delta = host_ms - device_ms_per_step
-        if delta >= 0:
-            print(f"host - device:         {delta:+.2f} ms/step host overhead "
-                  "(feed + enqueue)")
-        else:
-            print(f"host - device:         {delta:+.2f} ms/step — dispatch "
-                  "runs ahead; the wall cost lands at the log-interval sync")
-
-
 def main() -> int:
     p = argparse.ArgumentParser()
     p.add_argument("trace", help="trace dir or xplane.pb file")
     p.add_argument("--steps", type=int, default=1, help="profiled step count")
     p.add_argument("--top", type=int, default=25)
-    p.add_argument("--correlate", default=None, metavar="FLIGHT_RECORDER",
-                   help="flight_recorder.json (or a dir holding one): print "
-                   "host train.step span stats against the device ms/step")
     args = p.parse_args()
 
     try:
@@ -119,7 +79,6 @@ def main() -> int:
     with open(_find_xplane(args.trace), "rb") as f:
         xs.ParseFromString(f.read())
 
-    device_ms_per_step = 0.0
     for plane in xs.planes:
         if "TPU" not in plane.name and "GPU" not in plane.name:
             continue
@@ -148,7 +107,6 @@ def main() -> int:
                 stack.append((start, end, name))
 
             total = sum(excl.values())
-            device_ms_per_step = max(device_ms_per_step, total / 1e9 / args.steps)
             print(f"== {plane.name} :: {line.name} — {total/1e9/args.steps:.2f} ms/step ==")
             print("\n-- categories --")
             for cat, t in cats.most_common():
@@ -156,8 +114,6 @@ def main() -> int:
             print(f"\n-- top {args.top} ops (exclusive) --")
             for name, t in excl.most_common(args.top):
                 print(f"{t/1e9/args.steps:9.2f} ms x{cnt[name]//max(args.steps,1):<4} {name[:110]}")
-    if args.correlate:
-        correlate_flight_recorder(args.correlate, device_ms_per_step)
     return 0
 
 
