@@ -56,7 +56,7 @@ class ExperimentConfig:
     compute_dtype: str  # 'bfloat16'
     g_accum_iters: int
     shard_model: bool
-    model_config: GPTConfig
+    model_config: tp.Any  # GPTConfig, or another family's config (models/kimi_linear.py)
     mesh: MeshConfig = MeshConfig()
     eval_steps: int = 200  # batches per eval (reference train.py:110)
     # Max eval batches materialized on host / staged to device at once
@@ -173,9 +173,7 @@ class ExperimentConfig:
     debug: bool = False
 
     def __post_init__(self):
-        # Fail at construction, not at trace time deep inside the first step:
-        # attention-probability dropout exists only on the naive path
-        # (ops/attention.py dispatch).
+        # Fail at construction, not at trace time deep inside the first step.
         mc = self.model_config
         if not (0.0 < self.beta2 < 1.0):
             # beta2 >= 1 makes adam's bias correction divide by zero on step
@@ -183,6 +181,86 @@ class ExperimentConfig:
             # grad-norm health check cannot see (its soundness induction
             # assumes the chain maps finite state+grads to finite updates).
             raise ValueError(f"beta2={self.beta2} must be in (0, 1)")
+        if self.fsdp_mode not in ("gspmd", "shard_map"):
+            # A typo would silently run the GSPMD dispatch (train.py
+            # branches on == 'shard_map' else gspmd) — fail at construction
+            # like qkv_proj/rope_style.
+            raise ValueError(
+                f"unknown fsdp_mode {self.fsdp_mode!r} ('gspmd' or 'shard_map')"
+            )
+        if not 0 <= self.spec_layers < mc.n_layer:
+            # spec_layers == n_layer would "draft" with the target itself —
+            # all cost, no amortization — and deeper is shape-invalid.
+            raise ValueError(
+                f"spec_layers={self.spec_layers} must be in [0, n_layer="
+                f"{mc.n_layer})"
+            )
+        for k_name, k_val in (("spec_k_max", self.spec_k_max),
+                              ("spec_k_min", self.spec_k_min)):
+            if k_val < 1 or k_val & (k_val - 1):
+                # non-pow2 k would mint a fresh draft+verify program pair
+                # per value instead of riding the bucketed compile set
+                # (sampling/serve.py _spec_round)
+                raise ValueError(f"{k_name}={k_val} must be a power of two")
+        if self.spec_k_min > self.spec_k_max:
+            raise ValueError(
+                f"spec_k_min={self.spec_k_min} > spec_k_max={self.spec_k_max}"
+            )
+        if self.kv_cache_dtype not in ("bf16", "int8"):
+            # A typo would silently serve from a bf16 pool the operator
+            # believed was quantized (half the expected page capacity at a
+            # byte budget) — fail at construction like the other enums.
+            raise ValueError(
+                f"unknown kv_cache_dtype {self.kv_cache_dtype!r} "
+                "('bf16' or 'int8')"
+            )
+        if self.data_step_offset < 0:
+            # A negative offset would re-sample windows already consumed
+            # before the rollback — the exact data the skip exists to avoid.
+            raise ValueError(f"data_step_offset={self.data_step_offset} must be >= 0")
+        if self.max_restarts < 0:
+            raise ValueError(f"max_restarts={self.max_restarts} must be >= 0")
+        if self.ckpt_max_to_keep < 1:
+            raise ValueError(f"ckpt_max_to_keep={self.ckpt_max_to_keep} must be >= 1")
+        if self.ckpt_write_retries < 1:
+            raise ValueError(f"ckpt_write_retries={self.ckpt_write_retries} must be >= 1")
+        if self.preempt_check_interval < 1:
+            raise ValueError(
+                f"preempt_check_interval={self.preempt_check_interval} must be >= 1"
+            )
+        if self.restart_backoff_sec < 0 or self.ckpt_retry_backoff_sec < 0:
+            raise ValueError("backoff seconds must be >= 0")
+        if self.watchdog_deadline_s < 0:
+            # Negative would arm a guard that expires before the first poll
+            # — every step "hangs". 0 is the documented off switch.
+            raise ValueError(
+                f"watchdog_deadline_s={self.watchdog_deadline_s} must be "
+                ">= 0 (0 disables the watchdog)"
+            )
+        if self.watchdog_escalate not in ("raise", "exit"):
+            raise ValueError(
+                f"unknown watchdog_escalate {self.watchdog_escalate!r} "
+                "('raise' or 'exit')"
+            )
+        if self.on_resume_mesh not in ("same", "any"):
+            raise ValueError(
+                f"unknown on_resume_mesh {self.on_resume_mesh!r} "
+                "('same' or 'any')"
+            )
+        if self.preempt_grace_s < 0:
+            raise ValueError(
+                f"preempt_grace_s={self.preempt_grace_s} must be >= 0 "
+                "(0 = unbounded)"
+            )
+        # The model family's own rules (every family's config has
+        # `check_experiment`: models/__init__.py): the GPT's are
+        # `check_gpt_family` below, reached through `GPTConfig`.
+        mc.check_experiment(self)
+
+    def check_gpt_family(self) -> None:
+        """The GPT's rules (`GPTConfig.check_experiment`): its own knobs and
+        how they compose with the mesh axes and schedules of this config."""
+        mc = self.model_config
         if mc.qkv_proj not in ("fused", "split3"):
             # A typo here would silently fall back to the fused lowering AND
             # bypass the tp auto-switch (training/train.py) — fail loudly.
@@ -200,14 +278,9 @@ class ExperimentConfig:
             raise ValueError(
                 f"unknown attn_layout {mc.attn_layout!r} ('seq' or 'head')"
             )
-        if self.fsdp_mode not in ("gspmd", "shard_map"):
-            # A typo would silently run the GSPMD dispatch (train.py
-            # branches on == 'shard_map' else gspmd) — fail at construction
-            # like qkv_proj/rope_style.
-            raise ValueError(
-                f"unknown fsdp_mode {self.fsdp_mode!r} ('gspmd' or 'shard_map')"
-            )
         if mc.dropout > 0.0 and mc.attn_impl != "naive":
+            # attention-probability dropout exists only on the naive path
+            # (ops/attention.py dispatch)
             raise ValueError(
                 f"attn_impl={mc.attn_impl!r} does not support attention "
                 f"dropout (dropout={mc.dropout}); use attn_impl='naive' or "
@@ -336,70 +409,6 @@ class ExperimentConfig:
         sp = self.mesh.sp
         if sp == -1:
             sp = 1
-        if not 0 <= self.spec_layers < mc.n_layer:
-            # spec_layers == n_layer would "draft" with the target itself —
-            # all cost, no amortization — and deeper is shape-invalid.
-            raise ValueError(
-                f"spec_layers={self.spec_layers} must be in [0, n_layer="
-                f"{mc.n_layer})"
-            )
-        for k_name, k_val in (("spec_k_max", self.spec_k_max),
-                              ("spec_k_min", self.spec_k_min)):
-            if k_val < 1 or k_val & (k_val - 1):
-                # non-pow2 k would mint a fresh draft+verify program pair
-                # per value instead of riding the bucketed compile set
-                # (sampling/serve.py _spec_round)
-                raise ValueError(f"{k_name}={k_val} must be a power of two")
-        if self.spec_k_min > self.spec_k_max:
-            raise ValueError(
-                f"spec_k_min={self.spec_k_min} > spec_k_max={self.spec_k_max}"
-            )
-        if self.kv_cache_dtype not in ("bf16", "int8"):
-            # A typo would silently serve from a bf16 pool the operator
-            # believed was quantized (half the expected page capacity at a
-            # byte budget) — fail at construction like the other enums.
-            raise ValueError(
-                f"unknown kv_cache_dtype {self.kv_cache_dtype!r} "
-                "('bf16' or 'int8')"
-            )
-        if self.data_step_offset < 0:
-            # A negative offset would re-sample windows already consumed
-            # before the rollback — the exact data the skip exists to avoid.
-            raise ValueError(f"data_step_offset={self.data_step_offset} must be >= 0")
-        if self.max_restarts < 0:
-            raise ValueError(f"max_restarts={self.max_restarts} must be >= 0")
-        if self.ckpt_max_to_keep < 1:
-            raise ValueError(f"ckpt_max_to_keep={self.ckpt_max_to_keep} must be >= 1")
-        if self.ckpt_write_retries < 1:
-            raise ValueError(f"ckpt_write_retries={self.ckpt_write_retries} must be >= 1")
-        if self.preempt_check_interval < 1:
-            raise ValueError(
-                f"preempt_check_interval={self.preempt_check_interval} must be >= 1"
-            )
-        if self.restart_backoff_sec < 0 or self.ckpt_retry_backoff_sec < 0:
-            raise ValueError("backoff seconds must be >= 0")
-        if self.watchdog_deadline_s < 0:
-            # Negative would arm a guard that expires before the first poll
-            # — every step "hangs". 0 is the documented off switch.
-            raise ValueError(
-                f"watchdog_deadline_s={self.watchdog_deadline_s} must be "
-                ">= 0 (0 disables the watchdog)"
-            )
-        if self.watchdog_escalate not in ("raise", "exit"):
-            raise ValueError(
-                f"unknown watchdog_escalate {self.watchdog_escalate!r} "
-                "('raise' or 'exit')"
-            )
-        if self.on_resume_mesh not in ("same", "any"):
-            raise ValueError(
-                f"unknown on_resume_mesh {self.on_resume_mesh!r} "
-                "('same' or 'any')"
-            )
-        if self.preempt_grace_s < 0:
-            raise ValueError(
-                f"preempt_grace_s={self.preempt_grace_s} must be >= 0 "
-                "(0 = unbounded)"
-            )
         if mc.attn_impl == "ulysses":
             # Ulysses re-shards heads over sp (after any tp head sharding):
             # every (tp, sp) device needs whole heads.
@@ -417,17 +426,38 @@ def to_json(config: ExperimentConfig) -> str:
     return json.dumps(dataclasses.asdict(config), indent=2)
 
 
-_NESTED: tp.Dict[str, type] = {"model_config": GPTConfig, "mesh": MeshConfig}
+# Model families: the `family` a config.json's `model_config` names (absent:
+# the GPT, which every older rundir holds) -> "module:class" of its config
+# dataclass. The one place a family is registered; a family's module is
+# imported when a config names it, not with this module.
+MODEL_FAMILIES: tp.Dict[str, str] = {
+    "gpt": "midgpt_tpu.models.gpt:GPTConfig",
+    "kimi_linear": "midgpt_tpu.models.kimi_linear:KimiLinearConfig",
+}
+
+
+def _model_config_class(raw: dict) -> type:
+    family = raw.get("family", "gpt")
+    if family not in MODEL_FAMILIES:
+        raise ValueError(
+            f"config.json names model family {family!r}; this checkout has {sorted(MODEL_FAMILIES)}"
+        )
+    module, cls = MODEL_FAMILIES[family].split(":")
+    return getattr(importlib.import_module(module), cls)
+
+
+def _known(cls: type, raw: dict):
+    known = {f.name for f in dataclasses.fields(cls)}
+    return cls(**{k: v for k, v in raw.items() if k in known})
 
 
 def from_json(text: str) -> ExperimentConfig:
     raw = json.loads(text)
-    for name, cls in _NESTED.items():
-        if name in raw and isinstance(raw[name], dict):
-            known = {f.name for f in dataclasses.fields(cls)}
-            raw[name] = cls(**{k: v for k, v in raw[name].items() if k in known})
-    known = {f.name for f in dataclasses.fields(ExperimentConfig)}
-    return ExperimentConfig(**{k: v for k, v in raw.items() if k in known})
+    if isinstance(raw.get("model_config"), dict):
+        raw["model_config"] = _known(_model_config_class(raw["model_config"]), raw["model_config"])
+    if isinstance(raw.get("mesh"), dict):
+        raw["mesh"] = _known(MeshConfig, raw["mesh"])
+    return _known(ExperimentConfig, raw)
 
 
 def load_config(name: str) -> ExperimentConfig:

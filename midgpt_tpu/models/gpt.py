@@ -189,6 +189,16 @@ class GPTConfig:
                     "ulysses training kernels carry no window mask)"
                 )
 
+    # -- what the runtime reads of any model config (models/__init__.py) --
+    def model(self):
+        return GPT
+
+    def check_experiment(self, config) -> None:
+        config.check_gpt_family()
+
+    def check_serving(self, who: str) -> None:
+        """sample.py and ServeEngine serve this family."""
+
     @property
     def head_dim(self) -> int:
         assert self.n_embd % self.n_head == 0
@@ -1646,3 +1656,54 @@ class GPT:
         (reference model.py:161-164)."""
         total = sum(x.size for x in jax.tree.leaves(params))
         return total - params.lm_head.size
+
+    @staticmethod
+    def cast_params(params: GPTParams, dtype) -> GPTParams:
+        """The compute copy: every floating leaf in `dtype`."""
+        return jax.tree.map(
+            lambda p: p.astype(dtype) if jnp.issubdtype(p.dtype, jnp.floating) else p,
+            params,
+        )
+
+    # Decay applies to ALL params, including norm scales and embeddings, as in
+    # the reference (training/optim.py).
+    weight_decay_mask = None
+    # No counters of its own for the train loop's logged steps.
+    route_stats = None
+
+    @staticmethod
+    def param_specs(config, tree, mesh):
+        """Placement rule: GPipe layer-axis sharding when the mesh has a real
+        'pp' axis (parallel/pipeline.py), else Megatron tp x fsdp
+        (parallel/tp.py), which with mesh tp=1 reduces to the plain FSDP rule
+        exactly (pinned by test_tp.py). `config`: the ExperimentConfig."""
+        if mesh.shape["pp"] > 1:
+            # layer axis over 'pp', large leaves additionally over 'fsdp'
+            from midgpt_tpu.parallel.pipeline import pipeline_param_specs
+
+            return pipeline_param_specs(tree, mesh, config.shard_model, config.fsdp_min_size)
+        from midgpt_tpu.parallel.tp import tp_param_specs
+
+        return tp_param_specs(
+            tree, mesh, config.shard_model, config.fsdp_min_size, vocab_parallel=config.tp_vocab
+        )
+
+    @staticmethod
+    def flops_per_token(cfg: GPTConfig, seq_len: tp.Optional[int] = None, stats=None) -> float:
+        """Training FLOPs/token: 6N for the matmuls (fwd 2N + bwd 4N) plus the
+        12*L*D*T attention-scores term (PaLM appendix B accounting)."""
+        del stats
+        T = seq_len or cfg.block_size
+        D, L, V = cfg.n_embd, cfg.n_layer, cfg.vocab_size
+        if cfg.n_experts > 0:
+            # ACTIVE-expert accounting (the MoE convention): top_k expert MLPs
+            # + the router per token. The masked-dense lowering EXECUTES all E
+            # experts, so reported MFU under-counts by E/top_k there — honest
+            # for the useful-FLOPs metric.
+            mlp = min(cfg.moe_top_k, cfg.n_experts) * 8 * D * D + cfg.n_experts * D
+        else:
+            mlp = 8 * D * D
+        n_params = V * D + L * (4 * D * D + mlp + 2 * cfg.head_dim) + V * D
+        # Count the tied embedding once, like reference count_params (model.py:161).
+        n_params -= V * D
+        return 6.0 * n_params + 12.0 * L * D * T

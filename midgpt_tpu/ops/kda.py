@@ -1,0 +1,215 @@
+"""Kimi Delta Attention (KDA): the gated delta-rule recurrence, chunked.
+
+Per head, with a float32 state S of (d_k, d_v), a per-CHANNEL log decay
+g_t <= 0 of (d_k,) and a write strength beta_t in (0, 1):
+
+    S' = Diag(exp(g_t)) S_{t-1}
+    S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
+    o_t = S_t^T q_t
+
+`kda_recurrent` is that, token by token (`lax.scan` over T): the test oracle,
+and nothing on the training path calls it. `kda_chunked` computes the same in
+chunks of C tokens. Inside a chunk, with G_r = sum_{j<=r} g_j (so every decay
+between two positions of the chunk is exp(G_r - G_i) <= 1) and S_0 the state
+at the chunk's start, each token writes a rank-one term k_r u_r^T:
+
+    u_r = beta_r (v_r - S_0^T (k_r * e^{G_r}) - sum_{i<r} A_ri u_i),
+    A_ri = sum_c k_r[c] k_i[c] e^{G_r[c] - G_i[c]}
+    => U = W_v - W_k S_0,   [W_v | W_k] = (I + Diag(beta) tril(A,-1))^{-1} Diag(beta) [V | K * e^G]
+    O   = (Q * e^G) S_0 + tril(B) U,   B_ri = sum_c q_r[c] k_i[c] e^{G_r[c] - G_i[c]}
+    S_C = Diag(e^{G_C}) S_0 + (K * e^{G_C - G})^T U
+
+Everything but the three products with S_0 is independent of the state, so it
+is computed for all chunks at once, as matmuls; the `lax.scan` over chunks
+carries only S, in float32, and does three matmuls a chunk.
+
+Decay without overflow: A and B are NOT factored as (k_r e^{G_r}) . (k_i
+e^{-G_i}): e^{-G_i} overflows float32 once a channel has decayed by e^-88
+inside a chunk, which the published initial range of `A_log` and `dt_bias`
+reaches in 64 tokens. A chunk is cut into sub-blocks of `sub` tokens. Between
+two sub-blocks the decay is split at the later block's start R_a,
+e^{G_r - R_a} * e^{R_a - G_i}, both factors <= 1, and the product over the
+channels is a matmul. Inside one sub-block the (sub, sub, d_k) tensor of
+e^{G_r - G_i} is formed directly.
+
+Backward: plain reverse-mode AD of the above. The state-independent part of
+each chunk and the scan's body are `jax.checkpoint`ed, so the backward pass
+keeps one state per CHUNK (T / C of them), never one per token, and
+recomputes a chunk's (sub, sub, d_k) tensors when it reaches the chunk.
+"""
+
+from __future__ import annotations
+
+import functools
+import typing as tp
+
+import jax
+import jax.numpy as jnp
+
+Array = jax.Array
+_HIGHEST = jax.lax.Precision.HIGHEST
+# Tokens a chunk, tokens a sub-block, and chunks whose state-independent terms
+# are prepared together (a `lax.map` batch: it bounds what the backward pass
+# recomputes at once). On the v5e (B=1, T=8,192, 32 heads of 128, forward +
+# backward 72.2 ms a layer) no other setting moved the time by more than 6 %,
+# most for the worse (PERF.md §5, PR 26): constants, not options.
+CHUNK, SUB, CHUNKS_PER_BATCH = 64, 16, 8
+assert CHUNK % SUB == 0
+
+
+def causal_depthwise_conv(x: Array, taps: Array) -> Array:
+    """y_t[c] = sum_j taps[c, j] * x_{t-(K-1)+j}[c]: a causal convolution over
+    time of K taps on each channel alone (x (B, T, C), taps (C, K); the LAST
+    tap multiplies the current token, as in a `Conv1d` with left padding)."""
+    K = taps.shape[-1]
+    T = x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    return sum(xp[:, j : j + T] * taps[:, j] for j in range(K))
+
+
+def kda_recurrent(q: Array, k: Array, v: Array, g: Array, beta: Array) -> tp.Tuple[Array, Array]:
+    """Token-by-token oracle. q, k, g (B, T, H, d_k); v (B, T, H, d_v); beta
+    (B, T, H). Returns (o (B, T, H, d_v) float32, final state (B, H, d_k, d_v))."""
+    f32 = jnp.float32
+    q, k, v, g, beta = (a.astype(f32) for a in (q, k, v, g, beta))
+    B, T, H, dk = k.shape
+    S0 = jnp.zeros((B, H, dk, v.shape[-1]), f32)
+
+    def step(S, x):
+        q_t, k_t, v_t, g_t, b_t = x  # (B, H, d), beta (B, H)
+        S = jnp.exp(g_t)[..., None] * S
+        pred = jnp.einsum("bhkv,bhk->bhv", S, k_t, precision=_HIGHEST)
+        S = S + jnp.einsum("bhk,bhv->bhkv", b_t[..., None] * k_t, v_t - pred, precision=_HIGHEST)
+        return S, jnp.einsum("bhkv,bhk->bhv", S, q_t, precision=_HIGHEST)
+
+    xs = tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, beta))
+    S, o = jax.lax.scan(step, S0, xs)
+    return jnp.moveaxis(o, 0, 1), S
+
+
+def _unit_lower_solve(L: Array, rhs: Array, sub: int) -> Array:
+    """X with (I + L) X = rhs, L (..., C, C) STRICTLY lower triangular, rhs
+    (..., C, n), by blocks of `sub` rows, as matmuls: each diagonal block's
+    inverse is the finite Neumann product (I - N)(I + N^2)(I + N^4)... (N is
+    nilpotent of order `sub`), then forward substitution over the C / sub block
+    rows. Exact in exact arithmetic, float32 at `highest` here. (XLA's
+    triangular solve on these (64, 64) systems took 22 % of the v5e's step,
+    PERF.md §6 PR 26. The Neumann product over the WHOLE chunk would be one
+    step shorter and is not used: its powers grow as C(C, j) |L|^j before they
+    vanish, which float32 does not survive at C = 64 once the keys correlate.)"""
+    C = L.shape[-1]
+    ns = C // sub
+    lead = L.shape[:-2]
+    Lb = L.reshape(*lead, ns, sub, ns, sub)
+    mm = functools.partial(jnp.matmul, precision=_HIGHEST)
+    N = jnp.stack([Lb[..., a, :, a, :] for a in range(ns)], axis=-3)  # (..., ns, sub, sub) diagonal blocks
+    eye = jnp.eye(sub, dtype=L.dtype)
+    inv, power, order = eye - N, N, 2
+    while order < sub:
+        power = mm(power, power)
+        inv = mm(inv, eye + power)
+        order *= 2
+    R = rhs.reshape(*lead, ns, sub, rhs.shape[-1])
+    X = []
+    for a in range(ns):
+        r = R[..., a, :, :]
+        for b in range(a):
+            r = r - mm(Lb[..., a, :, b, :], X[b])
+        X.append(mm(inv[..., a, :, :], r))
+    return jnp.concatenate(X, axis=-2)
+
+
+def _chunk_terms(q: Array, k: Array, v: Array, g: Array, beta: Array, *, sub: int, out_dtype):
+    """The state-independent part of ONE chunk. q, k, g (..., C, d_k), v
+    (..., C, d_v), beta (..., C), all float32. Returns W_v, W_k, Q e^G,
+    tril(B), K e^{G_C - G}, e^{G_C}: the last four and W_k in `out_dtype`
+    (they only feed matmuls), W_v in float32."""
+    C, dk = k.shape[-2:]
+    ns = C // sub
+    lead = k.shape[:-2]
+    G = jnp.cumsum(g, axis=-2)  # (..., C, dk), decreasing along C
+    Gb = G.reshape(*lead, ns, sub, dk)
+    # R_a: G just before sub-block a (0 for the first)
+    R = jnp.concatenate([jnp.zeros_like(Gb[..., :1, 0, :]), Gb[..., :-1, -1, :]], axis=-2)  # (..., ns, dk)
+    rows = jnp.stack([k, q], axis=-3).reshape(*lead, 2, ns, sub, dk)  # k-rows give A, q-rows give B
+    # -- between sub-blocks: (row_r e^{G_r - R_a}) . (k_i e^{R_a - G_i}), i before block a
+    row_f = rows * jnp.exp(Gb - R[..., None, :])[..., None, :, :, :]
+    col_f = k[..., None, :, :] * jnp.exp(jnp.minimum(R[..., None, :] - G[..., None, :, :], 0.0))  # (..., ns, C, dk)
+    # in `out_dtype` with float32 accumulation, like every product against the state
+    cross = jnp.einsum(
+        "...xard,...aid->...xari", row_f.astype(out_dtype), col_f.astype(out_dtype),
+        precision=_HIGHEST, preferred_element_type=jnp.float32,
+    )  # (..., 2, ns, sub, C)
+    blk = jnp.arange(C) // sub
+    cross = jnp.where(blk[None, None, :] < jnp.arange(ns)[:, None, None], cross, 0.0)
+    # -- inside a sub-block: the (sub, sub, dk) decay tensor, directly
+    kb = k.reshape(*lead, ns, sub, dk)
+    decay = jnp.exp(jnp.minimum(Gb[..., :, None, :] - Gb[..., None, :, :], 0.0))  # (..., ns, sub, sub, dk)
+    diag = jnp.einsum("...xard,...aid,...arid->...xari", rows, kb, decay, precision=_HIGHEST)
+    eye = jnp.eye(ns, dtype=diag.dtype)
+    full = cross.reshape(*lead, 2, ns, sub, ns, sub) + diag[..., :, :, None, :] * eye[:, None, :, None]
+    full = full.reshape(*lead, 2, C, C)
+    r_i = jnp.arange(C)
+    A = jnp.where(r_i[:, None] > r_i[None, :], full[..., 0, :, :], 0.0)
+    Bm = jnp.where(r_i[:, None] >= r_i[None, :], full[..., 1, :, :], 0.0)
+    # -- [W_v | W_k] = (I + Diag(beta) A)^{-1} Diag(beta) [V | K e^G]
+    eG = jnp.exp(G)
+    rhs = beta[..., None] * jnp.concatenate([v, k * eG], axis=-1)
+    W = _unit_lower_solve(beta[..., None] * A, rhs, sub)
+    dv = v.shape[-1]
+    G_last = G[..., -1:, :]
+    return (
+        W[..., :dv],
+        W[..., dv:].astype(out_dtype),
+        (q * eG).astype(out_dtype),
+        Bm.astype(out_dtype),
+        (k * jnp.exp(G_last - G)).astype(out_dtype),
+        jnp.exp(G_last[..., 0, :]),
+    )
+
+
+def kda_chunked(q: Array, k: Array, v: Array, g: Array, beta: Array) -> tp.Tuple[Array, Array]:
+    """Chunked KDA. q, k, g (B, T, H, d_k); v (B, T, H, d_v); beta (B, T, H).
+    Returns (o (B, T, H, d_v) in v's dtype, final state (B, H, d_k, d_v) f32).
+
+    The matmuls against the state, and the decayed q-k and k-k products
+    between sub-blocks, run in q's dtype with float32 accumulation (bf16 on the
+    training path: the MXU's native product; float32 in a float32 forward); the
+    state itself, the decays, the products inside a sub-block, the triangular
+    solve and U are float32 always."""
+    chunk, sub = CHUNK, SUB
+    f32, mm = jnp.float32, q.dtype
+    B, T, H, dk = k.shape
+    dv = v.shape[-1]
+    N = -(-T // chunk)
+    pad = N * chunk - T
+
+    def to_chunks(a):  # (B, T, H, ...) -> (N, B, H, C, ...); zero rows change nothing
+        a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        a = a.reshape(B, N, chunk, *a.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(a, 2, 3), 1, 0)
+
+    xs = tuple(to_chunks(a) for a in (q, k, v, g.astype(f32), beta.astype(f32)[..., None]))
+    terms = jax.checkpoint(  # q, k, v travel in their own dtype and become float32 a batch of chunks at a time
+        lambda q_, k_, v_, g_, b_: _chunk_terms(
+            q_.astype(f32), k_.astype(f32), v_.astype(f32), g_, b_[..., 0], sub=sub, out_dtype=mm)
+    )
+    Wv, Wk, Qg, Bm, Kd, gC = jax.lax.map(
+        lambda x: terms(*x), xs, batch_size=min(CHUNKS_PER_BATCH, N)
+    )
+
+    dot = functools.partial(jnp.einsum, preferred_element_type=f32)
+
+    @jax.checkpoint
+    def step(S, x):
+        wv, wk, qg, bm, kd, gc = x
+        Sm = S.astype(mm)
+        U = wv - dot("bhck,bhkv->bhcv", wk, Sm)
+        Um = U.astype(mm)
+        o = dot("bhck,bhkv->bhcv", qg, Sm) + dot("bhci,bhiv->bhcv", bm, Um)
+        S = gc[..., None] * S + dot("bhck,bhcv->bhkv", kd, Um)
+        return S, o.astype(v.dtype)
+
+    S, o = jax.lax.scan(step, jnp.zeros((B, H, dk, dv), f32), (Wv, Wk, Qg, Bm, Kd, gC))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3).reshape(B, N * chunk, H, dv)
+    return o[:, :T], S

@@ -747,6 +747,7 @@ class ServeEngine:
         watchdog=None,  # Optional[robustness.watchdog.StepWatchdog]
     ):
         assert decode_chunk & (decode_chunk - 1) == 0, "decode_chunk: power of two"
+        config.check_serving("ServeEngine")  # a family this engine holds no cache for stops here
         # ---- tp serving mesh (docs/SERVING.md "Mesh-sharded serving") ----
         # Params shard by the megatron training rules (vocab-parallel off so
         # logits stay replicated for the host-side first-token argmax), the

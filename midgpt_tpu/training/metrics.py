@@ -25,23 +25,11 @@ except Exception:  # pragma: no cover - depends on environment
     _wandb = None
 
 
-def flops_per_token(cfg: GPTConfig, seq_len: tp.Optional[int] = None) -> float:
-    """Training FLOPs/token: 6N for the matmuls (fwd 2N + bwd 4N) plus the
-    12*L*D*T attention-scores term (PaLM appendix B accounting)."""
-    T = seq_len or cfg.block_size
-    D, L, V = cfg.n_embd, cfg.n_layer, cfg.vocab_size
-    if cfg.n_experts > 0:
-        # ACTIVE-expert accounting (the MoE convention): top_k expert MLPs
-        # + the router per token. The masked-dense lowering EXECUTES all E
-        # experts, so reported MFU under-counts by E/top_k there — honest
-        # for the useful-FLOPs metric.
-        mlp = min(cfg.moe_top_k, cfg.n_experts) * 8 * D * D + cfg.n_experts * D
-    else:
-        mlp = 8 * D * D
-    n_params = V * D + L * (4 * D * D + mlp + 2 * cfg.head_dim) + V * D
-    # Count the tied embedding once, like reference count_params (model.py:161).
-    n_params -= V * D
-    return 6.0 * n_params + 12.0 * L * D * T
+def flops_per_token(cfg, seq_len: tp.Optional[int] = None, stats: tp.Optional[dict] = None) -> float:
+    """Training FLOPs/token, by the model family's own count
+    (`flops_per_token` of its namespace, models/__init__.py). `stats`: the
+    family's counters of a logged step (`route_stats`), where it has any."""
+    return cfg.model().flops_per_token(cfg, seq_len, stats)
 
 
 # Peak dense bf16 FLOP/s per chip, keyed by a substring of `device_kind`
@@ -74,14 +62,14 @@ def device_peak_flops(device: tp.Optional[jax.Device] = None) -> float:
     )
 
 
-def mfu(tokens_per_sec: float, cfg: GPTConfig, n_devices: int) -> tp.Optional[float]:
+def mfu(tokens_per_sec: float, cfg, n_devices: int, stats: tp.Optional[dict] = None) -> tp.Optional[float]:
     """Model FLOP/s utilization of the attached accelerators; None on the
     host CPU (tests, rehearsals), which has no peak to be utilized against
     — the train loop then logs no MFU at all rather than a made-up one."""
     device = jax.devices()[0]
     if device.platform == "cpu":
         return None
-    return tokens_per_sec * flops_per_token(cfg) / (
+    return tokens_per_sec * flops_per_token(cfg, stats=stats) / (
         device_peak_flops(device) * n_devices
     )
 

@@ -5,8 +5,11 @@ adam moments (b1=0.9, b2 from config) → add params * (weight_decay /
 learning_rate) → scale by warmup-cosine schedule → negate. Dividing the decay
 by the peak LR before the schedule multiplies makes the *effective* decay
 independent of the learning rate (the small-scale-proxies recipe) while still
-following the schedule. Decay applies to ALL params, including norm scales
-and embeddings, as in the reference.
+following the schedule. Decay applies to ALL params of the GPT, including
+norm scales and embeddings, as in the reference (`GPT.weight_decay_mask` is
+None). A model family whose namespace has a `weight_decay_mask(params)`
+(models/kimi_linear.py: False on norm weights, `A_log`, `dt_bias`, the
+router's correction bias, the convolution taps) names the leaves that decay.
 """
 
 from __future__ import annotations
@@ -35,7 +38,9 @@ def make_optimizer(
     optimizer = optax.chain(
         optax.clip_by_global_norm(1.0),
         optax.scale_by_adam(b2=config.beta2),
-        optax.add_decayed_weights(config.weight_decay / config.learning_rate),
+        optax.add_decayed_weights(
+            config.weight_decay / config.learning_rate, mask=config.model_config.model().weight_decay_mask
+        ),
         optax.scale_by_schedule(schedule),
         optax.scale(-1.0),
     )

@@ -31,7 +31,7 @@ import optax
 
 from midgpt_tpu.config import ExperimentConfig
 from midgpt_tpu.data.dataset import TokenDataset
-from midgpt_tpu.models.gpt import GPT, GPTParams
+from midgpt_tpu.models.gpt import GPTParams
 from midgpt_tpu.obs import STEP_SCOPES, dump_flight_recorder, flight_recorder
 from midgpt_tpu.ops.loss import fused_linear_cross_entropy
 from midgpt_tpu.parallel.data import make_global_batch
@@ -96,7 +96,10 @@ def make_train_step(
 ) -> tp.Tuple[tp.Callable, tp.Callable, tp.Callable]:
     """Build (step, eval_loss, eval_loss_many) jitted functions."""
     model_cfg = config.model_config
-    if mesh.shape["tp"] > 1 and model_cfg.qkv_proj == "fused":
+    # The model is reached through what every family's config and namespace
+    # provide (models/__init__.py), never by name.
+    model = model_cfg.model()
+    if mesh.shape["tp"] > 1 and model_cfg.qkv_proj == "fused":  # tp: the GPT only (check_experiment)
         # The fused lowering reshapes the tp-sharded feature axis into the
         # merged 3D axis (a reshard); the batched per-third form keeps each
         # of q/k/v independently column-sharded (models/gpt.py _project_qkv).
@@ -203,10 +206,12 @@ def make_train_step(
             config.moe_aux_coef != 0.0 and model_cfg.n_experts > 0
         )
 
+        aux_kw = {"return_moe_aux": True} if use_moe_aux else {}
+
         def loss_fn(params_c: GPTParams, x: Array, y: Array, key) -> Array:
-            h = GPT.hidden(
+            h = model.hidden(
                 model_cfg, params_c, x, key=key, inference=False, attn_fn=attn_fn,
-                return_moe_aux=use_moe_aux,
+                **aux_kw,
             )
             if use_moe_aux:
                 h, aux = h
@@ -217,10 +222,7 @@ def make_train_step(
             return ce + config.moe_aux_coef * aux if use_moe_aux else ce
 
     def cast_compute(params: GPTParams) -> GPTParams:
-        return jax.tree.map(
-            lambda p: p.astype(compute_dtype) if jnp.issubdtype(p.dtype, jnp.floating) else p,
-            params,
-        )
+        return model.cast_params(params, compute_dtype)
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def step(params: GPTParams, opt_state, x_GBT: Array, y_GBT: Array, key,
@@ -297,7 +299,7 @@ def make_train_step(
             # through the same GPipe schedule instead (dropout-free, so the
             # train-mode loss IS the eval loss).
             return loss_fn(params_c, x, y, None)
-        h = GPT.hidden(model_cfg, params_c, x, inference=True, attn_fn=attn_fn)
+        h = model.hidden(model_cfg, params_c, x, inference=True, attn_fn=attn_fn)
         return fused_linear_cross_entropy(
             h, params_c.lm_head, y, config.loss_chunk_tokens,
             config.loss_remat_chunks,
@@ -333,37 +335,21 @@ def init_state(config: ExperimentConfig, mesh) -> tp.Tuple[GPTParams, tp.Any, tp
 
     Returns (params, opt_state, param_specs, optimizer)."""
     optimizer, _ = make_optimizer(config)
+    model = config.model_config.model()
     abstract_params = jax.eval_shape(
-        lambda k: GPT.init(config.model_config, k), jax.random.PRNGKey(0)
+        lambda k: model.init(config.model_config, k), jax.random.PRNGKey(0)
     )
-    # Spec rule: GPipe layer-axis sharding when the mesh has a real 'pp'
-    # axis (parallel/pipeline.py), else Megatron tp x fsdp (parallel/tp.py)
-    # — which with mesh tp=1 reduces to the plain FSDP rule exactly (pinned
-    # by test_tp.py).
-    if mesh.shape["pp"] > 1:
-        # Same (tree, mesh, shard_model, min_size) signature as the tp rule:
-        # layer axis over 'pp', large leaves additionally over 'fsdp'.
-        from midgpt_tpu.parallel.pipeline import pipeline_param_specs as spec_rule
-
-    else:
-        from midgpt_tpu.parallel.tp import tp_param_specs
-
-        spec_rule = functools.partial(tp_param_specs, vocab_parallel=config.tp_vocab)
-    param_specs = spec_rule(
-        abstract_params, mesh, config.shard_model, config.fsdp_min_size
-    )
+    param_specs = model.param_specs(config, abstract_params, mesh)
 
     def init_fn(key):
-        params = GPT.init(config.model_config, key)
+        params = model.init(config.model_config, key)
         params = jax.tree.map(lambda p: p.astype(jnp.dtype(config.param_dtype)), params)
         return constrain(params, param_specs, mesh)
 
     params = jax.jit(init_fn)(jax.random.PRNGKey(config.seed))
 
     abstract_opt = jax.eval_shape(optimizer.init, abstract_params)
-    opt_specs = spec_rule(
-        abstract_opt, mesh, config.shard_model, config.fsdp_min_size
-    )
+    opt_specs = model.param_specs(config, abstract_opt, mesh)  # the same rule over the optimizer's tree
     opt_state = jax.jit(
         optimizer.init, out_shardings=named_shardings(opt_specs, mesh)
     )(params)
@@ -487,6 +473,11 @@ class TrainRuntime:
     # shardings; the key and the loss carrier concrete): what
     # `step_program_text` lowers with.
     step_avals: tp.Tuple = ()
+    # Jitted (params, x (B, T)) -> {counter name: scalar} of the model's own
+    # counters (models/kimi_linear.py route_stats: moe.*), or None for a model
+    # that has none. Forward only, one microbatch, off the step program.
+    model_stats: tp.Optional[tp.Callable] = None
+    _step_text: tp.Optional[str] = None
 
     def step_program_text(self) -> str:
         """Optimized HLO of the compiled step program, with each
@@ -497,8 +488,11 @@ class TrainRuntime:
         what maps a traced op to its phase
         (benchmarks/metrics/step_phases.py). Same avals as the loop's call,
         so the compile is the persistent cache's entry of the running
-        program (a load, not a compile)."""
-        return self.step.lower(*self.step_avals).compile().as_text()
+        program (a load, not a compile); kept, since more than one reader
+        asks (step_phases.py, hybrid_step_phases.py)."""
+        if self._step_text is None:
+            self._step_text = self.step.lower(*self.step_avals).compile().as_text()
+        return self._step_text
 
     def take_initial(self, config: ExperimentConfig) -> tp.Tuple[tp.Any, tp.Any]:
         """Hand out the freshly initialized state (once); re-init if a later
@@ -562,6 +556,15 @@ def make_runtime(
     )
     global _LAST_RUNTIME
     abstract_params, abstract_opt = _abstract_like(params), _abstract_like(opt_state)
+    model = config.model_config.model()
+    model_stats = None
+    if model.route_stats is not None:
+        # forward-only counters of ONE microbatch (B, T), for the loop's logged
+        # steps: the step program's own outputs stay (params, opt_state, loss)
+        compute_dtype = jnp.dtype(config.compute_dtype)
+        model_stats = jax.jit(
+            lambda p, x: model.route_stats(config.model_config, model.cast_params(p, compute_dtype), x)
+        )
     batch = jax.ShapeDtypeStruct(
         (config.g_accum_iters, config.batch_size, config.model_config.block_size),
         jnp.int32,
@@ -584,7 +587,8 @@ def make_runtime(
         eval_loss_many=eval_loss_many,
         abstract_state={"params": abstract_params, "opt_state": abstract_opt},
         finite_check=jax.jit(_all_finite),
-        n_params=GPT.count_params(params),
+        n_params=model.count_params(params),
+        model_stats=model_stats,
         _initial=(params, opt_state),
         step_avals=(
             abstract_params, abstract_opt, batch, batch,
@@ -836,7 +840,16 @@ def train(
                             "throughput/tokens_per_sec": tok_s,
                         }
                     )
-                    m = mfu(tok_s, config.model_config, jax.device_count())
+                    stats = None
+                    if rt.model_stats is not None:
+                        # the model's own counters of this step's first
+                        # microbatch: into the log line and the flight
+                        # recorder's gauges (docs/OBSERVABILITY.md)
+                        stats = {k: float(v) for k, v in rt.model_stats(params, xg[0]).items()}
+                        metrics.update(stats)
+                        for k, v in stats.items():
+                            flight_recorder().metrics.gauge(k).set(v)
+                    m = mfu(tok_s, config.model_config, jax.device_count(), stats)
                     if m is not None:
                         metrics["throughput/mfu"] = m
                     logger.log(itr, dict(metrics))

@@ -94,6 +94,10 @@ def main() -> None:
         with open(config_path, "r") as f:
             config = from_json(f.read())
     model_cfg = config.model_config
+    try:
+        model_cfg.check_serving("sample.py")
+    except NotImplementedError as e:  # a model family the serving stack does not hold yet
+        raise SystemExit(str(e))
     print(config)
 
     # Restore just the "params" item, sharded over an inference mesh (all
