@@ -365,7 +365,7 @@ class PagedKVCache:
     **Layout contract (TPU kernel path).** On the kernel path the pool
     has ONE DEVICE LAYOUT from a serving program's parameter to its
     result: row-major over (L, H, P, ps, C), the layout in which the
-    paged-attention template reads one page per block. Three rules keep it
+    paged-attention template copies a page at a time. Three rules keep it
     so, and a program that breaks one pays a whole-pool relayout per call
     (analysis/hlo_audit.pool_relayouts counts them; PERF.md, PR 25):
 
@@ -1412,7 +1412,7 @@ class GPT:
             # mode), in place in the decode loop carry, not a pool copy
             # (pinned) — scale side buffers included (_paged_write). The
             # attention then reads layer i of the WHOLE pool: the kernel
-            # through its page blocks, the gather through a fused slice.
+            # through its own page copies, the gather through a fused slice.
             ck_all, cv_all, cks_all, cvs_all = _paged_write(
                 (ck_all, cv_all, cks_all, cvs_all), i, write_pages, offs,
                 k1, v1, attn_impl, mesh,

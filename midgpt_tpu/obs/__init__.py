@@ -128,6 +128,19 @@ class Observability:
         self._h_round_slots = h(
             "round_decode_slots", "slots holding a decoding request after the round"
         )
+        # what the paged attention kernel's grid did with the round's tables
+        self._c_blocks_swept = self.metrics.counter(
+            "decode.blocks_swept", "grid steps (compute blocks) of one "
+            "layer's paged-attention call, summed over decode steps"
+        )
+        self._c_blocks_live = self.metrics.counter(
+            "decode.blocks_live", "of those, blocks holding a visible key "
+            "(copied and computed; the rest cost a bare grid step)"
+        )
+        self._g_live_share = self.metrics.gauge(
+            "decode.live_block_share", "blocks_live / blocks_swept of the "
+            "last decode round"
+        )
         _LIVE.append(self)
 
     # -- round timing ---------------------------------------------------
@@ -164,6 +177,13 @@ class Observability:
         self._h_round.observe(dur_s)
         self._h_round_chunks.observe(prefill_chunks)
         self._h_round_slots.observe(decode_slots)
+
+    def record_decode_blocks(self, swept: int, live: int) -> None:
+        """One decode round's paged-attention grid: blocks swept and blocks
+        live, per layer call (sampling/serve.py `_count_blocks`)."""
+        self._c_blocks_swept.inc(swept)
+        self._c_blocks_live.inc(live)
+        self._g_live_share.set(live / swept if swept else 0.0)
 
     def round_decomp(self) -> tp.Dict[str, tp.Any]:
         """p50/p95/mean per phase, milliseconds (stats() schema)."""

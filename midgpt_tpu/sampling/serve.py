@@ -132,6 +132,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from midgpt_tpu.kernels.attention_template import (
+    block_census,
+    block_pages,
+    normalize_split_k,
+)
 from midgpt_tpu.kernels.decode_attention import resolve_paged_impl
 from midgpt_tpu.models.gpt import GPT, GPTConfig, GPTParams, PagedKVCache
 from midgpt_tpu.obs import DISABLED_SNAPSHOT, Observability
@@ -1801,6 +1806,11 @@ class ServeEngine:
             self._key, key = jax.random.split(self._key)
         round_span = int(worst.max())
         bucket = self._page_bucket(round_span)
+        # lengths as the host holds them: a chained slot's trail the
+        # device's by the previous group's steps (the count runs low there)
+        self._count_blocks(
+            lengths, active, bucket, self._split_bucket(round_span), n_steps=T
+        )
         # Chain carry-in: the previous group's unforced outputs when
         # chaining, else zero fillers of the same shape/dtype — ONE
         # compiled program serves both cases, and nothing here syncs.
@@ -2199,6 +2209,41 @@ class ServeEngine:
             slot.pages[j] = -1
         self.window_reclaimed_pages += len(dead)
 
+    def _count_blocks(
+        self,
+        lengths: np.ndarray,  # (B,) tokens cached per slot at dispatch
+        active: np.ndarray,  # (B,) bool
+        bucket: int,
+        split_k: int,
+        n_steps: int = 1,
+        n_rows: int = 1,
+    ) -> None:
+        """Record what the paged-attention kernel's grid does with this
+        round: compute blocks swept and blocks live, per layer call, over
+        the round's `n_steps` decode steps (step t's row r sees
+        lengths + t + r + 1 keys; an inactive slot's rows see one,
+        GPT.decode_step_paged). The block width is the kernel's own
+        (`block_pages`, from the same shapes); the live rule is its
+        `block_live` (`block_census`). Integers the round already holds:
+        no device work. Kernel path only: the gather lowering has no blocks."""
+        if self.obs is None or self.attn_impl != "kernel":
+            return
+        _, n_kv, _, ps, lanes = self.cache.k.shape
+        n_tp = 1 if self.mesh is None else int(self.mesh.shape["tp"])
+        n = block_pages(
+            n_kv // n_tp, lanes, self.cache.k.dtype.itemsize, ps,
+            bucket // normalize_split_k(split_k, bucket),
+            n_rows * (self.config.n_head // n_kv),
+        )
+        steps = np.arange(1, n_steps + 1)[:, None]  # (n_steps, B) below
+        first = np.maximum(active * (lengths + steps), 1).ravel()
+        self.obs.record_decode_blocks(
+            *block_census(
+                first, first + n_rows - 1, bucket, n, ps,
+                self.config.sliding_window, self.config.attn_sinks,
+            )
+        )
+
     def _page_bucket(self, max_tokens: int) -> int:
         """Smallest power-of-two page count covering `max_tokens` positions.
 
@@ -2386,6 +2431,9 @@ class ServeEngine:
             self._key, key = jax.random.split(self._key)
         round_span = max(self.slots[i].length for i in active_idx) + n
         bucket = self._page_bucket(round_span)
+        self._count_blocks(
+            lengths, active, bucket, self._split_bucket(round_span), n_steps=n
+        )
         self.cache, toks = _serve_decode_chunk(
             self.config,
             self.params,
@@ -2492,6 +2540,8 @@ class ServeEngine:
         round_span = max(self.slots[i].length for i in active_idx) + k + 1
         bucket = self._page_bucket(round_span)
         split_k = self._split_bucket(round_span)
+        # the target's verify call (the draft's k steps run another model)
+        self._count_blocks(lengths, active, bucket, split_k, n_rows=k + 1)
         table = jnp.asarray(self._page_table(bucket))
         token_j = jnp.asarray(token)
         lengths_j = jnp.asarray(lengths)

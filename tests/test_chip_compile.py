@@ -87,13 +87,15 @@ def test_flash_fwd_bwd_compiles_for_v5e(shape, one_chip, compiled_kernels):
     assert _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) >= 2
 
 
-# 124M serving geometry: 12 heads x 64, 8-token pages, a 1024-token table.
-H, C, PS, MAX_PAGES, N_PAGES, B = 12, 64, 8, 128, 257, 4
+# 124M serving geometry: 12 heads x 64 in a pool of whole 128-lane rows (as
+# the engine allocates it on the kernel path: PagedKVCache "Layout
+# contract"), 8-token pages, a 1024-token table.
+H, C, LANES, PS, MAX_PAGES, N_PAGES, B = 12, 64, 128, 8, 128, 257, 4
 
 
 def _paged_args(dev, n_rows, quantized, h_q=H, h_kv=H):
     sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=dev)
-    pool = sds((h_kv, N_PAGES, PS, C), jnp.int8 if quantized else jnp.bfloat16)
+    pool = sds((h_kv, N_PAGES, PS, LANES), jnp.int8 if quantized else jnp.bfloat16)
     args = [
         sds((B, h_q, n_rows, C), jnp.bfloat16),
         pool,
@@ -127,6 +129,70 @@ def test_paged_template_window_sinks_gqa_compiles_for_v5e(one_chip, compiled_ker
     fn = lambda *a: at.paged_attention_template(
         *a, sliding_window=256, attn_sinks=4
     )
+    assert _mosaic_calls(fn, *args) == 1
+
+
+# Both benchmark cells' kernel-path pools (PagedKVCache "Layout contract":
+# 128 lanes a row) at their decode geometry: (slots, H_kv, head_dim, widest
+# table in pages, split_k at that table). serve_124m_sample's contexts reach
+# 160 tokens (tables of 1-32 pages); serve_xl_chat's reach 880, and the
+# 1,024-token bucket splits in two (ServeEngine._split_bucket).
+BENCH_SHAPES = {"124m": (48, 12, 64, 32, 1), "xl": (16, 16, 128, 128, 2)}
+# variant: (query rows, int8 pool, query heads per pool head, template kwargs)
+BENCH_VARIANTS = {
+    "decode": (1, False, 1, {}),
+    "verify5": (5, False, 1, {}),
+    "int8": (1, True, 1, {}),
+    "int8_verify5": (5, True, 1, {}),
+    "split4": (1, False, 1, dict(split_k=4)),
+    "gqa4": (1, False, 4, {}),
+    "window_sinks": (1, False, 1, dict(sliding_window=256, attn_sinks=4)),
+}
+
+
+def _bench_args(dev, shape, table, n_rows, quantized, groups):
+    slots, h, c, _, _ = BENCH_SHAPES[shape]
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=dev)
+    n_pages, layers = slots * table + 1, 2
+    pool = sds((layers, h, n_pages, PS, LANES), jnp.int8 if quantized else jnp.bfloat16)
+    scale = sds((layers, n_pages, h, PS), jnp.float32) if quantized else None
+    return [
+        sds((slots, h * groups, n_rows, c), jnp.bfloat16), pool, pool,
+        sds((slots, table), jnp.int32), sds((slots, n_rows), jnp.int32),
+        scale, scale,
+    ]
+
+
+@pytest.mark.parametrize("variant", list(BENCH_VARIANTS))
+@pytest.mark.parametrize("shape", list(BENCH_SHAPES))
+def test_paged_template_variants_compile_at_benchmark_shapes(shape, variant, one_chip, compiled_kernels):
+    """Every spec of the blocked template at both cells' widest table, with
+    the block width the call derives (the page copies' slices, the double
+    buffers' VMEM and the scalar loops are what Mosaic can refuse)."""
+    n_rows, quantized, groups, kw = BENCH_VARIANTS[variant]
+    _, _, _, table, split = BENCH_SHAPES[shape]
+    kw = {"split_k": split, **kw}
+    args = _bench_args(one_chip, shape, table, n_rows, quantized, groups)
+    fn = lambda *a: at.paged_attention_template(*a, layer=jnp.int32(1), **kw)
+    assert _mosaic_calls(fn, *args) == 1
+
+
+def test_paged_template_compiles_for_a_pool_off_the_layout_contract(one_chip, compiled_kernels):
+    """A pool of 64-channel rows (no `kernel_layout`: what the benchmark's
+    correctness check allocates) still compiles: the wrapper pads it."""
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    pool = sds((12, H, 7, PS, C), jnp.bfloat16)
+    args = [sds((1, H, 1, C), jnp.bfloat16), pool, pool, sds((1, 6), jnp.int32), sds((1, 1), jnp.int32)]
+    fn = lambda *a: at.paged_attention_template(*a, layer=jnp.int32(3))
+    assert _mosaic_calls(fn, *args) == 1
+
+
+@pytest.mark.parametrize("table", [1, 2, 4, 8, 16])
+def test_paged_template_compiles_at_every_narrow_table(table, one_chip, compiled_kernels):
+    """serve_124m_sample's smaller page buckets: a table narrower than the
+    derived block is one block of its own width."""
+    args = _bench_args(one_chip, "124m", table, 1, False, 1)
+    fn = lambda *a: at.paged_attention_template(*a, layer=jnp.int32(0))
     assert _mosaic_calls(fn, *args) == 1
 
 

@@ -504,3 +504,38 @@ def test_rehearsal_lists_the_new_metrics(cell, names, tmp_path):
     last = json.loads(proc.stdout.strip().splitlines()[-1])
     assert last["rehearsal"] and last["correct"]
     assert names <= set(last["would_report"]), sorted(names - set(last["would_report"]))
+
+
+def test_decode_round_records_the_kernels_blocks(params, monkeypatch):
+    """`decode.blocks_swept` / `decode.blocks_live` / `decode.live_block_share`
+    on a hand-built round: the engine's host arithmetic with the kernel's own
+    block width and live rule (kernels/attention_template.py), recorded only
+    where the kernel runs."""
+    import midgpt_tpu.sampling.serve as serve_mod
+    from midgpt_tpu.kernels.attention_template import block_pages
+
+    obs = Observability()
+    eng = _engine(params, 33, StepClock(), obs)
+    lengths, active = np.asarray([40, 3]), np.asarray([True, False])
+    eng._count_blocks(lengths, active, 8, 1, n_steps=4)  # CPU: the gather lowering
+    assert obs.snapshot()["counters"]["decode.blocks_swept"] == 0
+
+    eng.attn_impl = "kernel"  # what a TPU engine resolves to
+    _, n_kv, _, ps, lanes = eng.cache.k.shape
+    assert block_pages(n_kv, lanes, 4, ps, 8, 1) == 8  # an 8-page table: one block
+    eng._count_blocks(lengths, active, 8, 1, n_steps=4)
+    snap = obs.snapshot()
+    # 2 slots x 1 block x 4 steps; the inactive slot's one visible key keeps its block live
+    assert snap["counters"]["decode.blocks_swept"] == 8
+    assert snap["counters"]["decode.blocks_live"] == 8
+    assert snap["gauges"]["decode.live_block_share"] == 1.0
+
+    # the same round cut into 2-page blocks, as a wider pool row would cut it
+    monkeypatch.setattr(serve_mod, "block_pages", lambda *a: 2)
+    eng._count_blocks(lengths, active, 8, 1, n_steps=1, n_rows=3)
+    snap = obs.snapshot()
+    # 4 blocks a slot; rows see 41..43 keys -> blocks 0-2 live; inactive: 1..3 -> block 0
+    assert snap["counters"]["decode.blocks_swept"] == 8 + 8
+    assert snap["counters"]["decode.blocks_live"] == 8 + 3 + 1
+    assert snap["gauges"]["decode.live_block_share"] == 0.5
+    assert "decode_blocks_live 12" in obs.metrics.to_prometheus()  # the .prom beside a dump
