@@ -24,9 +24,8 @@ Three passes (docs/ANALYSIS.md is the rule catalog):
     flowing into trailing static jit args (GC011). Same suppression
     machinery as pass 1.
 
-`analysis.bench_contract` is the shared checker for the one-JSON-line
-driver contract that bench.py / tools/bench_serve.py (and the graftcheck
-CLI's own --json mode) must honor.
+`analysis.bench_contract` is the checker for the one-JSON-line convention
+of the two summary CLIs (this package's --json mode, tools/chaos_run.py).
 
 CLI: `python -m midgpt_tpu.analysis [paths...] [--json] [--audit]
 [--fail-on-new] [--update-baseline]` (tools/graftcheck.py is a path-setup

@@ -2,9 +2,9 @@
 
 Exit status: 0 when no active findings (and, with --audit, every audit
 passes); 1 otherwise. Default output is one `path:line:col: GCnnn message`
-line per finding; --json emits ONE JSON line (the bench.py driver
-convention — schema in analysis/bench_contract.py) so automated drivers
-can consume findings without scraping.
+line per finding; --json emits ONE JSON line (schema in
+analysis/bench_contract.py) so automated drivers can consume findings
+without scraping.
 
 Pass 1 (the lint), pass 3 (the lifecycle/dataflow pass) and pass 4 (the
 concurrency/boundary pass) perform no JAX backend initialization; --audit

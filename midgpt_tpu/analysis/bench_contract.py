@@ -1,12 +1,13 @@
-"""Shared checker for the one-JSON-line driver contract.
+"""Checker for the one-JSON-line convention of the repo's two summary CLIs.
 
-bench.py and tools/bench_serve.py each print exactly ONE line of JSON to
-stdout and the driver consumes it blind — a stray print, a NaN (json.dumps
-emits bare `NaN`, which is not JSON), or a silently renamed field breaks
-the pipeline with no test noticing. This module is the single place the
-contract is written down; tests/test_bench_contract.py runs the real bench
-entry points and validates their stdout through it, and the graftcheck CLI
-validates its own --json output the same way.
+`python -m midgpt_tpu.analysis --json` (tools/graftcheck.py) and
+tools/chaos_run.py each print exactly ONE line of JSON to stdout for a
+caller that consumes it blind — a stray print, a NaN (json.dumps emits bare
+`NaN`, which is not JSON), or a silently renamed field breaks that caller
+with no test noticing. This module is the single place the convention is
+written down; tests/test_graftcheck.py and tests/test_chaos_serve.py run
+the real entry points and validate their stdout through it. Rates and
+latencies are not its business: those come from benchmarks/run.py.
 
 Checkers return a list of problem strings (empty = conformant) rather than
 raising, so callers can aggregate.
@@ -55,827 +56,6 @@ def _require(
                 f"field {key!r} has type {type(rec[key]).__name__}, expected "
                 + "/".join(t.__name__ for t in types)
             )
-
-
-def check_train_bench(rec: dict) -> tp.List[str]:
-    """bench.py profile: {metric, value, unit, vs_baseline, detail}."""
-    problems: tp.List[str] = []
-    _require(
-        rec,
-        {"metric": (str,), "value": Number, "unit": (str,), "detail": (dict,)},
-        problems,
-    )
-    if "vs_baseline" not in rec:
-        problems.append("missing required field 'vs_baseline'")
-    elif rec["vs_baseline"] is not None and not isinstance(rec["vs_baseline"], Number):
-        problems.append("field 'vs_baseline' must be a number or null")
-    if isinstance(rec.get("detail"), dict):
-        _require(
-            rec["detail"],
-            {"tokens_per_sec": Number, "step_ms": Number, "n_devices": (int,)},
-            problems,
-        )
-    return problems
-
-
-def _require_round_decomp(rec: dict, problems: tp.List[str]) -> None:
-    """round_host_ms / round_device_ms / overlap_hidden_ms: the decode-round
-    split the flight recorder measures (docs/OBSERVABILITY.md). Each is
-    {p50, p95} in ms, finite (NaN already rejected at parse) and
-    non-negative. Round-overlap dispatch (docs/SERVING.md) rides the same
-    records: `overlap_mode` names the dispatch mode, `round_group` the
-    fused rounds per dispatch (1 unless mode is 'group'), and
-    `overlap_hidden_ms` the host time hidden under in-flight dispatches —
-    an honest zero when overlap is off, which is why the fields are
-    required rather than optional: their absence is a silent A/B lie."""
-    for key in ("round_host_ms", "round_device_ms", "overlap_hidden_ms"):
-        d = rec.get(key)
-        if not isinstance(d, dict):
-            problems.append(f"field {key!r} must be an object with p50/p95")
-            continue
-        for q in ("p50", "p95"):
-            v = d.get(q)
-            if not isinstance(v, Number) or isinstance(v, bool):
-                problems.append(f"field {key!r}.{q} must be a number")
-            elif v < 0:
-                problems.append(f"{key}.{q} {v} < 0")
-    mode = rec.get("overlap_mode")
-    if mode not in ("off", "double", "group"):
-        problems.append(
-            f"field 'overlap_mode' is {mode!r}, expected off/double/group"
-        )
-    rg = rec.get("round_group")
-    if not isinstance(rg, int) or isinstance(rg, bool) or rg < 1:
-        problems.append(f"field 'round_group' must be an int >= 1, got {rg!r}")
-    elif mode != "group" and rg != 1:
-        problems.append(f"round_group {rg} with overlap_mode {mode!r} — "
-                        "groups only exist in 'group' mode")
-
-
-def check_serve_bench(rec: dict) -> tp.List[str]:
-    """tools/bench_serve.py profile (field table: docs/SERVING.md)."""
-    problems: tp.List[str] = []
-    _require(
-        rec,
-        {
-            "bench": (str,),
-            "backend": (str,),
-            "n_requests": (int,),
-            "total_new_tokens": (int,),
-            "continuous_tok_s": Number,
-            "sequential_tok_s": Number,
-            "speedup": Number,
-            "p50_token_ms": Number,
-            "p99_token_ms": Number,
-            "ttft_ms_mean": Number,
-            "ttft_ms_p50": Number,
-            "ttft_ms_p95": Number,
-            "req_tok_s_p50": Number,
-            "req_tok_s_p95": Number,
-            "decode_rounds": (int,),
-            "kv_dtype": (str,),
-            "num_pages": (int,),
-            "preemptions": (int,),
-            "cache_hbm_bytes": (int,),
-            "hbm_paged_cache_bytes": (int,),
-            "hbm_sequential_cache_bytes": (int,),
-            "model": (dict,),
-            "compile_counts": (dict,),
-        },
-        problems,
-    )
-    if rec.get("bench") != "serve":
-        problems.append(f"field 'bench' is {rec.get('bench')!r}, expected 'serve'")
-    _require_round_decomp(rec, problems)
-    if rec.get("kv_dtype") not in (None, "bf16", "int8"):
-        problems.append(f"field 'kv_dtype' is {rec.get('kv_dtype')!r}")
-    if "device_peak_bytes_in_use" not in rec:
-        problems.append("missing required field 'device_peak_bytes_in_use'")
-    elif rec["device_peak_bytes_in_use"] is not None and not isinstance(
-        rec["device_peak_bytes_in_use"], int
-    ):
-        problems.append("field 'device_peak_bytes_in_use' must be int or null")
-    # int8 runs carry the bf16-comparison block; when present it must be
-    # coherent (the driver keys the capacity claim off these numbers)
-    gmf = rec.get("greedy_match_frac")
-    if gmf is not None and (not isinstance(gmf, Number) or not 0.0 <= gmf <= 1.0):
-        problems.append(f"greedy_match_frac {gmf!r} outside [0, 1]")
-    if rec.get("kv_dtype") == "int8" and "greedy_match_frac" not in rec:
-        problems.append("int8 serve record missing 'greedy_match_frac'")
-    return problems
-
-
-def check_serve_spec_bench(rec: dict) -> tp.List[str]:
-    """tools/bench_serve.py --spec profile: speculative vs plain continuous
-    engine on the same trace (field table: docs/SERVING.md)."""
-    problems: tp.List[str] = []
-    _require(
-        rec,
-        {
-            "bench": (str,),
-            "backend": (str,),
-            "n_requests": (int,),
-            "total_new_tokens": (int,),
-            "model": (dict,),
-            "draft_layers": (int,),
-            "spec_k_max": (int,),
-            "train_steps": (int,),
-            "baseline_tok_s": Number,
-            "spec_tok_s": Number,
-            "speedup_spec": Number,
-            "accept_rate": Number,
-            "tokens_per_verify": Number,
-            "kv_dtype": (str,),
-            "cache_hbm_bytes": (int,),
-            "hbm_target_cache_bytes": (int,),
-            "hbm_draft_cache_bytes": (int,),
-            "compile_counts": (dict,),
-        },
-        problems,
-    )
-    if rec.get("bench") != "serve_spec":
-        problems.append(
-            f"field 'bench' is {rec.get('bench')!r}, expected 'serve_spec'"
-        )
-    ar = rec.get("accept_rate")
-    if isinstance(ar, Number) and not 0.0 <= ar <= 1.0:
-        problems.append(f"accept_rate {ar} outside [0, 1]")
-    tpv = rec.get("tokens_per_verify")
-    if isinstance(tpv, Number) and tpv < 1.0 and rec.get("n_requests", 0) > 0:
-        # every verify yields at least its correction/bonus token
-        problems.append(f"tokens_per_verify {tpv} < 1 — counter drift?")
-    return problems
-
-
-def check_serve_prefix_bench(rec: dict) -> tp.List[str]:
-    """tools/bench_serve.py --shared-prefix-frac profile: the template
-    workload run cache-off then cache-on at the same page budget (field
-    table: docs/SERVING.md 'Prefix cache'). The load-bearing invariant is
-    greedy_match_frac == 1.0 EXACTLY — prefix sharing is page-table
-    indirection over bit-identical K/V, so any mismatch at all means a
-    torn page, not noise — which makes it a schema check, not a quality
-    threshold."""
-    problems: tp.List[str] = []
-    _require(
-        rec,
-        {
-            "bench": (str,),
-            "backend": (str,),
-            "n_requests": (int,),
-            "total_new_tokens": (int,),
-            "shared_prefix_frac": Number,
-            "n_templates": (int,),
-            "template_tokens": (int,),
-            "kv_dtype": (str,),
-            "num_pages": (int,),
-            "model": (dict,),
-            "baseline_tok_s": Number,
-            "prefix_tok_s": Number,
-            "speedup_prefix": Number,
-            "baseline_ttft_ms_p50": Number,
-            "baseline_ttft_ms_p95": Number,
-            "prefix_ttft_ms_p50": Number,
-            "prefix_ttft_ms_p95": Number,
-            "prefix_hit_rate": Number,
-            "cow_pages": (int,),
-            "baseline_prefill_tokens": (int,),
-            "prefix_prefill_tokens": (int,),
-            "baseline_preemptions": (int,),
-            "prefix_preemptions": (int,),
-            "trie_pages": (int,),
-            "reclaimed_pages": (int,),
-            "greedy_match_frac": Number,
-            "cache_hbm_bytes": (int,),
-            "compile_counts": (dict,),
-        },
-        problems,
-    )
-    if rec.get("bench") != "serve_prefix":
-        problems.append(
-            f"field 'bench' is {rec.get('bench')!r}, expected 'serve_prefix'"
-        )
-    hr = rec.get("prefix_hit_rate")
-    if isinstance(hr, Number) and not 0.0 <= hr <= 1.0:
-        problems.append(f"prefix_hit_rate {hr} outside [0, 1]")
-    gmf = rec.get("greedy_match_frac")
-    if isinstance(gmf, Number) and gmf != 1.0:
-        problems.append(
-            f"greedy_match_frac {gmf} != 1.0 — prefix sharing must be "
-            "bit-invisible to greedy streams"
-        )
-    pf = rec.get("prefix_prefill_tokens")
-    bf = rec.get("baseline_prefill_tokens")
-    if isinstance(pf, int) and isinstance(bf, int) and pf > bf:
-        problems.append(
-            f"prefix run prefilled MORE tokens than baseline ({pf} > {bf})"
-        )
-    return problems
-
-
-def check_serve_tp_bench(rec: dict) -> tp.List[str]:
-    """tools/bench_serve.py --tp profile: the same greedy trace through a
-    single-chip engine and a tensor-parallel mesh-sharded engine, per cache
-    mode (base dtype / int8 / self-draft speculation). The load-bearing
-    invariant is match_* == 1.0 EXACTLY for every mode — tp sharding splits
-    head-aligned einsums whose all-reduce restores the same f32 partials a
-    single chip computes, so any token divergence means a wrong sharding
-    spec or a torn collective, not noise (tests/test_tp_serving.py pins the
-    same matrix). Per-shard HBM arithmetic is checked exactly: the pool is
-    sharded on the head axis, so each shard holds total/tp bytes."""
-    problems: tp.List[str] = []
-    _require(
-        rec,
-        {
-            "bench": (str,),
-            "backend": (str,),
-            "n_requests": (int,),
-            "total_new_tokens": (int,),
-            "max_slots": (int,),
-            "page_size": (int,),
-            "tp": (int,),
-            "n_devices": (int,),
-            "mesh": (dict,),
-            "base_dtype": (str,),
-            "model": (dict,),
-            "train_steps": (int,),
-            "train_loss": Number,
-            "draft_layers": (int,),
-            "spec_k_max": (int,),
-            "match_f32": Number,
-            "match_int8": Number,
-            "match_spec": Number,
-            "single_tok_s_f32": Number,
-            "single_tok_s_int8": Number,
-            "single_tok_s_spec": Number,
-            "tp_tok_s_f32": Number,
-            "tp_tok_s_int8": Number,
-            "tp_tok_s_spec": Number,
-            "num_pages": (int,),
-            "int8_num_pages": (int,),
-            "cache_hbm_bytes": (int,),
-            "cache_hbm_bytes_per_shard": (int,),
-            "hbm_per_slot_per_shard_bytes": (int,),
-            "int8_cache_hbm_bytes_per_shard": (int,),
-            "compile_counts": (dict,),
-        },
-        problems,
-    )
-    if rec.get("bench") != "serve_tp":
-        problems.append(
-            f"field 'bench' is {rec.get('bench')!r}, expected 'serve_tp'"
-        )
-    ntp = rec.get("tp")
-    if isinstance(ntp, int) and ntp < 2:
-        problems.append(f"tp {ntp} < 2 — the tp profile requires a sharded mesh")
-    mesh = rec.get("mesh")
-    if isinstance(mesh, dict) and isinstance(ntp, int) and mesh.get("tp") != ntp:
-        problems.append(f"mesh {mesh} does not carry tp={ntp}")
-    for mode in ("f32", "int8", "spec"):
-        m = rec.get(f"match_{mode}")
-        if isinstance(m, Number) and m != 1.0:
-            problems.append(
-                f"match_{mode} {m} != 1.0 — tp sharding must be bit-invisible "
-                "to greedy streams"
-            )
-    total = rec.get("cache_hbm_bytes")
-    shard = rec.get("cache_hbm_bytes_per_shard")
-    slot = rec.get("hbm_per_slot_per_shard_bytes")
-    slots = rec.get("max_slots")
-    if isinstance(total, int) and isinstance(shard, int) and isinstance(ntp, int):
-        if shard * ntp != total:
-            problems.append(
-                f"per-shard bytes {shard} * tp {ntp} != pool bytes {total}"
-            )
-    if isinstance(shard, int) and isinstance(slot, int) and isinstance(slots, int):
-        if slots > 0 and slot != shard // slots:
-            problems.append(
-                f"hbm_per_slot_per_shard_bytes {slot} != "
-                f"{shard} // max_slots {slots}"
-            )
-    return problems
-
-
-def check_serve_longctx_bench(rec: dict) -> tp.List[str]:
-    """tools/bench_serve.py --long-ctx profile: split-K decode A/B (field
-    table: docs/SERVING.md 'Split-K decode'). Two load-bearing invariants:
-
-      * greedy_match_frac == 1.0 EXACTLY — split-K reorders f32 softmax
-        reductions, so the bench pins that on a fitted model the argmax
-        margins absorb the reorder (tests/test_split_k.py pins the same
-        matrix per cache mode); any mismatch is a kernel bug or a model
-        with no margins, either of which invalidates the record.
-      * split_k_short == 1 — the no-regression-at-short-T guarantee is
-        structural: the auto bucket rule must keep short traffic on the
-        byte-identical unsplit program. The forced-split short latency
-        (short_ratio) is recorded as diagnostic context, not gated — on
-        tiny CPU-mesh rounds it is dominated by per-dispatch overhead.
-
-    split_k_long >= 2 and t_long >= 1024 keep the record an actual A/B:
-    an unsplit-vs-unsplit run would vacuously 'match'."""
-    problems: tp.List[str] = []
-    _require(
-        rec,
-        {
-            "bench": (str,),
-            "backend": (str,),
-            "t_long": (int,),
-            "t_short": (int,),
-            "page_size": (int,),
-            "decode_chunk": (int,),
-            "rounds": (int,),
-            "kv_dtype": (str,),
-            "model": (dict,),
-            "split_k_long": (int,),
-            "split_k_short": (int,),
-            "ms_round_long_unsplit": Number,
-            "ms_round_long_split": Number,
-            "long_speedup": Number,
-            "ms_round_short_unsplit": Number,
-            "ms_round_short_forced_split": Number,
-            "short_ratio": Number,
-            "match_block_size": (int,),
-            "greedy_match_frac": Number,
-            "train_steps": (int,),
-            "train_loss": Number,
-            "compile_counts": (dict,),
-        },
-        problems,
-    )
-    if rec.get("bench") != "serve_longctx":
-        problems.append(
-            f"field 'bench' is {rec.get('bench')!r}, expected 'serve_longctx'"
-        )
-    tl = rec.get("t_long")
-    if isinstance(tl, int) and tl < 1024:
-        problems.append(f"t_long {tl} < 1024 — below the auto-split regime")
-    sl = rec.get("split_k_long")
-    if isinstance(sl, int) and sl < 2:
-        problems.append(
-            f"split_k_long {sl} < 2 — the long point never engaged split-K, "
-            "so the A/B is vacuous"
-        )
-    ss = rec.get("split_k_short")
-    if isinstance(ss, int) and ss != 1:
-        problems.append(
-            f"split_k_short {ss} != 1 — short traffic must stay on the "
-            "unsplit program (the structural no-regression guarantee)"
-        )
-    gmf = rec.get("greedy_match_frac")
-    if isinstance(gmf, Number) and gmf != 1.0:
-        problems.append(
-            f"greedy_match_frac {gmf} != 1.0 — split-K must be invisible "
-            "to greedy streams"
-        )
-    for key in ("ms_round_long_unsplit", "ms_round_long_split",
-                "ms_round_short_unsplit", "ms_round_short_forced_split"):
-        v = rec.get(key)
-        if isinstance(v, Number) and v <= 0:
-            problems.append(f"{key} {v} <= 0")
-    return problems
-
-
-def check_serve_gqa_bench(rec: dict) -> tp.List[str]:
-    """tools/bench_serve.py --gqa profile: GQA/MQA KV-capacity A/B at a
-    fixed pool byte budget (docs/SERVING.md 'Attention variants'). The
-    load-bearing invariants:
-
-      * pages_ratio >= 0.75 * kv_groups — a GQA page is group-factor
-        smaller, so the same budget must admit (nearly) group-factor more
-        pages; the 0.75 floor absorbs the max(2, ...)/sink rounding of the
-        byte-budgeted sizing (the acceptance shape, 4x grouping, must
-        clear 3x).
-      * strictly fewer GQA preemptions on an oversubscribed trace, with
-        mha_preemptions > 0 required — a trace the MHA pool absorbs
-        without preempting proves nothing about capacity.
-      * BOTH greedy_match_frac_* == 1.0 EXACTLY — each variant's paged
-        streams vs dense-cache engine.generate on the same params; any
-        mismatch is a kernel/cache bug, not noise (capacity must be the
-        only thing the A/B varies).
-
-    kv_groups >= 2 keeps the record an actual A/B (an MHA-vs-MHA run
-    would vacuously 'match')."""
-    problems: tp.List[str] = []
-    _require(
-        rec,
-        {
-            "bench": (str,),
-            "backend": (str,),
-            "n_requests": (int,),
-            "total_new_tokens": (int,),
-            "max_slots": (int,),
-            "page_size": (int,),
-            "kv_dtype": (str,),
-            "pool_hbm_bytes": (int,),
-            "model": (dict,),
-            "kv_groups": (int,),
-            "n_kv_heads": (int,),
-            "sliding_window": (int,),
-            "attn_sinks": (int,),
-            "mha_page_bytes": (int,),
-            "gqa_page_bytes": (int,),
-            "mha_num_pages": (int,),
-            "gqa_num_pages": (int,),
-            "pages_ratio": Number,
-            "mha_slots_capacity": (int,),
-            "gqa_slots_capacity": (int,),
-            "mha_preemptions": (int,),
-            "gqa_preemptions": (int,),
-            "mha_tok_s": Number,
-            "gqa_tok_s": Number,
-            "window_reclaimed_pages": (int,),
-            "greedy_match_frac_mha": Number,
-            "greedy_match_frac_gqa": Number,
-            "compile_counts": (dict,),
-        },
-        problems,
-    )
-    if rec.get("bench") != "serve_gqa":
-        problems.append(
-            f"field 'bench' is {rec.get('bench')!r}, expected 'serve_gqa'"
-        )
-    groups = rec.get("kv_groups")
-    if isinstance(groups, int) and groups < 2:
-        problems.append(f"kv_groups {groups} < 2 — the A/B is vacuous")
-    ratio = rec.get("pages_ratio")
-    if (
-        isinstance(ratio, Number)
-        and isinstance(groups, int)
-        and ratio < 0.75 * groups
-    ):
-        problems.append(
-            f"pages_ratio {ratio} < 0.75 * kv_groups ({0.75 * groups}) — "
-            "the fixed byte budget did not convert into KV-head-scaled "
-            "page capacity"
-        )
-    pe_m, pe_g = rec.get("mha_preemptions"), rec.get("gqa_preemptions")
-    if isinstance(pe_m, int) and pe_m == 0:
-        problems.append(
-            "mha_preemptions == 0 — the trace never oversubscribed the MHA "
-            "pool, so the preemption comparison proves nothing (shrink "
-            "pool_hbm_bytes or grow the trace)"
-        )
-    if isinstance(pe_m, int) and isinstance(pe_g, int) and pe_g >= pe_m > 0:
-        problems.append(
-            f"gqa_preemptions {pe_g} >= mha_preemptions {pe_m} — the extra "
-            "pages must buy strictly fewer recompute preemptions"
-        )
-    for key in ("greedy_match_frac_mha", "greedy_match_frac_gqa"):
-        v = rec.get(key)
-        if isinstance(v, Number) and v != 1.0:
-            problems.append(
-                f"{key} {v} != 1.0 — paged reads must be bit-identical to "
-                "dense-cache reads per variant"
-            )
-    w = rec.get("sliding_window")
-    if isinstance(w, int) and w < 0:
-        problems.append(f"sliding_window {w} < 0")
-    return problems
-
-
-def check_serve_ops_bench(rec: dict) -> tp.List[str]:
-    """tools/bench_serve.py --hot-swap profile: zero-downtime model ops
-    (docs/ROBUSTNESS.md 'Zero-downtime model ops'). A verified-checkpoint
-    blue/green weight swap lands mid-trace, then the pool grows live; the
-    record carries the downtime claim, so its gates are structural:
-
-      * dropped == 0 — zero-downtime means every admitted stream finishes.
-      * swap_recompiles == 0 EXACTLY — a same-shape swap device_puts the
-        candidate onto the live shardings, so the serving jits' caches must
-        not grow at all; any new program means the staged params took a new
-        compile key and the 'live' in 'live swap' is a lie.
-      * parity_old_side + parity_new_side == n_requests, both sides >= 1 —
-        streams served before the flip must be bit-identical to the old
-        weights' reference, streams admitted after to the new weights'; an
-        empty side means the swap landed outside the traffic window and the
-        A/B is vacuous.
-      * pages_migrated >= 1 and pages_conserved — the resize leg actually
-        moved a resident working set and the free+trie+live accounting
-        closed at every boundary."""
-    problems: tp.List[str] = []
-    _require(
-        rec,
-        {
-            "bench": (str,),
-            "backend": (str,),
-            "n_requests": (int,),
-            "total_new_tokens": (int,),
-            "model": (dict,),
-            "num_pages": (int,),
-            "kv_dtype": (str,),
-            "checkpoint_step": (int,),
-            "weights_version_before": (str,),
-            "weights_version_after": (str,),
-            "swap_latency_ms": Number,
-            "streams_in_flight_at_flip": (int,),
-            "staged_round": (int,),
-            "flip_round": (int,),
-            "dropped": (int,),
-            "parity_old_side": (int,),
-            "parity_new_side": (int,),
-            "swap_recompiles": (int,),
-            "resize_from_pages": (int,),
-            "resize_to_pages": (int,),
-            "pages_migrated": (int,),
-            "compile_counts": (dict,),
-        },
-        problems,
-    )
-    if rec.get("bench") != "serve_ops":
-        problems.append(
-            f"field 'bench' is {rec.get('bench')!r}, expected 'serve_ops'"
-        )
-    if rec.get("dropped") != 0:
-        problems.append(
-            f"dropped {rec.get('dropped')!r} != 0 — a zero-downtime swap "
-            "must finish every admitted stream"
-        )
-    if rec.get("swap_recompiles") != 0:
-        problems.append(
-            f"swap_recompiles {rec.get('swap_recompiles')!r} != 0 — a "
-            "same-shape hot swap must reuse every compiled program"
-        )
-    po, pn, nr = (rec.get(k) for k in
-                  ("parity_old_side", "parity_new_side", "n_requests"))
-    if isinstance(po, int) and isinstance(pn, int):
-        if po < 1 or pn < 1:
-            problems.append(
-                f"parity sides {po}/{pn} — the flip must land inside the "
-                "traffic window (both sides non-empty)"
-            )
-        if isinstance(nr, int) and po + pn != nr:
-            problems.append(
-                f"parity_old_side {po} + parity_new_side {pn} != "
-                f"n_requests {nr} — some stream matched neither reference"
-            )
-    if rec.get("weights_version_before") == rec.get("weights_version_after"):
-        problems.append("weights_version did not change across the swap")
-    pm = rec.get("pages_migrated")
-    if isinstance(pm, int) and pm < 1:
-        problems.append(f"pages_migrated {pm} < 1 — the resize leg was vacuous")
-    if "pages_conserved" not in rec or rec["pages_conserved"] is not True:
-        problems.append("field 'pages_conserved' must be literal true")
-    sl = rec.get("swap_latency_ms")
-    if isinstance(sl, Number) and sl < 0:
-        problems.append(f"swap_latency_ms {sl} < 0")
-    return problems
-
-
-def check_serve_fleet_bench(rec: dict) -> tp.List[str]:
-    """tools/bench_serve.py --fleet profile: the shared-template trace
-    through one engine, then through an N-replica FleetRouter with a
-    replica killed mid-trace (docs/ROBUSTNESS.md 'Fleet serving &
-    failover'). The record carries the fleet's availability claim, so its
-    gates are structural:
-
-      * failovers >= 1 and dropped == 0 — a replica actually died and the
-        fleet still finished every accepted stream (otherwise the record
-        measured an unfaulted fleet and claims nothing).
-      * greedy_match_frac == 1.0 EXACTLY with parity_checked ==
-        n_requests — every stream, survivors and failover replays alike,
-        bit-matches the single-engine pass; failover replays the original
-        prompt with the full budget and greedy streams are
-        batch-composition-independent, so any mismatch is a router bug
-        (or a spill page that poisoned a decode), not noise.
-      * fleet_hit_rate >= single_hit_rate — prefix-affinity routing
-        exists so the fleet trie hit rate does NOT dilute toward 1/N of
-        the single engine's; a lower rate means the rendezvous hash
-        stopped steering templates to their pages.
-      * pages_conserved — per-alive-replica pool law plus the spill
-        ledger closed after the drain.
-
-    With `procs` true (bench_serve.py --fleet --procs: replicas are
-    worker PROCESSES behind the socket transport, the fault a real kill
-    -9 — docs/ROBUSTNESS.md 'Cross-process fleet') two gates shift:
-    the hit-rate ordering is NOT required — a SIGKILLed worker takes
-    its per-process host-RAM tier with it, so the KV the in-process
-    crash path spills and re-adopts is unrecoverable and the survivor
-    honestly re-prefills (zero-drop and exact-parity still hold, and
-    still ARE required) — and the record must carry the transport
-    claim: proc_failovers >= 1 (the death was detected through the
-    wire) plus rpc_p50_ms / rpc_p95_ms / wire_bytes. Both branches are
-    drift-pinned by tests/test_bench_contract.py."""
-    problems: tp.List[str] = []
-    _require(
-        rec,
-        {
-            "bench": (str,),
-            "backend": (str,),
-            "n_requests": (int,),
-            "total_new_tokens": (int,),
-            "fleet_size": (int,),
-            "model": (dict,),
-            "kv_dtype": (str,),
-            "num_pages": (int,),
-            "n_templates": (int,),
-            "single_tok_s": Number,
-            "fleet_tok_s": Number,
-            "single_hit_rate": Number,
-            "fleet_hit_rate": Number,
-            "failovers": (int,),
-            "failed_over_streams": (int,),
-            "dropped": (int,),
-            "parity_checked": (int,),
-            "greedy_match_frac": Number,
-            "spill_readopted_pages": (int,),
-            "spill": (dict,),
-            "compile_counts": (dict,),
-        },
-        problems,
-    )
-    if rec.get("bench") != "serve_fleet":
-        problems.append(
-            f"field 'bench' is {rec.get('bench')!r}, expected 'serve_fleet'"
-        )
-    fs = rec.get("fleet_size")
-    if isinstance(fs, int) and fs < 2:
-        problems.append(
-            f"fleet_size {fs} < 2 — a one-replica fleet cannot fail over"
-        )
-    if rec.get("failovers") == 0:
-        problems.append(
-            "failovers == 0 — no replica died, the availability A/B is vacuous"
-        )
-    if rec.get("dropped") != 0:
-        problems.append(
-            f"dropped {rec.get('dropped')!r} != 0 — failover must finish "
-            "every accepted stream"
-        )
-    gmf = rec.get("greedy_match_frac")
-    if isinstance(gmf, Number) and gmf != 1.0:
-        problems.append(
-            f"greedy_match_frac {gmf} != 1.0 — failover replays and spill "
-            "re-adoption must be bit-invisible to greedy streams"
-        )
-    pc, nr = rec.get("parity_checked"), rec.get("n_requests")
-    if isinstance(pc, int) and isinstance(nr, int) and pc != nr:
-        problems.append(
-            f"parity_checked {pc} != n_requests {nr} — some stream was "
-            "never checked against the single-engine reference"
-        )
-    fh, sh = rec.get("fleet_hit_rate"), rec.get("single_hit_rate")
-    for name, v in (("fleet_hit_rate", fh), ("single_hit_rate", sh)):
-        if isinstance(v, Number) and not 0.0 <= v <= 1.0:
-            problems.append(f"{name} {v} outside [0, 1]")
-    procs = rec.get("procs", False)
-    if not isinstance(procs, bool):
-        problems.append(f"field 'procs' must be a bool, got {procs!r}")
-        procs = False
-    if (
-        not procs
-        and isinstance(fh, Number) and isinstance(sh, Number) and fh < sh
-    ):
-        problems.append(
-            f"fleet_hit_rate {fh} < single_hit_rate {sh} — affinity "
-            "routing failed to protect the trie hit rate"
-        )
-    if procs:
-        _require(
-            rec,
-            {
-                "proc_failovers": (int,),
-                "worker_pids": (list,),
-                "transport": (dict,),
-                "rpc_p50_ms": Number,
-                "rpc_p95_ms": Number,
-                "wire_bytes": (int,),
-            },
-            problems,
-        )
-        pf = rec.get("proc_failovers")
-        if isinstance(pf, int) and pf < 1:
-            problems.append(
-                f"proc_failovers {pf} < 1 — kill -9 never detected "
-                "through the wire, the cross-process A/B is vacuous"
-            )
-        wb = rec.get("wire_bytes")
-        if isinstance(wb, int) and wb < 1:
-            problems.append(
-                f"wire_bytes {wb} < 1 — no frame ever crossed the socket"
-            )
-        for key in ("rpc_p50_ms", "rpc_p95_ms"):
-            v = rec.get(key)
-            if isinstance(v, Number) and v < 0:
-                problems.append(f"{key} {v} < 0")
-    if "pages_conserved" not in rec or rec["pages_conserved"] is not True:
-        problems.append("field 'pages_conserved' must be literal true")
-    return problems
-
-
-def check_serve_slo_bench(rec: dict) -> tp.List[str]:
-    """tools/loadgen.py profile: TTFT/TPOT percentiles + shed fraction
-    under a seeded arrival process, at >= 2 offered-load points (one point
-    is a measurement; the contract wants the start of an SLO curve). The
-    headline fields mirror the hottest point so drivers can gate without
-    digging into `points`. NaN rejection rides parse_single_json_line."""
-    problems: tp.List[str] = []
-    _require(
-        rec,
-        {
-            "bench": (str,),
-            "backend": (str,),
-            "process": (str,),
-            "scheduler": (str,),
-            "seed": (int,),
-            "n_requests": (int,),
-            "error_budget": Number,
-            "model": (dict,),
-            "points": (list,),
-            "ttft_p50_ms": Number,
-            "ttft_p95_ms": Number,
-            "tpot_p50_ms": Number,
-            "tpot_p95_ms": Number,
-            "shed_frac": Number,
-            "timeout_frac": Number,
-        },
-        problems,
-    )
-    if rec.get("bench") != "serve_slo":
-        problems.append(
-            f"field 'bench' is {rec.get('bench')!r}, expected 'serve_slo'"
-        )
-    _require_round_decomp(rec, problems)
-    if rec.get("process") not in (None, "poisson", "bursty"):
-        problems.append(f"field 'process' is {rec.get('process')!r}")
-    if "slo_ok" not in rec or not isinstance(rec["slo_ok"], bool):
-        problems.append("field 'slo_ok' must be a bool")
-    points = rec.get("points")
-    if isinstance(points, list):
-        if len(points) < 2:
-            problems.append(
-                f"{len(points)} load point(s) — the SLO profile requires "
-                ">= 2 offered-load points"
-            )
-        for i, p in enumerate(points):
-            if not isinstance(p, dict):
-                problems.append(f"points[{i}] is not an object")
-                continue
-            pp: tp.List[str] = []
-            _require(
-                p,
-                {
-                    "offered_rps": Number,
-                    "n_offered": (int,),
-                    "completed": (int,),
-                    "shed": (int,),
-                    "timeouts": (int,),
-                    "shed_frac": Number,
-                    "timeout_frac": Number,
-                    "ttft_p50_ms": Number,
-                    "ttft_p95_ms": Number,
-                    "tpot_p50_ms": Number,
-                    "tpot_p95_ms": Number,
-                    "rounds": (int,),
-                },
-                pp,
-            )
-            _require_round_decomp(p, pp)
-            problems.extend(f"points[{i}]: {q}" for q in pp)
-            # optional: present when loadgen ran with --prefix-cache
-            for frac in ("shed_frac", "timeout_frac", "prefix_hit_rate"):
-                v = p.get(frac)
-                if isinstance(v, Number) and not 0.0 <= v <= 1.0:
-                    problems.append(f"points[{i}].{frac} {v} outside [0, 1]")
-    sf = rec.get("shed_frac")
-    if isinstance(sf, Number) and not 0.0 <= sf <= 1.0:
-        problems.append(f"shed_frac {sf} outside [0, 1]")
-    # optional fleet block: present when loadgen ran with --fleet N
-    # (headline mirrors the hottest point, like the SLO percentiles)
-    fs = rec.get("fleet_size")
-    if fs is not None:
-        if not isinstance(fs, int) or fs < 1:
-            problems.append(f"fleet_size {fs!r} must be an int >= 1")
-        for key in ("failovers", "spill_hits"):
-            v = rec.get(key)
-            if not isinstance(v, int) or isinstance(v, bool) or v < 0:
-                problems.append(
-                    f"fleet record field {key!r} must be an int >= 0, "
-                    f"got {v!r}"
-                )
-        hr = rec.get("prefix_hit_rate")
-        if not isinstance(hr, Number) or not 0.0 <= hr <= 1.0:
-            problems.append(
-                f"fleet record 'prefix_hit_rate' {hr!r} outside [0, 1]"
-            )
-    # optional cross-process block: present when loadgen ran --fleet
-    # --procs (replicas are worker processes behind the socket transport;
-    # docs/ROBUSTNESS.md "Cross-process fleet")
-    if rec.get("procs"):
-        if fs is None:
-            problems.append("procs is true but fleet_size is absent")
-        for key in ("rpc_p50_ms", "rpc_p95_ms"):
-            v = rec.get(key)
-            if not isinstance(v, Number) or v < 0:
-                problems.append(
-                    f"procs record field {key!r} must be a number >= 0, "
-                    f"got {v!r}"
-                )
-        wb = rec.get("wire_bytes")
-        if not isinstance(wb, int) or isinstance(wb, bool) or wb < 1:
-            problems.append(
-                f"procs record 'wire_bytes' {wb!r} must be an int >= 1 — "
-                "no frame ever crossed the socket"
-            )
-    return problems
 
 
 def check_train_chaos(rec: dict) -> tp.List[str]:
@@ -982,16 +162,6 @@ def check_graftcheck(rec: dict) -> tp.List[str]:
 
 
 PROFILES: tp.Dict[str, tp.Callable[[dict], tp.List[str]]] = {
-    "train": check_train_bench,
-    "serve": check_serve_bench,
-    "serve_spec": check_serve_spec_bench,
-    "serve_prefix": check_serve_prefix_bench,
-    "serve_tp": check_serve_tp_bench,
-    "serve_longctx": check_serve_longctx_bench,
-    "serve_gqa": check_serve_gqa_bench,
-    "serve_ops": check_serve_ops_bench,
-    "serve_fleet": check_serve_fleet_bench,
-    "serve_slo": check_serve_slo_bench,
     "train_chaos": check_train_chaos,
     "graftcheck": check_graftcheck,
 }
@@ -1000,7 +170,7 @@ PROFILES: tp.Dict[str, tp.Callable[[dict], tp.List[str]]] = {
 def check_bench_stdout(
     stdout: str, profile: str
 ) -> tp.Tuple[tp.Optional[dict], tp.List[str]]:
-    """Parse + schema-check a bench process's stdout against a profile."""
+    """Parse + schema-check a CLI's stdout against a profile."""
     rec, problems = parse_single_json_line(stdout)
     if rec is not None:
         problems.extend(PROFILES[profile](rec))

@@ -68,7 +68,7 @@ RULES: tp.Dict[str, str] = {
 
 # Default lint roots, relative to the repo root (tests are excluded on
 # purpose: fixture snippets there *are* violations).
-DEFAULT_LINT_ROOTS = ("midgpt_tpu", "tools", "bench.py", "launch.py", "sample.py")
+DEFAULT_LINT_ROOTS = ("midgpt_tpu", "tools", "launch.py", "sample.py")
 
 _SUPPRESS_RE = re.compile(
     r"graftcheck:\s*disable=((?:GC\d{3})(?:\s*,\s*GC\d{3})*)\s*(.*)", re.DOTALL

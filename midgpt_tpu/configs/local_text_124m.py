@@ -5,9 +5,9 @@ configs/openwebtext.py:4-21) scaled to a single v5e chip and a ~2h horizon:
 identical model shape (GPT-2-small, vocab padded to 50304), identical
 optimizer constants (lr 1e-3 cosine to 1e-5, beta2 0.95, wd 1e-4 with
 wd/lr decoupling), the full fast path (flash attention, remat off — it
-fits at this scale, measured on an earlier toolchain, not re-measured —
-fused CE) and the G=16 accumulation schedule — with effective batch 256
-(16 x 16) instead of 2048 and the warmup/decay horizon scaled to 3000
+fits at this scale: the `train_124m` cell runs this recipe — fused CE) and
+the G=16 accumulation schedule — with effective batch 256 (16 x 16)
+instead of 2048 and the warmup/decay horizon scaled to 3000
 steps. Data comes from data/local_text/prepare.py (offline-trained
 byte-level BPE over local text trees).
 """
@@ -45,14 +45,14 @@ config = ExperimentConfig(
         dropout=0.0,
         attn_impl="flash",
         # 124M at microbatch 16 fits the 15.75 GB chip WITHOUT per-block
-        # remat (51.4% MFU remat-off vs 47.5% with the 'flash' policy at
-        # G=16, measured on an earlier toolchain, not re-measured);
+        # remat, so nothing is recomputed (this recipe IS the `train_124m`
+        # cell: its MFU is in the ledger; remat-on has no cell);
         # keep the policy name so
         # `--set model_config.remat=True` restores it for tighter chips.
         remat=False,
         remat_policy="flash",
-        # Remat-off only FITS with the layer scan fully unrolled (the bench's
-        # measured setting): the rolled scan's per-iteration temps push the
+        # Remat-off only FITS with the layer scan fully unrolled (the
+        # `train_124m` cell's setting): the rolled scan's per-iteration temps push the
         # no-remat activation set past 15.75 GB (OOMs at unroll=1).
         scan_unroll=12,
         rope_style="split",  # same-function fast RoPE (see openwebtext.py)

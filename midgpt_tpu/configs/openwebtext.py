@@ -24,9 +24,9 @@ config = ExperimentConfig(
         block_size=1024, vocab_size=50304, n_layer=12, n_head=12, n_embd=768,
         dropout=0.0,
         # Same function as the reference rotation via the in-graph q/k row
-        # permutation (models/gpt.py _qkv_weights, exactness test-pinned):
-        # +2.1 MFU on the v5e 124M bench (measured on an earlier toolchain, not
-        # re-measured).
+        # permutation (models/gpt.py _qkv_weights, exactness test-pinned);
+        # contiguous rotate-half instead of stride-2 gathers. `train_124m`
+        # (ledger) runs it; the interleaved form has no cell.
         rope_style="split",
     ),
 )

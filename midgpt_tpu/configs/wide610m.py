@@ -1,18 +1,12 @@
-"""610M wide-head (C=128) slice — the repo's best-MFU shape, as a config.
+"""610M wide-head (C=128) slice of GPT-2-XL, as a config for one chip.
 
 GPT-2-XL width (n_embd=2048, n_head=16 → head dim C=128) at 8 layers, so
 fp32 master params + Adam state + remat-free activations fit one v5e chip
 (15.75 GB). C=128 fills the MXU's 128-wide systolic array on QK^T/PV where
-the GPT-2-small C=64 runs it half-utilized; measured 63.8% MFU sustained at
-per-chip batch 12 — the repo's ≥55% target with 8 points to spare, 1.34×
-the reference's published 47.8% (reference README.md:55); the figures here
-were measured on an earlier toolchain, not re-measured.
+the GPT-2-small C=64 uses half of it. Its speed has no cell on the current
+toolchain (both one-chip GPT cells have C=64): PERF.md §7 row 7.
 
-This file is the single source of truth for the shape: `bench.py --shape
-wide` loads it, so the number is reproducible both ways —
-
-    python bench.py --shape wide              # driver-style one-liner
-    python launch.py --config=wide610m --rundir=outputs/wide  # real training
+    python launch.py --config=wide610m --rundir=outputs/wide
 
 Optimizer/schedule constants follow the openwebtext_xl recipe (reference
 configs/openwebtext_xl.py:4-22) with the horizon scaled to a single chip.
@@ -25,7 +19,7 @@ config = ExperimentConfig(
     rundir="",
     data_dir="data/local_text",
     learning_rate=1e-3,
-    batch_size=12,  # measured optimum: 12 → 63.8% MFU; 16 hits HBM pressure
+    batch_size=12,  # 16 is under HBM pressure on one chip; no cell: PERF.md §7 row 7
     warmup_steps=300,
     min_lr=1e-5,
     lr_decay_steps=3000,
@@ -47,19 +41,19 @@ config = ExperimentConfig(
         n_embd=2048,
         dropout=0.0,
         attn_impl="flash",
-        # Remat OFF is what fits-and-flies at batch 12 (63.8%); +remat OOMs
-        # at batch 16 and loses ~10 points at 12 (measured on an earlier
-        # toolchain, not re-measured).
+        # Remat OFF fits at batch 12 and recomputes nothing (no cell:
+        # PERF.md §7 row 7).
         remat=False,
         remat_policy="flash",
         # Like the 124M recipe: remat-off only FITS with the layer scan
-        # fully unrolled (the bench's measured setting) — the rolled scan's
+        # fully unrolled — the rolled scan's
         # per-iteration temps exceed HBM (OOMs at unroll=1).
         scan_unroll=8,
         rope_style="split",
-        # At C=128 the head-major end-to-end layout wins (+1.2 MFU, 63.9%
-        # measured r5); at C=64 it loses — keep 'seq' there (measured on an
-        # earlier toolchain, not re-measured).
+        # Head-major end to end: at C=128 a head's row is a whole 128-lane
+        # run, so the layout needs no relayout around the kernel; at C=64 it
+        # leaves half-lane runs — keep 'seq' there (`train_124m`, ledger).
+        # No cell at C=128: PERF.md §7 row 7.
         attn_layout="head",
     ),
 )

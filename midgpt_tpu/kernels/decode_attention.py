@@ -34,11 +34,9 @@ softmax STATISTICS: scores reshape into split_k independent partitions,
 one online-softmax block sweeps each, and partials merge with the SAME
 ops/online_softmax.merge_partials math as the kernel path. Deliberately so:
 a host core executes partitions sequentially either way, so the gather
-split lowering aims for structure-neutrality (measured within noise of the
-unsplit pass; measured on an earlier toolchain, not re-measured) while the
-kernel's parallel grid dimension
-carries the actual long-T win on hardware (tools/bench_serve.py
---long-ctx). The kernels themselves run in interpret mode only under
+split lowering aims for structure-neutrality, while the kernel's grid
+dimension is what a long-T decode on hardware would gain from (no cell
+runs T > 1024: PERF.md §7). The kernels themselves run in interpret mode only under
 their parity tests (tests/test_decode_attention.py, tests/test_split_k.py
 and tests/test_quant_cache.py — interpret is too slow for the serving
 tests' inner loop).

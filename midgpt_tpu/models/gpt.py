@@ -64,10 +64,9 @@ class GPTConfig:
     # head sharding and attention runs dense); the runtime injects the
     # mesh-bound implementation via the attn_fn hook on GPT.hidden.
     attn_impl: str = "naive"  # 'naive' | 'blockwise' | 'flash' | 'ring' | 'ulysses'
-    # Tile size for the blockwise/flash/ring/ulysses paths. 1024 measured 7
-    # MFU points faster than 512 on the 124M flash training step (v5e;
-    # measured on an earlier toolchain, not re-measured) and matches the ring's
-    # tuned per-pair tile.
+    # Tile size for the blockwise/flash/ring/ulysses paths. 1024 is one KV
+    # step at T=1024 (the flash kernel's single-step specialization) and the
+    # ring's per-pair tile; `train_124m` (ledger) runs it, 512 has no cell.
     attn_block_size: int = 1024
     remat: bool = True  # checkpoint each block inside the layer scan
     # What the per-block checkpoint may keep instead of recomputing in bwd:
@@ -94,10 +93,9 @@ class GPTConfig:
     # (checkpoints stay in reference convention) + the contiguous
     # rotate-half form — mathematically identical (QK^T is invariant under
     # a shared permutation of the C axis; pinned by test_rope/test_model),
-    # and measured 12.3 ms/step faster on the 124M v5e bench (measured on an
-    # earlier toolchain, not re-measured:
-    #  the interleaved form's stride-2 gathers cost copy passes in fwd
-    # AND bwd). Per-run choice recorded in config.json, so restores and
+    # and cheaper (the interleaved form's stride-2 gathers cost copy passes
+    # in fwd AND bwd; `train_124m`, ledger, runs 'split'; 'interleaved' has
+    # no cell). Per-run choice recorded in config.json, so restores and
     # sampling stay consistent.
     rope_style: str = "interleaved"
     # Internal activation layout of the attention fast paths (flash kernel /
@@ -222,10 +220,9 @@ class AttentionParams:
     # each break:
     #   * at tp=1 it reshapes (free: contiguous) to the flat stacked (3D, D)
     #     for ONE full-width matmul + contiguous split — the fast MXU path
-    #     (a head-major interleaved flat layout costs ~1.7 MFU points at
-    #     C=64, measured on an earlier toolchain, not re-measured: its
-    #     (B,T,H,3,C) unpack slices leave
-    #     64-element lane runs);
+    #     (a head-major interleaved flat layout's (B,T,H,3,C) unpack slices
+    #     leave 64-element lane runs at C=64; `train_124m`, ledger, runs
+    #     this one);
     #   * Megatron TP shards axis 1 (output features, parallel/tp.py): each
     #     of q, k, v is column-sharded independently, so shard boundaries
     #     land between whole heads (D = H*C head-major) and the schedule is
@@ -1100,7 +1097,7 @@ class GPT:
 
         # jax.named_scope boundaries (embed / block / attn / mlp / final_norm)
         # label the profiler trace like reference model.py:28,55,97,140 —
-        # tools/profile_summary.py groups exclusive op times by them.
+        # benchmarks/reduce.py groups device op times by them.
         with jax.named_scope("embed"):
             x = jnp.take(params.wte, tokens, axis=0)  # (B, T, D)
             x = dropout(x, config.dropout, drop_key, inference)

@@ -19,14 +19,14 @@ the engine loop reads its injected clock at four boundaries per round —
 — and derives `t_dispatch` = t1-t0 (host assembly + enqueue),
 `t_device_wait` = t_land-t1 (device compute + the copy to the host),
 `t_host_post` = t_post-t_land. These aggregate to p50/p95 in histograms
-and surface on `stats()["obs"]["round_decomp"]`, loadgen's serve_slo
-points, and the bench_serve profiles — the baseline artifact ROADMAP
-item 3's round-overlap dispatch A/Bs against. Under overlap="double"
+and surface on `stats()["obs"]["round_decomp"]`; the serving cells read
+the spans themselves (benchmarks/metrics/engine.py) — the baseline a
+round-overlap dispatch A/B is held against. Under overlap="double"
 (sampling/serve.py `_step_overlapped`) round N settles one step late, so
 its t1 -> t_land window CONTAINS host work for other rounds; the engine
 reports that overlapped span via `hidden_s` and it surfaces as the
-`overlap_hidden` decomposition entry (`overlap_hidden_ms` on the bench
-lines) — the host time the overlap actually hid, the A/B headline of
+`overlap_hidden` decomposition entry — the host time the overlap
+actually hid, the A/B headline of
 docs/SERVING.md "Round-overlap dispatch".
 
 The module-level `flight_recorder()` singleton is the always-on crash

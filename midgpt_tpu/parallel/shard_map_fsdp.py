@@ -26,15 +26,14 @@ Gather/compute overlap is pinned, not assumed (r5):
     scan_unroll=2 no weight gather in the scan body depends on the body's
     compute, so the scheduler is free to issue layer l+1's gathers during
     layer l.
-  * tools/check_overlap_tpu.py AOT-compiles this step for a v5e:2x4
-    topology and asserts the TPU compiler actually exploits that freedom:
-    the body's weight gathers become async (annotated
-    async_collective_name="all-gather-start") or are continuation-FUSED
-    into the block matmul kernels (gather windows streamed inside the dots,
-    forward and backward). The result was measured on an earlier toolchain, not
-    re-measured. NOTE: that
-    requires xla_tpu_enable_latency_hiding_scheduler=true — NOT default-on
-    in this toolchain; real-pod launches should set it (docs/PARALLELISM.md).
+  * tests/test_chip_compile.py AOT-compiles this step for a described v5e
+    2x2 and asserts the TPU compiler actually exploits that freedom: in
+    every gather-bearing scan body, forward and backward, weight gathers
+    are async (annotated async_collective_name="all-gather-start") or
+    continuation-FUSED into the block matmul kernels (gather windows
+    streamed inside the dots). On jax 0.9.0 / libtpu 0.0.34 it holds with
+    no compiler option set (docs/PARALLELISM.md "Overlap"). What it is
+    worth in step time is not measured: no cell runs this mode.
 
 Numerical parity with the GSPMD path is asserted in
 tests/test_shard_map_fsdp.py (same loss and same grads to fp32 tolerance on

@@ -458,7 +458,7 @@ def _fleet_router(cfg, params, obs, n_replicas: int = 2):
     """The fleet-under-fault: `n_replicas` prefix-cached greedy engines
     behind a FleetRouter with its shared spill tier (the router attaches
     it). Same per-replica shape as _engine except the pool: 31 is a fresh
-    program-key geometry — not 25 (recompile-pin baseline), 27 (loadgen),
+    program-key geometry — not 25 (recompile-pin baseline),
     29 (single-engine chaos), or 43/37 (resize targets)."""
     import jax.numpy as jnp
 

@@ -33,7 +33,7 @@ invariants rather than around speed:
   * **ModelOps** is a clock-injected controller (GC012: no wall-clock
     reads outside the injected callable) that consumes the signals the
     obs layer already surfaces — free-page fraction, backlog pages,
-    shed_frac, p95 TTFT when the caller has one (tools/loadgen.py) — and
+    shed_frac, p95 TTFT when the caller has one — and
     emits grow/shrink/re-role/shed-threshold decisions, observable as
     `ops.decision` tracer instants and Prometheus gauges.
 
@@ -538,7 +538,7 @@ class ModelOps:
 
     Consumes only signals the engine already exposes (free-page fraction,
     backlog pages, shed fraction, handoff queue depth) plus an optional
-    caller-measured `ttft_p95_ms` (tools/loadgen.py feeds its own window),
+    caller-measured `ttft_p95_ms` (a front end feeds its own window),
     and emits at most ONE decision per tick:
 
       grow            free pages below `low_free_frac`, TTFT over budget,

@@ -162,8 +162,7 @@ class SLOScheduler(Scheduler):
       FCFS), and additionally any request whose deadline is nearer than
       `min_headroom_s` (non-retryable: waiting only makes it later). A
       request shed at submit costs zero pool pages and zero prefill work —
-      the error-budget lever the load harness (tools/loadgen.py) measures
-      as `shed_frac`.
+      the error-budget lever; the engine counts it as `shed`.
     """
 
     name = "slo"

@@ -321,26 +321,6 @@ def _round_group_bucket(group: int) -> int:
     return 1 << (group.bit_length() - 1)
 
 
-def parse_overlap(spec: str) -> tp.Tuple[str, int]:
-    """Parse the `--overlap {off,double,group:k}` CLI form shared by
-    tools/bench_serve.py and tools/loadgen.py into the engine's
-    (overlap, round_group) kwargs. Strict: anything else raises, so a
-    typo'd A/B flag fails the bench instead of silently measuring 'off'."""
-    if spec in ("off", "double"):
-        return spec, 1
-    if spec.startswith("group:"):
-        try:
-            k = int(spec.split(":", 1)[1])
-        except ValueError:
-            k = 0
-        if k >= 1:
-            return "group", k
-    raise ValueError(
-        f"bad overlap spec {spec!r} (want 'off', 'double', or 'group:k' "
-        "with k >= 1)"
-    )
-
-
 @_PoolProgram
 @functools.partial(
     jax.jit,
@@ -1011,7 +991,7 @@ class ServeEngine:
         # Recompute-style preemptions since construction (one per _evict):
         # the oversubscription cost a byte budget trades against — int8
         # mode's 2x pages shows up here as strictly fewer evictions on the
-        # same trace (tests/test_quant_cache.py; reported by bench_serve).
+        # same trace (tests/test_quant_cache.py).
         self.preemptions = 0
         # Sliding-window page reclamation (config.sliding_window > 0,
         # cache-off, non-speculative engines): pages wholly behind every
@@ -1020,8 +1000,8 @@ class ServeEngine:
         # sink page — the bounded-resident-set lever that makes windowed
         # decode O(window) in pool pages, not O(T).
         self.window_reclaimed_pages = 0
-        # Robustness/SLO counters (reported by tools/loadgen.py and the
-        # chaos serve scenarios): scheduling rounds, deadline timeouts,
+        # Robustness/SLO counters (reported by stats() and the chaos
+        # serve scenarios): scheduling rounds, deadline timeouts,
         # admission sheds, client cancellations, and killed decode rounds.
         self.rounds = 0
         self.timeouts = 0
@@ -1403,9 +1383,7 @@ class ServeEngine:
         never recompiles — is only as good as these numbers staying flat:
         `decode` is bounded by |{(n_steps, page bucket)}|, `prefill` by
         |{page bucket}|, regardless of request mix. Pinned by
-        tests/test_recompile_pins.py; reported by tools/bench_serve.py so
-        drivers see compile-set growth as data, not as mystery latency.
-        Process-global (module-level jits shared by every engine).
+        tests/test_recompile_pins.py. Process-global (module-level jits shared by every engine).
 
         `pool_relayouts` is the kernel path's layout census, per compiled
         program: pool- or layer-sized copies in its compiled text, 0 when
@@ -1445,15 +1423,13 @@ class ServeEngine:
         """Per-DEVICE bytes of the target pool. Every pool leaf (K/V pages
         and int8 scale side buffers) shards its head axis over 'tp' and
         replicates elsewhere, so a tp shard holds exactly total/tp — the
-        number a per-chip HBM budget must be judged against, and the lever
-        the tp bench reports: slot capacity per chip grows with the mesh
-        (tools/bench_serve.py serve_tp profile)."""
+        number a per-chip HBM budget must be judged against: slot capacity
+        per chip grows with the mesh (tests/test_tp_serving.py)."""
         n_tp = 1 if self.mesh is None else int(self.mesh.shape["tp"])
         return self.cache_hbm_bytes() // n_tp
 
     def stats(self) -> tp.Dict[str, tp.Any]:
-        """Deployment-shape + counter snapshot for SLO reporting: the
-        `serve_slo` JSON lines (tools/loadgen.py) carry this so a sharded
+        """Deployment-shape + counter snapshot for SLO reporting: a sharded
         run is distinguishable from a single-chip one by its record alone."""
         return {
             "mesh": self.mesh_shape(),
@@ -2667,9 +2643,7 @@ class ServeEngine:
         }
 
     def prefix_stats(self) -> tp.Dict[str, tp.Any]:
-        """Prefix-cache counters since construction (reported by
-        tools/bench_serve.py's serve_prefix profile and tools/loadgen.py).
-        `hit_rate` is matched / MATCHABLE prompt tokens, where matchable is
+        """Prefix-cache counters since construction. `hit_rate` is matched / MATCHABLE prompt tokens, where matchable is
         the structural ceiling per admission — ((len(prompt) - 1) //
         page_size) * page_size, the most any match could hand out under the
         reserve-the-last-token rule — so a perfect template workload can

@@ -301,8 +301,7 @@ class AsyncServeServer:
         return None if st is None else st.finished
 
     def stats(self) -> tp.Dict[str, tp.Any]:
-        """Engine observability snapshot for metrics scrapes (used by
-        tools/loadgen.py). Counters are plain ints mutated only inside
+        """Engine observability snapshot for metrics scrapes. Counters are plain ints mutated only inside
         `engine.step` on the driver's worker thread, so a read from the
         event loop is at worst one round stale, never torn."""
         eng = self.engine

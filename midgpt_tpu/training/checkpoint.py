@@ -226,8 +226,8 @@ class CheckpointManager:
 
     def weights_version(self, step: int) -> tp.Optional[str]:
         """'<step>:<sha12>' identity of a step's committed manifest — the
-        value serving surfaces as `weights_version` on stats()/loadgen
-        lines so every round is attributable to exactly one verified
+        value serving surfaces as `weights_version` on stats() so every
+        round is attributable to exactly one verified
         checkpoint (sampling/ops.py hot-swap; "inline" means params were
         passed directly). Hashing the manifest FILE (which already records
         per-item sha256s) gives a stable content identity without

@@ -28,7 +28,13 @@ except Exception:  # pragma: no cover - depends on environment
 def flops_per_token(cfg, seq_len: tp.Optional[int] = None, stats: tp.Optional[dict] = None) -> float:
     """Training FLOPs/token, by the model family's own count
     (`flops_per_token` of its namespace, models/__init__.py). `stats`: the
-    family's counters of a logged step (`route_stats`), where it has any."""
+    family's counters of a logged step (`route_stats`), where it has any.
+
+    benchmarks/arithmetic*.py hold a copy of each family's count BY DESIGN:
+    the yardstick's own, so that a change to the program cannot move a
+    reported utilization. tests/test_metrics.py (dense GPT, and the peaks
+    table below against benchmarks/peaks.json) and tests/test_kimi_linear.py
+    (the hybrid) are what tie the copies to this one."""
     return cfg.model().flops_per_token(cfg, seq_len, stats)
 
 

@@ -3,8 +3,8 @@
 The 124M train step is a fully unrolled 12-layer program and the serving
 engine compiles a handful of programs per dtype; a second process running
 the same program should load it, not compile it again. `enable()` is
-called once by launch.py, sample.py, bench.py and chip_smoke.py's JAX
-children, before first backend use:
+called once by launch.py, sample.py, the benchmark's cells and
+chip_smoke.py's JAX children, before first backend use:
 
   * `JAX_COMPILATION_CACHE_DIR` set: no path is set in code — JAX reads the
     variable itself, so whoever runs the program places the cache.

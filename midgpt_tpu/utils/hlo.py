@@ -3,8 +3,8 @@
 Used by the structural pins that keep scheduling claims honest:
 tests/test_shard_map_fsdp.py (gather/compute dataflow independence),
 tests/test_configs_compile.py (at-scale configs lower), and
-tools/check_overlap_tpu.py (TPU async-collective behavior). One parser and
-one abstract-lowering scaffold so the pins can't drift apart.
+tests/test_chip_compile.py (the chip compiler's async collectives). One
+parser and one abstract-lowering scaffold so the pins can't drift apart.
 """
 
 from __future__ import annotations
@@ -67,9 +67,50 @@ def is_forward_shmap_line(line: str) -> bool:
 
 def is_forward_body(lines: tp.Sequence[str]) -> bool:
     """Forward (jvp) vs backward (transpose(jvp)) scan-body classification,
-    shared by tests/test_shard_map_fsdp.py and tools/check_overlap_tpu.py so
+    shared by tests/test_shard_map_fsdp.py and `gather_overlap_census` so
     the two overlap pins can't drift on what they call 'forward'."""
     return any(is_forward_shmap_line(l) and "while" in l for l in lines)
+
+
+def gather_overlap_census(txt: str) -> tp.List[tp.Dict[str, tp.Any]]:
+    """One entry per gather-bearing shard_map scan body of compiled TPU HLO:
+    `{"body", "kind", "plain", "annotated", "fused"}`. The TPU compiler does
+    not split async gathers into `all-gather-start`/`-done` pairs in its
+    text; overlap shows as gathers ANNOTATED `async_collective_name=
+    "all-gather-start*"` (the split happens in the backend scheduler) or as
+    collective-continuation fusions (`calls=%async_collective_fusion.*`: a
+    compute kernel carries the next layer's gather windows, the strongest
+    form). A body whose gathers are all `plain` streams its weights behind
+    compute. Bodies are found structurally (`body=%name` of a while op), not
+    by metadata: leaf fusions inherit the body's op_name and must not be
+    graded as bodies."""
+
+    def is_async(l: str) -> bool:
+        return (
+            "all-gather-start(" in l
+            or 'async_collective_name="all-gather-start' in l
+        )
+
+    bodies = while_body_names(txt)
+    census = []
+    for name, lines in hlo_computations(txt).items():
+        if name not in bodies or not any("shard_map/while" in l for l in lines):
+            continue
+        annotated = sum(1 for l in lines if is_async(l))
+        plain = sum(1 for l in lines if " all-gather(" in l and not is_async(l))
+        fused = sum(1 for l in lines if "calls=%async_collective_fusion" in l)
+        if plain + annotated + fused == 0:
+            continue  # gather-free body (not a ZeRO-3 layer scan)
+        census.append(
+            {
+                "body": name,
+                "kind": "forward" if is_forward_body(lines) else "backward",
+                "plain": plain,
+                "annotated": annotated,
+                "fused": fused,
+            }
+        )
+    return census
 
 
 def lower_abstract_train_step(config, mesh=None, eval_program=False):
@@ -79,7 +120,7 @@ def lower_abstract_train_step(config, mesh=None, eval_program=False):
     same mesh, through the implicit-GSPMD forward whatever `fsdp_mode` is).
 
     No buffers are materialized, so this works for 7B-class configs on a
-    CPU test host and for AOT device topologies (tools/check_overlap_tpu.py
+    CPU test host and for AOT device topologies (tests/test_chip_compile.py
     passes a mesh built from jax.experimental.topologies devices).
     Param/optimizer sharding specs follow the same rule selection as
     training/train.py init_state (pipeline rule under pp>1, else the

@@ -25,6 +25,8 @@ geometries, and split-K's 33/35.
 
 import math
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -32,7 +34,7 @@ import pytest
 
 from midgpt_tpu.analysis.hlo_audit import CompileCounter
 from midgpt_tpu.kernels.attention_template import paged_attention_template
-from midgpt_tpu.models.gpt import GPT, GPTConfig
+from midgpt_tpu.models.gpt import GPT, GPTConfig, PagedKVCache
 from midgpt_tpu.ops.quant import quantize_q8
 from midgpt_tpu.parallel.serve_tp import make_serve_mesh
 from midgpt_tpu.sampling.engine import generate
@@ -254,6 +256,44 @@ def test_gqa_engine_greedy_matches_generate(gqa_params, feature):
         cfg = WIN_CFG
         params = GPT.init(WIN_CFG, jax.random.PRNGKey(0))
     _serve_vs_generate(cfg, params, **kw)
+
+
+def test_gqa_byte_budget_scales_pages_and_reduces_preemptions(gqa_params):
+    """THE capacity claim of grouping (docs/SERVING.md "Attention variants"):
+    a GQA page is group-factor smaller (PagedKVCache.page_bytes), so at one
+    pool_hbm_bytes the GQA pool admits >= 0.75 x groups the pages of MHA (the
+    floor absorbs the sizing's rounding; here it is exact), and on a trace
+    that oversubscribes the MHA pool the GQA engine preempts STRICTLY less.
+    Capacity is the only thing that differs: each engine's streams, the
+    preempted ones included, are bit-identical to dense-cache generate on
+    its own params (different projection layouts are different models)."""
+    mha_cfg = dataclasses.replace(GQA_CFG, n_kv_heads=None)
+    mha_params = GPT.init(mha_cfg, jax.random.PRNGKey(0))
+    groups = GQA_CFG.n_head // GQA_CFG.kv_heads
+    budget = PagedKVCache.page_bytes(mha_cfg, 8, jnp.float32) * 10  # MHA: 10 pages
+    rng = np.random.default_rng(3)
+    # 3 x 4 pages of demand: over MHA's 9 allocatable pages, inside GQA's 19
+    trace = [(rng.integers(1, GQA_CFG.vocab_size, 8).tolist(), 24) for _ in range(3)]
+
+    def run(cfg, params):
+        eng = ServeEngine(
+            cfg, params, max_slots=3, page_size=8, pool_hbm_bytes=budget,
+            prefill_chunk=8, decode_chunk=8, temperature=0.0,
+            cache_dtype=jnp.float32,
+        )
+        uids = [eng.submit(p, m) for p, m in trace]
+        done = eng.run()
+        for uid, (p, m) in zip(uids, trace):
+            ref = generate(cfg, params, jnp.asarray(p, jnp.int32)[None], m, temperature=0.0)[0]
+            np.testing.assert_array_equal(np.asarray(done[uid].tokens), np.asarray(ref))
+        assert_conserved(eng, "after the oversubscribed trace")
+        return eng
+
+    mha, gqa = run(mha_cfg, mha_params), run(GQA_CFG, gqa_params)
+    assert mha.allocator.num_pages == 10
+    assert gqa.allocator.num_pages >= 0.75 * groups * mha.allocator.num_pages
+    assert mha.preemptions > 0, "the trace must oversubscribe the MHA pool"
+    assert gqa.preemptions < mha.preemptions, (gqa.preemptions, mha.preemptions)
 
 
 @pytest.mark.slow  # long stream + generate oracle: ~14 s on the 1-core host

@@ -4,15 +4,26 @@ drives). Each scenario runs a fault-free reference and a faulted pass of
 the same seeded trace and asserts the degradation invariants internally —
 engine alive, every page conserved, unaffected greedy streams bit-identical
 — so these tests mostly assert on the returned summary. The CLI JSON line
-is validated through the shared single-line parser at the end."""
+is validated through the shared single-line parser at the end, and the
+training chaos summary (`chaos_run.py` without --serve) through its
+`train_chaos` profile."""
 
 import json
+import os
+import runpy
+import sys
 
 import pytest
 
-from midgpt_tpu.analysis.bench_contract import parse_single_json_line
+from midgpt_tpu.analysis.bench_contract import (
+    check_bench_stdout,
+    check_train_chaos,
+    parse_single_json_line,
+)
 from midgpt_tpu.robustness import faults
 from midgpt_tpu.robustness.chaos_serve import run_serving_chaos
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -203,13 +214,8 @@ def test_chaos_spill_corrupt_discards_never_poisons():
 def test_chaos_run_serve_cli_emits_one_json_line(capsys):
     """`chaos_run.py --serve` holds the one-JSON-line driver contract and
     carries the chaos verdict fields."""
-    import runpy
-    import os
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     mod = runpy.run_path(
-        os.path.join(repo, "tools", "chaos_run.py"), run_name="chaos_under_test"
+        os.path.join(REPO, "tools", "chaos_run.py"), run_name="chaos_under_test"
     )
     argv, sys.argv = sys.argv, [
         "chaos_run.py", "--serve", "--fault", "kill_mid_decode@5",
@@ -228,3 +234,106 @@ def test_chaos_run_serve_cli_emits_one_json_line(capsys):
     assert rec["pages_conserved"] is True
     # the record round-trips as strict JSON (no NaN etc.)
     json.loads(out)
+
+
+def test_train_chaos_checker_catches_drift():
+    """The train_chaos gates hold on a synthetic record without running
+    the chaos bench: the recovery claims (a fault FIRED, detection was
+    timestamped, the recovered trajectory matches the unfaulted reference,
+    the finishing mesh is named) are contract, not numbers."""
+    good = {
+        "tool": "chaos_run", "config": "shakespeare_char", "rundir": "/r",
+        "status": "ok", "wall_s": 10.5,
+        "faults_requested": ["resume_reshard@6"],
+        "faults_fired": {"resume_reshard": 1},
+        "supervisor": {"restarts": 0, "hung_steps": []},
+        "loss_final": 4.5, "preempted": False, "bench": "train_chaos",
+        "detected_at_ms": 5001.7, "restarts": 1,
+        "final_mesh": {"n_devices": 4, "axes": {"data": 1, "fsdp": 4}},
+        "n_devices_final": 4, "loss_ref": 4.5, "loss_parity": True,
+    }
+    assert check_train_chaos(good) == []
+    assert any("loss_parity" in p
+               for p in check_train_chaos(dict(good, loss_parity=False)))
+    missing = dict(good)
+    missing.pop("detected_at_ms")
+    assert any("detected_at_ms" in p for p in check_train_chaos(missing))
+    assert any("faults_fired" in p
+               for p in check_train_chaos(dict(good, faults_fired={})))
+    assert any("status" in p
+               for p in check_train_chaos(dict(good, status="failed")))
+    assert any("bench" in p
+               for p in check_train_chaos(dict(good, bench="train")))
+    assert any(
+        "n_devices" in p
+        for p in check_train_chaos(
+            dict(good, final_mesh={"n_devices": 0, "axes": {"data": 1}})
+        )
+    )
+    assert any(
+        "axes" in p
+        for p in check_train_chaos(
+            dict(good, final_mesh={"n_devices": 4, "axes": {}})
+        )
+    )
+    assert any("restarts" in p
+               for p in check_train_chaos(dict(good, restarts=-1)))
+
+
+@pytest.mark.slow
+def test_chaos_run_train_cli_emits_conformant_train_chaos_line(
+    capsys, tmp_path
+):
+    """`chaos_run.py --fault resume_reshard@6` (train mode) holds the
+    one-JSON-line driver contract end to end: the fault ends attempt one
+    like a preemption, the driver restarts on HALF the devices with
+    on_resume_mesh='any', the run completes on the 4-device mesh, and the
+    summary passes the train_chaos profile. Step logs and supervisor
+    prints go to stderr — stdout is the summary line, full stop."""
+    import numpy as np
+
+    from midgpt_tpu.robustness import faults, preempt
+
+    data = tmp_path / "data"
+    data.mkdir()
+    stream = (np.arange(20000) % 17).astype(np.uint16)
+    stream.tofile(data / "train.bin")
+    stream[:4000].tofile(data / "val.bin")
+
+    mod = runpy.run_path(
+        os.path.join(REPO, "tools", "chaos_run.py"), run_name="chaos_under_test"
+    )
+    argv, sys.argv = sys.argv, [
+        "chaos_run.py", "--config=shakespeare_char",
+        f"--rundir={tmp_path / 'run'}",
+        "--fault", "resume_reshard@6",
+        "--set", "max_steps=16", "--set", "eval_interval=8",
+        "--set", "eval_steps=2", "--set", "batch_size=8",
+        "--set", "log_interval=4",
+        "--set", "model_config.n_layer=1", "--set", "model_config.n_head=2",
+        "--set", "model_config.n_embd=32",
+        "--set", "model_config.block_size=32",
+        "--set", "model_config.vocab_size=96",
+        "--set", f"data_dir={data}",
+        "--set", "mesh.data=2", "--set", "mesh.fsdp=4",
+        "--set", "param_dtype=float32", "--set", "compute_dtype=float32",
+        "--set", "restart_backoff_sec=0.0",
+    ]
+    try:
+        rc = mod["main"]()
+    finally:
+        sys.argv = argv
+        faults.clear()
+        preempt.reset()
+    assert rc == 0
+    out = capsys.readouterr().out
+    rec, problems = check_bench_stdout(out, "train_chaos")
+    assert not problems, problems
+    assert rec["faults_fired"] == {"resume_reshard": 1}
+    # the topology actually changed hands: started on 8, finished on 4
+    assert rec["final_mesh"]["n_devices"] == 4
+    assert rec["restarts"] >= 1
+    assert rec["loss_parity"] is True
+    history = rec["supervisor"]["mesh_history"]
+    assert [m["n_devices"] for m in history] == [8, 4]
+    json.loads(out)  # strict JSON round-trip (no NaN etc.)

@@ -360,3 +360,42 @@ def test_flash_train_step_compiles_on_four_chips(fsdp_mode, topo, compiled_kerne
         .as_text()
     )
     assert eval_hlo.count("tpu_custom_call") >= 1
+
+
+def test_shard_map_fsdp_gathers_overlap_compute_on_four_chips(topo):
+    """The other half of the ZeRO-3 overlap claim (parallel/shard_map_fsdp.py;
+    tests/test_shard_map_fsdp.py pins the dataflow half on the CPU mesh): in
+    the text the chip's compiler emits for the shard_map FSDP step, every
+    gather-bearing scan body, forward and backward, has at least one
+    all-gather that is async-annotated or fused into a compute kernel. The
+    CPU backend emits synchronous all-gathers, so only this compile shows it.
+    Real-ish shapes, so the scheduler has matmuls worth hiding gathers behind,
+    and no compiler option: launch.py sets none (docs/PARALLELISM.md
+    "Overlap")."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from midgpt_tpu.config import ExperimentConfig, MeshConfig
+    from midgpt_tpu.models.gpt import GPTConfig
+    from midgpt_tpu.parallel.mesh import AXES
+    from midgpt_tpu.utils.hlo import gather_overlap_census, lower_abstract_train_step
+
+    config = ExperimentConfig(
+        rundir="", data_dir="", learning_rate=1e-3, batch_size=16,
+        warmup_steps=2, min_lr=1e-4, lr_decay_steps=10, max_steps=10,
+        beta2=0.95, weight_decay=1e-4, eval_interval=5,
+        param_dtype="float32", compute_dtype="bfloat16", g_accum_iters=1,
+        shard_model=True, fsdp_min_size=0, fsdp_mode="shard_map",
+        mesh=MeshConfig(data=1, fsdp=4, sp=1),
+        model_config=GPTConfig(
+            block_size=512, vocab_size=8192, n_layer=4, n_head=8, n_embd=512,
+            attn_impl="naive", scan_unroll=2,
+        ),
+    )
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 4, 1, 1, 1, 1), axis_names=AXES)
+    with jax.default_matmul_precision("default"):
+        hlo = lower_abstract_train_step(config, mesh=mesh).compile().as_text()
+    census = gather_overlap_census(hlo)
+    assert {b["kind"] for b in census} == {"forward", "backward"}, census
+    serialized = [b for b in census if b["annotated"] + b["fused"] == 0]
+    assert not serialized, f"scan bodies whose weight gathers all run behind compute: {serialized}"

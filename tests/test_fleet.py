@@ -3,9 +3,10 @@ tier's checksum/version/ledger discipline, the PageHandoffQueue
 bounded-retry transport it shares with disagg, and the FleetRouter's
 affinity / health-check / failover policies. Router policy runs against
 duck-typed fake replicas — the policies are pure host-side scheduling, a
-model would only slow the assertions down. The end-to-end gates (crash
+model would only slow the assertions down; two tests at the end run real
+engines (burst absorption; hit rate and re-adoption). The fault gates (crash
 parity, corrupt-spill discard, cross-tier conservation) live in
-test_chaos_serve.py and the serve_fleet bench contract."""
+test_chaos_serve.py, the cross-process ones in test_fleet_proc.py."""
 
 import types
 
@@ -465,13 +466,9 @@ def test_router_requires_greedy_and_prefix_cache():
 # -- real engines: the availability story ---------------------------------
 
 
-def test_fleet_absorbs_burst_a_single_engine_sheds():
-    """The acceptance story behind `loadgen --fleet`: under a bounded
-    admission budget (max_backlog_pages), a burst that a single engine
-    must shed fits the fleet's aggregate budget — the affinity replica
-    refuses and the request spills over to the other survivor instead of
-    bouncing to the client. The fleet then drains every admitted stream
-    with pages conserved on every replica and the spill ledger closed."""
+def _real_engines():
+    """(config, factory) of prefix-cached greedy replicas at the fleet's
+    31-page pool geometry (chaos_serve._fleet_router's)."""
     import jax
 
     from midgpt_tpu.models.gpt import GPT, GPTConfig
@@ -480,6 +477,25 @@ def test_fleet_absorbs_burst_a_single_engine_sheds():
     cfg = GPTConfig(block_size=64, vocab_size=96, n_layer=2, n_head=2,
                     n_embd=32)
     params = GPT.init(cfg, jax.random.PRNGKey(0))
+
+    def mk(**kw):
+        return ServeEngine(
+            cfg, params, max_slots=3, page_size=8, num_pages=31,
+            prefill_chunk=16, decode_chunk=4, temperature=0.0,
+            cache_dtype=jnp.float32, prefix_cache=True, **kw,
+        )
+
+    return cfg, mk
+
+
+def test_fleet_absorbs_burst_a_single_engine_sheds():
+    """The acceptance story of fleet admission: under a bounded
+    admission budget (max_backlog_pages), a burst that a single engine
+    must shed fits the fleet's aggregate budget — the affinity replica
+    refuses and the request spills over to the other survivor instead of
+    bouncing to the client. The fleet then drains every admitted stream
+    with pages conserved on every replica and the spill ledger closed."""
+    cfg, mk_engine = _real_engines()
     rng = np.random.default_rng(0)
     template = rng.integers(0, cfg.vocab_size, 8).astype(np.int32)
     burst = [
@@ -490,12 +506,7 @@ def test_fleet_absorbs_burst_a_single_engine_sheds():
     ]  # worst case ceil((12+8)/8) = 3 pages each
 
     def mk():
-        return ServeEngine(
-            cfg, params, max_slots=3, page_size=8, num_pages=31,
-            prefill_chunk=16, decode_chunk=4, temperature=0.0,
-            cache_dtype=jnp.float32, prefix_cache=True,
-            max_backlog_pages=7,  # fits 2 bursts of 3 pages, not 3
-        )
+        return mk_engine(max_backlog_pages=7)  # fits 2 bursts of 3 pages, not 3
 
     single = mk()
     admitted, shed = 0, 0
@@ -515,6 +526,53 @@ def test_fleet_absorbs_burst_a_single_engine_sheds():
     assert router.router_shed == 0
     assert len({router.finished[u].tokens.tobytes() for u in uids}) >= 1
     assert_fleet_conserved(router, "burst")
+
+
+def test_fleet_hit_rate_is_not_diluted_and_readoption_is_token_invisible():
+    """What affinity routing and the shared spill tier are FOR: a
+    template-heavy trace through one prefix-cached engine and through a
+    2-replica fleet, both taking the same mid-trace trie flush (a pressure
+    spike reclaiming every unreferenced page). Random routing would dilute
+    the fleet's trie hit rate toward 1/N of the single engine's; rendezvous
+    affinity keeps each template on the replica that holds its pages, and
+    where the single engine loses the flushed KV and re-prefills, the
+    replicas spill it to the host tier and the second half re-adopts it. So
+    the fleet's hit rate is no lower than the single engine's, pages did
+    come back from the tier, and not one token differs."""
+    cfg, mk = _real_engines()
+    rng = np.random.default_rng(1)
+    templates = [rng.integers(0, cfg.vocab_size, 24).astype(np.int32)
+                 for _ in range(2)]
+    trace = [
+        (np.concatenate([templates[i % 2],
+                         rng.integers(0, cfg.vocab_size,
+                                      int(rng.integers(3, 9))).astype(np.int32)]),
+         int(rng.integers(8, 13)))
+        for i in range(8)
+    ]
+    half = len(trace) // 2
+
+    def two_halves(target, replicas):
+        uids = [target.submit(p, m) for p, m in trace[:half]]
+        target.run()
+        for rep in replicas:
+            rep._evict_shared_prefix_fault()  # the shared mid-trace flush
+        uids += [target.submit(p, m) for p, m in trace[half:]]
+        target.run()
+        return [np.asarray(target.finished[u].tokens) for u in uids]
+
+    single = mk()
+    single_tokens = two_halves(single, [single])
+    router = FleetRouter([mk(), mk()])
+    fleet_tokens = two_halves(router, router.engines)
+
+    for i, (a, b) in enumerate(zip(single_tokens, fleet_tokens)):
+        np.testing.assert_array_equal(a, b, err_msg=f"request {i}")
+    assert sum(e.spill_readopted_pages for e in router.engines) >= 1
+    single_hit = single.prefix_stats()["hit_rate"]
+    assert single_hit > 0.0
+    assert router.prefix_hit_rate() >= single_hit, (router.prefix_hit_rate(), single_hit)
+    assert_fleet_conserved(router, "after the two halves")
 
 
 def test_blocks_crc_is_order_and_content_sensitive():

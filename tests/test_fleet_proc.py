@@ -393,6 +393,7 @@ def test_proc_kill9_failover_representative():
     assert s["pages_conserved"] is True
     assert s["router_compiles_delta"] == 0
     assert s["transport"]["rpc_count"] > 0
+    assert s["transport"]["wire_bytes"] >= 1  # frames did cross the socket
 
 
 @pytest.mark.slow

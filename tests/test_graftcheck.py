@@ -15,7 +15,11 @@ import sys
 
 import pytest
 
-from midgpt_tpu.analysis.bench_contract import check_bench_stdout
+from midgpt_tpu.analysis.bench_contract import (
+    check_bench_stdout,
+    check_graftcheck,
+    parse_single_json_line,
+)
 from midgpt_tpu.analysis.concurrency import concurrency_source
 from midgpt_tpu.analysis.jit_surface import diff_surface, jit_surface
 from midgpt_tpu.analysis.lifecycle import lifecycle_source
@@ -329,7 +333,7 @@ class Engine:
     )
     assert [(f.rule, f.line) for f in active] == [("GC012", 5)]
     # the SAME source outside injectable-clock territory never flags
-    for path in ("midgpt_tpu/training/train.py", "tools/loadgen.py"):
+    for path in ("midgpt_tpu/training/train.py", "tools/chaos_run.py"):
         active, _ = check_source(src, path)
         assert active == [], path
 
@@ -966,3 +970,71 @@ def test_cli_json_reports_pass3_stats(tmp_path):
     assert not problems, problems
     assert rec["pass3_count"] == 0 and rec["pass3_suppressed"] == 0
     assert rec["pass3_wall_ms"] >= 0
+
+
+def test_graftcheck_cli_emits_conformant_json_line(tmp_path):
+    """tools/graftcheck.py (the path-setup wrapper) --json: its line must
+    satisfy the graftcheck profile, including the pass-3/pass-4 stats
+    fields and the jit-surface census count."""
+    p = tmp_path / "clean.py"
+    p.write_text("import jax\n\n@jax.jit\ndef f(x):\n    return x + 1\n")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(repo, "tools", "graftcheck.py"), "--json", str(p)],
+        capture_output=True,
+        text=True,
+        cwd=str(tmp_path),  # the wrapper, not the cwd, must find the package
+        timeout=120,
+    )
+    assert proc.returncode == 0, proc.stderr
+    rec, problems = check_bench_stdout(proc.stdout, "graftcheck")
+    assert not problems, problems
+    assert rec["tool"] == "graftcheck"
+    assert rec["count"] == 0 and rec["files_scanned"] == 1
+    assert rec["pass3_count"] == 0 and rec["pass3_wall_ms"] >= 0
+    assert rec["pass4_count"] == 0 and rec["pass4_wall_ms"] >= 0
+    assert rec["jit_surface_count"] == 1  # the @jax.jit wrapper above
+
+
+def test_checker_rejects_multiline_and_nonjson():
+    rec, problems = parse_single_json_line('{"a": 1}\nextra line\n')
+    assert any("exactly 1" in p for p in problems)
+    rec, problems = parse_single_json_line("not json at all\n")
+    assert rec is None and any("not valid JSON" in p for p in problems)
+
+
+def test_checker_rejects_nan():
+    """json.dumps happily emits bare NaN — which no strict consumer parses.
+    The checker must treat it as a contract violation, not a number."""
+    line = json.dumps({"metric": "m", "value": float("nan")}) + "\n"
+    rec, problems = parse_single_json_line(line)
+    assert rec is None and any("NaN" in p or "non-finite" in p for p in problems)
+
+
+def test_graftcheck_checker_catches_pass4_field_drift():
+    """The graftcheck profile holds on a synthetic record without running
+    the CLI: dropping or mistyping any pass-4 / jit-surface stat field is
+    a contract violation, not a number."""
+    good = {
+        "tool": "graftcheck", "count": 0, "suppressed": 0,
+        "files_scanned": 1, "findings": [],
+        "pass3_count": 0, "pass3_suppressed": 0, "pass3_wall_ms": 1.0,
+        "pass4_count": 0, "pass4_suppressed": 0, "pass4_wall_ms": 1.0,
+        "jit_surface_count": 3,
+    }
+    assert check_graftcheck(good) == []
+    for field in (
+        "pass4_count",
+        "pass4_suppressed",
+        "pass4_wall_ms",
+        "jit_surface_count",
+    ):
+        missing = dict(good)
+        missing.pop(field)
+        assert any(field in p for p in check_graftcheck(missing)), field
+    wrong_type = dict(good, pass4_count="0")
+    assert any("pass4_count" in p for p in check_graftcheck(wrong_type))
+    assert any(
+        "jit_surface_count" in p
+        for p in check_graftcheck(dict(good, jit_surface_count=2.5))
+    )

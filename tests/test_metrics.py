@@ -5,9 +5,12 @@ the resume contract (reference launch.py:59-68: a relaunched run must reuse
 the id persisted in rundir/wandb_id.txt) without the dependency.
 """
 
+import importlib.util
 import json
 import os
 import types
+
+import pytest
 
 import midgpt_tpu.training.metrics as metrics
 from midgpt_tpu.config import ExperimentConfig, MeshConfig
@@ -99,3 +102,52 @@ def test_explicit_resume_id_wins(tmp_path, monkeypatch):
     logger = metrics.MetricLogger(_config(tmp_path), resume_id="explicit-id")
     logger.close()
     assert created[0].id == "explicit-id"
+
+
+# ----------------------------------------------------------------------
+# one source of truth for speed: the program's FLOP count and peaks against
+# the benchmark's own (benchmarks/ is read by path, never imported as a package)
+# ----------------------------------------------------------------------
+
+BENCHMARKS = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks")
+
+
+def _arithmetic():
+    spec = importlib.util.spec_from_file_location("arithmetic", os.path.join(BENCHMARKS, "arithmetic.py"))
+    arith = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(arith)
+    return arith
+
+
+@pytest.mark.parametrize("name", ["midgpt_124m", "midgpt_xl_1p5b"])
+def test_flops_per_token_agrees_with_the_yardstick(name):
+    """benchmarks/arithmetic.py keeps its own copy of the dense FLOP count so
+    that no PR can move a utilization by editing the program; at both
+    benchmark configurations the copy and the program give one number."""
+    with open(os.path.join(BENCHMARKS, "configs", f"{name}.json")) as f:
+        model = json.load(f)["model"]
+    mc = GPTConfig(**model)
+    arith = _arithmetic()
+    assert arith.flops_per_token(model) == metrics.flops_per_token(mc)
+    assert arith.flops_per_token(model, 256) == metrics.flops_per_token(mc, 256)
+
+
+def test_peak_tables_agree():
+    """Every device kind the benchmark has a peak for, the train loop's MFU
+    divides by the same one (substring-keyed there, exact here)."""
+    with open(os.path.join(BENCHMARKS, "peaks.json")) as f:
+        table = {k: v for k, v in json.load(f).items() if not k.startswith("_")}
+    assert table
+    for kind, peaks in table.items():
+        device = types.SimpleNamespace(device_kind=kind, platform="tpu")
+        assert metrics.device_peak_flops(device) == peaks["bf16_flops_per_s"], kind
+
+
+def test_unknown_device_kind_has_no_assumed_peak():
+    """A device_kind missing from the peaks table is an error naming the
+    device, never a default peak; the v5e the chip tool hands out is in it."""
+    v5e = types.SimpleNamespace(device_kind="TPU v5 lite", platform="tpu")
+    assert metrics.device_peak_flops(v5e) == 197e12
+    unknown = types.SimpleNamespace(device_kind="TPU v99x", platform="tpu")
+    with pytest.raises(ValueError, match="TPU v99x"):
+        metrics.device_peak_flops(unknown)

@@ -27,7 +27,7 @@ from midgpt_tpu.robustness.errors import StepHangError
 from midgpt_tpu.robustness.watchdog import StepWatchdog
 from midgpt_tpu.sampling.engine import generate
 from midgpt_tpu.sampling.scheduler import FCFSScheduler, SLOScheduler
-from midgpt_tpu.sampling.serve import ServeEngine, parse_overlap
+from midgpt_tpu.sampling.serve import ServeEngine
 
 CFG = GPTConfig(block_size=64, vocab_size=96, n_layer=2, n_head=2, n_embd=32)
 
@@ -67,20 +67,6 @@ def _assert_conserved(eng):
         f"page leak: {eng.allocator.free_count} free + {trie} trie of "
         f"{eng.allocator.num_pages - 1} allocatable"
     )
-
-
-# ----------------------------------------------------------------------
-# parse_overlap: the one CLI form both tools share
-# ----------------------------------------------------------------------
-
-
-def test_parse_overlap():
-    assert parse_overlap("off") == ("off", 1)
-    assert parse_overlap("double") == ("double", 1)
-    assert parse_overlap("group:4") == ("group", 4)
-    for bad in ("", "group", "group:", "group:0", "group:x", "triple"):
-        with pytest.raises(ValueError, match="bad overlap spec"):
-            parse_overlap(bad)
 
 
 # ----------------------------------------------------------------------

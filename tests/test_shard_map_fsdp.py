@@ -157,8 +157,8 @@ def test_zero3_gathers_schedulable_ahead_of_compute():
     layer l+1 during layer l's compute; if a refactor ever made the gathers
     depend on activations (serializing the stream), this fails. The actual
     async overlap (all-gather-start/-done split around compute) is a TPU
-    scheduler behavior — asserted against the real backend by
-    tools/check_overlap_tpu.py (an AOT compile for described TPU devices);
+    scheduler behavior — asserted against the chip's compiler by
+    tests/test_chip_compile.py (an AOT compile for described TPU devices);
     the CPU backend emits synchronous all-gathers.
 
     Also pins that unroll=2 exposes BOTH layers' gathers in one body (the

@@ -8,8 +8,7 @@ activations a single chip computes, and the paged KV pool shards on the
 head axis without crossing shards — so the token streams must be
 IDENTICAL to the single-chip engine's, across cache dtype, prefix cache,
 and self-draft speculation. Any divergence means a wrong PartitionSpec or
-a torn collective, not numerical noise (the same invariant the serve_tp
-bench profile schema-enforces, analysis/bench_contract.py).
+a torn collective, not numerical noise.
 
 Pool geometry: num_pages=29/31 here, NOT 25 — pool size is a jit
 program-key dim and tests/test_recompile_pins.py counts compiles of the

@@ -1,5 +1,5 @@
 """Chaos harness: injected faults against the REAL recovery paths, one
-JSON summary line (the driver contract bench.py established).
+JSON summary line (schema: midgpt_tpu/analysis/bench_contract.py).
 
 Training mode (PR 3) — a supervised run through the real rollback/retry/
 verification machinery:
