@@ -12,6 +12,7 @@ from __future__ import annotations
 import dataclasses
 import importlib
 import json
+import math
 import typing as tp
 
 from midgpt_tpu.models.gpt import GPTConfig
@@ -75,10 +76,15 @@ class ExperimentConfig:
     # False forces storing the bf16 chunk logits (faster at single-chip
     # scales), True forces recompute for memory-tight shapes.
     loss_remat_chunks: tp.Optional[bool] = None
-    # FSDP collective authoring: 'gspmd' = sharding constraints, compiler
-    # chooses collectives (reference parity); 'shard_map' = explicit per-layer
-    # all-gather / grad reduce-scatter (parallel/shard_map_fsdp.py).
-    fsdp_mode: str = "gspmd"
+    # Which collective schedule the FSDP step compiles to. 'auto' (default):
+    # derived from the mesh the run was given and the model it trains
+    # (`fsdp_schedule` below) — the authored ZeRO-3 schedule wherever it
+    # composes (explicit per-layer all-gather / grad reduce-scatter,
+    # parallel/shard_map_fsdp.py), else the compiler's (sharding constraints
+    # in, GSPMD-chosen collectives out: reference parity). 'shard_map' and
+    # 'gspmd' force one side: the handle the parity tests compare the two
+    # lowerings with, not a tuning knob.
+    fsdp_mode: str = "auto"
     # MoE router load-balance auxiliary loss (Switch Transformer eq. 4-6):
     # training loss becomes CE + moe_aux_coef * aux, with aux the
     # layer-mean of E * sum_e P_e * f_e (models/gpt.py _moe_gates). 0.0
@@ -181,13 +187,20 @@ class ExperimentConfig:
             # grad-norm health check cannot see (its soundness induction
             # assumes the chain maps finite state+grads to finite updates).
             raise ValueError(f"beta2={self.beta2} must be in (0, 1)")
-        if self.fsdp_mode not in ("gspmd", "shard_map"):
-            # A typo would silently run the GSPMD dispatch (train.py
-            # branches on == 'shard_map' else gspmd) — fail at construction
-            # like qkv_proj/rope_style.
+        if self.fsdp_mode not in ("auto", "gspmd", "shard_map"):
+            # A typo would silently run the derived schedule — fail at
+            # construction like qkv_proj/rope_style.
             raise ValueError(
-                f"unknown fsdp_mode {self.fsdp_mode!r} ('gspmd' or 'shard_map')"
+                f"unknown fsdp_mode {self.fsdp_mode!r} ('auto', 'gspmd' or 'shard_map')"
             )
+        if self.fsdp_mode == "shard_map":
+            # Forcing the authored schedule where it does not compose fails
+            # loudly; left to 'auto', the same combinations fall back to the
+            # compiler's schedule (fsdp_schedule).
+            axes = {a: max(getattr(self.mesh, a), 1) for a in ("sp", "tp", "pp", "ep")}
+            why = self.authored_fsdp_refusal(axes)
+            if why is not None:
+                raise ValueError(f"fsdp_mode='shard_map' {why}")
         if not 0 <= self.spec_layers < mc.n_layer:
             # spec_layers == n_layer would "draft" with the target itself —
             # all cost, no amortization — and deeper is shape-invalid.
@@ -314,16 +327,6 @@ class ExperimentConfig:
                     f"vocab_size={mc.vocab_size} not divisible by mesh.tp={tp} "
                     "(set tp_vocab=False or pad the vocab)"
                 )
-            if self.fsdp_mode == "shard_map":
-                # r5: the explicit ZeRO-3 body composes with tp (auto-axis
-                # GSPMD inside, parallel/shard_map_fsdp.py) — but not yet
-                # together with its sequence-parallel schedules.
-                if self.mesh.sp not in (1, -1) or mc.attn_impl in ("ring", "ulysses"):
-                    raise ValueError(
-                        "fsdp_mode='shard_map' with mesh.tp > 1 does not "
-                        "compose with sequence parallelism yet (set sp=1 "
-                        "and a non-ring/ulysses attn_impl)"
-                    )
         pp = self.mesh.pp
         if pp == -1:
             pp = 1
@@ -351,8 +354,6 @@ class ExperimentConfig:
                 raise ValueError(f"n_layer={mc.n_layer} not divisible by mesh.pp={pp}")
             if mc.dropout != 0.0:
                 raise ValueError("mesh.pp > 1 requires dropout=0.0")
-            if self.fsdp_mode != "gspmd":
-                raise ValueError("mesh.pp > 1 requires fsdp_mode='gspmd'")
             if self.mesh.sp not in (1, -1):
                 raise ValueError(
                     "mesh.pp > 1 does not compose with mesh.sp > 1 yet "
@@ -375,14 +376,15 @@ class ExperimentConfig:
                     f"moe_aux_coef={self.moe_aux_coef} needs a routed MLP "
                     "(n_experts > 0)"
                 )
-            if self.fsdp_mode != "gspmd" or self.mesh.pp not in (1, -1):
+            if self.mesh.pp not in (1, -1):
                 # The aux term threads through GPT.hidden(return_moe_aux=True),
-                # which only the implicit-GSPMD loss calls; the shard_map and
-                # pipeline bodies have their own layer loops. Fail loudly
-                # instead of silently training without balance pressure.
+                # which only the implicit-GSPMD loss calls (the authored
+                # ZeRO-3 loss is refused in authored_fsdp_refusal); the
+                # pipeline body has its own layer loop. Fail loudly instead
+                # of silently training without balance pressure.
                 raise ValueError(
-                    "moe_aux_coef requires fsdp_mode='gspmd' and mesh.pp == 1 "
-                    "(the aux term is only folded into the implicit-GSPMD loss)"
+                    "moe_aux_coef requires mesh.pp == 1 (the aux term is only "
+                    "folded into the implicit-GSPMD loss)"
                 )
         ep = self.mesh.ep
         if ep == -1:
@@ -404,8 +406,6 @@ class ExperimentConfig:
                 raise ValueError(
                     f"mesh.ep={ep} needs n_experts ({mc.n_experts}) divisible by it"
                 )
-            if self.fsdp_mode != "gspmd":
-                raise ValueError("mesh.ep > 1 requires fsdp_mode='gspmd'")
         sp = self.mesh.sp
         if sp == -1:
             sp = 1
@@ -417,6 +417,57 @@ class ExperimentConfig:
                     f"attn_impl='ulysses' needs n_head % (tp*sp) == 0, got "
                     f"n_head={mc.n_head}, tp={tp}, sp={sp}"
                 )
+
+    def authored_fsdp_refusal(self, axes: tp.Mapping[str, int]) -> tp.Optional[str]:
+        """Why the authored ZeRO-3 loss (parallel/shard_map_fsdp.py) does not
+        compose with this config on a mesh whose axis sizes are `axes`
+        (`Mesh.shape`, or the configured sizes with -1 read as 1), or None
+        where it does. The one statement of the rule: forcing
+        fsdp_mode='shard_map' raises with it, 'auto' falls back on it."""
+        mc = self.model_config
+        if not isinstance(mc, GPTConfig):
+            return (
+                "is written over the GPT's layer scan (GPT.hidden "
+                "layer_transform); this model family trains under the "
+                "compiler's schedule (gspmd)"
+            )
+        if axes["pp"] > 1:
+            return (
+                "does not compose with mesh.pp > 1 (the pipeline's stages "
+                "gather their own weights; it requires gspmd)"
+            )
+        if axes["ep"] > 1:
+            return "does not compose with mesh.ep > 1 (expert parallelism requires gspmd)"
+        if self.moe_aux_coef != 0.0:
+            return (
+                "does not compose with moe_aux_coef (the aux term is only "
+                "folded into the implicit-GSPMD loss: gspmd)"
+            )
+        if axes["tp"] > 1 and (axes["sp"] > 1 or mc.attn_impl in ("ring", "ulysses")):
+            # r5: the explicit ZeRO-3 body composes with tp (auto-axis GSPMD
+            # inside, parallel/shard_map_fsdp.py) — but not yet together
+            # with its sequence-parallel schedules.
+            return (
+                "with mesh.tp > 1 does not compose with sequence parallelism "
+                "yet (set sp=1 and a non-ring/ulysses attn_impl)"
+            )
+        return None
+
+    def fsdp_schedule(self, mesh_shape: tp.Mapping[str, int]) -> str:
+        """The collective schedule the FSDP step compiles to on a mesh of this
+        shape ({axis: size}, as `Mesh.shape` gives it): 'authored' (per-layer
+        weight all-gathers whose transpose is the per-layer gradient
+        reduce-scatter, parallel/shard_map_fsdp.py) or 'compiler' (the
+        implicit-GSPMD loss). One algorithm, ZeRO-3, two ways to lower it,
+        chosen by what the program can observe: authored wherever it composes
+        on more than one device; the compiler's everywhere else (pipeline,
+        expert parallel, the MoE aux loss, another model family) and on EVERY
+        one-device mesh, whose step program has no collective to author."""
+        if self.fsdp_mode != "auto":
+            return "authored" if self.fsdp_mode == "shard_map" else "compiler"
+        if math.prod(mesh_shape.values()) > 1 and self.authored_fsdp_refusal(mesh_shape) is None:
+            return "authored"
+        return "compiler"
 
     def replace(self, **kw) -> "ExperimentConfig":
         return dataclasses.replace(self, **kw)

@@ -3,7 +3,12 @@
 The GSPMD path (parallel/fsdp.py) matches the reference's approach — sharding
 constraints in, compiler-chosen collectives out (reference model.py:167-178,
 train.py:87). This module is the TPU-first redesign: the FSDP schedule is
-*written down* instead of inferred.
+*written down* instead of inferred. It is the schedule a GPT takes on every
+multi-device mesh it composes with (ExperimentConfig.fsdp_schedule derives
+it; the compiler's is the fall-back), because on the chip the compiler's
+lowering of the same algorithm all-reduces whole gradients, moves
+activations through all-to-alls and leaves 39 % of the XL step to exposed
+collectives (PERF.md section 6, PR 29).
 
   * Params enter `jax.shard_map` still sharded (in_specs = their FSDP specs).
   * The embedding and lm_head are all-gathered once per step.
@@ -32,8 +37,17 @@ Gather/compute overlap is pinned, not assumed (r5):
     are async (annotated async_collective_name="all-gather-start") or
     continuation-FUSED into the block matmul kernels (gather windows
     streamed inside the dots). On jax 0.9.0 / libtpu 0.0.34 it holds with
-    no compiler option set (docs/PARALLELISM.md "Overlap"). What it is
-    worth in step time is not measured: no cell runs this mode.
+    no compiler option set (docs/PARALLELISM.md "Overlap", with what the
+    four-chip cell measures: the gathers are hidden, the synchronous
+    reduce-scatters are what stays exposed).
+
+Precision of the cross-chip gradient sum: the transpose of a tiled bf16
+`all_gather` is a bf16 `psum_scatter` over bf16-rounded per-chip partials.
+That is the dtype the compiler's schedule carries its own weight-sized
+gradient all-reduces in at the four-chip cell's shapes (bf16, on bf16
+partials: tests/test_chip_compile.py pins both programs' text), so the two
+lowerings sum the same partials in the same precision; the G microsteps'
+gradients are accumulated in float32 after it, as before.
 
 Numerical parity with the GSPMD path is asserted in
 tests/test_shard_map_fsdp.py (same loss and same grads to fp32 tolerance on

@@ -141,12 +141,12 @@ def make_train_step(
         and mesh.devices.size > 1
         and mesh.shape["pp"] == 1
     ):
-        # The implicit-GSPMD forward on more than one device — the gspmd
-        # train loss, and the EVAL path under either fsdp_mode: the compiler
-        # cannot partition a Mosaic kernel, so the flash call is mapped over
-        # the batch axes by hand (the explicit shard_map loss and the
-        # pipeline already run the kernel inside their own per-device
-        # bodies and never read this attn_fn).
+        # The implicit-GSPMD forward on more than one device — the train loss
+        # under the compiler's schedule, and the EVAL path under either
+        # schedule: the compiler cannot partition a Mosaic kernel, so the
+        # flash call is mapped over the batch axes by hand (the authored
+        # shard_map loss and the pipeline already run the kernel inside
+        # their own per-device bodies and never read this attn_fn).
         from midgpt_tpu.ops.attention import flash_attention_sharded
 
         attn_fn = functools.partial(
@@ -180,7 +180,9 @@ def make_train_step(
         def loss_fn(params_c: GPTParams, x: Array, y: Array, key) -> Array:
             return _pp_loss(params_c, x, y, key)
 
-    elif config.fsdp_mode == "shard_map":
+    elif config.fsdp_schedule(mesh.shape) == "authored":
+        # Derived from the mesh and the model, not asked of the user
+        # (ExperimentConfig.fsdp_schedule): never on a one-device mesh.
         from midgpt_tpu.parallel.shard_map_fsdp import make_shard_map_loss
 
         _sm_loss = make_shard_map_loss(
@@ -473,6 +475,12 @@ class TrainRuntime:
     # shardings; the key and the loss carrier concrete): what
     # `step_program_text` lowers with.
     step_avals: tp.Tuple = ()
+    # The collective schedule the step program took on this mesh: 'authored'
+    # (parallel/shard_map_fsdp.py) or 'compiler' (implicit GSPMD) —
+    # ExperimentConfig.fsdp_schedule(mesh.shape), the branch make_train_step
+    # picked the loss by. Logged by make_runtime; the flight recorder's gauge
+    # `fsdp.schedule_authored` is 1 / 0 for it.
+    fsdp_schedule: str = "compiler"
     # Jitted (params, x (B, T)) -> {counter name: scalar} of the model's own
     # counters (models/kimi_linear.py route_stats: moe.*), or None for a model
     # that has none. Forward only, one microbatch, off the step program.
@@ -554,6 +562,15 @@ def make_runtime(
     step, eval_loss, eval_loss_many = make_train_step(
         config, optimizer, mesh, param_specs
     )
+    fsdp_schedule = config.fsdp_schedule(mesh.shape)
+    flight_recorder().metrics.gauge("fsdp.schedule_authored").set(
+        float(fsdp_schedule == "authored")
+    )
+    if jax.process_index() == 0:
+        print(
+            f"fsdp schedule: {fsdp_schedule} (fsdp_mode={config.fsdp_mode!r}) "
+            f"on mesh {dict(mesh.shape)}"
+        )
     global _LAST_RUNTIME
     abstract_params, abstract_opt = _abstract_like(params), _abstract_like(opt_state)
     model = config.model_config.model()
@@ -588,6 +605,7 @@ def make_runtime(
         abstract_state={"params": abstract_params, "opt_state": abstract_opt},
         finite_check=jax.jit(_all_finite),
         n_params=model.count_params(params),
+        fsdp_schedule=fsdp_schedule,
         model_stats=model_stats,
         _initial=(params, opt_state),
         step_avals=(

@@ -9,6 +9,7 @@ parser and one abstract-lowering scaffold so the pins can't drift apart.
 
 from __future__ import annotations
 
+import math
 import re
 import typing as tp
 
@@ -110,6 +111,35 @@ def gather_overlap_census(txt: str) -> tp.List[tp.Dict[str, tp.Any]]:
                 "fused": fused,
             }
         )
+    return census
+
+
+_COLLECTIVE_RE = re.compile(
+    r"= (\(?[a-z]+\d*\[.*?) "
+    r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)(?:-start)?\("
+)
+_ARRAY_TYPE_RE = re.compile(r"([a-z]+\d*)\[([\d,]*)\]")
+
+
+def collective_census(txt: str) -> tp.List[tp.Tuple[str, str, int]]:
+    """(op, dtype, elements) of every cross-device collective instruction in
+    compiled HLO text; a tuple-typed collective counts as its largest member.
+    What tells the two FSDP schedules apart on the chip's compiler
+    (tests/test_chip_compile.py): the authored one reduce-scatters weight-
+    sized gradients and holds no all-to-all; the compiler's all-reduces them
+    and moves activations through all-to-alls. The dtype is the precision the
+    cross-chip gradient sum is carried in."""
+    census = []
+    for line in txt.splitlines():
+        m = _COLLECTIVE_RE.search(line)
+        if m is None:
+            continue
+        members = [
+            (dtype, math.prod(int(d) for d in dims.split(",") if d))
+            for dtype, dims in _ARRAY_TYPE_RE.findall(m.group(1))
+        ]
+        dtype, elements = max(members, key=lambda de: de[1])
+        census.append((m.group(2), dtype, elements))
     return census
 
 

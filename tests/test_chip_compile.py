@@ -362,6 +362,56 @@ def test_flash_train_step_compiles_on_four_chips(fsdp_mode, topo, compiled_kerne
     assert eval_hlo.count("tpu_custom_call") >= 1
 
 
+@pytest.mark.parametrize("schedule", ["authored", "compiler"])
+def test_fsdp_schedule_collective_census_at_xl_widths(schedule, topo, compiled_kernels, monkeypatch):
+    """`train_xl_fsdp4`'s step at its widths (D=2048, 16 heads of 128, V=50304,
+    T=1024, 2 sequences a chip x G=2, bf16 over f32, remat 'dots', flash) and
+    reduced depth, compiled for the 2x2 v5e under each collective schedule.
+
+    authored (what a config that says nothing derives on this mesh): no
+    all-to-all, the weight-sized gradients reduce-SCATTERED, no weight-sized
+    all-reduce left. compiler (forced by name; the parent of PR 29): no
+    reduce-scatter at all; the gradients it sums across chips it all-reduces.
+
+    The precision rule (ISSUE 29): the cross-chip gradient sum is carried in
+    the dtype the compiler's schedule carries it in. At these shapes its
+    weight-sized gradient all-reduces run in bfloat16 on bf16-rounded
+    per-chip partials (wqkv 3x2048x2048 and a 50304x2048 vocabulary matrix),
+    so the authored reduce-scatter, the transpose of a tiled bf16 all_gather,
+    sums the same bf16 partials in the same dtype. Both sides pin `bf16`: a
+    toolchain that moves either one fails here, not in a loss curve."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from midgpt_tpu.config import MeshConfig, load_config
+    from midgpt_tpu.parallel.mesh import AXES
+    from midgpt_tpu.utils.hlo import collective_census, lower_abstract_train_step
+
+    monkeypatch.setattr(fa, "RUN_INTERPRET_OFF_TPU", True)
+    config = load_config("openwebtext_xl")
+    config = config.replace(
+        batch_size=8, g_accum_iters=2, mesh=MeshConfig(data=-1, fsdp=4, sp=1),
+        model_config=dataclasses.replace(config.model_config, n_layer=2),
+        **({"fsdp_mode": "gspmd"} if schedule == "compiler" else {}),
+    )
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 4, 1, 1, 1, 1), axis_names=AXES)
+    assert config.fsdp_schedule(mesh.shape) == schedule
+    census = collective_census(lower_abstract_train_step(config, mesh=mesh).compile().as_text())
+    weight_sized = [c for c in census if c[2] >= config.fsdp_min_size]
+    summed = {"authored": "reduce-scatter", "compiler": "all-reduce"}[schedule]
+    other = {"authored": "all-reduce", "compiler": "reduce-scatter"}[schedule]
+    assert {dtype for op, dtype, _ in weight_sized if op == summed} == {"bf16"}, census
+    assert not [c for c in weight_sized if c[0] == other], census
+    assert {dtype for op, dtype, _ in weight_sized if op == "all-gather"} == {"bf16"}, census
+    if schedule == "authored":
+        assert not [c for c in census if c[0] == "all-to-all"], census
+        # every leaf of the blocks (wqkv, wo, w_up, w_down) and both
+        # vocabulary matrices: six scatters, not one per microstep or layer
+        assert sum(op == "reduce-scatter" for op, _, _ in weight_sized) == 6, census
+
+
 def test_shard_map_fsdp_gathers_overlap_compute_on_four_chips(topo):
     """The other half of the ZeRO-3 overlap claim (parallel/shard_map_fsdp.py;
     tests/test_shard_map_fsdp.py pins the dataflow half on the CPU mesh): in

@@ -15,7 +15,7 @@ from midgpt_tpu.analysis.hlo_audit import (
     jit_cache_size,
     while_body_collectives,
 )
-from midgpt_tpu.utils.hlo import hlo_computations, while_body_names
+from midgpt_tpu.utils.hlo import collective_census, hlo_computations, while_body_names
 
 # Shaped like a post-optimization dump: layout annotations and a nested-brace
 # constant inside instruction lines, an indented closing brace, and a while
@@ -83,6 +83,28 @@ def test_while_body_names_and_census():
     with pytest.raises(AssertionError, match="all-gather"):
         assert_no_while_body_collectives(SAMPLE_HLO)
     assert_no_while_body_collectives(SAMPLE_HLO, ops=("all-to-all",))
+
+
+def test_collective_census_reads_op_dtype_and_size_as_the_v5e_spells_them():
+    """Lines copied from the two FSDP step programs compiled for a v5e 2x2:
+    the authored reduce-scatter keeps its jax primitive's name, a tuple-typed
+    all-reduce counts as its largest member, async starts count, and ops that
+    merely consume a collective's result do not."""
+    txt = """
+  %reduce_scatter.93 = bf16[2048,2048]{1,0:T(8,128)(2,1)S(1)} reduce-scatter(%get-tuple-element.2215), channel_id=1, dimensions={1}
+  %all-reduce.42 = bf16[6144,2048]{1,0:T(8,128)(2,1)} all-reduce(%input.5), channel_id=73, to_apply=%add.1.clone
+  %all-reduce.35 = (f32[]{:T(128)}, f32[]{:T(128)}) all-reduce(%add.533, %multiply_reduce_fusion.10), channel_id=70
+  %all-to-all.60 = bf16[4,2,1024,2048]{2,3,1,0:T(8,128)(2,1)S(1)} all-to-all(%copy.611), channel_id=12, dimensions={0}
+  %all-gather-start.1 = (bf16[3,2048,512]{2,1,0}, bf16[3,2048,2048]{2,1,0}) all-gather-start(%p.1), dimensions={2}
+  %fusion.7 = bf16[2048,2048]{1,0} fusion(%reduce_scatter.93), kind=kLoop, calls=%fused_computation.7
+"""
+    assert collective_census(txt) == [
+        ("reduce-scatter", "bf16", 2048 * 2048),
+        ("all-reduce", "bf16", 6144 * 2048),
+        ("all-reduce", "f32", 1),
+        ("all-to-all", "bf16", 4 * 2 * 1024 * 2048),
+        ("all-gather", "bf16", 3 * 2048 * 2048),
+    ]
 
 
 def test_entry_parameter_dtypes_and_fp32_audit():

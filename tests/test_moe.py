@@ -211,6 +211,10 @@ def test_moe_aux_coef_zero_impact_when_disabled():
         eval_interval=5, beta2=0.95, weight_decay=0.0,
         param_dtype="float32", compute_dtype="float32", g_accum_iters=1,
         shard_model=True, fsdp_min_size=0,
+        # the pin is on the COMPILER's schedule: the aux term exists only in
+        # the implicit-GSPMD loss, and 'auto' would hand the aux-free side to
+        # the authored schedule on this mesh (two different programs)
+        fsdp_mode="gspmd",
         mesh=MeshConfig(data=2, fsdp=4, sp=1), model_config=MOE4,
     )
     rng = np.random.default_rng(3)

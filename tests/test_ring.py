@@ -116,6 +116,9 @@ def test_ring_train_step_matches_naive_sp1():
         g_accum_iters=1,
         shard_model=True,
         fsdp_min_size=0,
+        # the GSPMD-bound ring (ring_attention_sharded); the authored
+        # schedule x ring is tests/test_shard_map_fsdp.py's
+        fsdp_mode="gspmd",
         mesh=MeshConfig(data=2, fsdp=2, sp=2),
         model_config=GPTConfig(
             block_size=64, vocab_size=128, n_layer=2, n_head=2, n_embd=32,

@@ -285,8 +285,10 @@ def test_train_step_shard_map_tp_matches_gspmd():
     losses = {}
     for name, cfg in {
         "shard_map_tp": base.replace(fsdp_mode="shard_map"),
-        "gspmd_tp": base,
-        "fsdp_only": base.replace(mesh=MeshConfig(data=2, fsdp=4, sp=1)),
+        "gspmd_tp": base.replace(fsdp_mode="gspmd"),
+        "fsdp_only": base.replace(
+            fsdp_mode="gspmd", mesh=MeshConfig(data=2, fsdp=4, sp=1)
+        ),
     }.items():
         mesh = make_mesh(cfg.mesh)
         params, opt_state, specs, optimizer = init_state(cfg, mesh)
@@ -400,3 +402,224 @@ def test_train_step_shard_map_ring_matches_gspmd_sp1():
     np.testing.assert_allclose(
         losses["shard_map_ring"], losses["gspmd_naive_sp1"], rtol=1e-5
     )
+
+
+# ---- the derived schedule (ExperimentConfig.fsdp_schedule) ----
+
+_BASE = dict(
+    rundir="", data_dir="", learning_rate=1e-3, batch_size=8, warmup_steps=2,
+    min_lr=1e-4, lr_decay_steps=10, max_steps=10, beta2=0.95,
+    weight_decay=1e-4, eval_interval=5, param_dtype="float32",
+    compute_dtype="float32", g_accum_iters=1, shard_model=True, fsdp_min_size=0,
+)
+_TINY = GPTConfig(block_size=32, vocab_size=64, n_layer=2, n_head=2, n_embd=32)
+_MOE = GPTConfig(
+    block_size=32, vocab_size=64, n_layer=2, n_head=2, n_embd=32,
+    n_experts=4, moe_top_k=2,
+)
+
+
+def _shape(**axes):
+    return {a: axes.get(a, 1) for a in ("data", "fsdp", "sp", "tp", "pp", "ep")}
+
+
+def _kimi_tiny():
+    from test_kimi_linear import tiny_experiment
+
+    return tiny_experiment()
+
+
+# (case, config kwargs or a builder, mesh shape, schedule taken)
+_RULE = [
+    ("pure_fsdp", dict(model_config=_TINY), _shape(fsdp=4), "authored"),
+    ("data_x_fsdp", dict(model_config=_TINY), _shape(data=2, fsdp=4), "authored"),
+    ("data_parallel_only", dict(model_config=_TINY), _shape(data=8), "authored"),
+    ("one_device", dict(model_config=_TINY), _shape(), "compiler"),
+    ("fsdp_x_sp_ring",
+     dict(model_config=GPTConfig(block_size=32, vocab_size=64, n_layer=2, n_head=2,
+                                 n_embd=32, attn_impl="ring")),
+     _shape(fsdp=2, sp=2), "authored"),
+    ("fsdp_x_tp", dict(model_config=_TINY), _shape(fsdp=2, tp=2), "authored"),
+    ("tp_x_sp", dict(model_config=_TINY), _shape(fsdp=2, sp=2, tp=2), "compiler"),
+    ("tp_with_ring_attention",
+     dict(model_config=GPTConfig(block_size=32, vocab_size=64, n_layer=2, n_head=2,
+                                 n_embd=32, attn_impl="ring")),
+     _shape(fsdp=2, tp=2), "compiler"),
+    ("pipeline", dict(model_config=_TINY), _shape(fsdp=2, pp=2), "compiler"),
+    ("expert_parallel", dict(model_config=_MOE), _shape(fsdp=2, ep=2), "compiler"),
+    ("moe_without_aux", dict(model_config=_MOE), _shape(fsdp=4), "authored"),
+    ("moe_aux_loss", dict(model_config=_MOE, moe_aux_coef=0.01), _shape(fsdp=4), "compiler"),
+    ("kimi_linear_family", _kimi_tiny, _shape(data=8), "compiler"),
+    ("kimi_linear_one_device", _kimi_tiny, _shape(), "compiler"),
+    # the parity tests' handle forces either side, whatever the mesh
+    ("forced_gspmd", dict(model_config=_TINY, fsdp_mode="gspmd"), _shape(fsdp=4), "compiler"),
+    ("forced_shard_map_one_device", dict(model_config=_TINY, fsdp_mode="shard_map"),
+     _shape(), "authored"),
+]
+
+
+@pytest.mark.parametrize(
+    "make, mesh_shape, want", [r[1:] for r in _RULE], ids=[r[0] for r in _RULE]
+)
+def test_fsdp_schedule_is_derived_from_mesh_and_model(make, mesh_shape, want):
+    """(mesh shape, pp, ep, tp x sp, moe_aux_coef, model family) -> the
+    schedule the step takes. A configuration that does not mention fsdp_mode
+    gets the authored ZeRO-3 schedule wherever it composes on more than one
+    device, and the compiler's everywhere else: every one-device mesh, the
+    pipeline, expert parallelism, the aux loss, tp together with sequence
+    parallelism, another model family."""
+    config = make() if callable(make) else ExperimentConfig(**_BASE, **make)
+    assert config.fsdp_schedule(mesh_shape) == want
+
+
+@pytest.mark.parametrize(
+    "kw, match",
+    [
+        (dict(model_config=_TINY, mesh=MeshConfig(fsdp=2, pp=2)), "pp"),
+        (dict(model_config=_MOE, mesh=MeshConfig(fsdp=2, ep=2)), "ep"),
+        (dict(model_config=_MOE, moe_aux_coef=0.01), "moe_aux_coef"),
+        (dict(model_config=_TINY, mesh=MeshConfig(fsdp=2, sp=2, tp=2)), "sequence parallelism"),
+    ],
+    ids=["pp", "ep", "aux", "tp_x_sp"],
+)
+def test_forcing_the_authored_schedule_where_it_does_not_compose_raises(kw, match):
+    """What 'auto' falls back on, fsdp_mode='shard_map' refuses: the one
+    statement of the rule (ExperimentConfig.authored_fsdp_refusal)."""
+    ExperimentConfig(**_BASE, **kw)  # derived: constructs, takes the compiler's
+    with pytest.raises(ValueError, match=match):
+        ExperimentConfig(**_BASE, fsdp_mode="shard_map", **kw)
+
+
+def test_forcing_the_authored_schedule_on_another_family_raises():
+    config = _kimi_tiny()
+    with pytest.raises(ValueError, match="model family"):
+        config.replace(fsdp_mode="shard_map")
+
+
+def _traced_step(config, devices):
+    """jaxpr text of the train step on a mesh over `devices`, abstract state."""
+    from midgpt_tpu.training.optim import make_optimizer
+    from midgpt_tpu.training.train import make_train_step
+
+    mesh = make_mesh(config.mesh, devices=devices)
+    model = config.model_config.model()
+    abstract = jax.eval_shape(lambda k: model.init(config.model_config, k), jax.random.PRNGKey(0))
+    specs = model.param_specs(config, abstract, mesh)
+    optimizer, _ = make_optimizer(config)
+    step, *_ = make_train_step(config, optimizer, mesh, specs)
+    G, B, T = config.g_accum_iters, config.batch_size, config.model_config.block_size
+    tokens = jax.ShapeDtypeStruct((G, B, T), np.int32)
+    return str(
+        step.trace(
+            abstract, jax.eval_shape(optimizer.init, abstract), tokens, tokens,
+            jax.random.PRNGKey(0),
+        ).jaxpr
+    )
+
+
+@pytest.mark.parametrize("family", ["gpt", "kimi_linear"])
+def test_one_device_step_holds_no_shard_map_of_the_loss(family):
+    """The guard for `train_124m` and `train_kimi_linear_t8k`: on a
+    one-device mesh the derived schedule is the compiler's, so the traced
+    step is the program it was before the schedule was derived — no
+    shard_map anywhere in it (the GPT's four-device trace below has one,
+    so the probe can see it)."""
+    if family == "gpt":
+        config = ExperimentConfig(**_BASE, model_config=_TINY, mesh=MeshConfig(fsdp=1))
+    else:
+        config = _kimi_tiny()
+    assert "shard_map" not in _traced_step(config, jax.devices()[:1])
+    if family == "gpt":
+        four = config.replace(mesh=MeshConfig(fsdp=4))
+        assert "shard_map" in _traced_step(four, jax.devices()[:4])
+
+
+def test_runtime_reports_the_schedule_it_took(tmp_path, capsys):
+    """TrainRuntime.fsdp_schedule, make_runtime's one log line and the flight
+    recorder's gauge all say which loss make_train_step picked."""
+    from midgpt_tpu.obs import flight_recorder
+    from midgpt_tpu.training.train import make_runtime
+
+    rng = np.random.default_rng(0)
+    for split in ("train", "val"):
+        rng.integers(0, 64, 4096, dtype=np.uint16).tofile(tmp_path / f"{split}.bin")
+    base = ExperimentConfig(**{**_BASE, "data_dir": str(tmp_path)}, model_config=_TINY)
+    for mesh_cfg, devices, want in (
+        (MeshConfig(fsdp=4), jax.devices()[:4], "authored"),
+        (MeshConfig(fsdp=1), jax.devices()[:1], "compiler"),
+    ):
+        rt = make_runtime(base.replace(mesh=mesh_cfg), devices=devices)
+        assert rt.fsdp_schedule == want
+        assert f"fsdp schedule: {want}" in capsys.readouterr().out
+        gauges = flight_recorder().metrics.snapshot()["gauges"]
+        assert gauges["fsdp.schedule_authored"] == float(want == "authored")
+
+
+# ---- parity at the four-chip cell's numerics ----
+
+
+def _sgd_step_grads(config, x, y):
+    """(loss, f32 gradient tree) of ONE real train step, read back through
+    plain SGD at rate 1: new = old - grad."""
+    import optax
+
+    from midgpt_tpu.training.train import init_state, make_train_step
+
+    mesh = make_mesh(config.mesh, devices=jax.devices()[:4])
+    params, _, specs, _ = init_state(config, mesh)
+    optimizer = optax.sgd(1.0)
+    step, *_ = make_train_step(config, optimizer, mesh, specs)
+    before = jax.tree.map(np.asarray, params)
+    xg = make_global_batch(x, mesh, batch_spec())
+    yg = make_global_batch(y, mesh, batch_spec())
+    after, _, loss = step(params, optimizer.init(params), xg, yg, jax.random.PRNGKey(0))
+    grads = jax.tree.map(lambda b, a: b - np.asarray(a), before, after)
+    return float(loss), grads
+
+
+def test_schedules_agree_at_the_cell_numerics_bf16_dots_remat_g2():
+    """`train_xl_fsdp4`'s numerics at a toy size: bf16 compute over f32 master
+    weights, remat_policy='dots', G=2 accumulated in f32, fsdp=4. Loss and f32
+    gradients of the authored schedule against the compiler's, both read off
+    the REAL step (make_train_step), plus each against the float32-compute
+    gradient of the same step.
+
+    Where the tolerance comes from: the f32 case above holds the two
+    schedules to rtol 1e-4 + atol 1e-5, about 1.7e3 float32 epsilons; that
+    many bfloat16 epsilons (2^-8) would be 6.5 and say nothing. So the bound
+    is relative to what bf16 itself costs: per leaf, the two schedules may
+    differ (Frobenius norm, relative) by no more than 1.5 times the larger of
+    their own distances to the float32-compute gradient — the choice of
+    schedule stays inside the rounding noise the precision already has — and
+    by no more than 16 bf16 epsilons (6.25e-2) outright. Readings on the CPU
+    mesh (a correctness check): schedules apart 0.9e-2 to 2.5e-2 a leaf, each
+    0.9e-2 to 2.5e-2 from float32 (two independent roundings of one
+    gradient); losses apart 5.3e-5 relative. The authored schedule sums four bf16-rounded
+    per-chip partials in a bf16 reduce-scatter, what the compiler's own
+    gradient all-reduces do at the cell's shapes (PERF.md section 6, PR 29)."""
+    rng = np.random.default_rng(3)
+    cfg = dict(
+        **{**_BASE, "g_accum_iters": 2, "compute_dtype": "bfloat16"},
+        mesh=MeshConfig(data=1, fsdp=4),
+        model_config=GPTConfig(
+            block_size=64, vocab_size=256, n_layer=2, n_head=2, n_embd=64,
+            remat=True, remat_policy="dots",
+        ),
+    )
+    x = rng.integers(0, 256, (2, 8, 64), dtype=np.int32)
+    y = np.roll(x, -1, axis=-1)
+    loss_c, g_c = _sgd_step_grads(ExperimentConfig(fsdp_mode="gspmd", **cfg), x, y)
+    loss_a, g_a = _sgd_step_grads(ExperimentConfig(fsdp_mode="shard_map", **cfg), x, y)
+    _, g_f = _sgd_step_grads(
+        ExperimentConfig(fsdp_mode="gspmd", **{**cfg, "compute_dtype": "float32"}), x, y
+    )
+    assert abs(loss_a - loss_c) <= 2 ** -8 * abs(loss_c)
+
+    def rel(a, b):
+        return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+    paths = [jax.tree_util.keystr(p) for p, _ in jax.tree_util.tree_leaves_with_path(g_c)]
+    for path, c, a, f in zip(paths, *(jax.tree.leaves(g) for g in (g_c, g_a, g_f))):
+        assert c.dtype == a.dtype == np.float32
+        apart, noise = rel(a, c), max(rel(c, f), rel(a, f))
+        assert apart <= min(1.5 * noise, 16 * 2 ** -8), (path, apart, noise)

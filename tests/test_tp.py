@@ -178,6 +178,7 @@ def test_tp_train_step_matches_fsdp_only(data_dir):
         shard_model=True,
         eval_steps=2,
         fsdp_min_size=0,
+        fsdp_mode="gspmd",  # the compiler's Megatron schedule is what this file tests (authored x tp: test_shard_map_fsdp.py)
         model_config=CFG,
     )
     ref = ExperimentConfig(mesh=MeshConfig(data=2, fsdp=4, sp=1), **base)
@@ -214,6 +215,7 @@ def test_tp_ring_sp_composition_matches_fsdp_only(data_dir):
         shard_model=True,
         eval_steps=2,
         fsdp_min_size=0,
+        fsdp_mode="gspmd",  # as above: the compiler's schedule on both legs
     )
     ref = ExperimentConfig(
         mesh=MeshConfig(data=2, fsdp=4, sp=1), model_config=CFG, **base

@@ -202,13 +202,13 @@ def test_obs_off_reads_the_clock_as_before_and_emits_the_same_tokens(params, num
 # ---------------------------------------------------------------------------
 
 
-def _lower_step(tmp_path):
+def _lower_step(tmp_path, fsdp_mode):
     from midgpt_tpu.parallel.data import make_global_batch
     from midgpt_tpu.parallel.mesh import batch_spec, make_mesh
     from midgpt_tpu.training.train import init_state, make_train_step
     from test_train import tiny_config
 
-    cfg = tiny_config(tmp_path, g_accum_iters=2, compute_dtype="bfloat16")
+    cfg = tiny_config(tmp_path, g_accum_iters=2, compute_dtype="bfloat16", fsdp_mode=fsdp_mode)
     mesh = make_mesh(cfg.mesh)
     params, opt_state, specs, optimizer = init_state(cfg, mesh)
     step, *_ = make_train_step(cfg, optimizer, mesh, specs)
@@ -216,19 +216,26 @@ def _lower_step(tmp_path):
     return step.lower(params, opt_state, x, x, jax.random.PRNGKey(0))
 
 
-def test_step_program_names_every_scope_and_gains_no_operation(tmp_path, monkeypatch):
+@pytest.mark.parametrize("fsdp_mode", ["gspmd", "shard_map"])
+def test_step_program_names_every_scope_and_gains_no_operation(fsdp_mode, tmp_path, monkeypatch):
+    """Under either collective schedule (the compiler's, and the authored one
+    the four-chip cell takes): step_phases.py reads the same scopes off both."""
     sys.path.insert(0, os.path.join(ROOT, "tests"))
-    lowered = _lower_step(tmp_path)
+    lowered = _lower_step(tmp_path, fsdp_mode)
     n_ops = sum(" = " in line for line in lowered.as_text().splitlines())
     locs = set(re.findall(r'loc\("([^"]+)"', lowered.as_text(debug_info=True)))
     step_phases = _reader("step_phases.py")
     seen = {step_phases.innermost_scope(loc) for loc in locs}
     assert set(STEP_SCOPES) <= seen, f"scopes the lowered step does not name: {set(STEP_SCOPES) - seen}"
     assert {"attn", "mlp", "embed", "final_norm"} <= seen
-    # backward ops of the loss keep the scope (no custom backward rule drops it)
-    assert any("transpose(jvp(lm_head_loss))" in loc for loc in locs)
+    # backward ops of the loss keep the scope (no custom backward rule drops
+    # it). The shard_map body is a function of its own in the lowered text:
+    # its locations start at the body, so the loss's cotangent sums read
+    # `lm_head_loss/add_any` there.
+    backward = "transpose(jvp(lm_head_loss))" if fsdp_mode == "gspmd" else "lm_head_loss/add_any"
+    assert any(backward in loc for loc in locs)
     monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
-    bare = sum(" = " in line for line in _lower_step(tmp_path).as_text().splitlines())
+    bare = sum(" = " in line for line in _lower_step(tmp_path, fsdp_mode).as_text().splitlines())
     assert n_ops == bare
 
 

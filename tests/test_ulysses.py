@@ -84,7 +84,8 @@ def test_ulysses_train_step_matches_naive_sp1():
         eval_steps=2,
     )
     oracle_cfg = ExperimentConfig(
-        mesh=MeshConfig(data=2, fsdp=4, sp=1), model_config=mc, **base
+        mesh=MeshConfig(data=2, fsdp=4, sp=1), model_config=mc,
+        fsdp_mode="gspmd", **base,
     )
     uly_cfg = ExperimentConfig(
         mesh=MeshConfig(data=2, fsdp=2, sp=2),
@@ -220,7 +221,8 @@ def test_ulysses_shard_map_fsdp_train_step_matches_gspmd():
     )
     uly = dataclasses.replace(mc, attn_impl="ulysses")
     gspmd_cfg = ExperimentConfig(
-        mesh=MeshConfig(data=2, fsdp=2, sp=2), model_config=uly, **base
+        mesh=MeshConfig(data=2, fsdp=2, sp=2), model_config=uly,
+        fsdp_mode="gspmd", **base,
     )
     sm_cfg = ExperimentConfig(
         mesh=MeshConfig(data=2, fsdp=2, sp=2), model_config=uly,
