@@ -484,6 +484,7 @@ def to_json(config: ExperimentConfig) -> str:
 MODEL_FAMILIES: tp.Dict[str, str] = {
     "gpt": "midgpt_tpu.models.gpt:GPTConfig",
     "kimi_linear": "midgpt_tpu.models.kimi_linear:KimiLinearConfig",
+    "mimo_v2": "midgpt_tpu.models.mimo_v2:MimoV2Config",
 }
 
 

@@ -427,7 +427,7 @@ def _tpl_kernel(
 # program share one body, which lowers once.
 @functools.partial(
     jax.jit,
-    static_argnames=("split_k", "sliding_window", "attn_sinks", "pages_per_block"),
+    static_argnames=("split_k", "sliding_window", "attn_sinks", "pages_per_block", "v_dim"),
     inline=True,
 )
 def paged_attention_template(
@@ -443,11 +443,15 @@ def paged_attention_template(
     attn_sinks: int = 0,
     layer: tp.Optional[Array] = None,  # () int — which layer of the pool
     pages_per_block: tp.Optional[int] = None,  # tests and sweeps; else derived
+    v_dim: tp.Optional[int] = None,  # V's head width where it is not q's (K 192 / V 128)
 ) -> Array:
     """Instantiate the template for one (n_rows, quantized, split_k,
     kv_groups, window) spec.
 
-    Returns (B, H_q, R, C) in q.dtype. int8 pools require both scale side
+    Returns (B, H_q, R, C) in q.dtype — (B, H_q, R, v_dim) where the V pool
+    has a head width (and lanes) of its own: the K and V page copies, the V
+    buffers, the accumulator and the output then take V's lanes, and nothing
+    else in the sweep knows (scores scale by q's width). int8 pools require both scale side
     buffers; bf16/f32 pools take none. split_k is normalized to a pow2
     divisor of the table width; split_k == 1 is the classic in-kernel
     finalize, split_k > 1 emits per-partition partials and merges them
@@ -475,23 +479,24 @@ def paged_attention_template(
             k_scale, v_scale = k_scale[None], v_scale[None]
     B, HQ, R, C = q.shape
     _, H, _, page_size, lanes = k_pages.shape
+    lanes_v = v_pages.shape[-1]
     scale = 1.0 / math.sqrt(C)
-    if lanes % 128:
+    if lanes % 128 or lanes_v % 128:
         # Off the layout contract (a pool allocated without `kernel_layout`:
         # direct callers, the benchmark's correctness check): the chip keeps
         # such rows lane-padded and a page copy cannot slice them, so the
         # pool is padded to whole rows here — a pool-sized copy per call,
         # which a ServeEngine pool never takes.
-        widen = [(0, 0)] * 4 + [(0, -lanes % 128)]
-        k_pages, v_pages = jnp.pad(k_pages, widen), jnp.pad(v_pages, widen)
-        lanes = k_pages.shape[-1]
+        widen = lambda n: [(0, 0)] * 4 + [(0, -n % 128)]
+        k_pages, v_pages = jnp.pad(k_pages, widen(lanes)), jnp.pad(v_pages, widen(lanes_v))
+        lanes, lanes_v = k_pages.shape[-1], v_pages.shape[-1]
     if lanes > C:
         # A kernel-path pool is allocated at whole 128-lane rows and holds
         # zeros past head_dim (PagedKVCache "Layout contract"): q meets it
         # there with zeros, which add nothing to q.k, and the output's
         # extra lanes (p @ zeros) are dropped on the way out.
         q = jnp.pad(q, [(0, 0)] * 3 + [(0, lanes - C)])
-    C_out, C = C, lanes
+    C_out, C, Cv = v_dim or C, lanes, lanes_v
     groups = HQ // H
     if groups > 1:
         # Fold: head h = kv*groups + g, so (B, HQ, R, C) is contiguously
@@ -543,13 +548,13 @@ def paged_attention_template(
         in_specs += [scale_spec, scale_spec]
         operands += [block_scales(k_scale), block_scales(v_scale)]
     scratch = [
-        pltpu.VMEM((H, R, C), jnp.float32),
+        pltpu.VMEM((H, R, Cv), jnp.float32),
         pltpu.VMEM((H, R, _STATS_LANES), jnp.float32),
         pltpu.VMEM((H, R, _STATS_LANES), jnp.float32),
         pltpu.SMEM((2,), jnp.int32),
         pltpu.SemaphoreType.DMA((2, 2)),
         pltpu.VMEM((2, H, tokens, C), k_pages.dtype),
-        pltpu.VMEM((2, H, tokens, C), v_pages.dtype),
+        pltpu.VMEM((2, H, tokens, Cv), v_pages.dtype),
     ]
 
     if split_k > 1:
@@ -557,20 +562,20 @@ def paged_attention_template(
         # whose trailing block dims span the full array dims (Mosaic rule).
         part_idx = lambda b, si, i, pt, cnt, ly: (b * split_k + si, 0, 0, 0)
         out_specs = [
-            pl.BlockSpec((1, H, R, C), part_idx),
+            pl.BlockSpec((1, H, R, Cv), part_idx),
             pl.BlockSpec((1, H, R, _STATS_LANES), part_idx),
             pl.BlockSpec((1, H, R, _STATS_LANES), part_idx),
         ]
         out_shape = [
-            jax.ShapeDtypeStruct((B * split_k, H, R, C), jnp.float32),
+            jax.ShapeDtypeStruct((B * split_k, H, R, Cv), jnp.float32),
             jax.ShapeDtypeStruct((B * split_k, H, R, _STATS_LANES), jnp.float32),
             jax.ShapeDtypeStruct((B * split_k, H, R, _STATS_LANES), jnp.float32),
         ]
     else:
         out_specs = pl.BlockSpec(
-            (1, H, R, C), lambda b, si, i, pt, cnt, ly: (b, 0, 0, 0)
+            (1, H, R, Cv), lambda b, si, i, pt, cnt, ly: (b, 0, 0, 0)
         )
-        out_shape = jax.ShapeDtypeStruct((B, H, R, C), q.dtype)
+        out_shape = jax.ShapeDtypeStruct((B, H, R, Cv), q.dtype)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -601,14 +606,14 @@ def paged_attention_template(
         *operands,
     )
     if split_k == 1:
-        out = out.reshape(B, HQ, R_full, C) if groups > 1 else out
+        out = out.reshape(B, HQ, R_full, Cv) if groups > 1 else out
         return out[..., :C_out]
     o, m, l = out
-    o = o.reshape(B, split_k, H, R, C)
+    o = o.reshape(B, split_k, H, R, Cv)
     m = m.reshape(B, split_k, H, R, _STATS_LANES)[..., 0]
     l = l.reshape(B, split_k, H, R, _STATS_LANES)[..., 0]
     m, l, acc = merge_partials(m, l, o, axis=1)
     merged, _ = finalize(m, l, acc)
     merged = merged.astype(q.dtype)
-    merged = merged.reshape(B, HQ, R_full, C) if groups > 1 else merged
+    merged = merged.reshape(B, HQ, R_full, Cv) if groups > 1 else merged
     return merged[..., :C_out]

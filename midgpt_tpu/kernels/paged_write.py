@@ -110,6 +110,7 @@ def paged_write_kernel(
     write_pages[n], :, offs[n]] = sval[n]`), in place. Returns the pools
     (and scale buffers) — the same buffers when the caller donates them."""
     L, H, P, ps, C = k_pool.shape
+    Cv = v_pool.shape[-1]  # V's lanes where K and V differ in head width
     N = write_pages.shape[0]
     quantized = k_scale is not None
     write_pages = write_pages.astype(jnp.int32)
@@ -126,8 +127,17 @@ def paged_write_kernel(
     page_spec = pl.BlockSpec(
         (None, H, 1, ps, C), lambda n, ly, pg, of, fi: (ly[0], 0, pg[n], 0, 0)
     )
-    in_specs = [val_spec, val_spec, page_spec, page_spec]
-    out_specs = [page_spec, page_spec]
+    if Cv == C:
+        v_val_spec, v_page_spec = val_spec, page_spec
+    else:
+        v_val_spec = pl.BlockSpec(
+            (None, H, 1, 1, Cv), lambda n, ly, pg, of, fi: (n, 0, 0, 0, 0)
+        )
+        v_page_spec = pl.BlockSpec(
+            (None, H, 1, ps, Cv), lambda n, ly, pg, of, fi: (ly[0], 0, pg[n], 0, 0)
+        )
+    in_specs = [val_spec, v_val_spec, page_spec, v_page_spec]
+    out_specs = [page_spec, v_page_spec]
     operands = [k_val[:, :, None, None, :], v_val[:, :, None, None, :], k_pool, v_pool]
     out_shape = [
         jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),

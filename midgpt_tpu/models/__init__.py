@@ -1,20 +1,23 @@
 """Model families. A family is a config dataclass and a namespace of pure
-functions over (config, params); the training runtime, the optimizer and the
-entry points reach a model ONLY through what is listed here, and call each
-without asking whether it is there. Families are registered in
-`midgpt_tpu/config.py` `MODEL_FAMILIES`.
+functions over (config, params); the training runtime, the optimizer, the
+serving engine and the entry points reach a model ONLY through what is listed
+here, and call each without asking whether it is there. Families are
+registered in `midgpt_tpu/config.py` `MODEL_FAMILIES`.
 
-The config (`GPTConfig`, `KimiLinearConfig`):
+The config (`GPTConfig`, `KimiLinearConfig`, `MimoV2Config`):
 
     block_size, vocab_size, n_layer, n_head, n_embd   fields, under these names
     model()                    -> the namespace below
     check_experiment(config)   raises ValueError for an ExperimentConfig this
                                family cannot run (mesh axes, schedules, knobs)
+    check_training(who)        raises NotImplementedError where no backward is
+                               wired for this family (`make_train_step` asks);
+                               returns None where it trains
     check_serving(who)         raises NotImplementedError where the serving
                                stack (sample.py, ServeEngine) holds no cache
                                for this family; returns None where it does
 
-The namespace (`GPT`, `KimiLinear`):
+The namespace (`GPT`, `KimiLinear`, `MimoV2`):
 
     init(config, key) -> params
     hidden(config, params, tokens, *, key, inference, attn_fn) -> (B, T, D)
@@ -24,11 +27,48 @@ The namespace (`GPT`, `KimiLinear`):
                                bools, True where AdamW's decay applies
     param_specs(config, tree, mesh) -> PartitionSpec tree (config: the
                                ExperimentConfig)
-    flops_per_token(config, seq_len=None, stats=None) -> training FLOPs a
-                               token; `stats`: what `route_stats` returned
+    flops_per_token(config, seq_len=None, stats=None) -> FLOPs a token
+                               (training's, 3 x forward; forward only for a
+                               family that is served and not trained);
+                               `stats`: what `route_stats` returned
     route_stats                None, or (config, params, tokens (B, T)) ->
                                {counter name: scalar}, forward only: the
                                counters the train loop logs at a logged step
+
+The SERVING members, of every family whose `check_serving` returns None
+(`GPT`, `MimoV2`; sampling/serve.py calls them, never a family by name):
+
+    cache_kinds(config) -> (CacheKind(name, window, sinks), ...)
+                               the kinds of paged cache the layers need, the
+                               engine's first kind first. Each gets a pool, a
+                               page table and an allocator of its own; `window`
+                               > 0: a page is freed once every future query's
+                               window has passed it (0: it lives as long as
+                               its request); `sinks`: leading tokens never
+                               freed. The GPT: one kind. MimoV2: `global`
+                               (window 0) and `window`.
+    init_cache(config, num_pages, page_size, dtype, kernel_layout) -> cache
+                               `num_pages[i]` pages for kind i; the cache is a
+                               pytree with `pool_arrays()` (its page pools, for
+                               the layout census), `page_size`, `num_pages`
+    prefill_paged_chunk(config, params, tokens (1, T), start, n_valid, cache,
+        page_table, attn_impl, mesh) -> (logits, cache)
+                               `page_table`: the slot's (1, pages) row, or the
+                               tuple of every kind's where there are several.
+                               logits (1, T, V), or (1, 1, V): the last valid
+                               row's alone (all the engine reads)
+    decode_step_paged(config, params, token (B,), cache, page_table, lengths,
+        active, attn_impl, mesh, split_k) -> (logits (B, V), cache)
+    verify_step_paged          the speculative verify step with decode's
+                               arguments over (B, k + 1) tokens, or None where
+                               the family has none (the engine then refuses a
+                               draft model)
+    kernel_sweep(config, cache) -> (pool shape, q rows a pool head, window,
+                               sinks) of the decode kernel's sweep, for the
+                               engine's block counters
+    serve_counters             None, or (config, cache) -> {counter: number}
+                               the family's own counters kept in the cache
+                               (MimoV2: the expert layers'), read on demand
 """
 
 from midgpt_tpu.models.gpt import GPT, GPTConfig, GPTParams

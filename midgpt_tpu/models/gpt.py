@@ -28,6 +28,7 @@ TPU-first structure (different from the reference's Equinox modules):
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import math
 import typing as tp
@@ -197,6 +198,9 @@ class GPTConfig:
     def check_serving(self, who: str) -> None:
         """sample.py and ServeEngine serve this family."""
 
+    def check_training(self, who: str) -> None:
+        """launch.py trains this family."""
+
     @property
     def head_dim(self) -> int:
         assert self.n_embd % self.n_head == 0
@@ -313,6 +317,13 @@ class KVCache:
             v=jnp.zeros(shape, dtype),
             length=jnp.zeros((), jnp.int32),
         )
+
+
+# What the serving engine sizes and frees by: a kind of paged cache a family's
+# layers need (`cache_kinds`, models/__init__.py). `window` 0: a page lives as
+# long as its request; > 0: a page is dead once every future query's window
+# has passed it. `sinks`: leading tokens that stay visible, never freed.
+CacheKind = collections.namedtuple("CacheKind", "name window sinks")
 
 
 @pytree_dataclass
@@ -446,6 +457,10 @@ class PagedKVCache:
         per_tok = config.n_layer * config.kv_heads * lanes
         return 2 * per_tok * page_size * jnp.dtype(dtype).itemsize
 
+    def pool_arrays(self) -> tp.List[Array]:
+        """The page pools (not the scale side buffers), for the layout census."""
+        return [self.k, self.v]
+
     @property
     def quantized(self) -> bool:
         return self.k.dtype == jnp.int8
@@ -514,11 +529,13 @@ def _paged_write(
     if impl == "kernel":
         from midgpt_tpu.kernels.paged_write import paged_write
 
-        # rows at the pool's lanes: zeros past head_dim (they stay zero)
-        pad = ck.shape[-1] - k.shape[-1]
-        if pad:
-            lanes = [(0, 0)] * (k.ndim - 1) + [(0, pad)]
-            k, v = jnp.pad(k, lanes), jnp.pad(v, lanes)
+        # rows at the pool's lanes: zeros past head_dim (they stay zero);
+        # K and V each at their own pool's (a family with K 192 / V 128)
+        lanes = lambda a, pool: [(0, 0)] * (a.ndim - 1) + [(0, pool.shape[-1] - a.shape[-1])]
+        if ck.shape[-1] != k.shape[-1]:
+            k = jnp.pad(k, lanes(k, ck))
+        if cv.shape[-1] != v.shape[-1]:
+            v = jnp.pad(v, lanes(v, cv))
         rows = lambda a: None if a is None else a.reshape(-1, *a.shape[offs.ndim:])
         return paged_write(
             ck, cv, i, write_pages.reshape(-1), offs.reshape(-1),
@@ -1646,6 +1663,25 @@ class GPT:
         return logits, PagedKVCache(
             k=k_new, v=v_new, k_scale=ks_new, v_scale=vs_new
         )
+
+    # -- the serving members of the family contract (models/__init__.py) --
+    serve_counters = None  # no counters of its own beside the engine's
+
+    @staticmethod
+    def cache_kinds(config: GPTConfig) -> tp.Tuple[CacheKind, ...]:
+        """One kind: every layer keeps K/V alike (windowed, if the model is)."""
+        return (CacheKind("kv", config.sliding_window, config.attn_sinks),)
+
+    @staticmethod
+    def init_cache(config: GPTConfig, num_pages: tp.Sequence[int], page_size: int = 8,
+                   dtype=jnp.bfloat16, kernel_layout: bool = False) -> PagedKVCache:
+        return PagedKVCache.init(config, num_pages[0], page_size, dtype, kernel_layout)
+
+    @staticmethod
+    def kernel_sweep(config: GPTConfig, cache: PagedKVCache):
+        """(pool shape, q rows a pool head, window, sinks) of the decode
+        kernel's sweep, for the engine's block counters."""
+        return cache.k.shape, config.kv_groups, config.sliding_window, config.attn_sinks
 
     @staticmethod
     def count_params(params: GPTParams) -> int:

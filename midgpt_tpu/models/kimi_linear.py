@@ -144,11 +144,14 @@ class KimiLinearConfig:
 
     def check_serving(self, who: str) -> None:
         raise NotImplementedError(
-            f"{who} cannot serve a {FAMILY} checkpoint yet: PagedKVCache / ServeEngine hold one kind of "
-            "cache (paged K/V of one head width) and this model needs a recurrent KDA state per slot "
-            "beside a latent (MLA) pool, and kernels/attention_template.py has no absorbed-latent "
+            f"{who} cannot serve a {FAMILY} checkpoint yet: the serving stack holds paged K/V pools, of "
+            "several kinds side by side (models/mimo_v2.py), and this model needs a recurrent KDA state per "
+            "slot beside a latent (MLA) pool, and kernels/attention_template.py has no absorbed-latent "
             "decode path. Train it with launch.py; serving is listed in ROADMAP.md Queue 2."
         )
+
+    def check_training(self, who: str) -> None:
+        """launch.py trains this family."""
 
     def check_experiment(self, config) -> None:
         """`ExperimentConfig.__post_init__` for this family: what the training

@@ -9,7 +9,18 @@ computed and adds nothing: on one chip of an expert-parallel job that is the
 chip's share of the sum, and the exchange that would add the other shares is
 no part of this op.
 
-Dispatch has static shapes and drops nothing:
+Two callers, one layout of tiles (a tile = up to `tile` pairs of ONE held
+expert). Training (`moe_experts`, differentiable, thousands of rows): the tiles
+in a buffer of a fixed size, batched matmuls over each tile's gathered expert
+weights, an exact path behind it. Serving (`moe_experts_serving`, forward only,
+a prefill chunk's or a decode step's rows): a loop over the tiles in use that
+reads each tile's expert in place. Why two: at serving row counts the gathered
+weights ARE the cost (v5e, 16 held experts of 3 x 2,048 x 4,096, 512 / 32 rows:
+buffer 10.06 / 5.52 ms, every held expert over every row 2.54 / 1.12 ms, the
+loop 1.54 / 0.63 ms; PERF.md §6 PR 30), and a loop whose trip count is data has
+no transpose.
+
+`moe_experts`' dispatch has static shapes and drops nothing:
 
   fast path   the held experts share ONE buffer of `n_tiles` tiles of `tile`
               rows. The pairs are laid out sorted by expert, each expert's run
@@ -152,3 +163,66 @@ def moe_experts(
     overflowed = ends[-1] > n_tiles
     y, computed = jax.lax.cond(overflowed, exact, fast, None)
     return y, {"counts": counts, "dropped": jnp.sum(counts) - computed, "overflowed": overflowed}
+
+
+def moe_serving_tile(n_tokens: int, top_k: int, n_experts: int) -> int:
+    """Rows a tile of `moe_experts_serving`: the power of two at or over FOUR
+    times the mean pairs an expert gets, within [8, 256]. A tile of few rows
+    costs its expert's three matrices read once whatever it holds (until 256
+    rows fill the MXU), so the cheaper tile is one that takes a whole run, the
+    most loaded expert's too (2.4 to 2.9 times the mean in the serving cell),
+    and not the mean run. On the v5e at 512 rows (mean 16): tiles of 8 / 16 /
+    32 / 64 / 128 rows took 3.22 / 2.16 / 1.66 / 1.54 / 1.78 ms (PERF.md §6 PR
+    30). Derived from the row count, not configured."""
+    tile = 8
+    while tile < min(256, 4 * n_tokens * top_k / n_experts):
+        tile *= 2
+    return tile
+
+
+def moe_experts_serving(
+    x: Array, idx: Array, weights: Array,
+    w_gate: Array, w_up: Array, w_down: Array, *, offset: int, tile: int,
+) -> tp.Tuple[Array, tp.Dict[str, Array]]:
+    """`moe_experts`' result for a FORWARD-ONLY call of few rows (a prefill
+    chunk, a decode step's slots): the same tiles (a tile = up to `tile` pairs
+    of ONE held expert, its rows found by the same binary search), walked by a
+    loop that runs as many times as there are tiles IN USE, each reading its
+    expert's matrices in place (a dynamic slice, no gathered copy) and adding
+    its weighted rows into the result. No buffer of a fixed size, so nothing
+    overflows and no exact path exists; work is the held experts touched, not
+    the held experts. `dropped` counts assigned pairs that were not computed:
+    0 by construction, counted not assumed. Not differentiable (the trip count
+    is data): training takes `moe_experts`."""
+    N, D = x.shape
+    E_h = w_gate.shape[0]
+    local = idx - offset
+    onehot = (local[..., None] == jnp.arange(E_h)) & ((local >= 0) & (local < E_h))[..., None]
+    per_token = jnp.sum(onehot, axis=1, dtype=jnp.int32)  # (N, E_h) 0/1
+    counts = jnp.sum(per_token, axis=0)
+    with jax.named_scope("moe_route"):
+        w_tok = jnp.sum(jnp.where(onehot, weights[..., None], 0.0), axis=1)  # (N, E_h)
+        tiles_e = -(-counts // tile)
+        ends = jnp.cumsum(tiles_e)
+        cum = jnp.cumsum(per_token, axis=0).T  # (E_h, N), non-decreasing
+        xz = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])
+        wz = jnp.concatenate([w_tok, jnp.zeros((1, E_h), w_tok.dtype)])
+        rows = jnp.arange(1, tile + 1, dtype=jnp.int32)
+
+    def one_tile(t, carry):
+        y, computed = carry
+        with jax.named_scope("moe_route"):
+            e = jnp.searchsorted(ends, t, side="right").astype(jnp.int32)  # < E_h: t < ends[-1]
+            j = t - (ends[e] - tiles_e[e])  # the tile's place in its expert's run
+            # row r holds the token whose pair is the (j * tile + r + 1)-th of expert e; past the last pair: N, a zero row
+            tok = jnp.searchsorted(cum[e], j * tile + rows, side="left").astype(jnp.int32)
+            xe, we = xz[tok], wz[tok, e]
+        with jax.named_scope("moe_experts"):
+            ye = swiglu(xe, w_gate[e], w_up[e], w_down[e])
+        with jax.named_scope("moe_route"):
+            y = y.at[tok].add(ye.astype(jnp.float32) * we[:, None])
+        return y, computed + jnp.sum(tok < N, dtype=counts.dtype)
+
+    y, computed = jax.lax.fori_loop(
+        0, ends[-1], one_tile, (jnp.zeros((N + 1, D), jnp.float32), jnp.zeros((), counts.dtype)))
+    return y[:N].astype(x.dtype), {"counts": counts, "dropped": jnp.sum(counts) - computed}

@@ -155,3 +155,16 @@ def apply_rope_bthc(
     sin = _duplicate_pairs(sin).astype(x.dtype)[:, None, :]  # (T, 1, C)
     cos = _duplicate_pairs(cos).astype(x.dtype)[:, None, :]
     return x * cos + rotate_interleaved(x) * sin
+
+
+def apply_rope_leading(x: Array, sin: Array, cos: Array, positions: Array) -> Array:
+    """Partial rotary: rotate-half on the LEADING `2 * sin.shape[-1]` channels
+    of `x` (B, T, H, C), the rest pass through. `positions` is (T,) (one
+    vector for the batch) or (B, T) (per-token, the paged decode step).
+    The tables are `rope_table(rotary_dim, length, base)`: a model with two
+    bases (window and global layers) holds two tables."""
+    rot = 2 * sin.shape[-1]
+    s = _tile_halves(jnp.take(sin, positions, axis=0)).astype(x.dtype)[..., None, :]
+    c = _tile_halves(jnp.take(cos, positions, axis=0)).astype(x.dtype)[..., None, :]
+    xr = x[..., :rot]
+    return jnp.concatenate((xr * c + rotate_half(xr) * s, x[..., rot:]), axis=-1)

@@ -251,6 +251,13 @@ class DisaggServe:
         obs: tp.Optional[Observability] = None,
         **engine_kw,
     ):
+        kinds = config.model().cache_kinds(config)
+        if len(kinds) > 1:
+            raise NotImplementedError(
+                "DisaggServe: disaggregated prefill (a hand-off of pages of ONE kind, owned through "
+                f"the prefix trie) is not wired for a model with {len(kinds)} kinds of paged cache "
+                f"({', '.join(k.name for k in kinds)})"
+            )
         if engine_kw.get("temperature", 0.0) != 0.0:
             raise ValueError("DisaggServe is greedy-only (module docstring)")
         if engine_kw.pop("prefix_cache", True) is not True:

@@ -157,7 +157,7 @@ def restore_for_sampling(
         mesh = make_mesh(MeshConfig(data=1, fsdp=jax.device_count(), sp=1))
     model_cfg = config.model_config
     abstract = jax.eval_shape(
-        lambda k: GPT.init(model_cfg, k), jax.random.PRNGKey(0)
+        lambda k: model_cfg.model().init(model_cfg, k), jax.random.PRNGKey(0)
     )
     specs = fsdp_param_specs(
         abstract,
@@ -184,6 +184,16 @@ def restore_for_sampling(
     return params, step
 
 
+def check_batch_engine(config) -> None:
+    """`generate` runs the GPT's dense KV cache (`GPT.prefill` /
+    `GPT.decode_step`) and no other family's: refused by name, never switched."""
+    if not isinstance(config, GPTConfig):
+        raise NotImplementedError(
+            f"the batch engine (sampling/engine.py generate) holds the GPT's dense KV cache only; "
+            f"family {config.family!r} is served by ServeEngine: sample.py --engine=continuous"
+        )
+
+
 def generate(
     config: GPTConfig,
     params: GPTParams,
@@ -196,6 +206,7 @@ def generate(
     key: tp.Optional[Array] = None,
 ) -> Array:
     """Returns (B, T0 + max_new_tokens) including the prompt."""
+    check_batch_engine(config)
     key = key if key is not None else jax.random.PRNGKey(0)
     B, T0 = prompt.shape
     S = config.block_size

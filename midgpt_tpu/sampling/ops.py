@@ -325,7 +325,7 @@ def assert_conserved(engine: ServeEngine, where: str) -> None:
     held = set() if pc is None else pc.pages_held()
     # -1 entries are window-reclaimed placeholders (serve.py
     # _reclaim_window) — already back on the free list, not live.
-    live = {p for s in engine.slots if s is not None for p in s.pages if p >= 0}
+    live = {p for s in engine.slots if s is not None for p in s.pages[0] if p >= 0}
     total = engine.allocator.free_count + len(held) + len(live - held)
     assert total == engine.allocator.num_pages - 1, (
         f"page conservation violated {where}: free={engine.allocator.free_count} "
@@ -423,7 +423,7 @@ def resize_pool(
         )
 
     pc = engine.prefix_cache
-    live = {p for s in live_slots for p in s.pages if p >= 0}
+    live = {p for s in live_slots for p in s.pages[0] if p >= 0}
     referenced = set() if pc is None else pc.referenced_pages()
     # Slot-shared pages (pages[:n_shared]) are referenced trie entries by
     # construction, so |live ∪ referenced| = |live − held| + |referenced|.
@@ -476,7 +476,7 @@ def resize_pool(
             engine.draft_config,
         )
     for s in live_slots:
-        s.pages[:] = [mapping[p] if p >= 0 else -1 for p in s.pages]
+        s.pages[0][:] = [mapping[p] if p >= 0 else -1 for p in s.pages[0]]
     if pc is not None:
         pc.remap_pages(mapping)
     engine.allocator = allocator

@@ -138,7 +138,7 @@ from midgpt_tpu.kernels.attention_template import (
     normalize_split_k,
 )
 from midgpt_tpu.kernels.decode_attention import resolve_paged_impl
-from midgpt_tpu.models.gpt import GPT, GPTConfig, GPTParams, PagedKVCache
+from midgpt_tpu.models.gpt import GPTConfig, GPTParams, PagedKVCache
 from midgpt_tpu.obs import DISABLED_SNAPSHOT, Observability
 from midgpt_tpu.obs.trace import NULL_TRACER
 from midgpt_tpu.robustness import faults
@@ -167,8 +167,9 @@ class _PoolProgram:
     what it compiled on the kernel path, for the pool-layout census.
 
     Calls, `lower` and `_cache_size` are the wrapped jit's own. When a call
-    with `attn_impl` resolving to 'kernel' compiles a program, its abstract
-    arguments are kept; `pool_relayouts` later counts the pool- or
+    compiles a program, its abstract arguments are kept (`texts` hands out
+    its optimized text); for those with `attn_impl` resolving to 'kernel',
+    `pool_relayouts` later counts the pool- or
     layer-sized copies in each such program's compiled text
     (PagedKVCache "Layout contract": 0 when the pool keeps one layout from
     the program's parameter to its result)."""
@@ -183,13 +184,13 @@ class _PoolProgram:
         # label -> abstract (args, kwargs) of a kernel-path program, and
         # label -> relayout count once `pool_relayouts` has read its text
         self._compiled: tp.Dict[str, tp.Any] = {}
+        self._kernel_path: tp.Set[str] = set()
         self._relayouts: tp.Dict[str, int] = {}
 
     def __call__(self, *args, **kwargs):
         bound = self._sig.bind(*args, **kwargs)
         a = bound.arguments
-        if resolve_paged_impl(a.get("attn_impl", "gather")) != "kernel":
-            return self.jit(*args, **kwargs)
+        kernel = resolve_paged_impl(a.get("attn_impl", "gather")) == "kernel"
         # the pool is donated: describe it before the call
         pool = jax.tree.map(_abstract, a["cache"])
         n_before = self._cache_size()
@@ -197,17 +198,20 @@ class _PoolProgram:
         if self._cache_size() != n_before:  # this call compiled a program
             statics = [f"{k}={a[k]}" for k in ("n_steps", "k_steps", "round_group") if k in a]
             widths = [
-                f"{k}={a[k].shape[-1]}"
+                f"{k}={jax.tree.leaves(a[k])[0].shape[-1]}"  # a family's tables: the first kind's
                 for k in ("page_table", "page_table_row", "tokens", "drafts")
                 if k in a
             ]
             label = " ".join(
-                [self.__name__.lstrip("_"), *statics, *widths, f"pool={pool.k.dtype}"]
+                [self.__name__.lstrip("_"), *statics, *widths,
+                 f"pool={pool.pool_arrays()[0].dtype}"]
             )
             a["cache"] = pool
             self._compiled[label] = jax.tree.map(
                 _abstract, (bound.args, bound.kwargs)
             )
+            if kernel:
+                self._kernel_path.add(label)
         return out
 
     def pool_relayouts(self) -> tp.Dict[str, int]:
@@ -219,13 +223,24 @@ class _PoolProgram:
         from midgpt_tpu.analysis.hlo_audit import pool_relayouts
 
         for label, (args, kwargs) in self._compiled.items():
-            if label not in self._relayouts:
+            if label in self._kernel_path and label not in self._relayouts:
                 text = self.jit.lower(*args, **kwargs).compile().as_text()
                 pool = self._sig.bind(*args, **kwargs).arguments["cache"]
                 self._relayouts[label] = pool_relayouts(
-                    text, [pool.k.shape, pool.v.shape]
+                    text, [a.shape for a in pool.pool_arrays()]
                 )
         return dict(self._relayouts)
+
+    def texts(self) -> tp.Dict[str, str]:
+        """{program as compiled: its optimized HLO text}, for a
+        reader that joins a traced op to the scope that opened it (the v5e
+        trace names an op by its instruction and carries no scope path). As
+        `pool_relayouts`: lowering the recorded arguments again finds the
+        executable the call compiled."""
+        return {
+            label: self.jit.lower(*args, **kwargs).compile().as_text()
+            for label, (args, kwargs) in self._compiled.items()
+        }
 
 
 def _abstract(a):
@@ -233,6 +248,8 @@ def _abstract(a):
     sharding when committed); anything else as it is."""
     if not isinstance(a, jax.Array):
         return a
+    if isinstance(a, jax.core.Tracer):  # the program called under another trace (tests)
+        return jax.ShapeDtypeStruct(a.shape, a.dtype)
     return jax.ShapeDtypeStruct(
         a.shape, a.dtype, sharding=a.sharding if a.committed else None
     )
@@ -247,7 +264,7 @@ def _serve_prefill_chunk(
     """One prompt chunk into the pool. `attn_impl` (the engine's resolved
     choice) selects the K/V WRITE only — the chunk's attention is an XLA
     gather on every backend (GPT.prefill_paged_chunk)."""
-    logits, cache = GPT.prefill_paged_chunk(
+    logits, cache = config.model().prefill_paged_chunk(
         config, params, tokens, start, n_valid, cache, page_table_row,
         attn_impl=attn_impl, mesh=mesh,
     )
@@ -285,7 +302,7 @@ def _serve_decode_chunk(
             key, k = jax.random.split(key)
         else:
             k = None
-        logits, cache = GPT.decode_step_paged(
+        logits, cache = config.model().decode_step_paged(
             config, params, token, cache, page_table, lengths, active,
             attn_impl=attn_impl, mesh=mesh, split_k=split_k,
         )
@@ -302,6 +319,22 @@ def _serve_decode_chunk(
         body, (token, cache, lengths, key), None, length=n_steps
     )
     return cache, toks
+
+
+@_PoolProgram
+@functools.partial(jax.jit, static_argnums=(0, 7, 8, 9), donate_argnums=(3,))
+def _serve_decode_logits(
+    config, params, token, cache, page_table, lengths, active,
+    attn_impl: str, mesh=None, split_k: int = 1,
+):
+    """`_serve_decode_chunk`'s step ONCE, handing out its logits and sampling
+    nothing (`ServeEngine.next_logits`: checks, not the serving loop). Returns
+    (logits (B, V), cache)."""
+    logits, cache = config.model().decode_step_paged(
+        config, params, token, cache, page_table, lengths, active,
+        attn_impl=attn_impl, mesh=mesh, split_k=split_k,
+    )
+    return logits, _maybe_constrain(cache, mesh)
 
 
 # Cap on the fused multi-round group size (docs/SERVING.md "Round-overlap
@@ -390,7 +423,7 @@ def _serve_decode_group(
         # Pre-step mask: the write for this step lands at position
         # `lengths`, so it must be gated BEFORE the decode step runs.
         step_active = active & (lengths < max_len)
-        logits, cache = GPT.decode_step_paged(
+        logits, cache = config.model().decode_step_paged(
             config, params, token, cache, page_table, lengths, step_active,
             attn_impl=attn_impl, mesh=mesh, split_k=split_k,
         )
@@ -447,7 +480,7 @@ def _spec_draft_chunk(
         token, cache, lengths, key = carry
         if key is not None:
             key, k = jax.random.split(key)
-        logits, cache = GPT.decode_step_paged(
+        logits, cache = config.model().decode_step_paged(
             config, params, token, cache, page_table, lengths, active,
             attn_impl=attn_impl, mesh=mesh, split_k=split_k,
         )
@@ -500,7 +533,7 @@ def _spec_verify_chunk(
     tokens = jnp.concatenate(
         [token[:, None], drafts.T.astype(token.dtype)], axis=1
     )  # (B, k+1)
-    logits, cache = GPT.verify_step_paged(
+    logits, cache = config.model().verify_step_paged(
         config, params, tokens, cache, page_table, lengths, active,
         attn_impl=attn_impl, mesh=mesh, split_k=split_k,
     )
@@ -551,6 +584,7 @@ class PageAllocator:
     def __init__(self, num_pages: int):
         self.num_pages = num_pages
         self._free = list(range(num_pages - 1, 0, -1))  # pop() yields 1, 2, ...
+        self.free_min = len(self._free)  # the fewest pages ever free: the pool's peak is the rest
 
     @property
     def free_count(self) -> int:
@@ -560,6 +594,7 @@ class PageAllocator:
         """n pages, or None (allocator unchanged) if the pool is short."""
         if n > len(self._free):
             return None
+        self.free_min = min(self.free_min, len(self._free) - n)
         return [self._free.pop() for _ in range(n)]
 
     def free(self, pages: tp.Iterable[int]) -> None:
@@ -623,10 +658,19 @@ class Request:
 class _Slot:
     request: Request
     admit_order: int
-    pages: tp.List[int] = dataclasses.field(default_factory=list)
+    # pages[k]: the slot's pages of cache kind k (models/__init__.py
+    # `cache_kinds`; the GPT has one kind), a LOGICAL list: entry j holds
+    # positions [j*ps, (j+1)*ps), -1 once window-reclaimed. What knows one
+    # kind only (prefix cache, spill tier, speculation's rollback, resize;
+    # each refused where there are several) reads pages[0].
+    pages: tp.List[tp.List[int]]
+    # reclaimed_to[k]: the first logical page of kind k the window rule has
+    # not passed yet (every page below it is freed, the sink prefix apart), so
+    # the rule scans forward only
+    reclaimed_to: tp.List[int]
     length: int = 0  # tokens in the paged cache
     prompt_pos: int = 0  # prompt tokens prefilled so far
-    # pages[:n_shared] are prefix-cache trie entries this slot holds one
+    # pages[0][:n_shared] are prefix-cache trie entries this slot holds one
     # reference each on (prefix_cache engines only; 0 otherwise). The slot
     # never writes them: match caps at len(prompt) - 1 tokens and
     # insert_live shares only complete prompt pages, while every write
@@ -644,7 +688,6 @@ class _Slot:
     # skipped at admission, and the round the slot was admitted in
     skipped: int = 0
     admit_round: int = 0
-
     @property
     def prefilling(self) -> bool:
         return self.prompt_pos < len(self.request.prompt)
@@ -725,6 +768,9 @@ class ServeEngine:
         clock: tp.Callable[[], float] = time.perf_counter,
         on_token: tp.Optional[tp.Callable[[int, int, float], None]] = None,
         on_finish: tp.Optional[tp.Callable[["FinishedRequest"], None]] = None,
+        # (uid, logits (V,)): the prefill program's logits at a prompt's last
+        # position, what the first token is sampled from (checks)
+        on_first_logits: tp.Optional[tp.Callable[[int, np.ndarray], None]] = None,
         mesh=None,  # Optional[jax.sharding.Mesh] — parallel/serve_tp.py
         obs: tp.Optional[Observability] = None,
         obs_tid: str = "engine",
@@ -733,6 +779,26 @@ class ServeEngine:
     ):
         assert decode_chunk & (decode_chunk - 1) == 0, "decode_chunk: power of two"
         config.check_serving("ServeEngine")  # a family this engine holds no cache for stops here
+        # The model is reached through what every served family's namespace
+        # provides (models/__init__.py), never by name. `kinds`: the kinds of
+        # paged cache its layers need, each with a pool, an allocator and a
+        # page table of its own here; the first is the one every mechanism
+        # that knows one kind reads (`allocator`, `slot.pages[0]`, `num_pages`).
+        self.config = config
+        self.model = config.model()
+        self.kinds = self.model.cache_kinds(config)
+        if len(self.kinds) > 1:
+            # what is not wired over several kinds of pages, by mechanism
+            for on, what in (
+                (prefix_cache, "the prefix cache (pages of several kinds under one trie)"),
+                (draft_params is not None or draft_config is not None,
+                 "speculative decoding (no verify step over a several-kind cache; the drafter is left out)"),
+                (normalize_cache_dtype(cache_dtype) == jnp.int8, "int8 pools (no quantised write or read per kind)"),
+                (mesh is not None, "a serving mesh (tp / ep > 1: no exchange of routed tokens is run)"),
+                (pool_hbm_bytes is not None, "byte-budgeted pool sizing (pool_hbm_bytes: one budget over several pools)"),
+            ):
+                if on:
+                    self._refuse_several_kinds(what)
         # ---- tp serving mesh (docs/SERVING.md "Mesh-sharded serving") ----
         # Params shard by the megatron training rules (vocab-parallel off so
         # logits stay replicated for the host-side first-token argmax), the
@@ -802,6 +868,7 @@ class ServeEngine:
         self.watchdog = watchdog
         self.on_token = on_token
         self.on_finish = on_finish
+        self.on_first_logits = on_first_logits
         self.page_size = page_size
         self.max_slots = max_slots
         self.prefill_chunk = prefill_chunk
@@ -881,7 +948,27 @@ class ServeEngine:
         # summed over every live request, queued or running. None (default):
         # admission is unbounded, the pre-TTL behavior.
         self.max_backlog_pages = max_backlog_pages
-        self.allocator = PageAllocator(num_pages)
+        # A further kind's pool: as many pages as the first where it keeps
+        # the whole context; where it keeps a window, what every slot can
+        # hold at once, window + the longest write between two reclaims (a
+        # prefill chunk; a decode group) + a page of alignment, so that this
+        # pool never runs dry before the first does.
+        burst = max(prefill_chunk, decode_chunk * _round_group_bucket(round_group))
+        pool_pages = [num_pages] + [
+            1 + max_slots * (-(-(k.window + burst) // page_size) + 1) if k.window else num_pages
+            for k in self.kinds[1:]
+        ]
+        self.allocators = [PageAllocator(n) for n in pool_pages]
+        # Sliding-window page reclamation (a kind with window > 0; cache-off,
+        # non-speculative engines): pages wholly behind every future row's
+        # window (and past the sink prefix) are returned to the free list
+        # mid-request, their table entries parked on the sink page — the
+        # bounded-resident-set lever that makes windowed decode O(window) in
+        # pool pages, not O(T). Per kind: pages the rule freed (the first
+        # kind's is stats()["window_reclaimed_pages"]); the most pages one
+        # slot ever held.
+        self.kind_reclaimed = [0] * len(self.kinds)
+        self.kind_slot_pages_max = [0] * len(self.kinds)
         # Cross-request prefix sharing (module docstring; default OFF so a
         # plain engine's scheduling is bit-for-bit the pre-trie behavior).
         self.prefix_cache = PrefixCache(page_size) if prefix_cache else None
@@ -904,8 +991,8 @@ class ServeEngine:
         self.spill_tier = None
         self.spill_readopted_pages = 0
         self.spill_readopt_events = 0
-        self.cache = PagedKVCache.init(
-            config, num_pages=num_pages, page_size=page_size, dtype=cache_dtype,
+        self.cache = self.model.init_cache(
+            config, pool_pages, page_size, cache_dtype,
             kernel_layout=self.attn_impl == "kernel",
         )
         if mesh is not None:
@@ -993,13 +1080,6 @@ class ServeEngine:
         # mode's 2x pages shows up here as strictly fewer evictions on the
         # same trace (tests/test_quant_cache.py).
         self.preemptions = 0
-        # Sliding-window page reclamation (config.sliding_window > 0,
-        # cache-off, non-speculative engines): pages wholly behind every
-        # future row's window (and past the sink prefix) are returned to
-        # the free list mid-request, their table entries parked on the
-        # sink page — the bounded-resident-set lever that makes windowed
-        # decode O(window) in pool pages, not O(T).
-        self.window_reclaimed_pages = 0
         # Robustness/SLO counters (reported by stats() and the chaos
         # serve scenarios): scheduling rounds, deadline timeouts,
         # admission sheds, client cancellations, and killed decode rounds.
@@ -1040,6 +1120,16 @@ class ServeEngine:
         self.resize_plan: tp.List[int] = []
 
     # -- public surface ------------------------------------------------
+
+    @property
+    def allocator(self) -> PageAllocator:
+        """The first kind's allocator: what every mechanism that knows one
+        kind of page reads (and `resize` replaces)."""
+        return self.allocators[0]
+
+    @allocator.setter
+    def allocator(self, allocator: PageAllocator) -> None:
+        self.allocators[0] = allocator
 
     def submit(
         self,
@@ -1214,6 +1304,18 @@ class ServeEngine:
                 return True
         return False
 
+    def _refuse_several_kinds(self, mechanism: str) -> None:
+        """What is not wired for a family whose layers need several kinds of
+        cache stops here, by mechanism (the constructor; hot_swap, resize,
+        attach_spill; sampling/disagg.py)."""
+        if len(self.kinds) > 1:
+            raise NotImplementedError(
+                f"ServeEngine: {mechanism} is not wired for a model with "
+                f"{len(self.kinds)} kinds of paged cache "
+                f"({', '.join(k.name for k in self.kinds)}: family "
+                f"{getattr(self.config, 'family', 'gpt')!r})"
+            )
+
     def hot_swap(
         self,
         params: GPTParams,
@@ -1229,6 +1331,7 @@ class ServeEngine:
         docs/ROBUSTNESS.md "Zero-downtime model ops"."""
         from midgpt_tpu.sampling import ops as _ops
 
+        self._refuse_several_kinds("a staged weight swap (fleet hot-swap)")
         return _ops.stage_hot_swap(
             self, params, draft_params=draft_params, version=version,
             config=config,
@@ -1247,6 +1350,7 @@ class ServeEngine:
         (sampling/ops.py)."""
         from midgpt_tpu.sampling import ops as _ops
 
+        self._refuse_several_kinds("a live pool resize")
         # A resize migrates the resident working set out of self.cache —
         # an unsettled in-flight group still writing into the OLD pool
         # must land (and its tokens commit) before the migration reads it.
@@ -1262,6 +1366,7 @@ class ServeEngine:
         CURRENT weights_version so a hot swap can never resurrect
         old-weights KV. Requires the prefix cache: the trie is both the
         spill source and the re-adoption anchor."""
+        self._refuse_several_kinds("the host-RAM spill tier")
         if self.prefix_cache is None:
             raise ValueError("attach_spill requires prefix_cache=True")
         tier.set_page_size(self.page_size)
@@ -1285,7 +1390,7 @@ class ServeEngine:
         corrupt spill bytes can never reach a decode."""
         tier = self.spill_tier
         ps = self.page_size
-        start = len(slot.pages)
+        start = len(slot.pages[0])
         limit = (len(req.prompt) - 1) // ps - start
         if limit <= 0:
             return
@@ -1342,7 +1447,7 @@ class ServeEngine:
                 dst,
                 {k: jnp.asarray(b) for k, b in blocks.items()},
             )
-        slot.pages.extend(got)
+        slot.pages[0].extend(got)
         slot.prompt_pos = slot.length = (start + m) * ps
         self._prefix_matched_tokens += m * ps  # a cross-tier hit is a hit
         self.spill_readopted_pages += m
@@ -1397,6 +1502,7 @@ class ServeEngine:
         programs = {
             "prefill": _serve_prefill_chunk,
             "decode": _serve_decode_chunk,
+            "decode_logits": _serve_decode_logits,
             "decode_group": _serve_decode_group,
             "spec_draft": _spec_draft_chunk,
             "spec_verify": _spec_verify_chunk,
@@ -1412,6 +1518,18 @@ class ServeEngine:
             ).items()
         }
         return stats
+
+    @staticmethod
+    def program_texts() -> tp.Dict[str, str]:
+        """{serving program as compiled: its optimized HLO text}
+        (as `TrainRuntime.step_program_text()` for the step): what a reader of
+        a device trace joins an op's instruction name to the `named_scope`
+        that opened it with. Process-global like `compile_stats`."""
+        return {
+            label: text
+            for p in (_serve_prefill_chunk, _serve_decode_chunk, _serve_decode_group)
+            for label, text in p.texts().items()
+        }
 
     def mesh_shape(self) -> tp.Optional[tp.Dict[str, int]]:
         """{'data': d, 'tp': t} when mesh-sharded, None single-chip."""
@@ -1448,16 +1566,42 @@ class ServeEngine:
             "resizes": self.resizes,
             "spill_readopted_pages": self.spill_readopted_pages,
             "spill_readopt_events": self.spill_readopt_events,
-            "window_reclaimed_pages": self.window_reclaimed_pages,
+            "window_reclaimed_pages": self.kind_reclaimed[0],
             "swap_pending": self._staged_swap is not None,
             "compile_counts": self.compile_stats(),
             # unified observability schema (docs/OBSERVABILITY.md): round
             # decomposition + metrics when an Observability is wired in,
             # {"enabled": False} otherwise — consumers key on the flag.
+            # The cache's and the family's counters ride in it.
             "obs": (
-                DISABLED_SNAPSHOT if self.obs is None else self.obs.snapshot()
+                DISABLED_SNAPSHOT
+                if self.obs is None
+                else {**self.obs.snapshot(), **self.serve_counters()}
             ),
         }
+
+    def serve_counters(self) -> tp.Dict[str, float]:
+        """Counters of the cache by kind and of the family's own layers
+        (docs/OBSERVABILITY.md), whether or not an Observability is wired:
+        `kv.<kind>_pages_live` (allocated now; `_pages_live_max`: at the peak),
+        `kv.<kind>_pages_reclaimed`
+        (freed by the window rule so far) and, for a windowed kind,
+        `kv.<kind>_tokens_per_slot_max` (the most one slot ever held, in
+        tokens: bounded by window + the longest write + a page); then what the
+        family's `serve_counters` reads off its cache (a device read: call it
+        between rounds, not in them)."""
+        out: tp.Dict[str, float] = {}
+        for i, (k, a) in enumerate(zip(self.kinds, self.allocators)):
+            out[f"kv.{k.name}_pages_live"] = a.num_pages - 1 - a.free_count
+            out[f"kv.{k.name}_pages_live_max"] = a.num_pages - 1 - a.free_min
+            out[f"kv.{k.name}_pages_reclaimed"] = self.kind_reclaimed[i]
+            if k.window:
+                out[f"kv.{k.name}_tokens_per_slot_max"] = (
+                    self.kind_slot_pages_max[i] * self.page_size
+                )
+        if self.model.serve_counters is not None:
+            out.update(self.model.serve_counters(self.config, self.cache))
+        return out
 
     # -- scheduling round ----------------------------------------------
 
@@ -1773,7 +1917,7 @@ class ServeEngine:
             active[i] = True
             if s.request.eos_id is not None:
                 eos[i] = s.request.eos_id
-            max_len[i] = min(_want(s), len(s.pages) * ps)
+            max_len[i] = min(_want(s), len(s.pages[0]) * ps)  # every kind's list has one logical length
             chain_mask[i] = i in chained
             worst[i] = min(_base(i, s) + T, max_len[i])
         if self.temperature == 0.0:
@@ -1800,7 +1944,7 @@ class ServeEngine:
             self.params,
             jnp.asarray(token),
             self.cache,
-            jnp.asarray(self._page_table(bucket)),
+            self._device_tables(bucket),
             jnp.asarray(lengths),
             jnp.asarray(active),
             jnp.asarray(eos),
@@ -1885,14 +2029,14 @@ class ServeEngine:
             (
                 s
                 for s in self.slots
-                if s is not None and any(p >= 0 for p in s.pages)
+                if s is not None and any(p >= 0 for p in s.pages[0])
             ),
             key=lambda s: s.admit_order,
             default=None,
         )
         if victim is None:
             return
-        page = next(p for p in victim.pages if p >= 0)
+        page = next(p for p in victim.pages[0] if p >= 0)
         bad = (
             float("nan")
             if jnp.issubdtype(self.cache.k.dtype, jnp.floating)
@@ -1906,7 +2050,7 @@ class ServeEngine:
         for s in self.slots:
             if (
                 s is not None
-                and page in s.pages
+                and page in s.pages[0]
                 and s.request.uid not in self.poisoned_uids
             ):
                 self.poisoned_uids.append(s.request.uid)
@@ -1997,7 +2141,8 @@ class ServeEngine:
                 # A preempted request restarts its k adaptation from
                 # spec_k_max like a fresh one — the draft pool it re-prefills
                 # is fresh too, so old acceptance evidence is stale anyway.
-                slot = _Slot(req, self._admitted, spec_k=self.spec_k_max)
+                slot = _Slot(req, self._admitted, [[] for _ in self.kinds], [0] * len(self.kinds),
+                             spec_k=self.spec_k_max)
                 if self.prefix_cache is not None:
                     # Map every fully-matched page into the slot's table and
                     # skip its prefill: the slot starts committed at the
@@ -2012,7 +2157,7 @@ class ServeEngine:
                             req.prompt, max_tokens=len(req.prompt) - 1
                         )
                     if mr.pages:
-                        slot.pages = list(mr.pages)
+                        slot.pages[0] = list(mr.pages)
                         slot.n_shared = len(mr.pages)
                         slot.prompt_pos = slot.length = mr.tokens
                     ps = self.page_size
@@ -2043,15 +2188,25 @@ class ServeEngine:
         engine-enforced deadlock-freedom invariant: the oldest request
         always makes progress regardless of policy) and retries; False
         only when no younger victim exists or the policy defers."""
-        need = -(-upto_tokens // self.page_size) - len(slot.pages)
+        want = -(-upto_tokens // self.page_size)
+        for k, pages in enumerate(slot.pages):  # a plain loop: this runs for every slot every round
+            if not self._grow(slot, k, want - len(pages)):
+                return False
+        return True
+
+    def _grow(self, slot: _Slot, kind: int, need: int) -> bool:
+        """`need` more logical pages of `kind` for `slot` (_ensure_pages)."""
+        allocator = self.allocators[kind]
         while need > 0:
-            got = self.allocator.alloc(need)
+            got = allocator.alloc(need)
             if got is not None:
-                slot.pages.extend(got)
+                slot.pages[kind].extend(got)
+                if self.kinds[kind].window:
+                    self._note_growth(slot, kind)
                 return True
             if self.prefix_cache is not None:
                 reclaimed = self.prefix_cache.evict(
-                    need - self.allocator.free_count
+                    need - allocator.free_count
                 )
                 if reclaimed:
                     self.allocator.free(reclaimed)
@@ -2075,6 +2230,13 @@ class ServeEngine:
                 )
             self._evict(victim)
         return True
+
+    def _note_growth(self, slot: _Slot, kind: int) -> None:
+        """The most pages of a windowed kind one slot ever held (serve_counters):
+        its list less what the window rule has freed below `reclaimed_to`."""
+        k = self.kinds[kind]
+        held = len(slot.pages[kind]) - max(0, slot.reclaimed_to[kind] - -(-k.sinks // self.page_size))
+        self.kind_slot_pages_max[kind] = max(self.kind_slot_pages_max[kind], held)
 
     def _evict(self, victim: _Slot) -> None:
         """Recompute-style preemption: fold generated tokens into the
@@ -2128,27 +2290,40 @@ class ServeEngine:
         num_pages - 1 (tests/test_prefix_cache.py, chaos_serve.py)."""
         if self.prefix_cache is None:
             # -1 entries are window-reclaimed placeholders (already freed)
-            self.allocator.free(p for p in slot.pages if p >= 0)
+            for allocator, pages in zip(self.allocators, slot.pages):
+                allocator.free(p for p in pages if p >= 0)
             return
         with self._trace.span("trie.release", "prefix", self._obs_tid):
             committed = np.concatenate(
                 [slot.request.prompt, np.asarray(slot.generated, np.int32)]
             )[: slot.length]
             self.allocator.free(
-                self.prefix_cache.release(committed, slot.pages, slot.n_shared)
+                self.prefix_cache.release(committed, slot.pages[0], slot.n_shared)
             )
 
-    def _page_table(self, n_pages: tp.Optional[int] = None) -> np.ndarray:
+    def _page_table(self, n_pages: tp.Optional[int] = None, kind: int = 0) -> np.ndarray:
         table = np.zeros((self.max_slots, n_pages or self.max_pages_per_slot), np.int32)
         for i, s in enumerate(self.slots):
             if s is not None:
-                pages = s.pages[: table.shape[1]]
+                pages = s.pages[kind][: table.shape[1]]
                 table[i, : len(pages)] = pages
         # Window-reclaimed entries (-1 in slot.pages) park on the sink page:
         # the kernel sweep skips them and the mask hides their columns, but
         # the BlockSpec index map still needs a valid physical page.
         np.maximum(table, 0, out=table)
         return table
+
+    def _device_tables(self, n_pages: int, slot_i: tp.Optional[int] = None):
+        """The round's page table as the serving programs take it: the (slots,
+        n_pages) table of the first kind, or, where the family has several
+        kinds, the tuple of every kind's. `slot_i`: that slot's row alone."""
+        rows = slice(None) if slot_i is None else slice(slot_i, slot_i + 1)
+        first = jnp.asarray(self._page_table(n_pages)[rows])
+        if len(self.kinds) == 1:
+            return first
+        return (first, *(
+            jnp.asarray(self._page_table(n_pages, k)[rows]) for k in range(1, len(self.kinds))
+        ))
 
     def _reclaim_window(self, slot: _Slot) -> None:
         """Free this slot's pages that no FUTURE attention row can see.
@@ -2163,27 +2338,25 @@ class ServeEngine:
         under the prefix cache (the trie owns shared pages' lifetime) and
         speculative decoding (verify rollback re-reads recent history);
         conservation becomes free + live non-placeholder == num_pages - 1."""
-        W = self.config.sliding_window
-        if (
-            not W
-            or self.prefix_cache is not None
-            or self.draft_config is not None
-        ):
+        if self.prefix_cache is not None or self.draft_config is not None:
             return
         ps = self.page_size
-        first_live = max(0, slot.length - W) // ps  # pages below are dead
-        sink_pages = -(-self.config.attn_sinks // ps)  # keep the sink prefix
-        dead = [
-            j
-            for j in range(sink_pages, first_live)
-            if slot.pages[j] >= 0
-        ]
-        if not dead:
-            return
-        self.allocator.free(slot.pages[j] for j in dead)
-        for j in dead:
-            slot.pages[j] = -1
-        self.window_reclaimed_pages += len(dead)
+        for k, kind in enumerate(self.kinds):
+            if not kind.window:
+                continue
+            pages = slot.pages[k]
+            first_live = max(0, slot.length - kind.window) // ps  # pages below are dead
+            # keep the sink prefix; below `reclaimed_to` everything is freed already
+            start = max(-(-kind.sinks // ps), slot.reclaimed_to[k])
+            dead = [j for j in range(start, first_live) if pages[j] >= 0]
+            if first_live > slot.reclaimed_to[k]:
+                slot.reclaimed_to[k] = first_live
+            if not dead:
+                continue
+            self.allocators[k].free(pages[j] for j in dead)
+            for j in dead:
+                pages[j] = -1
+            self.kind_reclaimed[k] += len(dead)
 
     def _count_blocks(
         self,
@@ -2204,19 +2377,20 @@ class ServeEngine:
         no device work. Kernel path only: the gather lowering has no blocks."""
         if self.obs is None or self.attn_impl != "kernel":
             return
-        _, n_kv, _, ps, lanes = self.cache.k.shape
+        (_, n_kv, _, ps, lanes), groups, window, sinks = self.model.kernel_sweep(
+            self.config, self.cache
+        )
         n_tp = 1 if self.mesh is None else int(self.mesh.shape["tp"])
         n = block_pages(
-            n_kv // n_tp, lanes, self.cache.k.dtype.itemsize, ps,
+            n_kv // n_tp, lanes, self.cache_dtype.itemsize, ps,
             bucket // normalize_split_k(split_k, bucket),
-            n_rows * (self.config.n_head // n_kv),
+            n_rows * groups,
         )
         steps = np.arange(1, n_steps + 1)[:, None]  # (n_steps, B) below
         first = np.maximum(active * (lengths + steps), 1).ravel()
         self.obs.record_decode_blocks(
             *block_census(
-                first, first + n_rows - 1, bucket, n, ps,
-                self.config.sliding_window, self.config.attn_sinks,
+                first, first + n_rows - 1, bucket, n, ps, window, sinks,
             )
         )
 
@@ -2273,7 +2447,7 @@ class ServeEngine:
         chunk = np.zeros((1, self.prefill_chunk), np.int32)
         chunk[0, :n_valid] = prompt[slot.prompt_pos : slot.prompt_pos + n_valid]
         bucket = self._page_bucket(slot.prompt_pos + n_valid)
-        row = jnp.asarray(self._page_table(bucket)[slot_i : slot_i + 1])
+        row = self._device_tables(bucket, slot_i)
         chunk_j = jnp.asarray(chunk)
         start_j = jnp.asarray(slot.prompt_pos, jnp.int32)
         n_valid_j = jnp.asarray(n_valid, jnp.int32)
@@ -2325,7 +2499,7 @@ class ServeEngine:
                 # share them so concurrent and future requests — including
                 # this one after a preemption — skip their prefill.
                 slot.n_shared = self.prefix_cache.insert_live(
-                    prompt, slot.pages, slot.n_shared
+                    prompt, slot.pages[0], slot.n_shared
                 )
             # Prompt complete: sample the first generated token from the
             # last valid prompt position's logits (host-side; greedy argmax
@@ -2336,7 +2510,11 @@ class ServeEngine:
                 "prefill.first_token", "prefill", self._obs_tid,
                 slot.request.uid,
             ):
-                last = np.asarray(logits)[0, n_valid - 1]
+                # (1, chunk, V): every row's logits; (1, 1, V): the family
+                # hands out the last valid row's alone (models/__init__.py)
+                last = np.asarray(logits)[0, min(n_valid, logits.shape[1]) - 1]
+                if self.on_first_logits is not None:
+                    self.on_first_logits(slot.request.uid, last)
                 if self.temperature == 0.0:
                     tok = int(np.argmax(last.astype(np.float32)))
                 else:
@@ -2352,21 +2530,26 @@ class ServeEngine:
                     )
             self._append_token(slot_i, slot, tok, self._clock())
 
-    def _decode_round(self) -> None:
+    def _decode_budget(self) -> tp.Tuple[tp.List[int], int]:
+        """(the slots a decode round would run: every slot past its prompt
+        with tokens left; the steps none of them overshoots)."""
         active_idx = [
             i
             for i, s in enumerate(self.slots)
             if s is not None and not s.prefilling and s.remaining > 0
         ]
         if not active_idx:
-            return
+            return [], 0
         S = self.config.block_size
-        budget = min(
+        return active_idx, min(
             self.decode_chunk,
             min(self.slots[i].remaining for i in active_idx),
             min(S - self.slots[i].length for i in active_idx),
         )
-        n = 1 << (budget.bit_length() - 1)  # largest power of two <= budget
+
+    def _decode_pages(self, active_idx: tp.List[int], n: int) -> tp.List[int]:
+        """Pages for `n` more steps of every slot in `active_idx`; the slots
+        that got them (and were not evicted for another's)."""
         for i in list(active_idx):
             slot = self.slots[i]
             if slot is None:
@@ -2383,7 +2566,56 @@ class ServeEngine:
                 active_idx.remove(i)
         # A slot processed earlier in the loop can still be evicted by a
         # later, older slot's growth — drop any that went None.
-        active_idx = [i for i in active_idx if self.slots[i] is not None]
+        return [i for i in active_idx if self.slots[i] is not None]
+
+    def _decode_args(self, active_idx: tp.List[int], n: int):
+        """The host side of a decode dispatch over `active_idx` for `n` steps:
+        (token, lengths, active) (max_slots,) arrays, and the positions the
+        round's widest slot spans (what its page bucket and split-K factor
+        follow)."""
+        token = np.zeros((self.max_slots,), np.int32)
+        lengths = np.zeros((self.max_slots,), np.int32)
+        active = np.zeros((self.max_slots,), bool)
+        for i in active_idx:
+            s = self.slots[i]
+            token[i] = s.generated[-1] if s.generated else s.request.prompt[-1]
+            lengths[i] = s.length
+            active[i] = True
+        return token, lengths, active, max(self.slots[i].length for i in active_idx) + n
+
+    def next_logits(self) -> tp.Dict[int, np.ndarray]:
+        """uid -> float32 logits (V,) of the NEXT decode step of every slot
+        the next decode round would run: one step of the family's
+        `decode_step_paged` on that round's own arguments (this engine's
+        cache, the page tables as the allocators and the window rule left
+        them, the lengths, the page bucket and split-K factor), nothing
+        sampled or committed. What `_serve_decode_chunk` samples its first
+        token from; the step's K/V write is the one that round repeats. For
+        checks (benchmarks/serve_family_cell.py, tests), not the serving
+        loop: a program of its own a (page bucket, split), and a sync."""
+        self._settle_inflight()
+        active_idx, budget = self._decode_budget()
+        if not active_idx:
+            return {}
+        n = 1 << (budget.bit_length() - 1)  # as the round: the largest power of two <= budget
+        active_idx = self._decode_pages(active_idx, n)
+        if not active_idx:
+            return {}
+        token, lengths, active, round_span = self._decode_args(active_idx, n)
+        logits, self.cache = _serve_decode_logits(
+            self.config, self.params, jnp.asarray(token), self.cache,
+            self._device_tables(self._page_bucket(round_span)), jnp.asarray(lengths),
+            jnp.asarray(active), self.attn_impl, self.mesh, self._split_bucket(round_span),
+        )
+        logits = np.asarray(logits, np.float32)
+        return {self.slots[i].request.uid: logits[i] for i in active_idx}
+
+    def _decode_round(self) -> None:
+        active_idx, budget = self._decode_budget()
+        if not active_idx:
+            return
+        n = 1 << (budget.bit_length() - 1)  # largest power of two <= budget
+        active_idx = self._decode_pages(active_idx, n)
         if not active_idx:
             return
 
@@ -2393,19 +2625,11 @@ class ServeEngine:
         # t_done -> t_post is token commit.
         obs = self.obs
         t0 = 0.0 if obs is None else self._clock()
-        token = np.zeros((self.max_slots,), np.int32)
-        lengths = np.zeros((self.max_slots,), np.int32)
-        active = np.zeros((self.max_slots,), bool)
-        for i in active_idx:
-            s = self.slots[i]
-            token[i] = s.generated[-1] if s.generated else s.request.prompt[-1]
-            lengths[i] = s.length
-            active[i] = True
+        token, lengths, active, round_span = self._decode_args(active_idx, n)
         if self.temperature == 0.0:
             key = None
         else:
             self._key, key = jax.random.split(self._key)
-        round_span = max(self.slots[i].length for i in active_idx) + n
         bucket = self._page_bucket(round_span)
         self._count_blocks(
             lengths, active, bucket, self._split_bucket(round_span), n_steps=n
@@ -2415,7 +2639,7 @@ class ServeEngine:
             self.params,
             jnp.asarray(token),
             self.cache,
-            jnp.asarray(self._page_table(bucket)),
+            self._device_tables(bucket),
             jnp.asarray(lengths),
             jnp.asarray(active),
             n,
@@ -2612,9 +2836,9 @@ class ServeEngine:
             keep = max(
                 -(-slot.length // self.page_size), slot.n_shared
             )
-            if len(slot.pages) > keep:
-                tail = slot.pages[keep:]
-                del slot.pages[keep:]
+            if len(slot.pages[0]) > keep:
+                tail = slot.pages[0][keep:]
+                del slot.pages[0][keep:]
                 self.allocator.free(tail)
         if obs is not None:
             obs.record_round(

@@ -99,6 +99,7 @@ def make_train_step(
     # The model is reached through what every family's config and namespace
     # provide (models/__init__.py), never by name.
     model = model_cfg.model()
+    model_cfg.check_training("make_train_step")  # a family with no backward stops here, by name
     if mesh.shape["tp"] > 1 and model_cfg.qkv_proj == "fused":  # tp: the GPT only (check_experiment)
         # The fused lowering reshapes the tp-sharded feature axis into the
         # merged 3D axis (a reshard); the batched per-third form keeps each

@@ -81,7 +81,7 @@ def main() -> None:
     import numpy as np
 
     from midgpt_tpu.config import from_json
-    from midgpt_tpu.sampling.engine import generate, restore_for_sampling
+    from midgpt_tpu.sampling.engine import check_batch_engine, generate, restore_for_sampling
     from midgpt_tpu.utils.precision import cast_floating
 
     config_path = os.path.join(args.ckpt_dir, "config.json")
@@ -96,7 +96,9 @@ def main() -> None:
     model_cfg = config.model_config
     try:
         model_cfg.check_serving("sample.py")
-    except NotImplementedError as e:  # a model family the serving stack does not hold yet
+        if args.engine != "continuous":
+            check_batch_engine(model_cfg)
+    except NotImplementedError as e:  # a model family the serving stack, or the engine asked for, does not hold
         raise SystemExit(str(e))
     print(config)
 
@@ -108,7 +110,9 @@ def main() -> None:
     except FileNotFoundError as e:
         raise SystemExit(str(e))
     print(f"restored checkpoint step {step}")
-    params = cast_floating(params, jnp.dtype(config.compute_dtype))
+    # the family's own compute copy (models/__init__.py): what it keeps in
+    # float32 (a router, a sink logit) stays so
+    params = model_cfg.model().cast_params(params, jnp.dtype(config.compute_dtype))
 
     # Tokenizer: dataset-shipped codec if present (char stoi/itos, or an
     # offline-trained HF BPE from data/local_text/prepare.py), else GPT-2 BPE

@@ -314,7 +314,7 @@ def test_window_engine_reclaims_pages_and_stays_bounded(monkeypatch):
 
     def spy(self, slot_i, slot, tok, t):
         ok = orig(self, slot_i, slot, tok, t)
-        live_high.append(sum(p >= 0 for p in slot.pages))
+        live_high.append(sum(p >= 0 for p in slot.pages[0]))
         return ok
 
     monkeypatch.setattr(ServeEngine, "_append_token", spy)

@@ -449,3 +449,37 @@ def test_shard_map_fsdp_gathers_overlap_compute_on_four_chips(topo):
     assert {b["kind"] for b in census} == {"forward", "backward"}, census
     serialized = [b for b in census if b["annotated"] + b["fused"] == 0]
     assert not serialized, f"scan bodies whose weight gathers all run behind compute: {serialized}"
+
+
+@pytest.mark.parametrize("program", ["decode8", "prefill512"])
+def test_two_kind_serving_program_never_relays_out_either_pool(program, one_chip, compiled_kernels):
+    """models/mimo_v2.py at its published head widths (q/k 192, v 128; 4 K/V
+    heads in the global layer, 8 in the window layers; three layers, two held
+    experts): the decode program (the global layer through the template with
+    `v_dim`, the window layers through an XLA gather) and the prefill program
+    compile for the v5e with NO copy as large as either kind's pool or one
+    layer of it, and every layer's K/V write is the in-place Pallas one."""
+    import dataclasses
+
+    from midgpt_tpu.analysis.hlo_audit import pool_relayouts
+    from midgpt_tpu.config import load_config
+    from midgpt_tpu.sampling import serve
+
+    mc = dataclasses.replace(load_config("mimo_v2_5").model_config, n_layer=3, n_experts_held=2, vocab_size=512)
+    model = mc.model()
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(sds, jax.eval_shape(lambda k: model.cast_params(model.init(mc, k), jnp.bfloat16), jax.random.PRNGKey(0)))
+    cache = jax.tree.map(sds, jax.eval_shape(lambda: model.init_cache(mc, (2049, 673), 32, jnp.bfloat16, kernel_layout=True)))
+    assert cache.gk.shape[-1] == 256 and cache.gv.shape[-1] == 128  # K 192 at whole lanes, V 128
+    arr = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    B, T = 32, 64
+    if program == "decode8":
+        lowered = serve._serve_decode_chunk.lower(
+            mc, params, arr((B,)), cache, (arr((B, T)), arr((B, T))), arr((B,)), arr((B,), jnp.bool_), 8,
+            0.8, None, None, "kernel", arr((2,), jnp.uint32), None, 2)
+    else:
+        lowered = serve._serve_prefill_chunk.lower(
+            mc, params, arr((1, 512)), arr(()), arr(()), cache, (arr((1, T)), arr((1, T))), None, "kernel")
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") == (4 if program == "decode8" else 3)  # 3 writes (+ the global layer's attention)
+    assert pool_relayouts(text, [a.shape for a in cache.pool_arrays()]) == 0

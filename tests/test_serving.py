@@ -152,7 +152,7 @@ def test_serve_pages_grow_lazily(params):
     eng._admit()
     eng._prefill_round()  # 16 of 40 prompt tokens -> 2 pages
     slot = eng.slots[0]
-    assert slot.prompt_pos == 16 and len(slot.pages) == 2
+    assert slot.prompt_pos == 16 and len(slot.pages[0]) == 2
 
 
 def test_serve_interleaves_prefill_with_decode(params):
@@ -338,7 +338,7 @@ def test_cancel_during_prefill_conserves_pages(params):
     slot = next(
         s for s in eng.slots if s is not None and s.request.uid == u_victim
     )
-    assert slot.prefilling and slot.pages, "victim must be mid-prefill"
+    assert slot.prefilling and slot.pages[0], "victim must be mid-prefill"
     assert eng.cancel(u_victim)
     assert eng.finished[u_victim].status == "cancelled"
     assert len(eng.finished[u_victim].tokens) == len(p_victim)  # prompt only

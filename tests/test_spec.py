@@ -208,10 +208,10 @@ def test_spec_rollback_is_page_aligned(params):
         for slot in eng.slots:
             if slot is None:
                 continue
-            assert len(slot.pages) == -(-slot.length // eng.page_size), (
-                slot.length, slot.pages,
+            assert len(slot.pages[0]) == -(-slot.length // eng.page_size), (
+                slot.length, slot.pages[0],
             )
-            held += len(slot.pages)
+            held += len(slot.pages[0])
         # conservation: every page is either free or held by a live slot
         assert eng.allocator.free_count + held == eng.allocator.num_pages - 1
         rejected_rounds += eng._spec_drafted > eng._spec_accepted
