@@ -51,12 +51,27 @@ The SERVING members, of every family whose `check_serving` returns None
                                `num_pages[i]` pages for kind i; the cache is a
                                pytree with `pool_arrays()` (its page pools, for
                                the layout census), `page_size`, `num_pages`
-    prefill_paged_chunk(config, params, tokens (1, T), start, n_valid, cache,
-        page_table, attn_impl, mesh) -> (logits, cache)
-                               `page_table`: the slot's (1, pages) row, or the
-                               tuple of every kind's where there are several.
-                               logits (1, T, V), or (1, 1, V): the last valid
-                               row's alone (all the engine reads)
+    prefill_batched            True: `prefill_paged_chunk` takes the chunks of
+                               B slots as the rows of one batch; False: one
+                               row a call (MimoV2: two tables and a window
+                               slice a slot), and the engine's
+                               `prefill_width` is 1
+    prefill_paged_chunk(config, params, tokens (B, T), start (B,),
+        n_valid (B,), cache, page_table (B, pages), attn_impl, mesh)
+        -> (logits (B, V), cache)
+                               row b: the chunk [start[b], start[b] +
+                               n_valid[b]) of one slot, `page_table[b]` its
+                               pages; n_valid 0: an empty place that writes
+                               nothing. logits: the row at each slot's last
+                               valid position (all the engine reads).
+                               The ONE-ROW call, which every family takes and
+                               an engine of width 1 makes (a family that is
+                               not `prefill_batched`, or shapes that give 1):
+                               tokens (1, T), SCALAR start / n_valid,
+                               `page_table` the slot's (1, pages) row (the
+                               tuple of every kind's where there are
+                               several), logits (1, T, V), or (1, 1, V): the
+                               last valid row's alone
     decode_step_paged(config, params, token (B,), cache, page_table, lengths,
         active, attn_impl, mesh, split_k) -> (logits (B, V), cache)
     verify_step_paged          the speculative verify step with decode's

@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from midgpt_tpu.ops.attention import flash_or_blockwise, multihead_attention
+from midgpt_tpu.ops.attention import flash_or_blockwise, multihead_attention, visible_mask
 from midgpt_tpu.ops.dropout import dropout
 from midgpt_tpu.ops.norms import head_layer_norm, rms_norm
 from midgpt_tpu.ops.quant import dequantize_q8, quantize_q8
@@ -554,25 +554,25 @@ def _gather_layer_kv(
     pool: Array,  # (L, H, P, ps, C) — the whole K or V pool
     scales: tp.Optional[Array],  # (L, P, H, ps) f32 | None
     i: Array,  # () int — layer index
-    page_rows: Array,  # (MP,) int32 — one slot's logical->physical pages
+    page_rows: Array,  # (..., MP) int32 — each slot's logical->physical pages
     out_dtype,
     head_dim: int,  # C: the pool's lanes past it are padding
 ) -> Array:
-    """Gather one slot's pages of layer i contiguous -> (H, MP*ps, C),
+    """Gather each slot's pages of layer i contiguous -> (..., H, MP*ps, C),
     dequantizing after the gather in int8 mode (the CPU sibling of the
     kernel's in-VMEM dequant). Used by prefill's inline attention; the
-    batched variant lives in kernels/decode_attention.py. ONE gather whose
-    indices carry the layer beside the page reads the MP pages from the
+    per-layer-pool variant lives in kernels/decode_attention.py. ONE gather
+    whose indices carry the layer beside the page reads the pages from the
     pool where it lies: slicing the layer out first makes the TPU compiler
     materialise it, a layer-sized copy per tensor per layer (PR 25)."""
     _, H, _, ps, _ = pool.shape
-    MP, C = page_rows.shape[0], head_dim
-    g = pool[i, :, page_rows][..., :C]  # (MP, H, ps, C): advanced dims lead
-    g = g.transpose(1, 0, 2, 3).reshape(H, MP * ps, C)
+    lead, MP, C = page_rows.shape[:-1], page_rows.shape[-1], head_dim
+    g = pool[i, :, page_rows][..., :C]  # (..., MP, H, ps, C): advanced dims lead
+    g = jnp.moveaxis(g, -3, -4).reshape(*lead, H, MP * ps, C)
     if scales is None:
         return g
-    sg = scales[i, page_rows]  # (MP, H, ps)
-    sg = sg.transpose(1, 0, 2).reshape(H, MP * ps)
+    sg = scales[i, page_rows]  # (..., MP, H, ps)
+    sg = jnp.moveaxis(sg, -2, -3).reshape(*lead, H, MP * ps)
     return dequantize_q8(g, sg).astype(out_dtype)
 
 
@@ -1556,99 +1556,114 @@ class GPT:
     def prefill_paged_chunk(
         config: GPTConfig,
         params: GPTParams,
-        tokens: Array,  # (1, T_c) int — one request's prompt chunk, padded
-        start: Array,  # () int32 — absolute position of tokens[0, 0]
-        n_valid: Array,  # () int32 — real tokens in this chunk (rest is pad)
+        tokens: Array,  # (B, T_c) int — one prompt chunk a row, padded
+        start: Array,  # (B,) int32 — absolute position of tokens[b, 0]; or ()
+        n_valid: Array,  # (B,) int32 — real tokens in row b (rest is pad); or ()
         cache: "PagedKVCache",
-        page_table: Array,  # (1, max_pages) int32
+        page_table: Array,  # (B, max_pages) int32
         attn_impl: str = "auto",
         mesh=None,  # Optional[Mesh] — tp serving mesh (parallel/serve_tp.py)
     ) -> tp.Tuple[Array, "PagedKVCache"]:
-        """Prefill ONE request's prompt chunk [start, start + n_valid) into
-        its pages, attending causally to the chunk itself plus everything
-        the slot already holds ([0, start) — earlier chunks).
+        """Prefill the prompt chunks of B requests, row b's being
+        [start[b], start[b] + n_valid[b]), into their pages; each row
+        attends causally to its own chunk plus everything its slot already
+        holds ([0, start[b]) — earlier chunks, or a prefix the cache handed
+        it). A row with n_valid == 0 is an empty place: it writes nothing
+        and reads one masked-in key of whatever page its table row names
+        (the engine: the sink page), and its logits are garbage.
+
+        The rows of a round ride ONE program so that the round reads the
+        weights once (sampling/serve.py `PREFILL_ROWS`), and the program
+        hands back the ONE logits row a slot that the engine samples from,
+        (B, V): the row at each slot's last valid position. Called with a
+        SCALAR `start` and `n_valid` (one row, B = 1) it hands back every
+        row's logits, (1, T_c, V), for a caller that compares them all.
 
         Chunking is what lets the scheduler interleave long-prompt admission
         with running decodes: each serve round spends at most T_c prompt
-        tokens of work before the batch decodes again (docs/SERVING.md).
+        tokens a slot before the batch decodes again (docs/SERVING.md).
         T_c is static — the engine pads the tail chunk and passes n_valid;
         pad positions are redirected to an out-of-range page index so the
         scatter DROPS them (XLA oob-scatter semantics) instead of clobbering
         allocated pages, and pad logits are garbage the caller ignores.
 
-        Attention here is an XLA gather path only: the slot's pages are
-        gathered contiguous ONCE per layer and all T_c chunk rows attend to
+        Attention here is an XLA gather path only: each row's pages are
+        gathered contiguous ONCE per layer and its T_c chunk rows attend to
         that buffer under per-row length masks (the Pallas decode kernel's
         one-query-row online-softmax shape doesn't fit a chunk — a
         chunked-prefill kernel is the TPU upgrade path, docs/SERVING.md).
         `attn_impl` therefore chooses the WRITE only: 'kernel' (what 'auto'
-        resolves to on a TPU) stores the chunk's K/V with the in-place
+        resolves to on a TPU) stores the chunks' K/V with the in-place
         Pallas write, so that this program too holds no scatter on the pool
         and keeps it in the layout the decode kernels read; `mesh` routes
-        that write per tp shard.
+        that write per tp shard over the flattened B x T_c rows.
 
-        Returns (logits (1, T_c, V), updated cache)."""
+        Returns (logits (B, V) — or (1, T_c, V) for a scalar `start` —,
+        updated cache)."""
         from midgpt_tpu.kernels.decode_attention import resolve_paged_impl
+        from midgpt_tpu.ops.rope import apply_rope_positions
 
         attn_impl = resolve_paged_impl(attn_impl)
+        every_row = jnp.ndim(start) == 0  # the one-row call: all T_c logits
+        start, n_valid = jnp.reshape(start, (-1,)), jnp.reshape(n_valid, (-1,))
         _, T_c = tokens.shape
         C = config.head_dim
         ps = cache.page_size
         t_idx = jnp.arange(T_c, dtype=jnp.int32)
-        positions = start + t_idx  # (T_c,)
-        valid = t_idx < n_valid
+        positions = start[:, None] + t_idx  # (B, T_c)
+        valid = t_idx < n_valid[:, None]
         # Pad writes go out of range -> dropped by the scatter.
         write_pages = jnp.where(
             valid,
-            jnp.take(page_table[0], positions // ps, axis=0),
+            jnp.take_along_axis(page_table, positions // ps, axis=1),
             cache.num_pages,
         )
         offs = positions % ps
-        x = jnp.take(params.wte, tokens, axis=0)  # (1, T_c, D)
+        x = jnp.take(params.wte, tokens, axis=0)  # (B, T_c, D)
         sin, cos = rope_table(C, config.block_size)
-        # The chunk attends to attn_count = start + t + 1 keys at row t; pad
-        # rows clamp to the last valid count (their output is discarded).
-        attn_counts = jnp.minimum(positions, start + n_valid - 1) + 1  # (T_c,)
+        # Row t of a chunk attends to start + t + 1 keys; pad rows clamp to
+        # the last valid count (their output is discarded), an empty row's
+        # to one key.
+        attn_counts = jnp.maximum(
+            jnp.minimum(positions, (start + n_valid)[:, None] - 1) + 1, 1
+        )  # (B, T_c)
 
         def block_fn(carry, block_and_idx):
             x, ck_all, cv_all, cks_all, cvs_all = carry
             block, i = block_and_idx
             h = rms_norm(x)
-            q, k, v = GPT._project_qkv(config, block, h)  # k/v (1, T_c, HK, C)
-            qr = apply_rope_bthc(q, sin, cos, positions, style=config.rope_style)
-            kr = apply_rope_bthc(k, sin, cos, positions, style=config.rope_style)
-            # kr[0]/v[0] are (T_c, H, C): one (T_c,)-indexed column write
-            # (quantized with per-vector scales in int8 mode).
+            q, k, v = GPT._project_qkv(config, block, h)  # k/v (B, T_c, HK, C)
+            qr = apply_rope_positions(q, sin, cos, positions, style=config.rope_style)
+            kr = apply_rope_positions(k, sin, cos, positions, style=config.rope_style)
+            # one (B, T_c)-indexed column write (quantized with per-vector
+            # scales in int8 mode).
             ck_all, cv_all, cks_all, cvs_all = _paged_write(
                 (ck_all, cv_all, cks_all, cvs_all), i, write_pages, offs,
-                kr[0], v[0], attn_impl, mesh,
+                kr, v, attn_impl, mesh,
             )
-            # Gather the slot's pages contiguous ONCE, straight from the
+            # Gather each row's pages contiguous ONCE, straight from the
             # whole pool (dequantizing after the gather in int8 mode);
-            # every chunk row attends to the same buffer under its own
+            # every chunk row attends to its slot's buffer under its own
             # length mask (same mask-then-scale-then-f32-softmax order as
             # decode_step).
-            kg = _gather_layer_kv(ck_all, cks_all, i, page_table[0], x.dtype, C)
-            vg = _gather_layer_kv(cv_all, cvs_all, i, page_table[0], x.dtype, C)
-            # GQA: gathered buffers are (HK, S, C) — broadcast to the query
-            # head count for the per-row masked attention.
-            kg = _repeat_kv(config, kg, 0)
-            vg = _repeat_kv(config, vg, 0)
-            S = kg.shape[1]
-            scores = jnp.einsum("thc,hsc->hts", qr[0].astype(kg.dtype), kg)
-            col = jnp.arange(S)[None, None, :]
-            ok = col < attn_counts[None, :, None]
-            if config.sliding_window:
-                keep = col >= attn_counts[None, :, None] - config.sliding_window
-                if config.attn_sinks:
-                    keep |= col < config.attn_sinks
-                ok &= keep
+            kg = _gather_layer_kv(ck_all, cks_all, i, page_table, x.dtype, C)
+            vg = _gather_layer_kv(cv_all, cvs_all, i, page_table, x.dtype, C)
+            # GQA: gathered buffers are (B, HK, S, C) — broadcast to the
+            # query head count for the per-row masked attention.
+            kg = _repeat_kv(config, kg, 1)
+            vg = _repeat_kv(config, vg, 1)
+            S = kg.shape[2]
+            scores = jnp.einsum("bthc,bhsc->bhts", qr.astype(kg.dtype), kg)
+            ok = visible_mask(
+                jnp.arange(S)[None, None, None, :], attn_counts[:, None, :, None],
+                config.sliding_window, config.attn_sinks,
+            )
             scores = jnp.where(ok, scores, float("-inf"))
             probs = jax.nn.softmax(
                 scores.astype(jnp.float32) / math.sqrt(C), axis=-1
             ).astype(kg.dtype)
-            att = jnp.einsum("hts,hsc->thc", probs, vg)  # (T_c, H, C)
-            x = GPT._attn_out_and_mlp(config, block, x, att[None].astype(x.dtype))
+            att = jnp.einsum("bhts,bhsc->bthc", probs, vg)  # (B, T_c, H, C)
+            x = GPT._attn_out_and_mlp(config, block, x, att.astype(x.dtype))
             return (x, ck_all, cv_all, cks_all, cvs_all), None
 
         carry = GPT._decode_layer_loop(
@@ -1658,14 +1673,20 @@ class GPT:
             params.blocks,
         )
         x, k_new, v_new, ks_new, vs_new = carry
+        if not every_row:
+            # the last valid row of each slot, before the head: the lm_head
+            # runs over B rows, not B x T_c
+            last = jnp.maximum(n_valid - 1, 0)[:, None, None]
+            x = jnp.take_along_axis(x, last, axis=1)  # (B, 1, D)
         x = rms_norm(x, eps=1e-5)
         logits = jnp.einsum("btd,vd->btv", x, params.lm_head)
-        return logits, PagedKVCache(
+        return logits if every_row else logits[:, 0], PagedKVCache(
             k=k_new, v=v_new, k_scale=ks_new, v_scale=vs_new
         )
 
     # -- the serving members of the family contract (models/__init__.py) --
     serve_counters = None  # no counters of its own beside the engine's
+    prefill_batched = True  # prefill_paged_chunk takes B rows, hands back (B, V)
 
     @staticmethod
     def cache_kinds(config: GPTConfig) -> tp.Tuple[CacheKind, ...]:

@@ -639,6 +639,9 @@ class MimoV2:
         logits = MimoV2._head(c, params, x)[:, 0]
         return logits, MimoKVCache.of(pools, moe_counts, totals)
 
+    # one row a call: two page tables a slot, window pages freed per slot
+    prefill_batched = False
+
     @staticmethod
     def prefill_paged_chunk(config: MimoV2Config, params: MimoV2Params, tokens: Array, start: Array,
                             n_valid: Array, cache: MimoKVCache, page_table: tp.Tuple[Array, Array],

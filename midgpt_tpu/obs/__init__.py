@@ -125,6 +125,10 @@ class Observability:
         self._h_round_chunks = h(
             "round_prefill_chunks", "prefill chunks that rode the round"
         )
+        self._h_round_calls = h(
+            "round_prefill_calls", "prefill programs enqueued in the round "
+            "(chunks / calls: slots that rode a program)"
+        )
         self._h_round_slots = h(
             "round_decode_slots", "slots holding a decoding request after the round"
         )
@@ -140,6 +144,14 @@ class Observability:
         self._g_live_share = self.metrics.gauge(
             "decode.live_block_share", "blocks_live / blocks_swept of the "
             "last decode round"
+        )
+        # prefill programs enqueued and the slot-chunks that rode them
+        self._c_prefill_calls = self.metrics.counter(
+            "prefill.calls", "prefill programs enqueued"
+        )
+        self._c_prefill_chunks = self.metrics.counter(
+            "prefill.chunks", "slot-chunks prefilled (chunks / calls rode "
+            "a program; / (calls x width): how full it was)"
         )
         _LIVE.append(self)
 
@@ -171,12 +183,15 @@ class Observability:
         )
 
     def record_engine_round(self, dur_s: float, prefill_chunks: int,
-                            decode_slots: int) -> None:
+                            prefill_calls: int, decode_slots: int) -> None:
         """One scheduler round's envelope (the `engine.round` span's own
         duration) and what rode it."""
         self._h_round.observe(dur_s)
         self._h_round_chunks.observe(prefill_chunks)
+        self._h_round_calls.observe(prefill_calls)
         self._h_round_slots.observe(decode_slots)
+        self._c_prefill_chunks.inc(prefill_chunks)
+        self._c_prefill_calls.inc(prefill_calls)
 
     def record_decode_blocks(self, swept: int, live: int) -> None:
         """One decode round's paged-attention grid: blocks swept and blocks

@@ -235,8 +235,14 @@ def _run_server(eng, trace):
     uid_to_idx: tp.Dict[int, int] = {}
 
     async def main():
+        # The bound is what ONE step can hand a client that has read all it
+        # was sent: the first token and a decode chunk. A step lower and a
+        # healthy client is shed unless its consumer wins a race INSIDE the
+        # step (the first token read before the chunk lands); between steps
+        # the loop runs the consumers before it starts the next one.
         server = AsyncServeServer(
-            eng, max_buffered_tokens=4, submit_retries=1, idle_poll_s=0.001
+            eng, max_buffered_tokens=1 + eng.decode_chunk, submit_retries=1,
+            idle_poll_s=0.001,
         )
         driver = asyncio.create_task(server.run())
 

@@ -14,13 +14,15 @@ Scheduling is host-side and runs every round (`ServeEngine.step`):
   1. **Admit** — waiting requests claim free slots (FCFS). Admission needs
      only enough free pages for the FIRST prefill chunk; later pages are
      allocated lazily as the request grows.
-  2. **Prefill** — ONE waiting slot advances its prompt by at most
+  2. **Prefill** — every waiting slot advances its prompt by at most
      `prefill_chunk` tokens (GPT.prefill_paged_chunk), so a 30k-token
      prompt costs each running generation at most one chunk of extra
      latency per round instead of stalling the batch for the whole prompt
      (the chunked-prefill lever, Sarathi/vLLM-style, adapted to XLA static
      shapes: the chunk is padded to a fixed width, so ONE compiled program
-     serves every chunk of every prompt).
+     a page bucket serves every chunk of every prompt). The round's chunks
+     ride as the rows of one `(prefill_width, prefill_chunk)` batch, so the
+     round reads the weights once for all of them (`PREFILL_ROWS`).
   3. **Decode** — all generating slots step together as one device program:
      a power-of-two-sized chain of `GPT.decode_step_paged` calls
      (`_serve_decode_chunk`, same dispatch-amortization scheme as
@@ -146,6 +148,7 @@ from midgpt_tpu.sampling.engine import sample_logits, warp_logits
 from midgpt_tpu.sampling.prefix_cache import PrefixCache
 from midgpt_tpu.sampling.scheduler import FCFSScheduler, Scheduler
 from midgpt_tpu.sampling.spec import speculative_accept
+from midgpt_tpu.utils.stack_chunk import call_on_own_chunk
 
 Array = jax.Array
 
@@ -184,6 +187,7 @@ class _PoolProgram:
         # label -> abstract (args, kwargs) of a kernel-path program, and
         # label -> relayout count once `pool_relayouts` has read its text
         self._compiled: tp.Dict[str, tp.Any] = {}
+        self._called: tp.Set[str] = set()  # labels a call was made under
         self._kernel_path: tp.Set[str] = set()
         self._relayouts: tp.Dict[str, int] = {}
 
@@ -193,19 +197,29 @@ class _PoolProgram:
         kernel = resolve_paged_impl(a.get("attn_impl", "gather")) == "kernel"
         # the pool is donated: describe it before the call
         pool = jax.tree.map(_abstract, a["cache"])
+        statics = [f"{k}={a[k]}" for k in ("n_steps", "k_steps", "round_group") if k in a]
+        widths = [
+            f"{k}={jax.tree.leaves(a[k])[0].shape[-1]}"  # a family's tables: the first kind's
+            for k in ("page_table", "page_table_row", "tokens", "drafts")
+            if k in a
+        ]
+        label = " ".join(
+            [self.__name__.lstrip("_"), *statics, *widths,
+             f"pool={pool.pool_arrays()[0].dtype}"]
+        )
         n_before = self._cache_size()
-        out = self.jit(*args, **kwargs)
+        if label in self._called:
+            out = self.jit(*args, **kwargs)
+        else:
+            # A program not called yet: this call traces and lowers it. Those
+            # ~80 frames go on a stack chunk of their own, or what the
+            # lowering costs turns on the sizes of the frames under this call
+            # (utils/stack_chunk.py). A program the label does not tell apart
+            # from one called before (another engine's statics) compiles
+            # from here.
+            self._called.add(label)
+            out = call_on_own_chunk(self.jit, *args, **kwargs)
         if self._cache_size() != n_before:  # this call compiled a program
-            statics = [f"{k}={a[k]}" for k in ("n_steps", "k_steps", "round_group") if k in a]
-            widths = [
-                f"{k}={jax.tree.leaves(a[k])[0].shape[-1]}"  # a family's tables: the first kind's
-                for k in ("page_table", "page_table_row", "tokens", "drafts")
-                if k in a
-            ]
-            label = " ".join(
-                [self.__name__.lstrip("_"), *statics, *widths,
-                 f"pool={pool.pool_arrays()[0].dtype}"]
-            )
             a["cache"] = pool
             self._compiled[label] = jax.tree.map(
                 _abstract, (bound.args, bound.kwargs)
@@ -261,9 +275,15 @@ def _serve_prefill_chunk(
     config, params, tokens, start, n_valid, cache, page_table_row, mesh=None,
     attn_impl: str = "gather",
 ):
-    """One prompt chunk into the pool. `attn_impl` (the engine's resolved
-    choice) selects the K/V WRITE only — the chunk's attention is an XLA
-    gather on every backend (GPT.prefill_paged_chunk)."""
+    """One prompt chunk a row into the pool: `tokens` (W, prefill_chunk),
+    `start` / `n_valid` (W,), `page_table_row` (W, pages), an empty row
+    having n_valid 0; hands back the logits of each row's last valid
+    position, (W, V). An engine of width 1 makes the family's one-row call
+    (models/__init__.py): `tokens` (1, prefill_chunk), SCALAR `start` /
+    `n_valid`, the slot's own table row(s), and the logits are what the
+    family hands out for it, (1, T, V) or (1, 1, V). `attn_impl` (the
+    engine's resolved choice) selects the K/V WRITE only — the chunk's
+    attention is an XLA gather on every backend (GPT.prefill_paged_chunk)."""
     logits, cache = config.model().prefill_paged_chunk(
         config, params, tokens, start, n_valid, cache, page_table_row,
         attn_impl=attn_impl, mesh=mesh,
@@ -335,6 +355,28 @@ def _serve_decode_logits(
         attn_impl=attn_impl, mesh=mesh, split_k=split_k,
     )
     return logits, _maybe_constrain(cache, mesh)
+
+
+# Rows a prefill program should bring to each weight it reads. A bf16 matmul
+# of R rows against a (D, N) weight does 2 R D N FLOP for 2 D N bytes of
+# weight: R FLOP a byte. The v5e's ridge is 197e12 FLOP/s / 819e9 B/s = 240
+# FLOP a byte, so under ~240 rows the program waits for the weights, and a
+# 16-token chunk alone reads the whole model for a fifteenth of that. 256 is
+# the ridge rounded to a power of two. The chunks of a round's prefilling
+# slots ride as rows of one batch until they make up that many.
+PREFILL_ROWS = 256
+
+
+def prefill_width(max_slots: int, prefill_chunk: int) -> int:
+    """Chunks (slots) a prefill program takes: as many as bring
+    `PREFILL_ROWS` token rows to a weight read, no more than there are
+    slots. STATIC for an engine: one program a page bucket whatever the
+    number of prefilling slots (a round with more goes in groups; a call
+    with fewer leaves empty rows), so the compile set does not grow and a
+    warm-up that runs requests alone visits every program. A chunk that
+    is past the ridge on its own (512 tokens) gives 1: the one-row call,
+    a slot at a time."""
+    return min(max_slots, max(1, PREFILL_ROWS // prefill_chunk))
 
 
 # Cap on the fused multi-round group size (docs/SERVING.md "Round-overlap
@@ -872,6 +914,11 @@ class ServeEngine:
         self.page_size = page_size
         self.max_slots = max_slots
         self.prefill_chunk = prefill_chunk
+        # rows of the prefill program: the module's rule at this engine's
+        # shapes; 1 for a family whose `prefill_paged_chunk` takes one row
+        self.prefill_width = (
+            prefill_width(max_slots, prefill_chunk) if self.model.prefill_batched else 1
+        )
         self.decode_chunk = decode_chunk
         self.temperature = temperature
         self.top_k, self.top_p = top_k, top_p
@@ -982,7 +1029,8 @@ class ServeEngine:
         self.cow_pages = 0
         self.prefix_evictions = 0
         self.prefilled_tokens = 0
-        self.prefill_chunks = 0
+        self.prefill_chunks = 0  # slot-chunks prefilled
+        self.prefill_calls = 0  # prefill programs enqueued (chunks / calls rode each)
         # Host-RAM KV spill tier (sampling/fleet.py SpillTier), wired by
         # attach_spill: evicted trie pages land there instead of being
         # discarded, and _admit re-adopts resident runs past the trie
@@ -1625,7 +1673,7 @@ class ServeEngine:
         Either order runs inside one `engine.round` span: the phases and
         the decode decomposition nest under it (the tracer records the
         parent), so its self time is the scheduler's own cost."""
-        chunks0 = self.prefill_chunks
+        chunks0, calls0 = self.prefill_chunks, self.prefill_calls
         with self._trace.span("engine.round", "round", self._obs_tid) as sp:
             if self.overlap == "double" and self.draft_params is None:
                 self._step_overlapped()
@@ -1633,7 +1681,7 @@ class ServeEngine:
                 self._step_classic()
         if self.obs is not None:
             self.obs.record_engine_round(
-                sp.dur, self.prefill_chunks - chunks0,
+                sp.dur, self.prefill_chunks - chunks0, self.prefill_calls - calls0,
                 sum(s is not None and not s.prefilling for s in self.slots),
             )
 
@@ -2313,17 +2361,22 @@ class ServeEngine:
         np.maximum(table, 0, out=table)
         return table
 
-    def _device_tables(self, n_pages: int, slot_i: tp.Optional[int] = None):
+    def _device_tables(self, n_pages: int, rows: tp.Optional[tp.Sequence[int]] = None):
         """The round's page table as the serving programs take it: the (slots,
         n_pages) table of the first kind, or, where the family has several
-        kinds, the tuple of every kind's. `slot_i`: that slot's row alone."""
-        rows = slice(None) if slot_i is None else slice(slot_i, slot_i + 1)
-        first = jnp.asarray(self._page_table(n_pages)[rows])
+        kinds, the tuple of every kind's. `rows`: those slots' rows alone, in
+        that order, then empty rows (the sink page) up to `prefill_width`."""
+        def table(kind: int) -> Array:
+            full = self._page_table(n_pages, kind)
+            if rows is None:
+                return jnp.asarray(full)
+            picked = np.zeros((self.prefill_width, full.shape[1]), np.int32)
+            picked[: len(rows)] = full[list(rows)]
+            return jnp.asarray(picked)
+
         if len(self.kinds) == 1:
-            return first
-        return (first, *(
-            jnp.asarray(self._page_table(n_pages, k)[rows]) for k in range(1, len(self.kinds))
-        ))
+            return table(0)
+        return tuple(table(k) for k in range(len(self.kinds)))
 
     def _reclaim_window(self, slot: _Slot) -> None:
         """Free this slot's pages that no FUTURE attention row can see.
@@ -2432,30 +2485,64 @@ class ServeEngine:
         One chunk per slot per round bounds how long any running decode
         stalls (a 30k prompt can't monopolize the device), while letting
         freshly admitted slots reach the decode batch in parallel — an
-        empty decode slot is pure lost throughput."""
-        for slot_i, slot in enumerate(self.slots):
+        empty decode slot is pure lost throughput. The chunks ride together
+        as the rows of one program, `prefill_width` of them a call: the
+        prefilling slots go in slot order in groups of that many, a group's
+        pages found and its call made before the next group is looked at.
+        `self.slots` is read as the walk reaches it: a slot that a group
+        before evicted for its pages is gone by then. At width 1 this is a
+        slot's pages, its call, the next slot."""
+        group: tp.List[tp.Tuple[int, _Slot]] = []
+        for slot_i in range(self.max_slots):
+            slot = self.slots[slot_i]
             if slot is not None and slot.prefilling:
-                self._prefill_one(slot_i, slot)
+                group.append((slot_i, slot))
+            if group and (len(group) == self.prefill_width or slot_i == self.max_slots - 1):
+                self._prefill_group(group)
+                group = []
 
-    def _prefill_one(self, slot_i: int, slot: _Slot) -> None:
-        prompt = slot.request.prompt
-        n_valid = min(self.prefill_chunk, len(prompt) - slot.prompt_pos)
-        if not self._ensure_pages(slot, slot.prompt_pos + n_valid):
-            return  # pool fully ours and still short — wait for finishes
-        if self.slots[slot_i] is not slot:  # evicted ourselves? (impossible)
-            return
-        chunk = np.zeros((1, self.prefill_chunk), np.int32)
-        chunk[0, :n_valid] = prompt[slot.prompt_pos : slot.prompt_pos + n_valid]
-        bucket = self._page_bucket(slot.prompt_pos + n_valid)
-        row = self._device_tables(bucket, slot_i)
+    def _prefill_group(self, group: tp.List[tp.Tuple[int, _Slot]]) -> None:
+        """Find the pages of every slot of `group`, then make ONE call over
+        those that have them. The pages come BEFORE the batch is built:
+        finding them may evict a younger slot, one of this group already
+        given its pages included, and an evicted slot is in no batch."""
+        rows: tp.List[tp.Tuple[int, _Slot, int]] = []
+        for slot_i, slot in group:
+            if self.slots[slot_i] is not slot:
+                continue  # evicted for the pages of a row before it
+            n_valid = min(self.prefill_chunk, len(slot.request.prompt) - slot.prompt_pos)
+            # False: pool fully ours and still short — wait for finishes
+            if self._ensure_pages(slot, slot.prompt_pos + n_valid):
+                rows.append((slot_i, slot, n_valid))
+        rows = [r for r in rows if self.slots[r[0]] is r[1]]
+        if rows:
+            self._prefill_call(rows)
+
+    def _prefill_call(self, rows: tp.List[tp.Tuple[int, _Slot, int]]) -> None:
+        """One prefill program over `rows` (slot index, slot, n_valid), at
+        most `prefill_width` of them and each with its pages in hand; then
+        the first token of every slot whose prompt that completed."""
+        W = self.prefill_width
+        chunk = np.zeros((W, self.prefill_chunk), np.int32)
+        start, n_valid = np.zeros((W,), np.int32), np.zeros((W,), np.int32)
+        for r, (_, slot, n) in enumerate(rows):
+            pos = slot.prompt_pos
+            chunk[r, :n] = slot.request.prompt[pos : pos + n]
+            start[r], n_valid[r] = pos, n
+        # the page bucket of a call is the bucket of its longest row
+        bucket = self._page_bucket(int((start + n_valid).max()))
+        table = self._device_tables(bucket, [slot_i for slot_i, _, _ in rows])
         chunk_j = jnp.asarray(chunk)
-        start_j = jnp.asarray(slot.prompt_pos, jnp.int32)
-        n_valid_j = jnp.asarray(n_valid, jnp.int32)
-        # Span covers host assembly + async ENQUEUE only — prefill logits
-        # are not forced here (mid-prompt chunks never sync; the final
-        # chunk's force happens in the first-token block below).
+        if W > 1:
+            start_j, n_valid_j = jnp.asarray(start), jnp.asarray(n_valid)
+        else:  # the one-row call every family takes: scalars
+            start_j, n_valid_j = jnp.asarray(start[0]), jnp.asarray(n_valid[0])
+        # Span covers host assembly + async ENQUEUE of ONE call only — the
+        # logits are not forced here (a call none of whose rows ends its
+        # prompt never syncs; the force happens in the first-token block
+        # below). It belongs to no one request: rid is the first row's.
         with self._trace.span(
-            "prefill.chunk", "prefill", self._obs_tid, slot.request.uid
+            "prefill.chunk", "prefill", self._obs_tid, rows[0][1].request.uid
         ):
             logits, self.cache = _serve_prefill_chunk(
                 self.config,
@@ -2464,7 +2551,7 @@ class ServeEngine:
                 start_j,
                 n_valid_j,
                 self.cache,
-                row,
+                table,
                 self.mesh,
                 self.attn_impl,
             )
@@ -2483,36 +2570,45 @@ class ServeEngine:
                     start_j,
                     n_valid_j,
                     self.draft_cache,
-                    row,
+                    table,
                     self.mesh,
                     self.attn_impl,
                 )
-        slot.prompt_pos += n_valid
-        slot.length = slot.prompt_pos
-        self._reclaim_window(slot)  # long prompts free behind-window pages
-        self.prefilled_tokens += n_valid
-        self.prefill_chunks += 1
-        if not slot.prefilling:
+        self.prefill_calls += 1
+        last_rows = None  # the call's logits on the host, pulled ONCE
+        for r, (slot_i, slot, n) in enumerate(rows):
+            slot.prompt_pos += n
+            slot.length = slot.prompt_pos
+            self._reclaim_window(slot)  # long prompts free behind-window pages
+            self.prefilled_tokens += n
+            self.prefill_chunks += 1
+            if slot.prefilling:
+                continue
             if self.prefix_cache is not None:
                 # The prompt's complete pages are immutable from here on
                 # (every later write lands at a position >= len(prompt)):
                 # share them so concurrent and future requests — including
                 # this one after a preemption — skip their prefill.
                 slot.n_shared = self.prefix_cache.insert_live(
-                    prompt, slot.pages[0], slot.n_shared
+                    slot.request.prompt, slot.pages[0], slot.n_shared
                 )
             # Prompt complete: sample the first generated token from the
-            # last valid prompt position's logits (host-side; greedy argmax
-            # matches engine.generate's sample_logits(temperature=0) exactly).
-            # The np.asarray is the force/sync — the span holds the device
-            # wait for the final prefill chunk plus the host sample.
+            # last valid prompt position's logits (host-side, in slot order;
+            # greedy argmax matches engine.generate's
+            # sample_logits(temperature=0) exactly). The np.asarray is the
+            # force/sync — the span of the call's first finisher holds the
+            # device wait for the call plus its host sample.
             with self._trace.span(
                 "prefill.first_token", "prefill", self._obs_tid,
                 slot.request.uid,
             ):
-                # (1, chunk, V): every row's logits; (1, 1, V): the family
-                # hands out the last valid row's alone (models/__init__.py)
-                last = np.asarray(logits)[0, min(n_valid, logits.shape[1]) - 1]
+                if last_rows is None:
+                    last_rows = np.asarray(logits)
+                    if last_rows.ndim == 3:
+                        # the one-row call's (1, chunk, V): every row's
+                        # logits; or (1, 1, V): the last valid row's alone
+                        last_rows = last_rows[:, min(rows[0][2], last_rows.shape[1]) - 1]
+                last = last_rows[r]
                 if self.on_first_logits is not None:
                     self.on_first_logits(slot.request.uid, last)
                 if self.temperature == 0.0:

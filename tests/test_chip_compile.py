@@ -276,9 +276,12 @@ def test_serving_program_never_relays_out_the_pool(program, heads, one_chip, com
             0.8, None, None, "kernel", key,
         )
     elif program == "prefill":
+        # the serving cells' batch: 16 slots' chunks of 16 tokens a program,
+        # at the page bucket of a 1024-token row (serve_xl_chat's longest)
+        W = serve.prefill_width(16, 16)
         lowered = serve._serve_prefill_chunk.lower(
-            cfg, params, arr((1, 16)), arr(()), arr(()), cache,
-            arr((1, SERVE_TABLE)), None, "kernel",
+            cfg, params, arr((W, 16)), arr((W,)), arr((W,)), cache,
+            arr((W, 128)), None, "kernel",
         )
     else:
         k = 4  # spec_k_max drafts + the pending token: 5 verify rows

@@ -284,7 +284,8 @@ def test_the_reference_blocks_its_queries_without_changing_its_result(model, mon
 # ---------------------------------------------------------------------------
 
 # sha256 of `.lower(...).as_text()` (StableHLO, no locations) on the CPU backend with the XLA
-# gather lowering, taken on the parent of PR 30 (commit 0410ecb) by this same function
+# gather lowering, taken on the parent of PR 30 (commit 0410ecb) by this same function; the two
+# `prefill16` entries: taken at PR 31, which made that program a batch of B rows handing back (B, V)
 GPT_PROGRAM_HASHES = json.load(open(os.path.join(os.path.dirname(__file__), "golden", "gpt_serving_programs_pr29.json")))
 
 
@@ -308,7 +309,7 @@ def test_gpt_serving_programs_lower_to_the_parents_text(name):
         elif program == "decode1_greedy":
             low = serve._serve_decode_chunk.lower(cfg, params, arr((B,)), cache, table, lengths, active, 1, 0.0, None, None, impl, None)
         elif program == "prefill16":
-            low = serve._serve_prefill_chunk.lower(cfg, params, arr((1, 16)), arr(()), arr(()), cache, arr((1, T)), None, impl)
+            low = serve._serve_prefill_chunk.lower(cfg, params, arr((B, 16)), arr((B,)), arr((B,)), cache, arr((B, T)), None, impl)
         else:
             low = serve._spec_verify_chunk.lower(cfg, params, arr((B,)), arr((4, B)), arr((4, B, cfg.vocab_size), jnp.float32),
                                                  cache, table, lengths, active, 0.8, None, None, impl, key)

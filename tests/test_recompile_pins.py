@@ -53,11 +53,11 @@ def params():
     return GPT.init(CFG, jax.random.PRNGKey(0))
 
 
-def _serve_mix(params, lengths, max_new, seed):
+def _serve_mix(params, lengths, max_new, seed, max_slots=3):
     eng = ServeEngine(
         CFG,
         params,
-        max_slots=3,
+        max_slots=max_slots,
         page_size=8,
         num_pages=25,  # full working set fits: no eviction churn in the pin
         prefill_chunk=16,
@@ -75,6 +75,22 @@ def _serve_mix(params, lengths, max_new, seed):
     for uid, (n, m) in uids.items():
         assert len(done[uid].tokens) == n + m
     return eng
+
+
+def test_prefill_is_one_program_a_page_bucket_however_many_slots_prefill(params):
+    """The prefill program's width is the engine's (`prefill_width`), not the
+    round's: a request served ALONE compiles every page bucket's program, and
+    rounds with two, three and four prefilling slots compile nothing."""
+    p0 = jit_cache_size(_serve_prefill_chunk)
+    eng = _serve_mix(params, (47,), (9,), seed=0, max_slots=4)  # a width no other test here has
+    assert eng.prefill_width == 4 and eng.prefill_calls == eng.prefill_chunks == 3
+    assert jit_cache_size(_serve_prefill_chunk) - p0 == 3  # page buckets {2, 4, 8}
+    with CompileCounter() as cc:
+        for n in (2, 3, 4):
+            eng = _serve_mix(params, (25, 34, 47, 40)[:n], (9, 17, 17, 9)[:n], seed=n, max_slots=4)
+            assert eng.prefill_calls < eng.prefill_chunks  # slots did ride together
+    assert cc.count == 0, f"a round with more prefilling slots compiled {cc.count} program(s)"
+    assert jit_cache_size(_serve_prefill_chunk) - p0 == 3
 
 
 def test_serve_mixes_exactly_one_decode_compile(params):

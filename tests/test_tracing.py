@@ -160,10 +160,17 @@ def test_request_queue_plus_prefill_is_time_to_first_token(params):
     assert hist["req_queue_s"]["n"] == hist["req_prefill_s"]["n"] == hist["req_decode_s"]["n"] == 4
     assert hist["round_s"]["n"] == eng.rounds
     assert hist["round_prefill_chunks"]["n"] == eng.rounds and hist["round_decode_slots"]["max"] <= 2
+    # chunks ride a round's prefill program together: never more programs than chunks
+    assert hist["round_prefill_calls"]["n"] == eng.rounds and hist["round_prefill_calls"]["max"] == 1
+    counters = eng.stats()["obs"]["counters"]
+    assert counters["prefill.calls"] == eng.prefill_calls < eng.prefill_chunks == counters["prefill.chunks"]
     assert eng._req_open == {}
-    # spans of one request share its id
-    chunk_rids = {e[8] for e in obs.tracer.events() if e[1] in ("prefill.chunk", "prefill.first_token")}
-    assert chunk_rids == set(uids)
+    # the first-token span is its request's; a prefill.chunk span is one CALL's
+    # enqueue, shared by the slots that rode it: it carries the first row's uid
+    evs = obs.tracer.events()
+    assert {e[8] for e in evs if e[1] == "prefill.first_token"} == set(uids)
+    assert {e[8] for e in evs if e[1] == "prefill.chunk"} <= set(uids)
+    assert sum(e[1] == "prefill.chunk" for e in evs) == eng.prefill_calls
 
 
 def test_preempted_request_gets_a_second_queue_leg(params):
