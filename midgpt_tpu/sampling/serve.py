@@ -116,8 +116,9 @@ timeout, cancel) — the async server's per-token streaming rides these.
 
 Greedy (temperature=0) serving is token-for-token identical to
 `engine.generate` on the same prompt (parity pin in tests/test_sampling.py);
-stochastic sampling draws from a different key stream (per-chunk splits per
-slot batch) and is only distributionally equivalent.
+stochastic sampling draws from a different key stream (one key a prefill
+call and a decode round, split over the slot batch inside the program) and
+is only distributionally equivalent.
 """
 
 from __future__ import annotations
@@ -270,25 +271,51 @@ def _abstract(a):
 
 
 @_PoolProgram
-@functools.partial(jax.jit, static_argnums=(0, 7, 8), donate_argnums=(5,))
+@functools.partial(
+    jax.jit, static_argnums=(0, 7, 8, 9, 10, 11), donate_argnums=(5,)
+)
 def _serve_prefill_chunk(
     config, params, tokens, start, n_valid, cache, page_table_row, mesh=None,
     attn_impl: str = "gather",
+    temperature: float = 0.0,
+    top_k=None,
+    top_p=None,
+    key=None,
 ):
-    """One prompt chunk a row into the pool: `tokens` (W, prefill_chunk),
-    `start` / `n_valid` (W,), `page_table_row` (W, pages), an empty row
-    having n_valid 0; hands back the logits of each row's last valid
-    position, (W, V). An engine of width 1 makes the family's one-row call
-    (models/__init__.py): `tokens` (1, prefill_chunk), SCALAR `start` /
-    `n_valid`, the slot's own table row(s), and the logits are what the
-    family hands out for it, (1, T, V) or (1, 1, V). `attn_impl` (the
-    engine's resolved choice) selects the K/V WRITE only — the chunk's
-    attention is an XLA gather on every backend (GPT.prefill_paged_chunk)."""
+    """One prompt chunk a row into the pool, and each row's next token
+    sampled at its end: `tokens` (W, prefill_chunk), `start` / `n_valid`
+    (W,), `page_table_row` (W, pages), an empty row having n_valid 0. An
+    engine of width 1 makes the family's one-row call (models/__init__.py):
+    `tokens` (1, prefill_chunk), SCALAR `start` / `n_valid`, the slot's own
+    table row(s). `attn_impl` (the engine's resolved choice) selects the K/V
+    WRITE only — the chunk's attention is an XLA gather on every backend
+    (GPT.prefill_paged_chunk).
+
+    What the family hands out is brought to one row of logits a slot, those
+    of its last valid position: (W, V) as it is, the one-row call's (1, T, V)
+    at `n_valid - 1`, its (1, 1, V) as it is. The token of each row is
+    sampled from that row as `_serve_decode_chunk`'s step samples: the f32
+    argmax at `temperature` 0 (static, with `top_k` / `top_p`), else
+    `sample_logits` under `key`, one key split over the rows. Returns
+    (tokens (W,) int32, rows (W, V), cache): the first token of a row whose
+    prompt this chunk ends, and THE logits it was sampled from; an empty
+    row's pair means nothing."""
     logits, cache = config.model().prefill_paged_chunk(
         config, params, tokens, start, n_valid, cache, page_table_row,
         attn_impl=attn_impl, mesh=mesh,
     )
-    return logits, _maybe_constrain(cache, mesh)
+    if logits.ndim == 3:  # the one-row call: every position's, or the last's
+        if logits.shape[1] > 1:
+            logits = jax.lax.dynamic_index_in_dim(
+                logits, n_valid - 1, axis=1, keepdims=False
+            )
+        else:
+            logits = logits[:, 0]
+    if temperature == 0.0:
+        first = jnp.argmax(logits.astype(jnp.float32), axis=-1)
+    else:
+        first = sample_logits(logits, key, temperature, top_k, top_p)
+    return first.astype(jnp.int32), logits, _maybe_constrain(cache, mesh)
 
 
 @_PoolProgram
@@ -811,7 +838,8 @@ class ServeEngine:
         on_token: tp.Optional[tp.Callable[[int, int, float], None]] = None,
         on_finish: tp.Optional[tp.Callable[["FinishedRequest"], None]] = None,
         # (uid, logits (V,)): the prefill program's logits at a prompt's last
-        # position, what the first token is sampled from (checks)
+        # position, the row its first token was sampled from (checks). Set,
+        # it is what brings a call's logits to the host at all.
         on_first_logits: tp.Optional[tp.Callable[[int, np.ndarray], None]] = None,
         mesh=None,  # Optional[jax.sharding.Mesh] — parallel/serve_tp.py
         obs: tp.Optional[Observability] = None,
@@ -1031,6 +1059,8 @@ class ServeEngine:
         self.prefilled_tokens = 0
         self.prefill_chunks = 0  # slot-chunks prefilled
         self.prefill_calls = 0  # prefill programs enqueued (chunks / calls rode each)
+        self.first_tokens = 0  # first tokens taken from the prefill program's sample
+        self.first_logit_pulls = 0  # calls whose logits came to the host (on_first_logits)
         # Host-RAM KV spill tier (sampling/fleet.py SpillTier), wired by
         # attach_spill: evicted trie pages land there instead of being
         # discarded, and _admit re-adopts resident runs past the trie
@@ -1606,6 +1636,8 @@ class ServeEngine:
             "round_group": self.round_group,
             "overlap_kills": self.overlap_kills,
             "preemptions": self.preemptions,
+            "first_tokens": self.first_tokens,
+            "first_logit_pulls": self.first_logit_pulls,
             "timeouts": self.timeouts,
             "shed": self.shed,
             "cancelled": self.cancelled,
@@ -2521,7 +2553,10 @@ class ServeEngine:
     def _prefill_call(self, rows: tp.List[tp.Tuple[int, _Slot, int]]) -> None:
         """One prefill program over `rows` (slot index, slot, n_valid), at
         most `prefill_width` of them and each with its pages in hand; then
-        the first token of every slot whose prompt that completed."""
+        the first token of every slot whose prompt that completed. The
+        program samples it (`_serve_prefill_chunk`): the host pulls the
+        call's `prefill_width` int32 tokens, once, and the logits they were
+        sampled from only when `on_first_logits` asks for them."""
         W = self.prefill_width
         chunk = np.zeros((W, self.prefill_chunk), np.int32)
         start, n_valid = np.zeros((W,), np.int32), np.zeros((W,), np.int32)
@@ -2537,14 +2572,18 @@ class ServeEngine:
             start_j, n_valid_j = jnp.asarray(start), jnp.asarray(n_valid)
         else:  # the one-row call every family takes: scalars
             start_j, n_valid_j = jnp.asarray(start[0]), jnp.asarray(n_valid[0])
-        # Span covers host assembly + async ENQUEUE of ONE call only — the
-        # logits are not forced here (a call none of whose rows ends its
+        if self.temperature == 0.0:
+            key = None
+        else:  # one key a call, as `_decode_round` makes one a round
+            self._key, key = jax.random.split(self._key)
+        # Span covers host assembly + async ENQUEUE of ONE call only —
+        # nothing is forced here (a call none of whose rows ends its
         # prompt never syncs; the force happens in the first-token block
         # below). It belongs to no one request: rid is the first row's.
         with self._trace.span(
             "prefill.chunk", "prefill", self._obs_tid, rows[0][1].request.uid
         ):
-            logits, self.cache = _serve_prefill_chunk(
+            first, logits, self.cache = _serve_prefill_chunk(
                 self.config,
                 self.params,
                 chunk_j,
@@ -2554,16 +2593,20 @@ class ServeEngine:
                 table,
                 self.mesh,
                 self.attn_impl,
+                self.temperature,
+                self.top_k,
+                self.top_p,
+                key,
             )
             if self.draft_params is not None and not self.draft_shares_cache:
                 # A separate draft model's pool must hold the same positions
                 # as the target's — the spec round's draft steps attend
                 # through the shared page table under the same per-slot
-                # lengths. Draft prefill logits are discarded (the pending
-                # token is sampled from the TARGET). A prefix self-draft
+                # lengths. What the draft's program samples is discarded
+                # (the pending token is the TARGET's). A prefix self-draft
                 # skips this: the target prefill above already filled its
                 # layers of the shared pool.
-                _, self.draft_cache = _serve_prefill_chunk(
+                _, _, self.draft_cache = _serve_prefill_chunk(
                     self.draft_config,
                     self.draft_params,
                     chunk_j,
@@ -2575,7 +2618,7 @@ class ServeEngine:
                     self.attn_impl,
                 )
         self.prefill_calls += 1
-        last_rows = None  # the call's logits on the host, pulled ONCE
+        pulled = False  # the call's tokens are on the host: pulled ONCE
         for r, (slot_i, slot, n) in enumerate(rows):
             slot.prompt_pos += n
             slot.length = slot.prompt_pos
@@ -2592,38 +2635,26 @@ class ServeEngine:
                 slot.n_shared = self.prefix_cache.insert_live(
                     slot.request.prompt, slot.pages[0], slot.n_shared
                 )
-            # Prompt complete: sample the first generated token from the
-            # last valid prompt position's logits (host-side, in slot order;
-            # greedy argmax matches engine.generate's
-            # sample_logits(temperature=0) exactly). The np.asarray is the
-            # force/sync — the span of the call's first finisher holds the
-            # device wait for the call plus its host sample.
+            # Prompt complete: its first generated token is row r of what
+            # the program sampled (greedy: the f32 argmax, as
+            # engine.generate's sample_logits(temperature=0)). The
+            # np.asarray is the call's one force/sync — the span of the
+            # call's first finisher holds the device wait for the call plus
+            # the pull of its W tokens.
             with self._trace.span(
                 "prefill.first_token", "prefill", self._obs_tid,
                 slot.request.uid,
             ):
-                if last_rows is None:
-                    last_rows = np.asarray(logits)
-                    if last_rows.ndim == 3:
-                        # the one-row call's (1, chunk, V): every row's
-                        # logits; or (1, 1, V): the last valid row's alone
-                        last_rows = last_rows[:, min(rows[0][2], last_rows.shape[1]) - 1]
-                last = last_rows[r]
+                if not pulled:
+                    pulled = True
+                    first = np.asarray(first)
+                    if self.on_first_logits is not None:
+                        logits = np.asarray(logits)
+                        self.first_logit_pulls += 1
                 if self.on_first_logits is not None:
-                    self.on_first_logits(slot.request.uid, last)
-                if self.temperature == 0.0:
-                    tok = int(np.argmax(last.astype(np.float32)))
-                else:
-                    self._key, k = jax.random.split(self._key)
-                    tok = int(
-                        sample_logits(
-                            jnp.asarray(last)[None],
-                            k,
-                            self.temperature,
-                            self.top_k,
-                            self.top_p,
-                        )[0]
-                    )
+                    self.on_first_logits(slot.request.uid, logits[r])
+                tok = int(first[r])
+            self.first_tokens += 1
             self._append_token(slot_i, slot, tok, self._clock())
 
     def _decode_budget(self) -> tp.Tuple[tp.List[int], int]:

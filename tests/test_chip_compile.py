@@ -281,7 +281,7 @@ def test_serving_program_never_relays_out_the_pool(program, heads, one_chip, com
         W = serve.prefill_width(16, 16)
         lowered = serve._serve_prefill_chunk.lower(
             cfg, params, arr((W, 16)), arr((W,)), arr((W,)), cache,
-            arr((W, 128)), None, "kernel",
+            arr((W, 128)), None, "kernel", 0.8, None, None, key,
         )
     else:
         k = 4  # spec_k_max drafts + the pending token: 5 verify rows
@@ -482,7 +482,8 @@ def test_two_kind_serving_program_never_relays_out_either_pool(program, one_chip
             0.8, None, None, "kernel", arr((2,), jnp.uint32), None, 2)
     else:
         lowered = serve._serve_prefill_chunk.lower(
-            mc, params, arr((1, 512)), arr(()), arr(()), cache, (arr((1, T)), arr((1, T))), None, "kernel")
+            mc, params, arr((1, 512)), arr(()), arr(()), cache, (arr((1, T)), arr((1, T))), None, "kernel",
+            0.8, None, None, arr((2,), jnp.uint32))
     text = lowered.compile().as_text()
     assert text.count("tpu_custom_call") == (4 if program == "decode8" else 3)  # 3 writes (+ the global layer's attention)
     assert pool_relayouts(text, [a.shape for a in cache.pool_arrays()]) == 0

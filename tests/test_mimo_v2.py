@@ -285,7 +285,8 @@ def test_the_reference_blocks_its_queries_without_changing_its_result(model, mon
 
 # sha256 of `.lower(...).as_text()` (StableHLO, no locations) on the CPU backend with the XLA
 # gather lowering, taken on the parent of PR 30 (commit 0410ecb) by this same function; the two
-# `prefill16` entries: taken at PR 31, which made that program a batch of B rows handing back (B, V)
+# `prefill16` entries: taken at PR 35, whose program samples each row's first token at its end (temperature 0.8, as the
+# serving cells run it) and hands back (tokens (B,), rows (B, V)); PR 31 had made it a batch of B rows
 GPT_PROGRAM_HASHES = json.load(open(os.path.join(os.path.dirname(__file__), "golden", "gpt_serving_programs_pr29.json")))
 
 
@@ -309,7 +310,8 @@ def test_gpt_serving_programs_lower_to_the_parents_text(name):
         elif program == "decode1_greedy":
             low = serve._serve_decode_chunk.lower(cfg, params, arr((B,)), cache, table, lengths, active, 1, 0.0, None, None, impl, None)
         elif program == "prefill16":
-            low = serve._serve_prefill_chunk.lower(cfg, params, arr((B, 16)), arr((B,)), arr((B,)), cache, arr((B, T)), None, impl)
+            low = serve._serve_prefill_chunk.lower(cfg, params, arr((B, 16)), arr((B,)), arr((B,)), cache, arr((B, T)), None, impl,
+                                                   0.8, None, None, key)
         else:
             low = serve._spec_verify_chunk.lower(cfg, params, arr((B,)), arr((4, B)), arr((4, B, cfg.vocab_size), jnp.float32),
                                                  cache, table, lengths, active, 0.8, None, None, impl, key)

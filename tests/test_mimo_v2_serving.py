@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from midgpt_tpu.models.gpt import GPT, GPTConfig
 from midgpt_tpu.models.mimo_v2 import GLOBAL, WINDOW, MimoV2
 from midgpt_tpu.sampling.serve import ServeEngine
 from test_mimo_v2 import ROOT, _load, _tokens, model, toy  # noqa: F401 (model: the module-scoped fixture)
@@ -103,6 +104,97 @@ def test_engine_hands_out_the_logits_its_rounds_sample_from(model):
         assert len(later[uid]) >= 2 and all(r >= p for r, _ in later[uid])
         for r, row in later[uid]:
             np.testing.assert_allclose(row, want[r], atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the prefill program samples each row's first token itself
+# ---------------------------------------------------------------------------
+
+_GPT = GPTConfig(block_size=64, vocab_size=96, n_layer=2, n_head=2, n_embd=32)
+_PAGE = 4
+
+
+@pytest.fixture(scope="module")
+def gpt_params():
+    return GPT.init(_GPT, jax.random.PRNGKey(0))
+
+
+def _prefill_case(shape, model, gpt_params):
+    """(config, params, tokens, start, n_valid, a fresh cache, table, rows that hold a chunk) of one of the three
+    shapes a family hands logits out in (models/__init__.py `prefill_paged_chunk`)."""
+    i32 = lambda a: jnp.asarray(a, jnp.int32)
+    if shape == "batch":  # (W, V): four slots' chunks, one mid-prompt, one place empty
+        start, n_valid = [0, 8, 0, 0], [8, 3, 0, 5]
+        tokens = np.stack([_tokens(8, seed=r, vocab=_GPT.vocab_size) for r in range(4)])
+        table = 1 + np.arange(16, dtype=np.int32).reshape(4, 4)
+        table[2] = 0  # the empty place: the sink page
+        return (_GPT, gpt_params, i32(tokens), i32(start), i32(n_valid), lambda: GPT.init_cache(_GPT, (17,), _PAGE, jnp.float32),
+                i32(table), [0, 1, 3])
+    tokens, table = i32(_tokens(8, seed=9)[None] % 96), i32(np.arange(1, 3)[None])
+    if shape == "one_row_all":  # the GPT's one-row call: (1, T, V), n_valid < T
+        return (_GPT, gpt_params, tokens, i32(0), i32(5), lambda: GPT.init_cache(_GPT, (3,), _PAGE, jnp.float32), table, [0])
+    c, params = model  # MimoV2's: (1, 1, V), the last valid row's alone
+    return c, params, tokens, i32(0), i32(5), lambda: MimoV2.init_cache(c, (3, 3), _PAGE, jnp.float32), (table, table), [0]
+
+
+@pytest.mark.parametrize("temperature,top_k", [(0.0, None), (0.8, None), (0.8, 5)], ids=["greedy", "t0.8", "t0.8_top5"])
+@pytest.mark.parametrize("shape,family_gives", [("batch", (4, 96)), ("one_row_all", (1, 8, 96)), ("one_row_last", (1, 1, 97))])
+def test_prefill_program_samples_its_first_tokens_from_the_rows_it_hands_back(model, gpt_params, shape, family_gives,
+                                                                               temperature, top_k):
+    """`_serve_prefill_chunk` brings what the family hands out to one row a
+    slot, the last valid position's, and its tokens are `sample_logits` of
+    those rows under the key it was given (the f32 argmax at temperature 0)."""
+    from midgpt_tpu.sampling.engine import sample_logits
+    from midgpt_tpu.sampling.serve import _serve_prefill_chunk
+
+    config, params, tokens, start, n_valid, cache, table, live = _prefill_case(shape, model, gpt_params)
+    family = jax.jit(lambda p, ca: config.model().prefill_paged_chunk(config, p, tokens, start, n_valid, ca, table))
+    want = np.asarray(family(params, cache())[0])
+    assert want.shape == family_gives
+    if want.ndim == 3:
+        want = want[:, min(int(n_valid), want.shape[1]) - 1]
+    key = None if temperature == 0.0 else jax.random.PRNGKey(11)
+    first, rows, _ = _serve_prefill_chunk(config, params, tokens, start, n_valid, cache(), table, None, "gather",
+                                          temperature, top_k, None, key)
+    assert (first.shape, first.dtype, rows.shape) == ((tokens.shape[0],), jnp.int32, want.shape)
+    np.testing.assert_allclose(np.asarray(rows)[live], want[live], atol=1e-6)
+    if temperature == 0.0:
+        expect = np.argmax(np.asarray(rows, np.float32), axis=-1)
+    else:
+        expect = np.asarray(sample_logits(rows, key, temperature, top_k, None))
+        if top_k is not None:  # every token is one of its row's top five
+            assert (np.asarray(rows)[np.arange(len(expect)), expect] >= np.sort(np.asarray(rows), -1)[:, -top_k]).all()
+    np.testing.assert_array_equal(np.asarray(first), expect)
+
+
+@pytest.mark.parametrize("width", [16, 1])
+def test_on_first_logits_gets_the_row_the_first_token_was_sampled_from(model, gpt_params, width):
+    """The hook is what brings a call's logits to the host, once a call that
+    ends a prompt; greedy, each row it is handed argmaxes to the token the
+    engine appended. Width 16: the GPT, prompts ending together in one call;
+    width 1: MimoV2's one-row call."""
+    got = {}
+    hook = lambda uid, row: got.setdefault(uid, np.array(row))
+    if width == 16:
+        config, params = _GPT, gpt_params
+        eng = ServeEngine(config, params, max_slots=16, page_size=4, prefill_chunk=16, decode_chunk=4,
+                          cache_dtype="float32", on_first_logits=hook)
+        work = [(5, 4), (16, 3), (21, 5), (9, 2), (40, 4), (33, 1)]
+    else:
+        config, params = model
+        eng = ServeEngine(config, params, max_slots=3, page_size=4, prefill_chunk=10, decode_chunk=4,
+                          cache_dtype="float32", on_first_logits=hook)
+        work = [(37, 4), (5, 1), (23, 3)]
+    assert eng.prefill_width == width and eng.temperature == 0.0
+    uids = {eng.submit(_tokens(p, seed=p) % config.vocab_size, m): p for p, m in work}
+    done = eng.run()
+    assert set(got) == set(uids) == set(done)
+    for uid, p in uids.items():
+        assert got[uid].shape == (config.vocab_size,)
+        assert int(np.argmax(got[uid].astype(np.float32))) == done[uid].tokens[p]
+    assert eng.first_tokens == len(work)
+    # calls that ended a prompt: the width-16 engine's end three in its first call, one in its second, two in its third
+    assert eng.first_logit_pulls == (3 if width == 16 else len(work))
 
 
 def test_engine_conserves_both_pools_through_evict_and_cancel(model):

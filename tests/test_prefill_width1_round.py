@@ -21,9 +21,21 @@ one row a call) and a GPT whose shapes give 1 (3 slots, chunks of 512: a chunk
 past the ridge alone). Each is recorded in a process of its own: what an
 earlier test left in the process's trace caches would move the counts.
 
-The MimoV2 record has to equal the parent's in everything. The GPT's prefill
-PROGRAM is a new text (a batch of B rows, here B = 1), so the functions
-traced inside it differ; what is held to the parent there: the calls, the
+PR 35 moved the first token's sample into `_serve_prefill_chunk` (both engines
+are greedy: an f32 argmax at the program's end) and the program now takes
+`temperature`, `top_k`, `top_p` and `key` and hands back (tokens (1,), rows
+(1, V), cache). `calls` and `events` were regenerated on PR 35's finished tree
+by the same script; `tokens` and `preemptions` are the PARENT's objects, kept
+byte for byte: greedy output did not move. The ORDER of the `_ensure_pages` /
+`_device_tables` / `_serve_prefill_chunk` / `_reclaim_window` calls and every
+aval the parent recorded are still the parent's (what was added: the sampling
+arguments and the avals handed back); `lower` and `compile` came out the
+parent's name for name and count for count (`PARENT_LOWERED`), `trace` gained
+one `_argmax` a prefill program.
+
+The MimoV2 record has to equal the golden in everything. The GPT's prefill
+PROGRAM is a text of PR 32's (a batch of B rows, here B = 1), so the functions
+traced inside it differ from the parent's; what is held there: the calls, the
 argument avals, and the programs lowered and compiled, by name."""
 
 import collections
@@ -40,6 +52,14 @@ _EVENTS = {
     "/jax/core/compile/jaxpr_trace_duration": "trace",
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
     "/jax/core/compile/backend_compile_duration": "compile",
+}
+
+
+# every function the PARENT's golden lowered (and compiled), either case: no later tree may add an eager program
+PARENT_LOWERED = {
+    "_normal", "_serve_decode_chunk", "_serve_prefill_chunk", "_threefry_seed", "_threefry_split", "_truncated_normal",
+    "_unstack", "broadcast_in_dim", "convert_element_type", "dynamic_slice", "multiply", "reshape", "slice", "squeeze",
+    "true_divide",
 }
 
 
@@ -97,14 +117,15 @@ def record(case: str) -> dict:
     eng._device_tables = hook("device_tables", eng._device_tables, lambda n_pages, *rows: [n_pages])
     real_chunk, real_round = serve._serve_prefill_chunk, eng._prefill_round
 
-    def chunk(config, p, tokens, start, n_valid, cache, table, mesh, attn_impl):
-        logits, cache = real_chunk(config, p, tokens, start, n_valid, cache, table, mesh, attn_impl)
+    def chunk(config, p, tokens, start, n_valid, cache, table, mesh, attn_impl, temperature, top_k, top_p, key):
+        first, logits, cache = real_chunk(
+            config, p, tokens, start, n_valid, cache, table, mesh, attn_impl, temperature, top_k, top_p, key)
         calls.append([
             "serve_prefill_chunk", aval(tokens), aval(start), aval(n_valid),
             [aval(t) for t in jax.tree.leaves(table)], np.asarray(start).tolist(), np.asarray(n_valid).tolist(),
-            attn_impl, list(logits.shape),
+            attn_impl, [temperature, top_k, top_p, key], aval(first), list(logits.shape),
         ])
-        return logits, cache
+        return first, logits, cache
 
     def prefill_round():
         inside[0] = True
@@ -139,6 +160,8 @@ def test_width_1_round_is_the_parents_call_for_call(case):
     assert got["tokens"] == want["tokens"] and got["preemptions"] == want["preemptions"]
     assert got["events"]["lower"] == want["events"]["lower"]
     assert got["events"]["compile"] == want["events"]["compile"]
+    for kind in ("lower", "compile"):
+        assert set(got["events"][kind]) <= PARENT_LOWERED and got["events"][kind]["_serve_prefill_chunk"] == 4
     if case == "mimo":
         assert got["events"]["trace"] == want["events"]["trace"]
     else:  # the programs' own traces; what is traced inside the new prefill text differs

@@ -201,6 +201,44 @@ def test_serve_stochastic_sampling_runs(params):
         assert (out >= 0).all() and (out < CFG.vocab_size).all()
 
 
+def test_first_tokens_come_from_the_prefill_program_alone(params, monkeypatch):
+    """An engine without `on_first_logits` pulls no logits: every first token
+    is the prefill program's own sample, and no sampling function runs
+    eagerly between a round's programs (`sample_logits` sees tracers only)."""
+    from midgpt_tpu.sampling import serve
+
+    real = serve.sample_logits
+
+    def traced_only(logits, *args):
+        assert isinstance(logits, jax.core.Tracer), "sample_logits called eagerly in a serving round"
+        return real(logits, *args)
+
+    monkeypatch.setattr(serve, "sample_logits", traced_only)
+    trace = _trace()
+    # top_p: a setting no other test compiles, so the prefill program is traced under the patch
+    make = lambda: ServeEngine(
+        CFG, params, max_slots=3, num_pages=25, page_size=8, prefill_chunk=16, decode_chunk=4,
+        temperature=0.8, top_p=0.9, seed=3, cache_dtype=jnp.float32,
+    )
+    eng = make()
+    assert eng.prefill_width == 3
+    uids = [eng.submit(p, m) for p, m in trace]
+    done = eng.run()
+    assert eng.preemptions == 0 and set(done) == set(uids)
+    assert (eng.first_tokens, eng.first_logit_pulls) == (len(trace), 0)
+    stats = eng.stats()
+    assert (stats["first_tokens"], stats["first_logit_pulls"]) == (len(trace), 0)
+    for (p, m), u in zip(trace, uids):
+        out = done[u].tokens
+        assert len(out) == len(p) + m and (out >= 0).all() and (out < CFG.vocab_size).all()
+    # one seed gives one stream (journal / replay need no more)
+    again = make()
+    for p, m in trace:
+        again.submit(p, m)
+    for u, r in again.run().items():
+        np.testing.assert_array_equal(r.tokens, done[u].tokens)
+
+
 class FakeClock:
     """Injectable engine clock (satellite): TTL tests advance time
     explicitly instead of racing wall-clock sleeps on the 1-core host."""

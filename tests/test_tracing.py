@@ -171,6 +171,9 @@ def test_request_queue_plus_prefill_is_time_to_first_token(params):
     assert {e[8] for e in evs if e[1] == "prefill.first_token"} == set(uids)
     assert {e[8] for e in evs if e[1] == "prefill.chunk"} <= set(uids)
     assert sum(e[1] == "prefill.chunk" for e in evs) == eng.prefill_calls
+    # one first-token span a request, each closing on a token the device sampled; no hook, so no logits came over
+    assert sum(e[1] == "prefill.first_token" for e in evs) == eng.first_tokens == len(uids)
+    assert (eng.stats()["first_tokens"], eng.stats()["first_logit_pulls"]) == (len(uids), 0)
 
 
 def test_preempted_request_gets_a_second_queue_leg(params):
