@@ -11,6 +11,9 @@ Round decomposition semantics (docs/OBSERVABILITY.md has the full story):
 the engine loop reads its injected clock at four boundaries per round —
 
     t0      batch assembly starts
+              t_a  the numpy arguments, page bucket and split are chosen
+              t_k  the round's key is split (sampled rounds only)
+              t_p  the arguments are on the device
     t1      jit call returned (dispatch enqueued; NOT compute done)
     t_land  np.asarray(...) force returned — the round's one device
             sync: the tokens are on the host
@@ -21,7 +24,13 @@ the engine loop reads its injected clock at four boundaries per round —
 `t_host_post` = t_post-t_land. These aggregate to p50/p95 in histograms
 and surface on `stats()["obs"]["round_decomp"]`; the serving cells read
 the spans themselves (benchmarks/metrics/engine.py) — the baseline a
-round-overlap dispatch A/B is held against. Under overlap="double"
+round-overlap dispatch A/B is held against. The three inner readings cut
+the dispatch into the child spans `<kind>.assemble`, `.key`, `.put` and
+`.enqueue`, and the dispatch and commit spans say in their `args` what
+rode the round: steps, slots, the most steps it could run and which limit
+set them; tokens committed, requests ended, and the seconds of the commit
+spent inside the client's `on_token` (benchmarks/metrics/engine_dispatch.py
+reads all of it). Under overlap="double"
 (sampling/serve.py `_step_overlapped`) round N settles one step late, so
 its t1 -> t_land window CONTAINS host work for other rounds; the engine
 reports that overlapped span via `hidden_s` and it surfaces as the
@@ -132,6 +141,10 @@ class Observability:
         self._h_round_slots = h(
             "round_decode_slots", "slots holding a decoding request after the round"
         )
+        self._h_round_steps = h(
+            "round_decode_steps", "decode steps the round's program ran "
+            "(of decode_chunk x round_group)"
+        )
         # what the paged attention kernel's grid did with the round's tables
         self._c_blocks_swept = self.metrics.counter(
             "decode.blocks_swept", "grid steps (compute blocks) of one "
@@ -161,26 +174,86 @@ class Observability:
         self, kind: str, tid: str,
         t0: float, t1: float, t_land: float, t_post: float,
         hidden_s: float = 0.0,
+        *,
+        cuts: tp.Optional[tp.Tuple[float, tp.Optional[float], float]] = None,
+        steps: tp.Optional[int] = None,
+        slots: tp.Optional[int] = None,
+        chunk: tp.Optional[int] = None,
+        limit: tp.Optional[str] = None,
+        tokens: tp.Optional[int] = None,
+        finished: tp.Optional[int] = None,
+        callback_s: tp.Optional[float] = None,
     ) -> None:
         """Record one engine round's boundary clock readings (see module
         docstring for the four-boundary semantics). Also emits the three
         phase spans into the flight recorder with explicit timestamps —
-        no extra clock reads beyond the four the engine already took.
+        no extra clock reads beyond the ones the engine already took.
         `hidden_s` is the slice of t1 -> t_land spent doing OTHER rounds'
         host work under round-overlap dispatch (the engine reads the clock
         once more as the settle force starts); it defaults to 0.0 so
-        classic rounds record an honest zero."""
+        classic rounds record an honest zero.
+
+        What rode the round, where the engine says it: `steps` the program
+        ran, `slots` active in it, `chunk` the most steps it could run and
+        `limit` the term that set them go on the dispatch span; `tokens`
+        committed, requests `finished` and `callback_s` (seconds of the
+        commit inside the client's `on_token`) on the host_post span.
+        `cuts` = (t_a, t_k, t_p), the engine's readings inside t0 -> t1:
+        the children `.assemble`, `.key` (t_k None: no key was split),
+        `.put` and `.enqueue` tile the dispatch span, recorded after it and
+        named its children (`Tracer.complete`)."""
         self._h_dispatch.observe(t1 - t0)
         self._h_device.observe(t_land - t1)
         self._h_post.observe(t_post - t_land)
         self._h_hidden.observe(hidden_s)
-        self.tracer.complete(f"{kind}.dispatch", "round", tid, t0, t1 - t0)
-        self.tracer.complete(
-            f"{kind}.device_wait", "round", tid, t1, t_land - t1
+        rode = None
+        if steps is not None:
+            self._h_round_steps.observe(steps)
+            rode = {"steps": steps, "slots": slots, "chunk": chunk, "limit": limit}
+        complete = self.tracer.complete
+        seq = complete(f"{kind}.dispatch", "round", tid, t0, t1 - t0, rode)
+        if cuts is not None:
+            t_a, t_k, t_p = cuts
+            self._children(seq, "round", tid, t0, (
+                (f"{kind}.assemble", t_a), (f"{kind}.key", t_k),
+                (f"{kind}.put", t_p), (f"{kind}.enqueue", t1),
+            ))
+        complete(f"{kind}.device_wait", "round", tid, t1, t_land - t1)
+        complete(
+            f"{kind}.host_post", "round", tid, t_land, t_post - t_land,
+            None if tokens is None else
+            {"tokens": tokens, "finished": finished, "callback_s": callback_s},
         )
-        self.tracer.complete(
-            f"{kind}.host_post", "round", tid, t_land, t_post - t_land
+
+    def record_prefill_assemble(
+        self, tid: str, rid: int, t0: float, t_n: float,
+        t_p: tp.Optional[float], t_end: float,
+    ) -> None:
+        """A prefill call's host time BEFORE its enqueue span
+        (`prefill.chunk`) opens at `t_end`: the numpy chunk, starts and page
+        bucket (t0 -> t_n: the span's self time), then the children
+        `prefill.put` (page tables and arrays to the device) and, in a
+        sampled call, `prefill.key` (the call's key split, from t_p)."""
+        seq = self.tracer.complete(
+            "prefill.assemble", "prefill", tid, t0, t_end - t0, rid=rid
         )
+        self._children(seq, "prefill", tid, t_n, (
+            ("prefill.put", t_end if t_p is None else t_p),
+            ("prefill.key", None if t_p is None else t_end),
+        ), rid)
+
+    def _children(
+        self, parent: int, cat: str, tid: str, t: float,
+        parts: tp.Iterable[tp.Tuple[str, tp.Optional[float]]],
+        rid: tp.Optional[int] = None,
+    ) -> None:
+        """Back-to-back child spans of `parent` from consecutive readings,
+        the first starting at `t`: (name, the reading that ends it), a part
+        whose reading is None did not happen."""
+        for name, t_end in parts:
+            if t_end is not None:
+                self.tracer.complete(name, cat, tid, t, t_end - t, rid=rid, parent=parent)
+                t = t_end
 
     def record_engine_round(self, dur_s: float, prefill_chunks: int,
                             prefill_calls: int, decode_slots: int) -> None:

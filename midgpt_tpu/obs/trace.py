@@ -58,35 +58,38 @@ class _SpanHandle:
     deque.append is atomic under the GIL).
     """
 
-    __slots__ = ("_tracer", "_name", "_cat", "_tid", "_rid", "_t0", "_seq",
-                 "_stack", "dur")
+    __slots__ = ("_tracer", "_name", "_cat", "_tid", "_rid", "_args", "t0",
+                 "_seq", "_stack", "dur")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, tid: str,
-                 rid: tp.Optional[int] = None):
+                 rid: tp.Optional[int] = None, args: tp.Optional[dict] = None):
         self._tracer = tracer
         self._name = name
         self._cat = cat
         self._tid = tid
         self._rid = rid
-        self._t0 = 0.0
-        self.dur = 0.0  # set on exit, for callers that also feed a histogram
+        self._args = args
+        # set on entry / exit, for callers that also feed a histogram or
+        # end a span of explicit readings where this one began
+        self.t0 = 0.0
+        self.dur = 0.0
 
     def __enter__(self) -> "_SpanHandle":
         tr = self._tracer
         self._seq = tr._next_seq()
         self._stack = tr._open_stack(self._tid)
         self._stack.append(self._seq)
-        self._t0 = tr._clock()
+        self.t0 = tr._clock()
         return self
 
     def __exit__(self, *exc) -> None:
         tr = self._tracer
-        self.dur = tr._clock() - self._t0
+        self.dur = tr._clock() - self.t0
         stack = self._stack
         stack.pop()
         tr._push(
-            (_COMPLETE, self._name, self._cat, self._tid, self._t0, self.dur,
-             None, None, self._rid, stack[-1] if stack else None, self._seq)
+            (_COMPLETE, self._name, self._cat, self._tid, self.t0, self.dur,
+             None, self._args, self._rid, stack[-1] if stack else None, self._seq)
         )
 
 
@@ -141,24 +144,36 @@ class Tracer:
     def _open_stack(self, tid: str) -> tp.List[int]:
         return self._open.setdefault((threading.get_ident(), tid), [])
 
-    def _record(self, kind, name, cat, tid, t, dur, ident, args, rid) -> None:
-        stack = self._open.get((threading.get_ident(), tid))
-        self._push((kind, name, cat, tid, t, dur, ident, args, rid,
-                    stack[-1] if stack else None, self._next_seq()))
+    def _record(self, kind, name, cat, tid, t, dur, ident, args, rid,
+                parent: tp.Optional[int] = None) -> int:
+        if parent is None:
+            stack = self._open.get((threading.get_ident(), tid))
+            parent = stack[-1] if stack else None
+        seq = self._next_seq()
+        self._push((kind, name, cat, tid, t, dur, ident, args, rid, parent, seq))
+        return seq
 
     def span(self, name: str, cat: str = "", tid: str = "main",
-             rid: tp.Optional[int] = None) -> _SpanHandle:
-        """Context manager measuring one host-side phase."""
-        return _SpanHandle(self, name, cat, tid, rid)
+             rid: tp.Optional[int] = None,
+             args: tp.Optional[dict] = None) -> _SpanHandle:
+        """Context manager measuring one host-side phase; `args` say what
+        rode it (known at entry)."""
+        return _SpanHandle(self, name, cat, tid, rid, args)
 
     def complete(
         self, name: str, cat: str, tid: str, start: float, dur: float,
         args: tp.Optional[dict] = None, rid: tp.Optional[int] = None,
-    ) -> None:
+        parent: tp.Optional[int] = None,
+    ) -> int:
         """Record a span from explicit clock readings — for phases whose
         boundaries were already captured (the round decomposition reads
-        the clock once per boundary and derives several spans)."""
-        self._record(_COMPLETE, name, cat, tid, start, dur, None, args, rid)
+        the clock once per boundary and derives several spans). Returns the
+        span's sequence number: handed back as `parent`, it makes a later
+        span of explicit readings this one's child, whatever `span()` is
+        open by then (absent, the innermost open one is the parent). Record
+        a child AFTER its parent: where two spans start together, readers
+        take the later entry as the inner one."""
+        return self._record(_COMPLETE, name, cat, tid, start, dur, None, args, rid, parent)
 
     def instant(
         self, name: str, cat: str = "", tid: str = "main",
@@ -262,7 +277,8 @@ class _NullTracer:
     dropped = 0
 
     def span(self, name: str, cat: str = "", tid: str = "main",
-             rid: tp.Optional[int] = None) -> _NullSpan:
+             rid: tp.Optional[int] = None,
+             args: tp.Optional[dict] = None) -> _NullSpan:
         return _NULL_SPAN
 
     def complete(self, *a, **k) -> None:

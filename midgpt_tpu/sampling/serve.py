@@ -800,6 +800,9 @@ class _InflightRound:
     round_no: int
     t0: float
     t1: float
+    # for obs: the readings inside t0 -> t1, and which term set the steps
+    cuts: tp.Tuple[float, tp.Optional[float], float]
+    limit: str
 
 
 class ServeEngine:
@@ -930,6 +933,10 @@ class ServeEngine:
         self._obs_tid = obs_tid
         # uid -> (open request phase, its start): obs-on only (_req_phase)
         self._req_open: tp.Dict[int, tp.Tuple[str, float]] = {}
+        # obs only: since a decode round began its commit, [tokens appended
+        # (`_append_token`), requests that ended (`_finish`), seconds inside
+        # the client's on_token]; `record_round` takes them
+        self._commit: tp.List[tp.Any] = [0, 0, 0.0]
         # Hung-dispatch watchdog (robustness/watchdog.py), same injection
         # discipline as clock/obs: None (default) leaves the decode round's
         # force a plain np.asarray — no thread, no event, nothing for the
@@ -2000,17 +2007,15 @@ class ServeEngine:
             max_len[i] = min(_want(s), len(s.pages[0]) * ps)  # every kind's list has one logical length
             chain_mask[i] = i in chained
             worst[i] = min(_base(i, s) + T, max_len[i])
-        if self.temperature == 0.0:
-            key = None
-        else:
-            self._key, key = jax.random.split(self._key)
         round_span = int(worst.max())
         bucket = self._page_bucket(round_span)
-        # lengths as the host holds them: a chained slot's trail the
-        # device's by the previous group's steps (the count runs low there)
-        self._count_blocks(
-            lengths, active, bucket, self._split_bucket(round_span), n_steps=T
-        )
+        split_k = self._split_bucket(round_span)
+        t_a = 0.0 if obs is None else self._clock()
+        if self.temperature == 0.0:
+            key = t_k = None
+        else:
+            self._key, key = jax.random.split(self._key)
+            t_k = 0.0 if obs is None else self._clock()
         # Chain carry-in: the previous group's unforced outputs when
         # chaining, else zero fillers of the same shape/dtype — ONE
         # compiled program serves both cases, and nothing here syncs.
@@ -2019,19 +2024,27 @@ class ServeEngine:
         else:
             chain_token = np.zeros((B,), np.int32)
             chain_len = np.zeros((B,), np.int32)
+        # the arguments go to the device BEFORE the call, so that the put
+        # and the enqueue have a boundary between them
+        token_j, tables = jnp.asarray(token), self._device_tables(bucket)
+        lengths_j, active_j, eos_j, max_len_j, mask_j, chain_token_j, chain_len_j = (
+            jnp.asarray(a)
+            for a in (lengths, active, eos, max_len, chain_mask, chain_token, chain_len)
+        )
+        t_p = 0.0 if obs is None else self._clock()
         self.cache, toks, emitted, tok_fin, len_fin = _serve_decode_group(
             self.config,
             self.params,
-            jnp.asarray(token),
+            token_j,
             self.cache,
-            self._device_tables(bucket),
-            jnp.asarray(lengths),
-            jnp.asarray(active),
-            jnp.asarray(eos),
-            jnp.asarray(max_len),
-            jnp.asarray(chain_mask),
-            jnp.asarray(chain_token),
-            jnp.asarray(chain_len),
+            tables,
+            lengths_j,
+            active_j,
+            eos_j,
+            max_len_j,
+            mask_j,
+            chain_token_j,
+            chain_len_j,
             n,
             self.round_group,
             self.temperature,
@@ -2040,9 +2053,13 @@ class ServeEngine:
             self.attn_impl,
             key,
             self.mesh,
-            self._split_bucket(round_span),
+            split_k,
         )
         t1 = 0.0 if obs is None else self._clock()
+        # lengths as the host holds them: a chained slot's trail the
+        # device's by the previous group's steps (the count runs low there).
+        # Counted after t1, while the device computes: not dispatch time.
+        self._count_blocks(lengths, active, bucket, split_k, n_steps=T)
         self.dispatch_log.append(
             (self.rounds, tuple(s.request.uid for _, s in cand))
         )
@@ -2058,6 +2075,9 @@ class ServeEngine:
             round_no=self.rounds,
             t0=t0,
             t1=t1,
+            cuts=(t_a, t_k, t_p),
+            # the group runs what its NEEDIEST slot wants and masks the rest
+            limit="chunk" if need == self.decode_chunk else "need",
         )
 
     def _settle_round(self, h: _InflightRound) -> None:
@@ -2076,6 +2096,8 @@ class ServeEngine:
             "serve.overlap_sync",
         )
         t_done = self._clock()
+        if obs is not None:
+            self._commit = [0, 0, 0.0]
         for idx, s in zip(h.active_idx, h.slots):
             if self.slots[idx] is not s:
                 continue
@@ -2089,6 +2111,10 @@ class ServeEngine:
             obs.record_round(
                 "decode", self._obs_tid, h.t0, h.t1, t_done, self._clock(),
                 hidden_s=max(0.0, t_force - h.t1),
+                cuts=h.cuts, steps=h.n_steps, slots=len(h.active_idx),
+                chunk=self.decode_chunk * self.round_group, limit=h.limit,
+                tokens=self._commit[0], finished=self._commit[1],
+                callback_s=self._commit[2],
             )
 
     def _poison_page(self) -> None:
@@ -2557,6 +2583,11 @@ class ServeEngine:
         program samples it (`_serve_prefill_chunk`): the host pulls the
         call's `prefill_width` int32 tokens, once, and the logits they were
         sampled from only when `on_first_logits` asks for them."""
+        # obs on: `prefill.assemble` runs from here to where `prefill.chunk`
+        # opens (t_n: the numpy arrays and the bucket are there; t_p: the
+        # puts are done and the key split begins, sampled calls only)
+        obs = self.obs
+        t0 = 0.0 if obs is None else self._clock()
         W = self.prefill_width
         chunk = np.zeros((W, self.prefill_chunk), np.int32)
         start, n_valid = np.zeros((W,), np.int32), np.zeros((W,), np.int32)
@@ -2566,6 +2597,7 @@ class ServeEngine:
             start[r], n_valid[r] = pos, n
         # the page bucket of a call is the bucket of its longest row
         bucket = self._page_bucket(int((start + n_valid).max()))
+        t_n = 0.0 if obs is None else self._clock()
         table = self._device_tables(bucket, [slot_i for slot_i, _, _ in rows])
         chunk_j = jnp.asarray(chunk)
         if W > 1:
@@ -2573,16 +2605,21 @@ class ServeEngine:
         else:  # the one-row call every family takes: scalars
             start_j, n_valid_j = jnp.asarray(start[0]), jnp.asarray(n_valid[0])
         if self.temperature == 0.0:
-            key = None
+            key = t_p = None
         else:  # one key a call, as `_decode_round` makes one a round
+            t_p = 0.0 if obs is None else self._clock()
             self._key, key = jax.random.split(self._key)
-        # Span covers host assembly + async ENQUEUE of ONE call only —
-        # nothing is forced here (a call none of whose rows ends its
+        # The span covers the async ENQUEUE of ONE call and nothing else:
+        # the arguments were assembled and put above (`prefill.assemble`),
+        # and nothing is forced here (a call none of whose rows ends its
         # prompt never syncs; the force happens in the first-token block
         # below). It belongs to no one request: rid is the first row's.
+        # Its args say what rode the call.
         with self._trace.span(
-            "prefill.chunk", "prefill", self._obs_tid, rows[0][1].request.uid
-        ):
+            "prefill.chunk", "prefill", self._obs_tid, rows[0][1].request.uid,
+            None if obs is None else
+            {"rows": len(rows), "tokens": int(n_valid.sum()), "bucket": bucket},
+        ) as sp:
             first, logits, self.cache = _serve_prefill_chunk(
                 self.config,
                 self.params,
@@ -2617,6 +2654,10 @@ class ServeEngine:
                     self.mesh,
                     self.attn_impl,
                 )
+        if obs is not None:
+            obs.record_prefill_assemble(
+                self._obs_tid, rows[0][1].request.uid, t0, t_n, t_p, sp.t0
+            )
         self.prefill_calls += 1
         pulled = False  # the call's tokens are on the host: pulled ONCE
         for r, (slot_i, slot, n) in enumerate(rows):
@@ -2657,22 +2698,28 @@ class ServeEngine:
             self.first_tokens += 1
             self._append_token(slot_i, slot, tok, self._clock())
 
-    def _decode_budget(self) -> tp.Tuple[tp.List[int], int]:
+    def _decode_budget(self) -> tp.Tuple[tp.List[int], int, str]:
         """(the slots a decode round would run: every slot past its prompt
-        with tokens left; the steps none of them overshoots)."""
+        with tokens left; the steps none of them overshoots; which term set
+        them: `"chunk"` (`decode_chunk`), `"remaining"` (the slot with the
+        fewest tokens left) or `"block"` (the slot nearest `block_size`))."""
         active_idx = [
             i
             for i, s in enumerate(self.slots)
             if s is not None and not s.prefilling and s.remaining > 0
         ]
         if not active_idx:
-            return [], 0
+            return [], 0, ""
         S = self.config.block_size
-        return active_idx, min(
+        remaining = min(self.slots[i].remaining for i in active_idx)
+        budget = min(
             self.decode_chunk,
-            min(self.slots[i].remaining for i in active_idx),
+            remaining,
             min(S - self.slots[i].length for i in active_idx),
         )
+        if budget == self.decode_chunk:
+            return active_idx, budget, "chunk"
+        return active_idx, budget, "remaining" if budget == remaining else "block"
 
     def _decode_pages(self, active_idx: tp.List[int], n: int) -> tp.List[int]:
         """Pages for `n` more steps of every slot in `active_idx`; the slots
@@ -2721,7 +2768,7 @@ class ServeEngine:
         checks (benchmarks/serve_family_cell.py, tests), not the serving
         loop: a program of its own a (page bucket, split), and a sync."""
         self._settle_inflight()
-        active_idx, budget = self._decode_budget()
+        active_idx, budget, _ = self._decode_budget()
         if not active_idx:
             return {}
         n = 1 << (budget.bit_length() - 1)  # as the round: the largest power of two <= budget
@@ -2738,7 +2785,7 @@ class ServeEngine:
         return {self.slots[i].request.uid: logits[i] for i in active_idx}
 
     def _decode_round(self) -> None:
-        active_idx, budget = self._decode_budget()
+        active_idx, budget, limit = self._decode_budget()
         if not active_idx:
             return
         n = 1 << (budget.bit_length() - 1)  # largest power of two <= budget
@@ -2747,28 +2794,34 @@ class ServeEngine:
             return
 
         # Round decomposition (obs/__init__.py docstring): t0 -> t1 is host
-        # assembly + jit ENQUEUE, t1 -> t_done is device compute + the copy
-        # to the host (the np.asarray force is the round's one sync),
-        # t_done -> t_post is token commit.
+        # assembly + jit ENQUEUE (cut at t_a, t_k, t_p into assemble / key /
+        # put / enqueue), t1 -> t_done is device compute + the copy to the
+        # host (the np.asarray force is the round's one sync), t_done ->
+        # t_post is token commit.
         obs = self.obs
         t0 = 0.0 if obs is None else self._clock()
         token, lengths, active, round_span = self._decode_args(active_idx, n)
+        bucket = self._page_bucket(round_span)
+        split_k = self._split_bucket(round_span)
+        t_a = 0.0 if obs is None else self._clock()
         if self.temperature == 0.0:
-            key = None
+            key = t_k = None
         else:
             self._key, key = jax.random.split(self._key)
-        bucket = self._page_bucket(round_span)
-        self._count_blocks(
-            lengths, active, bucket, self._split_bucket(round_span), n_steps=n
-        )
+            t_k = 0.0 if obs is None else self._clock()
+        # the arguments go to the device BEFORE the call, so that the put
+        # and the enqueue have a boundary between them
+        token_j, tables = jnp.asarray(token), self._device_tables(bucket)
+        lengths_j, active_j = jnp.asarray(lengths), jnp.asarray(active)
+        t_p = 0.0 if obs is None else self._clock()
         self.cache, toks = _serve_decode_chunk(
             self.config,
             self.params,
-            jnp.asarray(token),
+            token_j,
             self.cache,
-            self._device_tables(bucket),
-            jnp.asarray(lengths),
-            jnp.asarray(active),
+            tables,
+            lengths_j,
+            active_j,
             n,
             self.temperature,
             self.top_k,
@@ -2776,9 +2829,11 @@ class ServeEngine:
             self.attn_impl,
             key,
             self.mesh,
-            self._split_bucket(round_span),
+            split_k,
         )
         t1 = 0.0 if obs is None else self._clock()
+        # counted after t1, while the device computes: not dispatch time
+        self._count_blocks(lengths, active, bucket, split_k, n_steps=n)
         self.dispatch_log.append(
             (
                 self.rounds,
@@ -2791,6 +2846,8 @@ class ServeEngine:
             lambda: np.asarray(toks), "serve.decode_sync"
         )  # (n, B)
         t_done = self._clock()
+        if obs is not None:
+            self._commit = [0, 0, 0.0]
         for i in active_idx:
             slot = self.slots[i]
             if slot is None:
@@ -2801,7 +2858,11 @@ class ServeEngine:
                     break  # finished (max_new or EOS); rest of chunk discarded
         if obs is not None:
             obs.record_round(
-                "decode", self._obs_tid, t0, t1, t_done, self._clock()
+                "decode", self._obs_tid, t0, t1, t_done, self._clock(),
+                cuts=(t_a, t_k, t_p), steps=n, slots=len(active_idx),
+                chunk=self.decode_chunk, limit=limit,
+                tokens=self._commit[0], finished=self._commit[1],
+                callback_s=self._commit[2],
             )
 
     def _spec_round(self) -> None:
@@ -2867,8 +2928,6 @@ class ServeEngine:
         round_span = max(self.slots[i].length for i in active_idx) + k + 1
         bucket = self._page_bucket(round_span)
         split_k = self._split_bucket(round_span)
-        # the target's verify call (the draft's k steps run another model)
-        self._count_blocks(lengths, active, bucket, split_k, n_rows=k + 1)
         table = jnp.asarray(self._page_table(bucket))
         token_j = jnp.asarray(token)
         lengths_j = jnp.asarray(lengths)
@@ -2923,6 +2982,9 @@ class ServeEngine:
             split_k,
         )
         t1 = 0.0 if obs is None else self._clock()
+        # the target's verify call (the draft's k steps run another model);
+        # counted after t1, while the device computes: not dispatch time
+        self._count_blocks(lengths, active, bucket, split_k, n_rows=k + 1)
         n_accept = np.asarray(n_accept)
         out = np.asarray(out)  # forces both dispatches
         t_done = self._clock()
@@ -3024,6 +3086,7 @@ class ServeEngine:
         last token's time where the caller has it (obs only)."""
         self.finished[fr.uid] = fr
         if self.obs is not None:
+            self._commit[1] += 1
             self._req_phase(
                 fr.uid, None, self._clock() if t is None else t,
                 end_args={"tokens_out": len(fr.token_times), "status": fr.status},
@@ -3042,19 +3105,28 @@ class ServeEngine:
         slot.generated.append(tok)
         slot.token_times.append(t)
         req = slot.request
-        if self.on_token is not None:
-            self.on_token(req.uid, tok, t)
-        if self.obs is not None and len(slot.generated) == 1:
-            todo = len(req.prompt) - slot.skipped
-            self._req_phase(
-                req.uid, "req.decode", t,
-                end_args={
-                    "prompt_tokens": len(req.prompt),
-                    "prefix_skipped": slot.skipped,
-                    "chunks": -(-todo // self.prefill_chunk),
-                    "rounds": self.rounds - slot.admit_round + 1,
-                },
-            )
+        if self.obs is None:
+            if self.on_token is not None:
+                self.on_token(req.uid, tok, t)
+        else:
+            # the client's part of a commit, timed apart from the engine's
+            commit = self._commit
+            commit[0] += 1
+            if self.on_token is not None:
+                c0 = self._clock()
+                self.on_token(req.uid, tok, t)
+                commit[2] += self._clock() - c0
+            if len(slot.generated) == 1:
+                todo = len(req.prompt) - slot.skipped
+                self._req_phase(
+                    req.uid, "req.decode", t,
+                    end_args={
+                        "prompt_tokens": len(req.prompt),
+                        "prefix_skipped": slot.skipped,
+                        "chunks": -(-todo // self.prefill_chunk),
+                        "rounds": self.rounds - slot.admit_round + 1,
+                    },
+                )
         hit_eos = req.eos_id is not None and tok == req.eos_id
         if hit_eos or len(slot.generated) >= req.max_new_tokens:
             self._finish(

@@ -46,12 +46,11 @@ def params():
     return GPT.init(CFG, jax.random.PRNGKey(0))
 
 
-def _engine(params, num_pages, clock, obs=None, on_token=None):
-    return ServeEngine(
-        CFG, params, max_slots=2, page_size=8, num_pages=num_pages,
-        prefill_chunk=8, decode_chunk=4, temperature=0.0,
-        cache_dtype=jnp.float32, clock=clock, obs=obs, on_token=on_token,
-    )
+def _engine(params, num_pages, clock, obs=None, on_token=None, **over):
+    kw = dict(max_slots=2, page_size=8, num_pages=num_pages, prefill_chunk=8, decode_chunk=4,
+              temperature=0.0, cache_dtype=jnp.float32, clock=clock, obs=obs, on_token=on_token)
+    kw.update(over)
+    return ServeEngine(CFG, params, **kw)
 
 
 def _serve(eng):
@@ -93,8 +92,31 @@ def test_ring_tuple_head_unchanged_and_rid_parent_appended():
     assert exported["engine.round"]["args"] == {"seq": rnd[10]}
 
 
+def test_complete_takes_an_explicit_parent_and_a_span_its_args():
+    """A span of explicit readings recorded AFTER the round can still name the
+    span it tiles (PR 36): `complete` hands back its sequence number and takes
+    one as `parent`, whatever `span()` is open; `span(args=...)` says what rode it."""
+    tr = Tracer(capacity=16, clock=StepClock(1.0))
+    with tr.span("engine.round", "round", "engine"):
+        seq = tr.complete("decode.dispatch", "round", "engine", 2.0, 1.0, {"steps": 4})
+        tr.complete("decode.assemble", "round", "engine", 2.0, 0.25, parent=seq)
+        with tr.span("prefill.chunk", "prefill", "engine", 7, {"rows": 3}) as sp:
+            pass
+    evs = {e[1]: e for e in tr.events()}
+    assert evs["decode.dispatch"][10] == seq and evs["decode.dispatch"][7] == {"steps": 4}
+    assert evs["decode.assemble"][9] == seq                      # not the open engine.round
+    assert evs["decode.dispatch"][9] == evs["engine.round"][10]  # absent: the open span, as before
+    assert evs["prefill.chunk"][7] == {"rows": 3} and evs["prefill.chunk"][4] == sp.t0
+    names = [e[1] for e in tr.events()]
+    assert names.index("decode.assemble") > names.index("decode.dispatch")  # a child after its parent
+    exported = {e["name"]: e for e in tr.export() if e["ph"] != "M"}
+    assert exported["decode.assemble"]["args"] == {"seq": evs["decode.assemble"][10], "parent": seq}
+    assert exported["prefill.chunk"]["args"]["rows"] == 3
+    assert NULL_TRACER.complete("a", "b", "c", 0.0, 1.0, parent=3) is None
+
+
 def test_null_tracer_takes_the_new_arguments_and_stays_empty():
-    with NULL_TRACER.span("prefill.chunk", "prefill", "engine", 7):
+    with NULL_TRACER.span("prefill.chunk", "prefill", "engine", 7, {"rows": 1}):
         NULL_TRACER.async_begin("req.queue", 7, "request", "engine", None, 1.0)
         NULL_TRACER.async_end("req.queue", 7, "request", "engine", t=2.0)
         NULL_TRACER.complete("a", "b", "c", 0.0, 1.0, rid=7)
@@ -205,6 +227,160 @@ def test_obs_off_reads_the_clock_as_before_and_emits_the_same_tokens(params, num
     assert clock.calls == parent_reads
     _, toks_on = _serve(_engine(params, num_pages, StepClock(), Observability(clock=StepClock())))
     assert toks_on == toks_off
+
+
+@pytest.mark.parametrize("overlap,num_pages,parent_reads", [
+    ("group", 17, 26), ("group", 7, 36), ("double", 17, 32), ("double", 7, 42)])
+def test_obs_off_reads_the_clock_as_before_on_the_grouped_paths(params, overlap, num_pages, parent_reads):
+    """The same pin for the dispatch the two overlap modes share (round_group 2):
+    the reads counted on the parent of PR 36 with this scenario, and obs on
+    changes no token."""
+    clock = StepClock()
+    _, toks_off = _serve(_engine(params, num_pages, clock, overlap=overlap, round_group=2))
+    assert clock.calls == parent_reads
+    _, toks_on = _serve(_engine(params, num_pages, StepClock(), Observability(clock=StepClock()),
+                                overlap=overlap, round_group=2))
+    assert toks_on == toks_off
+
+
+# ---------------------------------------------------------------------------
+# B2. what rode a round, and what its dispatch and commit are made of (PR 36)
+# ---------------------------------------------------------------------------
+
+
+def _args_of(events, name):
+    return [e[7] for e in events if e[1] == name]
+
+
+@pytest.mark.parametrize("overlap,group", [("off", 1), ("group", 2), ("double", 2)])
+def test_round_args_count_the_tokens_the_client_got(params, overlap, group):
+    """On every decode path the commits' `tokens` sum to what `on_token`
+    delivered after each request's first (that one comes out of the prefill
+    call), `finished` to the requests, `callback_s` to the clock's steps inside
+    the client's callback, and `steps` feeds the one new histogram."""
+    clock = StepClock()
+    obs = Observability(clock=clock)
+    got = {}
+    eng = _engine(params, 17, clock, obs, overlap=overlap, round_group=group,
+                  on_token=lambda uid, tok, t: got.setdefault(uid, []).append(tok))
+    uids, _ = _serve(eng)
+    evs = obs.tracer.events()
+    rounds, commits = _args_of(evs, "decode.dispatch"), _args_of(evs, "decode.host_post")
+    assert len(rounds) == len(commits) > 0
+    delivered = sum(len(v) for v in got.values())
+    assert sum(c["tokens"] for c in commits) == delivered - len(uids)
+    assert sum(c["finished"] for c in commits) == len(uids)
+    for c in commits:  # a fake clock step a callback: two reads a token
+        assert c["callback_s"] == pytest.approx(c["tokens"] * clock.step)
+    for r, c in zip(rounds, commits):
+        assert r["chunk"] == 4 * group and 1 <= r["steps"] <= r["chunk"] and 1 <= r["slots"] <= 2
+        assert c["tokens"] <= r["steps"] * r["slots"]
+        assert r["limit"] in (("chunk", "remaining", "block") if overlap == "off" else ("chunk", "need"))
+    hist = eng.stats()["obs"]["histograms"]["round_decode_steps"]
+    assert hist["n"] == len(rounds) and hist["max"] == max(r["steps"] for r in rounds)
+    assert hist["mean"] == pytest.approx(sum(r["steps"] for r in rounds) / len(rounds))
+
+
+@pytest.mark.parametrize("overlap,want", [
+    ("off", {"steps": 2, "slots": 3, "chunk": 4, "limit": "remaining", "tokens": 6, "finished": 0}),
+    # the grouped dispatch runs what its NEEDIEST slot wants and masks the rest
+    ("group", {"steps": 8, "slots": 3, "chunk": 8, "limit": "chunk", "tokens": 3 + 8 + 8, "finished": 1}),
+])
+def test_a_hand_built_round_says_its_steps_and_why(params, overlap, want):
+    """One slot with 3 tokens left among slots with 18: the classic round runs
+    the largest power of two under the FEWEST left (2 steps, `remaining`), for
+    all three slots; the grouped one its chunk, masking the short slot."""
+    clock = StepClock()
+    obs = Observability(clock=clock)
+    eng = _engine(params, 33, clock, obs, max_slots=3, overlap=overlap, round_group=2 if overlap == "group" else 1)
+    for max_new in (4, 19, 19):  # one prefill call ends all three prompts: 3, 18, 18 left
+        eng.submit(np.arange(1, 7, dtype=np.int32), max_new)
+    eng.step()
+    evs = obs.tracer.events()
+    (rode,), (commit,) = _args_of(evs, "decode.dispatch"), _args_of(evs, "decode.host_post")
+    assert {**rode, **{k: commit[k] for k in ("tokens", "finished")}} == want
+    if overlap == "off":  # the tail: 3 left -> 2, then 1 step, then the others alone at the chunk
+        eng.step(), eng.step()
+        assert [(a["steps"], a["limit"], a["slots"]) for a in _args_of(obs.tracer.events(), "decode.dispatch")] == [
+            (2, "remaining", 3), (1, "remaining", 3), (4, "chunk", 2)]
+    else:
+        while not eng.idle:
+            eng.step()
+        assert _args_of(obs.tracer.events(), "decode.dispatch")[-1]["limit"] == "need"  # 18 = 8 + 8 + 2
+
+
+@pytest.mark.parametrize("overlap", ["off", "group", "double"])
+@pytest.mark.parametrize("temperature", [0.0, 0.7])
+def test_dispatch_children_tile_their_parent_and_the_block_census_waits(params, overlap, temperature):
+    """`decode.assemble` / `key` / `put` / `enqueue` are children of their
+    round's `decode.dispatch` (its `seq`), back to back from its start to its
+    end, `decode.key` only where a key was split; and the kernel-grid census
+    (instrumentation) runs after t1, not inside the dispatch it used to be timed as."""
+    clock = StepClock()
+    obs = Observability(clock=clock)
+    eng = _engine(params, 17, clock, obs, temperature=temperature, overlap=overlap, round_group=2)
+    census_at = []
+    real = eng._count_blocks
+    eng._count_blocks = lambda *a, **k: (census_at.append(clock.t), real(*a, **k))[1]
+    _serve(eng)
+    evs = obs.tracer.events()
+    parents = [e for e in evs if e[1] == "decode.dispatch"]
+    assert len(parents) == len(census_at) > 0
+    names = ["decode.assemble"] + ["decode.key"] * (temperature > 0) + ["decode.put", "decode.enqueue"]
+    for parent, t_census in zip(parents, census_at):
+        kids = [e for e in evs if e[9] == parent[10]]
+        assert [k[1] for k in kids] == names and all(k[10] > parent[10] for k in kids)
+        assert kids[0][4] == parent[4]
+        for a, b in zip(kids, kids[1:]):
+            assert a[4] + a[5] == pytest.approx(b[4], abs=1e-12)
+        assert sum(k[5] for k in kids) == pytest.approx(parent[5], abs=1e-12)
+        assert t_census >= parent[4] + parent[5] - 1e-12  # after t1
+    assert not any(e[1] == "decode.key" for e in evs) or temperature > 0
+
+
+def test_gap_at_a_dispatchs_start_goes_to_the_child_not_the_parent():
+    """`reduce.attribute_gaps` gives a gap to the latest-STARTED span over its
+    midpoint and breaks a tie of starts by the order of the list: the child
+    `decode.assemble` starts with its parent and is recorded after it."""
+    reduce = _load("reduce.py")
+    obs = Observability(clock=StepClock())
+    obs.record_round("decode", "engine", 1.0, 1.004, 1.010, 1.012,
+                     cuts=(1.001, 1.002, 1.003), steps=4, slots=2, chunk=4, limit="chunk",
+                     tokens=8, finished=0, callback_s=0.001)
+    spans_ns = [(e[1], int(e[4] * 1e9), int(e[5] * 1e9)) for e in obs.tracer.events()]
+    busy = lambda a, b: [0, int(a * 1e9), int((b - a) * 1e9)]
+    ops = [busy(0.9, 1.0002), busy(1.0008, 1.0022), busy(1.0028, 1.0101), busy(1.0119, 1.1)]
+    gaps = reduce.attribute_gaps(ops, spans_ns, int(0.9e9), int(1.1e9))
+    assert set(gaps) == {"decode.assemble", "decode.put", "decode.host_post"}
+    assert gaps["decode.assemble"] == pytest.approx(600_000, abs=2)
+
+
+@pytest.mark.parametrize("max_slots,width", [(1, 1), (16, 16)])
+@pytest.mark.parametrize("temperature", [0.0, 0.7])
+def test_prefill_assemble_ends_where_the_enqueue_span_opens(params, max_slots, width, temperature):
+    clock = StepClock()
+    obs = Observability(clock=clock)
+    eng = _engine(params, 16 * 2 + 1, clock, obs, max_slots=max_slots, prefill_chunk=16, temperature=temperature)
+    assert eng.prefill_width == width
+    for _ in range(16):
+        eng.submit(np.arange(1, 6, dtype=np.int32), 2)
+    while not eng.idle:
+        eng.step()
+    evs = obs.tracer.events()
+    by_start = lambda name: sorted((e for e in evs if e[1] == name), key=lambda e: e[4])
+    assembles, chunks = by_start("prefill.assemble"), by_start("prefill.chunk")
+    assert len(assembles) == len(chunks) == eng.prefill_calls
+    for a, c in zip(assembles, chunks):
+        assert a[4] + a[5] == pytest.approx(c[4], abs=1e-12) and a[8] == c[8] and a[9] == c[9]
+        kids = [e for e in evs if e[9] == a[10]]
+        assert [k[1] for k in kids] == ["prefill.put"] + ["prefill.key"] * (temperature > 0)
+        assert kids[0][4] > a[4]  # the numpy arrays are the span's own time
+        assert kids[-1][4] + kids[-1][5] == pytest.approx(c[4], abs=1e-12)
+    rode = [c[7] for c in chunks]
+    assert rode[0] == {"rows": width, "tokens": 5 * width, "bucket": 1}
+    assert all(r["rows"] <= width for r in rode)
+    assert sum(r["rows"] for r in rode) == eng.prefill_chunks == 16
+    assert sum(r["tokens"] for r in rode) == eng.prefilled_tokens == 16 * 5
 
 
 # ---------------------------------------------------------------------------
@@ -449,6 +625,75 @@ def test_engine_requests_reader_without_tracks_reports_rounds_only():
     assert any("no engine.round span" in line for line in run["logs"])
 
 
+def _dispatch_window(obs, with_args):
+    """Two decode rounds and two prefill calls inside a window, a third round
+    after it; as this program records them, or (`with_args` False) as the
+    parent of PR 36 did: the same phase spans with no args and no children."""
+    tr = obs.tracer
+    rounds = [  # t0, (t_a, t_k, t_p), t1, t_land, t_post, rode, commit
+        (100.0, (100.001, 100.0015, 100.003), 100.004, 100.010, 100.012,
+         dict(steps=4, slots=2, chunk=8, limit="remaining"), dict(tokens=8, finished=1, callback_s=0.0005)),
+        (101.0, (101.002, 101.003, 101.005), 101.006, 101.020, 101.024,
+         dict(steps=8, slots=2, chunk=8, limit="chunk"), dict(tokens=16, finished=0, callback_s=0.0015)),
+        (200.0, (200.001, 200.002, 200.003), 200.004, 200.010, 200.011,  # after the window
+         dict(steps=1, slots=1, chunk=8, limit="remaining"), dict(tokens=1, finished=1, callback_s=0.0)),
+    ]
+    for t0, cuts, t1, t_land, t_post, rode, commit in rounds:
+        if with_args:
+            obs.record_round("decode", "engine", t0, t1, t_land, t_post, cuts=cuts, **rode, **commit)
+        else:
+            obs.record_round("decode", "engine", t0, t1, t_land, t_post)
+    for t0, t_end, rows in ((100.5, 100.52, 3), (101.5, 101.53, 1)):
+        tr.complete("prefill.chunk", "prefill", "engine", t_end, 0.001,
+                    {"rows": rows, "tokens": 5 * rows, "bucket": 1} if with_args else None, rid=1)
+        if with_args:
+            obs.record_prefill_assemble("engine", 1, t0, t0 + 0.005, t0 + 0.015, t_end)
+    return [(e[1], e[4], e[5]) for e in tr.events() if e[0] == "X" and e[4] < 150.0]
+
+
+def test_engine_dispatch_reader_on_a_hand_made_window():
+    reader = _reader("engine_dispatch.py")
+    Observability()  # an older recorder without the window's spans is passed over
+    spans = _dispatch_window(Observability(clock=StepClock()), with_args=True)
+    Observability()  # and so is a newer one
+    run = _run_dict("serve", spans=spans, counters={"max_slots": 2})
+    out = reader.read(run)
+    assert {k: v for k, v in out.items() if v is not None} == pytest.approx({
+        "decode.steps_per_round_mean": 6.0,             # (4 + 8) / 2: the third round is outside
+        "decode.round_fill": 100.0 * 24 / (2 * 16),     # tokens over max_slots x chunk, summed
+        "decode.tail_limited_share": 50.0,              # `remaining` AND short of the chunk
+        "decode.host_ms_per_token": (4 + 6 + 2 + 4) / 24,
+        "decode.dispatch_ms_p50": 5.0, "decode.host_post_ms_p50": 3.0,
+        "decode.assemble_ms_p50": 1.5, "decode.key_ms_p50": 0.75,
+        "decode.put_ms_p50": 1.75, "decode.enqueue_ms_p50": 1.0,
+        "decode.callback_share": 100.0 * 0.002 / 0.006,
+        "prefill.assemble_ms_p50": 25.0, "prefill.rows_per_call_mean": 2.0,
+    })
+    assert any("decode rounds in the window: 2; steps {4: 1, 8: 1}" in line for line in run["logs"])
+    assert reader.read(_run_dict("train")) is None and reader.read(_run_dict("serve")) is None
+
+
+def test_engine_dispatch_reader_leaves_out_what_the_parent_cannot_say():
+    """On a program whose spans carry no args and no children (the parent of
+    PR 36, which the driver runs these readers over): the two medians that
+    need neither, a line for everything left out, and no exception."""
+    reader = _reader("engine_dispatch.py")
+    spans = _dispatch_window(Observability(clock=StepClock()), with_args=False)
+    run = _run_dict("serve", spans=spans, counters={"max_slots": 2})
+    out = reader.read(run)
+    assert {k: v for k, v in out.items() if v is not None} == pytest.approx(
+        {"decode.dispatch_ms_p50": 5.0, "decode.host_post_ms_p50": 3.0})
+    said = " ".join(run["logs"])
+    for name in ("decode.assemble", "prefill.assemble", "decode.steps_per_round_mean", "decode.round_fill",
+                 "decode.callback_share", "decode.host_ms_per_token", "prefill.rows_per_call_mean"):
+        assert name in said, name
+    for _ in range(4):
+        Observability()  # the window's recorder is out of reach: the span medians still come
+    run = _run_dict("serve", spans=spans, counters={"max_slots": 2})
+    assert reader.read(run)["decode.dispatch_ms_p50"] == pytest.approx(5.0)
+    assert any("no live recorder holds the window's spans" in line for line in run["logs"])
+
+
 def test_serve_prefill_reader_shares():
     reader = _reader("serve_prefill.py")
     spans = [("prefill.first_token", 1.0, 0.25), ("prefill.first_token", 2.0, 0.25), ("prefill.chunk", 3.0, 9.0)]
@@ -506,7 +751,13 @@ def test_setup_programs_reader(monkeypatch):
     ("serve_124m_sample", {"req.queue_ms_mean", "req.prefill_ms_mean", "engine.round_ms_p50",
                            "engine.round_self_ms_p50", "prefill.first_token_sync_share",
                            "setup.compile_or_load_s",
-                           "setup.trace_lower_s", "setup.programs"}),
+                           "setup.trace_lower_s", "setup.programs",
+                           # PR 36: the host side of a round from inside
+                           "decode.steps_per_round_mean", "decode.round_fill", "decode.tail_limited_share",
+                           "decode.host_ms_per_token", "decode.dispatch_ms_p50", "decode.host_post_ms_p50",
+                           "decode.assemble_ms_p50", "decode.key_ms_p50", "decode.put_ms_p50",
+                           "decode.enqueue_ms_p50", "decode.callback_share", "prefill.assemble_ms_p50",
+                           "prefill.rows_per_call_mean"}),
     ("train_124m", {"train.feed_ms_p50", "setup.compile_or_load_s", "setup.trace_lower_s", "setup.programs",
                     "step.attn_ms", "step.mlp_ms", "step.lm_head_loss_ms", "step.optimizer_ms",
                     "step.unattributed_ms"}),
