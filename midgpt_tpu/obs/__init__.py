@@ -12,8 +12,10 @@ the engine loop reads its injected clock at four boundaries per round —
 
     t0      batch assembly starts
               t_a  the numpy arguments, page bucket and split are chosen
-              t_k  the round's key is split (sampled rounds only)
-              t_p  the arguments are on the device
+              t_k  the host's time on the key ends (sampled rounds only;
+                   the device holds the key and the program splits it)
+              t_p  the page tables are built (numpy: the arguments' transfer
+                   rides the call)
     t1      jit call returned (dispatch enqueued; NOT compute done)
     t_land  np.asarray(...) force returned — the round's one device
             sync: the tokens are on the host
@@ -199,9 +201,10 @@ class Observability:
         committed, requests `finished` and `callback_s` (seconds of the
         commit inside the client's `on_token`) on the host_post span.
         `cuts` = (t_a, t_k, t_p), the engine's readings inside t0 -> t1:
-        the children `.assemble`, `.key` (t_k None: no key was split),
-        `.put` and `.enqueue` tile the dispatch span, recorded after it and
-        named its children (`Tracer.complete`)."""
+        the children `.assemble`, `.key` (t_k None: a greedy round, no key),
+        `.put` (the page tables' build) and `.enqueue` (the one jit call,
+        its numpy arguments' transfer with it) tile the dispatch span,
+        recorded after it and named its children (`Tracer.complete`)."""
         self._h_dispatch.observe(t1 - t0)
         self._h_device.observe(t_land - t1)
         self._h_post.observe(t_post - t_land)
@@ -232,8 +235,9 @@ class Observability:
         """A prefill call's host time BEFORE its enqueue span
         (`prefill.chunk`) opens at `t_end`: the numpy chunk, starts and page
         bucket (t0 -> t_n: the span's self time), then the children
-        `prefill.put` (page tables and arrays to the device) and, in a
-        sampled call, `prefill.key` (the call's key split, from t_p)."""
+        `prefill.put` (the call's page-table rows, built in numpy) and, in
+        a sampled call, `prefill.key` (the host's time on the key, from
+        t_p: the program splits it, so two clock reads apart)."""
         seq = self.tracer.complete(
             "prefill.assemble", "prefill", tid, t0, t_end - t0, rid=rid
         )

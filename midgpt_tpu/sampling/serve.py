@@ -260,14 +260,31 @@ class _PoolProgram:
 
 def _abstract(a):
     """A jax.Array as the ShapeDtypeStruct that lowers like it (its
-    sharding when committed); anything else as it is."""
+    sharding when committed), a numpy array or scalar (a round's arguments,
+    which ride the call as they are) as the one of its shape and dtype;
+    anything else as it is."""
+    # a Tracer: the program called under another trace (tests)
+    if isinstance(a, (np.ndarray, np.generic, jax.core.Tracer)):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype)
     if not isinstance(a, jax.Array):
         return a
-    if isinstance(a, jax.core.Tracer):  # the program called under another trace (tests)
-        return jax.ShapeDtypeStruct(a.shape, a.dtype)
     return jax.ShapeDtypeStruct(
         a.shape, a.dtype, sharding=a.sharding if a.committed else None
     )
+
+
+def _split_key(key, num: int = 2):
+    """A sampled serving program's FIRST operation: `key` is the engine's
+    key as the program before left it on the device, split here and never on
+    the host (an eager split is a program dispatch of its own, with the
+    device idle). Returns (the key the engine keeps for its next program,
+    the `num - 1` keys this one samples under): the sequence of keys is a
+    function of the seed and the order the programs were called in, the one
+    a host-side split before each call gives. `key` None (a greedy program):
+    as many Nones."""
+    if key is None:
+        return (None,) * num
+    return tuple(jax.random.split(key, num))
 
 
 @_PoolProgram
@@ -296,10 +313,12 @@ def _serve_prefill_chunk(
     at `n_valid - 1`, its (1, 1, V) as it is. The token of each row is
     sampled from that row as `_serve_decode_chunk`'s step samples: the f32
     argmax at `temperature` 0 (static, with `top_k` / `top_p`), else
-    `sample_logits` under `key`, one key split over the rows. Returns
-    (tokens (W,) int32, rows (W, V), cache): the first token of a row whose
-    prompt this chunk ends, and THE logits it was sampled from; an empty
-    row's pair means nothing."""
+    `sample_logits` under a key split off `key`, the engine's (`_split_key`),
+    one key over the rows. Returns (tokens (W,) int32, rows (W, V), cache,
+    the engine's next key): the first token of a row whose prompt this chunk
+    ends, and THE logits it was sampled from; an empty row's pair means
+    nothing."""
+    next_key, key = _split_key(key)
     logits, cache = config.model().prefill_paged_chunk(
         config, params, tokens, start, n_valid, cache, page_table_row,
         attn_impl=attn_impl, mesh=mesh,
@@ -315,7 +334,7 @@ def _serve_prefill_chunk(
         first = jnp.argmax(logits.astype(jnp.float32), axis=-1)
     else:
         first = sample_logits(logits, key, temperature, top_k, top_p)
-    return first.astype(jnp.int32), logits, _maybe_constrain(cache, mesh)
+    return first.astype(jnp.int32), logits, _maybe_constrain(cache, mesh), next_key
 
 
 @_PoolProgram
@@ -341,7 +360,10 @@ def _serve_decode_chunk(
 ):
     """n_steps decode+sample steps for the whole slot batch as ONE device
     program. Inactive slots hold their token and length (their writes land
-    on the sink page). Returns (cache, tokens (n_steps, B))."""
+    on the sink page). `key` is the engine's: the round's own is split off it
+    first (`_split_key`). Returns (cache, tokens (n_steps, B), the engine's
+    next key)."""
+    next_key, key = _split_key(key)
 
     def body(carry, _):
         token, cache, lengths, key = carry
@@ -365,7 +387,7 @@ def _serve_decode_chunk(
     (_, cache, _, _), toks = jax.lax.scan(
         body, (token, cache, lengths, key), None, length=n_steps
     )
-    return cache, toks
+    return cache, toks, next_key
 
 
 @_PoolProgram
@@ -477,9 +499,11 @@ def _serve_decode_group(
     `round_group` is a pow2-bucketed static (`_round_group_bucket`), so
     the compile set stays one program per (n_steps bucket, page bucket,
     round_group) — pinned by tests/test_recompile_pins.py. Returns
-    (cache, toks (T, B), emitted (T, B) bool, tok_fin (B,), len_fin (B,))
-    with T = n_steps * round_group; tok_fin/len_fin seed the next group's
-    chain without settling this one."""
+    (cache, toks (T, B), emitted (T, B) bool, tok_fin (B,), len_fin (B,),
+    the engine's next key) with T = n_steps * round_group; tok_fin/len_fin
+    seed the next group's chain without settling this one. `key` is the
+    engine's, the group's own split off it first (`_split_key`)."""
+    next_key, key = _split_key(key)
     token = jnp.where(chain_mask, chain_token, token)
     lengths = jnp.where(chain_mask, chain_len, lengths)
 
@@ -513,7 +537,7 @@ def _serve_decode_group(
         None,
         length=n_steps * round_group,
     )
-    return cache, toks, emitted, tok_fin, len_fin
+    return cache, toks, emitted, tok_fin, len_fin, next_key
 
 
 @_PoolProgram
@@ -543,7 +567,11 @@ def _spec_draft_chunk(
     (k, B, V) f32) where probs[i] is the warped draft distribution proposal
     i was drawn from — the q_i the verify program's rejection sampler
     needs. Compiled once per (k bucket, page bucket), independent of
-    request mix (pinned by tests/test_recompile_pins.py)."""
+    request mix (pinned by tests/test_recompile_pins.py). `key` is the
+    engine's: the round's three-way split is made here first (`_split_key`),
+    and after the three come the engine's next key and the key
+    `_spec_verify_chunk` takes, neither ever on the host."""
+    next_key, key, verify_key = _split_key(key, 3)
 
     def body(carry, _):
         token, cache, lengths, key = carry
@@ -569,7 +597,7 @@ def _spec_draft_chunk(
     (_, cache, _, _), (toks, probs) = jax.lax.scan(
         body, (token, cache, lengths, key), None, length=k_steps
     )
-    return cache, toks, probs
+    return cache, toks, probs, next_key, verify_key
 
 
 @_PoolProgram
@@ -1157,6 +1185,15 @@ class ServeEngine:
         self.slots: tp.List[tp.Optional[_Slot]] = [None] * max_slots
         self.queue: tp.List[Request] = []
         self.finished: tp.Dict[int, FinishedRequest] = {}
+        # The sampling key lives on the DEVICE and the host never splits it:
+        # every sampled program takes it, splits its own key(s) off it first
+        # thing (`_split_key`) and hands back the one kept here, unforced, for
+        # the next program (`_sampling_key`). A dispatch is then ONE jit call:
+        # the round's other arguments (tokens, lengths, masks, page tables)
+        # are numpy and ride that call's own argument handling, so nothing
+        # else is dispatched to the device on the path of a round. A greedy
+        # engine's programs take no key and hand None back: it has no use
+        # for this one.
         self._key = jax.random.PRNGKey(seed)
         self._uid = 0
         self._admitted = 0
@@ -2011,11 +2048,8 @@ class ServeEngine:
         bucket = self._page_bucket(round_span)
         split_k = self._split_bucket(round_span)
         t_a = 0.0 if obs is None else self._clock()
-        if self.temperature == 0.0:
-            key = t_k = None
-        else:
-            self._key, key = jax.random.split(self._key)
-            t_k = 0.0 if obs is None else self._clock()
+        key = self._sampling_key()
+        t_k = None if key is None or obs is None else self._clock()
         # Chain carry-in: the previous group's unforced outputs when
         # chaining, else zero fillers of the same shape/dtype — ONE
         # compiled program serves both cases, and nothing here syncs.
@@ -2024,27 +2058,21 @@ class ServeEngine:
         else:
             chain_token = np.zeros((B,), np.int32)
             chain_len = np.zeros((B,), np.int32)
-        # the arguments go to the device BEFORE the call, so that the put
-        # and the enqueue have a boundary between them
-        token_j, tables = jnp.asarray(token), self._device_tables(bucket)
-        lengths_j, active_j, eos_j, max_len_j, mask_j, chain_token_j, chain_len_j = (
-            jnp.asarray(a)
-            for a in (lengths, active, eos, max_len, chain_mask, chain_token, chain_len)
-        )
+        tables = self._device_tables(bucket)
         t_p = 0.0 if obs is None else self._clock()
-        self.cache, toks, emitted, tok_fin, len_fin = _serve_decode_group(
+        self.cache, toks, emitted, tok_fin, len_fin, self._key = _serve_decode_group(
             self.config,
             self.params,
-            token_j,
+            token,
             self.cache,
             tables,
-            lengths_j,
-            active_j,
-            eos_j,
-            max_len_j,
-            mask_j,
-            chain_token_j,
-            chain_len_j,
+            lengths,
+            active,
+            eos,
+            max_len,
+            chain_mask,
+            chain_token,
+            chain_len,
             n,
             self.round_group,
             self.temperature,
@@ -2407,6 +2435,13 @@ class ServeEngine:
                 self.prefix_cache.release(committed, slot.pages[0], slot.n_shared)
             )
 
+    def _sampling_key(self) -> tp.Optional[Array]:
+        """The `key` argument of this engine's next program: the engine's
+        key as the program before left it on the device (the program splits
+        its own off it and hands back the next: the caller stores that as
+        `_key`, unforced), or None, greedy: no key at all."""
+        return None if self.temperature == 0.0 else self._key
+
     def _page_table(self, n_pages: tp.Optional[int] = None, kind: int = 0) -> np.ndarray:
         table = np.zeros((self.max_slots, n_pages or self.max_pages_per_slot), np.int32)
         for i, s in enumerate(self.slots):
@@ -2420,17 +2455,19 @@ class ServeEngine:
         return table
 
     def _device_tables(self, n_pages: int, rows: tp.Optional[tp.Sequence[int]] = None):
-        """The round's page table as the serving programs take it: the (slots,
-        n_pages) table of the first kind, or, where the family has several
-        kinds, the tuple of every kind's. `rows`: those slots' rows alone, in
-        that order, then empty rows (the sink page) up to `prefill_width`."""
-        def table(kind: int) -> Array:
+        """The round's page table as the serving programs take it, in numpy
+        (its transfer rides the program's call, as every argument of a round
+        does): the (slots, n_pages) table of the first kind, or, where the
+        family has several kinds, the tuple of every kind's. `rows`: those
+        slots' rows alone, in that order, then empty rows (the sink page) up
+        to `prefill_width`."""
+        def table(kind: int) -> np.ndarray:
             full = self._page_table(n_pages, kind)
             if rows is None:
-                return jnp.asarray(full)
+                return full
             picked = np.zeros((self.prefill_width, full.shape[1]), np.int32)
             picked[: len(rows)] = full[list(rows)]
-            return jnp.asarray(picked)
+            return picked
 
         if len(self.kinds) == 1:
             return table(0)
@@ -2584,8 +2621,9 @@ class ServeEngine:
         call's `prefill_width` int32 tokens, once, and the logits they were
         sampled from only when `on_first_logits` asks for them."""
         # obs on: `prefill.assemble` runs from here to where `prefill.chunk`
-        # opens (t_n: the numpy arrays and the bucket are there; t_p: the
-        # puts are done and the key split begins, sampled calls only)
+        # opens (t_n: the chunk, starts and bucket are there; t_p: so are the
+        # page tables' rows, sampled calls only: what follows is the host's
+        # time on the key, two clock reads apart)
         obs = self.obs
         t0 = 0.0 if obs is None else self._clock()
         W = self.prefill_width
@@ -2599,33 +2637,28 @@ class ServeEngine:
         bucket = self._page_bucket(int((start + n_valid).max()))
         t_n = 0.0 if obs is None else self._clock()
         table = self._device_tables(bucket, [slot_i for slot_i, _, _ in rows])
-        chunk_j = jnp.asarray(chunk)
-        if W > 1:
-            start_j, n_valid_j = jnp.asarray(start), jnp.asarray(n_valid)
-        else:  # the one-row call every family takes: scalars
-            start_j, n_valid_j = jnp.asarray(start[0]), jnp.asarray(n_valid[0])
-        if self.temperature == 0.0:
-            key = t_p = None
-        else:  # one key a call, as `_decode_round` makes one a round
-            t_p = 0.0 if obs is None else self._clock()
-            self._key, key = jax.random.split(self._key)
-        # The span covers the async ENQUEUE of ONE call and nothing else:
-        # the arguments were assembled and put above (`prefill.assemble`),
-        # and nothing is forced here (a call none of whose rows ends its
-        # prompt never syncs; the force happens in the first-token block
-        # below). It belongs to no one request: rid is the first row's.
-        # Its args say what rode the call.
+        # the one-row call every family takes: (numpy) scalars
+        start_a, n_valid_a = (start, n_valid) if W > 1 else (start[0], n_valid[0])
+        # one key a call, as a decode round has one: split off inside the program
+        key = self._sampling_key()
+        t_p = None if key is None or obs is None else self._clock()
+        # The span covers the async ENQUEUE of ONE call, the transfer of its
+        # numpy arguments with it, and nothing else: they were assembled
+        # above (`prefill.assemble`), and nothing is forced here (a call
+        # none of whose rows ends its prompt never syncs; the force happens
+        # in the first-token block below). It belongs to no one request: rid
+        # is the first row's. Its args say what rode the call.
         with self._trace.span(
             "prefill.chunk", "prefill", self._obs_tid, rows[0][1].request.uid,
             None if obs is None else
             {"rows": len(rows), "tokens": int(n_valid.sum()), "bucket": bucket},
         ) as sp:
-            first, logits, self.cache = _serve_prefill_chunk(
+            first, logits, self.cache, self._key = _serve_prefill_chunk(
                 self.config,
                 self.params,
-                chunk_j,
-                start_j,
-                n_valid_j,
+                chunk,
+                start_a,
+                n_valid_a,
                 self.cache,
                 table,
                 self.mesh,
@@ -2643,12 +2676,12 @@ class ServeEngine:
                 # (the pending token is the TARGET's). A prefix self-draft
                 # skips this: the target prefill above already filled its
                 # layers of the shared pool.
-                _, _, self.draft_cache = _serve_prefill_chunk(
+                _, _, self.draft_cache, _ = _serve_prefill_chunk(
                     self.draft_config,
                     self.draft_params,
-                    chunk_j,
-                    start_j,
-                    n_valid_j,
+                    chunk,
+                    start_a,
+                    n_valid_a,
                     self.draft_cache,
                     table,
                     self.mesh,
@@ -2777,9 +2810,9 @@ class ServeEngine:
             return {}
         token, lengths, active, round_span = self._decode_args(active_idx, n)
         logits, self.cache = _serve_decode_logits(
-            self.config, self.params, jnp.asarray(token), self.cache,
-            self._device_tables(self._page_bucket(round_span)), jnp.asarray(lengths),
-            jnp.asarray(active), self.attn_impl, self.mesh, self._split_bucket(round_span),
+            self.config, self.params, token, self.cache,
+            self._device_tables(self._page_bucket(round_span)), lengths,
+            active, self.attn_impl, self.mesh, self._split_bucket(round_span),
         )
         logits = np.asarray(logits, np.float32)
         return {self.slots[i].request.uid: logits[i] for i in active_idx}
@@ -2795,33 +2828,29 @@ class ServeEngine:
 
         # Round decomposition (obs/__init__.py docstring): t0 -> t1 is host
         # assembly + jit ENQUEUE (cut at t_a, t_k, t_p into assemble / key /
-        # put / enqueue), t1 -> t_done is device compute + the copy to the
-        # host (the np.asarray force is the round's one sync), t_done ->
-        # t_post is token commit.
+        # put / enqueue: the key is the device's own and costs the host two
+        # clock reads, the put is the page tables' build, the enqueue is the
+        # one call and carries the numpy arguments' transfer), t1 -> t_done
+        # is device compute + the copy to the host (the np.asarray force is
+        # the round's one sync), t_done -> t_post is token commit.
         obs = self.obs
         t0 = 0.0 if obs is None else self._clock()
         token, lengths, active, round_span = self._decode_args(active_idx, n)
         bucket = self._page_bucket(round_span)
         split_k = self._split_bucket(round_span)
         t_a = 0.0 if obs is None else self._clock()
-        if self.temperature == 0.0:
-            key = t_k = None
-        else:
-            self._key, key = jax.random.split(self._key)
-            t_k = 0.0 if obs is None else self._clock()
-        # the arguments go to the device BEFORE the call, so that the put
-        # and the enqueue have a boundary between them
-        token_j, tables = jnp.asarray(token), self._device_tables(bucket)
-        lengths_j, active_j = jnp.asarray(lengths), jnp.asarray(active)
+        key = self._sampling_key()
+        t_k = None if key is None or obs is None else self._clock()
+        tables = self._device_tables(bucket)
         t_p = 0.0 if obs is None else self._clock()
-        self.cache, toks = _serve_decode_chunk(
+        self.cache, toks, self._key = _serve_decode_chunk(
             self.config,
             self.params,
-            token_j,
+            token,
             self.cache,
             tables,
-            lengths_j,
-            active_j,
+            lengths,
+            active,
             n,
             self.temperature,
             self.top_k,
@@ -2921,17 +2950,10 @@ class ServeEngine:
             token[i] = s.generated[-1] if s.generated else s.request.prompt[-1]
             lengths[i] = s.length
             active[i] = True
-        if self.temperature == 0.0:
-            key_d = key_v = None
-        else:
-            self._key, key_d, key_v = jax.random.split(self._key, 3)
         round_span = max(self.slots[i].length for i in active_idx) + k + 1
         bucket = self._page_bucket(round_span)
         split_k = self._split_bucket(round_span)
-        table = jnp.asarray(self._page_table(bucket))
-        token_j = jnp.asarray(token)
-        lengths_j = jnp.asarray(lengths)
-        active_j = jnp.asarray(active)
+        table = self._page_table(bucket)
         # drafts/draft_probs stay on device between the two dispatches —
         # the host only ever reads the small (B,) / (B, k+1) verify outputs.
         # With a prefix self-draft the draft steps run against the TARGET
@@ -2941,20 +2963,22 @@ class ServeEngine:
         # rewrites those columns with the identical values.
         shared = self.draft_shares_cache
         draft_cache_in = self.cache if shared else self.draft_cache
-        draft_cache_out, drafts, draft_probs = _spec_draft_chunk(
+        # The draft program makes the round's three-way key split: it hands
+        # back the engine's next key and the verify program's, on the device.
+        draft_cache_out, drafts, draft_probs, self._key, key_v = _spec_draft_chunk(
             self.draft_config,
             self.draft_params,
-            token_j,
+            token,
             draft_cache_in,
             table,
-            lengths_j,
-            active_j,
+            lengths,
+            active,
             k,
             self.temperature,
             self.top_k,
             self.top_p,
             self.attn_impl,
-            key_d,
+            self._sampling_key(),
             self.mesh,
             split_k,
         )
@@ -2966,13 +2990,13 @@ class ServeEngine:
         self.cache, n_accept, out = _spec_verify_chunk(
             self.config,
             self.params,
-            token_j,
+            token,
             drafts,
             draft_probs,
             self.cache,
             table,
-            lengths_j,
-            active_j,
+            lengths,
+            active,
             self.temperature,
             self.top_k,
             self.top_p,

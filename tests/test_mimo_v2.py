@@ -286,7 +286,11 @@ def test_the_reference_blocks_its_queries_without_changing_its_result(model, mon
 # sha256 of `.lower(...).as_text()` (StableHLO, no locations) on the CPU backend with the XLA
 # gather lowering, taken on the parent of PR 30 (commit 0410ecb) by this same function; the two
 # `prefill16` entries: taken at PR 35, whose program samples each row's first token at its end (temperature 0.8, as the
-# serving cells run it) and hands back (tokens (B,), rows (B, V)); PR 31 had made it a batch of B rows
+# serving cells run it) and hands back (tokens (B,), rows (B, V)); PR 31 had made it a batch of B rows. The four SAMPLED
+# entries (`decode8`, `prefill16`, both presets): taken again at PR 37, whose sampled programs take the engine's key, split
+# their own off it first and hand back the engine's next (a key in, a key out; the tokens are the parent's bit for bit:
+# tests/test_sampled_streams.py). The greedy `decode1_greedy` and `verify5` (its key is made on the device by the draft
+# program now, the program itself untouched) are the parent's of PR 30, byte for byte
 GPT_PROGRAM_HASHES = json.load(open(os.path.join(os.path.dirname(__file__), "golden", "gpt_serving_programs_pr29.json")))
 
 

@@ -143,7 +143,8 @@ def test_prefill_program_samples_its_first_tokens_from_the_rows_it_hands_back(mo
                                                                                temperature, top_k):
     """`_serve_prefill_chunk` brings what the family hands out to one row a
     slot, the last valid position's, and its tokens are `sample_logits` of
-    those rows under the key it was given (the f32 argmax at temperature 0)."""
+    those rows under the key it splits off the engine's, which it was given
+    (the f32 argmax at temperature 0)."""
     from midgpt_tpu.sampling.engine import sample_logits
     from midgpt_tpu.sampling.serve import _serve_prefill_chunk
 
@@ -154,8 +155,13 @@ def test_prefill_program_samples_its_first_tokens_from_the_rows_it_hands_back(mo
     if want.ndim == 3:
         want = want[:, min(int(n_valid), want.shape[1]) - 1]
     key = None if temperature == 0.0 else jax.random.PRNGKey(11)
-    first, rows, _ = _serve_prefill_chunk(config, params, tokens, start, n_valid, cache(), table, None, "gather",
-                                          temperature, top_k, None, key)
+    first, rows, _, next_key = _serve_prefill_chunk(config, params, tokens, start, n_valid, cache(), table, None, "gather",
+                                                    temperature, top_k, None, key)
+    if key is None:
+        assert next_key is None
+    else:  # the program's first operation is the split the host used to make: it keeps one half and hands back the other
+        kept, key = jax.random.split(key)
+        np.testing.assert_array_equal(np.asarray(next_key), np.asarray(kept))
     assert (first.shape, first.dtype, rows.shape) == ((tokens.shape[0],), jnp.int32, want.shape)
     np.testing.assert_allclose(np.asarray(rows)[live], want[live], atol=1e-6)
     if temperature == 0.0:

@@ -33,6 +33,11 @@ arguments and the avals handed back); `lower` and `compile` came out the
 parent's name for name and count for count (`PARENT_LOWERED`), `trace` gained
 one `_argmax` a prefill program.
 
+PR 37 hands the program's arguments over in numpy (the recorded avals are the
+same: shape, dtype, not weak) and the program hands back a fourth value, the
+engine's next sampling key (None here: both engines are greedy). The golden is
+untouched.
+
 The MimoV2 record has to equal the golden in everything. The GPT's prefill
 PROGRAM is a text of PR 32's (a batch of B rows, here B = 1), so the functions
 traced inside it differ from the parent's; what is held there: the calls, the
@@ -118,14 +123,14 @@ def record(case: str) -> dict:
     real_chunk, real_round = serve._serve_prefill_chunk, eng._prefill_round
 
     def chunk(config, p, tokens, start, n_valid, cache, table, mesh, attn_impl, temperature, top_k, top_p, key):
-        first, logits, cache = real_chunk(
+        first, logits, cache, next_key = real_chunk(
             config, p, tokens, start, n_valid, cache, table, mesh, attn_impl, temperature, top_k, top_p, key)
         calls.append([
             "serve_prefill_chunk", aval(tokens), aval(start), aval(n_valid),
             [aval(t) for t in jax.tree.leaves(table)], np.asarray(start).tolist(), np.asarray(n_valid).tolist(),
             attn_impl, [temperature, top_k, top_p, key], aval(first), list(logits.shape),
         ])
-        return first, logits, cache
+        return first, logits, cache, next_key
 
     def prefill_round():
         inside[0] = True

@@ -673,6 +673,100 @@ def test_engine_dispatch_reader_on_a_hand_made_window():
     assert reader.read(_run_dict("train")) is None and reader.read(_run_dict("serve")) is None
 
 
+THIRTEEN = (
+    "decode.steps_per_round_mean", "decode.round_fill", "decode.tail_limited_share", "decode.host_ms_per_token",
+    "decode.dispatch_ms_p50", "decode.host_post_ms_p50", "decode.assemble_ms_p50", "decode.key_ms_p50",
+    "decode.put_ms_p50", "decode.enqueue_ms_p50", "decode.callback_share", "prefill.assemble_ms_p50",
+    "prefill.rows_per_call_mean")
+
+
+@pytest.mark.parametrize("overlap", ["off", "group"])
+def test_engine_dispatch_reader_reads_all_thirteen_off_a_sampled_engine(params, overlap):
+    """PR 37 took the key split and the puts off the host; their spans are still
+    stamped in a sampled round (`decode.key`: two clock reads apart; `decode.put`:
+    the page tables' build), so the reader has a NUMBER for each of its thirteen
+    metrics off a real engine's window, none left out."""
+    clock = StepClock()
+    obs = Observability(clock=clock)
+    _serve(_engine(params, 17, clock, obs, temperature=0.8, overlap=overlap, round_group=2,
+                   on_token=lambda uid, tok, t: None))
+    spans = [(e[1], e[4], e[5]) for e in obs.tracer.events() if e[0] == "X"]
+    run = _run_dict("serve", spans=spans, counters={"max_slots": 2})
+    out = _reader("engine_dispatch.py").read(run)
+    assert set(out) == set(THIRTEEN)
+    assert all(isinstance(out[k], float) for k in THIRTEEN), {k: out[k] for k in THIRTEEN if out[k] is None}
+    assert out["decode.key_ms_p50"] == pytest.approx(1.0)  # one step of the clock: the host does nothing there
+    assert not [line for line in run["logs"] if "left out" in line]
+
+
+_DISPATCH_COUNT = """
+import json, sys
+import jax
+jax.config.update("jax_platforms", "cpu")
+import jax.numpy as jnp, numpy as np
+from midgpt_tpu.utils import compile_cache
+stats = compile_cache.enable()
+from midgpt_tpu.models.gpt import GPT, GPTConfig
+from midgpt_tpu.sampling import serve
+from midgpt_tpu.sampling.spec import self_draft
+cfg = GPTConfig(block_size=64, vocab_size=64, n_layer=2, n_head=2, n_embd=32)
+params = jax.jit(lambda k: GPT.init(cfg, k))(jax.random.PRNGKey(0))  # one program: no eager split of the model's own
+dcfg, dparams = self_draft(cfg, params, 1)
+rounds = 0
+for kw in ({}, {"overlap": "group", "round_group": 2}, {"overlap": "double", "round_group": 2},
+           {"draft_config": dcfg, "draft_params": dparams}):
+    eng = serve.ServeEngine(cfg, params, max_slots=2, page_size=8, num_pages=17, prefill_chunk=8, decode_chunk=4,
+                            temperature=0.8, cache_dtype=jnp.float32, **kw)
+    for i in range(4):
+        eng.submit(np.arange(1, 12 + 3 * i, dtype=np.int32), 10 + i)
+    while not eng.idle:
+        eng.step()
+    rounds += eng.rounds
+programs = (serve._serve_prefill_chunk, serve._serve_decode_chunk, serve._serve_decode_group,
+            serve._spec_draft_chunk, serve._spec_verify_chunk)
+print(json.dumps({"programs": {f: c.calls for f, c in stats.programs.items()}, "rounds": rounds,
+                  "labels": sorted(label for p in programs for label in p._compiled)}))
+"""
+
+# What the script above gave on the PARENT of PR 37 (commit bef1e18), whose table also held `_threefry_split` x2 (the
+# two- and the three-way split) and `_unstack` x2, each a program of its own that the host dispatched before every
+# sampled call: the serving programs the same traffic compiled there, by label, and how many a jitted function
+# (a draft model's prefill program has its target's label)
+PARENT_COMPILE_SET = [
+    "serve_decode_chunk n_steps=1 page_table=4 pool=float32", "serve_decode_chunk n_steps=2 page_table=4 pool=float32",
+    "serve_decode_chunk n_steps=4 page_table=4 pool=float32",
+    "serve_decode_group n_steps=2 round_group=2 page_table=4 pool=float32",
+    "serve_decode_group n_steps=4 round_group=2 page_table=4 pool=float32",
+    "serve_prefill_chunk page_table_row=1 tokens=8 pool=float32", "serve_prefill_chunk page_table_row=2 tokens=8 pool=float32",
+    "serve_prefill_chunk page_table_row=4 tokens=8 pool=float32",
+    "spec_draft_chunk k_steps=1 page_table=4 pool=float32", "spec_draft_chunk k_steps=1 page_table=8 pool=float32",
+    "spec_draft_chunk k_steps=2 page_table=4 pool=float32", "spec_draft_chunk k_steps=4 page_table=4 pool=float32",
+    "spec_verify_chunk page_table=4 drafts=2 pool=float32", "spec_verify_chunk page_table=8 drafts=2 pool=float32",
+]
+PARENT_COMPILES = {"_serve_prefill_chunk": 6, "_serve_decode_chunk": 3, "_serve_decode_group": 2,
+                   "_spec_draft_chunk": 4, "_spec_verify_chunk": 4}
+
+
+def test_a_sampled_round_dispatches_its_one_program_and_compiles_the_parents_set(tmp_path):
+    """A count, CPU: fresh engines (classic, group, double, speculative) served to
+    the end at temperature 0.8 in a process of their own leave in
+    `compile_cache.current()`'s table no `_threefry_split` and no `_unstack`
+    compiled (the host splits no key and unpacks none: the programs do, and a
+    split traced inside one compiles nothing of its own), and the serving
+    programs compiled are the parent's, label for label and count for count:
+    numpy arguments made no second entry."""
+    out = subprocess.run(
+        [sys.executable, "-c", _DISPATCH_COUNT], capture_output=True, text=True, check=False, cwd=ROOT,
+        env={**os.environ, "JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
+    assert out.returncode == 0, out.stderr[-3000:]
+    got = json.loads(out.stdout.splitlines()[-1])
+    compiled = {f: n for f, n in got["programs"].items() if n}
+    assert got["rounds"] > 40
+    assert not {"_threefry_split", "_unstack"} & set(compiled), compiled
+    assert got["labels"] == PARENT_COMPILE_SET
+    assert {f: n for f, n in compiled.items() if f.startswith(("_serve_", "_spec_"))} == PARENT_COMPILES
+
+
 def test_engine_dispatch_reader_leaves_out_what_the_parent_cannot_say():
     """On a program whose spans carry no args and no children (the parent of
     PR 36, which the driver runs these readers over): the two medians that
@@ -753,11 +847,7 @@ def test_setup_programs_reader(monkeypatch):
                            "setup.compile_or_load_s",
                            "setup.trace_lower_s", "setup.programs",
                            # PR 36: the host side of a round from inside
-                           "decode.steps_per_round_mean", "decode.round_fill", "decode.tail_limited_share",
-                           "decode.host_ms_per_token", "decode.dispatch_ms_p50", "decode.host_post_ms_p50",
-                           "decode.assemble_ms_p50", "decode.key_ms_p50", "decode.put_ms_p50",
-                           "decode.enqueue_ms_p50", "decode.callback_share", "prefill.assemble_ms_p50",
-                           "prefill.rows_per_call_mean"}),
+                           *THIRTEEN}),
     ("train_124m", {"train.feed_ms_p50", "setup.compile_or_load_s", "setup.trace_lower_s", "setup.programs",
                     "step.attn_ms", "step.mlp_ms", "step.lm_head_loss_ms", "step.optimizer_ms",
                     "step.unattributed_ms"}),
