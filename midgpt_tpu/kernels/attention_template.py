@@ -98,6 +98,15 @@ Variants ARE specs over this template, not new sweeps:
     that are fully behind every row's window and past the sink prefix —
     the resident work per row is O(window), which is what makes long
     windowed sessions O(1) in T.
+  * latent (MLA, absorbed) — `v_lanes` = n: there is ONE pool, whose row is
+    a token's normed latent followed by its rotated shared key, stored once;
+    K is the whole row and V is a VIEW of its leading n lanes. The kernel
+    copies each page once, into the K buffers, and reads the values as
+    `k[..., :n]`: no V operand, no V copy, no V buffer. The 128 query heads
+    (the up-projection's key half folded into q, models/pangu_ultra.py) are
+    the GQA fold's rows over the pool's one head; with one query row a head
+    they share one count, read as a scalar instead of a row a sublane.
+    `scale` is the published 1 / sqrt(nope + rope), which is not q's width here.
 """
 
 from __future__ import annotations
@@ -177,10 +186,11 @@ def block_pages(
 
 
 def block_vmem_bytes(
-    n_heads: int, lanes: int, itemsize: int, page_size: int, n: int
+    n_heads: int, lanes: int, itemsize: int, page_size: int, n: int, v_view: bool = False
 ) -> int:
-    """VMEM the block buffers of one call hold: K and V, two slots each."""
-    return 4 * n_heads * n * page_size * lanes * itemsize
+    """VMEM the block buffers of one call hold: K and V, two slots each; K
+    alone where V is a view of K's lanes (`v_lanes`: a latent pool)."""
+    return (2 if v_view else 4) * n_heads * n * page_size * lanes * itemsize
 
 
 def _seen(tok0, width, first, last, sliding_window: int, attn_sinks: int):
@@ -225,12 +235,13 @@ def _tpl_kernel(
     layer_ref,  # (1,) int32 scalar-prefetch: the pool's layer to read
     q_ref,  # (1, H, R, C) — head-major rows
     k_hbm,  # (L, H, P, page_size, C) — the whole pool, left in HBM
-    v_hbm,
-    *rest,  # int8 mode: ks_ref, vs_ref (1, 1, H, tokens) f32; then outputs
+    *rest,  # v_hbm (absent with `v_lanes`: V is a view of K's pages);
+    # int8 mode: ks_ref, vs_ref (1, 1, H, tokens) f32; then outputs
     # split_k == 1: o_ref (1, H, R, C)
     # split_k > 1:  o_ref (1, H, R, C) f32, m_ref/l_ref (1, H, R, 8) f32
     # then scratch: acc_sc (H, R, C) f32, m_sc/l_sc (H, R, 8) f32,
     #   st_ref (2,) int32 SMEM, sem (2, 2) DMA, k_buf/v_buf (2, H, tokens, C)
+    #   (no v_buf with `v_lanes`)
     scale: float,
     page_size: int,
     n_rows: int,
@@ -240,14 +251,22 @@ def _tpl_kernel(
     quantized: bool,
     sliding_window: int,
     attn_sinks: int,
+    v_lanes: int = 0,  # > 0: V is K's leading `v_lanes` lanes (one pool, one copy a page)
+    one_count: bool = False,  # every row of a slot sees the same keys (one query row, heads folded)
 ):
+    v_hbm = v_buf = None
+    if not v_lanes:
+        v_hbm, *rest = rest
     if quantized:
         ks_ref, vs_ref, *rest = rest
     if split_k > 1:
         o_ref, m_ref, l_ref, *rest = rest
     else:
         o_ref, *rest = rest
-    acc_sc, m_sc, l_sc, st_ref, sem, k_buf, v_buf = rest
+    if v_lanes:
+        acc_sc, m_sc, l_sc, st_ref, sem, k_buf = rest
+    else:
+        acc_sc, m_sc, l_sc, st_ref, sem, k_buf, v_buf = rest
     # The scalar code below is spelled in lax primitives on int32: a jnp
     # operator costs five times as much to trace, and `//`, `%` and `clip`
     # each leave a nested jit for Mosaic to lower, in every one of the
@@ -272,7 +291,8 @@ def _tpl_kernel(
         st_ref[0] = I(0)
         st_ref[1] = I(-1)
         k_buf[...] = jnp.zeros(k_buf.shape, k_buf.dtype)
-        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+        if v_buf is not None:
+            v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
 
     @pl.when(i == 0)
     def _init():
@@ -331,8 +351,11 @@ def _tpl_kernel(
 
     def copies(j, page, slot):
         rows = pl.ds(pl.multiple_of(mul(j, I(page_size)), page_size), page_size)
+        k_copy = pltpu.make_async_copy(k_hbm.at[layer, :, page], k_buf.at[slot, :, rows], sem.at[0, slot])
+        if v_buf is None:
+            return [k_copy]
         return [
-            pltpu.make_async_copy(k_hbm.at[layer, :, page], k_buf.at[slot, :, rows], sem.at[0, slot]),
+            k_copy,
             pltpu.make_async_copy(v_hbm.at[layer, :, page], v_buf.at[slot, :, rows], sem.at[1, slot]),
         ]
 
@@ -371,9 +394,13 @@ def _tpl_kernel(
         for_each_page(b, page0, slot, wait)
 
         tok0 = mul(page0, I(page_size))
-        counts = jnp.stack([cnt_ref[b, t] for t in range(n_rows)])  # (R,)
+        if one_count:
+            counts = cnt_ref[b, 0]  # a scalar: no row-a-sublane vector of 128 equal counts
+        else:
+            counts = jnp.stack([cnt_ref[b, t] for t in range(n_rows)])  # (R,)
         q = q_ref[0]  # (H, R, C)
-        k, v = k_buf[slot], v_buf[slot]  # (H, tokens, C)
+        k = k_buf[slot]  # (H, tokens, C)
+        v = k[:, :, :v_lanes] if v_lanes else v_buf[slot]
         if quantized:
             # Dequantize in VMEM: the block's f32 scales broadcast over C
             # (exact — int8 * f32, ops/quant.py), then the same dots as
@@ -389,9 +416,11 @@ def _tpl_kernel(
         # ops/attention.visible_mask spelled as straight-line selects
         # (no lax.cond — graftcheck GC001): causal/length bound, then the
         # window [count - W, count) widened by the sink prefix [0, sinks).
-        keep = col < counts[None, :, None]
+        # (the broadcast is spelled at each use: one shared value is another program text)
+        see = (lambda: counts) if one_count else (lambda: counts[None, :, None])
+        keep = col < see()
         if sliding_window:
-            w = col >= counts[None, :, None] - sliding_window
+            w = col >= see() - sliding_window
             if attn_sinks:
                 w |= col < attn_sinks
             keep &= w
@@ -427,13 +456,13 @@ def _tpl_kernel(
 # program share one body, which lowers once.
 @functools.partial(
     jax.jit,
-    static_argnames=("split_k", "sliding_window", "attn_sinks", "pages_per_block", "v_dim"),
+    static_argnames=("split_k", "sliding_window", "attn_sinks", "pages_per_block", "v_dim", "v_lanes", "scale"),
     inline=True,
 )
 def paged_attention_template(
     q: Array,  # (B, H_q, R, C) — head-major query rows (H_q >= pool heads)
     k_pages: Array,  # (L, H_kv, num_pages, page_size, C) — the WHOLE pool
-    v_pages: Array,  # (or one layer's (H_kv, P, ps, C) with layer=None)
+    v_pages: tp.Optional[Array],  # (or one layer's (H_kv, P, ps, C) with layer=None); None with `v_lanes`
     page_table: Array,  # (B, max_pages) int32
     counts: Array,  # (B, R) int32 — keys visible to row r of slot b
     k_scale: tp.Optional[Array] = None,  # (L, num_pages, H_kv, page_size) f32
@@ -444,6 +473,8 @@ def paged_attention_template(
     layer: tp.Optional[Array] = None,  # () int — which layer of the pool
     pages_per_block: tp.Optional[int] = None,  # tests and sweeps; else derived
     v_dim: tp.Optional[int] = None,  # V's head width where it is not q's (K 192 / V 128)
+    v_lanes: tp.Optional[int] = None,  # V is the leading `v_lanes` lanes of K's pages (a latent pool; `v_pages` None)
+    scale: tp.Optional[float] = None,  # the scores' factor where it is not q's width's rsqrt
 ) -> Array:
     """Instantiate the template for one (n_rows, quantized, split_k,
     kv_groups, window) spec.
@@ -451,7 +482,10 @@ def paged_attention_template(
     Returns (B, H_q, R, C) in q.dtype — (B, H_q, R, v_dim) where the V pool
     has a head width (and lanes) of its own: the K and V page copies, the V
     buffers, the accumulator and the output then take V's lanes, and nothing
-    else in the sweep knows (scores scale by q's width). int8 pools require both scale side
+    else in the sweep knows (scores scale by q's width). With `v_lanes` there
+    is one pool and no V operand: the kernel reads the values out of the K
+    buffer's leading lanes (module docstring, "latent"), the output is (B,
+    H_q, R, v_lanes). int8 pools require both scale side
     buffers; bf16/f32 pools take none. split_k is normalized to a pow2
     divisor of the table width; split_k == 1 is the classic in-kernel
     finalize, split_k > 1 emits per-partition partials and merges them
@@ -472,6 +506,10 @@ def paged_attention_template(
     has no layer-sized slice to materialise (PagedKVCache docstring,
     "Layout contract"). `layer=None` is the one-layer form the kernel
     tests call: the 4-D operands gain a unit layer dim (a bitcast)."""
+    if v_lanes:
+        if v_pages is not None or k_scale is not None:
+            raise ValueError("v_lanes: V is a view of the K pool (no v_pages), and no int8 pool is wired")
+        v_pages = k_pages  # shapes only below: the call carries one pool
     if layer is None:
         layer = 0
         k_pages, v_pages = k_pages[None], v_pages[None]
@@ -480,7 +518,11 @@ def paged_attention_template(
     B, HQ, R, C = q.shape
     _, H, _, page_size, lanes = k_pages.shape
     lanes_v = v_pages.shape[-1]
-    scale = 1.0 / math.sqrt(C)
+    scale = 1.0 / math.sqrt(C) if scale is None else scale
+    if v_lanes and (lanes % 128 or v_lanes % 128 or v_lanes > lanes):
+        # the view is a slice of whole 128-lane rows of the K buffer; a pool off
+        # the layout contract takes the XLA path (models/pangu_ultra.py)
+        raise ValueError(f"v_lanes={v_lanes} of a pool of {lanes} lanes: both must be whole 128-lane rows")
     if lanes % 128 or lanes_v % 128:
         # Off the layout contract (a pool allocated without `kernel_layout`:
         # direct callers, the benchmark's correctness check): the chip keeps
@@ -496,7 +538,7 @@ def paged_attention_template(
         # there with zeros, which add nothing to q.k, and the output's
         # extra lanes (p @ zeros) are dropped on the way out.
         q = jnp.pad(q, [(0, 0)] * 3 + [(0, lanes - C)])
-    C_out, C, Cv = v_dim or C, lanes, lanes_v
+    C_out, C, Cv = v_lanes or v_dim or C, lanes, v_lanes or lanes_v
     groups = HQ // H
     if groups > 1:
         # Fold: head h = kv*groups + g, so (B, HQ, R, C) is contiguously
@@ -523,6 +565,8 @@ def paged_attention_template(
         hbm,
     ]
     operands = [q, k_pages, v_pages]
+    if v_lanes:
+        in_specs, operands = in_specs[:2], operands[:2]
     if quantized:
         # The scale rows are 1/C of the pages' bytes in (H, page_size)
         # pieces, narrower than any copy the chip makes: XLA gathers each
@@ -556,6 +600,8 @@ def paged_attention_template(
         pltpu.VMEM((2, H, tokens, C), k_pages.dtype),
         pltpu.VMEM((2, H, tokens, Cv), v_pages.dtype),
     ]
+    if v_lanes:
+        scratch.pop()  # the values are read out of the K buffers
 
     if split_k > 1:
         # Partition axis folded into the slot axis: 4-D partial buffers
@@ -589,6 +635,7 @@ def paged_attention_template(
             _tpl_kernel, scale=scale, page_size=page_size, n_rows=R,
             split_k=split_k, pages_per_split=pps, n=n, quantized=quantized,
             sliding_window=sliding_window, attn_sinks=attn_sinks,
+            **(dict(v_lanes=v_lanes, one_count=R_full == 1) if v_lanes else {}),
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,

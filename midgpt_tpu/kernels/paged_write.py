@@ -24,6 +24,10 @@ page keep the output block resident — Pallas fetches and writes back a
 block only when its index changes — so the first step of a run patches the
 fetched page and the later ones patch the resident output block; the
 `first` flags (computed by XLA from the page indices) say which.
+
+A pool that is ONE array (a latent cache: a token's row is stored once and V
+is a view of it, models/pangu_ultra.py) is written by the same call with
+`v_pool` and `v_val` None: one value block, one page block, one alias.
 """
 
 from __future__ import annotations
@@ -68,10 +72,15 @@ def _write_kernel(
     first_ref,  # (N,) int32: 1 where row n's page differs from row n-1's
     *refs,  # vals, pools (, scale vals, scale pools); then the outputs
     quantized: bool,
+    single: bool = False,  # one pool: vals, pool; then the output
 ):
     del layer_ref, page_ref
     n = pl.program_id(0)
     first, off = first_ref[n], off_ref[n]
+    if single:
+        kv_ref, kp_ref, out_ref = refs
+        _patch(first, off, kp_ref, out_ref, kv_ref, 2)
+        return
     n_in = 8 if quantized else 4
     ins, outs = refs[:n_in], refs[n_in:]
     kv_ref, vv_ref, kp_ref, vp_ref = ins[:4]
@@ -94,12 +103,12 @@ def _write_kernel(
 @jax.jit
 def paged_write_kernel(
     k_pool: Array,  # (L, H, P, ps, C)
-    v_pool: Array,
+    v_pool: tp.Optional[Array],  # None: the pool is one array (module docstring)
     layer: Array,  # () int
     write_pages: Array,  # (N,) int32; >= P (or < 0) drops the row
     offs: Array,  # (N,) int32
     k_val: Array,  # (N, H, C): the pool's dtype AND lanes (zero-padded)
-    v_val: Array,
+    v_val: tp.Optional[Array],
     k_scale: tp.Optional[Array] = None,  # (L, P, H, ps) f32
     v_scale: tp.Optional[Array] = None,
     k_sval: tp.Optional[Array] = None,  # (N, H) f32
@@ -110,7 +119,8 @@ def paged_write_kernel(
     write_pages[n], :, offs[n]] = sval[n]`), in place. Returns the pools
     (and scale buffers) — the same buffers when the caller donates them."""
     L, H, P, ps, C = k_pool.shape
-    Cv = v_pool.shape[-1]  # V's lanes where K and V differ in head width
+    single = v_pool is None
+    Cv = C if single else v_pool.shape[-1]  # V's lanes where K and V differ in head width
     N = write_pages.shape[0]
     quantized = k_scale is not None
     write_pages = write_pages.astype(jnp.int32)
@@ -136,16 +146,22 @@ def paged_write_kernel(
         v_page_spec = pl.BlockSpec(
             (None, H, 1, ps, Cv), lambda n, ly, pg, of, fi: (ly[0], 0, pg[n], 0, 0)
         )
-    in_specs = [val_spec, v_val_spec, page_spec, v_page_spec]
-    out_specs = [page_spec, v_page_spec]
-    operands = [k_val[:, :, None, None, :], v_val[:, :, None, None, :], k_pool, v_pool]
-    out_shape = [
-        jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-        jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
-    ]
     n_prefetch = 4
-    # operand index (prefetch operands included) -> output index
-    aliases = {n_prefetch + 2: 0, n_prefetch + 3: 1}
+    if single:
+        in_specs, out_specs = [val_spec, page_spec], [page_spec]
+        operands = [k_val[:, :, None, None, :], k_pool]
+        out_shape = [jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype)]
+        aliases = {n_prefetch + 1: 0}
+    else:
+        in_specs = [val_spec, v_val_spec, page_spec, v_page_spec]
+        out_specs = [page_spec, v_page_spec]
+        operands = [k_val[:, :, None, None, :], v_val[:, :, None, None, :], k_pool, v_pool]
+        out_shape = [
+            jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+            jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
+        ]
+        # operand index (prefetch operands included) -> output index
+        aliases = {n_prefetch + 2: 0, n_prefetch + 3: 1}
     if quantized:
         sval_spec = pl.BlockSpec(
             (None, H, 1), lambda n, ly, pg, of, fi: (n, 0, 0)
@@ -167,7 +183,7 @@ def paged_write_kernel(
     # the write must not carry that name (PERF.md §7).
     with jax.named_scope("kv_write"):
         out = pl.pallas_call(
-            functools.partial(_write_kernel, quantized=quantized),
+            functools.partial(_write_kernel, quantized=quantized, **({"single": True} if single else {})),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=n_prefetch,
                 grid=(N,),
@@ -186,6 +202,8 @@ def paged_write_kernel(
         )
     if quantized:
         return tuple(out)
+    if single:
+        return out[0], None, None, None
     return out[0], out[1], None, None
 
 

@@ -4,7 +4,7 @@ serving engine and the entry points reach a model ONLY through what is listed
 here, and call each without asking whether it is there. Families are
 registered in `midgpt_tpu/config.py` `MODEL_FAMILIES`.
 
-The config (`GPTConfig`, `KimiLinearConfig`, `MimoV2Config`):
+The config (`GPTConfig`, `KimiLinearConfig`, `MimoV2Config`, `PanguUltraConfig`):
 
     block_size, vocab_size, n_layer, n_head, n_embd   fields, under these names
     model()                    -> the namespace below
@@ -17,7 +17,7 @@ The config (`GPTConfig`, `KimiLinearConfig`, `MimoV2Config`):
                                stack (sample.py, ServeEngine) holds no cache
                                for this family; returns None where it does
 
-The namespace (`GPT`, `KimiLinear`, `MimoV2`):
+The namespace (`GPT`, `KimiLinear`, `MimoV2`, `PanguUltra`):
 
     init(config, key) -> params
     hidden(config, params, tokens, *, key, inference, attn_fn) -> (B, T, D)
@@ -36,7 +36,8 @@ The namespace (`GPT`, `KimiLinear`, `MimoV2`):
                                counters the train loop logs at a logged step
 
 The SERVING members, of every family whose `check_serving` returns None
-(`GPT`, `MimoV2`; sampling/serve.py calls them, never a family by name):
+(`GPT`, `MimoV2`, `PanguUltra`; sampling/serve.py calls them, never a family
+by name):
 
     cache_kinds(config) -> (CacheKind(name, window, sinks), ...)
                                the kinds of paged cache the layers need, the
@@ -46,11 +47,21 @@ The SERVING members, of every family whose `check_serving` returns None
                                window has passed it (0: it lives as long as
                                its request); `sinks`: leading tokens never
                                freed. The GPT: one kind. MimoV2: `global`
-                               (window 0) and `window`.
+                               (window 0) and `window`. PanguUltra: one kind,
+                               `latent`, whose pool row is not K beside V of
+                               (heads, head_dim) but a token's LATENT, stored
+                               once (below).
     init_cache(config, num_pages, page_size, dtype, kernel_layout) -> cache
                                `num_pages[i]` pages for kind i; the cache is a
                                pytree with `pool_arrays()` (its page pools, for
-                               the layout census), `page_size`, `num_pages`
+                               the layout census), `page_size`, `num_pages`.
+                               A kind's pool is as many arrays as the family
+                               needs: K and V pools (the GPT, MimoV2: two a
+                               kind), or ONE array where a row is a latent
+                               (PanguUltra: (layers, 1, pages, page_size,
+                               kv_lora_rank + rope), 640 lanes on the kernel
+                               path; K is the row, V a VIEW of its leading
+                               kv_lora_rank lanes, so nothing is stored twice)
     prefill_batched            True: `prefill_paged_chunk` takes the chunks of
                                B slots as the rows of one batch; False: one
                                row a call (MimoV2: two tables and a window
@@ -77,13 +88,19 @@ The SERVING members, of every family whose `check_serving` returns None
     verify_step_paged          the speculative verify step with decode's
                                arguments over (B, k + 1) tokens, or None where
                                the family has none (the engine then refuses a
-                               draft model)
+                               draft model). MimoV2: none over two kinds.
+                               PanguUltra: none, because its published drafter
+                               is a next-token-prediction layer that reads the
+                               target's last hidden state, which is left out,
+                               and the engine's draft model is a GPT
     kernel_sweep(config, cache) -> (pool shape, q rows a pool head, window,
                                sinks) of the decode kernel's sweep, for the
                                engine's block counters
     serve_counters             None, or (config, cache) -> {counter: number}
                                the family's own counters kept in the cache
-                               (MimoV2: the expert layers'), read on demand
+                               (MimoV2, PanguUltra: the expert layers', through
+                               ops/moe.py's shared helpers; PanguUltra also the
+                               pool's bytes a token), read on demand
 """
 
 from midgpt_tpu.models.gpt import GPT, GPTConfig, GPTParams

@@ -518,8 +518,22 @@ def _paged_write(
         positions) drop BOTH writes via XLA oob-scatter semantics.
 
     Both store the same values at the same positions, bit for bit
-    (tests/test_paged_write.py)."""
+    (tests/test_paged_write.py).
+
+    A pool that is ONE array (`pools[1]` and `v` None: a latent cache, whose
+    row is stored once and whose V is a view of it) takes the same two
+    lowerings with one tensor; no int8 form of it is wired."""
     ck, cv, cks, cvs = pools
+    if cv is None:
+        k = k.astype(ck.dtype)
+        if impl == "kernel":
+            from midgpt_tpu.kernels.paged_write import paged_write
+
+            if ck.shape[-1] != k.shape[-1]:
+                k = jnp.pad(k, [(0, 0)] * (k.ndim - 1) + [(0, ck.shape[-1] - k.shape[-1])])
+            return paged_write(ck, None, i, write_pages.reshape(-1), offs.reshape(-1),
+                               k.reshape(-1, *k.shape[offs.ndim:]), None, mesh=mesh)
+        return ck.at[i, :, write_pages, offs, :].set(k), None, None, None
     quantized = cks is not None
     if quantized:
         k, ks = quantize_q8(k)  # (..., H, C) int8, (..., H) f32

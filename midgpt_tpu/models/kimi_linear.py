@@ -144,10 +144,11 @@ class KimiLinearConfig:
 
     def check_serving(self, who: str) -> None:
         raise NotImplementedError(
-            f"{who} cannot serve a {FAMILY} checkpoint yet: the serving stack holds paged K/V pools, of "
-            "several kinds side by side (models/mimo_v2.py), and this model needs a recurrent KDA state per "
-            "slot beside a latent (MLA) pool, and kernels/attention_template.py has no absorbed-latent "
-            "decode path. Train it with launch.py; serving is listed in ROADMAP.md Queue 2."
+            f"{who} cannot serve a {FAMILY} checkpoint yet: the serving stack holds paged pools, of several "
+            "kinds side by side (models/mimo_v2.py), a latent (MLA) pool and its absorbed decode among them "
+            "(models/pangu_ultra.py), and this model needs a recurrent KDA state per slot beside the latent "
+            "pool: a STATE kind of cache, a one-token and a state-carrying chunked KDA step. Train it with "
+            "launch.py; serving is listed in ROADMAP.md Queue 2."
         )
 
     def check_training(self, who: str) -> None:

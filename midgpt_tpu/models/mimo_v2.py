@@ -70,7 +70,9 @@ import jax.numpy as jnp
 
 from midgpt_tpu.models.gpt import CacheKind, _paged_write, pool_lanes
 from midgpt_tpu.ops.attention import visible_mask
-from midgpt_tpu.ops.moe import moe_experts_serving, moe_serving_tile, route, swiglu
+from midgpt_tpu.ops.moe import (
+    moe_count_decode, moe_count_dropped, moe_counters_init, moe_serve_counters, moe_serving, swiglu,
+)
 from midgpt_tpu.ops.norms import rms_norm
 from midgpt_tpu.ops.online_softmax import M_INIT, MASK, finalize, online_block
 from midgpt_tpu.ops.rope import apply_rope_leading, rope_table
@@ -435,12 +437,8 @@ class MimoV2:
     @staticmethod
     def _moe(c: MimoV2Config, p: MoEParams, x: Array) -> tp.Tuple[Array, Array, tp.Dict[str, Array]]:
         """x (N, D) -> (the held experts' part of the layer (N, D), idx (N, k), stats)."""
-        with jax.named_scope("moe_route"):
-            idx, w = route(x, p.router, p.router_bias, top_k=c.moe_top_k,
-                           scale=c.routed_scaling_factor, renormalize=c.moe_renormalize)
-        y, stats = moe_experts_serving(x, idx, w, p.w_gate, p.w_up, p.w_down, offset=c.expert_offset,
-                                       tile=moe_serving_tile(x.shape[0], c.moe_top_k, c.n_experts))
-        return y, idx, stats
+        return moe_serving(x, p.router, p.router_bias, p.w_gate, p.w_up, p.w_down, top_k=c.moe_top_k,
+                           scale=c.routed_scaling_factor, renormalize=c.moe_renormalize, offset=c.expert_offset)
 
     @staticmethod
     def _ffn(c: MimoV2Config, i: int, p: LayerParams, x: Array):
@@ -524,9 +522,8 @@ class MimoV2:
 
         gk, gv = pools(GLOBAL, num_pages[0])
         wk, wv = pools(WINDOW, num_pages[1])
-        return MimoKVCache(gk=gk, gv=gv, wk=wk, wv=wv,
-                           moe_counts=jnp.zeros((len(c.moe_layers), c.n_experts_held), jnp.int32),
-                           moe_totals=jnp.zeros((3,), jnp.int32))
+        moe_counts, moe_totals = moe_counters_init(len(c.moe_layers), c.n_experts_held)
+        return MimoKVCache(gk=gk, gv=gv, wk=wk, wv=wv, moe_counts=moe_counts, moe_totals=moe_totals)
 
     @staticmethod
     def kernel_sweep(config: MimoV2Config, cache: MimoKVCache) -> tp.Tuple[tp.Tuple[int, ...], int, int, int]:
@@ -536,19 +533,8 @@ class MimoV2:
 
     @staticmethod
     def serve_counters(config: MimoV2Config, cache: MimoKVCache) -> tp.Dict[str, float]:
-        """The expert layers' counters since the cache was made (a device
-        read: not for the serving loop). Decode steps of active slots only."""
-        counts = jax.device_get(cache.moe_counts).astype(float)
-        steps, touched, dropped = (int(v) for v in jax.device_get(cache.moe_totals))
-        n_moe = max(1, counts.shape[0])
-        load = counts.max(axis=-1) / counts.mean(axis=-1).clip(1e-9) if counts.size else counts.sum(axis=-1)
-        return {
-            "moe.decode_steps": steps,
-            "moe.pairs_here": counts.sum() / max(1, steps) / n_moe,  # a decode step a layer
-            "moe.experts_touched": touched / max(1, steps) / n_moe,  # held experts with a pair, a step a layer
-            "moe.load_max_over_mean": float(load.max()) if load.size else 0.0,  # worst layer, over the run
-            "moe.dropped": dropped,
-        }
+        """The expert layers' counters (ops/moe.py `moe_serve_counters`)."""
+        return moe_serve_counters(cache.moe_counts, cache.moe_totals)
 
     @staticmethod
     def _paged_attention(c: MimoV2Config, kind: str, p: AttnParams, q: Array, k_pool: Array, v_pool: Array,
@@ -628,12 +614,8 @@ class MimoV2:
                 x = x + jnp.einsum("bte,de->btd", o.astype(x.dtype), p.attn.wo)
             x, idx, stats = MimoV2._ffn(c, i, p, x)
             if idx is not None:
-                local = idx - c.expert_offset  # (B, k); the active slots' pairs, by held expert
-                here = jnp.sum((local[..., None] == jnp.arange(c.n_experts_held)) & active[:, None, None],
-                               axis=(0, 1), dtype=jnp.int32)
-                moe_counts = moe_counts.at[n_moe].add(here)
-                totals = totals + jnp.stack([jnp.zeros((), jnp.int32), jnp.sum(here > 0, dtype=jnp.int32),
-                                             stats["dropped"].astype(jnp.int32)])
+                moe_counts, totals = moe_count_decode(moe_counts, totals, n_moe, idx, active, stats["dropped"],
+                                                      offset=c.expert_offset)
                 n_moe += 1
         totals = totals.at[0].add(1)
         logits = MimoV2._head(c, params, x)[:, 0]
@@ -692,8 +674,7 @@ class MimoV2:
                 x = x + jnp.einsum("bte,de->btd", o.astype(x.dtype), p.attn.wo)
             x, idx, stats = MimoV2._ffn(c, i, p, x)
             if idx is not None:
-                z = jnp.zeros((), jnp.int32)
-                totals = totals + jnp.stack([z, z, stats["dropped"].astype(jnp.int32)])
+                totals = moe_count_dropped(totals, stats["dropped"])
         last = jax.lax.dynamic_slice_in_dim(x, jnp.maximum(n_valid - 1, 0), 1, axis=1)  # (1, 1, D)
         logits = MimoV2._head(c, params, last)
         return logits, MimoKVCache.of(pools, cache.moe_counts, totals)

@@ -226,3 +226,68 @@ def moe_experts_serving(
     y, computed = jax.lax.fori_loop(
         0, ends[-1], one_tile, (jnp.zeros((N + 1, D), jnp.float32), jnp.zeros((), counts.dtype)))
     return y[:N].astype(x.dtype), {"counts": counts, "dropped": jnp.sum(counts) - computed}
+
+
+# ---------------------------------------------------------------------------
+# What every SERVED family with routed experts shares (models/mimo_v2.py,
+# models/pangu_ultra.py): the serving call and the expert layers' counters,
+# which ride the family's cache as two small device arrays.
+# ---------------------------------------------------------------------------
+
+
+def moe_serving(
+    x: Array, router: Array, bias: Array, w_gate: Array, w_up: Array, w_down: Array,
+    *, top_k: int, scale: float, renormalize: bool, offset: int,
+) -> tp.Tuple[Array, Array, tp.Dict[str, Array]]:
+    """x (N, D) -> (the held experts' part of the routed layer (N, D), idx (N,
+    k), stats): `route` under the `moe_route` scope, then `moe_experts_serving`
+    at the tile the row count gives. The router keeps its published width
+    (`router`'s leading axis) whatever is held here."""
+    with jax.named_scope("moe_route"):
+        idx, w = route(x, router, bias, top_k=top_k, scale=scale, renormalize=renormalize)
+    y, stats = moe_experts_serving(x, idx, w, w_gate, w_up, w_down, offset=offset,
+                                   tile=moe_serving_tile(x.shape[0], top_k, router.shape[0]))
+    return y, idx, stats
+
+
+def moe_counters_init(n_moe_layers: int, n_held: int) -> tp.Tuple[Array, Array]:
+    """(counts (moe layers, n_held) int32: pairs of active slots' decode steps;
+    totals (3,) int32: decode steps, held experts touched (summed over steps and
+    layers), dropped), zeroed."""
+    return jnp.zeros((n_moe_layers, n_held), jnp.int32), jnp.zeros((3,), jnp.int32)
+
+
+def moe_count_decode(
+    counts: Array, totals: Array, layer: int, idx: Array, active: Array, dropped: Array, *, offset: int,
+) -> tp.Tuple[Array, Array]:
+    """One routed layer of one decode step into the counters: `idx` (B, k) of
+    the step's slots, of which only the `active` ones count."""
+    local = idx - offset  # (B, k); the active slots' pairs, by held expert
+    here = jnp.sum((local[..., None] == jnp.arange(counts.shape[1])) & active[:, None, None],
+                   axis=(0, 1), dtype=jnp.int32)
+    counts = counts.at[layer].add(here)
+    totals = totals + jnp.stack([jnp.zeros((), jnp.int32), jnp.sum(here > 0, dtype=jnp.int32),
+                                 dropped.astype(jnp.int32)])
+    return counts, totals
+
+
+def moe_count_dropped(totals: Array, dropped: Array) -> Array:
+    """A prefill chunk's routed layer: only what it dropped is counted."""
+    z = jnp.zeros((), jnp.int32)
+    return totals + jnp.stack([z, z, dropped.astype(jnp.int32)])
+
+
+def moe_serve_counters(counts: Array, totals: Array) -> tp.Dict[str, float]:
+    """The expert layers' counters since the cache was made (a device read:
+    not for the serving loop). Decode steps of active slots only."""
+    counts = jax.device_get(counts).astype(float)
+    steps, touched, dropped = (int(v) for v in jax.device_get(totals))
+    n_moe = max(1, counts.shape[0])
+    load = counts.max(axis=-1) / counts.mean(axis=-1).clip(1e-9) if counts.size else counts.sum(axis=-1)
+    return {
+        "moe.decode_steps": steps,
+        "moe.pairs_here": counts.sum() / max(1, steps) / n_moe,  # a decode step a layer
+        "moe.experts_touched": touched / max(1, steps) / n_moe,  # held experts with a pair, a step a layer
+        "moe.load_max_over_mean": float(load.max()) if load.size else 0.0,  # worst layer, over the run
+        "moe.dropped": dropped,
+    }

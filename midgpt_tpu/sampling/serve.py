@@ -191,6 +191,7 @@ class _PoolProgram:
         self._called: tp.Set[str] = set()  # labels a call was made under
         self._kernel_path: tp.Set[str] = set()
         self._relayouts: tp.Dict[str, int] = {}
+        self._texts: tp.Dict[str, str] = {}  # label -> optimized text, once `texts` has read it
 
     def __call__(self, *args, **kwargs):
         bound = self._sig.bind(*args, **kwargs)
@@ -251,11 +252,13 @@ class _PoolProgram:
         reader that joins a traced op to the scope that opened it (the v5e
         trace names an op by its instruction and carries no scope path). As
         `pool_relayouts`: lowering the recorded arguments again finds the
-        executable the call compiled."""
-        return {
-            label: self.jit.lower(*args, **kwargs).compile().as_text()
-            for label, (args, kwargs) in self._compiled.items()
-        }
+        executable the call compiled. Each program's text is read once:
+        several readers of one traced run ask (a family's scopes, then its
+        kernels), and a lowering is seconds."""
+        for label, (args, kwargs) in self._compiled.items():
+            if label not in self._texts:
+                self._texts[label] = self.jit.lower(*args, **kwargs).compile().as_text()
+        return dict(self._texts)
 
 
 def _abstract(a):
@@ -1707,9 +1710,10 @@ class ServeEngine:
     def serve_counters(self) -> tp.Dict[str, float]:
         """Counters of the cache by kind and of the family's own layers
         (docs/OBSERVABILITY.md), whether or not an Observability is wired:
-        `kv.<kind>_pages_live` (allocated now; `_pages_live_max`: at the peak),
-        `kv.<kind>_pages_reclaimed`
-        (freed by the window rule so far) and, for a windowed kind,
+        `kv.<kind>_pages_live` (allocated now; `_pages_live_max`: at the peak)
+        and, for a WINDOWED kind only (a kind without a window has no rule that
+        frees a page mid-request, so it has no such counter),
+        `kv.<kind>_pages_reclaimed` (freed by the window rule so far) and
         `kv.<kind>_tokens_per_slot_max` (the most one slot ever held, in
         tokens: bounded by window + the longest write + a page); then what the
         family's `serve_counters` reads off its cache (a device read: call it
@@ -1718,8 +1722,8 @@ class ServeEngine:
         for i, (k, a) in enumerate(zip(self.kinds, self.allocators)):
             out[f"kv.{k.name}_pages_live"] = a.num_pages - 1 - a.free_count
             out[f"kv.{k.name}_pages_live_max"] = a.num_pages - 1 - a.free_min
-            out[f"kv.{k.name}_pages_reclaimed"] = self.kind_reclaimed[i]
             if k.window:
+                out[f"kv.{k.name}_pages_reclaimed"] = self.kind_reclaimed[i]
                 out[f"kv.{k.name}_tokens_per_slot_max"] = (
                     self.kind_slot_pages_max[i] * self.page_size
                 )

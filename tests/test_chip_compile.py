@@ -487,3 +487,43 @@ def test_two_kind_serving_program_never_relays_out_either_pool(program, one_chip
     text = lowered.compile().as_text()
     assert text.count("tpu_custom_call") == (4 if program == "decode8" else 3)  # 3 writes (+ the global layer's attention)
     assert pool_relayouts(text, [a.shape for a in cache.pool_arrays()]) == 0
+
+
+@pytest.mark.parametrize("program", ["decode8", "prefill512"])
+def test_latent_serving_program_compiles_and_never_relays_out_the_pool(program, one_chip, compiled_kernels):
+    """models/pangu_ultra.py at its published widths (hidden 7,680, 128 heads,
+    q_lora 1,536, kv_lora 512 + rope 64; one dense and one expert layer, two
+    held experts): the decode program (the ABSORBED attention through the
+    template with `v_lanes`: 128 query rows against the pool's one head of 640
+    lanes, split-K 8 at a 1,024-page table) and the prefill program (XLA over
+    blocks of cached latents) compile for the v5e with NO copy as large as the
+    latent pool or one layer of it, and every layer's write is the in-place
+    Pallas one over the ONE pool array."""
+    import dataclasses
+
+    from midgpt_tpu.analysis.hlo_audit import pool_relayouts
+    from midgpt_tpu.config import load_config
+    from midgpt_tpu.sampling import serve
+
+    mc = dataclasses.replace(load_config("openpangu_ultra_moe").model_config, n_layer=2, first_k_dense=1,
+                             n_experts_held=2, vocab_size=512)
+    model = mc.model()
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(sds, jax.eval_shape(lambda k: model.cast_params(model.init(mc, k), jnp.bfloat16), jax.random.PRNGKey(0)))
+    cache = jax.tree.map(sds, jax.eval_shape(lambda: model.init_cache(mc, (8449,), 32, jnp.bfloat16, kernel_layout=True)))
+    (pool,) = cache.pool_arrays()
+    assert pool.shape == (2, 1, 8449, 32, 640)  # 576 values at whole 128-lane rows, stored once
+    assert pool.dtype.itemsize * pool.shape[-1] == 1280  # bytes a token a layer
+    arr = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    B, T = 16, 1024
+    if program == "decode8":
+        lowered = serve._serve_decode_chunk.lower(
+            mc, params, arr((B,)), cache, arr((B, T)), arr((B,)), arr((B,), jnp.bool_), 8,
+            0.8, None, None, "kernel", arr((2,), jnp.uint32), None, 8)
+    else:
+        lowered = serve._serve_prefill_chunk.lower(
+            mc, params, arr((1, 512)), arr(()), arr(()), cache, arr((1, T)), None, "kernel",
+            0.8, None, None, arr((2,), jnp.uint32))
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") == (4 if program == "decode8" else 2)  # a write a layer (+ decode's attention)
+    assert pool_relayouts(text, [pool.shape]) == 0

@@ -320,3 +320,62 @@ def test_gpt_serving_programs_lower_to_the_parents_text(name):
             low = serve._spec_verify_chunk.lower(cfg, params, arr((B,)), arr((4, B)), arr((4, B, cfg.vocab_size), jnp.float32),
                                                  cache, table, lengths, active, 0.8, None, None, impl, key)
     assert hashlib.sha256(low.as_text().encode()).hexdigest() == GPT_PROGRAM_HASHES[name]
+
+
+# sha256 taken on the parent of PR 39 (commit c9c92be), which gave `kernels/attention_template.py` a V that is a view
+# of K's lanes and `kernels/paged_write.py` a pool of one array, and moved MimoV2's serving MoE call and expert
+# counters into `ops/moe.py`: MimoV2's serving programs (published widths, toy depth, the gather lowering, StableHLO
+# text) and the traced kernels (the jaxpr of the wrapper and of the pallas_call's body, interpret mode) at the GPT's
+# and MimoV2's shapes are the parent's, byte for byte.
+SERVING_HASHES_PR37 = json.load(open(os.path.join(os.path.dirname(__file__), "golden", "serving_programs_pr37.json")))
+
+
+def _program_text(name):
+    from midgpt_tpu.config import load_config
+    from midgpt_tpu.kernels.attention_template import paged_attention_template
+    from midgpt_tpu.kernels.paged_write import paged_write_kernel
+    from midgpt_tpu.sampling import serve
+
+    arr = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(shape, dtype)
+    bf16, f32, i8 = jnp.bfloat16, jnp.float32, jnp.int8
+    pool = lambda hkv, lanes, dt=bf16: arr((2, hkv, 65, 8, lanes), dt)
+    scales = lambda h: arr((2, 65, h, 8), f32)
+    kind, what = name.split(".", 1)
+    if kind == "mimo_v2_5":
+        mc = dataclasses.replace(load_config("mimo_v2_5").model_config, n_layer=3, n_experts_held=2, vocab_size=512)
+        m = mc.model()
+        sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+        params = jax.tree.map(sds, jax.eval_shape(lambda k: m.cast_params(m.init(mc, k), bf16), jax.random.PRNGKey(0)))
+        cache = jax.tree.map(sds, jax.eval_shape(lambda: m.init_cache(mc, (257, 97), 32, bf16)))
+        B, T, key = 8, 16, arr((2,), jnp.uint32)
+        if what == "decode8.gather":
+            return serve._serve_decode_chunk.lower(mc, params, arr((B,)), cache, (arr((B, T)), arr((B, T))), arr((B,)),
+                                                   arr((B,), jnp.bool_), 8, 0.8, None, None, "gather", key, None, 2).as_text()
+        return serve._serve_prefill_chunk.lower(mc, params, arr((1, 64)), arr(()), arr(()), cache, (arr((1, T)), arr((1, T))),
+                                                None, "gather", 0.8, None, None, key).as_text()
+    if kind == "template":
+        q, kp, vp, rows, ks, kw = {
+            "gpt_h16c128_decode_split2": (arr((4, 16, 1, 128), bf16), pool(16, 128), pool(16, 128), 1, None, dict(split_k=2)),
+            "gpt_h12c64_verify5": (arr((4, 12, 5, 64), bf16), pool(12, 128), pool(12, 128), 5, None, {}),
+            "gpt_h12c64_int8": (arr((4, 12, 1, 64), bf16), pool(12, 128, i8), pool(12, 128, i8), 1, scales(12), {}),
+            "gpt_gqa4_window_sinks": (arr((4, 16, 1, 128), bf16), pool(4, 128), pool(4, 128), 1, None,
+                                      dict(sliding_window=32, attn_sinks=4)),
+            "mimo_k192_v128_split2": (arr((4, 64, 1, 192), bf16), pool(4, 256), pool(4, 128), 1, None, dict(split_k=2, v_dim=128)),
+        }[what]
+        f = lambda *a: paged_attention_template(*a, layer=jnp.int32(1), **kw)
+        return str(jax.make_jaxpr(f)(q, kp, vp, arr((4, 16)), arr((4, rows)), ks, ks))
+    kp, vp, ks = {"gpt_h16c128": (pool(16, 128), pool(16, 128), None),
+                  "gpt_h12c64_int8": (pool(12, 128, i8), pool(12, 128, i8), scales(12)),
+                  "mimo_k256_v128": (pool(4, 256), pool(4, 128), None)}[what]
+    H, n = kp.shape[1], 6
+    a = [kp, vp, arr(()), arr((n,)), arr((n,)), arr((n, H, kp.shape[-1]), kp.dtype), arr((n, H, vp.shape[-1]), vp.dtype)]
+    if ks is not None:
+        a += [ks, ks, arr((n, H), f32), arr((n, H), f32)]
+    return str(jax.make_jaxpr(paged_write_kernel)(*a))
+
+
+@pytest.mark.parametrize("name", sorted(SERVING_HASHES_PR37))
+def test_mimo_programs_and_the_shared_kernels_trace_to_the_parents_text(name):
+    with jax.default_matmul_precision("default"):
+        text = _program_text(name)
+    assert hashlib.sha256(text.encode()).hexdigest() == SERVING_HASHES_PR37[name]

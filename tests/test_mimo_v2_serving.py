@@ -61,7 +61,7 @@ def test_engine_serves_a_mixed_queue_like_the_model_path(model, overlap):
     counters = eng.serve_counters()
     burst = max(eng.prefill_chunk, eng.decode_chunk * eng.round_group)
     assert 0 < counters["kv.window_tokens_per_slot_max"] <= c.sliding_window + burst + eng.page_size
-    assert counters["kv.global_pages_reclaimed"] == 0 and counters["kv.window_pages_reclaimed"] > 0
+    assert "kv.global_pages_reclaimed" not in counters and counters["kv.window_pages_reclaimed"] > 0
     assert counters["kv.global_pages_live"] == counters["kv.window_pages_live"] == 0
     assert counters["moe.dropped"] == 0 and counters["moe.decode_steps"] > 0
     assert [a.free_count for a in eng.allocators] == [a.num_pages - 1 for a in eng.allocators]
@@ -223,7 +223,7 @@ def test_engine_conserves_both_pools_through_evict_and_cancel(model):
     assert eng.preemptions > 0 and eng.finished[uids[2]].status == "cancelled"
     for uid, (p, m) in list(zip(uids, work))[:2]:
         np.testing.assert_array_equal(eng.finished[uid].tokens, _greedy(c, params, _tokens(p, seed=p), m))
-    assert eng.serve_counters()["kv.global_pages_reclaimed"] == 0
+    assert "kv.global_pages_reclaimed" not in eng.serve_counters()  # a kind without a window has no reclaim counter
 
 
 def test_window_pool_is_sized_from_window_chunk_and_page(model):
@@ -305,7 +305,7 @@ def test_the_benchmark_share_counts_what_the_issue_reckoned():
 def test_kimi_linear_says_what_serving_it_still_lacks():
     from midgpt_tpu.config import load_config
 
-    with pytest.raises(NotImplementedError, match="recurrent KDA state.*absorbed-latent"):
+    with pytest.raises(NotImplementedError, match="recurrent KDA state.*STATE kind"):
         load_config("kimi_linear_48b_a3b").model_config.check_serving("sample.py")
 
 
