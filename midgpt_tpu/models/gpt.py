@@ -65,9 +65,12 @@ class GPTConfig:
     # head sharding and attention runs dense); the runtime injects the
     # mesh-bound implementation via the attn_fn hook on GPT.hidden.
     attn_impl: str = "naive"  # 'naive' | 'blockwise' | 'flash' | 'ring' | 'ulysses'
-    # Tile size for the blockwise/flash/ring/ulysses paths. 1024 is one KV
-    # step at T=1024 (the flash kernel's single-step specialization) and the
-    # ring's per-pair tile; `train_124m` (ledger) runs it, 512 has no cell.
+    # KV block of the blockwise/flash/ring/ulysses paths. At T <= this (1024
+    # holds every GPT training config's sequence in one block) the flash
+    # kernels are the tiled ones, whose score tile ops/attention.py
+    # flash_block_sizes derives (256) and this field does not set; at T >
+    # this it is the KV block of the multi-block kernels, and the ring's
+    # per-pair tile. `train_124m` and `train_xl_fsdp4` (ledger) run 1024.
     attn_block_size: int = 1024
     remat: bool = True  # checkpoint each block inside the layer scan
     # What the per-block checkpoint may keep instead of recomputing in bwd:

@@ -203,12 +203,16 @@ def flash_or_blockwise(impl: str, T: int, block_size: int) -> str:
 
 def flash_block_sizes(T: int, block_size: int) -> tp.Tuple[int, int]:
     """(block_q, block_k) for the flash kernel — the single place the tile
-    policy lives. KV blocks use the largest block the sequence allows;
-    Q tiles prefer 512 (keeps the f32 score tile + scratch inside VMEM,
-    measured fastest on v5e) but fall back to block_k when 512 does not
-    divide T (e.g. T=768)."""
+    policy lives. KV blocks use the largest block the sequence allows.
+    Where one KV block holds the sequence, block_q is the square score tile
+    of the tiled kernels (kernels/flash_attention.py, T <= 1024): 256, which
+    forms 10/16 of the score matrix at T=1024 and on the v5e measured under
+    512 (12/16), forward (-9 %) and backward (-3 %), at head widths 64 and
+    128 alike (PERF.md, PR 40), so the rule reads no width. Over several KV
+    blocks Q tiles are 512 (the f32 score tile + scratch stay inside VMEM).
+    Either falls back to block_k when it does not divide T (e.g. T=384)."""
     bk = min(block_size, T)
-    bq = min(512, bk)
+    bq = min(256 if bk == T else 512, bk)
     if T % bq:
         bq = bk
     return bq, bk

@@ -482,6 +482,11 @@ class TrainRuntime:
     # picked the loss by. Logged by make_runtime; the flight recorder's gauge
     # `fsdp.schedule_authored` is 1 / 0 for it.
     fsdp_schedule: str = "compiler"
+    # Share of the (T, T) causal score matrix the flash kernels form a call
+    # at this model's (T, attn_block_size): 1.0 = all of it, 0.5 the limit
+    # (kernels/flash_attention.py score_tile_share); None where attn_impl is
+    # not 'flash'. Logged by make_runtime; gauge `attn.score_tile_share`.
+    attn_score_tile_share: tp.Optional[float] = None
     # Jitted (params, x (B, T)) -> {counter name: scalar} of the model's own
     # counters (models/kimi_linear.py route_stats: moe.*), or None for a model
     # that has none. Forward only, one microbatch, off the step program.
@@ -532,6 +537,30 @@ class TrainRuntime:
         return make_runtime(config, devices=devices, dataset=self.dataset)
 
 
+def _report_score_tile_share(model_cfg) -> tp.Optional[float]:
+    """How far the flash kernels' causal tile skipping engages at this
+    model's sequence length, said once at start-up and set as the gauge
+    `attn.score_tile_share`; None (and silent) for any other attn_impl."""
+    if model_cfg.attn_impl != "flash":
+        return None
+    import importlib
+
+    from midgpt_tpu.ops.attention import flash_block_sizes
+
+    # by module path: the kernels package re-exports a same-named function
+    fa = importlib.import_module("midgpt_tpu.kernels.flash_attention")
+    T = model_cfg.block_size
+    bq, bk = flash_block_sizes(T, model_cfg.attn_block_size)
+    share = fa.score_tile_share(T, bq, bk)
+    flight_recorder().metrics.gauge("attn.score_tile_share").set(share)
+    if jax.process_index() == 0:
+        print(
+            f"flash attention: T={T} in blocks ({bq}, {bk}) forms {share:.4f} "
+            "of the causal score matrix (score_tile_share; 0.5 is the limit)"
+        )
+    return share
+
+
 def make_runtime(
     config: ExperimentConfig,
     *,
@@ -572,6 +601,7 @@ def make_runtime(
             f"fsdp schedule: {fsdp_schedule} (fsdp_mode={config.fsdp_mode!r}) "
             f"on mesh {dict(mesh.shape)}"
         )
+    score_tile_share = _report_score_tile_share(config.model_config)
     global _LAST_RUNTIME
     abstract_params, abstract_opt = _abstract_like(params), _abstract_like(opt_state)
     model = config.model_config.model()
@@ -607,6 +637,7 @@ def make_runtime(
         finite_check=jax.jit(_all_finite),
         n_params=model.count_params(params),
         fsdp_schedule=fsdp_schedule,
+        attn_score_tile_share=score_tile_share,
         model_stats=model_stats,
         _initial=(params, opt_state),
         step_avals=(

@@ -69,6 +69,24 @@ def _mosaic_calls(fn, *args) -> int:
     return jax.jit(fn).lower(*args).compile().as_text().count("tpu_custom_call")
 
 
+# The flash kernels' Mosaic custom calls as the v5e trace names them: the
+# instruction takes the innermost scope's name, which is what
+# benchmarks/metrics/flash_attention.py finds them by (no name= on the
+# pallas_call, no named_scope opened around it).
+_FLASH_CALL = r"%((?:attn|shard_map)\.\d+) = [^\n]*tpu_custom_call"
+
+
+def _custom_call_names(hlo: str):
+    import re
+
+    named = re.findall(_FLASH_CALL, hlo)
+    assert len(named) == hlo.count('custom_call_target="tpu_custom_call"'), (
+        "a Mosaic custom call of the step is not named attn.<n> / shard_map.<n>"
+    )
+    return named
+
+
+@pytest.mark.parametrize("tile", [256, 512], ids=["t256", "t512"])
 @pytest.mark.parametrize(
     "shape",
     [
@@ -77,14 +95,57 @@ def _mosaic_calls(fn, *args) -> int:
     ],
     ids=["c64", "c128"],
 )
-def test_flash_fwd_bwd_compiles_for_v5e(shape, one_chip, compiled_kernels):
+def test_flash_fwd_bwd_compiles_for_v5e(shape, tile, one_chip, compiled_kernels):
+    """The tiled kernels (one KV block at T=1024) at both benchmark widths,
+    at the tile the policy derives (256) and the other one it was measured
+    against."""
+    from midgpt_tpu.ops.attention import flash_block_sizes
+
+    assert flash_block_sizes(1024, 1024) == (256, 1024)
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
     def loss(q, k, v):
-        return jnp.sum(fa.flash_attention(q, k, v, 512, 1024).astype(jnp.float32))
+        return jnp.sum(fa.flash_attention(q, k, v, tile, 1024).astype(jnp.float32))
 
     # forward kernel + the fused backward kernel
     assert _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) >= 2
+
+
+def test_flash_one_kv_block_past_1024_compiles_for_v5e(one_chip, compiled_kernels):
+    """T=2048 in one KV block is past the tiled kernels (a head's operands
+    whole in VMEM): the multi-block grid kernels serve it with n_k = 1."""
+    x = jax.ShapeDtypeStruct((2, 4, 2048, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, 256, 2048).astype(jnp.float32))
+
+    assert _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) == 3  # fwd, dq, dk/dv
+
+
+def test_124m_train_step_compiles_and_names_its_flash_calls(topo, compiled_kernels, monkeypatch):
+    """`train_124m`'s step program (local_text_124m: 12 heads of 64, T=1024,
+    microbatch 16, layer scan unrolled, remat off) at reduced depth, for one
+    described v5e: the tiled kernels compile inside it, and each custom call
+    is still named by its innermost scope, `attn.<n>` (one forward and one
+    fused backward a layer)."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from midgpt_tpu.config import MeshConfig, load_config
+    from midgpt_tpu.parallel.mesh import AXES
+    from midgpt_tpu.utils.hlo import lower_abstract_train_step
+
+    monkeypatch.setattr(fa, "RUN_INTERPRET_OFF_TPU", True)
+    config = load_config("local_text_124m")
+    config = config.replace(
+        batch_size=16, g_accum_iters=2, spec_layers=0, mesh=MeshConfig(data=1, fsdp=1, sp=1),
+        model_config=dataclasses.replace(config.model_config, n_layer=2, scan_unroll=2),
+    )
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1, 1, 1, 1, 1), axis_names=AXES)
+    names = _custom_call_names(lower_abstract_train_step(config, mesh=mesh).compile().as_text())
+    assert len(names) == 4 and all(n.startswith("attn.") for n in names), names
 
 
 # 124M serving geometry: 12 heads x 64 in a pool of whole 128-lane rows (as
@@ -401,7 +462,11 @@ def test_fsdp_schedule_collective_census_at_xl_widths(schedule, topo, compiled_k
     )
     mesh = Mesh(np.asarray(topo.devices).reshape(1, 4, 1, 1, 1, 1), axis_names=AXES)
     assert config.fsdp_schedule(mesh.shape) == schedule
-    census = collective_census(lower_abstract_train_step(config, mesh=mesh).compile().as_text())
+    hlo = lower_abstract_train_step(config, mesh=mesh).compile().as_text()
+    # the tiled flash kernels at C=128 inside the step, under the names the
+    # benchmark's flash metrics find them by
+    assert len(_custom_call_names(hlo)) >= 2
+    census = collective_census(hlo)
     weight_sized = [c for c in census if c[2] >= config.fsdp_min_size]
     summed = {"authored": "reduce-scatter", "compiler": "all-reduce"}[schedule]
     other = {"authored": "all-reduce", "compiler": "reduce-scatter"}[schedule]

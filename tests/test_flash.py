@@ -2,6 +2,7 @@
 and backward — in interpret mode on CPU (compiled on real TPU)."""
 
 import dataclasses
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -123,8 +124,9 @@ def test_dispatch_never_downgrades_a_configured_flash():
 
 
 def test_backward_parity_fused_single_step():
-    """blk_k == T <= 1024 routes backward through the fully-fused dQ/dK/dV
-    kernel (one probability reconstruction) — the hot path at T=1024."""
+    """blk_k == T <= 1024 routes backward through the fully-fused tiled
+    dQ/dK/dV kernel (one probability reconstruction a tile; here two tiles
+    a side) — the hot path at T=1024."""
     q, k, v = make_qkv(jax.random.PRNGKey(5), 1, 2, 128, 32)
 
     def loss_flash(q, k, v):
@@ -142,8 +144,9 @@ def test_backward_parity_fused_single_step():
 
 
 def test_backward_parity_single_kv_long_seq():
-    """blk_k == T > 1024 skips the fused kernel: stateless dq-single +
-    tiled dk/dv kernels (the long-context backward split)."""
+    """blk_k == T > 1024 is past the tiled kernels (a head's operands whole
+    in VMEM): the multi-block grid kernels serve it with one KV step (dq +
+    dk/dv, the long-context backward split)."""
     q, k, v = make_qkv(jax.random.PRNGKey(6), 1, 1, 2048, 8)
 
     def loss_flash(q, k, v):
@@ -175,3 +178,140 @@ def test_default_blocks_fallback_non_divisible_T():
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=3e-5, rtol=3e-5, err_msg=f"d{name}"
         )
+
+
+# ----------------------------------------------------------------------
+# the tiled kernels (one KV block holds the sequence, T <= 1024): square
+# score tiles on and under the diagonal only
+# ----------------------------------------------------------------------
+
+fa = importlib.import_module("midgpt_tpu.kernels.flash_attention")
+
+
+def _unmasked_attention(q, k, v):
+    s = jnp.einsum("bhqc,bhkc->bhqk", q, k).astype(jnp.float32) / np.sqrt(q.shape[-1])
+    return jnp.einsum("bhqk,bhkc->bhqc", jax.nn.softmax(s, axis=-1).astype(q.dtype), v)
+
+
+def _derived_tile(T):
+    from midgpt_tpu.ops.attention import flash_block_sizes
+
+    return flash_block_sizes(T, 1024)[0]
+
+
+# (T, C, tile): the tile the policy derives at every benchmark shape, and
+# small private tiles (n = 2 and n = 4 tiles a side) at small T
+_TILED_CASES = [(T, C, None) for T in (256, 512, 1024) for C in (64, 128)] + [
+    (128, 64, 64), (128, 64, 32), (256, 128, 128), (256, 128, 64),
+]
+
+
+@pytest.mark.parametrize("T,C,tile", _TILED_CASES)
+def test_tiled_forward_and_gradient_parity_f32(T, C, tile):
+    tile = tile or _derived_tile(T)
+    assert fa._tiled(T, T) and T % tile == 0
+    q, k, v = make_qkv(jax.random.PRNGKey(T + C), 1, 1, T, C)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(jnp.sin(attn(q, k, v)))
+
+    flash = lambda q, k, v: flash_attention(q, k, v, tile, T)
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v)), np.asarray(naive_causal_attention(q, k, v)),
+        atol=2e-5, rtol=2e-5,
+    )
+    gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gn = jax.grad(loss(naive_causal_attention), argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(gf, gn, "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5, err_msg=f"d{name}"
+        )
+
+
+@pytest.mark.parametrize("T,tile", [(128, 128), (128, 64), (256, 64)])
+def test_tiled_not_causal_parity_through_the_rings_entry(T, tile):
+    """causal=False (ring attention's off-diagonal pairs, through the
+    private _flash_forward / _flash_backward) walks all n^2 tiles and masks
+    none: parity against unmasked attention, outputs and gradients."""
+    q, k, v = make_qkv(jax.random.PRNGKey(7), 1, 2, T, 32)
+    g = jax.random.normal(jax.random.PRNGKey(8), q.shape, q.dtype)
+    out, lse = fa._flash_forward(q, k, v, tile, T, causal=False)
+    ref, vjp = jax.vjp(_unmasked_attention, q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
+    s = jnp.einsum("bhqc,bhkc->bhqk", q, k) / np.sqrt(q.shape[-1])
+    np.testing.assert_allclose(
+        np.asarray(lse[..., 0]), np.asarray(jax.nn.logsumexp(s, axis=-1)), atol=2e-5, rtol=2e-5
+    )
+    got = fa._flash_backward(tile, T, (q, k, v, out, lse), g, causal=False)
+    for a, b, name in zip(got, vjp(g), "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5, err_msg=f"d{name}"
+        )
+
+
+@pytest.mark.parametrize("T,C,tile", [(256, 64, 64), (512, 128, 256)])
+def test_tiled_parity_bf16(T, C, tile):
+    q, k, v = make_qkv(jax.random.PRNGKey(11), 1, 2, T, C, jnp.bfloat16)
+    f32 = lambda a: np.asarray(a, np.float32)
+    np.testing.assert_allclose(
+        f32(flash_attention(q, k, v, tile, T)), f32(naive_causal_attention(q, k, v)),
+        atol=2e-2, rtol=2e-2,
+    )
+    loss = lambda attn: lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) ** 2)
+    gf = jax.grad(loss(lambda q, k, v: flash_attention(q, k, v, tile, T)), argnums=(0, 1, 2))(q, k, v)
+    gn = jax.grad(loss(naive_causal_attention), argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(gf, gn, "qkv"):
+        np.testing.assert_allclose(f32(a), f32(b), atol=6e-2, rtol=6e-2, err_msg=f"d{name}")
+
+
+def _kernel_dots(fn, *args):
+    """dot_generals in the bodies of the pallas_calls `fn` makes (the traced
+    kernel functions, sub-jaxprs included)."""
+
+    def count(jaxpr, inside):
+        n = 0
+        for eqn in jaxpr.eqns:
+            kernel = inside or eqn.primitive.name == "pallas_call"
+            n += kernel and eqn.primitive.name == "dot_general"
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                n += count(sub, kernel)
+        return n
+
+    return count(jax.make_jaxpr(fn)(*args).jaxpr, False)
+
+
+@pytest.mark.parametrize("tile,share", [(256, 10 / 16), (512, 3 / 4), (1024, 1.0)])
+def test_tiled_kernels_form_only_the_tiles_under_the_diagonal(tile, share):
+    """Fails if the skipping is lost: the counter, and the products in the
+    traced kernel bodies at T = 1024 (2 a formed tile forward, 5 backward)."""
+    T, n = 1024, 1024 // tile
+    assert fa.score_tile_share(T, tile, T, causal=True) == share
+    assert fa.score_tile_share(T, tile, T, causal=False) == 1.0
+    x = jnp.zeros((1, 1, T, 64), jnp.bfloat16)
+    lse = jnp.zeros((1, 1, T, fa._STATS_LANES), jnp.float32)
+    for causal, tiles in ((True, n * (n + 1) // 2), (False, n * n)):
+        assert tiles == round(fa.score_tile_share(T, tile, T, causal) * n * n)
+        fwd = lambda q, k, v: fa._flash_forward(q, k, v, tile, T, causal=causal)
+        bwd = lambda q, k, v, o, g: fa._flash_backward(tile, T, (q, k, v, o, lse), g, causal=causal)
+        assert _kernel_dots(fwd, x, x, x) == 2 * tiles
+        assert _kernel_dots(bwd, x, x, x, x, x) == 5 * tiles
+
+
+def test_score_tile_share_of_the_multi_block_grid_and_the_policy():
+    """Over several KV blocks the share is the blocks the grid's pl.when
+    lets through; the dispatcher's policy gives the tiled kernels tile 256
+    wherever one KV block holds the sequence, and leaves the multi-block
+    blocks (train_kimi_linear_t8k: T=8192, attn_block_size 512) alone."""
+    from midgpt_tpu.ops.attention import flash_block_sizes
+
+    assert flash_block_sizes(1024, 1024) == (256, 1024)
+    assert flash_block_sizes(512, 1024) == (256, 512)
+    assert flash_block_sizes(128, 1024) == (128, 128)
+    assert flash_block_sizes(384, 1024) == (384, 384)
+    assert flash_block_sizes(8192, 512) == (512, 512)
+    assert flash_block_sizes(2048, 1024) == (512, 1024)
+    assert fa.score_tile_share(1024, *flash_block_sizes(1024, 1024)) == 10 / 16
+    assert fa.score_tile_share(8192, 512, 512) == (16 * 17 // 2) / 16**2
+    assert fa.score_tile_share(2048, 512, 1024) == 6 / 8
+    # one KV block past the tiled kernels' reach: the multi-block grid forms all of it
+    assert not fa._tiled(2048, 2048) and fa.score_tile_share(2048, 512, 2048) == 1.0
