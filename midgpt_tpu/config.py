@@ -486,6 +486,7 @@ MODEL_FAMILIES: tp.Dict[str, str] = {
     "kimi_linear": "midgpt_tpu.models.kimi_linear:KimiLinearConfig",
     "mimo_v2": "midgpt_tpu.models.mimo_v2:MimoV2Config",
     "pangu_ultra": "midgpt_tpu.models.pangu_ultra:PanguUltraConfig",
+    "ouro": "midgpt_tpu.models.ouro:OuroConfig",
 }
 
 

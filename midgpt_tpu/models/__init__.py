@@ -4,9 +4,13 @@ serving engine and the entry points reach a model ONLY through what is listed
 here, and call each without asking whether it is there. Families are
 registered in `midgpt_tpu/config.py` `MODEL_FAMILIES`.
 
-The config (`GPTConfig`, `KimiLinearConfig`, `MimoV2Config`, `PanguUltraConfig`):
+The config (`GPTConfig`, `KimiLinearConfig`, `MimoV2Config`, `PanguUltraConfig`,
+`OuroConfig`):
 
     block_size, vocab_size, n_layer, n_head, n_embd   fields, under these names
+                               (`n_layer`: layers of WEIGHTS; how many layers
+                               the paged cache has is the family's to say, in
+                               `init_cache`)
     model()                    -> the namespace below
     check_experiment(config)   raises ValueError for an ExperimentConfig this
                                family cannot run (mesh axes, schedules, knobs)
@@ -17,7 +21,7 @@ The config (`GPTConfig`, `KimiLinearConfig`, `MimoV2Config`, `PanguUltraConfig`)
                                stack (sample.py, ServeEngine) holds no cache
                                for this family; returns None where it does
 
-The namespace (`GPT`, `KimiLinear`, `MimoV2`, `PanguUltra`):
+The namespace (`GPT`, `KimiLinear`, `MimoV2`, `PanguUltra`, `Ouro`):
 
     init(config, key) -> params
     hidden(config, params, tokens, *, key, inference, attn_fn) -> (B, T, D)
@@ -36,8 +40,8 @@ The namespace (`GPT`, `KimiLinear`, `MimoV2`, `PanguUltra`):
                                counters the train loop logs at a logged step
 
 The SERVING members, of every family whose `check_serving` returns None
-(`GPT`, `MimoV2`, `PanguUltra`; sampling/serve.py calls them, never a family
-by name):
+(`GPT`, `MimoV2`, `PanguUltra`, `Ouro`; sampling/serve.py calls them, never a
+family by name):
 
     cache_kinds(config) -> (CacheKind(name, window, sinks), ...)
                                the kinds of paged cache the layers need, the
@@ -50,11 +54,18 @@ by name):
                                (window 0) and `window`. PanguUltra: one kind,
                                `latent`, whose pool row is not K beside V of
                                (heads, head_dim) but a token's LATENT, stored
-                               once (below).
+                               once (below). Ouro: one kind, `looped`.
     init_cache(config, num_pages, page_size, dtype, kernel_layout) -> cache
                                `num_pages[i]` pages for kind i; the cache is a
                                pytree with `pool_arrays()` (its page pools, for
                                the layout census), `page_size`, `num_pages`.
+                               The pools' LAYER axis is the family's, not
+                               `n_layer`: the engine sizes, allocates and
+                               frees PAGES and never reads it. The GPT: one
+                               cache layer a weight layer. Ouro: `n_loop *
+                               n_layer` (each of the n_loop passes over the
+                               same weights keeps keys and values of its own;
+                               row r * n_layer + l), in the GPT pool's layout.
                                A kind's pool is as many arrays as the family
                                needs: K and V pools (the GPT, MimoV2: two a
                                kind), or ONE array where a row is a latent
@@ -66,7 +77,9 @@ by name):
                                B slots as the rows of one batch; False: one
                                row a call (MimoV2: two tables and a window
                                slice a slot), and the engine's
-                               `prefill_width` is 1
+                               `prefill_width` is 1. Ouro: True (a call reads
+                               the layers' weights n_loop times whatever
+                               rides it)
     prefill_paged_chunk(config, params, tokens (B, T), start (B,),
         n_valid (B,), cache, page_table (B, pages), attn_impl, mesh)
         -> (logits (B, V), cache)
@@ -92,7 +105,8 @@ by name):
                                PanguUltra: none, because its published drafter
                                is a next-token-prediction layer that reads the
                                target's last hidden state, which is left out,
-                               and the engine's draft model is a GPT
+                               and the engine's draft model is a GPT. Ouro:
+                               none
     kernel_sweep(config, cache) -> (pool shape, q rows a pool head, window,
                                sinks) of the decode kernel's sweep, for the
                                engine's block counters
@@ -100,7 +114,11 @@ by name):
                                the family's own counters kept in the cache
                                (MimoV2, PanguUltra: the expert layers', through
                                ops/moe.py's shared helpers; PanguUltra also the
-                               pool's bytes a token), read on demand
+                               pool's bytes a token; Ouro: decode steps, passes
+                               run, the exit gate's distribution summed over
+                               decoded tokens, the pools' bytes a token over
+                               all n_loop * n_layer cache layers), read on
+                               demand
 """
 
 from midgpt_tpu.models.gpt import GPT, GPTConfig, GPTParams

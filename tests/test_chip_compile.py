@@ -592,3 +592,51 @@ def test_latent_serving_program_compiles_and_never_relays_out_the_pool(program, 
     text = lowered.compile().as_text()
     assert text.count("tpu_custom_call") == (4 if program == "decode8" else 2)  # a write a layer (+ decode's attention)
     assert pool_relayouts(text, [pool.shape]) == 0
+
+
+@pytest.mark.parametrize("program", ["decode8", "prefill2x128"])
+def test_looped_serving_program_carries_the_pool_through_its_loops_without_a_copy(program, one_chip, compiled_kernels):
+    """models/ouro.py at the PUBLISHED preset and the cell's real sizes (48
+    layers applied 4 times, 12 slots, a 157-page pool of 192 cache layers:
+    7.90 GB beside 5.34 GB of weights): the 8-step decode chunk (steps x
+    passes x layers, three nested rolled loops with the pools in every carry)
+    and the prefill program compile for the v5e with NO copy as large as a
+    pool or one layer of it (`pool_relayouts`), no pool-shaped copy in any
+    while body, the WHOLE pool aliased from argument to result, ONE write and
+    ONE attention custom call for the 192 applications a step, and temporaries
+    of a few MB: the rolled loop costs no pool copy (PERF.md section 6 PR 41)."""
+    from midgpt_tpu.analysis.hlo_audit import pool_relayouts, while_body_pool_copies
+    from midgpt_tpu.config import load_config
+    from midgpt_tpu.sampling import serve
+
+    mc = load_config("ouro_2p6b").model_config
+    model = mc.model()
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(sds, jax.eval_shape(lambda k: model.cast_params(model.init(mc, k), jnp.bfloat16), jax.random.PRNGKey(0)))
+    cache = jax.tree.map(sds, jax.eval_shape(lambda: model.init_cache(mc, (157,), 32, jnp.bfloat16, kernel_layout=True)))
+    pools = cache.pool_arrays()
+    assert [a.shape for a in pools] == [(192, 16, 157, 32, 128)] * 2
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in pools)
+    assert pool_bytes == 157 * 32 * 1_572_864  # 7.90 GB
+    arr = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    B, T = 12, 32
+    if program == "decode8":
+        lowered = serve._serve_decode_chunk.lower(
+            mc, params, arr((B,)), cache, arr((B, T)), arr((B,)), arr((B,), jnp.bool_), 8,
+            0.8, None, None, "kernel", arr((2,), jnp.uint32), None, 1)
+    else:
+        W = serve.prefill_width(12, 128)
+        assert W == 2
+        lowered = serve._serve_prefill_chunk.lower(
+            mc, params, arr((W, 128)), arr((W,)), arr((W,)), cache, arr((W, T)), None, "kernel",
+            0.8, None, None, arr((2,), jnp.uint32))
+    assert len(lowered.as_text()) < 200_000  # 123 k characters whatever n_layer and n_loop
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == (2 if program == "decode8" else 1)
+    assert pool_relayouts(text, [a.shape for a in pools]) == 0
+    census = while_body_pool_copies(text, "bf16[192,16,157,32,128]")
+    assert len(census) >= (3 if program == "decode8" else 2) and not any(census.values()), census
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes and mem.temp_size_in_bytes < 64 << 20, mem
+    assert mem.argument_size_in_bytes < 13.3e9  # 5.34 GB of weights + the pools
