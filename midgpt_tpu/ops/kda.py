@@ -32,10 +32,18 @@ e^{G_r - R_a} * e^{R_a - G_i}, both factors <= 1, and the product over the
 channels is a matmul. Inside one sub-block the (sub, sub, d_k) tensor of
 e^{G_r - G_i} is formed directly.
 
-Backward: plain reverse-mode AD of the above. The state-independent part of
-each chunk and the scan's body are `jax.checkpoint`ed, so the backward pass
-keeps one state per CHUNK (T / C of them), never one per token, and
-recomputes a chunk's (sub, sub, d_k) tensors when it reaches the chunk.
+What runs where: on the TPU `kda_chunked` is `kernels/kda.py`, one Pallas call
+forward and one backward in which a chunk's terms and the state stay in VMEM;
+on any other backend it is the jnp body below, the readable statement of the
+algorithm between `kda_recurrent` and the kernels (same equations, same
+`CHUNK` and `SUB`, same precisions; tests/test_kimi_linear.py holds both to
+the recurrence). Which one is the backend's to say: there is no option.
+
+Backward of the jnp body: plain reverse-mode AD of the above. The
+state-independent part of each chunk and the scan's body are
+`jax.checkpoint`ed, so the backward pass keeps one state per CHUNK (T / C of
+them), never one per token, and recomputes a chunk's (sub, sub, d_k) tensors
+when it reaches the chunk. The kernels keep the same residual.
 """
 
 from __future__ import annotations
@@ -48,11 +56,12 @@ import jax.numpy as jnp
 
 Array = jax.Array
 _HIGHEST = jax.lax.Precision.HIGHEST
-# Tokens a chunk, tokens a sub-block, and chunks whose state-independent terms
-# are prepared together (a `lax.map` batch: it bounds what the backward pass
-# recomputes at once). On the v5e (B=1, T=8,192, 32 heads of 128, forward +
-# backward 72.2 ms a layer) no other setting moved the time by more than 6 %,
-# most for the worse (PERF.md §5, PR 26): constants, not options.
+# Tokens a chunk and tokens a sub-block, shared by the kernels and the jnp body;
+# and, for the jnp body alone, chunks whose state-independent terms are
+# prepared together (a `lax.map` batch: it bounds what its backward pass
+# recomputes at once). With the jnp body on the v5e (B=1, T=8,192, 32 heads of
+# 128, forward + backward 72.2 ms a layer) no other setting moved the time by
+# more than 6 %, most for the worse (PERF.md §6, PR 26): constants, not options.
 CHUNK, SUB, CHUNKS_PER_BATCH = 64, 16, 8
 assert CHUNK % SUB == 0
 
@@ -67,13 +76,16 @@ def causal_depthwise_conv(x: Array, taps: Array) -> Array:
     return sum(xp[:, j : j + T] * taps[:, j] for j in range(K))
 
 
-def kda_recurrent(q: Array, k: Array, v: Array, g: Array, beta: Array) -> tp.Tuple[Array, Array]:
+def kda_recurrent(
+    q: Array, k: Array, v: Array, g: Array, beta: Array, initial_state: tp.Optional[Array] = None
+) -> tp.Tuple[Array, Array]:
     """Token-by-token oracle. q, k, g (B, T, H, d_k); v (B, T, H, d_v); beta
-    (B, T, H). Returns (o (B, T, H, d_v) float32, final state (B, H, d_k, d_v))."""
+    (B, T, H); `initial_state` (B, H, d_k, d_v), zeros if None. Returns
+    (o (B, T, H, d_v) float32, final state (B, H, d_k, d_v))."""
     f32 = jnp.float32
     q, k, v, g, beta = (a.astype(f32) for a in (q, k, v, g, beta))
     B, T, H, dk = k.shape
-    S0 = jnp.zeros((B, H, dk, v.shape[-1]), f32)
+    S0 = jnp.zeros((B, H, dk, v.shape[-1]), f32) if initial_state is None else initial_state.astype(f32)
 
     def step(S, x):
         q_t, k_t, v_t, g_t, b_t = x  # (B, H, d), beta (B, H)
@@ -176,7 +188,18 @@ def kda_chunked(q: Array, k: Array, v: Array, g: Array, beta: Array) -> tp.Tuple
     between sub-blocks, run in q's dtype with float32 accumulation (bf16 on the
     training path: the MXU's native product; float32 in a float32 forward); the
     state itself, the decays, the products inside a sub-block, the triangular
-    solve and U are float32 always."""
+    solve and U are float32 always. On the TPU the Pallas kernels compute it
+    (module docstring), elsewhere `kda_chunked_jnp`."""
+    if jax.default_backend() == "tpu":
+        from midgpt_tpu.kernels.kda import kda_scan
+
+        return kda_scan(q, k, v, g, beta, chunk=CHUNK, sub=SUB)
+    return kda_chunked_jnp(q, k, v, g, beta)
+
+
+def kda_chunked_jnp(q: Array, k: Array, v: Array, g: Array, beta: Array) -> tp.Tuple[Array, Array]:
+    """`kda_chunked` as plain jnp: the terms of `CHUNKS_PER_BATCH` chunks at a
+    time under `lax.map`, then a `lax.scan` over the chunks."""
     chunk, sub = CHUNK, SUB
     f32, mm = jnp.float32, q.dtype
     B, T, H, dk = k.shape

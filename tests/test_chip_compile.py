@@ -640,3 +640,72 @@ def test_looped_serving_program_carries_the_pool_through_its_loops_without_a_cop
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes and mem.temp_size_in_bytes < 64 << 20, mem
     assert mem.argument_size_in_bytes < 13.3e9  # 5.34 GB of weights + the pools
+
+
+@pytest.fixture(scope="module")
+def kda_programs(one_chip):
+    """Forward and gradient of the chunked KDA recurrence through
+    kernels/kda.py at the published shape (`train_kimi_linear_t8k`: B=1,
+    T=8,192, 32 heads of 128, bf16 q/k/v, float32 decay and beta), inside the
+    `kda_scan` scope as models/kimi_linear.py opens it, compiled ONCE each for
+    the v5e: the optimized HLO of both, shared by the assertions below."""
+    import re
+
+    kk = importlib.import_module("midgpt_tpu.kernels.kda")
+    from midgpt_tpu.ops.kda import CHUNK, SUB
+
+    B, T, Hk, d = 1, 8192, 32, 128
+    arr = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = (arr((B, T, Hk, d), jnp.bfloat16),) * 3 + (arr((B, T, Hk, d), jnp.float32), arr((B, T, Hk), jnp.float32))
+
+    def scan(*a):
+        with jax.named_scope("kda_scan"):
+            return kk.kda_scan(*a, chunk=CHUNK, sub=SUB)
+
+    def loss(*a):
+        o, s = scan(*a)
+        return jnp.sum(o.astype(jnp.float32) ** 2) + jnp.sum(s)
+
+    was = kk._interpret
+    kk._interpret = lambda: False  # Mosaic, not the interpreter (module scope: no monkeypatch fixture here)
+    try:
+        with jax.default_matmul_precision("default"):
+            programs = {
+                "forward": jax.jit(scan).lower(*args).compile(),
+                "gradient": jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*args).compile(),
+            }
+    finally:
+        kk._interpret = was
+        kk._forward.clear_cache(); kk._backward.clear_cache()  # no trace made for Mosaic outlives this fixture
+    under_scope = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = ([^\n]*?)metadata=\{[^}\n]*?op_name="[^"]*kda_scan[^"]*"', re.M)
+    return {name: (c, under_scope.findall(c.as_text())) for name, c in programs.items()}
+
+
+@pytest.mark.parametrize("program,calls", [("forward", 1), ("gradient", 2)])
+def test_kda_kernels_compile_at_the_published_shape_and_carry_the_scope(program, calls, kda_programs):
+    """One Mosaic call forward; forward + backward in the gradient. Each is
+    named `kda_scan.<n>`, what benchmarks/metrics/kda_kernel.py sums."""
+    import re
+
+    _, ops = kda_programs[program]
+    mosaic = [name for name, rest in ops if "tpu_custom_call" in rest]
+    assert len(mosaic) == calls, mosaic
+    assert all(re.fullmatch(r"kda_scan(\.\d+)?", n) for n in mosaic), mosaic
+
+
+@pytest.mark.parametrize("program", ["forward", "gradient"])
+def test_kda_chunk_terms_and_state_never_reach_hbm(program, kda_programs):
+    """What the jnp body leaves in HBM is gone from under the scope: no
+    (sub, sub, d_k) decay tensor, no `convolution` (its sub-block products, its
+    solve, its scan's products with the state), no while loop; and the
+    program's temporaries are the residuals (the chunk-start states, G) plus
+    the cotangents, well under the jnp body's 1.23 GB of backward scratch
+    beside them."""
+    import re
+
+    compiled, ops = kda_programs[program]
+    assert ops, "no op under kda_scan"
+    for name, rest in ops:
+        assert not re.search(r"f32\[[\d,]*16,16,128\]", rest), (name, rest[:200])
+        assert "convolution(" not in rest and " while(" not in rest, (name, rest[:200])
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.7e9

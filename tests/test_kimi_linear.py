@@ -123,13 +123,26 @@ def _kda_inputs(seed, T, decay):
     return q, k, v, g, beta
 
 
+def _kda_kernel(*args, **kw):
+    """`kda_chunked` as the TPU runs it: kernels/kda.py, here in Pallas interpret mode."""
+    from midgpt_tpu.kernels.kda import kda_scan
+    from midgpt_tpu.ops.kda import CHUNK, SUB
+
+    return kda_scan(*args, chunk=CHUNK, sub=SUB, **kw)
+
+
+_KDA_BODIES = {"jnp": kda_chunked, "kernel": _kda_kernel}
+
+
+@pytest.mark.parametrize("body", sorted(_KDA_BODIES))
 @pytest.mark.parametrize("T,decay", [(192, 0.3), (150, 0.3), (150, 2.0), (7, 1.0)])
-def test_chunked_kda_matches_the_recurrence(T, decay):
+def test_chunked_kda_matches_the_recurrence(T, decay, body):
     """Values, final state and gradients over three chunks of 64; T = 150 and
     7 are no multiple of the chunk; decay 2.0 a token is e^-128 a chunk, which
-    a factored e^{G_r} e^{-G_i} would overflow on."""
+    a factored e^{G_r} e^{-G_i} would overflow on. Both bodies: the jnp one
+    (what `kda_chunked` runs off the TPU) and the Pallas kernels."""
     args = _kda_inputs(T, T, decay)
-    chunked = kda_chunked
+    chunked = _KDA_BODIES[body]
     o_r, s_r = kda_recurrent(*args)
     o_c, s_c = chunked(*args)
     np.testing.assert_allclose(o_c, o_r, atol=2e-6)
@@ -141,7 +154,8 @@ def test_chunked_kda_matches_the_recurrence(T, decay):
         assert float(jnp.abs(a - b).max()) <= 2e-5 * float(jnp.abs(b).max())
 
 
-def test_chunked_kda_carries_its_state_in_float32():
+@pytest.mark.parametrize("body", sorted(_KDA_BODIES))
+def test_chunked_kda_carries_its_state_in_float32(body):
     """bf16 inputs: the products run in bf16, the state does not. A state
     rounded to bf16 at every chunk (what this guards against) is 10x further
     from the float32 recurrence than the op is."""
@@ -149,10 +163,30 @@ def test_chunked_kda_carries_its_state_in_float32():
     o_ref, _ = kda_recurrent(*args)
     lo = tuple(a.astype(jnp.bfloat16) for a in args[:3]) + args[3:]
     o_ref_lo, _ = kda_recurrent(*(a.astype(jnp.float32) for a in lo))
-    o, s = kda_chunked(*lo)
+    o, s = _KDA_BODIES[body](*lo)
     assert s.dtype == jnp.float32
     err = float(jnp.sqrt(jnp.mean((o.astype(jnp.float32) - o_ref_lo) ** 2)))
     assert err < 2e-2 * float(jnp.sqrt(jnp.mean(o_ref**2))), err
+
+
+def test_the_kda_kernel_starts_from_a_state_carried_in():
+    """The kernels take the state IN (a chunked-prefill step will start from a
+    slot's state): the last 100 tokens from the state the first 90 left are
+    the last 100 of all 190, values, final state and the gradients of the
+    tokens and of the state carried in."""
+    args = _kda_inputs(11, 190, 0.3)
+    head, tail = (a[:, :90] for a in args), tuple(a[:, 90:] for a in args)
+    o_all, s_all = kda_recurrent(*args)
+    _, s_head = kda_recurrent(*head)
+    o, s = _kda_kernel(*tail, initial_state=s_head)
+    np.testing.assert_allclose(o, o_all[:, 90:], atol=2e-6)
+    np.testing.assert_allclose(s, s_all, atol=2e-6)
+
+    scalar = lambda fn: lambda s0, *a: jnp.sum(fn(*a, initial_state=s0)[0] ** 2) + jnp.sum(fn(*a, initial_state=s0)[1])
+    g_r = jax.grad(scalar(kda_recurrent), argnums=tuple(range(6)))(s_head, *tail)
+    g_k = jax.grad(scalar(_kda_kernel), argnums=tuple(range(6)))(s_head, *tail)
+    for a, b in zip(g_k, g_r):
+        assert float(jnp.abs(a - b).max()) <= 2e-5 * float(jnp.abs(b).max())
 
 
 # ---------------------------------------------------------------------------

@@ -56,8 +56,9 @@ def test_trains_through_make_runtime_and_resumes_to_the_same_loss(tmp_path):
 def test_benchmark_cell_rehearses_on_the_cpu(tmp_path):
     """`run.py --workload train_kimi_linear_t8k --rehearse-cpu` exits 0 and
     names every metric declared for the cell that a CPU run can produce: all
-    but the two that read the TPU's Mosaic custom calls (`mla_attention_*`:
-    the rehearsal's attention is the naive one) and those that need the
+    but the three that read the TPU's Mosaic custom calls (`mla_attention_*`:
+    the rehearsal's attention is the naive one; `kda_kernel_ms_per_step`: off
+    the TPU `kda_chunked` is its jnp body) and those that need the
     chip's peaks or its memory counters (`train.mfu_hybrid`, `step.device_ms`,
     `train.peak_hbm_gb`, as in the GPT cells' rehearsals)."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
@@ -74,7 +75,7 @@ def test_benchmark_cell_rehearses_on_the_cpu(tmp_path):
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
     last = json.loads(proc.stdout.strip().splitlines()[-1])
     assert last["rehearsal"] and last["correct"]
-    cpu_cannot = {"mla_attention_ms_per_step", "mla_attention_roofline", "train.mfu_hybrid",
+    cpu_cannot = {"mla_attention_ms_per_step", "mla_attention_roofline", "kda_kernel_ms_per_step", "train.mfu_hybrid",
                   "step.device_ms", "train.peak_hbm_gb"}  # the last two: run.py gives a CPU no peaks and no memory_stats
     assert declared - cpu_cannot <= set(last["would_report"]), sorted(declared - cpu_cannot - set(last["would_report"]))
     assert "moe.dropped 0" in proc.stdout
