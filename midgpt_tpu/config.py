@@ -428,7 +428,7 @@ class ExperimentConfig:
         if not isinstance(mc, GPTConfig):
             return (
                 "is written over the GPT's layer scan (GPT.hidden "
-                "layer_transform); this model family trains under the "
+                "layer_scan); this model family trains under the "
                 "compiler's schedule (gspmd)"
             )
         if axes["pp"] > 1:

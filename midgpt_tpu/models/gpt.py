@@ -1085,7 +1085,7 @@ class GPT:
         *,
         key: tp.Optional[KeyArray] = None,
         inference: bool = False,
-        layer_transform: tp.Optional[tp.Callable[[BlockParams], BlockParams]] = None,
+        layer_scan: tp.Optional[tp.Callable] = None,
         attn_fn: tp.Optional[tp.Callable[[Array, Array, Array], Array]] = None,
         positions: tp.Optional[Array] = None,
         rope_len: tp.Optional[int] = None,
@@ -1115,12 +1115,14 @@ class GPT:
         or fused into the chunked loss (training — ops/loss.py
         fused_linear_cross_entropy, which avoids the (B*T, V) f32 buffer).
 
-        `layer_transform` is applied to each layer's BlockParams slice inside
-        the scan body, before use. The explicit-FSDP path
-        (parallel/shard_map_fsdp.py) passes the per-layer all-gather here, so
-        under `jax.checkpoint` the gathered weights are rematerialized (ZeRO-3
-        re-gather) rather than saved, and AD of the gather transposes to the
-        per-layer grad reduce-scatter."""
+        `layer_scan(block_fn, x, (blocks, layer_keys)) -> (x, ys)` replaces
+        `jax.lax.scan` over the layer stack. The explicit-FSDP path
+        (parallel/shard_map_fsdp.py exchange_behind_scan) passes its own here:
+        the blocks arrive SHARDED, it gathers a layer's weights before
+        `block_fn` (the block under the config's remat policy) and brings its
+        own backward over the stack, in which the gathers are replayed
+        (ZeRO-3 re-gather) and a layer's cross-chip gradient sum runs one
+        layer behind."""
         B, T = tokens.shape
         C = config.head_dim
         if key is not None:
@@ -1145,8 +1147,6 @@ class GPT:
 
         def block_fn(x, block_and_key):
             block, k = block_and_key
-            if layer_transform is not None:
-                block = layer_transform(block)
             with jax.named_scope("block"):
                 out = GPT.block_apply(
                     config, block, x, key=k, inference=inference, rope=rope,
@@ -1159,10 +1159,20 @@ class GPT:
             return out if return_moe_aux else (out, None)
 
         if config.remat:
-            block_fn = jax.checkpoint(block_fn, policy=_remat_policy(config.remat_policy))
-        x, aux = jax.lax.scan(
-            block_fn, x, (params.blocks, layer_keys), unroll=config.scan_unroll
-        )
+            # A layer_scan differentiates block_fn inside loops of its own,
+            # where a replay cannot be merged with the forward it replays;
+            # the barrier prevent_cse puts on the inputs would stand between
+            # its re-gathered weights and the matmuls that stream them.
+            block_fn = jax.checkpoint(
+                block_fn, policy=_remat_policy(config.remat_policy),
+                prevent_cse=layer_scan is None,
+            )
+        if layer_scan is None:
+            x, aux = jax.lax.scan(
+                block_fn, x, (params.blocks, layer_keys), unroll=config.scan_unroll
+            )
+        else:
+            x, aux = layer_scan(block_fn, x, (params.blocks, layer_keys))
 
         with jax.named_scope("final_norm"):
             x = rms_norm(x, eps=1e-5)  # final norm (reference model.py:133,156)

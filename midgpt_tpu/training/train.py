@@ -596,10 +596,19 @@ def make_runtime(
     flight_recorder().metrics.gauge("fsdp.schedule_authored").set(
         float(fsdp_schedule == "authored")
     )
+    # the authored schedule sums a layer's gradients across 'fsdp' with its
+    # own ppermutes (parallel/shard_map_fsdp.py), n-1 a layer; the
+    # compiler's schedule has whatever collective the compiler chose
+    grad_hops = mesh.shape["fsdp"] - 1 if fsdp_schedule == "authored" else 0
+    flight_recorder().metrics.gauge("fsdp.grad_ring_hops").set(float(grad_hops))
     if jax.process_index() == 0:
+        if grad_hops:
+            grad_sum = f"{grad_hops} authored ppermute(s) a layer, one layer behind the backward"
+        else:
+            grad_sum = "the compiler's" if fsdp_schedule == "compiler" else "none across fsdp=1"
         print(
             f"fsdp schedule: {fsdp_schedule} (fsdp_mode={config.fsdp_mode!r}) "
-            f"on mesh {dict(mesh.shape)}"
+            f"on mesh {dict(mesh.shape)}; gradient sum over fsdp: {grad_sum}"
         )
     score_tile_share = _report_score_tile_share(config.model_config)
     global _LAST_RUNTIME

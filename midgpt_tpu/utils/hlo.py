@@ -3,7 +3,8 @@
 Used by the structural pins that keep scheduling claims honest:
 tests/test_shard_map_fsdp.py (gather/compute dataflow independence),
 tests/test_configs_compile.py (at-scale configs lower), and
-tests/test_chip_compile.py (the chip compiler's async collectives). One
+tests/test_chip_compile.py (the chip compiler's async collectives and what
+its schedule puts between their starts and dones). One
 parser and one abstract-lowering scaffold so the pins can't drift apart.
 """
 
@@ -111,6 +112,58 @@ def gather_overlap_census(txt: str) -> tp.List[tp.Dict[str, tp.Any]]:
                 "fused": fused,
             }
         )
+    return census
+
+
+_INSTRUCTION_RE = re.compile(r"^(?:ROOT )?%?([\w.\-]+) = ")
+_PERMUTE_START_RE = re.compile(r"= \(([a-z]+\d*)\[[\d,]*\][^ ]* .*? collective-permute-start\(")
+_PERMUTE_DONE_RE = re.compile(r" collective-permute-done\(%?([\w.\-]+)\)")
+_FUSION_CALLS_RE = re.compile(r" fusion\(.*calls=%([\w.\-]+)")
+
+
+def permute_overlap_census(txt: str) -> tp.List[tp.Dict[str, tp.Any]]:
+    """One entry per computation of compiled TPU HLO that holds asynchronous
+    collective-permutes: `{"computation", "loop_body", "kind", "pairs",
+    "covered", "dtypes"}`. The TPU compiler DOES split these in its text
+    (`collective-permute-start` ... `collective-permute-done`), and the text
+    is the schedule, so what a transfer runs beside is what stands between
+    its start and its done: `covered` counts the pairs with at least one
+    matmul fusion (a fusion whose computation holds a `convolution`) or
+    Mosaic kernel (`tpu_custom_call`) there, `pairs` all of them. A pair
+    with nothing between holds the chip as a synchronous collective would.
+    `kind` is 'backward' where the computation is the backward of a
+    shard_map scan (see `is_forward_body`), 'forward' otherwise."""
+    comps = hlo_computations(txt)
+    heavy_comps = {n for n, ls in comps.items() if any(" convolution(" in l for l in ls)}
+    bodies = while_body_names(txt)
+    census = []
+    for name, lines in comps.items():
+        heavy, starts, pairs, dtypes = [], {}, [], set()
+        for i, line in enumerate(lines):
+            m = _INSTRUCTION_RE.match(line)
+            if m is None:
+                continue
+            called = _FUSION_CALLS_RE.search(line)
+            if (called and called.group(1) in heavy_comps) or "tpu_custom_call" in line:
+                heavy.append(i)
+            start = _PERMUTE_START_RE.search(line)
+            if start:
+                starts[m.group(1)] = i
+                dtypes.add(start.group(1))
+            done = _PERMUTE_DONE_RE.search(line)
+            if done and done.group(1) in starts:
+                pairs.append((starts[done.group(1)], i))
+        if pairs:
+            census.append(
+                {
+                    "computation": name,
+                    "loop_body": name in bodies,
+                    "kind": "forward" if is_forward_body(lines) else "backward",
+                    "pairs": len(pairs),
+                    "covered": sum(any(a < h < b for h in heavy) for a, b in pairs),
+                    "dtypes": sorted(dtypes),
+                }
+            )
     return census
 
 

@@ -107,6 +107,47 @@ def test_collective_census_reads_op_dtype_and_size_as_the_v5e_spells_them():
     ]
 
 
+_PERMUTE_HLO = """\
+HloModule permutes
+
+%fused_dot (p0: bf16[8,8]) -> bf16[8,8] {
+  %p0 = bf16[8,8]{1,0} parameter(0)
+  ROOT %convolution.1 = bf16[8,8]{1,0} convolution(%p0, %p0), dim_labels=bf_io->bf
+}
+
+%loop_body (arg: (bf16[8,8])) -> (bf16[8,8]) {
+  %arg = (bf16[8,8]{1,0}) parameter(0)
+  %x = bf16[8,8]{1,0} get-tuple-element(%arg), index=0
+  %collective-permute-start.1 = (bf16[8,8]{1,0:T(8,128)(2,1)}, bf16[8,8]{1,0}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%x), channel_id=1, source_target_pairs={{0,1},{1,0}}
+  %collective-permute-start.2 = (f32[8,8]{1,0}, f32[8,8]{1,0}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%x), channel_id=2, source_target_pairs={{0,1},{1,0}}
+  %collective-permute-done.2 = f32[8,8]{1,0} collective-permute-done(%collective-permute-start.2)
+  %fusion.7 = bf16[8,8]{1,0} fusion(%x), kind=kOutput, calls=%fused_dot
+  %collective-permute-done.1 = bf16[8,8]{1,0} collective-permute-done(%collective-permute-start.1)
+  ROOT %t = (bf16[8,8]{1,0}) tuple(%collective-permute-done.1)
+}
+
+ENTRY %main (a: bf16[8,8]) -> bf16[8,8] {
+  %a = bf16[8,8]{1,0} parameter(0)
+  %init = (bf16[8,8]{1,0}) tuple(%a)
+  %w = (bf16[8,8]{1,0}) while(%init), condition=%cond, body=%loop_body
+  ROOT %out = bf16[8,8]{1,0} get-tuple-element(%w), index=0
+}
+"""
+
+
+def test_permute_overlap_census_reads_what_stands_between_start_and_done():
+    """A start/done pair with a matmul fusion between them is covered, one
+    with nothing between is not; dtypes come from the start's first buffer;
+    a computation without permutes has no entry."""
+    from midgpt_tpu.utils.hlo import permute_overlap_census
+
+    assert permute_overlap_census(_PERMUTE_HLO) == [
+        {"computation": "loop_body", "loop_body": True, "kind": "backward", "pairs": 2,
+         "covered": 1, "dtypes": ["bf16", "f32"]}
+    ]
+    assert permute_overlap_census(SAMPLE_HLO) == []
+
+
 def test_entry_parameter_dtypes_and_fp32_audit():
     assert entry_parameter_dtypes(SAMPLE_HLO) == ["f32", "bf16", "s32"]
     audit = fp32_master_param_audit(SAMPLE_HLO)

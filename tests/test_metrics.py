@@ -151,3 +151,59 @@ def test_unknown_device_kind_has_no_assumed_peak():
     unknown = types.SimpleNamespace(device_kind="TPU v99x", platform="tpu")
     with pytest.raises(ValueError, match="TPU v99x"):
         metrics.device_peak_flops(unknown)
+
+
+def _benchmark_module(*path):
+    spec = importlib.util.spec_from_file_location(path[-1][:-3], os.path.join(BENCHMARKS, *path))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+# (case, op names, what a chip's line looks like as (name index, start, duration) in ns,
+#  in flight, exposed)
+_GRAD_REDUCE_TRACES = [
+    ("synchronous_reduce_scatter_by_the_primitives_name",
+     ["fusion.1", "reduce_scatter.93", "all-gather.2"],
+     [(0, 0, 100), (1, 100, 50), (2, 150, 30), (0, 180, 20)], 50, 50),
+    ("async_permute_pair_hidden_but_for_its_ends",
+     ["collective-permute-start.1", "fusion.7", "collective-permute-done.1"],
+     [(0, 0, 5), (1, 5, 90), (2, 95, 10)], 105, 15),
+    ("two_pairs_in_flight_together_and_a_gap_between_ops",
+     ["collective-permute-start.1", "collective-permute-start.2", "fusion.7",
+      "collective-permute-done.1", "collective-permute-done.2", "while.3"],
+     [(5, 0, 200), (0, 0, 5), (1, 5, 5), (2, 10, 50), (2, 80, 20), (3, 100, 10), (4, 110, 10)],
+     120, 50),
+    ("ppermute_by_the_primitives_name_beside_a_weight_gather",
+     ["ppermute.4", "all-gather-start.1", "all-gather-done.1", "reduce-scatter.9"],
+     [(1, 0, 10), (0, 10, 40), (2, 50, 10), (3, 60, 7)], 47, 47),
+    ("no_gradient_collective", ["fusion.1", "all-reduce.2"], [(0, 0, 10), (1, 10, 5)], 0, 0),
+]
+
+
+@pytest.mark.parametrize("names, ops, in_flight, exposed", [c[1:] for c in _GRAD_REDUCE_TRACES],
+                         ids=[c[0] for c in _GRAD_REDUCE_TRACES])
+def test_grad_reduce_reader_on_small_traces(names, ops, in_flight, exposed):
+    """benchmarks/metrics/fsdp_grad_reduce.py: a gradient-reduction
+    collective is found by the jax primitive's name and by the compiler's,
+    is in flight for its own duration (synchronous) or from its start op's
+    beginning to its done op's end (a pair), and is EXPOSED where no other
+    leaf op runs on the chip: a loop wrapper is no op, a weight all-gather
+    is one. Per step and chip; a program with none reports nothing."""
+    reduce = _benchmark_module("reduce.py")
+    reader = _benchmark_module("metrics", "fsdp_grad_reduce.py")
+    ops = sorted(([n, s, d] for n, s, d in ops), key=lambda o: (o[1], -o[2]))
+    assert reader.exposed_ns(names, ops, reduce) == (in_flight, exposed)
+    run = {
+        "kind": "train", "chips": 4, "counters": {"traced_steps": 2}, "log": lambda _: None,
+        "load": lambda name: reduce,
+        "trace_summary": {"trace": {"names": names}, "n_devices": 2,
+                          "devices": [{"ops": ops}, {"ops": ops}]},
+    }
+    got = reader.read(run)
+    if in_flight == 0:
+        assert got is None
+    else:
+        assert list(got) == ["fsdp.grad_reduce_exposed_ms_per_step"]
+        assert got["fsdp.grad_reduce_exposed_ms_per_step"] == pytest.approx(exposed / 1e6 / 2)
+    assert reader.read({**run, "chips": 1}) is None

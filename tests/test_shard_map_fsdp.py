@@ -82,6 +82,102 @@ def test_grads_sharded_like_params():
         assert g.sharding == p.sharding, f"{path}: {g.sharding} != {p.sharding}"
 
 
+# (case, mesh, device count)
+_EXCHANGE_MESHES = [
+    ("fsdp2", MeshConfig(data=1, fsdp=2), 2),
+    ("fsdp4", MeshConfig(data=1, fsdp=4), 4),
+    ("fsdp8", MeshConfig(data=1, fsdp=8), 8),
+    ("data2_x_fsdp4", MeshConfig(data=2, fsdp=4), 8),
+    # an axis of one: nothing to send, the partials pass through
+    ("data2_x_fsdp1", MeshConfig(data=2, fsdp=1), 2),
+]
+# (case, [(full shape, axis sharded over 'fsdp' or None)], dtype)
+_EXCHANGE_LEAVES = [
+    ("axis0", [((16, 6), 0)], "float32"),
+    ("axis1", [((6, 32), 1)], "float32"),
+    ("several_widths_and_a_replicated_leaf",
+     [((3, 8, 16), 2), ((5,), None), ((16, 64), 1), ((64, 8), 1), ((8, 24), 0)], "float32"),
+    ("bf16", [((8, 16), 1), ((16, 8), 0)], "bfloat16"),
+]
+
+
+@pytest.mark.parametrize("leaves, dtype", [c[1:] for c in _EXCHANGE_LEAVES],
+                         ids=[c[0] for c in _EXCHANGE_LEAVES])
+@pytest.mark.parametrize("mesh_cfg, n_dev", [c[1:] for c in _EXCHANGE_MESHES],
+                         ids=[c[0] for c in _EXCHANGE_MESHES])
+def test_gradient_exchange_is_psum_scatter(mesh_cfg, n_dev, leaves, dtype):
+    """The authored cross-chip gradient sum (GradExchange: n-1 ppermutes of
+    one packed buffer, summed in float32) against `jax.lax.psum_scatter`
+    over 'fsdp', every chip holding its own partials: a leaf sharded on
+    axis 0, on axis 1, several leaves of different widths in one exchange, a
+    replicated leaf (untouched), bf16 in and out. Small whole numbers, so
+    any order of summation is exact. With a 'data' axis the sums a
+    parameter replicated over it is owed are psummed over it as well."""
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from midgpt_tpu.parallel.shard_map_fsdp import GradExchange
+
+    mesh = make_mesh(mesh_cfg, devices=jax.devices()[:n_dev])
+    every = ("data", "fsdp")
+    specs = [P(*["fsdp" if i == ax else None for i in range(len(shape))]) for shape, ax in leaves]
+    rng = np.random.default_rng(0)
+    partials = [
+        jnp.asarray(rng.integers(-3, 4, (n_dev, *shape)), dtype) for shape, _ in leaves
+    ]
+
+    def body(*gs):
+        gs = [g[0] for g in gs]
+        want = [
+            g if ax is None else jax.lax.psum_scatter(g, "fsdp", scatter_dimension=ax, tiled=True)
+            for g, (_, ax) in zip(gs, leaves)
+        ]
+        exchange = GradExchange(specs)
+        got = exchange.finish(exchange.stage(gs), want)
+        # what a parameter replicated over 'data' is owed
+        over_data = [jax.lax.psum(w, "data") for w in want]
+        got_over_data = exchange.finish(exchange.stage(gs), over_data)
+        return jax.tree.map(lambda a: a[None], (want, got, over_data, got_over_data))
+
+    want, got, over_data, got_over_data = jax.jit(
+        jax.shard_map(body, mesh=mesh, in_specs=P(every), out_specs=P(every))
+    )(*partials)
+    for w, g, (shape, ax) in zip(want + over_data, got + got_over_data, leaves + leaves):
+        assert g.dtype == w.dtype == jnp.dtype(dtype) and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g, np.float32), np.asarray(w, np.float32))
+
+
+@pytest.mark.parametrize("scan_unroll", [1, 2])
+def test_carried_backward_puts_each_layers_gradient_in_its_own_row(scan_unroll):
+    """The authored backward over the stack carries layer l's partial
+    gradients into iteration l-1 and writes their sums from there: every
+    layer's gradient must land in the layer's OWN row of the stacked leaves
+    (a shift by one is the bug this pins), the top layer's (peeled before
+    the loop) and layer 0's (summed after it) included. Four layers against
+    AD of the plain scan; the rows are told apart first."""
+    import dataclasses
+
+    cfg, mesh, params, specs, xg, yg = _setup()
+    cfg = dataclasses.replace(cfg, n_layer=4, scan_unroll=scan_unroll)
+    params = GPT.init(cfg, jax.random.PRNGKey(1))
+    specs = fsdp_param_specs(params, mesh, shard_model=True, min_size=0)
+    params = jax.jit(lambda p: constrain(p, specs, mesh))(params)
+
+    def plain_loss(p, x, y):
+        return fused_linear_cross_entropy(GPT.hidden(cfg, p, x, inference=True), p.lm_head, y, CHUNK)
+
+    sm_loss = make_shard_map_loss(cfg, mesh, specs, CHUNK)
+    want = jax.jit(jax.grad(plain_loss))(params, xg, yg).blocks
+    got = jax.jit(jax.grad(lambda p, x, y: sm_loss(p, x, y, None)))(params, xg, yg).blocks
+    for w, g in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        w, g = np.asarray(w), np.asarray(g)
+        assert w.shape[0] == 4
+        for a in range(4):
+            for b in range(a + 1, 4):
+                assert not np.allclose(w[a], w[b], atol=1e-5, rtol=1e-4), "rows cannot be told apart"
+            np.testing.assert_allclose(g[a], w[a], atol=1e-5, rtol=1e-4, err_msg=f"row {a}")
+
+
 def test_train_step_e2e_shard_map():
     """One full training step with fsdp_mode='shard_map' runs and is finite."""
     from midgpt_tpu.training.train import init_state, make_train_step
@@ -523,15 +619,20 @@ def test_one_device_step_holds_no_shard_map_of_the_loss(family):
     one-device mesh the derived schedule is the compiler's, so the traced
     step is the program it was before the schedule was derived — no
     shard_map anywhere in it (the GPT's four-device trace below has one,
-    so the probe can see it)."""
+    so the probe can see it). The four-device trace sums its block and
+    lm_head gradients with ppermutes; the one reduce_scatter left is wte's."""
     if family == "gpt":
         config = ExperimentConfig(**_BASE, model_config=_TINY, mesh=MeshConfig(fsdp=1))
     else:
         config = _kimi_tiny()
-    assert "shard_map" not in _traced_step(config, jax.devices()[:1])
+    one = _traced_step(config, jax.devices()[:1])
+    # ... and nothing of the authored gradient sum: no ppermute, no
+    # custom_vjp of the layer stack (the stack is `lax.scan` and its AD)
+    assert not [w for w in ("shard_map", "ppermute", "custom_vjp") if w in one]
     if family == "gpt":
-        four = config.replace(mesh=MeshConfig(fsdp=4))
-        assert "shard_map" in _traced_step(four, jax.devices()[:4])
+        four = _traced_step(config.replace(mesh=MeshConfig(fsdp=4)), jax.devices()[:4])
+        assert "shard_map" in four and "ppermute" in four
+        assert four.count("reduce_scatter[") == 1  # wte's, once in the microstep loop
 
 
 def test_runtime_reports_the_schedule_it_took(tmp_path, capsys):
@@ -553,6 +654,8 @@ def test_runtime_reports_the_schedule_it_took(tmp_path, capsys):
         assert f"fsdp schedule: {want}" in capsys.readouterr().out
         gauges = flight_recorder().metrics.snapshot()["gauges"]
         assert gauges["fsdp.schedule_authored"] == float(want == "authored")
+        # n-1 authored ppermutes a layer where the schedule is authored
+        assert gauges["fsdp.grad_ring_hops"] == (3.0 if want == "authored" else 0.0)
 
 
 # ---- parity at the four-chip cell's numerics ----
@@ -570,14 +673,21 @@ def _sgd_step_grads(config, x, y):
     optimizer = optax.sgd(1.0)
     step, *_ = make_train_step(config, optimizer, mesh, specs)
     before = jax.tree.map(np.asarray, params)
-    xg = make_global_batch(x, mesh, batch_spec())
-    yg = make_global_batch(y, mesh, batch_spec())
+    spec = batch_spec(shard_seq=mesh.shape["sp"] > 1)
+    xg = make_global_batch(x, mesh, spec)
+    yg = make_global_batch(y, mesh, spec)
     after, _, loss = step(params, optimizer.init(params), xg, yg, jax.random.PRNGKey(0))
     grads = jax.tree.map(lambda b, a: b - np.asarray(a), before, after)
     return float(loss), grads
 
 
-def test_schedules_agree_at_the_cell_numerics_bf16_dots_remat_g2():
+@pytest.mark.parametrize(
+    "mesh_cfg, attn_impl",
+    [(MeshConfig(data=1, fsdp=4), "naive"), (MeshConfig(data=1, fsdp=2, sp=2), "ring"),
+     (MeshConfig(data=1, fsdp=2, sp=2), "ulysses")],
+    ids=["fsdp4", "fsdp2_x_sp2_ring", "fsdp2_x_sp2_ulysses"],
+)
+def test_schedules_agree_at_the_cell_numerics_bf16_dots_remat_g2(mesh_cfg, attn_impl):
     """`train_xl_fsdp4`'s numerics at a toy size: bf16 compute over f32 master
     weights, remat_policy='dots', G=2 accumulated in f32, fsdp=4. Loss and f32
     gradients of the authored schedule against the compiler's, both read off
@@ -594,16 +704,23 @@ def test_schedules_agree_at_the_cell_numerics_bf16_dots_remat_g2():
     by no more than 16 bf16 epsilons (6.25e-2) outright. Readings on the CPU
     mesh (a correctness check): schedules apart 0.9e-2 to 2.5e-2 a leaf, each
     0.9e-2 to 2.5e-2 from float32 (two independent roundings of one
-    gradient); losses apart 5.3e-5 relative. The authored schedule sums four bf16-rounded
-    per-chip partials in a bf16 reduce-scatter, what the compiler's own
-    gradient all-reduces do at the cell's shapes (PERF.md section 6, PR 29)."""
+    gradient); losses apart 5.3e-5 relative. The authored schedule sums the bf16-rounded
+    per-chip partials of a block leaf and of lm_head in its own exchange
+    (parallel/shard_map_fsdp.py: bf16 on the wire, added in float32, rounded
+    to bf16 once; wte's in a bf16 reduce-scatter), where the compiler's own
+    gradient all-reduces at the cell's shapes are bf16 throughout (PERF.md
+    section 6, PR 29 and PR 44). G=2 runs the whole of the carried backward
+    twice (the peeled top layer, layer 0's exchange after the loop) and
+    accumulates in float32 across the microsteps; with an `sp` axis the same
+    body holds the ring / ulysses attention collectives, and the sums a
+    weight is owed go over `sp` as well."""
     rng = np.random.default_rng(3)
     cfg = dict(
         **{**_BASE, "g_accum_iters": 2, "compute_dtype": "bfloat16"},
-        mesh=MeshConfig(data=1, fsdp=4),
+        mesh=mesh_cfg,
         model_config=GPTConfig(
             block_size=64, vocab_size=256, n_layer=2, n_head=2, n_embd=64,
-            remat=True, remat_policy="dots",
+            remat=True, remat_policy="dots", attn_impl=attn_impl,
         ),
     )
     x = rng.integers(0, 256, (2, 8, 64), dtype=np.int32)
