@@ -8,7 +8,7 @@ executable checks:
     jax.monitoring event stream, so tests can pin "N request mixes -> 0 new
     compiles" (SERVING.md: admitting/finishing requests never recompiles)
     and "the train step compiles exactly once".
-  * `jit_cache_size` — the jit wrapper's executable-cache population (one
+  * `jit_cache_size` (utils/hlo.py) — the jit wrapper's executable-cache population (one
     entry per compiled program), for pinning the *total* compile set of a
     module-level jit like sampling/serve._serve_decode_chunk.
   * `while_body_collectives` / `assert_no_while_body_collectives` — a
@@ -27,7 +27,14 @@ from __future__ import annotations
 import re
 import typing as tp
 
-from midgpt_tpu.utils.hlo import hlo_computations, while_body_names
+# jit_cache_size and pool_relayouts live in utils/hlo.py (the serving engine
+# reads them and imports nothing of analysis/); they are this module's too.
+from midgpt_tpu.utils.hlo import (
+    hlo_computations,
+    jit_cache_size,
+    pool_relayouts,
+    while_body_names,
+)
 
 # Event recorded once per actual XLA backend compilation (jax wraps
 # backend.compile in record_event_duration_secs under this name).
@@ -75,14 +82,6 @@ class CompileCounter:
         import jax.monitoring
 
         jax.monitoring.unregister_event_duration_listener(self._listener)
-
-
-def jit_cache_size(fn: tp.Any) -> tp.Optional[int]:
-    """Compiled-program count in a jit wrapper's cache (None if the jax
-    version does not expose it). One entry per distinct (static args,
-    input avals) combination that actually lowered + compiled."""
-    probe = getattr(fn, "_cache_size", None)
-    return probe() if callable(probe) else None
 
 
 # ----------------------------------------------------------------------
@@ -145,41 +144,6 @@ def while_body_pool_copies(
 
 _SHAPE_DIMS_RE = re.compile(r"[a-z]+[0-9]*\[([0-9,]*)\]")
 _SCATTER_RE = re.compile(r"= ([a-z]+[0-9]*\[[0-9,]*\])\S* scatter\(")
-
-
-_RELAYOUT_RE = re.compile(
-    r"= \(?[a-z]+[0-9]*\[([0-9,]*)\][^=\n]*? (?:copy|copy-start|transpose)\("
-)
-
-
-def pool_relayouts(
-    hlo_text: str, pool_shapes: tp.Iterable[tp.Sequence[int]]
-) -> int:
-    """Copies and transposes in a compiled program whose result is as large
-    as a KV-pool buffer or ONE LAYER of it: the TPU layout census of the
-    serving programs (PagedKVCache "Layout contract").
-
-    `pool_shapes` are the logical shapes of the pool's leaves ((L, H, P,
-    ps, C) pools, (L, P, H, ps) int8 scale buffers); a result counts when
-    its dims are a leaf's, or a leaf's without its layer dim (with or
-    without a unit dim in its place), in any dtype and layout. Every
-    computation of the module is read, so a relayout fused into a `fusion`
-    is counted by the `copy`/`transpose` inside it; an entry PARAMETER is a
-    parameter, not a copy, and is not counted. What it finds, on the chip's
-    compiler: a program that scatters into the pool relays it out on entry
-    and on exit (2 per pool tensor) and copies one layer per tensor per
-    layer for the attention custom call, 4 + 2L for a bf16 pool (PR 25);
-    one that keeps the contract reads 0. On other backends the number is
-    whatever that backend's lowering does and pins nothing."""
-    wanted = set()
-    for shape in pool_shapes:
-        dims = tuple(int(d) for d in shape)
-        wanted |= {dims, dims[1:], (1,) + dims[1:]}
-    return sum(
-        1
-        for m in _RELAYOUT_RE.finditer(hlo_text)
-        if tuple(int(d) for d in m.group(1).split(",") if d) in wanted
-    )
 
 
 def _shape_signature(shape: str) -> tp.Tuple[int, ...]:

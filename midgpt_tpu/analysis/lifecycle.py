@@ -5,9 +5,10 @@ suppression machinery this pass shares. Where pass 1 flags single-site
 footguns, pass 3 tracks *obligations* across paths:
 
   GC009  page-set / refcount lifecycle. Every acquisition site — pool
-         `allocator.alloc`, trie `prefix_cache.match` (takes refs),
-         `prefix_cache.evict` / `prefix_cache.release` (both RETURN freed
-         page lists that must reach `allocator.free`) — must reach exactly
+         `pool.alloc` / `allocator.alloc`, trie `prefix_cache.match` (takes
+         refs), `prefix_cache.evict` / `prefix_cache.release` (both RETURN
+         freed page lists that must reach `pool.free` / `allocator.free`)
+         — must reach exactly
          one release funnel on every path, including explicit `raise`
          edges. Flags: discarded acquisition results, rebinding a variable
          that still holds pages, falling off a return/raise/function end
@@ -35,7 +36,7 @@ footguns, pass 3 tracks *obligations* across paths:
          values (interprocedural, depth-limited).
 
 Scope model and limits (docs/ANALYSIS.md "Pass 3"): receiver names are
-matched by hint (`allocator` / `prefix_cache` / `trie` path components, or
+matched by hint (`pool` / `allocator` / `prefix_cache` / `trie` path components, or
 locals aliased from one), so the trie module's own internals — which by
 design mutate `.refs` and shuffle page lists — are exempt, as is any
 `re.match`-style lookalike. Analysis is per-function for GC009/GC010 and
@@ -92,7 +93,9 @@ def _chain(node: ast.AST) -> tp.Tuple[str, ...]:
     return tuple(dotted.split(".")) if dotted else ()
 
 
-_ALLOC_HINTS = ("allocator",)
+# `pool`: sampling/pages.py PagePool, whose `alloc(kind, n)` / `free(kind,
+# pages)` are the allocators' by kind
+_ALLOC_HINTS = ("allocator", "pool")
 _TRIE_HINTS = ("prefix_cache", "trie")
 
 

@@ -51,7 +51,7 @@ Skeleton (shared by every mode):
   NEVER-DEREFERENCE RULE. With copies issued by hand this is a safety rule
   and not only a saving: `table[b, j]` is read ONLY for a page some row can
   see. Entries past a slot's length and window-reclaimed entries (-1,
-  ServeEngine._reclaim_window) may hold anything. A page that is not
+  sampling/pages.py PagePool.reclaim) may hold anything. A page that is not
   fetched leaves its rows of the buffer as they were — zeros from the
   call's first step, later another page's values, always finite — and its
   columns are masked, so they add exactly 0 (tests/test_decode_attention.py

@@ -150,7 +150,7 @@ class GPTConfig:
     # Training support: attn_impl 'naive' or 'blockwise' (the flash/ring/
     # ulysses kernels have no window mask — validated below). Serving:
     # every paged path masks by the same rule, and the engine reclaims
-    # pages that fall fully behind the window (sampling/serve.py).
+    # pages that fall fully behind the window (sampling/pages.py).
     sliding_window: int = 0
     attn_sinks: int = 0
 
@@ -338,7 +338,7 @@ class PagedKVCache:
     head count, so GQA/MQA configs shrink every page (and its int8 scale
     rows) by the group factor, which is what turns the grouping into pages
     per HBM byte — and a request occupies
-    whatever pages the host-side allocator (sampling/serve.py PageAllocator)
+    whatever pages the host-side allocator (sampling/pages.py PageAllocator)
     hands it — so device memory holds O(sum of used lengths) instead of
     `n_slots * block_size` (the KVCache sizing above). Page 0 is the SINK:
     never allocated, it is what unallocated page-table entries (zeros) point
@@ -378,7 +378,7 @@ class PagedKVCache:
     result: row-major over (L, H, P, ps, C), the layout in which the
     paged-attention template copies a page at a time. Three rules keep it
     so, and a program that breaks one pays a whole-pool relayout per call
-    (analysis/hlo_audit.pool_relayouts counts them; PERF.md, PR 25):
+    (utils/hlo.py pool_relayouts counts them; PERF.md, PR 25):
 
       1. the pool's DEFAULT device layout is that layout. The TPU
          compiler gives a buffer whose trailing dim is under 128 lanes a
@@ -449,7 +449,7 @@ class PagedKVCache:
         config: "GPTConfig", page_size: int, dtype, kernel_layout: bool = False
     ) -> int:
         """K+V bytes of ONE page across all layers/heads — the unit the
-        byte-budgeted pool sizing divides by (sampling/serve.py
+        byte-budgeted pool sizing divides by (sampling/pages.py
         `pool_hbm_bytes`). Deliberately excludes the int8 scale side
         buffers: the budget governs the page pools (what doubles), and the
         +4/head_dim side buffer is reported separately via

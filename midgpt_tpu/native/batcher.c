@@ -6,9 +6,10 @@
  * materializing a (B*G, T) index matrix and walking the stream twice with
  * per-element index arithmetic. This C kernel does one contiguous pass per
  * window — read T+1 tokens once, widen to int32, write x and y together —
- * parallelized across windows with pthreads. 7-9.5x on pod-scale host
- * batches (tools/bench_batcher.py; RESULTS.md), which keeps TPUs fed at
- * openwebtext_mh batch sizes without host-side double-buffering tricks.
+ * parallelized across windows with pthreads, so that a host keeps its TPUs
+ * fed at openwebtext_mh batch sizes without double-buffering tricks (what it
+ * saves a training step shows in the training cells' set-up and step
+ * times, benchmarks/: the repo holds no other measurement of it).
  *
  * Contract (ctypes, see midgpt_tpu/native/__init__.py):
  *   sample_windows(data, n_windows, T, starts, x_out, y_out, n_threads)

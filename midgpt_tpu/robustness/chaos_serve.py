@@ -319,12 +319,7 @@ def _assert_drained_conserved(eng) -> int:
     trie_pages = (
         0 if eng.prefix_cache is None else eng.prefix_cache.page_count()
     )
-    assert (
-        eng.allocator.free_count + trie_pages == eng.allocator.num_pages - 1
-    ), (
-        f"page leak: {eng.allocator.free_count} free + {trie_pages} trie of "
-        f"{eng.allocator.num_pages - 1} allocatable"
-    )
+    assert eng.pool.conserved(eng.slots), f"page leak: {eng.pool.ledger(eng.slots)}"
     if eng.prefix_cache is not None:
         dangling = eng.prefix_cache.referenced_page_count()
         assert dangling == 0, f"{dangling} trie refcount(s) outlived the drain"

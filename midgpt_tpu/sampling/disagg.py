@@ -25,11 +25,12 @@ small:
     into the DECODE trie, so the decode engine's ordinary admission path
     re-matches them and skips prompt re-prefill — the decode role needs no
     new admission states either;
-  * page content moves as a host-gathered block and lands through one
-    jitted scatter (`_adopt_pages`, donated pool, oob-padded page indices
-    like every engine scatter), so the adopt is one compiled program per
-    (page-count bucket, dtype) — the same bucketing discipline that keeps
-    the serving jits' compile set mix-independent.
+  * page content moves as a host-gathered block (`pages.take_pages`) and
+    lands through one jitted scatter (`pages.adopt_pages`, donated pool,
+    oob-padded page indices like every engine scatter), so the adopt is
+    one compiled program per (page-count bucket, dtype) — the same
+    bucketing discipline that keeps the serving jits' compile set
+    mix-independent. The pool's format is sampling/pages.py's alone.
 
 Greedy parity: the decode role's prompt is `prompt + [first_token]`; its
 prefill recomputes exactly the positions the handoff did not ship and its
@@ -46,49 +47,22 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import functools
 import itertools
 import time
 import typing as tp
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-from midgpt_tpu.models.gpt import GPTConfig, GPTParams, PagedKVCache
+from midgpt_tpu.models.gpt import GPTConfig, GPTParams
 from midgpt_tpu.obs import DISABLED_SNAPSHOT, Observability
 from midgpt_tpu.robustness.backoff import backoff_delays
 from midgpt_tpu.obs.trace import NULL_TRACER
+from midgpt_tpu.sampling.pages import adopt_pages, take_pages
 from midgpt_tpu.sampling.serve import (
     BackpressureError,
     FinishedRequest,
     ServeEngine,
 )
-
-
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
-def _adopt_pages(mesh, cache, dst, blocks):
-    """Scatter handed-off page blocks into the decode pool at physical
-    pages `dst` ((n,) int32, padded to a power-of-two bucket with
-    `num_pages` so pad writes drop under XLA oob-scatter semantics — the
-    same funnel shape as the engine's K/V column writes). `blocks` carries
-    'k'/'v' (L, H, n, ps, C) and, int8 pools, 'k_scale'/'v_scale'
-    (L, n, H, ps); its key set and the dst bucket are the compile keys.
-    The pool is donated: an adopt is an in-place page write, not a pool
-    copy. `mesh` is static like the serving jits' trailing mesh arg and
-    pins the sharded pool's out-sharding (serve._maybe_constrain)."""
-    k = cache.k.at[:, :, dst].set(blocks["k"].astype(cache.k.dtype))
-    v = cache.v.at[:, :, dst].set(blocks["v"].astype(cache.v.dtype))
-    ks, vs = cache.k_scale, cache.v_scale
-    if "k_scale" in blocks:
-        ks = ks.at[:, dst].set(blocks["k_scale"])
-        vs = vs.at[:, dst].set(blocks["v_scale"])
-    new = PagedKVCache(k=k, v=v, k_scale=ks, v_scale=vs)
-    if mesh is not None:
-        from midgpt_tpu.parallel.serve_tp import constrain_cache
-
-        new = constrain_cache(new, mesh)
-    return new
 
 
 @dataclasses.dataclass
@@ -105,7 +79,7 @@ class HandoffItem:
     max_new_tokens: int  # ORIGINAL budget (decode role gets it minus 1)
     eos_id: tp.Optional[int]
     deadline: tp.Optional[float]
-    blocks: tp.Dict[str, np.ndarray]  # page content, keys as _adopt_pages
+    blocks: tp.Dict[str, np.ndarray]  # page content, as pages.take_pages gives it
     n_pages: int
 
 
@@ -456,20 +430,10 @@ class DisaggServe:
             n = len(mr.pages)
             blocks: tp.Dict[str, np.ndarray] = {}
             if n:
-                idx = jnp.asarray(mr.pages, jnp.int32)
-                cache = self.prefill.cache
-                blocks["k"] = np.asarray(jnp.take(cache.k, idx, axis=2))
-                blocks["v"] = np.asarray(jnp.take(cache.v, idx, axis=2))
-                if cache.k_scale is not None:
-                    blocks["k_scale"] = np.asarray(
-                        jnp.take(cache.k_scale, idx, axis=1)
-                    )
-                    blocks["v_scale"] = np.asarray(
-                        jnp.take(cache.v_scale, idx, axis=1)
-                    )
+                blocks = take_pages(self.prefill.cache, mr.pages)
                 ps = self.prefill.page_size
-                self.prefill.allocator.free(
-                    pc.release(prompt[: n * ps], mr.pages, n)
+                self.prefill.pool.free(
+                    0, pc.release(prompt[: n * ps], mr.pages, n)
                 )
         return HandoffItem(
             uid=uid, prompt=prompt, first_token=first, first_time=first_time,
@@ -530,14 +494,14 @@ class DisaggServe:
         if n == 0:
             return
         eng = self.decode
-        dst = eng.allocator.alloc(n)
+        dst = eng.pool.alloc(0, n)
         if dst is None:
             # Reclaim unreferenced trie pages, the engine's own pressure
             # valve, then retry once.
-            eng.allocator.free(
-                eng.prefix_cache.evict(n - eng.allocator.free_count)
+            eng.pool.free(
+                0, eng.prefix_cache.evict(n - eng.allocator.free_count)
             )
-            dst = eng.allocator.alloc(n)
+            dst = eng.pool.alloc(0, n)
         if dst is None:
             self.fallback_reprefills += 1
             self._trace.instant(
@@ -545,30 +509,10 @@ class DisaggServe:
                 args={"uid": item.uid},
             )
             return
-        bucket = 1
-        while bucket < n:
-            bucket *= 2
-        pad = bucket - n
-        dst_j = jnp.asarray(
-            np.asarray(dst + [eng.cache.num_pages] * pad, np.int32)
-        )
-        def _pad(blk: np.ndarray, axis: int):
-            if pad == 0:
-                return jnp.asarray(blk)
-            shape = list(blk.shape)
-            shape[axis] = pad
-            return jnp.asarray(
-                np.concatenate([blk, np.zeros(shape, blk.dtype)], axis=axis)
-            )
-
-        blocks = {
-            key: _pad(blk, 1 if key.endswith("scale") else 2)
-            for key, blk in item.blocks.items()
-        }
-        eng.cache = _adopt_pages(eng.mesh, eng.cache, dst_j, blocks)
+        eng.cache = adopt_pages(eng.mesh, eng.cache, dst, item.blocks)
         ps = eng.page_size
-        eng.allocator.free(
-            eng.prefix_cache.release(item.prompt[: n * ps], dst, 0)
+        eng.pool.free(
+            0, eng.prefix_cache.release(item.prompt[: n * ps], dst, 0)
         )
 
     def _drain_decode(self) -> None:

@@ -42,7 +42,7 @@ with three cooperating pieces:
     or stale page is discarded and the tokens re-prefill — the PR 3
     verified-checkpoint discipline applied to KV, so a flipped bit can
     never poison a decode. Re-adoption rides the pow2-bucketed adoption
-    scatter (`disagg._adopt_pages`). The tier is SHARED fleet-wide: KV
+    scatter (`pages.adopt_pages`). The tier is SHARED fleet-wide: KV
     content depends only on tokens and weights, not on which replica
     computed it, so a failed-over stream re-prefills from pages its dead
     replica spilled.
@@ -92,6 +92,7 @@ from midgpt_tpu.sampling.disagg import (
     HandoffRetryExhausted,
     PageHandoffQueue,
 )
+from midgpt_tpu.sampling.pages import split_pages, take_pages
 from midgpt_tpu.sampling.serve import (
     BackpressureError,
     FinishedRequest,
@@ -177,8 +178,6 @@ class SpillTier:
         AFTER the hook returns. int8 pools spill quantized: the int8
         columns plus their per-page scales, half the bytes of a bf16
         page."""
-        import jax.numpy as jnp
-
         key = tuple(int(t) for t in prefix)
         existing = self._entries.get(key)
         if existing is not None:
@@ -189,20 +188,7 @@ class SpillTier:
             # stale duplicate from before a hot swap: replace it
             del self._entries[key]
             self.stale_discarded += 1
-        # (1,)-shaped take keeps ONE cached gather program for every page
-        # index (a python-int slice would compile per index).
-        idx = jnp.asarray([page], jnp.int32)
-        blocks: tp.Dict[str, np.ndarray] = {
-            "k": np.asarray(jnp.take(cache.k, idx, axis=2))[:, :, 0],
-            "v": np.asarray(jnp.take(cache.v, idx, axis=2))[:, :, 0],
-        }
-        if cache.k_scale is not None:
-            blocks["k_scale"] = np.asarray(
-                jnp.take(cache.k_scale, idx, axis=1)
-            )[:, 0]
-            blocks["v_scale"] = np.asarray(
-                jnp.take(cache.v_scale, idx, axis=1)
-            )[:, 0]
+        (blocks,) = split_pages(take_pages(cache, [page]))
         self._tick += 1
         entry = _SpillEntry(
             blocks, _blocks_crc(blocks), weights_version, self._tick

@@ -23,7 +23,7 @@ invariants rather than around speed:
   * **Resize** (`resize_pool`) moves the resident working set — live slot
     pages plus every referenced trie page — into a freshly allocated pool
     through the same pow2-bucketed gather/adoption scatter that the
-    disagg handoff uses (sampling/disagg.py `_adopt_pages`), then remaps
+    disagg handoff uses (sampling/pages.py `PagePool.migrate`), then remaps
     slot page lists and trie entries onto the new physical ids. Shrink
     REFUSES with a structured, retryable `PoolResizeError` rather than
     evicting below the resident working set (the backpressure discipline,
@@ -53,9 +53,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from midgpt_tpu.models.gpt import PagedKVCache
-from midgpt_tpu.sampling.disagg import _adopt_pages
-from midgpt_tpu.sampling.serve import PageAllocator, ServeEngine
+from midgpt_tpu.sampling.pages import pow2_bucket as _pow2_bucket
+from midgpt_tpu.sampling.serve import ServeEngine
 
 
 class HotSwapError(RuntimeError):
@@ -318,63 +317,12 @@ def maybe_flip_swap(engine: ServeEngine) -> bool:
 
 
 def assert_conserved(engine: ServeEngine, where: str) -> None:
-    """The serving-wide page conservation law (chaos_serve.py invariant):
-    free + trie-held + live-slot-only == num_pages - 1 (page 0 is the
-    sink). Resize asserts it on BOTH sides of a migration."""
-    pc = engine.prefix_cache
-    held = set() if pc is None else pc.pages_held()
-    # -1 entries are window-reclaimed placeholders (serve.py
-    # _reclaim_window) — already back on the free list, not live.
-    live = {p for s in engine.slots if s is not None for p in s.pages[0] if p >= 0}
-    total = engine.allocator.free_count + len(held) + len(live - held)
-    assert total == engine.allocator.num_pages - 1, (
-        f"page conservation violated {where}: free={engine.allocator.free_count} "
-        f"trie={len(held)} live_only={len(live - held)} "
-        f"!= num_pages-1={engine.allocator.num_pages - 1}"
+    """The serving-wide page conservation law (`PagePool.conserved`: free +
+    trie-held + live-slot-only == num_pages - 1, page 0 being the sink).
+    Resize asserts it on BOTH sides of a migration."""
+    assert engine.pool.conserved(engine.slots), (
+        f"page conservation violated {where}: {engine.pool.ledger(engine.slots)}"
     )
-
-
-def _pow2_bucket(n: int) -> int:
-    return 1 if n <= 1 else 1 << (n - 1).bit_length()
-
-
-def _gather_resident(cache, old_ids: tp.List[int], pad_to: int):
-    """Host-gather the resident pages (padded to the pow2 bucket with the
-    sink page 0, so the gather's compile key is the bucket, not the exact
-    resident count — the same bucket discipline as the serving jits)."""
-    idx = jnp.asarray(old_ids + [0] * (pad_to - len(old_ids)), jnp.int32)
-    blocks = {
-        "k": np.asarray(jnp.take(cache.k, idx, axis=2)),
-        "v": np.asarray(jnp.take(cache.v, idx, axis=2)),
-    }
-    if cache.k_scale is not None:
-        blocks["k_scale"] = np.asarray(jnp.take(cache.k_scale, idx, axis=1))
-        blocks["v_scale"] = np.asarray(jnp.take(cache.v_scale, idx, axis=1))
-    return blocks
-
-
-def _migrate_cache(engine, cache, old_ids, new_ids, num_pages, config):
-    """Copy resident pages of one pool (target or draft) into a freshly
-    allocated `num_pages` pool via the disagg adoption scatter — int8
-    scales travel with their pages ('k_scale'/'v_scale' blocks)."""
-    bucket = _pow2_bucket(len(old_ids))
-    blocks = _gather_resident(cache, old_ids, bucket)
-    # Pad destinations with `num_pages`: XLA oob-scatter drops the pad
-    # writes (disagg.py _adopt_pages contract).
-    dst = jnp.asarray(new_ids + [num_pages] * (bucket - len(new_ids)), jnp.int32)
-    new_cache = PagedKVCache.init(
-        config, num_pages=num_pages, page_size=engine.page_size,
-        dtype=engine.cache_dtype, kernel_layout=engine.attn_impl == "kernel",
-    )
-    if engine.mesh is not None:
-        from midgpt_tpu.parallel import serve_tp as _stp
-
-        new_cache = _stp.put_sharded(
-            new_cache, _stp.serve_cache_specs(new_cache), engine.mesh
-        )
-    if not old_ids:
-        return new_cache
-    return _adopt_pages(engine.mesh, new_cache, dst, blocks)
 
 
 def resize_pool(
@@ -393,10 +341,11 @@ def resize_pool(
          live slot pages + referenced trie pages — cannot fit, or if live
          slots exceed the requested slot count.
       2. LRU-evict unreferenced trie pages that no longer fit.
-      3. Gather resident pages (pow2 bucket, sink-padded), scatter into
-         the new pool with the disagg adoption jit (int8 scales ride
-         along), remap slot page lists + trie entries to the new ids.
-      4. Install pool + allocator; conservation asserted on both sides.
+      3. `PagePool.migrate`: gather resident pages (pow2 bucket,
+         sink-padded), scatter into the new pool with the adoption jit
+         (int8 scales ride along), install pool + allocator; then remap
+         slot page lists + trie entries to the new ids.
+      4. Conservation asserted on both sides.
 
     The new pool's first decode/prefill round compiles the page-bucket
     programs for the new num_pages (a program key); an identical resize
@@ -423,7 +372,7 @@ def resize_pool(
         )
 
     pc = engine.prefix_cache
-    live = {p for s in live_slots for p in s.pages[0] if p >= 0}
+    live = engine.pool.live_pages(live_slots)[0]
     referenced = set() if pc is None else pc.referenced_pages()
     # Slot-shared pages (pages[:n_shared]) are referenced trie entries by
     # construction, so |live ∪ referenced| = |live − held| + |referenced|.
@@ -449,7 +398,7 @@ def resize_pool(
             # Only unreferenced entries are evictable; the resident check
             # above guarantees there are at least `overflow` of them.
             freed = engine.prefix_cache.evict(overflow)
-            engine.allocator.free(freed)
+            engine.pool.free(0, freed)
             trie_evicted = len(freed)
             assert trie_evicted == overflow, (
                 f"resize eviction shortfall: wanted {overflow}, "
@@ -459,27 +408,11 @@ def resize_pool(
     held = set() if pc is None else pc.pages_held()
     old_ids = sorted(live | held)
     n_migrate = len(old_ids)
-    allocator = PageAllocator(num_pages)
-    new_ids: tp.List[int] = []
-    if n_migrate:
-        got = allocator.alloc(n_migrate)
-        assert got is not None  # n_migrate <= num_pages - 1 checked above
-        new_ids.extend(got)
-    mapping = dict(zip(old_ids, new_ids))
-
-    engine.cache = _migrate_cache(
-        engine, engine.cache, old_ids, new_ids, num_pages, engine.config
-    )
-    if engine.draft_cache is not None:
-        engine.draft_cache = _migrate_cache(
-            engine, engine.draft_cache, old_ids, new_ids, num_pages,
-            engine.draft_config,
-        )
+    mapping = engine.pool.migrate(num_pages, old_ids)
     for s in live_slots:
         s.pages[0][:] = [mapping[p] if p >= 0 else -1 for p in s.pages[0]]
     if pc is not None:
         pc.remap_pages(mapping)
-    engine.allocator = allocator
     if max_slots is not None and max_slots != engine.max_slots:
         # Live slots keep their _Slot objects; the page table is rebuilt
         # from engine.slots every round, so compaction is free. A new

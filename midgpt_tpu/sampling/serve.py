@@ -7,7 +7,10 @@ requests are. This module serves a STREAM: requests are admitted into decode
 slots the moment one frees (or a new one arrives), long prompts prefill in
 bounded chunks interleaved with the running batch's decode steps, and K/V
 live in a shared paged pool sized to the expected working set instead of
-`n_slots * block_size` (models/gpt.py PagedKVCache).
+`n_slots * block_size` (models/gpt.py PagedKVCache). The pool's format, its
+size, its books (allocators, the release funnel, the window rule, page
+conservation) and its page tables are sampling/pages.py's (`PagePool`, one an
+engine: `self.pool`); this module is the policy over it and the round.
 
 Scheduling is host-side and runs every round (`ServeEngine.step`):
 
@@ -146,9 +149,11 @@ from midgpt_tpu.obs import DISABLED_SNAPSHOT, Observability
 from midgpt_tpu.obs.trace import NULL_TRACER
 from midgpt_tpu.robustness import faults
 from midgpt_tpu.sampling.engine import sample_logits, warp_logits
+from midgpt_tpu.sampling.pages import PageAllocator, PagePool, adopt_pages, join_pages
 from midgpt_tpu.sampling.prefix_cache import PrefixCache
 from midgpt_tpu.sampling.scheduler import FCFSScheduler, Scheduler
 from midgpt_tpu.sampling.spec import speculative_accept
+from midgpt_tpu.utils.hlo import jit_cache_size, pool_relayouts
 from midgpt_tpu.utils.stack_chunk import call_on_own_chunk
 
 Array = jax.Array
@@ -232,12 +237,10 @@ class _PoolProgram:
 
     def pool_relayouts(self) -> tp.Dict[str, int]:
         """{kernel-path program as compiled: pool- or layer-sized copies
-        and transposes in its compiled text} (analysis/hlo_audit
-        .pool_relayouts). Reads each program's text once: lowering the
-        recorded abstract arguments again finds the executable the call
-        compiled, it does not compile."""
-        from midgpt_tpu.analysis.hlo_audit import pool_relayouts
-
+        and transposes in its compiled text} (utils/hlo.pool_relayouts).
+        Reads each program's text once: lowering the recorded abstract
+        arguments again finds the executable the call compiled, it does not
+        compile."""
         for label, (args, kwargs) in self._compiled.items():
             if label in self._kernel_path and label not in self._relayouts:
                 text = self.jit.lower(*args, **kwargs).compile().as_text()
@@ -676,33 +679,6 @@ def normalize_cache_dtype(dtype) -> jnp.dtype:
     return jnp.dtype(dtype)
 
 
-class PageAllocator:
-    """Free-list allocator over the pool's pages. Page 0 is the SINK
-    (absorbs inactive-slot writes, models/gpt.py PagedKVCache) and is never
-    handed out."""
-
-    def __init__(self, num_pages: int):
-        self.num_pages = num_pages
-        self._free = list(range(num_pages - 1, 0, -1))  # pop() yields 1, 2, ...
-        self.free_min = len(self._free)  # the fewest pages ever free: the pool's peak is the rest
-
-    @property
-    def free_count(self) -> int:
-        return len(self._free)
-
-    def alloc(self, n: int) -> tp.Optional[tp.List[int]]:
-        """n pages, or None (allocator unchanged) if the pool is short."""
-        if n > len(self._free):
-            return None
-        self.free_min = min(self.free_min, len(self._free) - n)
-        return [self._free.pop() for _ in range(n)]
-
-    def free(self, pages: tp.Iterable[int]) -> None:
-        for p in pages:
-            assert 0 < p < self.num_pages
-            self._free.append(p)
-
-
 class BackpressureError(RuntimeError):
     """Admission was refused — the caller should shed load or (when
     `retryable`) retry later, instead of the request sitting in an
@@ -1035,53 +1011,11 @@ class ServeEngine:
         self.dispatch_log: tp.Deque[tp.Tuple[int, tp.Tuple[int, ...]]] = (
             collections.deque(maxlen=256)
         )
-        self.max_pages_per_slot = -(-config.block_size // page_size)
-        cache_dtype = normalize_cache_dtype(cache_dtype)
-        self.cache_dtype = cache_dtype
-        if pool_hbm_bytes is not None:
-            # Byte-budgeted paging: the pool is sized by HBM SPEND, not page
-            # count, so the page capacity follows the cache dtype — int8
-            # admits 2x the pages of bf16 at the same budget (the int8 scale
-            # side buffers ride on top, +4/head_dim; PagedKVCache.page_bytes
-            # documents the accounting, cache_hbm_bytes() reports the true
-            # total).
-            if num_pages is not None:
-                raise ValueError("pass num_pages OR pool_hbm_bytes, not both")
-            per_page = PagedKVCache.page_bytes(
-                config, page_size, cache_dtype,
-                kernel_layout=resolve_paged_impl(attn_impl) == "kernel",
-            )
-            num_pages = max(2, pool_hbm_bytes // per_page)  # sink + >= 1
-        elif num_pages is None:
-            # Default: half of what dedicated full-length caches would take
-            # (+ the sink) — the continuous-batching bet that Σ used-lengths
-            # stays well under n_slots * block_size.
-            num_pages = 1 + max_slots * self.max_pages_per_slot // 2
+        self.cache_dtype = normalize_cache_dtype(cache_dtype)
         # Backpressure bound: worst-case page demand (prompt + full budget)
         # summed over every live request, queued or running. None (default):
         # admission is unbounded, the pre-TTL behavior.
         self.max_backlog_pages = max_backlog_pages
-        # A further kind's pool: as many pages as the first where it keeps
-        # the whole context; where it keeps a window, what every slot can
-        # hold at once, window + the longest write between two reclaims (a
-        # prefill chunk; a decode group) + a page of alignment, so that this
-        # pool never runs dry before the first does.
-        burst = max(prefill_chunk, decode_chunk * _round_group_bucket(round_group))
-        pool_pages = [num_pages] + [
-            1 + max_slots * (-(-(k.window + burst) // page_size) + 1) if k.window else num_pages
-            for k in self.kinds[1:]
-        ]
-        self.allocators = [PageAllocator(n) for n in pool_pages]
-        # Sliding-window page reclamation (a kind with window > 0; cache-off,
-        # non-speculative engines): pages wholly behind every future row's
-        # window (and past the sink prefix) are returned to the free list
-        # mid-request, their table entries parked on the sink page — the
-        # bounded-resident-set lever that makes windowed decode O(window) in
-        # pool pages, not O(T). Per kind: pages the rule freed (the first
-        # kind's is stats()["window_reclaimed_pages"]); the most pages one
-        # slot ever held.
-        self.kind_reclaimed = [0] * len(self.kinds)
-        self.kind_slot_pages_max = [0] * len(self.kinds)
         # Cross-request prefix sharing (module docstring; default OFF so a
         # plain engine's scheduling is bit-for-bit the pre-trie behavior).
         self.prefix_cache = PrefixCache(page_size) if prefix_cache else None
@@ -1107,16 +1041,6 @@ class ServeEngine:
         self.spill_tier = None
         self.spill_readopted_pages = 0
         self.spill_readopt_events = 0
-        self.cache = self.model.init_cache(
-            config, pool_pages, page_size, cache_dtype,
-            kernel_layout=self.attn_impl == "kernel",
-        )
-        if mesh is not None:
-            from midgpt_tpu.parallel import serve_tp as _stp
-
-            self.cache = _stp.put_sharded(
-                self.cache, _stp.serve_cache_specs(self.cache), mesh
-            )
         # ---- speculative decoding (docs/SERVING.md) ----
         # A draft model turns every decode round into draft-k-then-verify:
         # the draft proposes spec_k tokens against its OWN paged pool, the
@@ -1158,28 +1082,16 @@ class ServeEngine:
         self.spec_k_max = spec_k_max
         self.spec_k_min = spec_k_min
         self.spec_adapt = spec_adapt
-        # A layer-prefix self-draft needs no pool of its own: draft layer i
-        # IS target layer i, so the committed K/V it must attend to already
-        # sit in the target pool's first n_draft layers, and its speculative
-        # writes there are the same values the verify forward rewrites. The
-        # draft then also skips prompt prefill entirely — the target's
-        # prefill filled its layers. A separate draft model gets a dedicated
-        # pool (same page table/allocator: one logical page, two pools).
-        self.draft_cache = (
-            None
-            if draft_config is None or draft_shares_cache
-            else PagedKVCache.init(
-                draft_config, num_pages=num_pages, page_size=page_size,
-                dtype=cache_dtype,
-                kernel_layout=self.attn_impl == "kernel",
-            )
+        # The paged pool (sampling/pages.py): one pool, one allocator and one
+        # page table a kind of cache, the draft model's pool beside the first
+        # where it has one of its own, and the books of all of them.
+        self.pool = PagePool(
+            config, max_slots=max_slots, num_pages=num_pages, pool_hbm_bytes=pool_hbm_bytes,
+            page_size=page_size, burst=max(prefill_chunk, decode_chunk * self.round_group),
+            cache_dtype=self.cache_dtype, kernel_layout=self.attn_impl == "kernel",
+            prefill_width=self.prefill_width, mesh=mesh, prefix_cache=self.prefix_cache,
+            draft_config=draft_config, draft_shares_cache=draft_shares_cache,
         )
-        if mesh is not None and self.draft_cache is not None:
-            from midgpt_tpu.parallel import serve_tp as _stp
-
-            self.draft_cache = _stp.put_sharded(
-                self.draft_cache, _stp.serve_cache_specs(self.draft_cache), mesh
-            )
         # aggregate speculative counters (spec_stats)
         self._spec_rounds = 0
         self._spec_verifies = 0  # (slot, round) pairs
@@ -1246,15 +1158,37 @@ class ServeEngine:
 
     # -- public surface ------------------------------------------------
 
+    # What the pool owns, as the engine's callers read it (benchmarks/, tests).
+    # `cache` is also ASSIGNED, by every dispatch: a serving program is handed
+    # the pool (donated) and hands back the next one.
+
+    @property
+    def cache(self):
+        return self.pool.cache
+
+    @cache.setter
+    def cache(self, cache) -> None:
+        self.pool.cache = cache
+
+    @property
+    def allocators(self) -> tp.List[PageAllocator]:
+        return self.pool.allocators
+
     @property
     def allocator(self) -> PageAllocator:
         """The first kind's allocator: what every mechanism that knows one
-        kind of page reads (and `resize` replaces)."""
-        return self.allocators[0]
+        kind of page reads."""
+        return self.pool.allocators[0]
 
-    @allocator.setter
-    def allocator(self, allocator: PageAllocator) -> None:
-        self.allocators[0] = allocator
+    @property
+    def max_pages_per_slot(self) -> int:
+        return self.pool.max_pages_per_slot
+
+    def cache_hbm_bytes(self) -> int:
+        return self.pool.hbm_bytes()
+
+    def cache_hbm_bytes_per_shard(self) -> int:
+        return self.pool.hbm_bytes_per_shard()
 
     def submit(
         self,
@@ -1412,22 +1346,28 @@ class ServeEngine:
                 return True
         for i, slot in enumerate(self.slots):
             if slot is not None and slot.request.uid == uid:
-                req = slot.request
                 self.cancelled += 1
-                self._finish(
-                    FinishedRequest(
-                        uid=uid,
-                        tokens=np.concatenate(
-                            [req.prompt, np.asarray(slot.generated, np.int32)]
-                        ),
-                        token_times=slot.token_times,
-                        status=status,
-                    )
-                )
-                self._release_slot(slot)
-                self.slots[i] = None
+                self._retire(i, slot, status)
                 return True
         return False
+
+    def _retire(self, slot_i: int, slot: "_Slot", status: str, t: tp.Optional[float] = None) -> None:
+        """The one way a RUNNING slot's request ends (finished, EOS, timeout,
+        cancelled): its tokens are recorded under `status`, its pages go
+        through `_release_slot`, the slot is free. `t`: as `_finish`."""
+        self._finish(
+            FinishedRequest(
+                uid=slot.request.uid,
+                tokens=np.concatenate(
+                    [slot.request.prompt, np.asarray(slot.generated, np.int32)]
+                ),
+                token_times=slot.token_times,
+                status=status,
+            ),
+            t,
+        )
+        self._release_slot(slot)
+        self.slots[slot_i] = None
 
     def _refuse_several_kinds(self, mechanism: str) -> None:
         """What is not wired for a family whose layers need several kinds of
@@ -1506,8 +1446,8 @@ class ServeEngine:
         stopped, allocate plainly (a spill hit is an optimization, never
         a demand — it must not evict trie pages or preempt anyone),
         checksum-verify and move the run out of the tier, scatter it into
-        the pool through the disagg adoption jit (pow2 dst bucket,
-        oob-padded — the one page-transport funnel), and start the slot
+        the pool (pages.adopt_pages: the one page-transport funnel), and
+        start the slot
         committed past it. The re-adopted pages are PRIVATE until prefill
         completion, when insert_live shares them like any other complete
         prompt pages. A checksum or weights_version mismatch truncates
@@ -1525,53 +1465,19 @@ class ServeEngine:
         n = min(n, self.allocator.free_count)  # plain alloc: take what's free
         if n == 0:
             return
-        got = self.allocator.alloc(n)
+        got = self.pool.alloc(0, n)
         if got is None:
             return
         blocks_list = tier.take_run(req.prompt, start, n, self.weights_version)
         m = len(blocks_list)
         if m == 0:
-            self.allocator.free(got)
+            self.pool.free(0, got)
             return
         if m < n:
-            self.allocator.free(got[m:])
+            self.pool.free(0, got[m:])
             got = got[:m]
         with self._trace.span("spill.readopt", "prefix", self._obs_tid):
-            blocks = {
-                key: np.stack(
-                    [b[key] for b in blocks_list],
-                    axis=1 if key.endswith("scale") else 2,
-                )
-                for key in blocks_list[0]
-            }
-            bucket = 1
-            while bucket < m:
-                bucket *= 2
-            pad = bucket - m
-            if pad:
-
-                def _zpad(blk: np.ndarray, axis: int) -> np.ndarray:
-                    shape = list(blk.shape)
-                    shape[axis] = pad
-                    return np.concatenate(
-                        [blk, np.zeros(shape, blk.dtype)], axis=axis
-                    )
-
-                blocks = {
-                    k: _zpad(b, 1 if k.endswith("scale") else 2)
-                    for k, b in blocks.items()
-                }
-            dst = jnp.asarray(
-                np.asarray(got + [self.cache.num_pages] * pad, np.int32)
-            )
-            from midgpt_tpu.sampling.disagg import _adopt_pages
-
-            self.cache = _adopt_pages(
-                self.mesh,
-                self.cache,
-                dst,
-                {k: jnp.asarray(b) for k, b in blocks.items()},
-            )
+            self.cache = adopt_pages(self.mesh, self.cache, got, join_pages(blocks_list))
         slot.pages[0].extend(got)
         slot.prompt_pos = slot.length = (start + m) * ps
         self._prefix_matched_tokens += m * ps  # a cross-tier hit is a hit
@@ -1599,12 +1505,6 @@ class ServeEngine:
             return
         self.resize(self.resize_plan.pop(0))
 
-    def cache_hbm_bytes(self) -> int:
-        """Total device bytes of the target pool — K/V pages plus, in int8
-        mode, the f32 scale side buffers (the honest spend a byte budget
-        must be judged against)."""
-        return sum(a.nbytes for a in jax.tree.leaves(self.cache))
-
     @staticmethod
     def compile_stats(census: bool = False) -> tp.Dict[str, tp.Any]:
         """Compiled-program census of the serving jits (graftcheck pass-2
@@ -1622,8 +1522,6 @@ class ServeEngine:
         `census=True` (sample.py's exit table) reads the text of every
         program compiled since, a fraction of a second each, so it is not
         for the serving loop. Empty on the XLA path."""
-        from midgpt_tpu.analysis.hlo_audit import jit_cache_size
-
         programs = {
             "prefill": _serve_prefill_chunk,
             "decode": _serve_decode_chunk,
@@ -1662,15 +1560,6 @@ class ServeEngine:
 
         return mesh_shape(self.mesh)
 
-    def cache_hbm_bytes_per_shard(self) -> int:
-        """Per-DEVICE bytes of the target pool. Every pool leaf (K/V pages
-        and int8 scale side buffers) shards its head axis over 'tp' and
-        replicates elsewhere, so a tp shard holds exactly total/tp — the
-        number a per-chip HBM budget must be judged against: slot capacity
-        per chip grows with the mesh (tests/test_tp_serving.py)."""
-        n_tp = 1 if self.mesh is None else int(self.mesh.shape["tp"])
-        return self.cache_hbm_bytes() // n_tp
-
     def stats(self) -> tp.Dict[str, tp.Any]:
         """Deployment-shape + counter snapshot for SLO reporting: a sharded
         run is distinguishable from a single-chip one by its record alone."""
@@ -1693,7 +1582,7 @@ class ServeEngine:
             "resizes": self.resizes,
             "spill_readopted_pages": self.spill_readopted_pages,
             "spill_readopt_events": self.spill_readopt_events,
-            "window_reclaimed_pages": self.kind_reclaimed[0],
+            "window_reclaimed_pages": self.pool.kind_reclaimed[0],
             "swap_pending": self._staged_swap is not None,
             "compile_counts": self.compile_stats(),
             # unified observability schema (docs/OBSERVABILITY.md): round
@@ -1708,25 +1597,11 @@ class ServeEngine:
         }
 
     def serve_counters(self) -> tp.Dict[str, float]:
-        """Counters of the cache by kind and of the family's own layers
-        (docs/OBSERVABILITY.md), whether or not an Observability is wired:
-        `kv.<kind>_pages_live` (allocated now; `_pages_live_max`: at the peak)
-        and, for a WINDOWED kind only (a kind without a window has no rule that
-        frees a page mid-request, so it has no such counter),
-        `kv.<kind>_pages_reclaimed` (freed by the window rule so far) and
-        `kv.<kind>_tokens_per_slot_max` (the most one slot ever held, in
-        tokens: bounded by window + the longest write + a page); then what the
-        family's `serve_counters` reads off its cache (a device read: call it
-        between rounds, not in them)."""
-        out: tp.Dict[str, float] = {}
-        for i, (k, a) in enumerate(zip(self.kinds, self.allocators)):
-            out[f"kv.{k.name}_pages_live"] = a.num_pages - 1 - a.free_count
-            out[f"kv.{k.name}_pages_live_max"] = a.num_pages - 1 - a.free_min
-            if k.window:
-                out[f"kv.{k.name}_pages_reclaimed"] = self.kind_reclaimed[i]
-                out[f"kv.{k.name}_tokens_per_slot_max"] = (
-                    self.kind_slot_pages_max[i] * self.page_size
-                )
+        """Counters of the cache by kind (`PagePool.counters`) and of the
+        family's own layers (docs/OBSERVABILITY.md), whether or not an
+        Observability is wired: what the family's `serve_counters` reads off its
+        cache is a device read: call it between rounds, not in them."""
+        out = self.pool.counters()
         if self.model.serve_counters is not None:
             out.update(self.model.serve_counters(self.config, self.cache))
         return out
@@ -2062,7 +1937,7 @@ class ServeEngine:
         else:
             chain_token = np.zeros((B,), np.int32)
             chain_len = np.zeros((B,), np.int32)
-        tables = self._device_tables(bucket)
+        tables = self.pool.tables(self.slots, bucket)
         t_p = 0.0 if obs is None else self._clock()
         self.cache, toks, emitted, tok_fin, len_fin, self._key = _serve_decode_group(
             self.config,
@@ -2175,16 +2050,7 @@ class ServeEngine:
         if victim is None:
             return
         page = next(p for p in victim.pages[0] if p >= 0)
-        bad = (
-            float("nan")
-            if jnp.issubdtype(self.cache.k.dtype, jnp.floating)
-            else 127
-        )
-        self.cache = dataclasses.replace(
-            self.cache,
-            k=self.cache.k.at[:, :, page].set(bad),
-            v=self.cache.v.at[:, :, page].set(bad),
-        )
+        self.pool.poison(page)
         for s in self.slots:
             if (
                 s is not None
@@ -2206,7 +2072,7 @@ class ServeEngine:
         if self.prefix_cache is None:
             return
         freed = self.prefix_cache.evict(0, force_all=True)
-        self.allocator.free(freed)
+        self.pool.free(0, freed)
         self.prefix_evictions += len(freed)
 
     def _expire_round(self) -> None:
@@ -2237,20 +2103,8 @@ class ServeEngine:
         self.queue[:] = still_queued
         for i, slot in enumerate(self.slots):
             if slot is not None and expired(slot.request):
-                req = slot.request
                 self.timeouts += 1
-                self._finish(
-                    FinishedRequest(
-                        uid=req.uid,
-                        tokens=np.concatenate(
-                            [req.prompt, np.asarray(slot.generated, np.int32)]
-                        ),
-                        token_times=slot.token_times,
-                        status="timeout",
-                    )
-                )
-                self._release_slot(slot)
-                self.slots[i] = None
+                self._retire(i, slot, "timeout")
 
     def _admit(self) -> None:
         now = self._clock()
@@ -2334,20 +2188,19 @@ class ServeEngine:
 
     def _grow(self, slot: _Slot, kind: int, need: int) -> bool:
         """`need` more logical pages of `kind` for `slot` (_ensure_pages)."""
-        allocator = self.allocators[kind]
+        pool = self.pool
         while need > 0:
-            got = allocator.alloc(need)
+            got = pool.alloc(kind, need)
             if got is not None:
                 slot.pages[kind].extend(got)
-                if self.kinds[kind].window:
-                    self._note_growth(slot, kind)
+                pool.note_growth(slot, kind)
                 return True
             if self.prefix_cache is not None:
                 reclaimed = self.prefix_cache.evict(
-                    need - allocator.free_count
+                    need - pool.allocators[kind].free_count
                 )
                 if reclaimed:
-                    self.allocator.free(reclaimed)
+                    pool.free(0, reclaimed)
                     self.prefix_evictions += len(reclaimed)
                     continue
             candidates = [
@@ -2368,13 +2221,6 @@ class ServeEngine:
                 )
             self._evict(victim)
         return True
-
-    def _note_growth(self, slot: _Slot, kind: int) -> None:
-        """The most pages of a windowed kind one slot ever held (serve_counters):
-        its list less what the window rule has freed below `reclaimed_to`."""
-        k = self.kinds[kind]
-        held = len(slot.pages[kind]) - max(0, slot.reclaimed_to[kind] - -(-k.sinks // self.page_size))
-        self.kind_slot_pages_max[kind] = max(self.kind_slot_pages_max[kind], held)
 
     def _evict(self, victim: _Slot) -> None:
         """Recompute-style preemption: fold generated tokens into the
@@ -2420,24 +2266,13 @@ class ServeEngine:
 
     def _release_slot(self, slot: _Slot) -> None:
         """The ONE funnel a departing slot's pages go through (finish,
-        cancel, timeout, preemption). Cache off: straight back to the
-        allocator. Cache on: the trie drops the slot's shared-page refs,
-        absorbs its complete committed pages for future matches, and only
-        the remainder (partial tails, content-duplicates) hits the free
-        list — page conservation becomes free_count + trie pages ==
-        num_pages - 1 (tests/test_prefix_cache.py, chaos_serve.py)."""
+        cancel, timeout, preemption): `PagePool.release`, under a
+        `trie.release` span where the prefix trie takes its share."""
         if self.prefix_cache is None:
-            # -1 entries are window-reclaimed placeholders (already freed)
-            for allocator, pages in zip(self.allocators, slot.pages):
-                allocator.free(p for p in pages if p >= 0)
+            self.pool.release(slot)
             return
         with self._trace.span("trie.release", "prefix", self._obs_tid):
-            committed = np.concatenate(
-                [slot.request.prompt, np.asarray(slot.generated, np.int32)]
-            )[: slot.length]
-            self.allocator.free(
-                self.prefix_cache.release(committed, slot.pages[0], slot.n_shared)
-            )
+            self.pool.release(slot)
 
     def _sampling_key(self) -> tp.Optional[Array]:
         """The `key` argument of this engine's next program: the engine's
@@ -2445,70 +2280,6 @@ class ServeEngine:
         its own off it and hands back the next: the caller stores that as
         `_key`, unforced), or None, greedy: no key at all."""
         return None if self.temperature == 0.0 else self._key
-
-    def _page_table(self, n_pages: tp.Optional[int] = None, kind: int = 0) -> np.ndarray:
-        table = np.zeros((self.max_slots, n_pages or self.max_pages_per_slot), np.int32)
-        for i, s in enumerate(self.slots):
-            if s is not None:
-                pages = s.pages[kind][: table.shape[1]]
-                table[i, : len(pages)] = pages
-        # Window-reclaimed entries (-1 in slot.pages) park on the sink page:
-        # the kernel sweep skips them and the mask hides their columns, but
-        # the BlockSpec index map still needs a valid physical page.
-        np.maximum(table, 0, out=table)
-        return table
-
-    def _device_tables(self, n_pages: int, rows: tp.Optional[tp.Sequence[int]] = None):
-        """The round's page table as the serving programs take it, in numpy
-        (its transfer rides the program's call, as every argument of a round
-        does): the (slots, n_pages) table of the first kind, or, where the
-        family has several kinds, the tuple of every kind's. `rows`: those
-        slots' rows alone, in that order, then empty rows (the sink page) up
-        to `prefill_width`."""
-        def table(kind: int) -> np.ndarray:
-            full = self._page_table(n_pages, kind)
-            if rows is None:
-                return full
-            picked = np.zeros((self.prefill_width, full.shape[1]), np.int32)
-            picked[: len(rows)] = full[list(rows)]
-            return picked
-
-        if len(self.kinds) == 1:
-            return table(0)
-        return tuple(table(k) for k in range(len(self.kinds)))
-
-    def _reclaim_window(self, slot: _Slot) -> None:
-        """Free this slot's pages that no FUTURE attention row can see.
-
-        Page j (positions [j*ps, (j+1)*ps)) is dead once the youngest
-        visible position has moved past it — counts only grow, so
-        (j+1)*ps <= length - sliding_window is permanent — unless it holds
-        sink-prefix tokens. Freed entries become -1 placeholders so the
-        page list keeps its LOGICAL length (position -> table column stays
-        the identity; _ensure_pages and the settle bound len(pages)*ps are
-        untouched); _page_table parks them on the sink page. Gated off
-        under the prefix cache (the trie owns shared pages' lifetime) and
-        speculative decoding (verify rollback re-reads recent history);
-        conservation becomes free + live non-placeholder == num_pages - 1."""
-        if self.prefix_cache is not None or self.draft_config is not None:
-            return
-        ps = self.page_size
-        for k, kind in enumerate(self.kinds):
-            if not kind.window:
-                continue
-            pages = slot.pages[k]
-            first_live = max(0, slot.length - kind.window) // ps  # pages below are dead
-            # keep the sink prefix; below `reclaimed_to` everything is freed already
-            start = max(-(-kind.sinks // ps), slot.reclaimed_to[k])
-            dead = [j for j in range(start, first_live) if pages[j] >= 0]
-            if first_live > slot.reclaimed_to[k]:
-                slot.reclaimed_to[k] = first_live
-            if not dead:
-                continue
-            self.allocators[k].free(pages[j] for j in dead)
-            for j in dead:
-                pages[j] = -1
-            self.kind_reclaimed[k] += len(dead)
 
     def _count_blocks(
         self,
@@ -2547,18 +2318,9 @@ class ServeEngine:
         )
 
     def _page_bucket(self, max_tokens: int) -> int:
-        """Smallest power-of-two page count covering `max_tokens` positions.
-
-        The serve step's attention (and its CPU gather fallback) is
-        O(table_width x page_size) per slot; slicing the table to a bucket
-        makes it O(longest-active-request) instead of O(block_size) — the
-        used-length attention lever of the ISSUE — while the pow2 bucketing
-        keeps the compile set logarithmic, not per-length."""
-        need = -(-max_tokens // self.page_size)
-        b = 1
-        while b < need:
-            b *= 2
-        return min(b, self.max_pages_per_slot)
+        """Smallest power-of-two page count covering `max_tokens` positions
+        (`PagePool.bucket`): a round's table is sliced to it."""
+        return self.pool.bucket(max_tokens)
 
     def _split_bucket(self, max_tokens: int) -> int:
         """Static split-K factor for a round whose widest slot spans
@@ -2640,7 +2402,7 @@ class ServeEngine:
         # the page bucket of a call is the bucket of its longest row
         bucket = self._page_bucket(int((start + n_valid).max()))
         t_n = 0.0 if obs is None else self._clock()
-        table = self._device_tables(bucket, [slot_i for slot_i, _, _ in rows])
+        table = self.pool.tables(self.slots, bucket, [slot_i for slot_i, _, _ in rows])
         # the one-row call every family takes: (numpy) scalars
         start_a, n_valid_a = (start, n_valid) if W > 1 else (start[0], n_valid[0])
         # one key a call, as a decode round has one: split off inside the program
@@ -2680,13 +2442,13 @@ class ServeEngine:
                 # (the pending token is the TARGET's). A prefix self-draft
                 # skips this: the target prefill above already filled its
                 # layers of the shared pool.
-                _, _, self.draft_cache, _ = _serve_prefill_chunk(
+                _, _, self.pool.draft_cache, _ = _serve_prefill_chunk(
                     self.draft_config,
                     self.draft_params,
                     chunk,
                     start_a,
                     n_valid_a,
-                    self.draft_cache,
+                    self.pool.draft_cache,
                     table,
                     self.mesh,
                     self.attn_impl,
@@ -2700,7 +2462,7 @@ class ServeEngine:
         for r, (slot_i, slot, n) in enumerate(rows):
             slot.prompt_pos += n
             slot.length = slot.prompt_pos
-            self._reclaim_window(slot)  # long prompts free behind-window pages
+            self.pool.reclaim(slot)  # long prompts free behind-window pages
             self.prefilled_tokens += n
             self.prefill_chunks += 1
             if slot.prefilling:
@@ -2815,7 +2577,7 @@ class ServeEngine:
         token, lengths, active, round_span = self._decode_args(active_idx, n)
         logits, self.cache = _serve_decode_logits(
             self.config, self.params, token, self.cache,
-            self._device_tables(self._page_bucket(round_span)), lengths,
+            self.pool.tables(self.slots, self._page_bucket(round_span)), lengths,
             active, self.attn_impl, self.mesh, self._split_bucket(round_span),
         )
         logits = np.asarray(logits, np.float32)
@@ -2845,7 +2607,7 @@ class ServeEngine:
         t_a = 0.0 if obs is None else self._clock()
         key = self._sampling_key()
         t_k = None if key is None or obs is None else self._clock()
-        tables = self._device_tables(bucket)
+        tables = self.pool.tables(self.slots, bucket)
         t_p = 0.0 if obs is None else self._clock()
         self.cache, toks, self._key = _serve_decode_chunk(
             self.config,
@@ -2929,15 +2691,7 @@ class ServeEngine:
             self._decode_round()
             return
         k = 1 << (budget.bit_length() - 1)  # largest power of two <= budget
-        for i in list(active_idx):
-            slot = self.slots[i]
-            if slot is None:
-                # evicted by an older slot's page growth earlier in this loop
-                active_idx.remove(i)
-                continue
-            if not self._ensure_pages(slot, slot.length + k + 1):
-                active_idx.remove(i)  # pool held by older slots; wait
-        active_idx = [i for i in active_idx if self.slots[i] is not None]
+        active_idx = self._decode_pages(active_idx, k + 1)
         if not active_idx:
             return
 
@@ -2946,18 +2700,10 @@ class ServeEngine:
         # with draft/verify enqueue sub-spans recorded off the same reads.
         obs = self.obs
         t0 = 0.0 if obs is None else self._clock()
-        token = np.zeros((self.max_slots,), np.int32)
-        lengths = np.zeros((self.max_slots,), np.int32)
-        active = np.zeros((self.max_slots,), bool)
-        for i in active_idx:
-            s = self.slots[i]
-            token[i] = s.generated[-1] if s.generated else s.request.prompt[-1]
-            lengths[i] = s.length
-            active[i] = True
-        round_span = max(self.slots[i].length for i in active_idx) + k + 1
+        token, lengths, active, round_span = self._decode_args(active_idx, k + 1)
         bucket = self._page_bucket(round_span)
         split_k = self._split_bucket(round_span)
-        table = self._page_table(bucket)
+        table = self.pool.table(self.slots, bucket)
         # drafts/draft_probs stay on device between the two dispatches —
         # the host only ever reads the small (B,) / (B, k+1) verify outputs.
         # With a prefix self-draft the draft steps run against the TARGET
@@ -2966,7 +2712,7 @@ class ServeEngine:
         # columns written at the prefix layers) feeds verify, which
         # rewrites those columns with the identical values.
         shared = self.draft_shares_cache
-        draft_cache_in = self.cache if shared else self.draft_cache
+        draft_cache_in = self.cache if shared else self.pool.draft_cache
         # The draft program makes the round's three-way key split: it hands
         # back the engine's next key and the verify program's, on the device.
         draft_cache_out, drafts, draft_probs, self._key, key_v = _spec_draft_chunk(
@@ -2990,7 +2736,7 @@ class ServeEngine:
         if shared:
             self.cache = draft_cache_out
         else:
-            self.draft_cache = draft_cache_out
+            self.pool.draft_cache = draft_cache_out
         self.cache, n_accept, out = _spec_verify_chunk(
             self.config,
             self.params,
@@ -3056,7 +2802,7 @@ class ServeEngine:
             if len(slot.pages[0]) > keep:
                 tail = slot.pages[0][keep:]
                 del slot.pages[0][keep:]
-                self.allocator.free(tail)
+                self.pool.free(0, tail)
         if obs is not None:
             obs.record_round(
                 "spec", self._obs_tid, t0, t1, t_done, self._clock()
@@ -3129,7 +2875,7 @@ class ServeEngine:
     def _append_token(self, slot_i: int, slot: _Slot, tok: int, t: float) -> bool:
         """Record one generated token; returns True if the request finished
         (and the slot was freed)."""
-        self._reclaim_window(slot)  # no-op unless config.sliding_window
+        self.pool.reclaim(slot)  # no-op unless a kind has a window
         slot.generated.append(tok)
         slot.token_times.append(t)
         req = slot.request
@@ -3157,17 +2903,6 @@ class ServeEngine:
                 )
         hit_eos = req.eos_id is not None and tok == req.eos_id
         if hit_eos or len(slot.generated) >= req.max_new_tokens:
-            self._finish(
-                FinishedRequest(
-                    uid=req.uid,
-                    tokens=np.concatenate(
-                        [req.prompt, np.asarray(slot.generated, np.int32)]
-                    ),
-                    token_times=slot.token_times,
-                ),
-                t,
-            )
-            self._release_slot(slot)
-            self.slots[slot_i] = None
+            self._retire(slot_i, slot, "ok", t)
             return True
         return False

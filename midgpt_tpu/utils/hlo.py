@@ -196,6 +196,49 @@ def collective_census(txt: str) -> tp.List[tp.Tuple[str, str, int]]:
     return census
 
 
+def jit_cache_size(fn: tp.Any) -> tp.Optional[int]:
+    """Compiled-program count in a jit wrapper's cache (None if the jax
+    version does not expose it). One entry per distinct (static args,
+    input avals) combination that actually lowered + compiled."""
+    probe = getattr(fn, "_cache_size", None)
+    return probe() if callable(probe) else None
+
+
+_RELAYOUT_RE = re.compile(
+    r"= \(?[a-z]+[0-9]*\[([0-9,]*)\][^=\n]*? (?:copy|copy-start|transpose)\("
+)
+
+
+def pool_relayouts(
+    hlo_text: str, pool_shapes: tp.Iterable[tp.Sequence[int]]
+) -> int:
+    """Copies and transposes in a compiled program whose result is as large
+    as a KV-pool buffer or ONE LAYER of it: the TPU layout census of the
+    serving programs (PagedKVCache "Layout contract").
+
+    `pool_shapes` are the logical shapes of the pool's leaves ((L, H, P,
+    ps, C) pools, (L, P, H, ps) int8 scale buffers); a result counts when
+    its dims are a leaf's, or a leaf's without its layer dim (with or
+    without a unit dim in its place), in any dtype and layout. Every
+    computation of the module is read, so a relayout fused into a `fusion`
+    is counted by the `copy`/`transpose` inside it; an entry PARAMETER is a
+    parameter, not a copy, and is not counted. What it finds, on the chip's
+    compiler: a program that scatters into the pool relays it out on entry
+    and on exit (2 per pool tensor) and copies one layer per tensor per
+    layer for the attention custom call, 4 + 2L for a bf16 pool (PR 25);
+    one that keeps the contract reads 0. On other backends the number is
+    whatever that backend's lowering does and pins nothing."""
+    wanted = set()
+    for shape in pool_shapes:
+        dims = tuple(int(d) for d in shape)
+        wanted |= {dims, dims[1:], (1,) + dims[1:]}
+    return sum(
+        1
+        for m in _RELAYOUT_RE.finditer(hlo_text)
+        if tuple(int(d) for d in m.group(1).split(",") if d) in wanted
+    )
+
+
 def lower_abstract_train_step(config, mesh=None, eval_program=False):
     """Lower the full training step against ABSTRACT sharded inputs — or,
     with `eval_program`, the batched eval the train loop runs beside it

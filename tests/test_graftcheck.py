@@ -431,6 +431,27 @@ def adopt(allocator, slot, n):
     assert active == []
 
 
+def test_gc009_follows_the_page_pool_by_name():
+    """`pool.alloc(kind, n)` / `pool.free(kind, pages)` (sampling/pages.py
+    PagePool) are acquisition and release sites like the allocator's own."""
+    src = """\
+def leak(self, n):
+    got = self.pool.alloc(0, n)
+    if got is None:
+        return False
+    return True
+
+def fine(eng, trie, n):
+    got = eng.pool.alloc(0, n)
+    if got is None:
+        eng.pool.free(0, trie.evict(n))
+        return
+    eng.pool.free(0, got)
+"""
+    active, _ = check_source(src, "pool.py")
+    assert [(f.rule, f.line) for f in active] == [("GC009", 5)]
+
+
 def test_gc009_refs_protocol():
     trie_src = """\
 class _Node:

@@ -88,3 +88,32 @@ def test_default_roots_exclude_tests():
     never pull them in (it would make the clean gate unsatisfiable)."""
     for path in iter_python_files(_default_paths()):
         assert os.sep + "tests" + os.sep not in path, path
+
+
+def test_the_serving_stack_imports_point_one_way():
+    """sampling/pages.py sits under sampling/serve.py, and both under what is
+    built on the engine (the topology layers, the static analyser): an import
+    of one of those, at module level or inside a function, puts a cycle back."""
+    import ast
+
+    def imported(rel):
+        with open(os.path.join(_repo_root(), rel), encoding="utf-8") as fh:
+            tree = ast.parse(fh.read())
+        names = set()
+        for node in ast.walk(tree):  # every depth: a function-level import is an import
+            if isinstance(node, ast.Import):
+                names |= {a.name for a in node.names}
+            elif isinstance(node, ast.ImportFrom) and node.module:
+                names |= {node.module} | {f"{node.module}.{a.name}" for a in node.names}
+        return names
+
+    above = ("midgpt_tpu.sampling.disagg", "midgpt_tpu.sampling.fleet", "midgpt_tpu.sampling.fleet_proc",
+             "midgpt_tpu.analysis")
+    for rel, banned in (
+        ("midgpt_tpu/sampling/pages.py", above + ("midgpt_tpu.sampling.serve",)),
+        ("midgpt_tpu/sampling/serve.py", above),
+    ):
+        names = imported(rel)
+        assert len(names) > 5, rel
+        bad = sorted(n for n in names if any(n == b or n.startswith(b + ".") for b in banned))
+        assert not bad, f"{rel} imports {bad}"

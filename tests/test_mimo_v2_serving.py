@@ -37,9 +37,7 @@ def _greedy(c, params, prompt, n):
 
 
 def _conserved(eng):
-    for k, a in enumerate(eng.allocators):
-        live = sum(p >= 0 for s in eng.slots if s is not None for p in s.pages[k])
-        assert a.free_count + live == a.num_pages - 1, (eng.kinds[k].name, a.free_count, live)
+    assert len(eng.allocators) == 2 and eng.pool.conserved(eng.slots), eng.pool.ledger(eng.slots)
 
 
 @pytest.mark.parametrize("overlap", ["off", "group"])

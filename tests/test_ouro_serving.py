@@ -32,9 +32,7 @@ def _greedy(c, params, prompt, n):
 
 
 def _conserved(eng):
-    (a,) = eng.allocators
-    live = sum(p >= 0 for s in eng.slots if s is not None for p in s.pages[0])
-    assert a.free_count + live == a.num_pages - 1, (a.free_count, live)
+    assert len(eng.allocators) == 1 and eng.pool.conserved(eng.slots), eng.pool.ledger(eng.slots)
 
 
 @pytest.mark.parametrize("overlap", ["off", "group"])

@@ -12,8 +12,9 @@ PARENT tree of PR 32 (commit 9047794, where `_prefill_round` calls
 (one process a case, as the test runs them; the two lines merged into one object).
 
 It holds, for a ramp of five requests over three slots on a pool short enough
-to evict, (a) the sequence of `_ensure_pages` / `_device_tables` /
-`_serve_prefill_chunk` / `_reclaim_window` calls inside `_prefill_round`, each
+to evict, (a) the sequence of `_ensure_pages` / `pool.tables` (recorded as
+`device_tables`) / `_serve_prefill_chunk` / `pool.reclaim` (`reclaim_window`)
+calls inside `_prefill_round`, each
 program argument with its shape, dtype and weak type, and (b) how many
 jax.monitoring trace / lower / compile events the whole process fired, by
 function name. Two engines are of width 1: a `mimo_v2` toy (the family takes
@@ -118,8 +119,9 @@ def record(case: str) -> dict:
         return wrapped
 
     eng._ensure_pages = hook("ensure_pages", eng._ensure_pages, lambda slot, upto: [slot_of(slot), upto])
-    eng._reclaim_window = hook("reclaim_window", eng._reclaim_window, lambda slot: [slot_of(slot)])
-    eng._device_tables = hook("device_tables", eng._device_tables, lambda n_pages, *rows: [n_pages])
+    # the golden's names for what sampling/pages.py owns since PR 45
+    eng.pool.reclaim = hook("reclaim_window", eng.pool.reclaim, lambda slot: [slot_of(slot)])
+    eng.pool.tables = hook("device_tables", eng.pool.tables, lambda slots, n_pages, *rows: [n_pages])
     real_chunk, real_round = serve._serve_prefill_chunk, eng._prefill_round
 
     def chunk(config, p, tokens, start, n_valid, cache, table, mesh, attn_impl, temperature, top_k, top_p, key):

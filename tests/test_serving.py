@@ -9,7 +9,8 @@ import pytest
 
 from midgpt_tpu.models.gpt import GPT, GPTConfig
 from midgpt_tpu.sampling.engine import generate
-from midgpt_tpu.sampling.serve import PageAllocator, ServeEngine
+from midgpt_tpu.sampling.pages import PageAllocator
+from midgpt_tpu.sampling.serve import ServeEngine
 
 CFG = GPTConfig(block_size=64, vocab_size=96, n_layer=2, n_head=2, n_embd=32)
 
