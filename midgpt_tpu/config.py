@@ -487,6 +487,7 @@ MODEL_FAMILIES: tp.Dict[str, str] = {
     "mimo_v2": "midgpt_tpu.models.mimo_v2:MimoV2Config",
     "pangu_ultra": "midgpt_tpu.models.pangu_ultra:PanguUltraConfig",
     "ouro": "midgpt_tpu.models.ouro:OuroConfig",
+    "afmoe": "midgpt_tpu.models.trinity:TrinityConfig",
 }
 
 

@@ -5,7 +5,7 @@ here, and call each without asking whether it is there. Families are
 registered in `midgpt_tpu/config.py` `MODEL_FAMILIES`.
 
 The config (`GPTConfig`, `KimiLinearConfig`, `MimoV2Config`, `PanguUltraConfig`,
-`OuroConfig`):
+`OuroConfig`, `TrinityConfig`):
 
     block_size, vocab_size, n_layer, n_head, n_embd   fields, under these names
                                (`n_layer`: layers of WEIGHTS; how many layers
@@ -21,7 +21,7 @@ The config (`GPTConfig`, `KimiLinearConfig`, `MimoV2Config`, `PanguUltraConfig`,
                                stack (sample.py, ServeEngine) holds no cache
                                for this family; returns None where it does
 
-The namespace (`GPT`, `KimiLinear`, `MimoV2`, `PanguUltra`, `Ouro`):
+The namespace (`GPT`, `KimiLinear`, `MimoV2`, `PanguUltra`, `Ouro`, `Trinity`):
 
     init(config, key) -> params
     hidden(config, params, tokens, *, key, inference, attn_fn) -> (B, T, D)
@@ -40,8 +40,8 @@ The namespace (`GPT`, `KimiLinear`, `MimoV2`, `PanguUltra`, `Ouro`):
                                counters the train loop logs at a logged step
 
 The SERVING members, of every family whose `check_serving` returns None
-(`GPT`, `MimoV2`, `PanguUltra`, `Ouro`; sampling/serve.py calls them, never a
-family by name):
+(`GPT`, `MimoV2`, `PanguUltra`, `Ouro`, `Trinity`; sampling/serve.py calls them,
+never a family by name):
 
     cache_kinds(config) -> (CacheKind(name, window, sinks), ...)
                                the kinds of paged cache the layers need, the
@@ -50,8 +50,10 @@ family by name):
                                > 0: a page is freed once every future query's
                                window has passed it (0: it lives as long as
                                its request); `sinks`: leading tokens never
-                               freed. The GPT: one kind. MimoV2: `global`
-                               (window 0) and `window`. PanguUltra: one kind,
+                               freed. The GPT: one kind. MimoV2 and Trinity:
+                               `global` (window 0) and `window` (128; 2,048),
+                               held in one cache class (`MimoKVCache`).
+                               PanguUltra: one kind,
                                `latent`, whose pool row is not K beside V of
                                (heads, head_dim) but a token's LATENT, stored
                                once (below). Ouro: one kind, `looped`.
@@ -112,8 +114,10 @@ family by name):
                                engine's block counters
     serve_counters             None, or (config, cache) -> {counter: number}
                                the family's own counters kept in the cache
-                               (MimoV2, PanguUltra: the expert layers', through
-                               ops/moe.py's shared helpers; PanguUltra also the
+                               (MimoV2, PanguUltra, Trinity: the expert
+                               layers', through ops/moe.py's shared helpers;
+                               Trinity also the expert bytes its decode steps
+                               had to read and the windowed kernel's grid; PanguUltra also the
                                pool's bytes a token; Ouro: decode steps, passes
                                run, the exit gate's distribution summed over
                                decoded tokens, the pools' bytes a token over

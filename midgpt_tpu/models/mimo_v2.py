@@ -48,7 +48,17 @@ Attention on the paged path:
                    window touches, on every backend: 128 keys are five pages,
                    and the template's grid would still step over the whole
                    table's blocks (~0.3 us each) to find them. The sink term
-                   is added to the denominator here.
+                   is added to the denominator here. The gathered copy grows
+                   with the window (slots x pages x heads x K and V, written
+                   and read again) and the grid's dead steps do not: by that
+                   arithmetic the gather stops being the right choice at a
+                   few hundred keys (~16 pages of 32 at 4 heads of 128 lanes).
+                   `models/trinity.py` (window 2,048 = 64 pages, where a
+                   gather would copy 277 MB a layer a step for 64 slots) takes
+                   the template with `sliding_window`: 0.50 ms a layer call
+                   for 61 live slots, 56 % of its roofline with 84 % of its
+                   grid steps dead (PERF.md section 6 PR 46; the gather was
+                   not measured there, nor the kernel here).
   prefill chunk    XLA: the window layers gather the pages that [start - W,
                    start + chunk) touches; the global layers sweep the context
                    in blocks of keys with an online softmax (a 512 x 16k score
@@ -254,7 +264,8 @@ class MimoKVCache:
     width and V at the v width, page 0 of each the sink. `moe_counts` and
     `moe_totals` are the expert layers' counters, summed on the device by the
     decode steps that donate this pytree and read by `MimoV2.serve_counters`
-    when somebody asks: no decode round syncs for them."""
+    when somebody asks: no decode round syncs for them. The cache of every
+    family with these two kinds of K/V pool (models/trinity.py holds it too)."""
 
     gk: Array
     gv: Array
@@ -320,6 +331,63 @@ def _gather_pages(pool: Array, li: int, ids: Array, width: int) -> Array:
     g = pool[li, :, ids][..., :width]  # (..., n, H, ps, width): advanced dims lead
     g = jnp.moveaxis(g, -3, -4)  # (..., H, n, ps, width)
     return g.reshape(*g.shape[:-3], g.shape[-3] * g.shape[-2], width)
+
+
+def paged_gather_attention(q: Array, k_pool: Array, v_pool: Array, li: int, ids: Array, col0: Array, counts: Array,
+                           *, n_kv: int, dv: int, window: int, sink: tp.Optional[Array] = None) -> Array:
+    """XLA gather attention of query rows against paged keys (every family
+    with K/V pools of this layout: models/trinity.py too). q (B, R, H, dq);
+    `ids` (B, n) the pages gathered, whose first column is position `col0`
+    (B,); row r of slot b sees `counts[b, r]` keys, the last `window` of them
+    where `window` > 0; `sink` (H,): a logit added to the softmax's denominator
+    only. -> (B, R, H * dv)."""
+    B, R, H, dq = q.shape
+    G = H // n_kv
+    kg = _gather_pages(k_pool, li, ids, dq)  # (B, n_kv, S, dq)
+    vg = _gather_pages(v_pool, li, ids, dv)
+    s = jnp.einsum("brkgc,bksc->bkgrs", q.reshape(B, R, n_kv, G, dq).astype(kg.dtype), kg)
+    s = s.astype(jnp.float32) / math.sqrt(dq)
+    col = col0[:, None] + jnp.arange(kg.shape[2], dtype=jnp.int32)  # (B, S)
+    keep = visible_mask(col[:, None, None, None, :], counts[:, None, None, :, None], window)
+    sink = None if sink is None else sink.astype(jnp.float32).reshape(n_kv, G)[None, :, :, None]
+    prob = _softmax_sink(s, keep, sink).astype(vg.dtype)
+    return jnp.einsum("bkgrs,bksc->brkgc", prob, vg).reshape(B, R, H * dv)
+
+
+def prefill_sweep(q: Array, k_pool: Array, v_pool: Array, li: int, table_row: Array, counts: Array,
+                  *, n_kv: int, dv: int, sink: tp.Optional[Array] = None) -> Array:
+    """A chunk's rows q (T, H, dq) against the slot's whole context, in
+    blocks of `PREFILL_KEY_BLOCK` keys with an online softmax; the loop runs
+    over the blocks that hold a visible key, not over the table. Row t sees
+    `counts[t]` keys. -> (T, H * dv)."""
+    T, H, dq = q.shape
+    G, ps, MP = H // n_kv, k_pool.shape[3], table_row.shape[0]
+    kp = max(1, min(MP, PREFILL_KEY_BLOCK // ps))  # pages a block
+    qg = q.reshape(T, n_kv, G, dq)
+
+    def body(b, carry):
+        m, l, acc = carry
+        page = b * kp + jnp.arange(kp, dtype=jnp.int32)
+        ids = jnp.take(table_row, jnp.minimum(page, MP - 1), axis=0)  # past the table: masked (col >= any count)
+        kg = _gather_pages(k_pool, li, ids, dq)  # (n_kv, kp * ps, dq)
+        vg = _gather_pages(v_pool, li, ids, dv)
+        s = jnp.einsum("tkgc,ksc->kgts", qg.astype(kg.dtype), kg).astype(jnp.float32) / math.sqrt(dq)
+        col = b * (kp * ps) + jnp.arange(kp * ps, dtype=jnp.int32)
+        s = jnp.where(col[None, None, None, :] < counts[None, None, :, None], s, MASK)
+        m, alpha, prob, l = online_block(m, l, s)
+        pv = jnp.einsum("kgts,ksc->kgtc", prob.astype(vg.dtype), vg).astype(jnp.float32)
+        return m, l, acc * alpha[..., None] + pv
+
+    init = (jnp.full((n_kv, G, T), M_INIT, jnp.float32), jnp.zeros((n_kv, G, T), jnp.float32),
+            jnp.zeros((n_kv, G, T, dv), jnp.float32))
+    n_live = (counts[-1] + kp * ps - 1) // (kp * ps)
+    m, l, acc = jax.lax.fori_loop(0, n_live, body, init)
+    if sink is not None:  # a global kind with a sink bias: one more term in the denominator
+        sink = sink.astype(jnp.float32).reshape(n_kv, G)[:, :, None]
+        m_new = jnp.maximum(m, sink)
+        l, acc, m = l * jnp.exp(m - m_new) + jnp.exp(sink - m_new), acc * jnp.exp(m - m_new)[..., None], m_new
+    out, _ = finalize(m, l, acc)
+    return jnp.transpose(out, (2, 0, 1, 3)).reshape(T, H * dv)
 
 
 class MimoV2:
@@ -539,22 +607,9 @@ class MimoV2:
     @staticmethod
     def _paged_attention(c: MimoV2Config, kind: str, p: AttnParams, q: Array, k_pool: Array, v_pool: Array,
                          li: int, ids: Array, col0: Array, counts: Array) -> Array:
-        """XLA gather attention of query rows against paged keys. q (B, R, H,
-        dq); `ids` (B, n) the pages gathered, whose first column is position
-        `col0` (B,); row r of slot b sees `counts[b, r]` keys (the window and
-        the sink term by the layer's kind). -> (B, R, H * dv)."""
-        n_kv, dq, dv, _, window = c.attn_geometry(kind)
-        B, R, H, _ = q.shape
-        G = H // n_kv
-        kg = _gather_pages(k_pool, li, ids, dq)  # (B, n_kv, S, dq)
-        vg = _gather_pages(v_pool, li, ids, dv)
-        s = jnp.einsum("brkgc,bksc->bkgrs", q.reshape(B, R, n_kv, G, dq).astype(kg.dtype), kg)
-        s = s.astype(jnp.float32) / math.sqrt(dq)
-        col = col0[:, None] + jnp.arange(kg.shape[2], dtype=jnp.int32)  # (B, S)
-        keep = visible_mask(col[:, None, None, None, :], counts[:, None, None, :, None], window)
-        sink = None if p.sink is None else p.sink.astype(jnp.float32).reshape(n_kv, G)[None, :, :, None]
-        prob = _softmax_sink(s, keep, sink).astype(vg.dtype)
-        return jnp.einsum("bkgrs,bksc->brkgc", prob, vg).reshape(B, R, H * dv)
+        """`paged_gather_attention` at the layer kind's geometry (the window and the sink term by the kind)."""
+        n_kv, _, dv, _, window = c.attn_geometry(kind)
+        return paged_gather_attention(q, k_pool, v_pool, li, ids, col0, counts, n_kv=n_kv, dv=dv, window=window, sink=p.sink)
 
     @staticmethod
     def decode_step_paged(config: MimoV2Config, params: MimoV2Params, token: Array, cache: MimoKVCache,
@@ -682,36 +737,6 @@ class MimoV2:
     @staticmethod
     def _prefill_sweep(c: MimoV2Config, kind: str, p: AttnParams, q: Array, k_pool: Array, v_pool: Array,
                        li: int, table_row: Array, counts: Array) -> Array:
-        """A chunk's rows q (T, H, dq) against the slot's whole context, in
-        blocks of `PREFILL_KEY_BLOCK` keys with an online softmax; the loop runs
-        over the blocks that hold a visible key, not over the table. Row t sees
-        `counts[t]` keys. -> (T, H * dv)."""
-        n_kv, dq, dv, _, _ = c.attn_geometry(kind)
-        T, H, _ = q.shape
-        G, ps, MP = H // n_kv, k_pool.shape[3], table_row.shape[0]
-        kp = max(1, min(MP, PREFILL_KEY_BLOCK // ps))  # pages a block
-        qg = q.reshape(T, n_kv, G, dq)
-
-        def body(b, carry):
-            m, l, acc = carry
-            page = b * kp + jnp.arange(kp, dtype=jnp.int32)
-            ids = jnp.take(table_row, jnp.minimum(page, MP - 1), axis=0)  # past the table: masked (col >= any count)
-            kg = _gather_pages(k_pool, li, ids, dq)  # (n_kv, kp * ps, dq)
-            vg = _gather_pages(v_pool, li, ids, dv)
-            s = jnp.einsum("tkgc,ksc->kgts", qg.astype(kg.dtype), kg).astype(jnp.float32) / math.sqrt(dq)
-            col = b * (kp * ps) + jnp.arange(kp * ps, dtype=jnp.int32)
-            s = jnp.where(col[None, None, None, :] < counts[None, None, :, None], s, MASK)
-            m, alpha, prob, l = online_block(m, l, s)
-            pv = jnp.einsum("kgts,ksc->kgtc", prob.astype(vg.dtype), vg).astype(jnp.float32)
-            return m, l, acc * alpha[..., None] + pv
-
-        init = (jnp.full((n_kv, G, T), M_INIT, jnp.float32), jnp.zeros((n_kv, G, T), jnp.float32),
-                jnp.zeros((n_kv, G, T, dv), jnp.float32))
-        n_live = (counts[-1] + kp * ps - 1) // (kp * ps)
-        m, l, acc = jax.lax.fori_loop(0, n_live, body, init)
-        if p.sink is not None:  # a global kind with a sink bias: one more term in the denominator
-            sink = p.sink.astype(jnp.float32).reshape(n_kv, G)[:, :, None]
-            m_new = jnp.maximum(m, sink)
-            l, acc, m = l * jnp.exp(m - m_new) + jnp.exp(sink - m_new), acc * jnp.exp(m - m_new)[..., None], m_new
-        out, _ = finalize(m, l, acc)
-        return jnp.transpose(out, (2, 0, 1, 3)).reshape(T, H * dv)
+        """`prefill_sweep` at the layer kind's geometry."""
+        n_kv, _, dv, _, _ = c.attn_geometry(kind)
+        return prefill_sweep(q, k_pool, v_pool, li, table_row, counts, n_kv=n_kv, dv=dv, sink=p.sink)

@@ -910,6 +910,46 @@ def test_two_kind_serving_program_never_relays_out_either_pool(program, one_chip
 
 
 @pytest.mark.parametrize("program", ["decode8", "prefill512"])
+def test_window_layers_decode_through_the_kernel_at_the_published_geometry(program, one_chip, compiled_kernels):
+    """models/trinity.py as the benchmark's cell runs it (5 layers, 128 experts,
+    32 q heads over 4 K/V heads of 128, window 2,048, pages of 32, 64 slots, a
+    table of 512 pages, split-K 8 for the global layer): in the decode program
+    BOTH kinds' attention are Mosaic custom calls (four under `attn_window`: no
+    gathered copy of the window's pages; one under `attn_global`) beside the
+    five in-place writes, the prefill program has the five writes alone, and
+    neither copies or relays out a pool."""
+    import dataclasses
+    import re
+
+    from midgpt_tpu.analysis.hlo_audit import pool_relayouts
+    from midgpt_tpu.config import load_config
+    from midgpt_tpu.sampling import serve
+
+    mc = dataclasses.replace(load_config("trinity_mini").model_config, n_layer=5, n_dense_layers=1)
+    model = mc.model()
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(sds, jax.eval_shape(lambda k: model.cast_params(model.init(mc, k), jnp.bfloat16), jax.random.PRNGKey(0)))
+    cache = jax.tree.map(sds, jax.eval_shape(lambda: model.init_cache(mc, (20481, 5185), 32, jnp.bfloat16, kernel_layout=True)))
+    assert cache.gk.shape == (1, 4, 20481, 32, 128) and cache.wk.shape == (4, 4, 5185, 32, 128)
+    arr = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    B, T = 64, 512
+    if program == "decode8":
+        lowered = serve._serve_decode_chunk.lower(
+            mc, params, arr((B,)), cache, (arr((B, T)), arr((B, T))), arr((B,)), arr((B,), jnp.bool_), 8,
+            0.8, None, None, "kernel", arr((2,), jnp.uint32), None, 8)
+    else:
+        lowered = serve._serve_prefill_chunk.lower(
+            mc, params, arr((1, 512)), arr(()), arr(()), cache, (arr((1, T)), arr((1, T))), None, "kernel",
+            0.8, None, None, arr((2,), jnp.uint32))
+    text = lowered.compile().as_text()
+    paths = re.findall(r'custom-call\([^\n]*tpu_custom_call[^\n]*?op_name="([^"]*)"', text)
+    attention = [p for p in paths if "kv_write" not in p]
+    assert sum("kv_write" in p for p in paths) == 5
+    assert sorted(p.split("/")[-2] for p in attention) == (["attn_global"] + ["attn_window"] * 4 if program == "decode8" else [])
+    assert pool_relayouts(text, [a.shape for a in cache.pool_arrays()]) == 0
+
+
+@pytest.mark.parametrize("program", ["decode8", "prefill512"])
 def test_latent_serving_program_compiles_and_never_relays_out_the_pool(program, one_chip, compiled_kernels):
     """models/pangu_ultra.py at its published widths (hidden 7,680, 128 heads,
     q_lora 1,536, kv_lora 512 + rope 64; one dense and one expert layer, two

@@ -228,7 +228,8 @@ def test_benchmark_declares_the_cell_and_only_adds():
         bench = json.load(f)
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("ouro_2p6b", "reason_closed", 1)
-    assert bench["workloads"][-1] is cell and bench["configs"][-1]["name"] == "ouro_2p6b" and bench["configs"][-1]["reduced"] == []
+    # the eighth cell and the sixth configuration: later PRs append after them, and edit neither
+    assert bench["workloads"][7] is cell and bench["configs"][5]["name"] == "ouro_2p6b" and bench["configs"][5]["reduced"] == []
     declared = {m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", [])}
     own = {"serve.loop_attn_ms", "serve.loop_mlp_ms", "serve.exit_gate_ms", "serve.lm_head_ms", "serve.weight_read_share",
            "loop.exit_pass_expected", "kv.looped_pool_fill", "kv.looped_bytes_per_token",
@@ -236,9 +237,11 @@ def test_benchmark_declares_the_cell_and_only_adds():
     assert own | {"serve.model_unattributed_ms", "kv_write_ms_per_token", "kv_write_roofline", "engine.occupancy",
                   "setup.programs", "setup.trace_lower_s", "window.compiles", "serve.device_idle_share"} <= declared
     assert not {n for n in declared if "moe" in n or "latent" in n or "global" in n or "window_" in n or n.startswith("paged_attention")}
+    later = {"serve.lm_head_ms", "serve.weight_read_share"}  # PR 46's cell reports under these names too, appended after this one
     for m in bench["per_layer"]:
         if m["name"] in own:
-            assert m["workloads"] == [CELL] and m["moves"] == "serve_tokens_per_s"
+            assert m["workloads"][0] == CELL and m["moves"] == "serve_tokens_per_s"
+            assert m["workloads"] == [CELL] or m["name"] in later
     e2e = {m["name"] for m in bench["end_to_end"] if CELL in m.get("workloads", [CELL])}
     assert e2e == {"setup_s", "serve_tokens_per_s"}
 

@@ -851,6 +851,12 @@ def test_setup_programs_reader(monkeypatch):
     ("train_124m", {"train.feed_ms_p50", "setup.compile_or_load_s", "setup.trace_lower_s", "setup.programs",
                     "step.attn_ms", "step.mlp_ms", "step.lm_head_loss_ms", "step.optimizer_ms",
                     "step.unattributed_ms"}),
+    # PR 46: a family with kinds of attention layer, read by the configuration's `metrics` group
+    ("serve_trinity_mini_reason", {"serve.attn_global_ms", "serve.attn_window_ms", "serve.attn_gate_ms",
+                                   "serve.moe_route_ms", "serve.moe_experts_ms", "serve.moe_shared_ms",
+                                   "serve.lm_head_ms", "serve.model_unattributed_ms", "serve.moe_experts_touched",
+                                   "serve.moe_load_max_over_mean", "kv.global_pool_fill",
+                                   "kv.window_tokens_per_slot_max", "engine.occupancy", "setup.programs"}),
 ])
 def test_rehearsal_lists_the_new_metrics(cell, names, tmp_path):
     env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"), JAX_PLATFORMS="cpu")
@@ -897,3 +903,45 @@ def test_decode_round_records_the_kernels_blocks(params, monkeypatch):
     assert snap["counters"]["decode.blocks_live"] == 8 + 3 + 1
     assert snap["gauges"]["decode.live_block_share"] == 0.5
     assert "decode_blocks_live 12" in obs.metrics.to_prometheus()  # the .prom beside a dump
+
+
+# ---------------------------------------------------------------------------
+# PR 46: the readers of a family with kinds of attention layer
+# ---------------------------------------------------------------------------
+
+KINDS_TEXT = """
+  %attn_global.10 = (f32[8,4]{1,0:T(8,128)S(1)}, f32[8,1]{1,0}) custom-call(%q, %pool), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/while/body/attn/attn_global/pallas_call"}
+  %pallas_call.38 = f32[8,4]{1,0:T(8,128)S(1)} get-tuple-element(%attn_global.10), index=0, metadata={op_name="jit(f)/while/body/attn/attn_global/pallas_call"}
+  %bitcast.7 = f32[2,4,4]{2,1,0} bitcast(%pallas_call.38)
+  %multiply_reduce_fusion.19 = f32[4]{0} fusion(%bitcast.7), kind=kLoop, calls=%fused.1, metadata={op_name="jit(f)/while/body/attn/attn_global/reduce_sum"}
+  %divide_convert_fusion.7 = bf16[4]{0} fusion(%multiply_reduce_fusion.19), kind=kLoop, calls=%fused.2, metadata={op_name="jit(f)/while/body/attn/attn_global/convert_element_type"}
+  %fusion.9 = bf16[4]{0} fusion(%divide_convert_fusion.7, %w), kind=kLoop, calls=%fused.3, metadata={op_name="jit(f)/while/body/attn/attn_global/attn_gate/mul"}
+  %fusion.10 = f32[4]{0} fusion(%pallas_call.38), kind=kLoop, calls=%fused.4, metadata={op_name="jit(f)/while/body/mlp/moe_route/add"}
+  %attn_window.40 = bf16[8,4]{1,0} custom-call(%q, %pool), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/while/body/attn/attn_window/pallas_call"}
+  %fusion.11 = bf16[4]{0} fusion(%attn_window.40), kind=kLoop, calls=%fused.5, metadata={op_name="jit(f)/while/body/attn/attn_window/mul"}
+"""
+
+
+def test_a_split_kernels_merge_is_found_after_it_and_nothing_else_is():
+    """serve_kinds_scopes.combine_of: from a kernel that gives float32
+    partials, along its users while float32 flows, inside its own scope, up to
+    the cast back; a kernel that finalizes in itself (the stream's type) has
+    none; an op of another scope that reads the partials is not the merge."""
+    kinds = _reader("serve_kinds_scopes.py")
+    scope_of = {"attn_global.10": "attn_global", "pallas_call.38": "attn_global", "multiply_reduce_fusion.19": "attn_global",
+                "divide_convert_fusion.7": "attn_global", "fusion.9": "attn_gate", "fusion.10": "moe_route",
+                "attn_window.40": "attn_window", "fusion.11": "attn_window"}
+    got = kinds.combine_of(KINDS_TEXT, {"attn_global.10": "attn_global", "attn_window.40": "attn_window"}, scope_of)
+    assert got == {k: "attn_global" for k in ("pallas_call.38", "bitcast.7", "multiply_reduce_fusion.19", "divide_convert_fusion.7")}
+
+
+def test_the_trace_coverage_line_says_where_the_trace_has_no_ops():
+    kinds = _reader("serve_kinds_scopes.py")
+    run = _run_dict("serve")
+    ms = 1_000_000
+    mods = [(0, 40 * ms, "jit__serve_decode_chunk(1)"), (50 * ms, 90 * ms, "jit__serve_prefill_chunk(2)")]
+    by_prog = {"decode": [[0, 0, 10 * ms], [1, 30 * ms, 10 * ms]], "prefill": [[2, 50 * ms, 40 * ms]], "other": [[3, 95 * ms, ms]]}
+    kinds._coverage(run, {"lo": 0, "hi": 100 * ms}, {"name": "/device:TPU:0"}, mods, by_prog, {"prefill": "_serve_prefill_chunk", "decode": "_serve_decode_chunk"})
+    (line,) = run["logs"]
+    assert "4 op events" in line and "decode 1 (40 ms; ops 20 ms" in line and "prefill 1 (40 ms" in line
+    assert "4 stretches over 2 ms with no op, 39 ms in all" in line and "20.0@10" in line
