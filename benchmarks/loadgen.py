@@ -163,5 +163,9 @@ class Traffic:
         """Closed loop: one staggering request per client, issued during
         set-up. Client i's output is scaled by (i+1)/clients, so the clients
         leave the ramp at evenly spread phases instead of in lockstep. The
-        scaling is seed-free; inside the window every request is unscaled."""
+        scaling is seed-free; inside the window every request is unscaled.
+        `"stagger": false` in the traffic file issues them unscaled: the
+        clients then start together, as one batch of samples does."""
+        if not self.spec.get("stagger", True):
+            return [self.next() for _ in range(self.clients)]
         return [self.next((i + 1) / self.clients) for i in range(self.clients)]

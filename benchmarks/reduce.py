@@ -288,6 +288,32 @@ def top(pairs: tp.Dict[str, float], n: int = 10, rest_label: str = "other") -> t
     return out
 
 
+def window_parts(window: dict, percentile) -> tp.Dict[str, tp.List[float]]:
+    """A serving window cut into `window["parts"]` equal parts, each part's own
+    figure of the three client-side metrics: tokens delivered in the part /
+    its length; mean submit-to-first-token over the requests SUBMITTED in it
+    (and first answered inside the window); 90th percentile of time per output
+    token over the requests that FINISHED in it. A host stall sits in one part
+    (two, across an edge) and moves the whole-window figures; the median over
+    the parts stays. `window` is what serve_cell.py hands over: `w0`, `w1`,
+    `token_times` (sorted) and `requests` as (t_submit, t_first or None,
+    t_last or None if unfinished, n_out). A part with nothing to read raises."""
+    n, w0, w1 = int(window["parts"]), window["w0"], window["w1"]
+    edges = [w0 + (w1 - w0) * k / n for k in range(n + 1)]
+    times = window["token_times"]
+    out: tp.Dict[str, tp.List[float]] = {"tokens_per_s": [], "ttft_ms_mean": [], "tpot_ms_p90": []}
+    for k, (a, b) in enumerate(zip(edges, edges[1:])):
+        ttft = [f - s for s, f, _, _ in window["requests"] if a <= s < b and f is not None and f < w1]
+        tpot = [(l - f) / (m - 1) for _, f, l, m in window["requests"] if l is not None and a <= l < b and m > 1]
+        if not ttft or not tpot:
+            raise ValueError(f"part {k + 1} of {n} ({b - a:.2f} s) holds {len(ttft)} submitted and {len(tpot)} "
+                             f"finished requests: too few parts' worth of traffic for `window_parts` {n}")
+        out["tokens_per_s"].append((bisect.bisect_left(times, b) - bisect.bisect_left(times, a)) / (b - a))
+        out["ttft_ms_mean"].append(1e3 * sum(ttft) / len(ttft))
+        out["tpot_ms_p90"].append(1e3 * percentile(tpot, 90))
+    return out
+
+
 def summarize(trace: dict, spans_s: tp.Sequence[tp.Tuple[str, float, float]],
               clock_offset_s: float) -> dict:
     """Everything the per-layer readers need from one traced window.

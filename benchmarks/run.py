@@ -189,6 +189,11 @@ class Context:
         offset = sync[-1][1] / 1e9 - t_sync
         summary = reduce.summarize(trace, spans, offset)
         summary["trace"] = trace
+        n_ops = sum(d["n_ops"] for d in summary["devices"])
+        last = max((s + d for dev in summary["devices"] for _, s, d in dev["ops"]), default=summary["lo"])
+        log(f"trace coverage: {n_ops} device op events inside bench.window, the last ending "
+            f"{(last - summary['lo']) / 1e6:.0f} ms into its {summary['window_ns'] / 1e6:.0f} ms "
+            f"(a trace that ends early was cut by the profiler, which keeps ~4.96 M events: PERF.md section 7 row 28)")
         return summary
 
 
@@ -398,7 +403,15 @@ def main() -> int:
         reduce = ctx.load("reduce.py")
         result["breakdown"] = {"device_ops": reduce.top(tsum["exclusive_s"], 10, "other operations"),
                                "idle_gaps": reduce.top(tsum["gaps_s"], 10, "other spans")}
+    compared = run.get("compared")  # what `correct` compared, each number beside its limit
+    if compared is not None:
+        compared["window_compiles"] = {"value": run["counters"]["window.compiles"], "limit": 0}
+        result["compared"] = compared  # last in the line
     print(json.dumps(result), flush=True)
+    if compared is not None:  # and as the last lines of standard error
+        for name, c in compared.items():
+            print(f"compared {name}: {c['value']!r} (limit {c['limit']!r})", file=sys.stderr)
+        print(f"correct: {result['correct']}", file=sys.stderr, flush=True)
     return 0
 
 

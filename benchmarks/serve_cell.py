@@ -71,7 +71,8 @@ def build_model(ctx):
     return mc, params
 
 
-def check_paged_path(ctx, mc, params, eng_spec) -> bool:
+def check_paged_path(ctx, mc, params, eng_spec):
+    """(ok, the numbers compared, each beside its limit)."""
     import jax
     import jax.numpy as jnp
 
@@ -110,7 +111,8 @@ def check_paged_path(ctx, mc, params, eng_spec) -> bool:
             f"decode steps, bf16 pool) vs float32 reference logits of the same sequence: error/std "
             f"rms {rms:.3e} (tolerance {RMS_TOLERANCE:.0e}), max {worst:.3e} (tolerance "
             f"{MAX_TOLERANCE:.0e}) -> {'ok' if ok else 'NOT CORRECT'}")
-    return ok
+    return ok, {"logits_rms_over_std": {"value": rms, "limit": RMS_TOLERANCE},
+                "logits_max_over_std": {"value": worst, "limit": MAX_TOLERANCE}}
 
 
 def page_bucket(tokens: int, page_size: int, max_pages: int) -> int:
@@ -188,7 +190,7 @@ def run(ctx) -> dict:
     es = spec["engine"]
     mc, params = build_model(ctx)
     ctx.phases.mark("weights")
-    correct = check_paged_path(ctx, mc, params, es)
+    correct, compared = check_paged_path(ctx, mc, params, es)
     ctx.phases.mark("correctness_check")
 
     traffic = loadgen.Traffic(spec, ctx.seed32, mc.vocab_size)
@@ -353,12 +355,29 @@ def run(ctx) -> dict:
         "ttft_ms_mean": 1e3 * statistics.fmean(ttft),
         "tpot_ms_p90": 1e3 * pct(tpot, 90),
     }
+    parts = None
+    if spec.get("window_parts"):
+        window = {"w0": w0, "w1": w1, "parts": int(spec["window_parts"]),
+                  "token_times": [t for t, _, _ in token_log if w0 <= t < w1],
+                  "requests": [(r.t_submit, r.t_first if r.n_out else None, r.t_last if r.status else None, r.n_out)
+                               for r in recs.values() if not r.primer]}
+        try:
+            parts = ctx.load("reduce.py").window_parts(window, pct)
+            ctx.log(f"window in {window['parts']} parts of {window_s / window['parts']:.2f} s (a host stall shows "
+                    f"as one part off the others; the medians over the parts are per-layer metrics): "
+                    + "; ".join(f"{k} median {statistics.median(v):.6g} of {[float(f'{x:.5g}') for x in v]}"
+                                for k, v in parts.items()))
+        except ValueError as e:  # the three part medians are then left out of the line
+            ctx.log(f"window parts: {e}")
     ctx.log(f"ttft ms mean {e2e['ttft_ms_mean']:.1f} p50 {1e3 * pct(ttft, 50):.1f} p90 {1e3 * pct(ttft, 90):.1f} max {1e3 * max(ttft):.1f}; "
             f"tpot ms p50 {1e3 * pct(tpot, 50):.2f} p90 {e2e['tpot_ms_p90']:.2f} max {1e3 * max(tpot):.2f}")
     return {
         "kind": "serve", "correct": correct and failed == 0 and stats["preemptions"] == 0,
         "attempted": attempted, "failed": failed, "end_to_end": e2e,
-        "samples": {"ttft_s": ttft, "tpot_s": tpot, "occupancy": occ},
+        "samples": {"ttft_s": ttft, "tpot_s": tpot, "occupancy": occ}, "window_parts": parts,
+        "compared": {**compared,
+                     "requests_failed": {"value": failed, "limit": 0},
+                     "preemptions": {"value": stats["preemptions"], "limit": 0}},
         "counters": {"window.compiles": window_compiles, "prefilled_tokens": prefilled,
                      "output_tokens": tokens_in, "rounds": rounds, "max_slots": eng.max_slots,
                      "completed": len(done), "kv_itemsize": 2},
