@@ -272,7 +272,7 @@ class MimoKVCache:
     wk: Array
     wv: Array
     moe_counts: Array  # (moe layers, n_experts_held) int32: pairs of active slots' decode steps
-    moe_totals: Array  # (3,) int32: decode steps, held experts touched (summed over steps and layers), dropped
+    moe_totals: Array  # (4,) int32: decode steps, held experts touched (summed over steps and layers), dropped, row blocks in use
 
     def pool_arrays(self) -> tp.List[Array]:
         return [self.gk, self.gv, self.wk, self.wv]
@@ -669,7 +669,7 @@ class MimoV2:
                 x = x + jnp.einsum("bte,de->btd", o.astype(x.dtype), p.attn.wo)
             x, idx, stats = MimoV2._ffn(c, i, p, x)
             if idx is not None:
-                moe_counts, totals = moe_count_decode(moe_counts, totals, n_moe, idx, active, stats["dropped"],
+                moe_counts, totals = moe_count_decode(moe_counts, totals, n_moe, idx, active, stats,
                                                       offset=c.expert_offset)
                 n_moe += 1
         totals = totals.at[0].add(1)

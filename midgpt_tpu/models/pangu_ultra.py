@@ -583,7 +583,7 @@ class PanguUltra:
                 x = x + _norm(c, o, p.norm_post_attn)
             x, idx, stats = PanguUltra._ffn(c, i, p, x)
             if idx is not None:
-                moe_counts, totals = moe_count_decode(moe_counts, totals, n_moe, idx, active, stats["dropped"],
+                moe_counts, totals = moe_count_decode(moe_counts, totals, n_moe, idx, active, stats,
                                                       offset=c.expert_offset)
                 n_moe += 1
         totals = totals.at[0].add(1)

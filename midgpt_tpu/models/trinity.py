@@ -532,7 +532,7 @@ class Trinity:
                 x = Trinity._attn_out(c, p, x, u, o)
             x, idx, stats = Trinity._ffn(c, i, p, x)
             if idx is not None:
-                moe_counts, totals = moe_count_decode(moe_counts, totals, n_moe, idx, active, stats["dropped"],
+                moe_counts, totals = moe_count_decode(moe_counts, totals, n_moe, idx, active, stats,
                                                       offset=c.expert_offset)
                 n_moe += 1
         totals = totals.at[0].add(1)

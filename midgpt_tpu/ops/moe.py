@@ -9,16 +9,22 @@ computed and adds nothing: on one chip of an expert-parallel job that is the
 chip's share of the sum, and the exchange that would add the other shares is
 no part of this op.
 
-Two callers, one layout of tiles (a tile = up to `tile` pairs of ONE held
-expert). Training (`moe_experts`, differentiable, thousands of rows): the tiles
-in a buffer of a fixed size, batched matmuls over each tile's gathered expert
-weights, an exact path behind it. Serving (`moe_experts_serving`, forward only,
-a prefill chunk's or a decode step's rows): a loop over the tiles in use that
-reads each tile's expert in place. Why two: at serving row counts the gathered
+Two callers, one idea (the pairs sorted by held expert, each expert's run
+padded to whole tiles, so a tile belongs to ONE expert), two layouts. Training
+(`moe_experts`, differentiable, thousands of rows): the tiles in a buffer of a
+size the mean load sets, batched matmuls over each tile's GATHERED expert
+weights, an exact path behind it for the call that overflows. Serving
+(`moe_experts_serving`, forward only, a prefill chunk's or a decode step's
+rows): the rows in a buffer that takes the most padding can need (nothing
+overflows, no exact path), and ONE Pallas call over its row blocks that reads
+each block's expert IN PLACE, the next block's in flight meanwhile
+(`kernels/grouped_matmul.py`). Why two: at serving row counts the gathered
 weights ARE the cost (v5e, 16 held experts of 3 x 2,048 x 4,096, 512 / 32 rows:
-buffer 10.06 / 5.52 ms, every held expert over every row 2.54 / 1.12 ms, the
-loop 1.54 / 0.63 ms; PERF.md §6 PR 30), and a loop whose trip count is data has
-no transpose.
+buffer 10.06 / 5.52 ms, every held expert over every row 2.54 / 1.12 ms, a loop
+over the tiles in use 1.54 / 0.63 ms; PERF.md §6 PR 30), a serving step's cost
+is the expert matrices it must stream once (PERF.md §6 PR 50: the loop read
+them at 42 % of the chip's rate, the grouped matmul at twice that), and the
+kernel has no transpose.
 
 `moe_experts`' dispatch has static shapes and drops nothing:
 
@@ -165,67 +171,92 @@ def moe_experts(
     return y, {"counts": counts, "dropped": jnp.sum(counts) - computed, "overflowed": overflowed}
 
 
-def moe_serving_tile(n_tokens: int, top_k: int, n_experts: int) -> int:
-    """Rows a tile of `moe_experts_serving`: the power of two at or over FOUR
-    times the mean pairs an expert gets, within [8, 256]. A tile of few rows
-    costs its expert's three matrices read once whatever it holds (until 256
-    rows fill the MXU), so the cheaper tile is one that takes a whole run, the
-    most loaded expert's too (2.4 to 2.9 times the mean in the serving cell),
-    and not the mean run. On the v5e at 512 rows (mean 16): tiles of 8 / 16 /
-    32 / 64 / 128 rows took 3.22 / 2.16 / 1.66 / 1.54 / 1.78 ms (PERF.md §6 PR
-    30). Derived from the row count, not configured."""
-    tile = 8
-    while tile < min(256, 4 * n_tokens * top_k / n_experts):
-        tile *= 2
-    return tile
+def moe_row_block(n_tokens: int, top_k: int, n_experts: int, itemsize: int) -> int:
+    """Rows a block of `moe_experts_serving`: the power of two at or over FOUR
+    times the mean pairs an expert gets, from the rows one vector register
+    holds at this itemsize (8 of float32, 16 of bf16) to the 256 that fill the
+    MXU. A block of few rows costs its expert's three matrices read once
+    whatever it holds, so the cheaper block is one that takes a whole run, the
+    most loaded expert's too (2.4 to 2.9 times the mean in the serving cells),
+    and not the mean run. Derived from the call's shapes, not configured."""
+    block = max(8, 32 // itemsize)
+    while block < min(256, 4 * n_tokens * top_k / n_experts):
+        block *= 2
+    return block
+
+
+def moe_serving_plan(
+    idx: Array, weights: Array, *, offset: int, n_held: int, block_rows: int,
+) -> tp.Dict[str, Array]:
+    """Where every pair of a serving call goes in a buffer of rows SORTED BY
+    HELD EXPERT, each expert's run padded to whole blocks of `block_rows` (a
+    block belongs to ONE expert), the blocks in use first. The buffer has
+    P = round_up(N * k, block_rows) + n_held * block_rows rows: the most that
+    padding can need whatever `idx` is, so nothing overflows. Static shapes, no
+    loop, no sort (a pair's row is its expert's first row + its rank within the
+    expert, the tokens before it that picked the expert), and the one scatter
+    is of N * k scalars to rows no two pairs share. idx, weights (N, k) from
+    `route`. Returns
+      counts (n_held,)      pairs assigned to each held expert
+      row (N, k)            each pair's row; P, past the buffer, for a pair not held here
+      src (P,)              each row's token; N (no token: a zero row) on padding
+      ws (P,) f32           each row's pair weight; 0 on padding
+      block_expert (P / block_rows,)  each block's expert; the blocks past those
+                            in use REPEAT the last used one (they fetch nothing new)
+      blocks_used ()        blocks in use = sum over held experts of ceil(pairs / block_rows)
+    """
+    N, k = idx.shape
+    P = (-(-N * k // block_rows) + n_held) * block_rows
+    local = idx - offset
+    onehot = (local[..., None] == jnp.arange(n_held)) & ((local >= 0) & (local < n_held))[..., None]  # (N, k, n_held)
+    per_token = jnp.sum(onehot, axis=1, dtype=jnp.int32)  # (N, n_held) 0/1: an expert is picked once a token
+    counts = jnp.sum(per_token, axis=0)
+    blocks_e = -(-counts // block_rows)
+    ends = jnp.cumsum(blocks_e)
+    # a pair's row = its expert's first row + the earlier tokens that picked the expert (one-hot: no gather)
+    at = (ends - blocks_e) * block_rows + jnp.cumsum(per_token, axis=0) - per_token  # (N, n_held)
+    row = jnp.where(jnp.any(onehot, axis=-1), jnp.sum(jnp.where(onehot, at[:, None, :], 0), axis=-1), P)
+    # a row's pair: scattered to rows that are all different (a pair not held here: its own row past the buffer, dropped)
+    to = jnp.where(row < P, row, P + jnp.arange(N * k, dtype=jnp.int32).reshape(N, k)).reshape(-1)
+    put = lambda init, vals: init.at[to].set(vals.reshape(-1), mode="drop", unique_indices=True)
+    src = put(jnp.full((P,), N, jnp.int32), jnp.broadcast_to(jnp.arange(N, dtype=jnp.int32)[:, None], (N, k)))
+    ws = put(jnp.zeros((P,), jnp.float32), weights.astype(jnp.float32))
+    blocks = jnp.minimum(jnp.arange(P // block_rows, dtype=jnp.int32), ends[-1] - 1)  # past those in use: the last used
+    block_expert = jnp.minimum(jnp.sum(ends <= blocks[:, None], axis=1, dtype=jnp.int32), n_held - 1)
+    return {"counts": counts, "row": row, "src": src, "ws": ws, "block_expert": block_expert, "blocks_used": ends[-1]}
 
 
 def moe_experts_serving(
     x: Array, idx: Array, weights: Array,
-    w_gate: Array, w_up: Array, w_down: Array, *, offset: int, tile: int,
+    w_gate: Array, w_up: Array, w_down: Array, *, offset: int, block_rows: int,
 ) -> tp.Tuple[Array, tp.Dict[str, Array]]:
     """`moe_experts`' result for a FORWARD-ONLY call of few rows (a prefill
-    chunk, a decode step's slots): the same tiles (a tile = up to `tile` pairs
-    of ONE held expert, its rows found by the same binary search), walked by a
-    loop that runs as many times as there are tiles IN USE, each reading its
-    expert's matrices in place (a dynamic slice, no gathered copy) and adding
-    its weighted rows into the result. No buffer of a fixed size, so nothing
+    chunk, a decode step's slots), in three parts with static shapes: the plan
+    (`moe_serving_plan`) and ONE gather of the tokens into rows sorted by
+    expert; ONE Pallas call over the row blocks (`kernels/grouped_matmul.py`:
+    the next block's expert is in flight while this block is multiplied, and an
+    expert whose run is one block is streamed once a call); each token's k rows
+    gathered back and summed in float32 (a pair not held here reads zeros: no
+    scatter-add). The buffer takes the most that padding can need, so nothing
     overflows and no exact path exists; work is the held experts touched, not
     the held experts. `dropped` counts assigned pairs that were not computed:
-    0 by construction, counted not assumed. Not differentiable (the trip count
-    is data): training takes `moe_experts`."""
-    N, D = x.shape
-    E_h = w_gate.shape[0]
-    local = idx - offset
-    onehot = (local[..., None] == jnp.arange(E_h)) & ((local >= 0) & (local < E_h))[..., None]
-    per_token = jnp.sum(onehot, axis=1, dtype=jnp.int32)  # (N, E_h) 0/1
-    counts = jnp.sum(per_token, axis=0)
+    0 by construction, counted not assumed; `visits` the row blocks in use.
+    Not differentiable: training takes `moe_experts`."""
+    from midgpt_tpu.kernels.grouped_matmul import grouped_swiglu
+
+    N = x.shape[0]
     with jax.named_scope("moe_route"):
-        w_tok = jnp.sum(jnp.where(onehot, weights[..., None], 0.0), axis=1)  # (N, E_h)
-        tiles_e = -(-counts // tile)
-        ends = jnp.cumsum(tiles_e)
-        cum = jnp.cumsum(per_token, axis=0).T  # (E_h, N), non-decreasing
-        xz = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])
-        wz = jnp.concatenate([w_tok, jnp.zeros((1, E_h), w_tok.dtype)])
-        rows = jnp.arange(1, tile + 1, dtype=jnp.int32)
-
-    def one_tile(t, carry):
-        y, computed = carry
-        with jax.named_scope("moe_route"):
-            e = jnp.searchsorted(ends, t, side="right").astype(jnp.int32)  # < E_h: t < ends[-1]
-            j = t - (ends[e] - tiles_e[e])  # the tile's place in its expert's run
-            # row r holds the token whose pair is the (j * tile + r + 1)-th of expert e; past the last pair: N, a zero row
-            tok = jnp.searchsorted(cum[e], j * tile + rows, side="left").astype(jnp.int32)
-            xe, we = xz[tok], wz[tok, e]
-        with jax.named_scope("moe_experts"):
-            ye = swiglu(xe, w_gate[e], w_up[e], w_down[e])
-        with jax.named_scope("moe_route"):
-            y = y.at[tok].add(ye.astype(jnp.float32) * we[:, None])
-        return y, computed + jnp.sum(tok < N, dtype=counts.dtype)
-
-    y, computed = jax.lax.fori_loop(
-        0, ends[-1], one_tile, (jnp.zeros((N + 1, D), jnp.float32), jnp.zeros((), counts.dtype)))
-    return y[:N].astype(x.dtype), {"counts": counts, "dropped": jnp.sum(counts) - computed}
+        plan = moe_serving_plan(idx, weights, offset=offset, n_held=w_gate.shape[0], block_rows=block_rows)
+        xz = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
+        xs = jnp.take(xz, plan["src"], axis=0, mode="clip")  # (P, D); no token: the zero row
+    with jax.named_scope("moe_experts"):
+        ys = grouped_swiglu(xs, plan["ws"], plan["block_expert"], plan["blocks_used"], w_gate, w_up, w_down,
+                            block_rows=block_rows)
+    with jax.named_scope("moe_route"):
+        y = jnp.sum(ys.at[plan["row"]].get(mode="fill", fill_value=0), axis=1)  # (N, k, D) f32 -> (N, D)
+        computed = jnp.sum(plan["src"] < N, dtype=plan["counts"].dtype)  # rows that hold a pair: counted
+    return y.astype(x.dtype), {"counts": plan["counts"], "dropped": jnp.sum(plan["counts"]) - computed,
+                               "visits": plan["blocks_used"]}
 
 
 # ---------------------------------------------------------------------------
@@ -241,53 +272,58 @@ def moe_serving(
 ) -> tp.Tuple[Array, Array, tp.Dict[str, Array]]:
     """x (N, D) -> (the held experts' part of the routed layer (N, D), idx (N,
     k), stats): `route` under the `moe_route` scope, then `moe_experts_serving`
-    at the tile the row count gives. The router keeps its published width
-    (`router`'s leading axis) whatever is held here."""
+    at the row block the call's shapes give (`moe_row_block`). The router keeps
+    its published width (`router`'s leading axis) whatever is held here. Every
+    device op of it lies under `moe_route` or `moe_experts`, which are ONE cost
+    (the benchmark divides the expert bytes a step must read by their sum)."""
     with jax.named_scope("moe_route"):
         idx, w = route(x, router, bias, top_k=top_k, scale=scale, renormalize=renormalize)
-    y, stats = moe_experts_serving(x, idx, w, w_gate, w_up, w_down, offset=offset,
-                                   tile=moe_serving_tile(x.shape[0], top_k, router.shape[0]))
+    y, stats = moe_experts_serving(
+        x, idx, w, w_gate, w_up, w_down, offset=offset,
+        block_rows=moe_row_block(x.shape[0], top_k, router.shape[0], w_gate.dtype.itemsize))
     return y, idx, stats
 
 
 def moe_counters_init(n_moe_layers: int, n_held: int) -> tp.Tuple[Array, Array]:
     """(counts (moe layers, n_held) int32: pairs of active slots' decode steps;
-    totals (3,) int32: decode steps, held experts touched (summed over steps and
-    layers), dropped), zeroed."""
-    return jnp.zeros((n_moe_layers, n_held), jnp.int32), jnp.zeros((3,), jnp.int32)
+    totals (4,) int32: decode steps, held experts touched (summed over steps and
+    layers), dropped, row blocks in use (summed the same way)), zeroed."""
+    return jnp.zeros((n_moe_layers, n_held), jnp.int32), jnp.zeros((4,), jnp.int32)
 
 
 def moe_count_decode(
-    counts: Array, totals: Array, layer: int, idx: Array, active: Array, dropped: Array, *, offset: int,
+    counts: Array, totals: Array, layer: int, idx: Array, active: Array, stats: tp.Dict[str, Array], *, offset: int,
 ) -> tp.Tuple[Array, Array]:
     """One routed layer of one decode step into the counters: `idx` (B, k) of
-    the step's slots, of which only the `active` ones count."""
+    the step's slots, of which only the `active` ones count; `stats` what
+    `moe_serving` gave for the layer (its row blocks cover every slot's row)."""
     local = idx - offset  # (B, k); the active slots' pairs, by held expert
     here = jnp.sum((local[..., None] == jnp.arange(counts.shape[1])) & active[:, None, None],
                    axis=(0, 1), dtype=jnp.int32)
     counts = counts.at[layer].add(here)
     totals = totals + jnp.stack([jnp.zeros((), jnp.int32), jnp.sum(here > 0, dtype=jnp.int32),
-                                 dropped.astype(jnp.int32)])
+                                 stats["dropped"].astype(jnp.int32), stats["visits"].astype(jnp.int32)])
     return counts, totals
 
 
 def moe_count_dropped(totals: Array, dropped: Array) -> Array:
     """A prefill chunk's routed layer: only what it dropped is counted."""
     z = jnp.zeros((), jnp.int32)
-    return totals + jnp.stack([z, z, dropped.astype(jnp.int32)])
+    return totals + jnp.stack([z, z, dropped.astype(jnp.int32), z])
 
 
 def moe_serve_counters(counts: Array, totals: Array) -> tp.Dict[str, float]:
     """The expert layers' counters since the cache was made (a device read:
     not for the serving loop). Decode steps of active slots only."""
     counts = jax.device_get(counts).astype(float)
-    steps, touched, dropped = (int(v) for v in jax.device_get(totals))
+    steps, touched, dropped, visits = (int(v) for v in jax.device_get(totals))
     n_moe = max(1, counts.shape[0])
     load = counts.max(axis=-1) / counts.mean(axis=-1).clip(1e-9) if counts.size else counts.sum(axis=-1)
     return {
         "moe.decode_steps": steps,
         "moe.pairs_here": counts.sum() / max(1, steps) / n_moe,  # a decode step a layer
         "moe.experts_touched": touched / max(1, steps) / n_moe,  # held experts with a pair, a step a layer
+        "moe.expert_visits": visits / max(1, steps) / n_moe,  # row blocks in use, a step a layer: each streams one expert
         "moe.load_max_over_mean": float(load.max()) if load.size else 0.0,  # worst layer, over the run
         "moe.dropped": dropped,
     }

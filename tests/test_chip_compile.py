@@ -20,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 fa = importlib.import_module("midgpt_tpu.kernels.flash_attention")
 at = importlib.import_module("midgpt_tpu.kernels.attention_template")
 pw = importlib.import_module("midgpt_tpu.kernels.paged_write")
+gm = importlib.import_module("midgpt_tpu.kernels.grouped_matmul")
 
 
 @pytest.fixture(scope="module")
@@ -61,6 +62,7 @@ def compiled_kernels(monkeypatch):
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     monkeypatch.setattr(at, "_interpret", lambda: False)
     monkeypatch.setattr(pw, "_interpret", lambda: False)
+    monkeypatch.setattr(gm, "_interpret", lambda: False)
     with jax.default_matmul_precision("default"):
         yield
 
@@ -892,7 +894,9 @@ def test_two_kind_serving_program_never_relays_out_either_pool(program, one_chip
     model = mc.model()
     sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
     params = jax.tree.map(sds, jax.eval_shape(lambda k: model.cast_params(model.init(mc, k), jnp.bfloat16), jax.random.PRNGKey(0)))
-    cache = jax.tree.map(sds, jax.eval_shape(lambda: model.init_cache(mc, (2049, 673), 32, jnp.bfloat16, kernel_layout=True)))
+    # 4,097 global pages: a V pool of 134 MB. At 2,049 (67 MB) the compiler may park the WHOLE toy pool in VMEM around
+    # the prefill program (a copy-start / copy-done pair in one layout: no relayout, and nothing a cell's GBs can meet)
+    cache = jax.tree.map(sds, jax.eval_shape(lambda: model.init_cache(mc, (4097, 673), 32, jnp.bfloat16, kernel_layout=True)))
     assert cache.gk.shape[-1] == 256 and cache.gv.shape[-1] == 128  # K 192 at whole lanes, V 128
     arr = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     B, T = 32, 64
@@ -905,7 +909,8 @@ def test_two_kind_serving_program_never_relays_out_either_pool(program, one_chip
             mc, params, arr((1, 512)), arr(()), arr(()), cache, (arr((1, T)), arr((1, T))), None, "kernel",
             0.8, None, None, arr((2,), jnp.uint32))
     text = lowered.compile().as_text()
-    assert text.count("tpu_custom_call") == (4 if program == "decode8" else 3)  # 3 writes (+ the global layer's attention)
+    # 3 writes (+ the global layer's attention) and ONE grouped matmul a routed layer
+    assert text.count("tpu_custom_call") == (4 if program == "decode8" else 3) + len(mc.moe_layers)
     assert pool_relayouts(text, [a.shape for a in cache.pool_arrays()]) == 0
 
 
@@ -943,8 +948,11 @@ def test_window_layers_decode_through_the_kernel_at_the_published_geometry(progr
             0.8, None, None, arr((2,), jnp.uint32))
     text = lowered.compile().as_text()
     paths = re.findall(r'custom-call\([^\n]*tpu_custom_call[^\n]*?op_name="([^"]*)"', text)
-    attention = [p for p in paths if "kv_write" not in p]
+    attention = [p for p in paths if "kv_write" not in p and "moe_experts" not in p]
     assert sum("kv_write" in p for p in paths) == 5
+    assert sum("moe_experts" in p for p in paths) == len(mc.moe_layers) == 4  # ONE grouped matmul a routed layer
+    if program == "decode8":  # the step loop is the program's ONLY loop: the expert loop, whose trip count was data, is gone
+        assert re.findall(r' while\([^\n]*op_name="([^"]*)"', text) == ["jit(_serve_decode_chunk)/while"]
     assert sorted(p.split("/")[-2] for p in attention) == (["attn_global"] + ["attn_window"] * 4 if program == "decode8" else [])
     assert pool_relayouts(text, [a.shape for a in cache.pool_arrays()]) == 0
 
@@ -985,7 +993,8 @@ def test_latent_serving_program_compiles_and_never_relays_out_the_pool(program, 
             mc, params, arr((1, 512)), arr(()), arr(()), cache, arr((1, T)), None, "kernel",
             0.8, None, None, arr((2,), jnp.uint32))
     text = lowered.compile().as_text()
-    assert text.count("tpu_custom_call") == (4 if program == "decode8" else 2)  # a write a layer (+ decode's attention)
+    # a write a layer (+ decode's attention) and the routed layer's ONE grouped matmul
+    assert text.count("tpu_custom_call") == (4 if program == "decode8" else 2) + len(mc.moe_layers)
     assert pool_relayouts(text, [pool.shape]) == 0
 
 
@@ -1104,3 +1113,30 @@ def test_kda_chunk_terms_and_state_never_reach_hbm(program, kda_programs):
         assert not re.search(r"f32\[[\d,]*16,16,128\]", rest), (name, rest[:200])
         assert "convolution(" not in rest and " while(" not in rest, (name, rest[:200])
     assert compiled.memory_analysis().temp_size_in_bytes < 0.7e9
+
+
+SERVING_EXPERTS = {  # family: D, F, experts held, router width (bf16, top-8): the published widths, the cells' shares
+    "trinity_1024x2048_128_whole": (2048, 1024, 128, 128),
+    "mimo_2048x4096_16_of_256": (4096, 2048, 16, 256),
+    "pangu_2048x7680_16_of_256": (7680, 2048, 16, 256),
+}
+
+
+@pytest.mark.parametrize("rows", [64, 512], ids=["decode64", "chunk512"])
+@pytest.mark.parametrize("family", list(SERVING_EXPERTS))
+def test_serving_experts_are_one_mosaic_call_at_the_published_widths(family, rows, one_chip, compiled_kernels):
+    """ops/moe.py `moe_experts_serving` at the three served families' expert
+    widths, for a decode step's rows and a prefill chunk's, at the row block
+    and the F slice the shapes give: ONE Mosaic call a routed layer inside the
+    VMEM limit the kernel sets (Mosaic refuses a call past it), and no loop."""
+    from midgpt_tpu.ops import moe
+
+    D, F, held, n_experts = SERVING_EXPERTS[family]
+    block = moe.moe_row_block(rows, 8, n_experts, 2)
+    bf = gm.f_slice(block, D, F, 2)
+    assert F % bf == 0 and 6 * bf * D * 2 <= gm.VMEM_BLOCKS < gm.VMEM_LIMIT <= 32 << 20  # of a v5e core's 128 MiB: XLA sets the limit aside
+    arr = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    text = jax.jit(lambda *a: moe.moe_experts_serving(*a, offset=0, block_rows=block)).lower(
+        arr((rows, D)), arr((rows, 8), jnp.int32), arr((rows, 8), jnp.float32),
+        arr((held, F, D)), arr((held, F, D)), arr((held, D, F))).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and " while(" not in text

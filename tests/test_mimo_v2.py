@@ -243,13 +243,13 @@ def test_partial_rotary_rotates_the_leading_channels_only():
     np.testing.assert_allclose(y[1, 1], want, atol=1e-5)
 
 
-@pytest.mark.parametrize("rows,tile", [(40, 8), (40, 16), (3, 8), (64, 64)])
-def test_serving_dispatch_is_the_training_dispatchs_result(rows, tile):
-    """`moe_experts_serving` (a loop over the tiles in use, the expert's
-    matrices read in place) gives what `moe_experts` gives, counts what it
-    counts and drops nothing, whatever the tile; with no pair routed here the
-    loop runs no tile and the result is zero."""
-    from midgpt_tpu.ops.moe import moe_capacity, moe_experts, moe_experts_serving, moe_serving_tile, route
+@pytest.mark.parametrize("rows,block", [(40, 8), (40, 16), (3, 8), (64, 64)])
+def test_serving_dispatch_is_the_training_dispatchs_result(rows, block):
+    """`moe_experts_serving` (rows sorted by expert, one grouped matmul over
+    their blocks, each token's rows summed back) gives what `moe_experts`
+    gives, counts what it counts and drops nothing, whatever the row block;
+    with no pair routed here no block is in use and the result is zero."""
+    from midgpt_tpu.ops.moe import moe_capacity, moe_experts, moe_experts_serving, moe_row_block, route
 
     key = jax.random.PRNGKey(1)
     x, wr = jax.random.normal(key, (rows, 32)), jax.random.normal(jax.random.fold_in(key, 1), (16, 32))
@@ -257,14 +257,16 @@ def test_serving_dispatch_is_the_training_dispatchs_result(rows, tile):
     idx, w = route(x, wr, jnp.zeros((16,)), top_k=4, scale=1.0)
     n_tiles, t = moe_capacity(rows, 4, 16, 4, 2.0)
     a, sa = moe_experts(x, idx, w, wg, wu, wd, offset=8, n_tiles=n_tiles, tile=t)
-    b, sb = jax.jit(lambda *args: moe_experts_serving(*args, offset=8, tile=tile))(x, idx, w, wg, wu, wd)
+    b, sb = jax.jit(lambda *args: moe_experts_serving(*args, offset=8, block_rows=block))(x, idx, w, wg, wu, wd)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
     np.testing.assert_array_equal(np.asarray(sa["counts"]), np.asarray(sb["counts"]))
     assert int(sb["dropped"]) == 0 and int(sb["counts"].sum()) > 0
-    none, sn = moe_experts_serving(x, idx, w, wg, wu, wd, offset=400, tile=tile)  # an offset no pair reaches
-    assert not np.asarray(none).any() and int(sn["counts"].sum()) == 0 and int(sn["dropped"]) == 0
-    # the tile is derived from the row count: four times the mean pairs an expert, within [8, 256]
-    assert [moe_serving_tile(n, 8, 256) for n in (32, 512, 4096, 65536)] == [8, 64, 256, 256]
+    assert int(sb["visits"]) == int(np.sum(-(-np.asarray(sb["counts"]) // block)))
+    none, sn = moe_experts_serving(x, idx, w, wg, wu, wd, offset=400, block_rows=block)  # an offset no pair reaches
+    assert not np.asarray(none).any() and int(sn["counts"].sum()) == 0 and int(sn["dropped"]) == 0 and int(sn["visits"]) == 0
+    # the row block is derived from the call's shapes: four times the mean pairs an expert, from a register's rows to 256
+    assert [moe_row_block(n, 8, 256, 4) for n in (32, 512, 4096, 65536)] == [8, 64, 256, 256]
+    assert [moe_row_block(n, 8, 256, 2) for n in (32, 512, 4096, 65536)] == [16, 64, 256, 256]
 
 
 def test_the_reference_blocks_its_queries_without_changing_its_result(model, monkeypatch):
