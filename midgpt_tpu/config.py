@@ -488,6 +488,7 @@ MODEL_FAMILIES: tp.Dict[str, str] = {
     "pangu_ultra": "midgpt_tpu.models.pangu_ultra:PanguUltraConfig",
     "ouro": "midgpt_tpu.models.ouro:OuroConfig",
     "afmoe": "midgpt_tpu.models.trinity:TrinityConfig",
+    "dots3_note": "midgpt_tpu.models.dots3:Dots3Config",
 }
 
 

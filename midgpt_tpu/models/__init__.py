@@ -5,7 +5,7 @@ here, and call each without asking whether it is there. Families are
 registered in `midgpt_tpu/config.py` `MODEL_FAMILIES`.
 
 The config (`GPTConfig`, `KimiLinearConfig`, `MimoV2Config`, `PanguUltraConfig`,
-`OuroConfig`, `TrinityConfig`):
+`OuroConfig`, `TrinityConfig`, `Dots3Config`):
 
     block_size, vocab_size, n_layer, n_head, n_embd   fields, under these names
                                (`n_layer`: layers of WEIGHTS; how many layers
@@ -21,7 +21,7 @@ The config (`GPTConfig`, `KimiLinearConfig`, `MimoV2Config`, `PanguUltraConfig`,
                                stack (sample.py, ServeEngine) holds no cache
                                for this family; returns None where it does
 
-The namespace (`GPT`, `KimiLinear`, `MimoV2`, `PanguUltra`, `Ouro`, `Trinity`):
+The namespace (`GPT`, `KimiLinear`, `MimoV2`, `PanguUltra`, `Ouro`, `Trinity`, `Dots3`):
 
     init(config, key) -> params
     hidden(config, params, tokens, *, key, inference, attn_fn) -> (B, T, D)
@@ -40,8 +40,8 @@ The namespace (`GPT`, `KimiLinear`, `MimoV2`, `PanguUltra`, `Ouro`, `Trinity`):
                                counters the train loop logs at a logged step
 
 The SERVING members, of every family whose `check_serving` returns None
-(`GPT`, `MimoV2`, `PanguUltra`, `Ouro`, `Trinity`; sampling/serve.py calls them,
-never a family by name):
+(`GPT`, `MimoV2`, `PanguUltra`, `Ouro`, `Trinity`, `Dots3`; sampling/serve.py
+calls them, never a family by name):
 
     cache_kinds(config) -> (CacheKind(name, window, sinks), ...)
                                the kinds of paged cache the layers need, the
@@ -56,7 +56,10 @@ never a family by name):
                                PanguUltra: one kind,
                                `latent`, whose pool row is not K beside V of
                                (heads, head_dim) but a token's LATENT, stored
-                               once (below). Ouro: one kind, `looped`.
+                               once (below). Ouro: one kind, `looped`. Dots3
+                               (models/dots3.py): `latent` (window 0; the layers
+                               whose indexer selects what they attend) and
+                               `window_latent` (513), both latent rows.
     init_cache(config, num_pages, page_size, dtype, kernel_layout) -> cache
                                `num_pages[i]` pages for kind i; the cache is a
                                pytree with `pool_arrays()` (its page pools, for
@@ -74,7 +77,13 @@ never a family by name):
                                (PanguUltra: (layers, 1, pages, page_size,
                                kv_lora_rank + rope), 640 lanes on the kernel
                                path; K is the row, V a VIEW of its leading
-                               kv_lora_rank lanes, so nothing is stored twice)
+                               kv_lora_rank lanes, so nothing is stored twice).
+                               Dots3's `latent` kind is TWO arrays under one
+                               page table, a token's latent row (640 lanes) and
+                               its index key (128), which live and die with the
+                               same pages; its `window_latent` kind ONE array
+                               (1,152 lanes): `KindsKVCache.pools[i]` is the
+                               tuple of kind i's arrays
     prefill_batched            True: `prefill_paged_chunk` takes the chunks of
                                B slots as the rows of one batch; False: one
                                row a call (MimoV2: two tables and a window
@@ -114,11 +123,12 @@ never a family by name):
                                engine's block counters
     serve_counters             None, or (config, cache) -> {counter: number}
                                the family's own counters kept in the cache
-                               (MimoV2, PanguUltra, Trinity: the expert
+                               (MimoV2, PanguUltra, Trinity, Dots3: the expert
                                layers', through ops/moe.py's shared helpers;
-                               Trinity also the expert bytes its decode steps
-                               had to read and the windowed kernel's grid; PanguUltra also the
-                               pool's bytes a token; Ouro: decode steps, passes
+                               PanguUltra also the pool's bytes a token; Dots3
+                               also each pool array's bytes a token and the
+                               indexer's `dsa.*`: decoded tokens, index keys
+                               scored, latent rows selected; Ouro: decode steps, passes
                                run, the exit gate's distribution summed over
                                decoded tokens, the pools' bytes a token over
                                all n_loop * n_layer cache layers), read on

@@ -998,6 +998,53 @@ def test_latent_serving_program_compiles_and_never_relays_out_the_pool(program, 
     assert pool_relayouts(text, [pool.shape]) == 0
 
 
+@pytest.mark.parametrize("program", ["decode8", "prefill512"])
+def test_sparse_serving_program_compiles_and_reads_only_the_selected_rows(program, one_chip, compiled_kernels):
+    """models/dots3.py at its published widths (hidden 5,120; a full layer of 128
+    heads with its indexer and a dense FFN, a sliding layer of 64 heads over a
+    latent of 1,088 with two held experts), 32 slots, a table of 2,048 pages:
+    the decode program's sliding layer runs the template with `v_lanes` 1,024
+    AND `sliding_window` 513 together (one Mosaic call under `attn_window`, a
+    pool head of 1,152 lanes), its full layer GATHERS 2,048 latent rows a slot
+    (`bf16[32,2048,640]`) and no more, whatever the 65,536-token table holds;
+    both programs write each of the three pool arrays in place and hold NO copy
+    as large as a pool array or one layer of it."""
+    import dataclasses
+    import re
+
+    from midgpt_tpu.analysis.hlo_audit import pool_relayouts
+    from midgpt_tpu.config import load_config
+    from midgpt_tpu.models.dots3 import FULL, SLIDING
+    from midgpt_tpu.sampling import serve
+
+    mc = dataclasses.replace(load_config("dots3_note").model_config, n_layer=2, layer_types=(FULL, SLIDING),
+                             n_experts_held=2, vocab_size=512)
+    model = mc.model()
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(sds, jax.eval_shape(lambda k: model.cast_params(model.init(mc, k), jnp.bfloat16), jax.random.PRNGKey(0)))
+    cache = jax.tree.map(sds, jax.eval_shape(lambda: model.init_cache(mc, (32769, 1089), 32, jnp.bfloat16, kernel_layout=True)))
+    assert [a.shape for a in cache.pool_arrays()] == [(1, 1, 32769, 32, 640), (1, 1, 32769, 32, 128), (1, 1, 1089, 32, 1152)]
+    arr = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    B, T = 32, 2048
+    if program == "decode8":
+        lowered = serve._serve_decode_chunk.lower(
+            mc, params, arr((B,)), cache, (arr((B, T)), arr((B, T))), arr((B,)), arr((B,), jnp.bool_), 8,
+            0.8, None, None, "kernel", arr((2,), jnp.uint32), None, 8)
+    else:
+        lowered = serve._serve_prefill_chunk.lower(
+            mc, params, arr((1, 512)), arr(()), arr(()), cache, (arr((1, T)), arr((1, T))), None, "kernel",
+            0.8, None, None, arr((2,), jnp.uint32))
+    text = lowered.compile().as_text()
+    paths = re.findall(r'custom-call\([^\n]*tpu_custom_call[^\n]*?op_name="([^"]*)"', text)
+    assert sum("kv_write" in p for p in paths) == 3 and sum("moe_experts" in p for p in paths) == 1
+    attention = [p.split("/")[-2] for p in paths if "kv_write" not in p and "moe_experts" not in p]
+    assert attention == (["attn_window"] if program == "decode8" else [])
+    if program == "decode8":
+        gathered = set(re.findall(r"= bf16\[32,(\d+),640\]\S* gather\(", text))
+        assert gathered == {"2048"}, gathered  # the selected rows, through the table; never the context
+    assert pool_relayouts(text, [a.shape for a in cache.pool_arrays()]) == 0
+
+
 @pytest.mark.parametrize("program", ["decode8", "prefill2x128"])
 def test_looped_serving_program_carries_the_pool_through_its_loops_without_a_copy(program, one_chip, compiled_kernels):
     """models/ouro.py at the PUBLISHED preset and the cell's real sizes (48

@@ -857,6 +857,14 @@ def test_setup_programs_reader(monkeypatch):
                                    "serve.lm_head_ms", "serve.model_unattributed_ms", "serve.moe_experts_touched",
                                    "serve.moe_load_max_over_mean", "kv.global_pool_fill",
                                    "kv.window_tokens_per_slot_max", "engine.occupancy", "setup.programs"}),
+    # PR 51: a family whose full layers select what they attend to, read by the same group and two new readers
+    ("serve_dots3_note_longctx", {"serve.attn_sparse_ms", "serve.dsa_index_ms", "serve.dsa_topk_ms", "serve.attn_select_ms",
+                                  "serve.attn_window_ms", "serve.attn_gate_ms", "serve.moe_route_ms", "serve.moe_experts_ms",
+                                  "serve.moe_shared_ms", "serve.lm_head_ms", "serve.model_unattributed_ms",
+                                  "dsa_index_sweep_ms_per_token", "select_decode_attention_ms_per_token",
+                                  "serve.dsa_selected_share", "kv.index_bytes_per_token", "kv.window_tokens_per_slot_max",
+                                  "kv.latent_pool_fill", "kv.latent_bytes_per_token", "serve.moe_experts_touched",
+                                  "serve.moe_visits_per_expert_touched", "engine.occupancy", "setup.programs"}),
 ])
 def test_rehearsal_lists_the_new_metrics(cell, names, tmp_path):
     env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"), JAX_PLATFORMS="cpu")
