@@ -85,20 +85,45 @@ calls them, never a family by name):
                                (1,152 lanes): `KindsKVCache.pools[i]` is the
                                tuple of kind i's arrays
     prefill_batched            True: `prefill_paged_chunk` takes the chunks of
-                               B slots as the rows of one batch; False: one
-                               row a call (MimoV2: two tables and a window
-                               slice a slot), and the engine's
-                               `prefill_width` is 1. Ouro: True (a call reads
-                               the layers' weights n_loop times whatever
-                               rides it)
+                               B slots as the rows of one batch (the GPT;
+                               Ouro: a call reads the layers' weights n_loop
+                               times whatever rides it; Trinity: a call
+                               streams every expert once whatever rides it.
+                               Its two kinds of table are no obstacle: the
+                               engine hands every kind's (B, pages) table and
+                               frees window pages per slot after the call;
+                               only the attention is per slot, and runs row
+                               after row INSIDE the program). False:
+                               one row a call, and the engine's
+                               `prefill_width` is 1 (MimoV2: its cell has no
+                               memory to spare for four rows' temporaries;
+                               PanguUltra, Dots3: the sweeps over latents
+                               that ROADMAP S13 / S17 rewrite; each its own
+                               PR, ROADMAP S15)
+    prefill_rows(config, dense_rows) -> int
+                               (a `prefill_batched` family) token rows a
+                               prefill call should carry, given the rows a
+                               DENSE weight wants (`sampling/serve.py`
+                               `PREFILL_ROWS`); the engine's `prefill_width`
+                               is as many chunks. A family whose every weight
+                               sees every row returns `dense_rows` (the GPT,
+                               Ouro). A routed expert sees `top_k /
+                               n_experts` of the rows: Trinity returns the
+                               rows that bring an expert dense_rows / 2 pairs
+                               on average (ops/moe.py `moe_prefill_rows`:
+                               2,048 at 128 experts, top-8)
     prefill_paged_chunk(config, params, tokens (B, T), start (B,),
         n_valid (B,), cache, page_table (B, pages), attn_impl, mesh)
         -> (logits (B, V), cache)
                                row b: the chunk [start[b], start[b] +
                                n_valid[b]) of one slot, `page_table[b]` its
-                               pages; n_valid 0: an empty place that writes
-                               nothing. logits: the row at each slot's last
-                               valid position (all the engine reads).
+                               pages (the tuple of every kind's (B, pages)
+                               where there are several); n_valid 0: an empty
+                               place that writes nothing (and, in a routed
+                               layer, takes no row among the experts':
+                               `moe_serving`'s `valid`). logits: the row at
+                               each slot's last valid position (all the
+                               engine reads).
                                The ONE-ROW call, which every family takes and
                                an engine of width 1 makes (a family that is
                                not `prefill_batched`, or shapes that give 1):

@@ -1716,6 +1716,12 @@ class GPT:
     prefill_batched = True  # prefill_paged_chunk takes B rows, hands back (B, V)
 
     @staticmethod
+    def prefill_rows(config: GPTConfig, dense_rows: int) -> int:
+        """Token rows a prefill call should carry: every weight sees every row,
+        so the rows a dense weight wants (sampling/serve.py `PREFILL_ROWS`)."""
+        return dense_rows
+
+    @staticmethod
     def cache_kinds(config: GPTConfig) -> tp.Tuple[CacheKind, ...]:
         """One kind: every layer keeps K/V alike (windowed, if the model is)."""
         return (CacheKind("kv", config.sliding_window, config.attn_sinks),)

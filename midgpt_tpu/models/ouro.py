@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from midgpt_tpu.models.gpt import CacheKind, _gather_layer_kv, _paged_write, pool_lanes
+from midgpt_tpu.models.gpt import GPT, CacheKind, _gather_layer_kv, _paged_write, pool_lanes
 from midgpt_tpu.ops.moe import swiglu
 from midgpt_tpu.ops.norms import rms_norm
 from midgpt_tpu.ops.rope import apply_rope_leading, rope_table
@@ -209,6 +209,8 @@ class Ouro:
     verify_step_paged = None  # no speculative verify over the looped cache
     # a prefill call reads the layers' weights n_loop times whatever rides it: the round's slots ride as rows
     prefill_batched = True
+
+    prefill_rows = staticmethod(GPT.prefill_rows)  # every weight is dense and sees every row: the ridge's rows
 
     @staticmethod
     def init(config: OuroConfig, key: KeyArray) -> OuroParams:

@@ -72,7 +72,7 @@ from midgpt_tpu.models.mimo_v2 import (
 )
 from midgpt_tpu.ops.attention import visible_mask
 from midgpt_tpu.ops.moe import (
-    moe_count_decode, moe_count_dropped, moe_counters_init, moe_serve_counters, moe_serving, swiglu,
+    moe_count_decode, moe_count_dropped, moe_counters_init, moe_prefill_rows, moe_serve_counters, moe_serving, swiglu,
 )
 from midgpt_tpu.ops.norms import rms_norm
 from midgpt_tpu.ops.online_softmax import MASK
@@ -245,13 +245,42 @@ def _norm(c: TrinityConfig, x: Array, w: Array) -> Array:
     return rms_norm(x.astype(jnp.float32), w.astype(jnp.float32), c.rms_norm_eps).astype(x.dtype)
 
 
+def _rows_in_turn(attend: tp.Callable[..., Array], live: Array, *rows: Array) -> Array:
+    """A batched prefill call's attention ROW AFTER ROW inside the program:
+    `attend(*row)` for row b of every array of `rows` (leading axis B) in turn
+    (`jax.lax.map`), stacked; a row that is not `live` (B,) (an empty place)
+    runs nothing and gives zeros. Attention reads the POOLS and no weight, so
+    nothing is lost by not batching it: each row keeps its own table row and
+    its own bounds (a long slot does not lengthen a short one's sweep), and
+    one row's float32 scores are live at a time (a window layer's are 170 MB a
+    row at the published widths). The XLA gather of B rows' windows at once
+    also lowers badly on the v5e: 25 ms a layer at B = 4 for 0.3 ms a row in
+    turn (PERF.md section 6 PR 52)."""
+    out = jax.eval_shape(attend, *(r[0] for r in rows))
+
+    def one(row):
+        ok, *args = row
+        return jax.lax.cond(ok, lambda: attend(*args), lambda: jnp.zeros(out.shape, out.dtype))
+
+    return jax.lax.map(one, (live, *rows))
+
+
 class Trinity:
     """Namespace of pure functions over (TrinityConfig, TrinityParams)."""
 
     weight_decay_mask = None
     route_stats = None
     verify_step_paged = None  # no speculative verify over the two-kind cache (the engine refuses a draft)
-    prefill_batched = False  # one row a call: two page tables a slot, window pages freed per slot
+    # the chunks of a round's prefilling slots ride one call: the engine hands every kind's (B, pages) table and
+    # frees window pages per slot after it; only the attention is per slot, and runs row after row inside the program
+    prefill_batched = True
+
+    @staticmethod
+    def prefill_rows(config: TrinityConfig, dense_rows: int) -> int:
+        """Token rows a prefill call should carry: what brings every routed
+        expert its share (ops/moe.py `moe_prefill_rows`: 2,048 at the published
+        128 experts, top-8), which is past what the dense weights want."""
+        return max(dense_rows, moe_prefill_rows(dense_rows, config.moe_top_k, config.n_experts))
 
     @staticmethod
     def init(config: TrinityConfig, key: KeyArray) -> TrinityParams:
@@ -367,25 +396,27 @@ class Trinity:
         return x + _norm(c, jnp.einsum("bte,de->btd", o, p.attn.wo), p.norm_post_attn)
 
     @staticmethod
-    def _moe(c: TrinityConfig, p: MoEParams, x: Array) -> tp.Tuple[Array, Array, tp.Dict[str, Array]]:
+    def _moe(c: TrinityConfig, p: MoEParams, x: Array, valid: tp.Optional[Array] = None):
         """x (N, D) -> (the shared expert + the held experts' part of the routed
-        layer (N, D), idx (N, k), stats)."""
+        layer (N, D), idx (N, k), stats). `valid` (N,): the rows that are tokens
+        (`moe_serving`); the others take no place among the experts' rows."""
         y, idx, stats = moe_serving(x, p.router, p.expert_bias, p.w_gate, p.w_up, p.w_down, top_k=c.moe_top_k,
-                                    scale=c.route_scale, renormalize=c.route_norm, offset=c.expert_offset)
+                                    scale=c.route_scale, renormalize=c.route_norm, offset=c.expert_offset, valid=valid)
         if p.shared is not None:
             with jax.named_scope("moe_shared"):
                 y = y + swiglu(x, p.shared.w_gate, p.shared.w_up, p.shared.w_down)
         return y, idx, stats
 
     @staticmethod
-    def _ffn(c: TrinityConfig, i: int, p: LayerParams, x: Array):
-        """x (B, T, D) + norm_post_mlp(FFN(norm_pre_mlp(x))); (x, idx | None, stats | None)."""
+    def _ffn(c: TrinityConfig, i: int, p: LayerParams, x: Array, valid: tp.Optional[Array] = None):
+        """x (B, T, D) + norm_post_mlp(FFN(norm_pre_mlp(x))); (x, idx | None, stats | None).
+        `valid` (B * T,): a routed layer's rows that are tokens (`_moe`)."""
         with jax.named_scope("mlp"):
             h = _norm(c, x, p.norm_pre_mlp)
             if c.mlp_kind(i) == "dense":
                 return x + _norm(c, swiglu(h, p.mlp.w_gate, p.mlp.w_up, p.mlp.w_down), p.norm_post_mlp), None, None
             B, T, D = h.shape
-            y, idx, stats = Trinity._moe(c, p.mlp, h.reshape(B * T, D))
+            y, idx, stats = Trinity._moe(c, p.mlp, h.reshape(B * T, D), valid)
             return x + _norm(c, y.reshape(B, T, D), p.norm_post_mlp), idx, stats
 
     @staticmethod
@@ -543,52 +574,74 @@ class Trinity:
     def prefill_paged_chunk(config: TrinityConfig, params: TrinityParams, tokens: Array, start: Array,
                             n_valid: Array, cache: MimoKVCache, page_table: tp.Tuple[Array, Array],
                             attn_impl: str = "auto", mesh=None) -> tp.Tuple[Array, MimoKVCache]:
-        """One request's prompt chunk [start, start + n_valid) into its pages
-        of both pools (GPT.prefill_paged_chunk's contract; `page_table` is the
-        slot's (global row, window row), both (1, pages)). The window row's
-        entries behind `start - sliding_window` may have been freed: they are
-        never read. Returns (logits of the LAST VALID row (1, 1, V), cache):
-        the engine samples from that row alone, and a 512-row chunk's logits
-        over 200,192 columns are 205 MB it would otherwise copy to the host."""
+        """The prompt chunks of B slots, row b's being [start[b], start[b] +
+        n_valid[b]), into their pages of both pools (GPT.prefill_paged_chunk's
+        contract; `page_table` is (global table, window table), both (B,
+        pages), row b the slot's). A window row's entries behind `start -
+        sliding_window` may have been freed: they are never read. A row with
+        n_valid 0 is an empty place: it writes nothing, attends to nothing and
+        takes no row of an expert's block, and its logits mean nothing.
+
+        Everything that reads a WEIGHT sees the B x T rows at once (the
+        projections, the dense layer, the shared expert; the routed layers read
+        their 128 experts once a call, and the rows past a slot's `n_valid` are
+        routed nowhere: `moe_serving`'s `valid`). What reads the POOLS, the
+        attention of both kinds, runs ROW AFTER ROW inside the program
+        (`_rows_in_turn`): a window layer gathers the row's own window of
+        pages, a global layer sweeps the row's own context (`prefill_sweep`,
+        bounded by one slot's length), and an empty row runs neither.
+
+        Returns (logits (B, V) of each row's LAST VALID position, cache): the
+        engine samples from that row alone, and a 512-row chunk's logits over
+        200,192 columns are 205 MB a slot. The one-row call (SCALAR `start` and
+        `n_valid`, models/__init__.py) is the same program at B = 1 and hands
+        back (1, 1, V)."""
         from midgpt_tpu.kernels.decode_attention import resolve_paged_impl
 
         if mesh is not None:
             raise NotImplementedError(f"{FAMILY}: no serving mesh")
         c = config
         attn_impl = resolve_paged_impl(attn_impl)
+        one_row = jnp.ndim(start) == 0
+        start, n_valid = jnp.reshape(start, (-1,)), jnp.reshape(n_valid, (-1,))
         tables = dict(zip((GLOBAL, WINDOW), page_table))
-        _, T = tokens.shape
+        B, T = tokens.shape
         ps, W = cache.page_size, c.sliding_window
         t_idx = jnp.arange(T, dtype=jnp.int32)
-        positions = start + t_idx
-        valid = t_idx < n_valid
-        counts = jnp.minimum(positions, start + n_valid - 1) + 1  # pad rows see what the last valid row sees
+        positions = start[:, None] + t_idx  # (B, T)
+        valid, live = t_idx < n_valid[:, None], n_valid > 0  # (B, T) the rows that are tokens; (B,) the places that hold a chunk
+        # pad rows see what the slot's last valid row sees; an empty row, nothing
+        counts = jnp.minimum(positions, (start + n_valid)[:, None] - 1) + 1
         rope = rope_table(c.head_dim, c.block_size, c.rope_theta)
         pools = cache.pools()
         write_pages = {
-            kind: jnp.where(valid, jnp.take(t[0], positions // ps, axis=0), pools[kind][0].shape[2])
+            kind: jnp.where(valid, jnp.take_along_axis(t, positions // ps, axis=1), pools[kind][0].shape[2])
             for kind, t in tables.items()
         }
         MP = tables[WINDOW].shape[1]
         n_win = min(MP, -(-(W + T) // ps) + 1)
-        first = jnp.minimum(jnp.maximum(start + 1 - W, 0) // ps, MP - n_win)
-        win_ids = jax.lax.dynamic_slice_in_dim(tables[WINDOW][0], first, n_win)[None]  # (1, n_win)
-        totals = cache.moe_totals
-        x = Trinity._embed(c, params, tokens)  # (1, T, D)
+        first = jnp.minimum(jnp.maximum(start + 1 - W, 0) // ps, MP - n_win)  # (B,)
+        win_ids = jnp.take_along_axis(tables[WINDOW], first[:, None] + jnp.arange(n_win, dtype=jnp.int32), axis=1)
+        totals, token_rows = cache.moe_totals, valid.reshape(-1)
+        x = Trinity._embed(c, params, tokens)  # (B, T, D)
         for i, (p, (kind, li)) in enumerate(zip(params.layers, c.pool_layers)):
             with jax.named_scope("attn"), jax.named_scope("attn_" + kind):
                 u = _norm(c, x, p.norm_in)
                 q, k, v = Trinity._qkv(c, kind, p.attn, u, rope, positions)
                 pk, pv, _, _ = _paged_write((*pools[kind], None, None), jnp.asarray(li), write_pages[kind],
-                                            positions % ps, k[0], v[0], attn_impl, None)
+                                            positions % ps, k, v, attn_impl, None)
                 pools[kind] = (pk, pv)
-                if kind == WINDOW:
-                    o = Trinity._gather_attention(c, kind, q, pk, pv, li, win_ids, (first * ps)[None], counts[None])
-                else:
-                    o = prefill_sweep(q[0], pk, pv, li, tables[kind][0], counts, n_kv=c.n_kv_heads, dv=c.head_dim)[None]
+                if kind == WINDOW:  # the pages that [start - W, start + chunk) touches, gathered
+                    attend = lambda q, ids, col0, n: Trinity._gather_attention(
+                        c, kind, q[None], pk, pv, li, ids[None], col0[None], n[None])[0]
+                    o = _rows_in_turn(attend, live, q, win_ids, first * ps, counts)
+                else:  # the slot's whole context in blocks of keys, as far as its own length
+                    attend = lambda q, row, n: prefill_sweep(q, pk, pv, li, row, n, n_kv=c.n_kv_heads, dv=c.head_dim)
+                    o = _rows_in_turn(attend, live, q, tables[kind], counts)
                 x = Trinity._attn_out(c, p, x, u, o)
-            x, idx, stats = Trinity._ffn(c, i, p, x)
+            x, idx, stats = Trinity._ffn(c, i, p, x, token_rows)
             if idx is not None:
                 totals = moe_count_dropped(totals, stats["dropped"])
-        last = jax.lax.dynamic_slice_in_dim(x, jnp.maximum(n_valid - 1, 0), 1, axis=1)  # (1, 1, D)
-        return Trinity._head(c, params, last), MimoKVCache.of(pools, cache.moe_counts, totals)
+        last = jnp.take_along_axis(x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)  # (B, 1, D)
+        logits = Trinity._head(c, params, last)
+        return logits if one_row else logits[:, 0], MimoKVCache.of(pools, cache.moe_counts, totals)

@@ -185,6 +185,20 @@ def moe_row_block(n_tokens: int, top_k: int, n_experts: int, itemsize: int) -> i
     return block
 
 
+def moe_prefill_rows(dense_rows: int, top_k: int, n_experts: int) -> int:
+    """Token rows a BATCHED prefill call should bring a routed layer, where a
+    dense weight wants `dense_rows` (the chip's ridge: sampling/serve.py
+    `PREFILL_ROWS`, which is also the largest row block of `moe_row_block`).
+    An expert multiplies `top_k / n_experts` of a call's rows, and its block
+    costs the expert's three matrices streamed once whatever it holds; a run
+    that passes the block starts a second one, which streams the expert again
+    (`kernels/grouped_matmul.py`: F is sliced). The most loaded expert's run is
+    2.4 to 2.9 times the mean, so the rows asked for are those that fill the
+    block to HALF on average: `dense_rows / 2` pairs an expert (128: one pass
+    of the v5e's 128-row MXU). Trinity: 128 * 128 / 8 = 2,048 token rows."""
+    return dense_rows // 2 * n_experts // top_k
+
+
 def moe_serving_plan(
     idx: Array, weights: Array, *, offset: int, n_held: int, block_rows: int,
 ) -> tp.Dict[str, Array]:
@@ -268,16 +282,25 @@ def moe_experts_serving(
 
 def moe_serving(
     x: Array, router: Array, bias: Array, w_gate: Array, w_up: Array, w_down: Array,
-    *, top_k: int, scale: float, renormalize: bool, offset: int,
+    *, top_k: int, scale: float, renormalize: bool, offset: int, valid: tp.Optional[Array] = None,
 ) -> tp.Tuple[Array, Array, tp.Dict[str, Array]]:
     """x (N, D) -> (the held experts' part of the routed layer (N, D), idx (N,
     k), stats): `route` under the `moe_route` scope, then `moe_experts_serving`
     at the row block the call's shapes give (`moe_row_block`). The router keeps
     its published width (`router`'s leading axis) whatever is held here. Every
     device op of it lies under `moe_route` or `moe_experts`, which are ONE cost
-    (the benchmark divides the expert bytes a step must read by their sum)."""
+    (the benchmark divides the expert bytes a step must read by their sum).
+
+    `valid` (N,) bool: the rows that are tokens (a batched prefill call's rows
+    past `n_valid` and its empty places are not). A row that is not valid is
+    routed NOWHERE: its `idx` comes back -1, which no chip holds, so the plan
+    gives its pairs no row of the buffer (they read zeros back: its y is 0),
+    they are in no expert's count and no block is in use for them. None: every
+    row is a token, and the lowering is the one without the argument."""
     with jax.named_scope("moe_route"):
         idx, w = route(x, router, bias, top_k=top_k, scale=scale, renormalize=renormalize)
+        if valid is not None:
+            idx = jnp.where(valid[:, None], idx, -1)
     y, stats = moe_experts_serving(
         x, idx, w, w_gate, w_up, w_down, offset=offset,
         block_rows=moe_row_block(x.shape[0], top_k, router.shape[0], w_gate.dtype.itemsize))

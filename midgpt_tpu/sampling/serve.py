@@ -419,19 +419,32 @@ def _serve_decode_logits(
 # 16-token chunk alone reads the whole model for a fifteenth of that. 256 is
 # the ridge rounded to a power of two. The chunks of a round's prefilling
 # slots ride as rows of one batch until they make up that many.
+#
+# That is a DENSE weight's arithmetic: it multiplies every token row. A
+# routed expert multiplies `top_k / n_experts` of them (Trinity: a sixteenth:
+# a 512-token chunk brings each of 128 experts 32 pairs, and streams all
+# 128), so a family with routed layers asks for more token rows a call: its
+# `prefill_rows(config, PREFILL_ROWS)` (ops/moe.py `moe_prefill_rows`: the
+# rows that bring an expert HALF this many pairs on average, since the most
+# loaded expert's run is over twice the mean and a run past the kernel's
+# 256-row block streams its expert again; Trinity: 2,048 rows, four chunks).
 PREFILL_ROWS = 256
 
 
-def prefill_width(max_slots: int, prefill_chunk: int) -> int:
-    """Chunks (slots) a prefill program takes: as many as bring
-    `PREFILL_ROWS` token rows to a weight read, no more than there are
-    slots. STATIC for an engine: one program a page bucket whatever the
-    number of prefilling slots (a round with more goes in groups; a call
-    with fewer leaves empty rows), so the compile set does not grow and a
-    warm-up that runs requests alone visits every program. A chunk that
-    is past the ridge on its own (512 tokens) gives 1: the one-row call,
-    a slot at a time."""
-    return min(max_slots, max(1, PREFILL_ROWS // prefill_chunk))
+def prefill_width(max_slots: int, prefill_chunk: int, rows: tp.Optional[int] = None) -> int:
+    """Chunks (slots) a prefill program takes: as many as bring `rows`
+    token rows to a call, no more than there are slots. `rows` is what the
+    FAMILY states for its configuration (`prefill_rows(config,
+    PREFILL_ROWS)`, models/__init__.py): `PREFILL_ROWS` itself where every
+    weight sees every row (the default), more where a weight sees a share
+    of them (a routed expert). STATIC for an engine: one program a page
+    bucket whatever the number of prefilling slots (a round with more goes
+    in groups; a call with fewer leaves empty rows), so the compile set
+    does not grow and a warm-up that runs requests alone visits every
+    program. A chunk that is past `rows` on its own (a dense family's 512
+    tokens) gives 1: the one-row call, a slot at a time."""
+    rows = PREFILL_ROWS if rows is None else rows
+    return min(max_slots, max(1, rows // prefill_chunk))
 
 
 # Cap on the fused multi-round group size (docs/SERVING.md "Round-overlap
@@ -957,9 +970,11 @@ class ServeEngine:
         self.max_slots = max_slots
         self.prefill_chunk = prefill_chunk
         # rows of the prefill program: the module's rule at this engine's
-        # shapes; 1 for a family whose `prefill_paged_chunk` takes one row
+        # shapes and the token rows the family asks for at its configuration;
+        # 1 for a family whose `prefill_paged_chunk` takes one row
         self.prefill_width = (
-            prefill_width(max_slots, prefill_chunk) if self.model.prefill_batched else 1
+            prefill_width(max_slots, prefill_chunk, self.model.prefill_rows(config, PREFILL_ROWS))
+            if self.model.prefill_batched else 1
         )
         self.decode_chunk = decode_chunk
         self.temperature = temperature

@@ -922,7 +922,13 @@ def test_window_layers_decode_through_the_kernel_at_the_published_geometry(progr
     BOTH kinds' attention are Mosaic custom calls (four under `attn_window`: no
     gathered copy of the window's pages; one under `attn_global`) beside the
     five in-place writes, the prefill program has the five writes alone, and
-    neither copies or relays out a pool."""
+    neither copies or relays out a pool. The prefill program is the BATCHED
+    one, `(W, 512)` at the width the family's rows give (4: the chunks of four
+    slots read the 128 experts once): every layer's attention is ONE loop over
+    the rows (the global layer's around its loop over a row's key blocks), and
+    its temporaries (the experts' buffer of 49,152 rows; ONE row's window
+    scores at a time) are recorded in the assertion: 0.68 GB here, held under
+    1 GB, beside 8.5 GB of weights and 2.7 of pools (0.29 GB at one row)."""
     import dataclasses
     import re
 
@@ -943,16 +949,24 @@ def test_window_layers_decode_through_the_kernel_at_the_published_geometry(progr
             mc, params, arr((B,)), cache, (arr((B, T)), arr((B, T))), arr((B,)), arr((B,), jnp.bool_), 8,
             0.8, None, None, "kernel", arr((2,), jnp.uint32), None, 8)
     else:
+        W = serve.prefill_width(B, 512, model.prefill_rows(mc, serve.PREFILL_ROWS))
+        assert model.prefill_batched and W == 4
         lowered = serve._serve_prefill_chunk.lower(
-            mc, params, arr((1, 512)), arr(()), arr(()), cache, (arr((1, T)), arr((1, T))), None, "kernel",
+            mc, params, arr((W, 512)), arr((W,)), arr((W,)), cache, (arr((W, T)), arr((W, T))), None, "kernel",
             0.8, None, None, arr((2,), jnp.uint32))
-    text = lowered.compile().as_text()
+    compiled = lowered.compile()
+    text = compiled.as_text()
     paths = re.findall(r'custom-call\([^\n]*tpu_custom_call[^\n]*?op_name="([^"]*)"', text)
     attention = [p for p in paths if "kv_write" not in p and "moe_experts" not in p]
     assert sum("kv_write" in p for p in paths) == 5
     assert sum("moe_experts" in p for p in paths) == len(mc.moe_layers) == 4  # ONE grouped matmul a routed layer
+    loops = re.findall(r' while\([^\n]*op_name="([^"]*)"', text)
     if program == "decode8":  # the step loop is the program's ONLY loop: the expert loop, whose trip count was data, is gone
-        assert re.findall(r' while\([^\n]*op_name="([^"]*)"', text) == ["jit(_serve_decode_chunk)/while"]
+        assert loops == ["jit(_serve_decode_chunk)/while"]
+    else:  # the rows in turn, a layer: four window layers' gathers, the global layer's sweep over the key blocks a row sees
+        assert sorted(p.split("/")[2] for p in loops) == ["attn_global"] * 2 + ["attn_window"] * 4, loops
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert 0.5e9 < temp < 1e9, f"prefill ({W}, 512) temporaries: {temp / 1e9:.2f} GB"
     assert sorted(p.split("/")[-2] for p in attention) == (["attn_global"] + ["attn_window"] * 4 if program == "decode8" else [])
     assert pool_relayouts(text, [a.shape for a in cache.pool_arrays()]) == 0
 

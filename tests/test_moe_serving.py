@@ -193,3 +193,34 @@ def test_the_counter_reads_blocks_in_use():
     assert got["moe.experts_touched"] == 1 and got["moe.expert_visits"] == int(stats["visits"]) == 2
     totals = moe.moe_count_dropped(jnp.zeros((4,), jnp.int32), jnp.asarray(2))
     assert totals.tolist() == [0, 0, 2, 0]  # a prefill chunk counts what it dropped, nothing else
+
+
+@pytest.mark.parametrize("case", ["a_chunks_padded_tail", "empty_places_between_rows", "no_row_is_a_token"])
+def test_rows_that_are_no_tokens_take_no_place_among_the_experts(case):
+    """`moe_serving(valid=...)` (a batched prefill call's rows past `n_valid`
+    and its empty places): the valid rows get what the call over them ALONE
+    gives, the others zeros and `idx` -1; no pair of theirs is in an expert's
+    count, nothing is dropped, and the blocks in use are the blocks the valid
+    rows' counts need, at the row block of the WHOLE call's shape."""
+    rows, n_experts, n_held, offset, top_k = 48, 16, 8, 4, 4
+    valid = {"a_chunks_padded_tail": np.arange(rows) < 29,
+             "empty_places_between_rows": (np.arange(rows) // 12) % 2 == 0,
+             "no_row_is_a_token": np.zeros(rows, bool)}[case]
+    key = jax.random.PRNGKey(11)
+    x = jax.random.normal(key, (rows, D))
+    router, bias = jax.random.normal(jax.random.fold_in(key, 1), (n_experts, D)), jnp.zeros((n_experts,))
+    wg, wu, wd = _weights(n_held)
+    serve = lambda xx, vv: moe.moe_serving(xx, router, bias, wg, wu, wd, top_k=top_k, scale=1.5, renormalize=True,
+                                           offset=offset, valid=vv)
+    y, idx, stats = jax.jit(serve)(x, jnp.asarray(valid))
+    y, idx = np.asarray(y), np.asarray(idx)
+    assert (idx[~valid] == -1).all() and not y[~valid].any() and int(stats["dropped"]) == 0
+    block = moe.moe_row_block(rows, top_k, n_experts, 4)
+    if valid.any():
+        y_alone, idx_alone, alone = serve(x[valid], None)
+        np.testing.assert_allclose(y[valid], np.asarray(y_alone), atol=2e-5)
+        np.testing.assert_array_equal(idx[valid], np.asarray(idx_alone))
+        np.testing.assert_array_equal(np.asarray(stats["counts"]), np.asarray(alone["counts"]))
+    counts = np.asarray(stats["counts"])
+    assert counts.sum() == ((idx >= offset) & (idx < offset + n_held)).sum()
+    assert int(stats["visits"]) == int(np.sum(-(-counts // block))) and (valid.any() or int(stats["visits"]) == 0)

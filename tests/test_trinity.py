@@ -158,7 +158,8 @@ def test_engine_prefill_then_decode_match_the_reference_past_the_window(model):
     eng = ServeEngine(c, params, max_slots=3, page_size=4, prefill_chunk=10, decode_chunk=4, temperature=0.8, seed=5,
                       cache_dtype="float32", on_first_logits=lambda uid, row: first.setdefault(uid, np.array(row)))
     assert isinstance(eng.cache, MimoKVCache) and [k.name for k in eng.kinds] == [GLOBAL, WINDOW]
-    assert eng.cache.gk.shape[:2] == (1, 2) and eng.cache.wk.shape[:2] == (4, 2) and eng.prefill_width == 1
+    assert eng.cache.gk.shape[:2] == (1, 2) and eng.cache.wk.shape[:2] == (4, 2)
+    assert eng.prefill_width == 3 == eng.max_slots  # the toy's 16 experts at top-4 ask for 512 token rows: every slot rides
     uids = {eng.submit(_tokens(p, seed=p), 13): p for p in (37, 50, 11)}
     assert 50 > 2 * c.sliding_window + eng.prefill_chunk
     while not eng.idle:
@@ -177,6 +178,33 @@ def test_engine_prefill_then_decode_match_the_reference_past_the_window(model):
         assert len(later[uid]) >= 2 and all(r >= p for r, _ in later[uid])
         for r, row in later[uid]:
             np.testing.assert_allclose(row, want[r], atol=ATOL)
+
+
+def test_the_batched_engine_serves_the_width_one_engines_tokens(model, monkeypatch):
+    """Five prompts on four slots, greedy, two of them past 2 x window + chunk
+    (window pages reclaimed while their chunks ride a call with others'): the
+    engine whose prefill calls carry the round's slots as rows produces, token
+    for token, what the engine of width 1 does (the family's one-row call, a
+    slot at a time: what `prefill_batched = False` gave before), in fewer calls."""
+    c, params = model
+    prompts = [(_tokens(p, seed=p), m) for p, m in ((37, 9), (50, 6), (11, 12), (29, 5), (64, 7))]
+
+    def run():
+        eng = ServeEngine(c, params, max_slots=4, page_size=4, prefill_chunk=10, decode_chunk=4, temperature=0.0,
+                          cache_dtype="float32")
+        uids = [eng.submit(p, m) for p, m in prompts]
+        done = eng.run()
+        counters = eng.serve_counters()
+        assert counters["moe.dropped"] == 0 and counters["kv.window_pages_reclaimed"] > 0
+        return eng, [np.asarray(done[u].tokens) for u in uids]
+
+    batched, got = run()
+    monkeypatch.setattr(Trinity, "prefill_batched", False)
+    one_row, want = run()
+    assert (batched.prefill_width, one_row.prefill_width) == (4, 1)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    assert batched.prefill_chunks == one_row.prefill_chunks == one_row.prefill_calls > 1.5 * batched.prefill_calls
 
 
 def test_window_decode_through_the_kernel_never_reads_behind_the_window(model, monkeypatch):
