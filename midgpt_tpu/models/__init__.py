@@ -146,6 +146,15 @@ calls them, never a family by name):
     kernel_sweep(config, cache) -> (pool shape, q rows a pool head, window,
                                sinks) of the decode kernel's sweep, for the
                                engine's block counters
+    kernel_sweep_whole         True: that sweep is EVERY paged-attention
+                               kernel call of the family's decode program
+                               (layers that gather have no blocks), so a
+                               round's two integers can ride its
+                               `decode.dispatch` span as `blocks_swept` /
+                               `blocks_live`. False (Trinity: global and
+                               window layers both run the template, at two
+                               geometries): the counters keep the one
+                               kernel's figure, the span says nothing
     serve_counters             None, or (config, cache) -> {counter: number}
                                the family's own counters kept in the cache
                                (MimoV2, PanguUltra, Trinity, Dots3: the expert

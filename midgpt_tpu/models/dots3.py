@@ -705,6 +705,8 @@ class Dots3:
             moe_counts=moe_counts, moe_totals=moe_totals, dsa=jnp.zeros((3, 2), jnp.uint32),
         )
 
+    kernel_sweep_whole = True  # the window layers' is the decode program's only kernel: the full layers gather the selected rows
+
     @staticmethod
     def kernel_sweep(config: Dots3Config, cache: KindsKVCache) -> tp.Tuple[tp.Tuple[int, ...], int, int, int]:
         """(pool shape, q rows a pool head, window, sinks) of the decode

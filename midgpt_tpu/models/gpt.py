@@ -1731,6 +1731,8 @@ class GPT:
                    dtype=jnp.bfloat16, kernel_layout: bool = False) -> PagedKVCache:
         return PagedKVCache.init(config, num_pages[0], page_size, dtype, kernel_layout)
 
+    kernel_sweep_whole = True  # every layer's decode attention is this one kernel call
+
     @staticmethod
     def kernel_sweep(config: GPTConfig, cache: PagedKVCache):
         """(pool shape, q rows a pool head, window, sinks) of the decode

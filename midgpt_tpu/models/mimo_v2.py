@@ -593,6 +593,8 @@ class MimoV2:
         moe_counts, moe_totals = moe_counters_init(len(c.moe_layers), c.n_experts_held)
         return MimoKVCache(gk=gk, gv=gv, wk=wk, wv=wv, moe_counts=moe_counts, moe_totals=moe_totals)
 
+    kernel_sweep_whole = True  # the global layers' is the decode program's only kernel: the window layers gather
+
     @staticmethod
     def kernel_sweep(config: MimoV2Config, cache: MimoKVCache) -> tp.Tuple[tp.Tuple[int, ...], int, int, int]:
         """(pool shape, q rows a pool head, window, sinks) of the decode

@@ -380,6 +380,8 @@ class Ouro:
         return LoopedKVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
                              loop_steps=jnp.zeros((2,), jnp.int32), exit_mass=jnp.zeros((c.n_loop,), jnp.float32))
 
+    kernel_sweep_whole = True  # every pass of every layer is this one kernel call
+
     @staticmethod
     def kernel_sweep(config: OuroConfig, cache: LoopedKVCache):
         """(pool shape, q rows a pool head, window, sinks) of the decode

@@ -510,6 +510,8 @@ class PanguUltra:
         return LatentKVCache(kv=jnp.zeros((c.n_layer, 1, num_pages[0], page_size, lanes), dtype),
                              moe_counts=moe_counts, moe_totals=moe_totals)
 
+    kernel_sweep_whole = True  # every layer's absorbed decode is this one kernel call
+
     @staticmethod
     def kernel_sweep(config: PanguUltraConfig, cache: LatentKVCache) -> tp.Tuple[tp.Tuple[int, ...], int, int, int]:
         """(pool shape, q rows a pool head, window, sinks) of the decode

@@ -489,6 +489,8 @@ class Trinity:
         return MimoKVCache.of({GLOBAL: pools(GLOBAL, num_pages[0]), WINDOW: pools(WINDOW, num_pages[1])},
                               moe_counts, moe_totals)
 
+    kernel_sweep_whole = False  # the window layers run the template too, at another geometry: the sweep below is one of two
+
     @staticmethod
     def kernel_sweep(config: TrinityConfig, cache: MimoKVCache) -> tp.Tuple[tp.Tuple[int, ...], int, int, int]:
         """(pool shape, q rows a pool head, window, sinks) of the decode

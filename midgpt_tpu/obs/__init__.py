@@ -32,7 +32,17 @@ the dispatch into the child spans `<kind>.assemble`, `.key`, `.put` and
 rode the round: steps, slots, the most steps it could run and which limit
 set them; tokens committed, requests ended, and the seconds of the commit
 spent inside the client's `on_token` (benchmarks/metrics/engine_dispatch.py
-reads all of it). Under overlap="double"
+reads all of it). Since PR 53 every jit call the engine enqueues has a
+number, `call` (`ServeEngine.dispatches` as the call took it): it rides the
+span that brackets the call (`<kind>.enqueue`, `prefill.chunk`, the spec
+round's two enqueue spans), the commit of its tokens (`<kind>.host_post`),
+and the `engine.dispatch` annotation the ENGINE opens around the call with
+`jax.profiler.TraceAnnotation` (not here: this package imports no jax), so
+inside a profile every dispatch is one instant on both clocks. The host
+phases that do not block on the device (`.dispatch`, `.host_post`,
+`prefill.assemble`) also say the engine thread's CPU seconds, `cpu_s`, from
+a second injected clock (`cpu_clock`, `time.thread_time`): wall minus
+`cpu_s` is time the thread was not running. Under overlap="double"
 (sampling/serve.py `_step_overlapped`) round N settles one step late, so
 its t1 -> t_land window CONTAINS host work for other rounds; the engine
 reports that overlapped span via `hidden_s` and it surfaces as the
@@ -105,8 +115,12 @@ class Observability:
         self,
         capacity: int = 16384,
         clock: tp.Callable[[], float] = time.perf_counter,
+        cpu_clock: tp.Callable[[], float] = time.thread_time,
     ):
         self.clock = clock
+        # the calling thread's CPU seconds: the engine reads it beside `clock`
+        # at the ends of the host phases that say `cpu_s` (module docstring)
+        self.cpu_clock = cpu_clock
         self.tracer = Tracer(capacity=capacity, clock=clock)
         self.metrics = MetricsRegistry()
         # round decomposition histograms, seconds; surfaced in ms
@@ -185,6 +199,10 @@ class Observability:
         tokens: tp.Optional[int] = None,
         finished: tp.Optional[int] = None,
         callback_s: tp.Optional[float] = None,
+        call: tp.Optional[int] = None,
+        bucket: tp.Optional[int] = None,
+        cpu: tp.Optional[tp.Tuple[float, float, float, float]] = None,
+        blocks: tp.Optional[tp.Tuple[int, int]] = None,
     ) -> None:
         """Record one engine round's boundary clock readings (see module
         docstring for the four-boundary semantics). Also emits the three
@@ -204,42 +222,66 @@ class Observability:
         the children `.assemble`, `.key` (t_k None: a greedy round, no key),
         `.put` (the page tables' build) and `.enqueue` (the one jit call,
         its numpy arguments' transfer with it) tile the dispatch span,
-        recorded after it and named its children (`Tracer.complete`)."""
+        recorded after it and named its children (`Tracer.complete`).
+
+        `call` is the number of the round's jit call (module docstring): on
+        `.enqueue`, with the `steps`, `slots` and page `bucket` its program
+        ran at, and on `.host_post`, whose tokens that program made (a round
+        under overlap settles a step late). `cpu` = `cpu_clock` read where
+        t0, t1, t_land and t_post were: `cpu_s` of the dispatch and of the
+        commit (not of the wait, which blocks on the device by design).
+        `blocks` = (swept, live) of the round's paged-attention grid
+        (`record_decode_blocks`' two integers), on the dispatch span as
+        `blocks_swept` / `blocks_live`; the engine hands none where they
+        would describe one of a program's several kernels."""
         self._h_dispatch.observe(t1 - t0)
         self._h_device.observe(t_land - t1)
         self._h_post.observe(t_post - t_land)
         self._h_hidden.observe(hidden_s)
-        rode = None
+        rode: tp.Dict[str, tp.Any] = {}  # the dispatch span's args, the commit's
+        commit: tp.Dict[str, tp.Any] = {}
         if steps is not None:
             self._h_round_steps.observe(steps)
-            rode = {"steps": steps, "slots": slots, "chunk": chunk, "limit": limit}
+            rode.update(steps=steps, slots=slots, chunk=chunk, limit=limit)
+        if blocks is not None:
+            rode["blocks_swept"], rode["blocks_live"] = blocks
+        if tokens is not None:
+            commit.update(tokens=tokens, finished=finished, callback_s=callback_s)
+        if cpu is not None:
+            rode["cpu_s"], commit["cpu_s"] = cpu[1] - cpu[0], cpu[3] - cpu[2]
+        if call is not None:
+            commit["call"] = call
         complete = self.tracer.complete
-        seq = complete(f"{kind}.dispatch", "round", tid, t0, t1 - t0, rode)
+        seq = complete(f"{kind}.dispatch", "round", tid, t0, t1 - t0, rode or None)
         if cuts is not None:
             t_a, t_k, t_p = cuts
             self._children(seq, "round", tid, t0, (
-                (f"{kind}.assemble", t_a), (f"{kind}.key", t_k),
-                (f"{kind}.put", t_p), (f"{kind}.enqueue", t1),
+                (f"{kind}.assemble", t_a), (f"{kind}.key", t_k), (f"{kind}.put", t_p),
             ))
+            complete(
+                f"{kind}.enqueue", "round", tid, t_p, t1 - t_p,
+                None if call is None else
+                {"call": call, "steps": steps, "bucket": bucket, "slots": slots},
+                parent=seq,
+            )
         complete(f"{kind}.device_wait", "round", tid, t1, t_land - t1)
-        complete(
-            f"{kind}.host_post", "round", tid, t_land, t_post - t_land,
-            None if tokens is None else
-            {"tokens": tokens, "finished": finished, "callback_s": callback_s},
-        )
+        complete(f"{kind}.host_post", "round", tid, t_land, t_post - t_land, commit or None)
 
     def record_prefill_assemble(
         self, tid: str, rid: int, t0: float, t_n: float,
         t_p: tp.Optional[float], t_end: float,
+        cpu_s: tp.Optional[float] = None,
     ) -> None:
         """A prefill call's host time BEFORE its enqueue span
         (`prefill.chunk`) opens at `t_end`: the numpy chunk, starts and page
         bucket (t0 -> t_n: the span's self time), then the children
         `prefill.put` (the call's page-table rows, built in numpy) and, in
         a sampled call, `prefill.key` (the host's time on the key, from
-        t_p: the program splits it, so two clock reads apart)."""
+        t_p: the program splits it, so two clock reads apart). `cpu_s`: the
+        thread's CPU seconds over t0 -> t_end (`cpu_clock` at both ends)."""
         seq = self.tracer.complete(
-            "prefill.assemble", "prefill", tid, t0, t_end - t0, rid=rid
+            "prefill.assemble", "prefill", tid, t0, t_end - t0,
+            None if cpu_s is None else {"cpu_s": cpu_s}, rid=rid,
         )
         self._children(seq, "prefill", tid, t_n, (
             ("prefill.put", t_end if t_p is None else t_p),

@@ -127,6 +127,7 @@ is only distributionally equivalent.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import functools
 import inspect
@@ -157,6 +158,9 @@ from midgpt_tpu.utils.hlo import jit_cache_size, pool_relayouts
 from midgpt_tpu.utils.stack_chunk import call_on_own_chunk
 
 Array = jax.Array
+
+# what a jit call runs in with obs off (`ServeEngine._call_mark`)
+_NO_MARK = contextlib.nullcontext()
 
 
 def _maybe_constrain(cache, mesh):
@@ -823,6 +827,12 @@ class _InflightRound:
     # for obs: the readings inside t0 -> t1, and which term set the steps
     cuts: tp.Tuple[float, tp.Optional[float], float]
     limit: str
+    # the group's dispatch number and page bucket; obs on only: the thread's
+    # CPU clock where t0 and t1 were read, the kernel grid's (swept, live)
+    call: int = 0
+    bucket: int = 0
+    cpu: tp.Tuple[float, float] = (0.0, 0.0)
+    blocks: tp.Optional[tp.Tuple[int, int]] = None
 
 
 class ServeEngine:
@@ -1136,6 +1146,11 @@ class ServeEngine:
         # serve scenarios): scheduling rounds, deadline timeouts,
         # admission sheds, client cancellations, and killed decode rounds.
         self.rounds = 0
+        # Jit calls enqueued on the serving path, every one of the six
+        # programs of this module (`_call_mark`): the number a call takes is
+        # its `call`, and execution k of the engine's programs on the device
+        # is dispatch k (docs/OBSERVABILITY.md "A dispatch on both clocks").
+        self.dispatches = 0
         self.timeouts = 0
         self.shed = 0
         self.cancelled = 0
@@ -1583,6 +1598,7 @@ class ServeEngine:
             "cache_hbm_bytes": self.cache_hbm_bytes(),
             "cache_hbm_bytes_per_shard": self.cache_hbm_bytes_per_shard(),
             "rounds": self.rounds,
+            "dispatches": self.dispatches,
             "overlap_mode": self.overlap,
             "round_group": self.round_group,
             "overlap_kills": self.overlap_kills,
@@ -1920,7 +1936,7 @@ class ServeEngine:
             return None
 
         obs = self.obs
-        t0 = 0.0 if obs is None else self._clock()
+        t0, c0 = (0.0, 0.0) if obs is None else (self._clock(), obs.cpu_clock())
         B = self.max_slots
         token = np.zeros((B,), np.int32)
         lengths = np.zeros((B,), np.int32)
@@ -1954,34 +1970,36 @@ class ServeEngine:
             chain_len = np.zeros((B,), np.int32)
         tables = self.pool.tables(self.slots, bucket)
         t_p = 0.0 if obs is None else self._clock()
-        self.cache, toks, emitted, tok_fin, len_fin, self._key = _serve_decode_group(
-            self.config,
-            self.params,
-            token,
-            self.cache,
-            tables,
-            lengths,
-            active,
-            eos,
-            max_len,
-            chain_mask,
-            chain_token,
-            chain_len,
-            n,
-            self.round_group,
-            self.temperature,
-            self.top_k,
-            self.top_p,
-            self.attn_impl,
-            key,
-            self.mesh,
-            split_k,
-        )
-        t1 = 0.0 if obs is None else self._clock()
+        with self._call_mark():
+            self.cache, toks, emitted, tok_fin, len_fin, self._key = _serve_decode_group(
+                self.config,
+                self.params,
+                token,
+                self.cache,
+                tables,
+                lengths,
+                active,
+                eos,
+                max_len,
+                chain_mask,
+                chain_token,
+                chain_len,
+                n,
+                self.round_group,
+                self.temperature,
+                self.top_k,
+                self.top_p,
+                self.attn_impl,
+                key,
+                self.mesh,
+                split_k,
+            )
+        call = self.dispatches
+        t1, c1 = (0.0, 0.0) if obs is None else (self._clock(), obs.cpu_clock())
         # lengths as the host holds them: a chained slot's trail the
         # device's by the previous group's steps (the count runs low there).
         # Counted after t1, while the device computes: not dispatch time.
-        self._count_blocks(lengths, active, bucket, split_k, n_steps=T)
+        blocks = self._count_blocks(lengths, active, bucket, split_k, n_steps=T)
         self.dispatch_log.append(
             (self.rounds, tuple(s.request.uid for _, s in cand))
         )
@@ -2000,6 +2018,10 @@ class ServeEngine:
             cuts=(t_a, t_k, t_p),
             # the group runs what its NEEDIEST slot wants and masks the rest
             limit="chunk" if need == self.decode_chunk else "need",
+            call=call,
+            bucket=bucket,
+            cpu=(c0, c1),
+            blocks=blocks,
         )
 
     def _settle_round(self, h: _InflightRound) -> None:
@@ -2019,6 +2041,7 @@ class ServeEngine:
         )
         t_done = self._clock()
         if obs is not None:
+            c_done = obs.cpu_clock()
             self._commit = [0, 0, 0.0]
         for idx, s in zip(h.active_idx, h.slots):
             if self.slots[idx] is not s:
@@ -2037,6 +2060,8 @@ class ServeEngine:
                 chunk=self.decode_chunk * self.round_group, limit=h.limit,
                 tokens=self._commit[0], finished=self._commit[1],
                 callback_s=self._commit[2],
+                call=h.call, bucket=h.bucket, blocks=h.blocks,
+                cpu=(*h.cpu, c_done, obs.cpu_clock()),
             )
 
     def _poison_page(self) -> None:
@@ -2296,6 +2321,20 @@ class ServeEngine:
         `_key`, unforced), or None, greedy: no key at all."""
         return None if self.temperature == 0.0 else self._key
 
+    def _call_mark(self):
+        """Counts one jit call of the serving path and hands back what it
+        runs in: with obs on a `jax.profiler.TraceAnnotation`
+        `engine.dispatch` carrying the call's number (about a microsecond of
+        Python with no profiler running; inside a profile the annotation's
+        start is the call's start on the PROFILER's clock, as the span that
+        brackets the call is on the engine's: every dispatch is a sync
+        mark), with obs off nothing. Opened here and not in
+        midgpt_tpu/obs/, which imports no jax."""
+        self.dispatches += 1
+        if self.obs is None:
+            return _NO_MARK
+        return jax.profiler.TraceAnnotation("engine.dispatch", call=self.dispatches)
+
     def _count_blocks(
         self,
         lengths: np.ndarray,  # (B,) tokens cached per slot at dispatch
@@ -2304,7 +2343,7 @@ class ServeEngine:
         split_k: int,
         n_steps: int = 1,
         n_rows: int = 1,
-    ) -> None:
+    ) -> tp.Optional[tp.Tuple[int, int]]:
         """Record what the paged-attention kernel's grid does with this
         round: compute blocks swept and blocks live, per layer call, over
         the round's `n_steps` decode steps (step t's row r sees
@@ -2312,9 +2351,14 @@ class ServeEngine:
         GPT.decode_step_paged). The block width is the kernel's own
         (`block_pages`, from the same shapes); the live rule is its
         `block_live` (`block_census`). Integers the round already holds:
-        no device work. Kernel path only: the gather lowering has no blocks."""
+        no device work. Kernel path only: the gather lowering has no blocks.
+        Returns the two integers for the round's `decode.dispatch` span, or
+        None where there are none or the family's `kernel_sweep` is one of
+        its decode program's several kernels (`kernel_sweep_whole` False):
+        the counters take them either way, a window's figure would not say
+        which kernel it is of."""
         if self.obs is None or self.attn_impl != "kernel":
-            return
+            return None
         (_, n_kv, _, ps, lanes), groups, window, sinks = self.model.kernel_sweep(
             self.config, self.cache
         )
@@ -2326,11 +2370,11 @@ class ServeEngine:
         )
         steps = np.arange(1, n_steps + 1)[:, None]  # (n_steps, B) below
         first = np.maximum(active * (lengths + steps), 1).ravel()
-        self.obs.record_decode_blocks(
-            *block_census(
-                first, first + n_rows - 1, bucket, n, ps, window, sinks,
-            )
+        blocks = block_census(
+            first, first + n_rows - 1, bucket, n, ps, window, sinks,
         )
+        self.obs.record_decode_blocks(*blocks)
+        return blocks if self.model.kernel_sweep_whole else None
 
     def _page_bucket(self, max_tokens: int) -> int:
         """Smallest power-of-two page count covering `max_tokens` positions
@@ -2406,7 +2450,7 @@ class ServeEngine:
         # page tables' rows, sampled calls only: what follows is the host's
         # time on the key, two clock reads apart)
         obs = self.obs
-        t0 = 0.0 if obs is None else self._clock()
+        t0, c0 = (0.0, 0.0) if obs is None else (self._clock(), obs.cpu_clock())
         W = self.prefill_width
         chunk = np.zeros((W, self.prefill_chunk), np.int32)
         start, n_valid = np.zeros((W,), np.int32), np.zeros((W,), np.int32)
@@ -2428,27 +2472,33 @@ class ServeEngine:
         # above (`prefill.assemble`), and nothing is forced here (a call
         # none of whose rows ends its prompt never syncs; the force happens
         # in the first-token block below). It belongs to no one request: rid
-        # is the first row's. Its args say what rode the call.
+        # is the first row's. Its args say what rode the call and which
+        # dispatch it is (`call`: the target's; a separate draft's call
+        # below is the next number and has no span of its own).
+        c_end = 0.0 if obs is None else obs.cpu_clock()
+        call = self.dispatches + 1
         with self._trace.span(
             "prefill.chunk", "prefill", self._obs_tid, rows[0][1].request.uid,
             None if obs is None else
-            {"rows": len(rows), "tokens": int(n_valid.sum()), "bucket": bucket},
+            {"rows": len(rows), "tokens": int(n_valid.sum()), "bucket": bucket,
+             "call": call, "width": W},
         ) as sp:
-            first, logits, self.cache, self._key = _serve_prefill_chunk(
-                self.config,
-                self.params,
-                chunk,
-                start_a,
-                n_valid_a,
-                self.cache,
-                table,
-                self.mesh,
-                self.attn_impl,
-                self.temperature,
-                self.top_k,
-                self.top_p,
-                key,
-            )
+            with self._call_mark():
+                first, logits, self.cache, self._key = _serve_prefill_chunk(
+                    self.config,
+                    self.params,
+                    chunk,
+                    start_a,
+                    n_valid_a,
+                    self.cache,
+                    table,
+                    self.mesh,
+                    self.attn_impl,
+                    self.temperature,
+                    self.top_k,
+                    self.top_p,
+                    key,
+                )
             if self.draft_params is not None and not self.draft_shares_cache:
                 # A separate draft model's pool must hold the same positions
                 # as the target's — the spec round's draft steps attend
@@ -2457,20 +2507,22 @@ class ServeEngine:
                 # (the pending token is the TARGET's). A prefix self-draft
                 # skips this: the target prefill above already filled its
                 # layers of the shared pool.
-                _, _, self.pool.draft_cache, _ = _serve_prefill_chunk(
-                    self.draft_config,
-                    self.draft_params,
-                    chunk,
-                    start_a,
-                    n_valid_a,
-                    self.pool.draft_cache,
-                    table,
-                    self.mesh,
-                    self.attn_impl,
-                )
+                with self._call_mark():
+                    _, _, self.pool.draft_cache, _ = _serve_prefill_chunk(
+                        self.draft_config,
+                        self.draft_params,
+                        chunk,
+                        start_a,
+                        n_valid_a,
+                        self.pool.draft_cache,
+                        table,
+                        self.mesh,
+                        self.attn_impl,
+                    )
         if obs is not None:
             obs.record_prefill_assemble(
-                self._obs_tid, rows[0][1].request.uid, t0, t_n, t_p, sp.t0
+                self._obs_tid, rows[0][1].request.uid, t0, t_n, t_p, sp.t0,
+                cpu_s=c_end - c0,
             )
         self.prefill_calls += 1
         pulled = False  # the call's tokens are on the host: pulled ONCE
@@ -2495,10 +2547,10 @@ class ServeEngine:
             # engine.generate's sample_logits(temperature=0)). The
             # np.asarray is the call's one force/sync — the span of the
             # call's first finisher holds the device wait for the call plus
-            # the pull of its W tokens.
+            # the pull of its W tokens (`call`: whose program's tokens these are).
             with self._trace.span(
                 "prefill.first_token", "prefill", self._obs_tid,
-                slot.request.uid,
+                slot.request.uid, None if obs is None else {"call": call},
             ):
                 if not pulled:
                     pulled = True
@@ -2590,11 +2642,12 @@ class ServeEngine:
         if not active_idx:
             return {}
         token, lengths, active, round_span = self._decode_args(active_idx, n)
-        logits, self.cache = _serve_decode_logits(
-            self.config, self.params, token, self.cache,
-            self.pool.tables(self.slots, self._page_bucket(round_span)), lengths,
-            active, self.attn_impl, self.mesh, self._split_bucket(round_span),
-        )
+        with self._call_mark():
+            logits, self.cache = _serve_decode_logits(
+                self.config, self.params, token, self.cache,
+                self.pool.tables(self.slots, self._page_bucket(round_span)), lengths,
+                active, self.attn_impl, self.mesh, self._split_bucket(round_span),
+            )
         logits = np.asarray(logits, np.float32)
         return {self.slots[i].request.uid: logits[i] for i in active_idx}
 
@@ -2615,7 +2668,7 @@ class ServeEngine:
         # is device compute + the copy to the host (the np.asarray force is
         # the round's one sync), t_done -> t_post is token commit.
         obs = self.obs
-        t0 = 0.0 if obs is None else self._clock()
+        t0, c0 = (0.0, 0.0) if obs is None else (self._clock(), obs.cpu_clock())
         token, lengths, active, round_span = self._decode_args(active_idx, n)
         bucket = self._page_bucket(round_span)
         split_k = self._split_bucket(round_span)
@@ -2624,26 +2677,28 @@ class ServeEngine:
         t_k = None if key is None or obs is None else self._clock()
         tables = self.pool.tables(self.slots, bucket)
         t_p = 0.0 if obs is None else self._clock()
-        self.cache, toks, self._key = _serve_decode_chunk(
-            self.config,
-            self.params,
-            token,
-            self.cache,
-            tables,
-            lengths,
-            active,
-            n,
-            self.temperature,
-            self.top_k,
-            self.top_p,
-            self.attn_impl,
-            key,
-            self.mesh,
-            split_k,
-        )
-        t1 = 0.0 if obs is None else self._clock()
+        with self._call_mark():
+            self.cache, toks, self._key = _serve_decode_chunk(
+                self.config,
+                self.params,
+                token,
+                self.cache,
+                tables,
+                lengths,
+                active,
+                n,
+                self.temperature,
+                self.top_k,
+                self.top_p,
+                self.attn_impl,
+                key,
+                self.mesh,
+                split_k,
+            )
+        call = self.dispatches
+        t1, c1 = (0.0, 0.0) if obs is None else (self._clock(), obs.cpu_clock())
         # counted after t1, while the device computes: not dispatch time
-        self._count_blocks(lengths, active, bucket, split_k, n_steps=n)
+        blocks = self._count_blocks(lengths, active, bucket, split_k, n_steps=n)
         self.dispatch_log.append(
             (
                 self.rounds,
@@ -2657,6 +2712,7 @@ class ServeEngine:
         )  # (n, B)
         t_done = self._clock()
         if obs is not None:
+            c_done = obs.cpu_clock()
             self._commit = [0, 0, 0.0]
         for i in active_idx:
             slot = self.slots[i]
@@ -2673,6 +2729,8 @@ class ServeEngine:
                 chunk=self.decode_chunk, limit=limit,
                 tokens=self._commit[0], finished=self._commit[1],
                 callback_s=self._commit[2],
+                call=call, bucket=bucket, blocks=blocks,
+                cpu=(c0, c1, c_done, obs.cpu_clock()),
             )
 
     def _spec_round(self) -> None:
@@ -2714,7 +2772,7 @@ class ServeEngine:
         # after the VERIFY call returns (both programs enqueued by then),
         # with draft/verify enqueue sub-spans recorded off the same reads.
         obs = self.obs
-        t0 = 0.0 if obs is None else self._clock()
+        t0, c0 = (0.0, 0.0) if obs is None else (self._clock(), obs.cpu_clock())
         token, lengths, active, round_span = self._decode_args(active_idx, k + 1)
         bucket = self._page_bucket(round_span)
         split_k = self._split_bucket(round_span)
@@ -2730,53 +2788,59 @@ class ServeEngine:
         draft_cache_in = self.cache if shared else self.pool.draft_cache
         # The draft program makes the round's three-way key split: it hands
         # back the engine's next key and the verify program's, on the device.
-        draft_cache_out, drafts, draft_probs, self._key, key_v = _spec_draft_chunk(
-            self.draft_config,
-            self.draft_params,
-            token,
-            draft_cache_in,
-            table,
-            lengths,
-            active,
-            k,
-            self.temperature,
-            self.top_k,
-            self.top_p,
-            self.attn_impl,
-            self._sampling_key(),
-            self.mesh,
-            split_k,
-        )
+        with self._call_mark():
+            draft_cache_out, drafts, draft_probs, self._key, key_v = _spec_draft_chunk(
+                self.draft_config,
+                self.draft_params,
+                token,
+                draft_cache_in,
+                table,
+                lengths,
+                active,
+                k,
+                self.temperature,
+                self.top_k,
+                self.top_p,
+                self.attn_impl,
+                self._sampling_key(),
+                self.mesh,
+                split_k,
+            )
         t_draft = 0.0 if obs is None else self._clock()
         if shared:
             self.cache = draft_cache_out
         else:
             self.pool.draft_cache = draft_cache_out
-        self.cache, n_accept, out = _spec_verify_chunk(
-            self.config,
-            self.params,
-            token,
-            drafts,
-            draft_probs,
-            self.cache,
-            table,
-            lengths,
-            active,
-            self.temperature,
-            self.top_k,
-            self.top_p,
-            self.attn_impl,
-            key_v,
-            self.mesh,
-            split_k,
-        )
-        t1 = 0.0 if obs is None else self._clock()
+        with self._call_mark():
+            self.cache, n_accept, out = _spec_verify_chunk(
+                self.config,
+                self.params,
+                token,
+                drafts,
+                draft_probs,
+                self.cache,
+                table,
+                lengths,
+                active,
+                self.temperature,
+                self.top_k,
+                self.top_p,
+                self.attn_impl,
+                key_v,
+                self.mesh,
+                split_k,
+            )
+        call = self.dispatches  # the verify's; the draft's is the one before
+        t1, c1 = (0.0, 0.0) if obs is None else (self._clock(), obs.cpu_clock())
         # the target's verify call (the draft's k steps run another model);
-        # counted after t1, while the device computes: not dispatch time
+        # counted after t1, while the device computes: not dispatch time.
+        # Its two integers go to the counters alone: the round's draft steps
+        # are paged-attention calls of another geometry.
         self._count_blocks(lengths, active, bucket, split_k, n_rows=k + 1)
         n_accept = np.asarray(n_accept)
         out = np.asarray(out)  # forces both dispatches
         t_done = self._clock()
+        c_done = 0.0 if obs is None else obs.cpu_clock()
         self._spec_rounds += 1
         for i in active_idx:
             slot = self.slots[i]
@@ -2820,14 +2884,16 @@ class ServeEngine:
                 self.pool.free(0, tail)
         if obs is not None:
             obs.record_round(
-                "spec", self._obs_tid, t0, t1, t_done, self._clock()
+                "spec", self._obs_tid, t0, t1, t_done, self._clock(),
+                call=call, cpu=(c0, c1, c_done, obs.cpu_clock()),
             )
             self._trace.complete(
-                "spec.draft_enqueue", "spec", self._obs_tid, t0, t_draft - t0
+                "spec.draft_enqueue", "spec", self._obs_tid, t0, t_draft - t0,
+                {"call": call - 1},
             )
             self._trace.complete(
                 "spec.verify_enqueue", "spec", self._obs_tid, t_draft,
-                t1 - t_draft,
+                t1 - t_draft, {"call": call},
             )
 
     def spec_stats(self) -> tp.Dict[str, float]:

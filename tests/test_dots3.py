@@ -440,7 +440,8 @@ def test_the_traffic_is_one_multiset_for_every_seed_and_the_benchmark_only_adds(
         "num_hidden_layers", "layer_types", "n_routed_experts", "vocab_size"]
     assert {m["name"] for m in bench["end_to_end"] if CELL in m.get("workloads", [CELL])} == {"setup_s", "serve_tokens_per_s"}
     mine = [m for m in bench["per_layer"] if m.get("workloads") == [CELL]]
-    assert [m["name"] for m in mine] == [m["name"] for m in bench["per_layer"][-len(mine):]] and len(mine) == 10
+    at = bench["per_layer"].index(mine[0])  # one run of entries, in order, as PR 51 appended them (later PRs append after)
+    assert mine == bench["per_layer"][at:at + len(mine)] and len(mine) == 10
     assert all(m["moves"] == "serve_tokens_per_s" for m in mine)
     assert all(m["workloads"][-1] == CELL for m in bench["per_layer"] if CELL in m["workloads"])
 

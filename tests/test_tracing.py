@@ -298,6 +298,7 @@ def test_a_hand_built_round_says_its_steps_and_why(params, overlap, want):
     eng.step()
     evs = obs.tracer.events()
     (rode,), (commit,) = _args_of(evs, "decode.dispatch"), _args_of(evs, "decode.host_post")
+    assert rode.pop("cpu_s") >= 0  # PR 53: the dispatch span also says the thread's CPU seconds
     assert {**rode, **{k: commit[k] for k in ("tokens", "finished")}} == want
     if overlap == "off":  # the tail: 3 left -> 2, then 1 step, then the others alone at the chunk
         eng.step(), eng.step()
@@ -377,7 +378,7 @@ def test_prefill_assemble_ends_where_the_enqueue_span_opens(params, max_slots, w
         assert kids[0][4] > a[4]  # the numpy arrays are the span's own time
         assert kids[-1][4] + kids[-1][5] == pytest.approx(c[4], abs=1e-12)
     rode = [c[7] for c in chunks]
-    assert rode[0] == {"rows": width, "tokens": 5 * width, "bucket": 1}
+    assert rode[0] == {"rows": width, "tokens": 5 * width, "bucket": 1, "call": 1, "width": width}  # PR 53: + call, width
     assert all(r["rows"] <= width for r in rode)
     assert sum(r["rows"] for r in rode) == eng.prefill_chunks == 16
     assert sum(r["tokens"] for r in rode) == eng.prefilled_tokens == 16 * 5
@@ -847,7 +848,9 @@ def test_setup_programs_reader(monkeypatch):
                            "setup.compile_or_load_s",
                            "setup.trace_lower_s", "setup.programs",
                            # PR 36: the host side of a round from inside
-                           *THIRTEEN}),
+                           *THIRTEEN,
+                           # PR 53: the one reading of engine_device_calls.py that needs no device trace
+                           "host.offcpu_share"}),
     ("train_124m", {"train.feed_ms_p50", "setup.compile_or_load_s", "setup.trace_lower_s", "setup.programs",
                     "step.attn_ms", "step.mlp_ms", "step.lm_head_loss_ms", "step.optimizer_ms",
                     "step.unattributed_ms"}),
