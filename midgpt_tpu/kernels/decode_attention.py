@@ -13,9 +13,10 @@ lengths — the two levers the serving layer needs (vLLM-style paged memory +
 FlashAttention-style work partitioning, PAPERS.md) under XLA's static-shape
 constraint.
 
-Both compiled variants — plain decode (one query row per slot) and
-multi-row speculative verify (T = k+1 rows with per-row visible-key
-counts, GPT.verify_step_paged) — are instantiations of ONE parameterized
+Both compiled variants — plain decode (one query row per slot) and the
+multi-row spec (T rows with per-row visible-key counts: the k+1 rows of
+speculative verify, GPT.verify_step_paged, and the T_c rows of a prefill
+chunk, GPT.prefill_paged_chunk) — are instantiations of ONE parameterized
 kernel (kernels/attention_template.py): shared scalar-prefetched page
 translation, shared online-softmax sweep (ops/online_softmax.py), shared
 int8 fused-dequant read path. `split_k > 1` additionally partitions each
@@ -391,9 +392,10 @@ def paged_verify_attention_gather(
     sliding_window: int = 0,
     attn_sinks: int = 0,
 ) -> Array:
-    """XLA gather lowering of the multi-row verify attention: pages
-    gathered contiguous once (dequantized in int8 mode, like
-    prefill_paged_chunk), then per-row count masks over the shared buffer.
+    """XLA gather lowering of the multi-row attention (speculative verify
+    and a prefill chunk off the TPU): pages gathered contiguous once
+    (dequantized in int8 mode), then per-row count masks over the shared
+    buffer.
     Same mask-then-scale-then-f32-softmax order as
     `paged_attention_gather`, so speculative greedy verify stays
     token-exact with plain paged decode (pinned by tests/test_spec.py).
@@ -476,10 +478,12 @@ def paged_verify_attention(
     attn_sinks: int = 0,
     layer: tp.Optional[Array] = None,
 ) -> Array:
-    """Batched multi-row paged attention for speculative verification
-    (GPT.verify_step_paged): every slot scores its k+1 candidate positions
-    against its own pages in ONE call. Row t of slot b attends to
-    counts[b, t] keys — the caller passes lengths[b] + t + 1, which makes
+    """Batched multi-row paged attention, for speculative verification
+    (GPT.verify_step_paged: every slot scores its k+1 candidate positions)
+    and for prefill (GPT.prefill_paged_chunk: the T_c rows of every slot's
+    chunk), against each slot's own pages in ONE call. Row t of slot b
+    attends to counts[b, t] keys, nondecreasing in t — the caller passes
+    lengths[b] + t + 1, which makes
     the chunk causal through the cache: all rows' K/V are written before
     the read, and the per-row count hides the later rows. Under a sliding
     window each row additionally masks to the last `sliding_window` of its

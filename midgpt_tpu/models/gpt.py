@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from midgpt_tpu.ops.attention import flash_or_blockwise, multihead_attention, visible_mask
+from midgpt_tpu.ops.attention import flash_or_blockwise, multihead_attention
 from midgpt_tpu.ops.dropout import dropout
 from midgpt_tpu.ops.norms import head_layer_norm, rms_norm
 from midgpt_tpu.ops.quant import dequantize_q8, quantize_q8
@@ -401,7 +401,8 @@ class PagedKVCache:
          every pool-carrying value the scatter's preferred layout;
       3. nothing slices a layer out of it for a custom call: the kernels
          take the whole pool and a layer index (attention_template.py),
-         and prefill's XLA gather carries the layer in its indices.
+         and a prefill that gathers in XLA there carries the layer in its
+         indices (`_gather_layer_kv`).
 
     Off the kernel path (CPU, tests) the channel dim is `head_dim` and the
     XLA scatter and gather lowerings apply. Host-side users of pages
@@ -577,8 +578,9 @@ def _gather_layer_kv(
 ) -> Array:
     """Gather each slot's pages of layer i contiguous -> (..., H, MP*ps, C),
     dequantizing after the gather in int8 mode (the CPU sibling of the
-    kernel's in-VMEM dequant). Used by prefill's inline attention; the
-    per-layer-pool variant lives in kernels/decode_attention.py. ONE gather
+    kernel's in-VMEM dequant). Used by a prefill that attends in XLA on a
+    TPU (Ouro's, models/ouro.py; the GPT's went to the template in PR 54);
+    the per-layer-pool variant lives in kernels/decode_attention.py. ONE gather
     whose indices carry the layer beside the page reads the pages from the
     pool where it lies: slicing the layer out first makes the TPU compiler
     materialise it, a layer-sized copy per tensor per layer (PR 25)."""
@@ -1614,20 +1616,22 @@ class GPT:
         scatter DROPS them (XLA oob-scatter semantics) instead of clobbering
         allocated pages, and pad logits are garbage the caller ignores.
 
-        Attention here is an XLA gather path only: each row's pages are
-        gathered contiguous ONCE per layer and its T_c chunk rows attend to
-        that buffer under per-row length masks (the Pallas decode kernel's
-        one-query-row online-softmax shape doesn't fit a chunk — a
-        chunked-prefill kernel is the TPU upgrade path, docs/SERVING.md).
-        `attn_impl` therefore chooses the WRITE only: 'kernel' (what 'auto'
-        resolves to on a TPU) stores the chunks' K/V with the in-place
-        Pallas write, so that this program too holds no scatter on the pool
-        and keeps it in the layout the decode kernels read; `mesh` routes
-        that write per tp shard over the flattened B x T_c rows.
+        Attention is ONE call of the multi-row paged attention a layer
+        (kernels/decode_attention.py `paged_verify_attention`, the pattern
+        of `verify_step_paged`: all T_c rows written, then row t reads
+        start + t + 1 keys through the page table). `attn_impl` chooses
+        both lowerings, as it does for decode and verify: 'kernel' (what
+        'auto' resolves to on a TPU) stores the chunks' K/V with the
+        in-place Pallas write and reads them through the paged-attention
+        template at n_rows = T_c, each row sweeping its own pages, so that
+        this program holds no scatter and no gather on the pool and keeps
+        it in the layout the kernels read; 'gather' is the XLA scatter and
+        gather of the same arithmetic. `mesh` routes write and read per tp
+        shard.
 
         Returns (logits (B, V) — or (1, T_c, V) for a scalar `start` —,
         updated cache)."""
-        from midgpt_tpu.kernels.decode_attention import resolve_paged_impl
+        from midgpt_tpu.kernels.decode_attention import paged_verify_attention, resolve_paged_impl
         from midgpt_tpu.ops.rope import apply_rope_positions
 
         attn_impl = resolve_paged_impl(attn_impl)
@@ -1668,28 +1672,17 @@ class GPT:
                 (ck_all, cv_all, cks_all, cvs_all), i, write_pages, offs,
                 kr, v, attn_impl, mesh,
             )
-            # Gather each row's pages contiguous ONCE, straight from the
-            # whole pool (dequantizing after the gather in int8 mode);
-            # every chunk row attends to its slot's buffer under its own
-            # length mask (same mask-then-scale-then-f32-softmax order as
-            # decode_step).
-            kg = _gather_layer_kv(ck_all, cks_all, i, page_table, x.dtype, C)
-            vg = _gather_layer_kv(cv_all, cvs_all, i, page_table, x.dtype, C)
-            # GQA: gathered buffers are (B, HK, S, C) — broadcast to the
-            # query head count for the per-row masked attention.
-            kg = _repeat_kv(config, kg, 1)
-            vg = _repeat_kv(config, vg, 1)
-            S = kg.shape[2]
-            scores = jnp.einsum("bthc,bhsc->bhts", qr.astype(kg.dtype), kg)
-            ok = visible_mask(
-                jnp.arange(S)[None, None, None, :], attn_counts[:, None, :, None],
-                config.sliding_window, config.attn_sinks,
-            )
-            scores = jnp.where(ok, scores, float("-inf"))
-            probs = jax.nn.softmax(
-                scores.astype(jnp.float32) / math.sqrt(C), axis=-1
-            ).astype(kg.dtype)
-            att = jnp.einsum("bhts,bhsc->bthc", probs, vg)  # (B, T_c, H, C)
+            # Every chunk row reads its slot's pages of layer i of the WHOLE
+            # pool under its own count. The innermost scope names the custom
+            # call in the device trace: the benchmark reads `closed_call.<n>`
+            # as the DECODE kernel (PERF.md §7).
+            with jax.named_scope("prefill_attn"):
+                att = paged_verify_attention(
+                    qr, ck_all, cv_all, page_table, attn_counts,
+                    k_scale=cks_all, v_scale=cvs_all, impl=attn_impl, mesh=mesh,
+                    sliding_window=config.sliding_window,
+                    attn_sinks=config.attn_sinks, layer=i,
+                )  # (B, T_c, H, C)
             x = GPT._attn_out_and_mlp(config, block, x, att.astype(x.dtype))
             return (x, ck_all, cv_all, cks_all, cvs_all), None
 

@@ -315,8 +315,9 @@ def _serve_prefill_chunk(
     engine of width 1 makes the family's one-row call (models/__init__.py):
     `tokens` (1, prefill_chunk), SCALAR `start` / `n_valid`, the slot's own
     table row(s). `attn_impl` (the engine's resolved choice) selects the K/V
-    WRITE only — the chunk's attention is an XLA gather on every backend
-    (GPT.prefill_paged_chunk).
+    write of every family and the lowering of the GPT's attention (the
+    paged-attention template or the XLA gather: GPT.prefill_paged_chunk);
+    every other family's prefill attends in XLA on every backend.
 
     What the family hands out is brought to one row of logits a slot, those
     of its last valid position: (W, V) as it is, the one-row call's (1, T, V)

@@ -356,6 +356,35 @@ def test_serving_program_never_relays_out_the_pool(program, heads, one_chip, com
     assert _pool_census(lowered, cache) == 0
 
 
+@pytest.mark.parametrize("heads", list(SERVE_HEADS))
+@pytest.mark.parametrize("chunk", [16, 128])
+def test_prefill_program_reads_the_pool_through_the_template(chunk, heads, one_chip, compiled_kernels):
+    """The cells' `(16, 16)` prefill program at both head shapes, and one of
+    128-token chunks (the template's block down to 8 pages): two Mosaic
+    calls a layer, named `kv_write` and `prefill_attn` (the innermost scope:
+    what benchmarks/metrics/prefill_attention.py finds, and NOT decode's
+    `closed_call`), no XLA computation that takes the pool (the per-layer
+    gather of every row's page bucket is gone), no scoped-VMEM request over
+    the default, census 0."""
+    import re
+
+    from midgpt_tpu.analysis.hlo_audit import pool_relayouts
+    from midgpt_tpu.sampling import serve
+
+    cfg, params, cache, arr = _serve_program_args(heads, one_chip, jnp.bfloat16)
+    W = 16
+    text = serve._serve_prefill_chunk.lower(
+        cfg, params, arr((W, chunk)), arr((W,)), arr((W,)), cache,
+        arr((W, 128)), None, "kernel", 0.8, None, None, arr((2,), jnp.uint32),
+    ).compile().as_text()
+    calls = re.findall(r"%([a-z_]+)\.\d+ = [^\n]*tpu_custom_call", text)
+    assert sorted(calls) == sorted(["kv_write", "prefill_attn"] * SERVE_L)
+    pool = "bf16[" + ",".join(map(str, cache.k.shape)) + "]"
+    assert not [ln for ln in text.splitlines() if re.match(r"%\S+ \(", ln) and pool in ln]
+    assert pool_relayouts(text, [cache.k.shape, cache.v.shape]) == 0
+    assert set(re.findall(r'"scoped_memory_configs":(\[[^\]]*\])', text)) <= {"[]"}
+
+
 def test_pool_census_counts_an_xla_scatter_on_the_pool(one_chip, compiled_kernels):
     """The census has teeth: the fallback write (an XLA scatter) in a
     program that also runs the attention kernel relays the pool out."""

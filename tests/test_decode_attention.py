@@ -106,9 +106,19 @@ BLK_LENGTHS = (0, 1, 15, 16, 17, 40, 64)
 BENIGN = 1  # a zero page: where the CLEAN table parks what no row can see
 
 
+# A prefill call's rows, (start, n_valid) of a 16-token chunk each: a first
+# chunk, a ragged one, an EMPTY row (one key of its first page: the engine's
+# sink), later chunks at and off a page boundary, the table's last tokens.
+CHUNK_ROWS = ((0, 16), (0, 5), (0, 0), (16, 16), (23, 9), (40, 16), (48, 16))
+
+
 def _block_problem(seed, groups=1, n_rows=1, quantized=False, lengths=BLK_LENGTHS,
-                   table_pages=BLK_MP, window=0, sinks=0):
+                   table_pages=BLK_MP, window=0, sinks=0, chunks=None, dtype=jnp.float32):
     """(q, k_pages, v_pages, clean table, dirty table, counts, scales).
+
+    `chunks`: rows of a prefill call instead of `lengths`; row t of a chunk
+    sees start + t + 1 keys, clamped at the chunk's last valid token, an
+    empty row one (GPT.prefill_paged_chunk's `attn_counts`).
 
     Live pages are out of order and non-contiguous in the pool. Pages 0, 2
     and the pool's last are POISON (NaN; NaN scales on an int8 pool): the
@@ -117,13 +127,13 @@ def _block_problem(seed, groups=1, n_rows=1, quantized=False, lengths=BLK_LENGTH
     or out of range. One fetched poison page puts NaN into the output; the
     clean table parks the same entries on a zero page for the reference."""
     rng = np.random.default_rng(seed)
-    B = len(lengths)
+    B = len(lengths if chunks is None else chunks)
     n_pool = B * table_pages + 4
     real = 3 + rng.permutation(B * table_pages).reshape(B, table_pages)
     keys = jax.random.split(jax.random.PRNGKey(seed), 3)
-    q = jax.random.normal(keys[0], (B, n_rows, BLK_H * groups, C), jnp.float32)
-    k_pages = jax.random.normal(keys[1], (BLK_H, n_pool, BLK_PS, C), jnp.float32)
-    v_pages = jax.random.normal(keys[2], (BLK_H, n_pool, BLK_PS, C), jnp.float32)
+    q = jax.random.normal(keys[0], (B, n_rows, BLK_H * groups, C), dtype)
+    k_pages = jax.random.normal(keys[1], (BLK_H, n_pool, BLK_PS, C), dtype)
+    v_pages = jax.random.normal(keys[2], (BLK_H, n_pool, BLK_PS, C), dtype)
     poison = np.asarray([0, 2, n_pool - 1])
     scales = (None, None)
     if quantized:
@@ -135,8 +145,12 @@ def _block_problem(seed, groups=1, n_rows=1, quantized=False, lengths=BLK_LENGTH
     else:
         k_pages = k_pages.at[:, poison].set(jnp.nan).at[:, BENIGN].set(0.0)
         v_pages = v_pages.at[:, poison].set(jnp.nan).at[:, BENIGN].set(0.0)
-    lengths = np.asarray(lengths)
-    counts = lengths[:, None] + (np.arange(n_rows)[None, :] + 1) * (lengths[:, None] > 0)
+    if chunks is None:
+        lengths = np.asarray(lengths)
+        counts = lengths[:, None] + (np.arange(n_rows)[None, :] + 1) * (lengths[:, None] > 0)
+    else:
+        start, n_valid = np.asarray(chunks).T[:, :, None]
+        counts = np.maximum(np.minimum(start + np.arange(n_rows), start + n_valid - 1) + 1, 1)
     page0 = np.arange(table_pages)[None, :] * BLK_PS
     seen = page0 < counts[:, -1:]
     if window:
@@ -168,6 +182,14 @@ BLOCK_CASES = {
                                dict(pages_per_block=2, sliding_window=24, attn_sinks=4)),
     "window_reclaimed_split2": (dict(window=24),
                                 dict(pages_per_block=2, sliding_window=24, split_k=2)),
+    # the multi-row spec at a prefill chunk's width (GPT.prefill_paged_chunk)
+    "chunk16": (dict(n_rows=16, chunks=CHUNK_ROWS), dict(pages_per_block=2)),
+    "chunk16_derived_width": (dict(n_rows=16, chunks=CHUNK_ROWS), {}),
+    "chunk16_bf16": (dict(n_rows=16, chunks=CHUNK_ROWS, dtype=jnp.bfloat16), dict(pages_per_block=2)),
+    "chunk16_int8": (dict(n_rows=16, chunks=CHUNK_ROWS, quantized=True), dict(pages_per_block=2)),
+    "chunk16_window_sinks": (dict(n_rows=16, chunks=CHUNK_ROWS, window=24, sinks=4),
+                             dict(pages_per_block=2, sliding_window=24, attn_sinks=4)),
+    "chunk16_gqa2": (dict(n_rows=16, chunks=CHUNK_ROWS, groups=2), dict(pages_per_block=2)),
 }
 
 
@@ -190,10 +212,13 @@ def test_kernel_blocks_match_gather_and_never_read_dead_entries(case):
             q.transpose(0, 2, 1, 3), kp, vp, dirty, counts, ks, vs, **tkw
         )
     ).transpose(0, 2, 1, 3)
+    got, want = got.astype(np.float32), want.astype(np.float32)
     assert np.isfinite(got).all()
     empty = np.asarray(counts)[:, -1] == 0  # the reference's softmax of nothing
     np.testing.assert_array_equal(got[empty], 0.0)
-    np.testing.assert_allclose(got[~empty], want[~empty], atol=3e-5, rtol=3e-5)
+    # bf16: the reference rounds its scores to bf16, the kernel keeps them f32
+    tol = 3e-5 if q.dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(got[~empty], want[~empty], atol=tol, rtol=tol)
 
 
 def test_verify_wrapper_runs_the_blocked_template():
@@ -216,8 +241,15 @@ def test_verify_wrapper_runs_the_blocked_template():
         (dict(n_heads=16, lanes=128, itemsize=2, page_size=8, table_pages=128, n_rows=1), 32, 4 << 20),
         # an odd table (direct callers): the largest pow2 that divides it
         (dict(n_heads=16, lanes=128, itemsize=2, page_size=8, table_pages=24, n_rows=1), 8, 1 << 20),
+        # a prefill chunk's 16 rows keep decode's block at both shapes
+        (dict(n_heads=12, lanes=128, itemsize=2, page_size=8, table_pages=32, n_rows=16), 32, 3 << 20),
+        (dict(n_heads=16, lanes=128, itemsize=2, page_size=8, table_pages=128, n_rows=16), 32, 4 << 20),
+        # wider chunks: the f32 score tile (heads x rows x block tokens) halves the block past 40 / 32 rows
+        (dict(n_heads=12, lanes=128, itemsize=2, page_size=8, table_pages=128, n_rows=64), 16, 3 << 19),
+        (dict(n_heads=16, lanes=128, itemsize=2, page_size=8, table_pages=128, n_rows=128), 8, 1 << 20),
     ],
-    ids=["124m_t32", "124m_t4", "xl_t64", "xl_t128", "odd_table"],
+    ids=["124m_t32", "124m_t4", "xl_t64", "xl_t128", "odd_table", "124m_chunk16", "xl_chunk16",
+         "124m_chunk64", "xl_chunk128"],
 )
 def test_block_width_is_derived_from_the_shapes(shape, n, vmem):
     """Pages a block, and the VMEM its buffers take, at the two benchmark

@@ -292,7 +292,10 @@ def test_the_reference_blocks_its_queries_without_changing_its_result(model, mon
 # entries (`decode8`, `prefill16`, both presets): taken again at PR 37, whose sampled programs take the engine's key, split
 # their own off it first and hand back the engine's next (a key in, a key out; the tokens are the parent's bit for bit:
 # tests/test_sampled_streams.py). The greedy `decode1_greedy` and `verify5` (its key is made on the device by the draft
-# program now, the program itself untouched) are the parent's of PR 30, byte for byte
+# program now, the program itself untouched) are the parent's of PR 30, byte for byte. The two `prefill16` entries: taken
+# again at PR 54, whose prefill attends through `paged_verify_attention` (here its gather lowering: the layer sliced, then one
+# gather of the pages, where the parent gathered with the layer in the indices; the same arithmetic in the same order, and the
+# tokens are the parent's bit for bit: tests/test_prefill_width1_round.py, tests/test_sampled_streams.py)
 GPT_PROGRAM_HASHES = json.load(open(os.path.join(os.path.dirname(__file__), "golden", "gpt_serving_programs_pr29.json")))
 
 
