@@ -27,12 +27,14 @@ from __future__ import annotations
 import re
 import typing as tp
 
-# jit_cache_size and pool_relayouts live in utils/hlo.py (the serving engine
-# reads them and imports nothing of analysis/); they are this module's too.
+# jit_cache_size, pool_relayouts and rotary_gathers live in utils/hlo.py (the
+# serving engine reads the first two and imports nothing of analysis/); they
+# are this module's too.
 from midgpt_tpu.utils.hlo import (
     hlo_computations,
     jit_cache_size,
     pool_relayouts,
+    rotary_gathers,
     while_body_names,
 )
 

@@ -26,7 +26,7 @@ config = ExperimentConfig(
         # Same function as the reference rotation via the in-graph q/k row
         # permutation (models/gpt.py _qkv_weights, exactness test-pinned);
         # contiguous rotate-half instead of stride-2 gathers. `train_124m`
-        # (ledger) runs it; the interleaved form has no cell.
+        # (ledger) runs it; the interleaved form runs in the XL cells.
         rope_style="split",
     ),
 )

@@ -97,10 +97,12 @@ class GPTConfig:
     # (checkpoints stay in reference convention) + the contiguous
     # rotate-half form — mathematically identical (QK^T is invariant under
     # a shared permutation of the C axis; pinned by test_rope/test_model),
-    # and cheaper (the interleaved form's stride-2 gathers cost copy passes
-    # in fwd AND bwd; `train_124m`, ledger, runs 'split'; 'interleaved' has
-    # no cell). Per-run choice recorded in config.json, so restores and
-    # sampling stay consistent.
+    # and cheaper where the interleaved form gathers stride-2 channels, fwd
+    # AND bwd: in training (the serving programs roll lanes since PR 57:
+    # ops/rope.py). The 124M cells run 'split', the XL cells 'interleaved'
+    # (ledger); ROADMAP D4 asks whether 'split' still earns its weight
+    # permutation in serving. Per-run choice recorded in config.json, so
+    # restores and sampling stay consistent.
     rope_style: str = "interleaved"
     # Internal activation layout of the attention fast paths (flash kernel /
     # injected ring/ulysses — both consume head-major):

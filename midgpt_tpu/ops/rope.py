@@ -30,7 +30,26 @@ def rope_table(head_dim: int, length: int, base: float = 10000.0) -> tp.Tuple[Ar
 
 
 def rotate_interleaved(x: Array) -> Array:
-    """[a b c d] -> [-b a -d c] over the trailing axis."""
+    """[a b c d] -> [-b a -d c] over the trailing axis, with nothing for XLA
+    to gather: two static lane rolls (each a `concatenate(slice, slice)` on
+    the minor axis, what `rotate_half` is) and a select on the channel's
+    parity. The lanes a roll wraps around are the ones the select drops. The
+    same values to the bit as `rotate_interleaved_strided` (a permutation and
+    a sign; pinned by tests/test_rope.py, forward and `jax.grad`)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    return jnp.where(lane % 2 == 0, -jnp.roll(x, -1, axis=-1), jnp.roll(x, 1, axis=-1))
+
+
+def rotate_interleaved_strided(x: Array) -> Array:
+    """`rotate_interleaved` as stride-2 slices, a stack and a reshape: on the
+    TPU a gather over the channel axis, forward, and a pad / scatter,
+    backward. The TRAINING entry points (`apply_rope`, `apply_rope_bthc`)
+    keep it and the serving one (`apply_rope_positions`) rolls, each by a
+    chip reading (PERF.md section 6 PR 57, v5e): rolling took 3.8 ms off the
+    XL's 12.7 ms (16, 16) prefill call; in the XL's four-chip training step
+    it took 7 ms off the layers' own time and the authored FSDP schedule
+    then left 10 ms more of its collectives exposed, 357.3 -> 360.8 ms a
+    step."""
     x1 = x[..., ::2]
     x2 = x[..., 1::2]
     return jnp.stack((-x2, x1), axis=-1).reshape(x.shape)
@@ -65,7 +84,7 @@ def apply_rope(
         return x * cos + rotate_half(x) * sin
     sin = _duplicate_pairs(sin).astype(x.dtype)
     cos = _duplicate_pairs(cos).astype(x.dtype)
-    return x * cos + rotate_interleaved(x) * sin
+    return x * cos + rotate_interleaved_strided(x) * sin
 
 
 def apply_rope_positions(
@@ -82,14 +101,19 @@ def apply_rope_positions(
     (T,) position vector over the batch, this gathers a (B, T) table slice
     instead. Same elementwise rotation, so for equal positions it is
     bit-identical to `apply_rope_bthc` (pinned by tests/test_rope.py)."""
-    sin = jnp.take(sin, positions, axis=0)  # (B, T, C/2)
-    cos = jnp.take(cos, positions, axis=0)
     if style == "split":
+        sin = jnp.take(sin, positions, axis=0)  # (B, T, C/2)
+        cos = jnp.take(cos, positions, axis=0)
         sin = _tile_halves(sin).astype(x.dtype)[:, :, None, :]  # (B, T, 1, C)
         cos = _tile_halves(cos).astype(x.dtype)[:, :, None, :]
         return x * cos + rotate_half(x) * sin
-    sin = _duplicate_pairs(sin).astype(x.dtype)[:, :, None, :]
-    cos = _duplicate_pairs(cos).astype(x.dtype)[:, :, None, :]
+    # The (block_size, C/2) tables are formed from iotas inside the program:
+    # widened ONCE, before the rows are taken, a step takes full-width rows
+    # of a constant and interleaves nothing of its own.
+    sin = jnp.take(jnp.repeat(sin, 2, axis=-1), positions, axis=0)  # (B, T, C)
+    cos = jnp.take(jnp.repeat(cos, 2, axis=-1), positions, axis=0)
+    sin = sin.astype(x.dtype)[:, :, None, :]
+    cos = cos.astype(x.dtype)[:, :, None, :]
     return x * cos + rotate_interleaved(x) * sin
 
 
@@ -135,13 +159,15 @@ def apply_rope_bthc(
     the fused QKV projection produces; using it end-to-end (projection → RoPE
     → flash kernel → merge heads) eliminates all head transposes.
 
-    style='interleaved' is the reference rotation (layers.py:79-99).
+    style='interleaved' is the reference rotation (layers.py:79-99), here in
+    its stride-2 spelling (`rotate_interleaved_strided` says why).
     style='split' expects the C axis pre-permuted by `split_permutation`
     (models/gpt.py permutes the q/k projection rows in-graph) and applies
-    the mathematically-identical rotate-half form — measured 12.3 ms/step
-    cheaper on the 124M v5e bench (measured on an earlier toolchain, not
-    re-measured): the interleaved form's
-    stride-2 pair gathers cost real copy passes in forward AND backward."""
+    the mathematically-identical rotate-half form, introduced against the
+    interleaved form's stride-2 pair gathers, which cost real copy passes in
+    forward AND backward. What those cost where they were last read
+    (PERF.md section 6 PR 57, v5e, the XL): 4.3 ms of `step.attn_ms` 90.8
+    and 2.9 of `step.mlp_ms` 126.1 in a 357 ms four-chip training step."""
     if positions is not None:
         sin = jnp.take(sin, positions, axis=0)
         cos = jnp.take(cos, positions, axis=0)
@@ -154,7 +180,7 @@ def apply_rope_bthc(
         return x * cos + rotate_half(x) * sin
     sin = _duplicate_pairs(sin).astype(x.dtype)[:, None, :]  # (T, 1, C)
     cos = _duplicate_pairs(cos).astype(x.dtype)[:, None, :]
-    return x * cos + rotate_interleaved(x) * sin
+    return x * cos + rotate_interleaved_strided(x) * sin
 
 
 def apply_rope_leading(x: Array, sin: Array, cos: Array, positions: Array) -> Array:

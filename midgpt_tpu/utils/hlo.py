@@ -239,6 +239,49 @@ def pool_relayouts(
     )
 
 
+_GATHER_RE = re.compile(
+    r" gather\([^\n]*?collapsed_slice_dims=\{([0-9,]*)\}[^\n]*?slice_sizes=\{([0-9,]*)\}"
+    r"(?:[^\n]*?stack_frame_id=(\d+))?"
+)
+
+
+def _frame_functions(hlo_text: str) -> tp.Dict[str, str]:
+    """{stack_frame_id: the function its innermost frame lies in}, from the
+    FunctionNames / FileLocations / StackFrames tables a compiled text
+    carries ahead of its computations (empty where it carries none)."""
+    functions = hlo_text.partition("\nFunctionNames\n")[2].partition("\n\n")[0]
+    names = dict(re.findall(r'^(\d+) "([^"\n]*)"$', functions, re.M))
+    location = dict(re.findall(r"^(\d+) \{file_name_id=\d+ function_name_id=(\d+) ", hlo_text, re.M))
+    frames = re.findall(r"^(\d+) \{file_location_id=(\d+) ", hlo_text, re.M)
+    return {frame: names.get(location.get(loc, ""), "") for frame, loc in frames}
+
+
+def rotary_gathers(hlo_text: str) -> int:
+    """`gather` instructions of a compiled program that pick CHANNELS out of
+    an activation: the census of the interleaved rotary's lowering
+    (ops/rope.py; PERF.md section 6 PR 57).
+
+    A gather counts when it collapses its operand's MINOR dim and takes
+    another dim whole (the stride-2 channel slices `x[..., ::2]` of
+    `rotate_interleaved_strided` came out of the chip's compiler as
+    `bf16[16,16,64] gather(bf16[16,16,128], ...)`, `collapsed_slice_dims={2}`,
+    `slice_sizes={16,16,1}`: four a layer, with their layout copies 3.8 ms of
+    the XL's 12.7 ms prefill call), or when its stack frame lies in a function
+    whose name starts with `rotate_interleaved`. NOT counted: rows taken whole
+    by an index (the embedding's and the rotary tables' rows by position,
+    `collapsed_slice_dims={0}`: they stay, `jnp.take(table, positions)`), and
+    an element picked by a full index (`take_along_axis` of a token id: every
+    dim collapsed). A program whose rotation rolls lanes reads 0."""
+    where = _frame_functions(hlo_text)
+
+    def counts(collapsed: str, sizes: str, frame: str) -> bool:
+        sizes = [int(d) for d in sizes.split(",") if d]
+        picks_channels = str(len(sizes) - 1) in collapsed.split(",") and max(sizes) > 1
+        return picks_channels or where.get(frame, "").startswith("rotate_interleaved")
+
+    return sum(counts(*m) for m in _GATHER_RE.findall(hlo_text))
+
+
 def lower_abstract_train_step(config, mesh=None, eval_program=False):
     """Lower the full training step against ABSTRACT sharded inputs — or,
     with `eval_program`, the batched eval the train loop runs beside it

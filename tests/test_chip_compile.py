@@ -300,12 +300,16 @@ def _serve_program_args(heads, dev, cache_dtype):
     return cfg, params, cache, arr
 
 
+def _compiled_text(lowered) -> str:
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") >= 1
+    return text
+
+
 def _pool_census(lowered, cache) -> int:
     from midgpt_tpu.analysis.hlo_audit import pool_relayouts
 
-    text = lowered.compile().as_text()
-    assert text.count("tpu_custom_call") >= 1
-    return pool_relayouts(text, [cache.k.shape, cache.v.shape])
+    return pool_relayouts(_compiled_text(lowered), [cache.k.shape, cache.v.shape])
 
 
 @pytest.mark.parametrize("heads", list(SERVE_HEADS))
@@ -323,7 +327,14 @@ def test_serving_program_never_relays_out_the_pool(program, heads, one_chip, com
     (28 for the 12-layer 124M decode program: an entry and an exit relayout
     per pool tensor around the XLA scatter's preferred layout, and one
     layer-sized copy per tensor per layer for the attention custom call);
-    each decode STEP paid the 2L, each program CALL the 4."""
+    each decode STEP paid the 2L, each program CALL the 4.
+
+    The same text holds NO gather over an activation's channels
+    (`rotary_gathers` == 0; `rope_style` is the default, "interleaved"): the
+    serving entry point of the rotary rolls lanes. On the parent of PR 57 it
+    read 4 a layer, q's and k's even and odd channels, in every one of these
+    programs."""
+    from midgpt_tpu.analysis.hlo_audit import pool_relayouts, rotary_gathers
     from midgpt_tpu.sampling import serve
 
     quantized = program.endswith("int8")
@@ -353,7 +364,25 @@ def test_serving_program_never_relays_out_the_pool(program, heads, one_chip, com
             arr((k, B, cfg.vocab_size), jnp.float32), cache, table, lengths,
             active, 0.8, None, None, "kernel", key,
         )
-    assert _pool_census(lowered, cache) == 0
+    text = _compiled_text(lowered)
+    assert pool_relayouts(text, [cache.k.shape, cache.v.shape]) == 0
+    assert rotary_gathers(text) == 0
+
+
+@pytest.mark.parametrize("style", ["strided", "rolled"])
+def test_rotary_census_counts_the_strided_spelling_s_channel_gathers(style, one_chip):
+    """The census of the census: the interleaved rotation in its stride-2
+    spelling (`rotate_interleaved_strided`: the training entry points', every
+    program's up to PR 57), compiled on its own at the XL decode step's
+    geometry, counts (one gather for the even channels, one for the odd);
+    the rolls and select of `rotate_interleaved` count 0 beside it."""
+    from midgpt_tpu.analysis.hlo_audit import rotary_gathers
+    from midgpt_tpu.ops.rope import rotate_interleaved, rotate_interleaved_strided
+
+    x = jax.ShapeDtypeStruct((16, 1, 16, 128), jnp.bfloat16, sharding=one_chip)
+    rotate = rotate_interleaved_strided if style == "strided" else rotate_interleaved
+    text = jax.jit(lambda x: x + rotate(x)).lower(x).compile().as_text()
+    assert (rotary_gathers(text) > 0) == (style == "strided"), text
 
 
 @pytest.mark.parametrize("heads", list(SERVE_HEADS))
@@ -383,332 +412,6 @@ def test_prefill_program_reads_the_pool_through_the_template(chunk, heads, one_c
     assert not [ln for ln in text.splitlines() if re.match(r"%\S+ \(", ln) and pool in ln]
     assert pool_relayouts(text, [cache.k.shape, cache.v.shape]) == 0
     assert set(re.findall(r'"scoped_memory_configs":(\[[^\]]*\])', text)) <= {"[]"}
-
-
-def test_pool_census_counts_an_xla_scatter_on_the_pool(one_chip, compiled_kernels):
-    """The census has teeth: the fallback write (an XLA scatter) in a
-    program that also runs the attention kernel relays the pool out."""
-    from midgpt_tpu.models.gpt import GPT
-
-    cfg, params, cache, arr = _serve_program_args("h16c128", one_chip, jnp.bfloat16)
-    B = SERVE_SLOTS
-
-    def step(params, token, cache, table, lengths, active):
-        # scatter write ('gather' path) ...
-        _, cache = GPT.decode_step_paged(
-            cfg, params, token, cache, table, lengths, active, attn_impl="gather"
-        )
-        # ... and the kernel read, in one program
-        return GPT.decode_step_paged(
-            cfg, params, token, cache, table, lengths, active, attn_impl="kernel"
-        )
-
-    lowered = jax.jit(step, donate_argnums=(2,)).lower(
-        params, arr((B,)), cache, arr((B, SERVE_TABLE)), arr((B,)),
-        arr((B,), jnp.bool_),
-    )
-    assert _pool_census(lowered, cache) >= 2
-
-
-@pytest.mark.parametrize("fsdp_mode", ["gspmd", "shard_map"])
-def test_flash_train_step_compiles_on_four_chips(fsdp_mode, topo, compiled_kernels, monkeypatch):
-    """The whole train step with attn_impl='flash' under FSDP over the 2x2
-    mesh, at toy depth and real head geometry (T=1024, C=128). Under GSPMD
-    the compiler cannot partition a Mosaic kernel, so the runtime maps the
-    call over the batch axes itself (ops/attention.flash_attention_sharded);
-    inside the explicit ZeRO-3 shard_map the kernel's outputs must carry
-    their varying axes. On a CPU mesh neither shows: interpreted kernels are
-    plain HLO."""
-    import numpy as np
-    from jax.sharding import Mesh
-
-    from midgpt_tpu.config import ExperimentConfig, MeshConfig
-    from midgpt_tpu.models.gpt import GPTConfig
-    from midgpt_tpu.parallel.mesh import AXES
-    from midgpt_tpu.utils.hlo import lower_abstract_train_step
-
-    # the dispatcher asks the BACKEND whether the kernel can run; here the
-    # backend is the CPU and the target is the described chip
-    monkeypatch.setattr(fa, "RUN_INTERPRET_OFF_TPU", True)
-    config = ExperimentConfig(
-        rundir="", data_dir="", learning_rate=1e-3, batch_size=8,
-        warmup_steps=2, min_lr=1e-5, lr_decay_steps=10, max_steps=10,
-        beta2=0.95, weight_decay=1e-4, eval_interval=5,
-        param_dtype="float32", compute_dtype="bfloat16", g_accum_iters=2,
-        shard_model=True, fsdp_mode=fsdp_mode,
-        mesh=MeshConfig(data=-1, fsdp=4, sp=1),
-        model_config=GPTConfig(
-            block_size=1024, vocab_size=2048, n_layer=2, n_head=2, n_embd=256,
-            attn_impl="flash",
-        ),
-    )
-    mesh = Mesh(np.asarray(topo.devices).reshape(1, 4, 1, 1, 1, 1), axis_names=AXES)
-    hlo = lower_abstract_train_step(config, mesh=mesh).compile().as_text()
-    assert hlo.count("tpu_custom_call") >= 2  # flash forward and backward
-    assert "all-gather" in hlo  # the weights really are sharded over fsdp
-    # ... and the eval program the loop runs before the first step: the
-    # implicit-GSPMD forward under EITHER mode (the first four-chip run of
-    # the smoke died here under shard_map)
-    eval_hlo = (
-        lower_abstract_train_step(config, mesh=mesh, eval_program=True)
-        .compile()
-        .as_text()
-    )
-    assert eval_hlo.count("tpu_custom_call") >= 1
-
-
-@pytest.mark.parametrize("schedule", ["authored", "compiler"])
-def test_fsdp_schedule_collective_census_at_xl_widths(schedule, topo, compiled_kernels, monkeypatch):
-    """`train_xl_fsdp4`'s step at its widths (D=2048, 16 heads of 128, V=50304,
-    T=1024, 2 sequences a chip x G=2, bf16 over f32, remat 'dots', flash) and
-    reduced depth, compiled for the 2x2 v5e under each collective schedule.
-
-    authored (what a config that says nothing derives on this mesh): no
-    all-to-all, no weight-sized all-reduce, and the gradients of the blocks
-    and of lm_head summed by the schedule's OWN collective-permutes
-    (parallel/shard_map_fsdp.py): one weight-sized reduce-scatter is left,
-    wte's, and none in the loops over the layers. The permutes are bf16
-    start / done pairs, n-1 = 3 a layer, each one layer's leaves packed
-    (25 MB); in the backward loop's body every pair has the block's matmul
-    fusions and flash kernels between its start and its done
-    (`permute_overlap_census`: the text is the schedule), as have lm_head's
-    three beside the top layer's backward; layer 0's three, after the loop,
-    have nothing to run beside. compiler (forced by name; the parent of PR
-    29): no reduce-scatter and no collective-permute at all; the gradients it
-    sums across chips it all-reduces.
-
-    The precision rule (ISSUE 29, kept by ISSUE 44): the cross-chip gradient
-    sum is carried in the dtype the compiler's schedule carries it in. At
-    these shapes its weight-sized gradient all-reduces run in bfloat16 on
-    bf16-rounded per-chip partials (wqkv 3x2048x2048 and a 50304x2048
-    vocabulary matrix), so the authored exchange sends the same bf16
-    partials (and adds them in float32, rounding once). Both sides pin
-    `bf16`: a toolchain that moves either one fails here, not in a loss
-    curve."""
-    import dataclasses
-
-    import numpy as np
-    from jax.sharding import Mesh
-
-    from midgpt_tpu.config import MeshConfig, load_config
-    from midgpt_tpu.parallel.mesh import AXES
-    from midgpt_tpu.utils.hlo import lower_abstract_train_step
-
-    monkeypatch.setattr(fa, "RUN_INTERPRET_OFF_TPU", True)
-    config = load_config("local_text_124m")
-    config = config.replace(
-        batch_size=16, g_accum_iters=2, spec_layers=0, mesh=MeshConfig(data=1, fsdp=1, sp=1),
-        model_config=dataclasses.replace(config.model_config, n_layer=2, scan_unroll=2),
-    )
-    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1, 1, 1, 1, 1), axis_names=AXES)
-    names = _custom_call_names(lower_abstract_train_step(config, mesh=mesh).compile().as_text())
-    assert len(names) == 4 and all(n.startswith("attn.") for n in names), names
-
-
-# 124M serving geometry: 12 heads x 64 in a pool of whole 128-lane rows (as
-# the engine allocates it on the kernel path: PagedKVCache "Layout
-# contract"), 8-token pages, a 1024-token table.
-H, C, LANES, PS, MAX_PAGES, N_PAGES, B = 12, 64, 128, 8, 128, 257, 4
-
-
-def _paged_args(dev, n_rows, quantized, h_q=H, h_kv=H):
-    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=dev)
-    pool = sds((h_kv, N_PAGES, PS, LANES), jnp.int8 if quantized else jnp.bfloat16)
-    args = [
-        sds((B, h_q, n_rows, C), jnp.bfloat16),
-        pool,
-        pool,
-        sds((B, MAX_PAGES), jnp.int32),
-        sds((B, n_rows), jnp.int32),
-    ]
-    if quantized:
-        scale = sds((N_PAGES, h_kv, PS), jnp.float32)
-        args += [scale, scale]
-    return args
-
-
-@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("n_rows", [1, 5], ids=["decode", "verify5"])
-def test_paged_template_compiles_for_v5e(n_rows, quantized, one_chip, compiled_kernels):
-    """n_rows 1 is plain decode, 5 is the verify of spec_k_max=4 drafts."""
-    args = _paged_args(one_chip, n_rows, quantized)
-    assert _mosaic_calls(at.paged_attention_template, *args) == 1
-
-
-def test_paged_template_split_k_compiles_for_v5e(one_chip, compiled_kernels):
-    args = _paged_args(one_chip, 1, False)
-    fn = lambda *a: at.paged_attention_template(*a, split_k=4)
-    assert _mosaic_calls(fn, *args) == 1
-
-
-def test_paged_template_window_sinks_gqa_compiles_for_v5e(one_chip, compiled_kernels):
-    """Sliding window + sinks over a GQA pool (16 query / 4 KV heads)."""
-    args = _paged_args(one_chip, 1, False, h_q=16, h_kv=4)
-    fn = lambda *a: at.paged_attention_template(
-        *a, sliding_window=256, attn_sinks=4
-    )
-    assert _mosaic_calls(fn, *args) == 1
-
-
-# Both benchmark cells' kernel-path pools (PagedKVCache "Layout contract":
-# 128 lanes a row) at their decode geometry: (slots, H_kv, head_dim, widest
-# table in pages, split_k at that table). serve_124m_sample's contexts reach
-# 160 tokens (tables of 1-32 pages); serve_xl_chat's reach 880, and the
-# 1,024-token bucket splits in two (ServeEngine._split_bucket).
-BENCH_SHAPES = {"124m": (48, 12, 64, 32, 1), "xl": (16, 16, 128, 128, 2)}
-# variant: (query rows, int8 pool, query heads per pool head, template kwargs)
-BENCH_VARIANTS = {
-    "decode": (1, False, 1, {}),
-    "verify5": (5, False, 1, {}),
-    "int8": (1, True, 1, {}),
-    "int8_verify5": (5, True, 1, {}),
-    "split4": (1, False, 1, dict(split_k=4)),
-    "gqa4": (1, False, 4, {}),
-    "window_sinks": (1, False, 1, dict(sliding_window=256, attn_sinks=4)),
-}
-
-
-def _bench_args(dev, shape, table, n_rows, quantized, groups):
-    slots, h, c, _, _ = BENCH_SHAPES[shape]
-    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=dev)
-    n_pages, layers = slots * table + 1, 2
-    pool = sds((layers, h, n_pages, PS, LANES), jnp.int8 if quantized else jnp.bfloat16)
-    scale = sds((layers, n_pages, h, PS), jnp.float32) if quantized else None
-    return [
-        sds((slots, h * groups, n_rows, c), jnp.bfloat16), pool, pool,
-        sds((slots, table), jnp.int32), sds((slots, n_rows), jnp.int32),
-        scale, scale,
-    ]
-
-
-@pytest.mark.parametrize("variant", list(BENCH_VARIANTS))
-@pytest.mark.parametrize("shape", list(BENCH_SHAPES))
-def test_paged_template_variants_compile_at_benchmark_shapes(shape, variant, one_chip, compiled_kernels):
-    """Every spec of the blocked template at both cells' widest table, with
-    the block width the call derives (the page copies' slices, the double
-    buffers' VMEM and the scalar loops are what Mosaic can refuse)."""
-    n_rows, quantized, groups, kw = BENCH_VARIANTS[variant]
-    _, _, _, table, split = BENCH_SHAPES[shape]
-    kw = {"split_k": split, **kw}
-    args = _bench_args(one_chip, shape, table, n_rows, quantized, groups)
-    fn = lambda *a: at.paged_attention_template(*a, layer=jnp.int32(1), **kw)
-    assert _mosaic_calls(fn, *args) == 1
-
-
-def test_paged_template_compiles_for_a_pool_off_the_layout_contract(one_chip, compiled_kernels):
-    """A pool of 64-channel rows (no `kernel_layout`: what the benchmark's
-    correctness check allocates) still compiles: the wrapper pads it."""
-    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-    pool = sds((12, H, 7, PS, C), jnp.bfloat16)
-    args = [sds((1, H, 1, C), jnp.bfloat16), pool, pool, sds((1, 6), jnp.int32), sds((1, 1), jnp.int32)]
-    fn = lambda *a: at.paged_attention_template(*a, layer=jnp.int32(3))
-    assert _mosaic_calls(fn, *args) == 1
-
-
-@pytest.mark.parametrize("table", [1, 2, 4, 8, 16])
-def test_paged_template_compiles_at_every_narrow_table(table, one_chip, compiled_kernels):
-    """serve_124m_sample's smaller page buckets: a table narrower than the
-    derived block is one block of its own width."""
-    args = _bench_args(one_chip, "124m", table, 1, False, 1)
-    fn = lambda *a: at.paged_attention_template(*a, layer=jnp.int32(0))
-    assert _mosaic_calls(fn, *args) == 1
-
-
-# ----------------------------------------------------------------------
-# The serving programs' pool layout census (PagedKVCache "Layout contract")
-# ----------------------------------------------------------------------
-
-# Both benchmark configurations' head shapes at toy depth and vocabulary:
-# what decides the pool's layout is (H, ps, C), not L or V. C = 64 is the
-# shape whose own DEFAULT device layout puts the page dim minor, which is
-# why a kernel-path pool is allocated at whole 128-lane rows. The pool is
-# 2,049 pages (50-130 MB, nothing is allocated): a pool of a few MB the
-# compiler moves into fast memory with a pool-sized `copy-start`, which the
-# census counts and no real pool can see.
-SERVE_HEADS = {"h12c64": (12, 64), "h16c128": (16, 128)}
-SERVE_L, SERVE_SLOTS, SERVE_PAGES, SERVE_TABLE = 2, 8, 2049, 16
-
-
-def _serve_program_args(heads, dev, cache_dtype):
-    from midgpt_tpu.models.gpt import GPT, GPTConfig, PagedKVCache
-
-    n_head, head_dim = SERVE_HEADS[heads]
-    cfg = GPTConfig(
-        block_size=1024, vocab_size=512, n_layer=SERVE_L, n_head=n_head,
-        n_embd=n_head * head_dim,
-    )
-    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev)
-    params = jax.tree.map(
-        lambda a: sds(jax.ShapeDtypeStruct(a.shape, jnp.bfloat16)),
-        jax.eval_shape(lambda k: GPT.init(cfg, k), jax.random.PRNGKey(0)),
-    )
-    # the pool as the engine allocates it on the kernel path
-    cache = jax.tree.map(
-        sds,
-        jax.eval_shape(
-            lambda: PagedKVCache.init(
-                cfg, SERVE_PAGES, PS, cache_dtype, kernel_layout=True
-            )
-        ),
-    )
-    arr = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
-    return cfg, params, cache, arr
-
-
-def _pool_census(lowered, cache) -> int:
-    from midgpt_tpu.analysis.hlo_audit import pool_relayouts
-
-    text = lowered.compile().as_text()
-    assert text.count("tpu_custom_call") >= 1
-    return pool_relayouts(text, [cache.k.shape, cache.v.shape])
-
-
-@pytest.mark.parametrize("heads", list(SERVE_HEADS))
-@pytest.mark.parametrize(
-    "program", ["decode1", "decode8", "decode8_int8", "prefill", "verify5"]
-)
-def test_serving_program_never_relays_out_the_pool(program, heads, one_chip, compiled_kernels):
-    """The TPU-compiled text of the decode (1 step: straight-line; 8 steps:
-    a while loop), prefill, speculative-verify and int8-pool decode programs
-    holds NO copy or transpose as large as the pool or as one layer of it
-    (analysis/hlo_audit.pool_relayouts == 0): the pool keeps the one layout
-    the paged kernels read, from the program's parameter to its result.
-
-    On the parent of PR 25 the same census read 4 + 2L for a bf16 pool
-    (28 for the 12-layer 124M decode program: an entry and an exit relayout
-    per pool tensor around the XLA scatter's preferred layout, and one
-    layer-sized copy per tensor per layer for the attention custom call);
-    each decode STEP paid the 2L, each program CALL the 4."""
-    from midgpt_tpu.sampling import serve
-
-    quantized = program.endswith("int8")
-    cfg, params, cache, arr = _serve_program_args(
-        heads, one_chip, jnp.int8 if quantized else jnp.bfloat16
-    )
-    B, key = SERVE_SLOTS, arr((2,), jnp.uint32)
-    table, lengths, active = arr((B, SERVE_TABLE)), arr((B,)), arr((B,), jnp.bool_)
-    if program.startswith("decode"):
-        n_steps = 1 if program == "decode1" else 8
-        lowered = serve._serve_decode_chunk.lower(
-            cfg, params, arr((B,)), cache, table, lengths, active, n_steps,
-            0.8, None, None, "kernel", key,
-        )
-    elif program == "prefill":
-        # the serving cells' batch: 16 slots' chunks of 16 tokens a program,
-        # at the page bucket of a 1024-token row (serve_xl_chat's longest)
-        W = serve.prefill_width(16, 16)
-        lowered = serve._serve_prefill_chunk.lower(
-            cfg, params, arr((W, 16)), arr((W,)), arr((W,)), cache,
-            arr((W, 128)), None, "kernel", 0.8, None, None, key,
-        )
-    else:
-        k = 4  # spec_k_max drafts + the pending token: 5 verify rows
-        lowered = serve._spec_verify_chunk.lower(
-            cfg, params, arr((B,)), arr((k, B)),
-            arr((k, B, cfg.vocab_size), jnp.float32), cache, table, lengths,
-            active, 0.8, None, None, "kernel", key,
-        )
-    assert _pool_census(lowered, cache) == 0
 
 
 def test_pool_census_counts_an_xla_scatter_on_the_pool(one_chip, compiled_kernels):

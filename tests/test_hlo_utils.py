@@ -135,6 +135,44 @@ ENTRY %main (a: bf16[8,8]) -> bf16[8,8] {
 """
 
 
+# `gather` instructions as the v5e's compiler printed them in the XL decode
+# program of PR 57's parent (the first) and of PR 57 (the next three), and one
+# whose only mark is the frame it was traced in.
+_FRAME_TABLES = """\
+FileNames
+1 "/root/repo/midgpt_tpu/ops/rope.py"
+
+FunctionNames
+1 "apply_rope_positions"
+2 "rotate_interleaved_strided"
+
+FileLocations
+1 {file_name_id=1 function_name_id=1 line=96 end_line=96 column=10 end_column=42}
+2 {file_name_id=1 function_name_id=2 line=34 end_line=34 column=9 end_column=20}
+
+StackFrames
+1 {file_location_id=1 parent_frame_id=1}
+2 {file_location_id=2 parent_frame_id=1}
+
+"""
+ROTARY_GATHER_LINES = {
+    "channels_of_an_activation": (1, '  %gather.126 = bf16[16,16,64]{2,1,0:T(8,128)(2,1)} gather(%param_0.1157, %transpose.186), offset_dims={0,1}, collapsed_slice_dims={2}, start_index_map={2}, index_vector_dim=1, slice_sizes={16,16,1}, indices_are_sorted=true, metadata={op_name="jit(_serve_decode_chunk)/while/body/closed_call/gather" stack_frame_id=1}'),
+    "table_rows_by_position": (0, '  %gather.35 = bf16[16,128]{1,0:T(8,128)(2,1)} gather(%param_0.1161, %transpose.100), offset_dims={1}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1,128}, metadata={op_name="gather" stack_frame_id=1}'),
+    "embedding_rows": (0, '  %gather.33 = bf16[16,2048]{1,0:T(8,128)(2,1)} gather(%param_0.1144, %transpose.96), offset_dims={1}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1,2048}, metadata={op_name="jit(_serve_decode_chunk)/while/body/closed_call/jit(_take)/gather" stack_frame_id=1}'),
+    "one_element_by_a_full_index": (0, '  %gather.36 = s32[16]{0:T(128)} gather(%param_0.1170, %custom-call.16), offset_dims={}, collapsed_slice_dims={0,1}, start_index_map={0,1}, index_vector_dim=1, slice_sizes={1,1}, metadata={op_name="jit(_serve_decode_chunk)/while/body/closed_call/jit(take_along_axis)/gather" stack_frame_id=1}'),
+    "traced_in_rotate_interleaved": (1, '  %gather.9 = bf16[16,128]{1,0} gather(%p, %i), offset_dims={1}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1,128}, metadata={op_name="gather" stack_frame_id=2}'),
+}
+
+
+@pytest.mark.parametrize("case", list(ROTARY_GATHER_LINES))
+def test_rotary_gathers_counts_channel_picks_and_not_rows_by_position(case):
+    from midgpt_tpu.analysis.hlo_audit import rotary_gathers
+
+    want, line = ROTARY_GATHER_LINES[case]
+    assert rotary_gathers(_FRAME_TABLES + line + "\n") == want
+    assert rotary_gathers(line + "\n") == (want if case != "traced_in_rotate_interleaved" else 0)
+
+
 def test_permute_overlap_census_reads_what_stands_between_start_and_done():
     """A start/done pair with a matmul fusion between them is covered, one
     with nothing between is not; dtypes come from the start's first buffer;

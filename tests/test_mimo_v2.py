@@ -295,7 +295,12 @@ def test_the_reference_blocks_its_queries_without_changing_its_result(model, mon
 # program now, the program itself untouched) are the parent's of PR 30, byte for byte. The two `prefill16` entries: taken
 # again at PR 54, whose prefill attends through `paged_verify_attention` (here its gather lowering: the layer sliced, then one
 # gather of the pages, where the parent gathered with the layer in the indices; the same arithmetic in the same order, and the
-# tokens are the parent's bit for bit: tests/test_prefill_width1_round.py, tests/test_sampled_streams.py)
+# tokens are the parent's bit for bit: tests/test_prefill_width1_round.py, tests/test_sampled_streams.py). The four
+# `openwebtext_xl` entries (`rope_style` "interleaved"): taken again at PR 57, whose `apply_rope_positions` (the serving
+# programs' rotary) rolls lanes and selects by the channel's parity over tables widened before their rows are taken (ops/rope.py),
+# where the parent sliced stride-2 channels and stacked them: a permutation and a sign, the same values to the bit
+# (tests/test_rope.py against the stride-2 spelling, which the training entry points keep; the two token goldens above pass
+# untouched). The four `openwebtext` entries (`rope_style` "split") did not move.
 GPT_PROGRAM_HASHES = json.load(open(os.path.join(os.path.dirname(__file__), "golden", "gpt_serving_programs_pr29.json")))
 
 

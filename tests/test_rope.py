@@ -5,13 +5,74 @@ assertion: rolling Q and K by s positions must roll the attention scores."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from midgpt_tpu.ops.rope import apply_rope, rope_table, rotate_interleaved
+from midgpt_tpu.ops import rope
+from midgpt_tpu.ops.rope import apply_rope, rope_table, rotate_interleaved, rotate_interleaved_strided
+
+ROTARY_CASES = [(C, dtype) for C in (64, 128) for dtype in ("float32", "bfloat16")]
+rotary_cases = pytest.mark.parametrize(
+    "C,dtype", ROTARY_CASES, ids=[f"c{C}-{d}" for C, d in ROTARY_CASES]
+)
 
 
-def test_rotate_interleaved_pattern():
+@pytest.mark.parametrize("rotate", [rotate_interleaved, rotate_interleaved_strided])
+def test_rotate_interleaved_pattern(rotate):
     x = jnp.array([[1.0, 2.0, 3.0, 4.0]])
-    np.testing.assert_allclose(np.asarray(rotate_interleaved(x)), [[-2.0, 1.0, -4.0, 3.0]])
+    np.testing.assert_allclose(np.asarray(rotate(x)), [[-2.0, 1.0, -4.0, 3.0]])
+
+
+@rotary_cases
+def test_rolled_rotation_is_the_strided_spelling_to_the_bit(C, dtype):
+    """[1 2 3 4 ...] -> [-2 1 -4 3 ...] at the two head widths, and any values
+    as the stride-2 spelling (the training entry points', the parent of PR
+    57's everywhere) rotates them: a permutation and a sign."""
+    ramp = jnp.arange(1, C + 1, dtype=dtype)[None]
+    want = np.stack((-np.arange(2, C + 1, 2), np.arange(1, C, 2)), -1).reshape(1, C)
+    np.testing.assert_array_equal(np.asarray(rotate_interleaved(ramp), np.float32), want)
+    x = jax.random.normal(jax.random.PRNGKey(5), (3, 5, 2, C), dtype)
+    got = rotate_interleaved(x)
+    assert got.dtype == x.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(rotate_interleaved_strided(x)))
+
+
+@rotary_cases
+def test_interleaved_entry_points_agree_to_the_bit(C, dtype):
+    """At equal positions the serving entry point (`apply_rope_positions`:
+    rolls, the tables widened before their rows are taken) is the training
+    entry points' (`apply_rope`, `apply_rope_bthc`: stride-2 slices, rows of
+    the half-width tables duplicated after) values to the bit."""
+    B, T, H = 2, 8, 3
+    x = jax.random.normal(jax.random.PRNGKey(6), (B, T, H, C), dtype)
+    sin, cos = rope_table(C, 32)
+    want = np.asarray(rope.apply_rope_bthc(x, sin, cos))
+    np.testing.assert_array_equal(
+        np.asarray(rope.apply_rope_bthc(x, sin, cos, positions=jnp.arange(T))), want
+    )
+    bhtc = apply_rope(x.transpose(0, 2, 1, 3), sin, cos)
+    np.testing.assert_array_equal(np.asarray(bhtc.transpose(0, 2, 1, 3)), want)
+    positions = jnp.broadcast_to(jnp.arange(T), (B, T))
+    np.testing.assert_array_equal(
+        np.asarray(rope.apply_rope_positions(x, sin, cos, positions)), want
+    )
+
+
+@rotary_cases
+def test_rolled_gradient_is_the_strided_spelling_s_to_the_bit(C, dtype):
+    """`jax.grad` through the rotation of `apply_rope_positions` against
+    `jax.grad` through `apply_rope_bthc`: the transpose of two rolls and a
+    select gives what the transpose of the stride-2 slices (a pad / scatter)
+    gives, so an entry point may change its spelling without a new golden."""
+    B, T = 2, 8
+    x = jax.random.normal(jax.random.PRNGKey(7), (B, T, 3, C), dtype)
+    w = jax.random.normal(jax.random.PRNGKey(8), x.shape, dtype)
+    sin, cos = rope_table(C, T)
+    positions = jnp.broadcast_to(jnp.arange(T), (B, T))
+    loss = lambda f: lambda x: jnp.sum((f(x) * w).astype(jnp.float32))
+    got = jax.grad(loss(lambda x: rope.apply_rope_positions(x, sin, cos, positions)))(x)
+    want = jax.grad(loss(lambda x: rope.apply_rope_bthc(x, sin, cos)))(x)
+    assert got.dtype == x.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_rope_preserves_norm():
