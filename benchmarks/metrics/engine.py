@@ -1,8 +1,9 @@
-"""serving engine: host time per decode round (the engine's own
-`decode.dispatch` + `decode.host_post` spans), how full the slots were (active
-slots / max_slots sampled after every round), and the share of processed
-tokens that were prompt tokens (eng.prefilled_tokens against delivered output
-tokens)."""
+"""serving engine: how full the slots were (active slots / max_slots sampled
+after every round), and the share of processed tokens that were prompt tokens
+(eng.prefilled_tokens against delivered output tokens). The host's time per
+decode round is read span by span: metrics/engine_dispatch.py
+(`decode.dispatch_ms_p50`, `decode.host_post_ms_p50`) and metrics/engine_requests.py
+(`engine.round_self_ms_p50`)."""
 
 import statistics
 
@@ -18,8 +19,4 @@ def read(run):
     if c["prefilled_tokens"] + c["output_tokens"]:
         out["engine.prefill_token_share"] = (
             100.0 * c["prefilled_tokens"] / (c["prefilled_tokens"] + c["output_tokens"]))
-    disp = [d for n, _, d in run["spans"] if n == "decode.dispatch"]
-    post = [d for n, _, d in run["spans"] if n == "decode.host_post"]
-    if disp and len(disp) == len(post):
-        out["engine.round_host_ms_p50.serve"] = 1e3 * statistics.median(a + b for a, b in zip(disp, post))
     return out

@@ -34,8 +34,10 @@ every idle instant that touches neither end of an execution: what the host
 did between a landing and its next dispatch.
 
 From the join: the device time of one prefill or decode execution (by rows,
-steps, bucket: the logged table), what a token costs the device, and the time
-from a mark to its execution where the device was found idle. From the marks
+steps, bucket: the logged table) and what a token costs the device. The time
+from ONE mark to its execution is not reported: it is good to the half width
+and no better, which is as large as the value; `engine.launch_idle_share` sums
+it over the window, where the sum is exact. From the marks
 and ALL module executions: the device's idle time outside executions, cut at
 every mark into LAUNCH (a dispatch has begun and its execution has not: the
 call's own work and the transfer of its numpy arguments) and STARVED (nothing
@@ -58,7 +60,6 @@ Modules` line (the CPU rehearsal) or a training cell reports nothing of the
 join and says so.
 """
 
-import bisect
 import collections
 import os
 import statistics
@@ -245,9 +246,9 @@ def device_calls(pairs, spans_by_call, commits_by_call, lo, hi):
 
 def idle_split(reduce, pairs, modules, leaf_busy, spans, lo, hi):
     """One device's idle nanoseconds inside [lo, hi): launch, starved (and by span name), the
-    residual inside executions, what the leaf ops say in all, and the launches that found the
-    device idle. `modules`: (name, start, duration) of EVERY program's executions; `leaf_busy`:
-    merged busy intervals of the leaf ops; `spans`: (start, seq, end, name) on the trace's clock."""
+    residual inside executions and what the leaf ops say in all. `modules`: (name, start,
+    duration) of EVERY program's executions; `leaf_busy`: merged busy intervals of the leaf ops;
+    `spans`: (start, seq, end, name) on the trace's clock."""
     window = [(lo, hi)]
     clipped = lambda ivs: [(max(a, lo), min(b, hi)) for a, b in ivs if min(b, hi) > max(a, lo)]
     busy = reduce.union(clipped((s, s + d) for _, s, d in modules))
@@ -255,18 +256,10 @@ def idle_split(reduce, pairs, modules, leaf_busy, spans, lo, hi):
     pending = reduce.union(clipped((m, hi if ex is None else ex[1]) for _, m, ex in pairs))
     starved = reduce.subtract(idle, pending)
     leaf = reduce.union(clipped(leaf_busy))
-    starts, found_idle, running = [b[0] for b in busy], [], lo
-    for _, m_start, ex in pairs:  # in call order: `running` = when the executions before this one end
-        if ex is not None and lo <= m_start and ex[1] + ex[2] <= hi:
-            k = bisect.bisect_right(starts, m_start) - 1
-            if m_start >= running and not (k >= 0 and busy[k][1] > m_start):
-                found_idle.append(ex[1] - m_start)
-        if ex is not None:
-            running = max(running, ex[1] + ex[2])
     return {"launch": reduce.total(idle) - reduce.total(starved), "starved": reduce.total(starved),
             "by_span": split_by_innermost(starved, spans),
             "residual": reduce.total(reduce.subtract(busy, leaf)),
-            "idle_leaf": (hi - lo) - reduce.total(leaf), "found_idle": found_idle}
+            "idle_leaf": (hi - lo) - reduce.total(leaf)}
 
 
 def plane_metrics(calls, idle, rode, window):
@@ -279,13 +272,10 @@ def plane_metrics(calls, idle, rode, window):
     out = {
         "prefill.call_device_ms_p50": med_ms([d for d, _ in calls["prefill"]]),
         "prefill.device_us_per_token": per_token(calls["prefill"], lambda c: c[1]["tokens"]),
-        "prefill.one_row_call_device_ms_p50": med_ms(
-            [d for d, a in calls["prefill"] if a.get("width", 1) > 1 and a["rows"] == 1]),
         "decode.step_device_ms_p50": med_ms([d / a["steps"] for d, a, _ in calls["decode"]]),
         "decode.device_us_per_token": per_token([c for c in calls["decode"] if c[2] is not None], lambda c: c[2]),
         "decode.live_block_share": 100.0 * sum(a["blocks_live"] for a in rode if "blocks_swept" in a) / swept
         if swept else None,
-        "engine.launch_ms_p50": med_ms(idle["found_idle"]),
     }
     parts = idle["launch"] + idle["starved"] + idle["residual"]
     closed = (abs(sum(idle["by_span"].values()) - idle["starved"]) <= SUM_TOLERANCE * window
@@ -345,9 +335,6 @@ def summarize(reduce, marks, modules, leaf_by_plane, events, lo, hi, log):
     if out["decode.live_block_share"] is None:
         log("engine_device_calls: the window's decode.dispatch spans carry no blocks_swept (the gather lowering, or "
             "a family whose decode program has two kernels of different geometry); decode.live_block_share left out")
-    if out["prefill.one_row_call_device_ms_p50"] is None:
-        log("engine_device_calls: no prefill call of ONE row in a program of width > 1 inside the window; "
-            "prefill.one_row_call_device_ms_p50 left out")
     return out
 
 

@@ -4,12 +4,16 @@ request waited to be admitted and how long from admission to its first token
 scheduler round is and what of it is the scheduler's own (`engine.round` and
 the phase spans nested in it).
 
-`req.queue_ms_mean` + `req.prefill_ms_mean` explain `serve.ttft_ms_mean`, so
-they are means over the SAME requests: those submitted and first answered
-inside the window. The engine stamps both tracks with the clock readings it
-hands the client (`on_token`), so per request queue + prefill is the client's
-time to first token to within the microseconds between the client's own clock
-read and `submit`'s. A request preempted before its first token has several
+`req.queue_ms_mean` + `req.prefill_ms_mean` ARE the mean time to first token
+(the two stand for it in the per-layer table: their sum read equal to the
+client's mean to four digits in all seven serving cells, ledger, PR 57; the
+cells still print the client's on their `ttft ms mean ... p50 ... p90 ... max`
+line, and `serve_124m_sample` reports it end to end), so they are means over
+the SAME requests: those submitted and first answered inside the window. The
+engine stamps both tracks with the clock readings it hands the client
+(`on_token`), so per request queue + prefill is the client's time to first
+token to within the microseconds between the client's own clock read and
+`submit`'s. A request preempted before its first token has several
 queue and prefill legs; they are summed.
 
 The async tracks are not in `run["spans"]` (it holds complete spans only), and

@@ -4,12 +4,13 @@ counters `ServeEngine.serve_counters()` gave the cell after its loops.
 a share of the pool; `kv.window_tokens_per_slot_max`: the most window-layer
 tokens one slot ever held (bounded by window + prefill chunk + page, whatever
 the context); `serve.moe_experts_touched`: held experts with at least one pair
-of an active slot, mean over decode steps and routed layers (what a kernel
-reads only touched experts reads: `ops/moe.py` `moe_experts_serving` walks
-the tiles in use); `serve.moe_load_max_over_mean`: the most loaded held expert
-over the mean of the held, worst layer, over the run's decode steps. A run
-whose counters hold none of these (every GPT cell, the parent of PR 30)
-reports nothing."""
+of an active slot, mean over decode steps and routed layers (the experts whose
+matrices a step has to read: `ops/moe.py` `moe_experts_serving` sorts the rows
+by expert and its one grouped matmul, `kernels/grouped_matmul.py`, streams an
+expert only where a row block holds its rows); `serve.moe_load_max_over_mean`:
+the most loaded held expert over the mean of the held, worst layer, over the
+run's decode steps. A run whose counters hold none of these (every GPT cell,
+the parent of PR 30) reports nothing."""
 
 
 def read(run):

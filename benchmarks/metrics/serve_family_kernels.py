@@ -15,10 +15,15 @@ FLOPs those tokens need at the published widths
 that time. `kv_write_ms_per_token` / `_roofline`: the write's time per token
 written (decoded or prefilled), against the bytes of the rows themselves. A
 program without these kernels (every GPT cell, the parent of PR 30) reports
-nothing."""
+nothing, and neither does a configuration without a `layer_pattern`: the
+arithmetic here is that of a family whose layers' kinds the pattern names
+(arithmetic_mimo_v2.layer_kinds), and a family that says its kinds another way
+brings readers of its own (serve_kinds_kernels.py, serve_sparse_kernels.py)."""
 
 
 def read(run):
+    if "layer_pattern" not in run["model"]:
+        return None
     got = run["load"]("metrics/serve_family_scopes.py").attribute(run)
     tr = run.get("traced") or {}
     if not got or not got["kernel"] or got["known"] < 0.98 * got["total"]:

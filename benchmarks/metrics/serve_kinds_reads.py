@@ -1,6 +1,6 @@
 """model step (serve), a family whose configuration file says what to read
-(serve_kinds_scopes.py `settings`): how close the decode step and its expert
-loop come to the time the chip needs just to READ what they must read.
+(serve_kinds_scopes.py `settings`): how close the decode step and its routed
+experts come to the time the chip needs just to READ what they must read.
 
 `serve.weight_read_share` (a name the benchmark has): the least time the chip
 could take to read the weights a decode step must read (the configuration's
@@ -12,21 +12,24 @@ decode step: the decode programs' device time in the traced window
 (serve_looped_cache.traced_decode_steps).
 
 `serve.moe_expert_read_share`: the least time the chip could take to read the
-expert matrices a decode step's loops had to read (the run's mean of held
-experts touched by an ACTIVE slot's pair a step a layer, the same counter, x
-the routed layers x the module's `expert_bytes`: the counter is cumulative and
+expert matrices a decode step's routed layers had to read (the run's mean of
+held experts touched by an ACTIVE slot's pair a step a layer, the same counter,
+x the routed layers x the module's `expert_bytes`: the counter is cumulative and
 the cell does not snapshot it at the trace's start, so it is the run's mean a
 decode step, times the traced steps) over the decode programs'
-device time under the group's `expert_scopes` in the traced window: the expert
-loop's share of its roofline. The scopes are `moe_route` AND `moe_experts`
-together: the compiler fuses a tile's row gather (opened under `moe_route`)
-with the matmul that reads the expert's matrix, and names the fusion after
-either, so the matrices' read time lies under both names (over `moe_experts`
-alone the first chip run read 202 %: the time left out part of the work;
-PERF.md section 6 PR 46; ROADMAP S11 found the same from PR 43: "the two
-scopes are ONE cost"). The router's own matmul and top-k ride in that time and
-are credited no bytes. An expert only an inactive slot's garbage pair selects
-is read and not credited.
+device time under the group's `expert_scopes` in the traced window: the routed
+experts' share of their read roofline. The scopes are `moe_route` AND
+`moe_experts` together. Since PR 50 the experts are one grouped matmul
+(`ops/moe.py` `moe_experts_serving`: a plan, one gather of the tokens into rows
+sorted by expert, one Pallas call, a gather back), and the plan and the gathers
+open under `moe_route`; before it, when a loop walked tiles, the compiler fused
+a tile's row gather with the matmul that read the expert's matrix and named the
+fusion after either scope (over `moe_experts` alone the first chip run read
+202 %: the time left out part of the work; PERF.md section 6 PR 46; ROADMAP S11
+found the same from PR 43: "the two scopes are ONE cost"). Both are kept so
+that the time holds all the work the bytes are credited to. The router's own
+matmul and top-k ride in that time and are credited no bytes. An expert only
+an inactive slot's garbage pair selects is read and not credited.
 
 A configuration without the group, a run without those counters or without a
 trace of decode steps reports nothing."""
@@ -61,7 +64,7 @@ def read(run):
         model = run["model"]
         step_bytes = float(touched) * (model["n_layer"] - model["n_dense_layers"]) * own.expert_bytes(model, itemsize)
         out["serve.moe_expert_read_share"] = 100.0 * step_bytes * steps / hbm / (expert_ns / 1e9)
-        run["log"](f"expert loop (decode): {expert_ns / 1e6 / steps:.2f} ms a step under {' + '.join(cfg['expert_scopes'])}; "
+        run["log"](f"routed experts (decode): {expert_ns / 1e6 / steps:.2f} ms a step under {' + '.join(cfg['expert_scopes'])}; "
                    f"{step_bytes / 1e9:.3f} GB of expert matrices to read a step: "
                    f"{out['serve.moe_expert_read_share']:.2f} % of its read floor")
     return out

@@ -6,8 +6,7 @@ on time.perf_counter like the benchmark's own spans), so the program's loop and
 the benchmark's copy of it are timed by the same spans. A step's feed is one
 `data.batch` and the `data.put`s up to the next one. The window is placed from
 `run["spans"]`: from their earliest start, for `run["window_s"]`. A program
-that opens no such spans (the parent of PR 24) reports nothing.
-(`data.batch_ms_p50` is the same quantity timed from outside.)"""
+that opens no such spans (the parent of PR 24) reports nothing."""
 
 import statistics
 

@@ -92,7 +92,6 @@ def test_both_sums_close_and_the_parts_are_the_hand_worked_nanoseconds():
         assert idle[k] == pytest.approx(v, abs=2), k
     assert idle["launch"] + idle["starved"] + idle["residual"] == pytest.approx(idle["idle_leaf"], abs=2)
     assert sum(idle["by_span"].values()) == pytest.approx(idle["starved"], abs=40)  # a nanosecond a boundary
-    assert idle["found_idle"] == [300_000, 350_000, 300_000]  # call 10's mark found call 9 running
 
 
 def test_split_by_overlap_where_the_midpoint_rule_names_another_owner():
@@ -187,13 +186,11 @@ def test_a_cell_that_cannot_have_a_metric_leaves_it_out_and_says_why():
     for e in events:
         if e[1] == "decode.dispatch":  # a family with two decode kernels: no census on the span
             e[7] = {k: v for k, v in e[7].items() if not k.startswith("blocks_")}
-        if e[1] == "prefill.chunk":  # a family that prefills one row a call
-            e[7] = {**e[7], "rows": 1, "width": 1}
     log = []
     got = summarize(changed(events=events), log)
-    assert got["decode.live_block_share"] is None and got["prefill.one_row_call_device_ms_p50"] is None
+    assert got["decode.live_block_share"] is None
     assert any("decode.live_block_share left out" in line for line in log)
-    assert any("prefill.one_row_call_device_ms_p50 left out" in line for line in log)
+    assert got["prefill.call_device_ms_p50"] == pytest.approx(0.7)  # the rest of the join stands
 
 
 def _run(**over):
