@@ -489,6 +489,7 @@ MODEL_FAMILIES: tp.Dict[str, str] = {
     "ouro": "midgpt_tpu.models.ouro:OuroConfig",
     "afmoe": "midgpt_tpu.models.trinity:TrinityConfig",
     "dots3_note": "midgpt_tpu.models.dots3:Dots3Config",
+    "olmo_hybrid": "midgpt_tpu.models.olmo_hybrid:OlmoHybridConfig",
 }
 
 

@@ -9,8 +9,12 @@ terms (decay tiles, A, B, the block solve, W_v, W_k) and the float32 state live
 in VMEM; HBM sees the inputs, `o`, the state at every chunk's start (the
 residual of the backward pass: T / C states a head, what the jnp body's
 checkpointed scan keeps), the final state and the cotangents. The state comes
-IN as an operand (zeros from `ops/kda.py`), so a chunked-prefill step can start
-from a slot's state.
+IN as an operand (zeros where `ops/kda.py` `kda_chunked` is given none: the
+training path), so a chunked-prefill step starts from a slot's state and hands
+the next chunk's back (models/olmo_hybrid.py). d_k and d_v are the operands'
+own (128 x 128 in Kimi-Linear, 96 x 192 in Olmo-Hybrid: blocks as wide as the
+arrays, nothing padded), and a head count that four does not divide takes two
+or one a step (`_dims`).
 
 Layout: q, k, v, g travel head-major, (B, H, T, d): a chunk of a head is one
 contiguous block, and the two transposes around the call are the compiler's to
@@ -467,9 +471,10 @@ def kda_scan(
     *, chunk: int, sub: int,
 ) -> tp.Tuple[Array, Array]:
     """`ops/kda.py`'s `kda_chunked` through the kernels. q, k, g (B, T, H,
-    d_k); v (B, T, H, d_v); beta (B, T, H); `initial_state` (B, H, d_k, d_v)
+    d_k); v (B, T, H, d_v); beta (B, T, H); `initial_state` (B, H, d_v, d_k)
     float32, zeros if None. Returns (o (B, T, H, d_v) in v's dtype, final
-    state (B, H, d_k, d_v) float32)."""
+    state (B, H, d_v, d_k) float32): the state as the kernels hold it, in and
+    out, and no transpose is made."""
     B, T, H, dk = k.shape
     dv = v.shape[-1]
     N = -(-T // chunk)
@@ -482,6 +487,6 @@ def kda_scan(
     # transposes are the compiler's to fold into the layouts of what feeds them
     q, k, v, g = (jnp.swapaxes(padded(a), 1, 2) for a in (q, k, v, g.astype(_F32)))
     b5 = jnp.swapaxes(padded(beta.astype(_F32)), 1, 2).reshape(B, H, N, 1, chunk)
-    s0T = jnp.zeros((B, H, dv, dk), _F32) if initial_state is None else jnp.swapaxes(initial_state, 2, 3).astype(_F32)
+    s0T = jnp.zeros((B, H, dv, dk), _F32) if initial_state is None else initial_state.astype(_F32)
     o, sfT = _kda(q, k, v, g, b5, s0T, sub)
-    return jnp.swapaxes(o, 1, 2)[:, :T], jnp.swapaxes(sfT, 2, 3)
+    return jnp.swapaxes(o, 1, 2)[:, :T], sfT

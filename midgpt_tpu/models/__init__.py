@@ -5,7 +5,7 @@ here, and call each without asking whether it is there. Families are
 registered in `midgpt_tpu/config.py` `MODEL_FAMILIES`.
 
 The config (`GPTConfig`, `KimiLinearConfig`, `MimoV2Config`, `PanguUltraConfig`,
-`OuroConfig`, `TrinityConfig`, `Dots3Config`):
+`OuroConfig`, `TrinityConfig`, `Dots3Config`, `OlmoHybridConfig`):
 
     block_size, vocab_size, n_layer, n_head, n_embd   fields, under these names
                                (`n_layer`: layers of WEIGHTS; how many layers
@@ -21,7 +21,8 @@ The config (`GPTConfig`, `KimiLinearConfig`, `MimoV2Config`, `PanguUltraConfig`,
                                stack (sample.py, ServeEngine) holds no cache
                                for this family; returns None where it does
 
-The namespace (`GPT`, `KimiLinear`, `MimoV2`, `PanguUltra`, `Ouro`, `Trinity`, `Dots3`):
+The namespace (`GPT`, `KimiLinear`, `MimoV2`, `PanguUltra`, `Ouro`, `Trinity`, `Dots3`,
+`OlmoHybrid`):
 
     init(config, key) -> params
     hidden(config, params, tokens, *, key, inference, attn_fn) -> (B, T, D)
@@ -40,10 +41,10 @@ The namespace (`GPT`, `KimiLinear`, `MimoV2`, `PanguUltra`, `Ouro`, `Trinity`, `
                                counters the train loop logs at a logged step
 
 The SERVING members, of every family whose `check_serving` returns None
-(`GPT`, `MimoV2`, `PanguUltra`, `Ouro`, `Trinity`, `Dots3`; sampling/serve.py
-calls them, never a family by name):
+(`GPT`, `MimoV2`, `PanguUltra`, `Ouro`, `Trinity`, `Dots3`, `OlmoHybrid`;
+sampling/serve.py calls them, never a family by name):
 
-    cache_kinds(config) -> (CacheKind(name, window, sinks), ...)
+    cache_kinds(config) -> (CacheKind(name, window, sinks), ..., [StateKind(name, shapes), ...])
                                the kinds of paged cache the layers need, the
                                engine's first kind first. Each gets a pool, a
                                page table and an allocator of its own; `window`
@@ -60,10 +61,34 @@ calls them, never a family by name):
                                (models/dots3.py): `latent` (window 0; the layers
                                whose indexer selects what they attend) and
                                `window_latent` (513), both latent rows.
+                               A STATE kind (`StateKind`, after the paged
+                               kinds) is memory that is a ROW A SLOT and not a
+                               row a token: a recurrent layer's state, as large
+                               for ten tokens as for ten thousand.
+                               `shapes(cache_dtype)` gives ((shape, dtype), ...)
+                               of one slot's row. It has no pages, window or
+                               table: the pool owner (sampling/pages.py "State
+                               kinds") sizes it by the slot count (+ a sink
+                               row), gives slot i row i, keeps it in the
+                               conservation law and hands the programs each
+                               table row's state row as the LAST entry of the
+                               `page_table` tuple; what moves pages (prefix
+                               cache, speculation, int8, a mesh, hot swap,
+                               resize, spill, disaggregated hand-off) is
+                               refused by name beside one. OlmoHybrid
+                               (models/olmo_hybrid.py): `global` (its full
+                               layers, one cache layer a period) and the state
+                               kind `gdn_state`: every linear layer's delta-rule
+                               state (heads, d_v, d_k) float32, as the chunked
+                               kernels hold it, and its convolution's history.
     init_cache(config, num_pages, page_size, dtype, kernel_layout) -> cache
-                               `num_pages[i]` pages for kind i; the cache is a
-                               pytree with `pool_arrays()` (its page pools, for
-                               the layout census), `page_size`, `num_pages`.
+                               `num_pages[i]` pages for kind i (for a STATE
+                               kind: its ROWS, the sink row included); the
+                               cache is a pytree with `pool_arrays()` (its page
+                               pools, for the layout census), `page_size`,
+                               `num_pages`, and, with a state kind, `state`: the
+                               tuple of its arrays, the row axis SECOND
+                               ((layers, rows, ...)), the last row the sink.
                                The pools' LAYER axis is the family's, not
                                `n_layer`: the engine sizes, allocates and
                                frees PAGES and never reads it. The GPT: one
@@ -123,7 +148,16 @@ calls them, never a family by name):
                                layer, takes no row among the experts':
                                `moe_serving`'s `valid`). logits: the row at
                                each slot's last valid position (all the
-                               engine reads).
+                               engine reads). A family with a state kind reads
+                               each row's state from its state row and writes
+                               back the state after its n_valid tokens; rows
+                               past n_valid change nothing of it. A row whose
+                               `start` is 0 (its prompt's first chunk) begins
+                               from ZEROS whatever its state row holds: that
+                               IS the row's reset for a request admitted to a
+                               slot another has left (a stale page is masked
+                               by the length, a stale row would be a wrong
+                               answer); no program of the pool owner does it.
                                The ONE-ROW call, which every family takes and
                                an engine of width 1 makes (a family that is
                                not `prefill_batched`, or shapes that give 1):
@@ -134,6 +168,11 @@ calls them, never a family by name):
                                last valid row's alone
     decode_step_paged(config, params, token (B,), cache, page_table, lengths,
         active, attn_impl, mesh, split_k) -> (logits (B, V), cache)
+                               the batch is the slots in order (slot b's state
+                               row is row b: `PagePool.tables` raises on any
+                               other); an INACTIVE slot writes no key
+                               and leaves its state row bit for bit (it may be
+                               in the middle of its chunked prefill)
     verify_step_paged          the speculative verify step with decode's
                                arguments over (B, k + 1) tokens, or None where
                                the family has none (the engine then refuses a
@@ -142,7 +181,9 @@ calls them, never a family by name):
                                is a next-token-prediction layer that reads the
                                target's last hidden state, which is left out,
                                and the engine's draft model is a GPT. Ouro:
-                               none
+                               none. OlmoHybrid: none (a rejected draft would
+                               need the state it started from: snapshots are
+                               not wired)
     kernel_sweep(config, cache) -> (pool shape, q rows a pool head, window,
                                sinks) of the decode kernel's sweep, for the
                                engine's block counters
@@ -165,7 +206,10 @@ calls them, never a family by name):
                                scored, latent rows selected; Ouro: decode steps, passes
                                run, the exit gate's distribution summed over
                                decoded tokens, the pools' bytes a token over
-                               all n_loop * n_layer cache layers), read on
+                               all n_loop * n_layer cache layers; OlmoHybrid:
+                               `gdn.decode_tokens`, `gdn.prefill_tokens`,
+                               `gdn.prefill_chunks`, what its linear layers
+                               took; the pool owner adds `state.*`), read on
                                demand
 """
 

@@ -329,6 +329,13 @@ class KVCache:
 # long as its request; > 0: a page is dead once every future query's window
 # has passed it. `sinks`: leading tokens that stay visible, never freed.
 CacheKind = collections.namedtuple("CacheKind", "name window sinks")
+# A kind of cache whose memory is a STATE a slot and not a row a token (a
+# recurrent layer's: models/olmo_hybrid.py): `shapes(cache_dtype)` gives
+# ((shape, dtype), ...) of ONE slot's row. The pool owner sizes it by the slot
+# count, hands slot i row i and keeps its books (sampling/pages.py "State
+# kinds"); the family's prefill begins a prompt's first chunk from zeros,
+# whatever the row holds. It has no pages, no window and no table.
+StateKind = collections.namedtuple("StateKind", "name shapes")
 
 
 @pytree_dataclass

@@ -144,11 +144,15 @@ class KimiLinearConfig:
 
     def check_serving(self, who: str) -> None:
         raise NotImplementedError(
-            f"{who} cannot serve a {FAMILY} checkpoint yet: the serving stack holds paged pools, of several "
-            "kinds side by side (models/mimo_v2.py), a latent (MLA) pool and its absorbed decode among them "
-            "(models/pangu_ultra.py), and this model needs a recurrent KDA state per slot beside the latent "
-            "pool: a STATE kind of cache, a one-token and a state-carrying chunked KDA step. Train it with "
-            "launch.py; serving is listed in ROADMAP.md Queue 2."
+            f"{who} cannot serve a {FAMILY} checkpoint yet. The serving stack holds paged pools of several kinds side "
+            "by side (models/mimo_v2.py), a latent (MLA) pool with its absorbed decode (models/pangu_ultra.py) and, "
+            "since models/olmo_hybrid.py, a STATE kind of cache (a row a slot, reset by a prompt's first chunk, carried from "
+            "prefill chunk to prefill chunk: sampling/pages.py) with a one-token and a state-carrying chunked "
+            "delta-rule step (ops/kda.py kda_step / kda_chunked(initial_state=)), both of which take this model's "
+            "per-channel gate as they are. What is STILL missing for this family: a NoPE variant of the latent row "
+            "(its MLA layers rotate no key group, the latent pool's row has one), this family's serving members "
+            "(cache_kinds, init_cache, prefill_paged_chunk, decode_step_paged over a latent kind AND a state kind), "
+            "and the routed experts' serving path at its shapes. Train it with launch.py; ROADMAP.md M2."
         )
 
     def check_training(self, who: str) -> None:

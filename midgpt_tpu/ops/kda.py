@@ -1,15 +1,32 @@
 """Kimi Delta Attention (KDA): the gated delta-rule recurrence, chunked.
 
 Per head, with a float32 state S of (d_k, d_v), a per-CHANNEL log decay
-g_t <= 0 of (d_k,) and a write strength beta_t in (0, 1):
+g_t <= 0 of (d_k,) and a write strength beta_t (in (0, 1) as Kimi-Linear
+publishes it; Gated DeltaNet with `allow_neg_eigval` doubles it to (0, 2), and
+nothing below asks for less than 2: the transition I - beta k k^T of a unit key
+then has eigenvalues in (-1, 1)):
 
     S' = Diag(exp(g_t)) S_{t-1}
     S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
     o_t = S_t^T q_t
 
 `kda_recurrent` is that, token by token (`lax.scan` over T): the test oracle,
-and nothing on the training path calls it. `kda_chunked` computes the same in
-chunks of C tokens. Inside a chunk, with G_r = sum_{j<=r} g_j (so every decay
+and nothing on the training path calls it. `kda_step` is ONE token of it, the
+serving path's decode step over every slot's state. `kda_chunked` computes the
+same in chunks of C tokens, from `initial_state` (zeros if None) to the final
+state it returns: training passes none and drops the result, a chunked prefill
+passes a slot's state in and keeps what comes back, one function and one oracle.
+A state that is CARRIED (into and out of `kda_step`, `kda_chunked` and the
+kernels) has one layout, S^T: (B, H, d_v, d_k), the one the kernels hold it in,
+so a serving pool stores its rows so and nothing transposes a slot's state at
+either end of a chunk (stored (d_k, d_v) the chip's compiler relaid the WHOLE
+pool of rows out around the prefill program: PERF.md section 6 PR 59). Only the
+oracle keeps the equations' (d_k, d_v); its tests transpose once.
+A gate that is ONE scalar a head and token (Gated DeltaNet) is the per-channel
+gate broadcast: g of (B, T, H) is taken as such (`_per_channel`). d_k and d_v
+need not be equal (96 x 192 there, 128 x 128 in Kimi-Linear).
+
+Inside a chunk, with G_r = sum_{j<=r} g_j (so every decay
 between two positions of the chunk is exp(G_r - G_i) <= 1) and S_0 the state
 at the chunk's start, each token writes a rank-one term k_r u_r^T:
 
@@ -76,14 +93,43 @@ def causal_depthwise_conv(x: Array, taps: Array) -> Array:
     return sum(xp[:, j : j + T] * taps[:, j] for j in range(K))
 
 
+def _per_channel(g: Array, k: Array) -> Array:
+    """The log decay as (..., d_k) float32: a gate of k's shape as it is, one
+    scalar a head (k's shape less its last dim) broadcast over the channels."""
+    g = g.astype(jnp.float32)
+    return g if g.ndim == k.ndim else jnp.broadcast_to(g[..., None], k.shape)
+
+
+def kda_step(q: Array, k: Array, v: Array, g: Array, beta: Array, state: Array) -> tp.Tuple[Array, Array]:
+    """ONE token of the recurrence, the module docstring's three lines. q, k
+    (B, H, d_k); v (B, H, d_v); g (B, H, d_k) or one scalar a head (B, H); beta
+    (B, H); `state` (B, H, d_v, d_k) float32, a carried state's one layout
+    (module docstring). Everything in float32, elementwise products and sums
+    over d_k (no matmul: a step is bound by the state it reads and writes, 2 x
+    4 x d_k x d_v bytes a head). Returns (o (B, H, d_v) float32, the next
+    state (B, H, d_v, d_k))."""
+    f32 = jnp.float32
+    q, k, v, beta = (a.astype(f32) for a in (q, k, v, beta))
+    of_k = lambda a: a[..., None, :]  # a (.., d_k) vector against the state's rows
+    S, decay = state.astype(f32), jnp.exp(_per_channel(g, k))
+    # Both sums over d_k are taken of the state AS IT CAME (one sweep reads it for both), the decay folded into the
+    # vectors: S'^T k = S^T (e^g k), and o = S_t^T q = S^T (e^g q) + beta (k . q) (v - S'^T k). With the write below
+    # the state is read twice and written once a step; summed after the write it would be read a third time.
+    pred = jnp.sum(S * of_k(decay * k), axis=-1)
+    seen = jnp.sum(S * of_k(decay * q), axis=-1)
+    wrote = v - pred
+    S = of_k(decay) * S + of_k(beta[..., None] * k) * wrote[..., None]
+    return seen + (beta * jnp.sum(k * q, axis=-1))[..., None] * wrote, S
+
+
 def kda_recurrent(
     q: Array, k: Array, v: Array, g: Array, beta: Array, initial_state: tp.Optional[Array] = None
 ) -> tp.Tuple[Array, Array]:
-    """Token-by-token oracle. q, k, g (B, T, H, d_k); v (B, T, H, d_v); beta
-    (B, T, H); `initial_state` (B, H, d_k, d_v), zeros if None. Returns
-    (o (B, T, H, d_v) float32, final state (B, H, d_k, d_v))."""
+    """Token-by-token oracle. q, k (B, T, H, d_k); g the same or (B, T, H); v
+    (B, T, H, d_v); beta (B, T, H); `initial_state` (B, H, d_k, d_v), zeros if
+    None. Returns (o (B, T, H, d_v) float32, final state (B, H, d_k, d_v))."""
     f32 = jnp.float32
-    q, k, v, g, beta = (a.astype(f32) for a in (q, k, v, g, beta))
+    q, k, v, g, beta = (a.astype(f32) for a in (q, k, v, _per_channel(g, k), beta))
     B, T, H, dk = k.shape
     S0 = jnp.zeros((B, H, dk, v.shape[-1]), f32) if initial_state is None else initial_state.astype(f32)
 
@@ -180,9 +226,14 @@ def _chunk_terms(q: Array, k: Array, v: Array, g: Array, beta: Array, *, sub: in
     )
 
 
-def kda_chunked(q: Array, k: Array, v: Array, g: Array, beta: Array) -> tp.Tuple[Array, Array]:
-    """Chunked KDA. q, k, g (B, T, H, d_k); v (B, T, H, d_v); beta (B, T, H).
-    Returns (o (B, T, H, d_v) in v's dtype, final state (B, H, d_k, d_v) f32).
+def kda_chunked(
+    q: Array, k: Array, v: Array, g: Array, beta: Array, initial_state: tp.Optional[Array] = None
+) -> tp.Tuple[Array, Array]:
+    """Chunked KDA. q, k (B, T, H, d_k); g the same or one scalar a head (B, T,
+    H); v (B, T, H, d_v); beta (B, T, H); `initial_state` (B, H, d_v, d_k),
+    zeros if None. Returns (o (B, T, H, d_v) in v's dtype, final state (B, H,
+    d_v, d_k) f32). A row with g = 0, beta = 0 leaves the state as it is (no
+    decay, no write): how a prefill chunk's rows past `n_valid` are masked.
 
     The matmuls against the state, and the decayed q-k and k-k products
     between sub-blocks, run in q's dtype with float32 accumulation (bf16 on the
@@ -193,14 +244,19 @@ def kda_chunked(q: Array, k: Array, v: Array, g: Array, beta: Array) -> tp.Tuple
     if jax.default_backend() == "tpu":
         from midgpt_tpu.kernels.kda import kda_scan
 
-        return kda_scan(q, k, v, g, beta, chunk=CHUNK, sub=SUB)
-    return kda_chunked_jnp(q, k, v, g, beta)
+        return kda_scan(q, k, v, _per_channel(g, k), beta, initial_state, chunk=CHUNK, sub=SUB)
+    return kda_chunked_jnp(q, k, v, g, beta, initial_state)
 
 
-def kda_chunked_jnp(q: Array, k: Array, v: Array, g: Array, beta: Array) -> tp.Tuple[Array, Array]:
+def kda_chunked_jnp(
+    q: Array, k: Array, v: Array, g: Array, beta: Array, initial_state: tp.Optional[Array] = None
+) -> tp.Tuple[Array, Array]:
     """`kda_chunked` as plain jnp: the terms of `CHUNKS_PER_BATCH` chunks at a
-    time under `lax.map`, then a `lax.scan` over the chunks."""
+    time under `lax.map`, then a `lax.scan` over the chunks (which carries the
+    state as the docstring's equations write it, (d_k, d_v): the carried
+    layout is taken and given at the two ends)."""
     chunk, sub = CHUNK, SUB
+    g = _per_channel(g, k)
     f32, mm = jnp.float32, q.dtype
     B, T, H, dk = k.shape
     dv = v.shape[-1]
@@ -233,6 +289,7 @@ def kda_chunked_jnp(q: Array, k: Array, v: Array, g: Array, beta: Array) -> tp.T
         S = gc[..., None] * S + dot("bhck,bhcv->bhkv", kd, Um)
         return S, o.astype(v.dtype)
 
-    S, o = jax.lax.scan(step, jnp.zeros((B, H, dk, dv), f32), (Wv, Wk, Qg, Bm, Kd, gC))
+    S0 = jnp.zeros((B, H, dk, dv), f32) if initial_state is None else jnp.swapaxes(initial_state, 2, 3).astype(f32)
+    S, o = jax.lax.scan(step, S0, (Wv, Wk, Qg, Bm, Kd, gC))
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3).reshape(B, N * chunk, H, dv)
-    return o[:, :T], S
+    return o[:, :T], jnp.swapaxes(S, 2, 3)

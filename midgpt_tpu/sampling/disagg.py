@@ -229,8 +229,8 @@ class DisaggServe:
         if len(kinds) > 1:
             raise NotImplementedError(
                 "DisaggServe: disaggregated prefill (a hand-off of pages of ONE kind, owned through "
-                f"the prefix trie) is not wired for a model with {len(kinds)} kinds of paged cache "
-                f"({', '.join(k.name for k in kinds)})"
+                f"the prefix trie) is not wired for a model with {len(kinds)} kinds of cache "
+                f"({', '.join(k.name for k in kinds)}: several kinds of page, or a STATE kind, whose row no page hand-off carries)"
             )
         if engine_kw.get("temperature", 0.0) != 0.0:
             raise ValueError("DisaggServe is greedy-only (module docstring)")

@@ -22,13 +22,35 @@ law has one place to be made:
     the conservation law (`live_pages`, `ledger`, `conserved`);
   * the page tables the serving programs take: `table`, `tables`, `bucket`.
 
+State kinds. A family may also name a kind of cache that is NOT paged
+(`models/gpt.py` `StateKind`: a recurrent layer's state, models/olmo_hybrid.py):
+one ROW a slot, as large for a prompt of ten tokens as for one of ten thousand,
+held in the family's cache under `state` (arrays whose SECOND axis is the row).
+It is this module's like the pages are: sized here (`max_slots` rows and a sink
+row, the last, which an empty place of a prefill call names), handed out here
+(`claim_state`: slot i's row is row i, so that a decode step over the slots in
+order updates the rows where they lie; `tables` holds every decode round's rows
+to that and raises otherwise) and given back through `release`; `ledger` /
+`conserved` hold it to the same law (rows free + rows live == rows), `counters`
+reports `state.*`, `hbm_bytes` counts its arrays, and `tables` hands the serving
+programs each slot's row index beside its page tables. A stale page is masked by
+the slot's length; a stale row would be a wrong answer, so a row is RESET for
+every request admitted to it: the family's prefill program begins a prompt's
+first chunk (start 0) from zeros whatever the row holds (the seam's contract,
+models/__init__.py), which a preempted request's recompute walks again; this
+module runs nothing on the device for it and counts the admissions
+(`state.resets`). What moves pages cannot move a row (the prefix
+trie, speculation's rollback, spill, resize, migrate, the disaggregated
+hand-off): the engine refuses each by name for such a family.
+
 `ServeEngine` (sampling/serve.py) keeps the policy: whom to admit, whose
 pages to take when the pool runs dry, what a round dispatches. A slot is the
 engine's (`serve._Slot`); what this module reads of one is `pages` (per kind,
 a LOGICAL list: entry j holds positions [j*ps, (j+1)*ps), -1 once the window
-rule freed it), `reclaimed_to`, `length`, `n_shared`, `generated` and
-`request.prompt`. This module imports neither the engine nor anything built
-on it.
+rule freed it), `reclaimed_to`, `length`, `n_shared`, `generated`,
+`request.prompt` and, for a family with a state kind, `state_row` (written
+here: -1 where the slot holds none). This module imports neither the engine
+nor anything built on it.
 """
 
 from __future__ import annotations
@@ -41,7 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from midgpt_tpu.models.gpt import PagedKVCache
+from midgpt_tpu.models.gpt import CacheKind, PagedKVCache
 
 # The page axis of each block key (and of the pool array it is taken from).
 _PAGE_AXIS = {"k": 2, "v": 2, "k_scale": 1, "v_scale": 1}
@@ -171,6 +193,14 @@ def adopt_pages(mesh, cache: PagedKVCache, dst: tp.Sequence[int], blocks: Blocks
     )
 
 
+def keep_state(new, old):
+    """`new` with `old`'s state rows: what a program that must commit nothing
+    hands back (`ServeEngine.next_logits`: a K/V write is repeated by the round
+    that follows, a state update applied twice is a wrong state). A cache
+    without a state kind as it is."""
+    return dataclasses.replace(new, state=old.state) if hasattr(old, "state") else new
+
+
 class PagePool:
     """The pools, allocators and books of one engine (module docstring).
     Takes what `ServeEngine` was given; adds no option of its own."""
@@ -194,7 +224,14 @@ class PagePool:
     ):
         self.config = config
         model = config.model()
-        self.kinds = model.cache_kinds(config)
+        kinds = model.cache_kinds(config)
+        self.kinds = tuple(k for k in kinds if isinstance(k, CacheKind))  # the PAGED kinds
+        self.state_kinds = tuple(k for k in kinds if not isinstance(k, CacheKind))
+        self.max_slots = max_slots
+        # state row i is slot i's; `state_held[i]`: whether a slot holds it now
+        self.state_held = [False] * max_slots
+        self.state_resets = 0
+        self.state_rows_live_max = 0
         self.page_size = page_size
         self.cache_dtype = cache_dtype
         self.kernel_layout = kernel_layout
@@ -237,9 +274,11 @@ class PagePool:
         # stats()["window_reclaimed_pages"]); the most pages one slot ever held.
         self.kind_reclaimed = [0] * len(self.kinds)
         self.kind_slot_pages_max = [0] * len(self.kinds)
+        # a state kind's count, after the paged kinds': its rows, the slots' and the sink
         self.cache = self._put(
             model.init_cache(
-                config, pool_pages, page_size, cache_dtype, kernel_layout=kernel_layout
+                config, pool_pages + [max_slots + 1] * len(self.state_kinds), page_size, cache_dtype,
+                kernel_layout=kernel_layout,
             )
         )
         # A layer-prefix self-draft needs no pool of its own: draft layer i
@@ -290,14 +329,30 @@ class PagePool:
         held = len(slot.pages[kind]) - max(0, slot.reclaimed_to[kind] - -(-k.sinks // self.page_size))
         self.kind_slot_pages_max[kind] = max(self.kind_slot_pages_max[kind], held)
 
+    def claim_state(self, slot, index: int) -> None:
+        """Slot `index` is admitted: where the family has a state kind, its
+        row (row `index`) is held by `slot` until `release`, and reset by the
+        request's first prefill chunk (module docstring). A family without
+        one: nothing."""
+        if not self.state_kinds:
+            return
+        assert not self.state_held[index], f"state row {index} is held by another slot"
+        self.state_held[index] = True
+        slot.state_row = index
+        self.state_resets += 1
+        self.state_rows_live_max = max(self.state_rows_live_max, sum(self.state_held))
+
     def release(self, slot) -> None:
-        """The ONE funnel a departing slot's pages go through (finish,
-        cancel, timeout, preemption). Cache off: straight back to the
-        allocator. Cache on: the trie drops the slot's shared-page refs,
+        """The ONE funnel a departing slot's pages (and its state row) go
+        through (finish, cancel, timeout, preemption). Cache off: straight back
+        to the allocator. Cache on: the trie drops the slot's shared-page refs,
         absorbs its complete committed pages for future matches, and only
         the remainder (partial tails, content-duplicates) hits the free
         list — page conservation becomes free_count + trie pages ==
         num_pages - 1 (`conserved`)."""
+        if self.state_kinds and slot.state_row >= 0:
+            self.state_held[slot.state_row] = False
+            slot.state_row = -1
         if self.prefix_cache is None:
             # -1 entries are window-reclaimed placeholders (already freed)
             for allocator, pages in zip(self.allocators, slot.pages):
@@ -355,7 +410,9 @@ class PagePool:
         """Per kind, the terms of the conservation law: pages `free`, held by
         the `trie` (the first kind's, where there is a prefix cache),
         `live_only` (held by a slot and not by the trie) and `allocatable`
-        (`num_pages - 1`: page 0 is the sink)."""
+        (`num_pages - 1`: page 0 is the sink). A state kind's entry counts
+        ROWS: `free` by this pool's books, `live_only` by what the slots say
+        they hold, `allocatable` the slot count (the sink row apart)."""
         pc = self.prefix_cache
         out = []
         for k, (kind, a, live) in enumerate(zip(self.kinds, self.allocators, self.live_pages(slots))):
@@ -364,11 +421,18 @@ class PagePool:
                 "kind": kind.name, "free": a.free_count, "trie": len(held),
                 "live_only": len(live - held), "allocatable": a.num_pages - 1,
             })
+        rows = {s.state_row for s in slots if s is not None and s.state_row >= 0} if self.state_kinds else ()
+        for kind in self.state_kinds:
+            out.append({
+                "kind": kind.name, "free": self.state_held.count(False), "trie": 0,
+                "live_only": len(rows), "allocatable": self.max_slots,
+            })
         return out
 
     def conserved(self, slots) -> bool:
         """THE conservation law, of every kind: free + trie-held + live-only
-        == num_pages - 1. It holds between any two calls of the engine."""
+        == num_pages - 1 (a state kind: rows free + rows live == the slots).
+        It holds between any two calls of the engine."""
         return all(
             t["free"] + t["trie"] + t["live_only"] == t["allocatable"]
             for t in self.ledger(slots)
@@ -380,7 +444,12 @@ class PagePool:
         frees a page mid-request, so it has no such counter),
         `kv.<kind>_pages_reclaimed` (freed by the window rule so far) and
         `kv.<kind>_tokens_per_slot_max` (the most one slot ever held, in
-        tokens: bounded by window + the longest write + a page)."""
+        tokens: bounded by window + the longest write + a page). With a state
+        kind: `state.rows` (the slots'), `state.rows_live` (held now; `_max`:
+        at the peak), `state.resets` (admissions that took a row: each is
+        reset by the request's first prefill chunk) and
+        `state.bytes_per_slot` (one row of every state array, as the arrays
+        declare it; what the device's tiling adds is not in an array's size)."""
         out: tp.Dict[str, float] = {}
         for i, (k, a) in enumerate(zip(self.kinds, self.allocators)):
             out[f"kv.{k.name}_pages_live"] = a.num_pages - 1 - a.free_count
@@ -390,6 +459,12 @@ class PagePool:
                 out[f"kv.{k.name}_tokens_per_slot_max"] = (
                     self.kind_slot_pages_max[i] * self.page_size
                 )
+        if self.state_kinds:
+            out["state.rows"] = self.max_slots
+            out["state.rows_live"] = sum(self.state_held)
+            out["state.rows_live_max"] = self.state_rows_live_max
+            out["state.resets"] = self.state_resets
+            out["state.bytes_per_slot"] = sum(a.nbytes // a.shape[1] for a in self.cache.state)
         return out
 
     def hbm_bytes(self) -> int:
@@ -439,7 +514,9 @@ class PagePool:
         does): the (slots, n_pages) table of the first kind, or, where the
         family has several kinds, the tuple of every kind's. `rows`: those
         slots' rows alone, in that order, then empty rows (the sink page) up
-        to `prefill_width`."""
+        to `prefill_width`. Where the family has a state kind the tuple ends
+        with each table row's STATE ROW, (rows,) int32: the slot's, or the
+        sink row (`max_slots`) for an empty slot or place."""
         def table(kind: int) -> np.ndarray:
             full = self.table(slots, n_pages, kind)
             if rows is None:
@@ -448,9 +525,21 @@ class PagePool:
             picked[: len(rows)] = full[list(rows)]
             return picked
 
-        if len(self.kinds) == 1:
+        if len(self.kinds) == 1 and not self.state_kinds:
             return table(0)
-        return tuple(table(k) for k in range(len(self.kinds)))
+        tables = tuple(table(k) for k in range(len(self.kinds)))
+        if not self.state_kinds:
+            return tables
+        picked = list(range(len(slots))) if rows is None else list(rows)
+        state_rows = np.full((len(tables[0]),), self.max_slots, np.int32)
+        for r, i in enumerate(picked):
+            if slots[i] is not None and slots[i].state_row >= 0:
+                state_rows[r] = slots[i].state_row
+                # a decode round's table is the slots in order, and its program updates rows [0, slots) where they
+                # lie without reading this vector (models/olmo_hybrid.py `decode_step_paged`): hold the rows to it
+                if rows is None and state_rows[r] != r:
+                    raise RuntimeError(f"slot {i} holds state row {state_rows[r]}: a decode round takes slot i's state from row i")
+        return (*tables, state_rows)
 
     # -- whole-pool operations (one kind of page) --------------------------
 

@@ -145,12 +145,12 @@ from midgpt_tpu.kernels.attention_template import (
     normalize_split_k,
 )
 from midgpt_tpu.kernels.decode_attention import resolve_paged_impl
-from midgpt_tpu.models.gpt import GPTConfig, GPTParams, PagedKVCache
+from midgpt_tpu.models.gpt import CacheKind, GPTConfig, GPTParams, PagedKVCache
 from midgpt_tpu.obs import DISABLED_SNAPSHOT, Observability
 from midgpt_tpu.obs.trace import NULL_TRACER
 from midgpt_tpu.robustness import faults
 from midgpt_tpu.sampling.engine import sample_logits, warp_logits
-from midgpt_tpu.sampling.pages import PageAllocator, PagePool, adopt_pages, join_pages
+from midgpt_tpu.sampling.pages import PageAllocator, PagePool, adopt_pages, join_pages, keep_state
 from midgpt_tpu.sampling.prefix_cache import PrefixCache
 from midgpt_tpu.sampling.scheduler import FCFSScheduler, Scheduler
 from midgpt_tpu.sampling.spec import speculative_accept
@@ -408,13 +408,15 @@ def _serve_decode_logits(
     attn_impl: str, mesh=None, split_k: int = 1,
 ):
     """`_serve_decode_chunk`'s step ONCE, handing out its logits and sampling
-    nothing (`ServeEngine.next_logits`: checks, not the serving loop). Returns
-    (logits (B, V), cache)."""
-    logits, cache = config.model().decode_step_paged(
+    nothing (`ServeEngine.next_logits`: checks, not the serving loop). The
+    K/V it writes are the ones the round that follows writes again; a STATE
+    row is handed back as it came in (`pages.keep_state`: the round would
+    apply the step a second time). Returns (logits (B, V), cache)."""
+    logits, stepped = config.model().decode_step_paged(
         config, params, token, cache, page_table, lengths, active,
         attn_impl=attn_impl, mesh=mesh, split_k=split_k,
     )
-    return logits, _maybe_constrain(cache, mesh)
+    return logits, _maybe_constrain(keep_state(stepped, cache), mesh)
 
 
 # Rows a prefill program should bring to each weight it reads. A bf16 matmul
@@ -770,6 +772,9 @@ class _Slot:
     # insert_live shares only complete prompt pages, while every write
     # after admission lands at a position >= length >= the shared span.
     n_shared: int = 0
+    # the slot's row of the family's STATE kind (sampling/pages.py
+    # `claim_state` writes it, `release` takes it back); -1: none
+    state_row: int = -1
     generated: tp.List[int] = dataclasses.field(default_factory=list)
     token_times: tp.List[float] = dataclasses.field(default_factory=list)
     # speculative-decoding state (draft engines only): current per-slot
@@ -890,9 +895,15 @@ class ServeEngine:
         # that knows one kind reads (`allocator`, `slot.pages[0]`, `num_pages`).
         self.config = config
         self.model = config.model()
-        self.kinds = self.model.cache_kinds(config)
-        if len(self.kinds) > 1:
-            # what is not wired over several kinds of pages, by mechanism
+        # A kind that is no `CacheKind` is a STATE kind, a row a slot
+        # (sampling/pages.py "State kinds"): the pool owner's, and here only
+        # what is refused for it.
+        kinds = self.model.cache_kinds(config)
+        self.kinds = tuple(k for k in kinds if isinstance(k, CacheKind))
+        self.state_kinds = tuple(k for k in kinds if not isinstance(k, CacheKind))
+        if len(self.kinds) > 1 or self.state_kinds:
+            # what is not wired over several kinds of pages, or beside rows
+            # of state that no page mechanism can move, by mechanism
             for on, what in (
                 (prefix_cache, "the prefix cache (pages of several kinds under one trie)"),
                 (draft_params is not None or draft_config is not None,
@@ -1402,14 +1413,21 @@ class ServeEngine:
 
     def _refuse_several_kinds(self, mechanism: str) -> None:
         """What is not wired for a family whose layers need several kinds of
-        cache stops here, by mechanism (the constructor; hot_swap, resize,
-        attach_spill; sampling/disagg.py)."""
+        cache, or a STATE kind (a row a slot: what moves, shares, snapshots or
+        quantises pages knows nothing of it), stops here, by mechanism (the
+        constructor; hot_swap, resize, attach_spill; sampling/disagg.py)."""
+        family = getattr(self.config, "family", "gpt")
         if len(self.kinds) > 1:
             raise NotImplementedError(
                 f"ServeEngine: {mechanism} is not wired for a model with "
                 f"{len(self.kinds)} kinds of paged cache "
                 f"({', '.join(k.name for k in self.kinds)}: family "
-                f"{getattr(self.config, 'family', 'gpt')!r})"
+                f"{family!r})"
+            )
+        if self.state_kinds:
+            raise NotImplementedError(
+                f"ServeEngine: {mechanism} is not wired for a model with a STATE kind of cache "
+                f"({', '.join(k.name for k in self.state_kinds)}: a row a slot and not a page a token; family {family!r})"
             )
 
     def hot_swap(
@@ -2203,6 +2221,7 @@ class ServeEngine:
                     if self.spill_tier is not None:
                         self._readopt_from_spill(slot, req)
                 self.slots[i] = slot
+                self.pool.claim_state(slot, i)  # a family with a state kind: row i
                 self._admitted += 1
                 self._trace.instant(
                     "admitted", "lifecycle", self._obs_tid,

@@ -933,3 +933,70 @@ def test_serving_experts_are_one_mosaic_call_at_the_published_widths(family, row
         arr((rows, D)), arr((rows, 8), jnp.int32), arr((rows, 8), jnp.float32),
         arr((held, F, D)), arr((held, F, D)), arr((held, D, F))).compile().as_text()
     assert text.count("tpu_custom_call") == 1 and " while(" not in text
+
+
+@pytest.mark.parametrize("program", ["decode8", "prefill512"])
+def test_state_kind_serving_programs_keep_pools_and_state_rows_in_place(program, one_chip, compiled_kernels, monkeypatch):
+    """models/olmo_hybrid.py as the benchmark's cell runs it (16 layers at the
+    published widths: 30 heads of 96 x 192 in a linear layer, 30 of 128 in a
+    full one; 24 slots and the sink row; pages of 32): the decode program (8
+    steps, a table of 128 pages, split-K 8) and the one-row prefill program (a
+    chunk of 512 through a table of 512 pages) compile for the v5e; neither
+    copies or relays out a K/V pool OR the state arrays (the delta-rule states
+    lie as the chunked kernels hold them, (d_v, d_k): stored the other way the
+    prefill program relaid the WHOLE 0.88 GB of rows out and back a call); the
+    prefill's scan is the Pallas kernel under `linear_state`, three calls a
+    period, its attention four 128-row calls a full layer, inside a scope of
+    their own (`prefill_attn`, the decode kernel's `attn_global` alone); and the programs'
+    temporaries stay under 0.25 GB beside 14.4 GB of weights, pools and rows (a
+    period's matrices handed to the loop as its scanned input were copied out
+    of the stack first: 0.8 and 1.3 GB of temporaries)."""
+    import dataclasses
+    import re
+
+    import midgpt_tpu.ops.kda as ops_kda
+    from midgpt_tpu.analysis.hlo_audit import pool_relayouts
+    from midgpt_tpu.config import load_config
+    from midgpt_tpu.sampling import serve
+
+    kk = importlib.import_module("midgpt_tpu.kernels.kda")
+    monkeypatch.setattr(kk, "_interpret", lambda: False)
+    # `kda_chunked` asks the backend, which here is the CPU under a described TPU: steer it to the kernels
+    monkeypatch.setattr(ops_kda.jax, "default_backend", lambda: "tpu")
+    mc = dataclasses.replace(load_config("olmo_hybrid_7b").model_config, n_layer=16, block_size=16384)
+    model = mc.model()
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(sds, jax.eval_shape(lambda k: model.cast_params(model.init(mc, k), jnp.bfloat16), jax.random.PRNGKey(0)))
+    B = 24
+    cache = jax.tree.map(sds, jax.eval_shape(lambda: model.init_cache(mc, (B * 112 + 1, B + 1), 32, jnp.bfloat16, kernel_layout=True)))
+    assert cache.k.shape == (4, 30, 2689, 32, 128) and [a.shape for a in cache.state] == [(12, 25, 30, 192, 96), (12, 25, 3 * 11520)]
+    arr = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    if program == "decode8":
+        lowered = serve._serve_decode_chunk.lower(
+            mc, params, arr((B,)), cache, (arr((B, 128)), arr((B,))), arr((B,)), arr((B,), jnp.bool_), 8,
+            0.8, None, None, "kernel", arr((2,), jnp.uint32), None, 8)
+    else:
+        lowered = serve._serve_prefill_chunk.lower(
+            mc, params, arr((1, 512)), arr(()), arr(()), cache, (arr((1, 512)), arr((1,))), None, "kernel",
+            0.8, None, None, arr((2,), jnp.uint32))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    paths = re.findall(r'custom-call\([^\n]*tpu_custom_call[^\n]*?op_name="([^"]*)"', text)
+    assert sum("kv_write" in p for p in paths) == 1  # one rolled period: the full layer's in-place write
+    attention = [p for p in paths if "attn_global" in p and "kv_write" not in p]
+    scans = [p for p in paths if "linear_state" in p]
+    # the prefill's attention calls sit in an innermost scope of their own and are named after it (what
+    # benchmarks/metrics/prefill_attention.py sums, and what keeps them out of the decode kernel's roofline:
+    # serve_kinds_scopes.py puts a custom call to the innermost listed scope of its path)
+    named = re.findall(r"%(prefill_attn[\w.]*) = [^\n]*custom-call\(", text)
+    if program == "decode8":
+        assert len(attention) == 1 and not scans  # the one-token update is XLA: two sweeps of the rows' states and one write
+        assert "prefill_attn" not in attention[0] and not named
+    else:
+        assert len(attention) == 4 and len(scans) == 3 and all("/attn_linear/linear_state/" in p and "kda_scan" in p for p in scans)
+        assert all("/attn_global/prefill_attn/" in p for p in attention) and len(named) == 4, (attention, named)
+        assert all(re.fullmatch(r"prefill_attn\.\d+", n) for n in named), named
+    assert len(paths) == 1 + len(attention) + len(scans)
+    assert pool_relayouts(text, [a.shape for a in cache.pool_arrays()]) == 0
+    assert pool_relayouts(text, [a.shape for a in cache.state]) == 0
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.25e9

@@ -434,16 +434,25 @@ def test_the_traffic_is_one_multiset_for_every_seed_and_the_benchmark_only_adds(
     assert check[0] < 513 < 513 + 512 < check[1] < 2048 < check[2] < 4096 < 2 * 2048 + 512 < check[3]
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
-    assert cell == bench["workloads"][-1] and (cell["config"], cell["traffic"], cell["chips"]) == ("dots3_note_ep16", "longctx_sparse_closed", 1)
-    assert bench["configs"][-1]["name"] == "dots3_note_ep16" and bench["configs"][-1]["reduced"] == [
+    # ORDER, not position: this cell's entries are one run each, after Trinity's (the PR before it appended those); what
+    # later PRs append after them is theirs to pin
+    names = [w["name"] for w in bench["workloads"]]
+    cell = bench["workloads"][names.index(CELL)]
+    assert names.index(CELL) == names.index("serve_trinity_mini_reason") + 1
+    assert (cell["config"], cell["traffic"], cell["chips"]) == ("dots3_note_ep16", "longctx_sparse_closed", 1)
+    configs = [c["name"] for c in bench["configs"]]
+    assert configs.index("dots3_note_ep16") == configs.index("trinity_mini_pp") + 1
+    assert bench["configs"][configs.index("dots3_note_ep16")]["reduced"] == [
         "num_hidden_layers", "layer_types", "n_routed_experts", "vocab_size"]
     assert {m["name"] for m in bench["end_to_end"] if CELL in m.get("workloads", [CELL])} == {"setup_s", "serve_tokens_per_s"}
     mine = [m for m in bench["per_layer"] if m.get("workloads") == [CELL]]
     at = bench["per_layer"].index(mine[0])  # one run of entries, in order, as PR 51 appended them (later PRs append after)
     assert mine == bench["per_layer"][at:at + len(mine)] and len(mine) == 10
     assert all(m["moves"] == "serve_tokens_per_s" for m in mine)
-    assert all(m["workloads"][-1] == CELL for m in bench["per_layer"] if CELL in m["workloads"])
+    for m in bench["end_to_end"] + bench["per_layer"]:  # in every list it joined, right after the cell before it
+        w = m.get("workloads", [])
+        if CELL in w and "serve_trinity_mini_reason" in w:
+            assert w.index(CELL) == w.index("serve_trinity_mini_reason") + 1, m["name"]
 
 
 def test_both_controls_are_refused_by_the_cells_own_limits(tmp_path):

@@ -146,7 +146,7 @@ def test_chunked_kda_matches_the_recurrence(T, decay, body):
     o_r, s_r = kda_recurrent(*args)
     o_c, s_c = chunked(*args)
     np.testing.assert_allclose(o_c, o_r, atol=2e-6)
-    np.testing.assert_allclose(s_c, s_r, atol=2e-6)
+    np.testing.assert_allclose(s_c, jnp.swapaxes(s_r, 2, 3), atol=2e-6)  # a carried state is (d_v, d_k)
     scalar = lambda fn: lambda *a: jnp.sum(fn(*a)[0] ** 2) + jnp.sum(fn(*a)[1])
     g_r = jax.grad(scalar(kda_recurrent), argnums=(0, 1, 2, 3, 4))(*args)
     g_c = jax.grad(scalar(chunked), argnums=(0, 1, 2, 3, 4))(*args)
@@ -173,19 +173,21 @@ def test_the_kda_kernel_starts_from_a_state_carried_in():
     """The kernels take the state IN (a chunked-prefill step will start from a
     slot's state): the last 100 tokens from the state the first 90 left are
     the last 100 of all 190, values, final state and the gradients of the
-    tokens and of the state carried in."""
+    tokens and of the state carried in. The oracle's state is the equations'
+    (d_k, d_v); carried, it is (d_v, d_k)."""
     args = _kda_inputs(11, 190, 0.3)
     head, tail = (a[:, :90] for a in args), tuple(a[:, 90:] for a in args)
+    T_ = lambda s: jnp.swapaxes(s, 2, 3)
     o_all, s_all = kda_recurrent(*args)
     _, s_head = kda_recurrent(*head)
-    o, s = _kda_kernel(*tail, initial_state=s_head)
+    o, s = _kda_kernel(*tail, initial_state=T_(s_head))
     np.testing.assert_allclose(o, o_all[:, 90:], atol=2e-6)
-    np.testing.assert_allclose(s, s_all, atol=2e-6)
+    np.testing.assert_allclose(s, T_(s_all), atol=2e-6)
 
     scalar = lambda fn: lambda s0, *a: jnp.sum(fn(*a, initial_state=s0)[0] ** 2) + jnp.sum(fn(*a, initial_state=s0)[1])
     g_r = jax.grad(scalar(kda_recurrent), argnums=tuple(range(6)))(s_head, *tail)
-    g_k = jax.grad(scalar(_kda_kernel), argnums=tuple(range(6)))(s_head, *tail)
-    for a, b in zip(g_k, g_r):
+    g_k = jax.grad(scalar(_kda_kernel), argnums=tuple(range(6)))(T_(s_head), *tail)
+    for a, b in zip(g_k, (T_(g_r[0]),) + g_r[1:]):
         assert float(jnp.abs(a - b).max()) <= 2e-5 * float(jnp.abs(b).max())
 
 

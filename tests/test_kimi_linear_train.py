@@ -14,6 +14,7 @@ import pytest
 
 from midgpt_tpu.config import from_json, to_json
 from test_kimi_linear import ROOT, tiny, tiny_experiment
+from rehearsal_tree import run_rehearsal
 
 
 def test_serving_entry_points_refuse_the_checkpoint(tmp_path):
@@ -67,11 +68,7 @@ def test_benchmark_cell_rehearses_on_the_cpu(tmp_path):
     assert {"step.kda_ms", "step.kda_scan_ms", "step.mla_ms", "step.moe_route_ms", "step.moe_experts_ms",
             "train.mfu_hybrid", "mla_attention_ms_per_step", "mla_attention_roofline",
             "moe.load_max_over_mean", "moe.overflowed", "setup.programs"} <= declared
-    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"), JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"), "--workload", "train_kimi_linear_t8k",
-         "--seed", "3000000019", "--seconds", "2", "--trace", "1", "--rehearse-cpu"],
-        cwd=ROOT, env=env, capture_output=True, text=True, timeout=900)
+    proc = run_rehearsal(tmp_path, "train_kimi_linear_t8k", seconds="2")  # a tree of its own: tests/rehearsal_tree.py
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
     last = json.loads(proc.stdout.strip().splitlines()[-1])
     assert last["rehearsal"] and last["correct"]

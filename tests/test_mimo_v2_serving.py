@@ -17,6 +17,7 @@ from midgpt_tpu.models.gpt import GPT, GPTConfig
 from midgpt_tpu.models.mimo_v2 import GLOBAL, WINDOW, MimoV2
 from midgpt_tpu.sampling.serve import ServeEngine
 from test_mimo_v2 import ROOT, _load, _tokens, model, toy  # noqa: F401 (model: the module-scoped fixture)
+from rehearsal_tree import run_rehearsal
 
 
 # ---------------------------------------------------------------------------
@@ -303,7 +304,7 @@ def test_the_benchmark_share_counts_what_the_issue_reckoned():
 def test_kimi_linear_says_what_serving_it_still_lacks():
     from midgpt_tpu.config import load_config
 
-    with pytest.raises(NotImplementedError, match="recurrent KDA state.*STATE kind"):
+    with pytest.raises(NotImplementedError, match="STATE kind of cache.*STILL missing for this family"):
         load_config("kimi_linear_48b_a3b").model_config.check_serving("sample.py")
 
 
@@ -342,11 +343,7 @@ def test_benchmark_cell_rehearses_on_the_cpu(tmp_path):
     assert not {"paged_attention_ms_per_token", "paged_attention_roofline"} & declared
     e2e = {m["name"] for m in bench["end_to_end"] if cell in m.get("workloads", [cell])}
     assert e2e == {"setup_s", "serve_tokens_per_s"}
-    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"), JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"), "--workload", cell,
-         "--seed", "3000000019", "--seconds", "2", "--trace", "1", "--rehearse-cpu"],
-        cwd=ROOT, env=env, capture_output=True, text=True, timeout=900)
+    proc = run_rehearsal(tmp_path, cell, seconds="2")  # a tree of its own: tests/rehearsal_tree.py
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
     last = json.loads(proc.stdout.strip().splitlines()[-1])
     assert last["rehearsal"] and last["correct"] and last["failed"] == 0

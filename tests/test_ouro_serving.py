@@ -18,6 +18,7 @@ import pytest
 from midgpt_tpu.models.ouro import Ouro
 from midgpt_tpu.sampling.serve import ServeEngine
 from test_ouro import ROOT, _load, _tokens, model, reference, seeded, toy  # noqa: F401 (model: the module-scoped fixture)
+from rehearsal_tree import run_rehearsal
 
 _APPLY = jax.jit(Ouro.apply, static_argnums=0)
 
@@ -254,11 +255,7 @@ def test_benchmark_cell_rehearses_on_the_cpu(tmp_path):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     declared = {m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", [])}
-    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"), JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"), "--workload", CELL,
-         "--seed", "3000000019", "--seconds", "1", "--trace", "1", "--rehearse-cpu"],
-        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+    proc = run_rehearsal(tmp_path, CELL, seconds="1", timeout=600)  # a tree of its own: tests/rehearsal_tree.py
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
     last = json.loads(proc.stdout.strip().splitlines()[-1])
     assert last["rehearsal"] and last["correct"] and last["failed"] == 0

@@ -160,6 +160,7 @@ def _replace_nested(obj, tree):
     ("serve_mimo_v2_5_mixed", [8705, 673]),
     ("serve_pangu_ultra_longctx", [8449]),
     ("serve_ouro_reason", [157]),
+    ("serve_olmo_hybrid_docchat", [2689]),  # PR 59: 24 x 112 pages + the sink; its 25 state rows are no allocator's
 ])
 def test_sizing_rule_gives_the_serving_cells_their_page_counts(cell, pages, monkeypatch):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
@@ -180,3 +181,4 @@ def test_sizing_rule_gives_the_serving_cells_their_page_counts(cell, pages, monk
         cache_dtype=jnp.dtype(jnp.bfloat16), kernel_layout=True, prefill_width=1,
     )
     assert [a.num_pages for a in pool.allocators] == pages
+    assert len(pool.state_kinds) == (cell == "serve_olmo_hybrid_docchat") and len(pool.kinds) == len(pages)

@@ -17,6 +17,7 @@ import pytest
 from midgpt_tpu.models.pangu_ultra import PanguUltra
 from midgpt_tpu.sampling.serve import ServeEngine
 from test_pangu_ultra import ROOT, _load, _tokens, model, reference, toy  # noqa: F401 (model: the module-scoped fixture)
+from rehearsal_tree import run_rehearsal
 
 _APPLY = jax.jit(PanguUltra.apply, static_argnums=0)
 
@@ -140,13 +141,15 @@ def test_what_is_not_wired_over_a_latent_cache_is_refused(model, what, kw):
 
 
 def test_kimi_linear_says_what_serving_it_still_lacks():
-    """With a latent pool and the absorbed decode path in the tree, what the
-    `kimi_linear` family still lacks is the per-slot STATE kind."""
+    """With a latent pool, the absorbed decode path and (since PR 59) a STATE
+    kind with its two delta-rule steps in the tree, what the `kimi_linear` family
+    still lacks is a NoPE latent row and its own serving members."""
     from midgpt_tpu.config import load_config
 
-    with pytest.raises(NotImplementedError, match="recurrent KDA state") as e:
+    with pytest.raises(NotImplementedError, match="STILL missing for this family: a NoPE variant of the latent row") as e:
         load_config("kimi_linear_48b_a3b").model_config.check_serving("sample.py")
     assert "models/pangu_ultra.py" in str(e.value) and "no absorbed-latent" not in str(e.value)
+    assert "models/olmo_hybrid.py" in str(e.value) and "sampling/pages.py" in str(e.value)
 
 
 # ---------------------------------------------------------------------------
@@ -202,11 +205,7 @@ def test_benchmark_cell_rehearses_on_the_cpu(tmp_path):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     declared = {m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", [])}
-    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"), JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"), "--workload", CELL,
-         "--seed", "3000000019", "--seconds", "1", "--trace", "1", "--rehearse-cpu"],
-        cwd=ROOT, env=env, capture_output=True, text=True, timeout=900)
+    proc = run_rehearsal(tmp_path, CELL, seconds="1")  # a tree of its own: tests/rehearsal_tree.py
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
     last = json.loads(proc.stdout.strip().splitlines()[-1])
     assert last["rehearsal"] and last["correct"] and last["failed"] == 0

@@ -24,6 +24,7 @@ from midgpt_tpu.obs import STEP_SCOPES, Observability
 from midgpt_tpu.obs.trace import NULL_TRACER, Tracer
 from midgpt_tpu.sampling.serve import ServeEngine
 from midgpt_tpu.utils import compile_cache
+from rehearsal_tree import run_rehearsal
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CFG = GPTConfig(block_size=64, vocab_size=64, n_layer=2, n_head=2, n_embd=32)
@@ -870,11 +871,7 @@ def test_setup_programs_reader(monkeypatch):
                                   "serve.moe_visits_per_expert_touched", "engine.occupancy", "setup.programs"}),
 ])
 def test_rehearsal_lists_the_new_metrics(cell, names, tmp_path):
-    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"), JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"), "--workload", cell, "--seed",
-         "3000000019", "--seconds", "2", "--trace", "1", "--rehearse-cpu"],
-        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+    proc = run_rehearsal(tmp_path, cell, seconds="2", timeout=600)  # a tree of its own: tests/rehearsal_tree.py
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
     last = json.loads(proc.stdout.strip().splitlines()[-1])
     assert last["rehearsal"] and last["correct"]
