@@ -61,9 +61,30 @@ Attention on the paged path:
   full, prefill    the chunk's rows and index keys are written first; I for the
                    chunk's rows against the whole context in key blocks; each
                    row's k-th largest score by 32 counting passes over the
-                   scores' bits (`kth_largest`: exact, no sort); then
-                   pangu_ultra's sweep over blocks of 1,024 latents EXPANDED to
-                   K and V, the selection as a MASK (dense FLOPs, no gather).
+                   scores' bits (`kth_largest`: exact, no sort); then the
+                   slot's cached latents swept in blocks of 1,024, each block
+                   EXPANDED to K and V, the selection as a MASK (dense FLOPs,
+                   no gather of selected rows). On a TPU that sweep is ONE
+                   Mosaic call a layer (kernels/latent_prefill.py, PR 60): grid
+                   (64 groups of 2 heads, key blocks innermost); a step holds
+                   in VMEM one block of the slot's rows (from one contiguous
+                   copy gathered through the page table before the call; the
+                   pool is not copied), the group's slices of W_kvb and of the
+                   queries, a head's expanded (1,024, 256) K_n and V, its (512,
+                   1,024) float32 score tile and probabilities, and the
+                   group's running (m, l, acc) across its sweep: no score,
+                   probability or expanded tile reaches HBM (as an XLA loop
+                   they were ~0.9 GB a block and the sweep was HBM-bound). The
+                   selection AND each row's visibility arrive as ONE int8
+                   operand `keep` (T, S) built from `kth_largest`'s (thr, need)
+                   by `selection_mask` (ties to the lower position), not as
+                   per-row scalars: 512 stacked scalar counts are refused by
+                   Mosaic; only the live block count rides scalar prefetch.
+                   Not a spec of kernels/attention_template.py, whose body
+                   scores pool rows as stored and copies pages by hand: a
+                   sibling file leaves the decode kernel's text as it is.
+                   Off the TPU: `_prefill_sparse_sweep`, the same arithmetic
+                   as an XLA loop (the tests' oracle).
   window, decode   kernels/attention_template.py with `v_lanes` AND
                    `sliding_window` over the window kind's own logical table
                    (TPU; absorbed: 64 query rows against one pool head of 1,152
@@ -409,6 +430,29 @@ def selected(key: Array, thr: Array, need: Array, eq_before: Array) -> tp.Tuple[
     eq = key == thr[:, None]
     rank = eq_before[:, None] + jnp.cumsum(eq, axis=1, dtype=jnp.int32)
     return (key > thr[:, None]) | (eq & (rank <= need[:, None])), rank[:, -1]
+
+
+def selection_mask(scores: Array, thr: Array, need: Array, block: int = KEY_BLOCK) -> Array:
+    """`selected` over WHOLE rows at once, from the index scores (R, S) whose
+    `sortable_bits` gave `thr` and `need` -> (R, S) bool, with no (R, S) count:
+    among a row's columns EQUAL to `thr` the first `need` are those up to the
+    column of the need-th one, found in two steps (the equal columns counted a
+    block of `block`, then a cumulative count inside the one block where the
+    need-th lies). The bits are formed again wherever they are compared, and
+    the one block a row is GATHERED from the scores: a gather of the bits would
+    keep a second (R, S) copy of them alive beside `kth_largest`'s."""
+    R, S = scores.shape
+    block = min(block, S)
+    blocks = jnp.pad(scores, ((0, 0), (0, -S % block)), constant_values=-jnp.inf).reshape(R, -1, block)  # padding lies past every real column
+    tot = jnp.sum(sortable_bits(blocks) == thr[:, None, None], axis=2, dtype=jnp.int32)  # (R, blocks)
+    upto = jnp.cumsum(tot, axis=1)
+    b = jnp.argmax(upto >= need[:, None], axis=1)  # the first block that reaches `need`
+    left = need - jnp.take_along_axis(upto - tot, b[:, None], axis=1)[:, 0]  # equal columns still to take inside it
+    inner = jnp.cumsum(sortable_bits(jnp.take_along_axis(blocks, b[:, None, None], axis=1)[:, 0]) == thr[:, None],
+                       axis=1, dtype=jnp.int32)
+    cut = b * block + jnp.argmax(inner >= left[:, None], axis=1)  # the column of the need-th equal entry
+    key, col = sortable_bits(scores), jnp.arange(S, dtype=jnp.int32)
+    return (key > thr[:, None]) | ((key == thr[:, None]) & (col[None, :] <= cut[:, None]))
 
 
 class Dots3:
@@ -916,10 +960,10 @@ class Dots3:
                     with jax.named_scope("dsa_index"):
                         scores = Dots3._index_sweep(c, qi, w, idx, li, tables[kind], counts[None])[0]  # (T, S)
                     with jax.named_scope("dsa_topk"):
-                        bits = sortable_bits(scores)
-                        thr, need = kth_largest(bits, min(c.index_topk, bits.shape[1]))
+                        thr, need = kth_largest(sortable_bits(scores), min(c.index_topk, scores.shape[1]))
                     with jax.named_scope("attn_select"):
-                        o = Dots3._prefill_sparse_sweep(g, p.attn, q[0], lat, li, tables[kind][0], counts, bits, thr, need)[None]
+                        sweep = Dots3._prefill_sparse_kernel if attn_impl == "kernel" else Dots3._prefill_sparse_sweep
+                        o = sweep(g, p.attn, q[0], lat, li, tables[kind][0], counts, scores, thr, need)[None]
                 else:
                     wlat = write(wlat, rows)
                     o = Dots3._window_gather_attention(g, p.attn, q, wlat, li, win_ids, (first * ps)[None], counts[None])
@@ -932,21 +976,37 @@ class Dots3:
                                                           moe_totals=totals, dsa=cache.dsa)
 
     @staticmethod
+    def _prefill_sparse_kernel(g: Geom, p: MLAParams, q: Array, pool: Array, li: int, table_row: Array, counts: Array,
+                               scores: Array, thr: Array, need: Array) -> Array:
+        """`_prefill_sparse_sweep` as ONE Mosaic call (kernels/latent_prefill.py),
+        from the index SCORES (T, MP * ps) whose sortable bits gave `thr` and
+        `need`: the slot's visible rows gathered once through the table, the
+        selection AND each row's visibility folded into one int8 `keep` (T, MP
+        * ps), and every block expanded, scored, masked and summed in VMEM.
+        -> (T, H, v)."""
+        from midgpt_tpu.kernels.latent_prefill import latent_prefill_attention, slot_rows
+
+        keep = selection_mask(scores, thr, need) & (jnp.arange(scores.shape[1], dtype=jnp.int32)[None, :] < counts[:, None])
+        return latent_prefill_attention(
+            q, slot_rows(pool, li, table_row, counts[-1]), p.w_kvb.reshape(g.n_head, g.nope + g.v, g.kv_rank), counts[-1],
+            keep, nope=g.nope, scale=1.0 / math.sqrt(g.qk))
+
+    @staticmethod
     def _prefill_sparse_sweep(g: Geom, p: MLAParams, q: Array, pool: Array, li: int, table_row: Array, counts: Array,
-                              bits: Array, thr: Array, need: Array) -> Array:
+                              scores: Array, thr: Array, need: Array) -> Array:
         """A chunk's rows q (T, H, nope + rope) against the slot's cached
         latents (the chunk's own included: they were written first), in blocks
         of `KEY_BLOCK` keys, each block EXPANDED to K and V of every head and
         swept with an online softmax, the SELECTION AS A MASK: row t keeps
-        column s iff s < counts[t] and `bits[t, s]` (the index score's sortable
-        bits, (T, MP * ps)) lies in the row's top k (`selected` with the row's
-        `thr`, `need`). The loop runs over the blocks that hold a visible key.
-        -> (T, H, v)."""
+        column s iff s < counts[t] and the sortable bits of `scores[t, s]` (the
+        index scores, (T, MP * ps)) lie in the row's top k (`selected` with the
+        row's `thr`, `need`). The loop runs over the blocks that hold a visible
+        key. -> (T, H, v). The off-TPU lowering and the tests' oracle."""
         T, H, _ = q.shape
         ps, MP = pool.shape[3], table_row.shape[0]
         kp = max(1, min(MP, KEY_BLOCK // ps))  # pages a block
         blk = kp * ps
-        bits = jnp.pad(bits, ((0, 0), (0, -bits.shape[1] % blk)))  # whole blocks (a table narrower than a block)
+        bits = jnp.pad(sortable_bits(scores), ((0, 0), (0, -scores.shape[1] % blk)))  # whole blocks (a table narrower than a block)
         scale = 1.0 / math.sqrt(g.qk)
 
         def body(b, carry):

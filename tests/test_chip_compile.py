@@ -21,6 +21,7 @@ fa = importlib.import_module("midgpt_tpu.kernels.flash_attention")
 at = importlib.import_module("midgpt_tpu.kernels.attention_template")
 pw = importlib.import_module("midgpt_tpu.kernels.paged_write")
 gm = importlib.import_module("midgpt_tpu.kernels.grouped_matmul")
+lp = importlib.import_module("midgpt_tpu.kernels.latent_prefill")
 
 
 @pytest.fixture(scope="module")
@@ -63,6 +64,7 @@ def compiled_kernels(monkeypatch):
     monkeypatch.setattr(at, "_interpret", lambda: False)
     monkeypatch.setattr(pw, "_interpret", lambda: False)
     monkeypatch.setattr(gm, "_interpret", lambda: False)
+    monkeypatch.setattr(lp, "_interpret", lambda: False)
     with jax.default_matmul_precision("default"):
         yield
 
@@ -753,8 +755,11 @@ def test_sparse_serving_program_compiles_and_reads_only_the_selected_rows(progra
     AND `sliding_window` 513 together (one Mosaic call under `attn_window`, a
     pool head of 1,152 lanes), its full layer GATHERS 2,048 latent rows a slot
     (`bf16[32,2048,640]`) and no more, whatever the 65,536-token table holds;
-    both programs write each of the three pool arrays in place and hold NO copy
-    as large as a pool array or one layer of it."""
+    the prefill program's full layer attends in ONE Mosaic call under
+    `attn_select` (kernels/latent_prefill.py) inside the default scoped VMEM,
+    and its temporaries are smaller than the XLA sweep's were; both programs
+    write each of the three pool arrays in place and hold NO copy as large as
+    a pool array or one layer of it."""
     import dataclasses
     import re
 
@@ -780,15 +785,46 @@ def test_sparse_serving_program_compiles_and_reads_only_the_selected_rows(progra
         lowered = serve._serve_prefill_chunk.lower(
             mc, params, arr((1, 512)), arr(()), arr(()), cache, (arr((1, T)), arr((1, T))), None, "kernel",
             0.8, None, None, arr((2,), jnp.uint32))
-    text = lowered.compile().as_text()
+    compiled = lowered.compile()
+    text = compiled.as_text()
     paths = re.findall(r'custom-call\([^\n]*tpu_custom_call[^\n]*?op_name="([^"]*)"', text)
     assert sum("kv_write" in p for p in paths) == 3 and sum("moe_experts" in p for p in paths) == 1
     attention = [p.split("/")[-2] for p in paths if "kv_write" not in p and "moe_experts" not in p]
-    assert attention == (["attn_window"] if program == "decode8" else [])
+    assert attention == (["attn_window"] if program == "decode8" else ["attn_select"])
     if program == "decode8":
         gathered = set(re.findall(r"= bf16\[32,(\d+),640\]\S* gather\(", text))
         assert gathered == {"2048"}, gathered  # the selected rows, through the table; never the context
+    else:
+        call, = re.findall(r'[^\n]*tpu_custom_call[^\n]*attn_select/pallas_call[^\n]*', text)
+        assert set(re.findall(r'"scoped_memory_configs":\[[^\]]*"size":"(\d+)"', call)) == {str(16 * 2 ** 20)}  # no vmem_limit_bytes
+        # 443,075,072 B with the XLA sweep in the kernel's place (AOT compile, PR 60: this program at e5b6b25): the
+        # selection's mask is built with no (T, S) count and no second copy of the scores' bits (`selection_mask`)
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.8 * 443_075_072
     assert pool_relayouts(text, [a.shape for a in cache.pool_arrays()]) == 0
+
+
+@pytest.mark.parametrize("form,pages", [("causal_chunk_no_mask_operand", 512), ("mask_operand_a_table_of_one_block", 16)])
+def test_latent_prefill_kernel_compiles_at_the_published_widths(form, pages, one_chip, compiled_kernels):
+    """kernels/latent_prefill.py alone at the widths dots3-note's full layers
+    and openPangu-Ultra share (128 heads, latent 512 + 64 in rows of 640 lanes,
+    qk 128 + 64, v 128; a chunk of 512 rows): the form WITHOUT the mask operand
+    (a causal chunk's visibility from two prefetched scalars: the sweep of
+    models/pangu_ultra.py, which no program runs yet), and the masked form
+    over a table of one key block. One Mosaic call, the default scoped VMEM."""
+    import math
+    import re
+
+    arr = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    S, i32 = pages * 32, jnp.int32
+
+    def attend(q, lat, w, n_keys, keep, start, n_valid):
+        keep, start, n_valid = (None, start, n_valid) if form.startswith("causal") else (keep, None, None)
+        return lp.latent_prefill_attention(q, lat, w, n_keys, keep, start, n_valid, nope=128, scale=1 / math.sqrt(192))
+
+    text = jax.jit(attend).lower(arr((512, 128, 192)), arr((S, 640)), arr((128, 256, 512)), arr((), i32),
+                                 arr((512, S), jnp.int8), arr((), i32), arr((), i32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert set(re.findall(r'"scoped_memory_configs":\[[^\]]*"size":"(\d+)"', text)) <= {str(16 * 2 ** 20)}
 
 
 @pytest.mark.parametrize("program", ["decode8", "prefill2x128"])

@@ -362,6 +362,73 @@ def test_window_decode_through_the_kernel_never_reads_behind_the_window(monkeypa
     np.testing.assert_allclose(np.asarray(got)[:2], np.asarray(want)[:2], atol=ATOL)
 
 
+# what a case of the prefill kernel's test changes of: 16 chunk rows from `start` (all real), a table of 16 pages of 4,
+# distinct index scores, blocks of 16 keys (four, of which the sweep reaches ceil((start + 16) / 16)), two heads a group
+_PREFILL_KERNEL_CASES = {
+    "a_context_of_several_blocks": dict(start=41),
+    "a_table_narrower_than_a_block": dict(start=4, pages=6, key_block=32),
+    "equal_index_scores_across_the_kth_place": dict(start=37, levels=1.5),
+    "a_chunk_with_pad_rows": dict(start=32, n_valid=5),
+    "a_first_chunk": dict(start=0, n_valid=11),
+    "entries_past_the_slots_length_poisoned": dict(start=20, poison=True),
+    "no_mask_operand_pangus_causal_chunk": dict(start=27, n_valid=13, masked=False),
+}
+
+
+@pytest.mark.parametrize("case", list(_PREFILL_KERNEL_CASES))
+def test_prefill_kernel_is_the_xla_sweep(case, monkeypatch):
+    """kernels/latent_prefill.py in interpret mode against the XLA sweep it
+    replaces on a TPU, at the rehearsal widths: a full layer's chunk of 16 rows
+    over a slot's cached latents, the selection (exact top 8, ties to the lower
+    position) and each row's visibility folded into the `keep` operand; without
+    the operand, the causal chunk against `PanguUltra._prefill_sweep`. Page
+    table entries past the slot's length are never dereferenced."""
+    import types
+
+    import midgpt_tpu.kernels.latent_prefill as lp
+    from midgpt_tpu.models import pangu_ultra
+
+    kw = dict(dict(pages=16, key_block=16, n_valid=16, levels=0, poison=False, masked=True), **_PREFILL_KERNEL_CASES[case])
+    c = toy()
+    g, T, ps, MP = c.geom(LATENT), 16, 4, kw["pages"]
+    monkeypatch.setattr(lp, "KEY_BLOCK", kw["key_block"])
+    monkeypatch.setattr(lp, "HEAD_GROUP", 2)
+    monkeypatch.setattr(dots3, "KEY_BLOCK", 16)
+    monkeypatch.setattr(pangu_ultra, "PREFILL_KEY_BLOCK", 16)
+    ks = jax.random.split(jax.random.PRNGKey(len(case)), 4)
+    p = types.SimpleNamespace(w_kvb=jax.random.normal(ks[0], (g.n_head * (g.nope + g.v), g.kv_rank)) / 4.0)
+    pool = jax.random.normal(ks[1], (2, 1, 3 * MP + 1, ps, g.latent_dim))
+    q = jax.random.normal(ks[2], (T, g.n_head, g.qk))
+    start, n_valid = jnp.asarray(kw["start"]), jnp.asarray(kw["n_valid"])
+    counts = jnp.minimum(start + jnp.arange(T), start + n_valid - 1) + 1
+    table = 1 + MP + np.arange(MP, dtype=np.int32)  # the slot's pages lie in the middle of the pool
+    used = table.copy()
+    if kw["poison"]:
+        used[-(-int(counts[-1]) // ps):] = 10 ** 6
+        assert (used != table).sum() >= 4
+    assert int(counts[-1]) <= MP * ps and (kw["key_block"] > MP * ps or int(counts[-1]) > 2 * kw["key_block"] or kw["start"] == 0)
+    if not kw["masked"]:
+        pc = types.SimpleNamespace(n_head=g.n_head, qk_nope_head_dim=g.nope, qk_rope_head_dim=g.rope, v_head_dim=g.v,
+                                   kv_lora_rank=g.kv_rank, qk_head_dim=g.qk, latent_dim=g.latent_dim)
+        want = pangu_ultra.PanguUltra._prefill_sweep(pc, p, q, pool, 1, jnp.asarray(table), counts)
+        got = lp.latent_prefill_attention(
+            q, lp.slot_rows(pool, 1, jnp.asarray(used), counts[-1]), p.w_kvb.reshape(g.n_head, g.nope + g.v, g.kv_rank),
+            counts[-1], None, start, n_valid, nope=g.nope, scale=1.0 / np.sqrt(g.qk))
+    else:
+        scores = jax.random.normal(ks[3], (T, MP * ps))
+        if kw["levels"]:
+            scores = jnp.floor(scores * kw["levels"])  # a few distinct values: a row's k-th place lies inside a run of equals
+        scores = jnp.where(jnp.arange(MP * ps)[None] < counts[:, None], scores, -jnp.inf)  # as `_index_sweep` hands them on
+        bits = dots3.sortable_bits(scores)
+        thr, need = dots3.kth_largest(bits, c.index_topk)
+        if kw["levels"]:
+            assert int(jnp.sum(jnp.sum(bits == thr[:, None], axis=1) > need)) >= 12  # rows with equals on both sides of the cut
+        want = Dots3._prefill_sparse_sweep(g, p, q, pool, 1, jnp.asarray(table), counts, scores, thr, need)
+        got = Dots3._prefill_sparse_kernel(g, p, q, pool, 1, jnp.asarray(used), counts, scores, thr, need)
+    assert got.shape == want.shape == (T, g.n_head, g.v) and float(jnp.abs(want).max()) > 0.3
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=ATOL)
+
+
 # ---------------------------------------------------------------------------
 # the seam, the configuration, the traffic, the cell
 # ---------------------------------------------------------------------------
