@@ -44,71 +44,50 @@ The SERVING members, of every family whose `check_serving` returns None
 (`GPT`, `MimoV2`, `PanguUltra`, `Ouro`, `Trinity`, `Dots3`, `OlmoHybrid`;
 sampling/serve.py calls them, never a family by name):
 
-    cache_kinds(config) -> (CacheKind(name, window, sinks), ..., [StateKind(name, shapes), ...])
-                               the kinds of paged cache the layers need, the
-                               engine's first kind first. Each gets a pool, a
-                               page table and an allocator of its own; `window`
-                               > 0: a page is freed once every future query's
-                               window has passed it (0: it lives as long as
-                               its request); `sinks`: leading tokens never
-                               freed. The GPT: one kind. MimoV2 and Trinity:
-                               `global` (window 0) and `window` (128; 2,048),
-                               held in one cache class (`MimoKVCache`).
-                               PanguUltra: one kind,
-                               `latent`, whose pool row is not K beside V of
-                               (heads, head_dim) but a token's LATENT, stored
-                               once (below). Ouro: one kind, `looped`. Dots3
-                               (models/dots3.py): `latent` (window 0; the layers
-                               whose indexer selects what they attend) and
-                               `window_latent` (513), both latent rows.
-                               A STATE kind (`StateKind`, after the paged
-                               kinds) is memory that is a ROW A SLOT and not a
-                               row a token: a recurrent layer's state, as large
-                               for ten tokens as for ten thousand.
-                               `shapes(cache_dtype)` gives ((shape, dtype), ...)
-                               of one slot's row. It has no pages, window or
-                               table: the pool owner (sampling/pages.py "State
-                               kinds") sizes it by the slot count (+ a sink
-                               row), gives slot i row i, keeps it in the
+    cache_kinds(config) -> (CacheKind(name, window, sinks), ..., [StateKind(name, shapes)])
+                               the kinds of cache the layers need, the PAGED
+                               kinds first and the engine's first kind first.
+                               Each paged kind gets a page table and an
+                               allocator of its own; `window` > 0: a page is
+                               freed once every future query's window has
+                               passed it (0: it lives as long as its
+                               request); `sinks`: leading tokens never freed.
+                               A STATE kind is memory that is a ROW A SLOT and
+                               not a row a token (a recurrent layer's state, as
+                               large for ten tokens as for ten thousand);
+                               `shapes(cache_dtype)` gives ((shape, dtype),
+                               ...) of one slot's row. It has no pages, window
+                               or table: the pool owner (sampling/pages.py
+                               "State kinds") sizes it by the slot count (+ a
+                               sink row), gives slot i row i, keeps it in the
                                conservation law and hands the programs each
                                table row's state row as the LAST entry of the
                                `page_table` tuple; what moves pages (prefix
                                cache, speculation, int8, a mesh, hot swap,
                                resize, spill, disaggregated hand-off) is
-                               refused by name beside one. OlmoHybrid
-                               (models/olmo_hybrid.py): `global` (its full
-                               layers, one cache layer a period) and the state
-                               kind `gdn_state`: every linear layer's delta-rule
-                               state (heads, d_v, d_k) float32, as the chunked
-                               kernels hold it, and its convolution's history.
+                               refused by name beside one. Which kinds a
+                               family names, and what a row of each holds, is
+                               in its own module docstring
     init_cache(config, num_pages, page_size, dtype, kernel_layout) -> cache
                                `num_pages[i]` pages for kind i (for a STATE
-                               kind: its ROWS, the sink row included); the
-                               cache is a pytree with `pool_arrays()` (its page
-                               pools, for the layout census), `page_size`,
-                               `num_pages`, and, with a state kind, `state`: the
-                               tuple of its arrays, the row axis SECOND
-                               ((layers, rows, ...)), the last row the sink.
-                               The pools' LAYER axis is the family's, not
-                               `n_layer`: the engine sizes, allocates and
-                               frees PAGES and never reads it. The GPT: one
-                               cache layer a weight layer. Ouro: `n_loop *
-                               n_layer` (each of the n_loop passes over the
-                               same weights keeps keys and values of its own;
-                               row r * n_layer + l), in the GPT pool's layout.
-                               A kind's pool is as many arrays as the family
-                               needs: K and V pools (the GPT, MimoV2: two a
-                               kind), or ONE array where a row is a latent
-                               (PanguUltra: (layers, 1, pages, page_size,
-                               kv_lora_rank + rope), 640 lanes on the kernel
-                               path; K is the row, V a VIEW of its leading
-                               kv_lora_rank lanes, so nothing is stored twice).
-                               Dots3's `latent` kind is TWO arrays under one
-                               page table, a token's latent row (640 lanes) and
-                               its index key (128), which live and die with the
-                               same pages; its `window_latent` kind ONE array
-                               (1,152 lanes): `KindsKVCache.pools[i]` is the
-                               tuple of kind i's arrays
+                               kind: its ROWS, the sink row included). Every
+                               family but the GPT (`PagedKVCache`) answers
+                               with ONE class, models/gpt.py `ServeCache`:
+                               `pools[i]` the arrays of paged kind i, as many
+                               as the kind needs, each (cache layers, pool
+                               heads, pages, page_size, lanes), page 0 the
+                               sink; `state` the state kind's arrays, the row
+                               axis SECOND, the last row the sink; `counters`
+                               the family's device-side counters. The family
+                               hands `ServeCache.zeros` each array's (layers,
+                               heads, width) and its counters, and holds
+                               neither the layout, the kernel path's lane rule
+                               nor the int8 refusal. The LAYER axis is the
+                               family's, not `n_layer`: the engine sizes,
+                               allocates and frees PAGES and never reads it.
+                               The engine reads `pool_arrays()` (the layout
+                               census), `page_size`, `num_pages`; the pool
+                               owner reads `state`
     prefill_batched            True: `prefill_paged_chunk` takes the chunks of
                                B slots as the rows of one batch (the GPT;
                                Ouro: a call reads the layers' weights n_loop

@@ -41,8 +41,8 @@ FFN: layers < `n_dense_layers` a SwiGLU of `dense_width`; the others `ops/moe.py
 renormalised, times `routed_scaling_factor` 1) over the experts HELD here
 (`[expert_offset, expert_offset + n_experts_held)`), plus the shared expert.
 
-WHAT IS CACHED (`KindsKVCache`: a kind's pool is a TUPLE of arrays under one
-page table). Kind `latent` (window 0; the full layers): a token's row [c; k_r
+WHAT IS CACHED (models/gpt.py `ServeCache`: `pools` = ((rows, index keys),
+(window rows,)), a kind's arrays under one page table). Kind `latent` (window 0; the full layers): a token's row [c; k_r
 rotated] (576 values, 640 lanes on the kernel path) AND, beside it, its rotated
 index key (128 values): two arrays that live and die with the same pages. Kind
 `window_latent` (window 513; the sliding layers): ONE array of rows of 1,088
@@ -106,7 +106,7 @@ import typing as tp
 import jax
 import jax.numpy as jnp
 
-from midgpt_tpu.models.gpt import CacheKind, _paged_write, pool_lanes
+from midgpt_tpu.models.gpt import CacheKind, ServeCache, _paged_write
 from midgpt_tpu.ops.attention import visible_mask
 from midgpt_tpu.ops.moe import (
     moe_count_decode, moe_count_dropped, moe_counters_init, moe_serve_counters, moe_serving, swiglu,
@@ -343,34 +343,6 @@ class Dots3Params:
     layers: tp.Tuple[LayerParams, ...]
     final_norm: Array  # (D,)
     lm_head: Array  # (V, D), untied
-
-
-@pytree_dataclass
-class KindsKVCache:
-    """The serving state of a family whose kinds of paged cache hold AS MANY
-    ARRAYS AS THEY NEED: `pools[i]` is the tuple of arrays of kind i of
-    `cache_kinds`, all (layers of the kind, 1, pages, page_size, lanes), indexed
-    by that kind's one page table, page 0 the sink. Here: ((latent rows, index
-    keys), (window latent rows,)). `moe_counts` / `moe_totals`: the expert
-    layers' counters (ops/moe.py); `dsa`: (3, 2) uint32, [decoded tokens, sum of
-    their contexts, sum of min(context, index_topk)] as (low, high) words, all
-    summed on the device by the decode steps that donate this pytree."""
-
-    pools: tp.Tuple[tp.Tuple[Array, ...], ...]
-    moe_counts: Array
-    moe_totals: Array
-    dsa: Array
-
-    def pool_arrays(self) -> tp.List[Array]:
-        return [a for kind in self.pools for a in kind]
-
-    @property
-    def page_size(self) -> int:
-        return self.pools[0][0].shape[3]
-
-    @property
-    def num_pages(self) -> int:
-        return self.pools[0][0].shape[2]
 
 
 _F32_LEAVES = ("norm1", "norm2", "final_norm", "q_norm", "kv_norm", "k_norm_w", "k_norm_b", "router", "router_bias")
@@ -731,35 +703,31 @@ class Dots3:
 
     @staticmethod
     def init_cache(config: Dots3Config, num_pages: tp.Sequence[int], page_size: int = 8,
-                   dtype=jnp.bfloat16, kernel_layout: bool = False) -> KindsKVCache:
-        """Zeroed pools, `num_pages[i]` pages for kind i of `cache_kinds`: the
-        latent kind's rows and index keys, the window kind's rows."""
-        if jnp.dtype(dtype) == jnp.int8:
-            raise NotImplementedError(f"{FAMILY}: no int8 pool (no quantised write or read of a latent row)")
+                   dtype=jnp.bfloat16, kernel_layout: bool = False) -> ServeCache:
+        """Zeroed pools of one head, `num_pages[i]` pages for kind i of
+        `cache_kinds`: the latent kind's rows and index keys (two arrays under
+        one page table), the window kind's rows. Counters: the expert layers'
+        `(moe_counts, moe_totals)` (ops/moe.py), then `dsa`: (3, 2) uint32,
+        [decoded tokens, sum of their contexts, sum of min(context,
+        index_topk)] as (low, high) words."""
         c = config
-        lanes = pool_lanes if kernel_layout else (lambda n: n)
-
-        def pool(kind: str, pages: int, width: int) -> Array:
-            return jnp.zeros((len(c.layers_of(kind)), 1, pages, page_size, lanes(width)), dtype)
-
-        moe_counts, moe_totals = moe_counters_init(len(c.moe_layers), c.n_experts_held)
-        return KindsKVCache(
-            pools=((pool(LATENT, num_pages[0], c.geom(LATENT).latent_dim), pool(LATENT, num_pages[0], c.index_head_dim)),
-                   (pool(WINDOW_LATENT, num_pages[1], c.geom(WINDOW_LATENT).latent_dim),)),
-            moe_counts=moe_counts, moe_totals=moe_totals, dsa=jnp.zeros((3, 2), jnp.uint32),
-        )
+        n_full, n_window = len(c.layers_of(LATENT)), len(c.layers_of(WINDOW_LATENT))
+        widths = (((n_full, 1, c.geom(LATENT).latent_dim), (n_full, 1, c.index_head_dim)),
+                  ((n_window, 1, c.geom(WINDOW_LATENT).latent_dim),))
+        return ServeCache.zeros(FAMILY, widths, num_pages, page_size, dtype, kernel_layout,
+                                (*moe_counters_init(len(c.moe_layers), c.n_experts_held), jnp.zeros((3, 2), jnp.uint32)))
 
     kernel_sweep_whole = True  # the window layers' is the decode program's only kernel: the full layers gather the selected rows
 
     @staticmethod
-    def kernel_sweep(config: Dots3Config, cache: KindsKVCache) -> tp.Tuple[tp.Tuple[int, ...], int, int, int]:
+    def kernel_sweep(config: Dots3Config, cache: ServeCache) -> tp.Tuple[tp.Tuple[int, ...], int, int, int]:
         """(pool shape, q rows a pool head, window, sinks) of the decode
         kernel's sweep, for the engine's block counters: the WINDOW layers' (the
         only decode attention that is a kernel: the full layers gather)."""
         return cache.pools[1][0].shape, config.swa_n_head, config.sliding_window, 0
 
     @staticmethod
-    def serve_counters(config: Dots3Config, cache: KindsKVCache) -> tp.Dict[str, float]:
+    def serve_counters(config: Dots3Config, cache: ServeCache) -> tp.Dict[str, float]:
         """The expert layers' counters (ops/moe.py `moe_serve_counters`); the
         indexer's, over decoded tokens of active slots and the full layers:
         `dsa.keys_scored` (index keys a query was scored against: its context)
@@ -767,11 +735,12 @@ class Dots3:
         index_topk)); and what each pool array keeps of a token over its
         layers, in bytes."""
         (lat, idx), (wlat,) = cache.pools
-        dsa = jax.device_get(cache.dsa).astype(object)
+        moe_counts, moe_totals, dsa = cache.counters
+        dsa = jax.device_get(dsa).astype(object)
         tokens, keys, rows = (int(lo) + (int(hi) << 32) for lo, hi in dsa)
         n_full = len(config.layers_of(LATENT))
         per_token = lambda a: a.nbytes / (a.shape[2] * a.shape[3])
-        return {**moe_serve_counters(cache.moe_counts, cache.moe_totals),
+        return {**moe_serve_counters(moe_counts, moe_totals),
                 "dsa.decode_tokens": tokens, "dsa.keys_scored": keys * n_full, "dsa.rows_selected": rows * n_full,
                 "kv.latent_bytes_per_token": per_token(lat), "kv.index_bytes_per_token": per_token(idx),
                 "kv.window_latent_bytes_per_token": per_token(wlat)}
@@ -831,9 +800,9 @@ class Dots3:
         return jnp.einsum("bhs,bsr->bhr", prob, rows[..., :g.kv_rank])
 
     @staticmethod
-    def decode_step_paged(config: Dots3Config, params: Dots3Params, token: Array, cache: KindsKVCache,
+    def decode_step_paged(config: Dots3Config, params: Dots3Params, token: Array, cache: ServeCache,
                           page_table: tp.Tuple[Array, Array], lengths: Array, active: Array,
-                          attn_impl: str = "auto", mesh=None, split_k: int = 1) -> tp.Tuple[Array, KindsKVCache]:
+                          attn_impl: str = "auto", mesh=None, split_k: int = 1) -> tp.Tuple[Array, ServeCache]:
         """One decode step for B requests at B positions (GPT.decode_step_paged's
         contract). `page_table` is (latent table, window table), both (B, pages)
         and LOGICAL; slot b writes its token's rows at position lengths[b] in
@@ -865,7 +834,7 @@ class Dots3:
             first = jnp.minimum(jnp.maximum(counts - W, 0) // ps, MP - n_win)
             win_ids = jnp.take_along_axis(tables[WINDOW_LATENT], first[:, None] + jnp.arange(n_win, dtype=jnp.int32), axis=1)
         k_sel = min(c.index_topk, tables[LATENT].shape[1] * ps)
-        moe_counts, totals = cache.moe_counts, cache.moe_totals
+        moe_counts, totals, dsa = cache.counters
         x = Dots3._embed(params, token[:, None])  # (B, 1, D)
         n_moe = 0
         for i, (p, (kind, li)) in enumerate(zip(params.layers, c.pool_layers)):
@@ -908,15 +877,15 @@ class Dots3:
                 n_moe += 1
         totals = totals.at[0].add(1)
         ctx = jnp.where(active, pos + 1, 0)
-        dsa = jnp.stack([_add64(cache.dsa[0], jnp.sum(active, dtype=jnp.int32)), _add64(cache.dsa[1], jnp.sum(ctx)),
-                         _add64(cache.dsa[2], jnp.sum(jnp.minimum(ctx, c.index_topk)))])
+        dsa = jnp.stack([_add64(dsa[0], jnp.sum(active, dtype=jnp.int32)), _add64(dsa[1], jnp.sum(ctx)),
+                         _add64(dsa[2], jnp.sum(jnp.minimum(ctx, c.index_topk)))])
         logits = Dots3._head(c, params, x)[:, 0]
-        return logits, KindsKVCache(pools=((lat, idx), (wlat,)), moe_counts=moe_counts, moe_totals=totals, dsa=dsa)
+        return logits, ServeCache(pools=((lat, idx), (wlat,)), counters=(moe_counts, totals, dsa))
 
     @staticmethod
     def prefill_paged_chunk(config: Dots3Config, params: Dots3Params, tokens: Array, start: Array,
-                            n_valid: Array, cache: KindsKVCache, page_table: tp.Tuple[Array, Array],
-                            attn_impl: str = "auto", mesh=None) -> tp.Tuple[Array, KindsKVCache]:
+                            n_valid: Array, cache: ServeCache, page_table: tp.Tuple[Array, Array],
+                            attn_impl: str = "auto", mesh=None) -> tp.Tuple[Array, ServeCache]:
         """One request's prompt chunk [start, start + n_valid) into its pages of
         both kinds (the ONE-ROW call of models/__init__.py: tokens (1, T),
         scalar start / n_valid, `page_table` the slot's (latent row, window
@@ -944,7 +913,7 @@ class Dots3:
         n_win = min(MP, -(-(W + T) // ps) + 1)
         first = jnp.minimum(jnp.maximum(start + 1 - W, 0) // ps, MP - n_win)
         win_ids = jax.lax.dynamic_slice_in_dim(tables[WINDOW_LATENT][0], first, n_win)[None]  # (1, n_win)
-        totals = cache.moe_totals
+        moe_counts, totals, dsa = cache.counters
         x = Dots3._embed(params, tokens)  # (1, T, D)
         for i, (p, (kind, li)) in enumerate(zip(params.layers, c.pool_layers)):
             g = c.geom(kind)
@@ -972,8 +941,7 @@ class Dots3:
             if e_idx is not None:
                 totals = moe_count_dropped(totals, stats["dropped"])
         last = jax.lax.dynamic_slice_in_dim(x, jnp.maximum(n_valid - 1, 0), 1, axis=1)  # (1, 1, D)
-        return Dots3._head(c, params, last), KindsKVCache(pools=((lat, idx), (wlat,)), moe_counts=cache.moe_counts,
-                                                          moe_totals=totals, dsa=cache.dsa)
+        return Dots3._head(c, params, last), ServeCache(pools=((lat, idx), (wlat,)), counters=(moe_counts, totals, dsa))
 
     @staticmethod
     def _prefill_sparse_kernel(g: Geom, p: MLAParams, q: Array, pool: Array, li: int, table_row: Array, counts: Array,

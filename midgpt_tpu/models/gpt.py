@@ -493,6 +493,57 @@ def pool_lanes(head_dim: int) -> int:
     return -(-head_dim // 128) * 128
 
 
+@pytree_dataclass
+class ServeCache:
+    """The serving memory of every served family beside the GPT (whose
+    `PagedKVCache` above carries the int8 side buffers and is what the page
+    movers know: ROADMAP D18). It flattens to pools, then state, then
+    counters, and the serving programs donate it whole.
+
+    `pools[i]`: the arrays of PAGED kind i of the family's `cache_kinds`, as
+    many as the kind needs (K and V; one array where a row is a latent; a
+    latent row and its index key), all under that kind's one page table, each
+    (cache layers, pool heads, pages, page_size, lanes) with page 0 the sink.
+    The layer axis is the family's, not `n_layer`; `lanes` is the array's
+    width, at `pool_lanes` on the kernel path (PagedKVCache "Layout contract",
+    rule 1). `state`: the arrays of a STATE kind, (layers, rows, ...), the
+    last row the sink (sampling/pages.py "State kinds" owns the rows); ()
+    without one. `counters`: what the family's steps sum on the device, in the
+    family's order, read by its `serve_counters` when somebody asks."""
+
+    pools: tp.Tuple[tp.Tuple[Array, ...], ...]
+    state: tp.Tuple[Array, ...] = ()
+    counters: tp.Tuple[Array, ...] = ()
+
+    @staticmethod
+    def zeros(family: str, widths, num_pages: tp.Sequence[int], page_size: int, dtype, kernel_layout: bool,
+              counters: tp.Sequence[Array], state_kind: tp.Optional[StateKind] = None) -> "ServeCache":
+        """Zeroed pools of `num_pages[i]` pages for paged kind i, `widths[i]`
+        giving (cache layers, pool heads, width) of each of its arrays, and
+        zeroed state arrays of `num_pages[len(widths)]` rows (the pool owner's
+        count: the slots and the sink row) in `state_kind`'s shapes."""
+        if jnp.dtype(dtype) == jnp.int8:
+            raise NotImplementedError(f"{family}: no int8 pool (the quantised write and read are the GPT's `PagedKVCache` alone)")
+        lanes = pool_lanes if kernel_layout else (lambda width: width)
+        pools = tuple(tuple(jnp.zeros((layers, heads, pages, page_size, lanes(width)), dtype) for layers, heads, width in kind)
+                      for kind, pages in zip(widths, num_pages))
+        state = () if state_kind is None else tuple(
+            jnp.zeros((shape[0], num_pages[len(widths)], *shape[1:]), d) for shape, d in state_kind.shapes(dtype))
+        return ServeCache(pools=pools, state=state, counters=tuple(counters))
+
+    def pool_arrays(self) -> tp.List[Array]:
+        """Every paged array, in kind order (the layout census reads them)."""
+        return [a for kind in self.pools for a in kind]
+
+    @property
+    def page_size(self) -> int:
+        return self.pools[0][0].shape[3]
+
+    @property
+    def num_pages(self) -> int:
+        return self.pools[0][0].shape[2]
+
+
 def _paged_write(
     pools: tp.Tuple[Array, Array, tp.Optional[Array], tp.Optional[Array]],
     i: Array,  # () int — layer index

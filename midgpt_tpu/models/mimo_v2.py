@@ -32,7 +32,8 @@ over the experts HELD here (`[expert_offset, expert_offset + n_experts_held)`:
 one chip's share of an expert-parallel deployment; the others' pairs add
 nothing, and no exchange is run).
 
-Serving state is TWO paged pools side by side (`MimoKVCache`), because the two
+Serving state is TWO kinds of paged pool side by side (models/gpt.py
+`ServeCache`: `pools` = ((global K, V), (window K, V))), because the two
 kinds keep different things: a global layer one K/V entry a token for the whole
 context at 4 heads, a window layer only the last `sliding_window` tokens at 8
 heads. Each kind has its own pages, page table and allocator
@@ -78,7 +79,7 @@ import typing as tp
 import jax
 import jax.numpy as jnp
 
-from midgpt_tpu.models.gpt import CacheKind, _paged_write, pool_lanes
+from midgpt_tpu.models.gpt import CacheKind, ServeCache, _paged_write
 from midgpt_tpu.ops.attention import visible_mask
 from midgpt_tpu.ops.moe import (
     moe_count_decode, moe_count_dropped, moe_counters_init, moe_serve_counters, moe_serving, swiglu,
@@ -255,44 +256,6 @@ class MimoV2Params:
     layers: tp.Tuple[LayerParams, ...]
     final_norm: Array  # (D,)
     lm_head: Array  # (V, D), untied
-
-
-@pytree_dataclass
-class MimoKVCache:
-    """The serving state: a pool per attention kind, (layers of the kind, kv
-    heads, pages, page_size, channels) like `PagedKVCache`'s, K at the q/k
-    width and V at the v width, page 0 of each the sink. `moe_counts` and
-    `moe_totals` are the expert layers' counters, summed on the device by the
-    decode steps that donate this pytree and read by `MimoV2.serve_counters`
-    when somebody asks: no decode round syncs for them. The cache of every
-    family with these two kinds of K/V pool (models/trinity.py holds it too)."""
-
-    gk: Array
-    gv: Array
-    wk: Array
-    wv: Array
-    moe_counts: Array  # (moe layers, n_experts_held) int32: pairs of active slots' decode steps
-    moe_totals: Array  # (4,) int32: decode steps, held experts touched (summed over steps and layers), dropped, row blocks in use
-
-    def pool_arrays(self) -> tp.List[Array]:
-        return [self.gk, self.gv, self.wk, self.wv]
-
-    def pools(self) -> tp.Dict[str, tp.Tuple[Array, Array]]:
-        """{kind: (K pool, V pool)}, as the serving forwards thread them."""
-        return {GLOBAL: (self.gk, self.gv), WINDOW: (self.wk, self.wv)}
-
-    @staticmethod
-    def of(pools, moe_counts: Array, moe_totals: Array) -> "MimoKVCache":
-        (gk, gv), (wk, wv) = pools[GLOBAL], pools[WINDOW]
-        return MimoKVCache(gk=gk, gv=gv, wk=wk, wv=wv, moe_counts=moe_counts, moe_totals=moe_totals)
-
-    @property
-    def page_size(self) -> int:
-        return self.gk.shape[3]
-
-    @property
-    def num_pages(self) -> int:
-        return self.gk.shape[2]
 
 
 _F32_LEAVES = ("norm1", "norm2", "final_norm", "router", "router_bias", "sink")
@@ -576,35 +539,31 @@ class MimoV2:
 
     @staticmethod
     def init_cache(config: MimoV2Config, num_pages: tp.Sequence[int], page_size: int = 8,
-                   dtype=jnp.bfloat16, kernel_layout: bool = False) -> MimoKVCache:
-        """Zeroed pools, `num_pages[i]` pages for kind i of `cache_kinds`."""
-        if jnp.dtype(dtype) == jnp.int8:
-            raise NotImplementedError(f"{FAMILY}: no int8 pool (no quantised write or read at K 192 / V 128)")
+                   dtype=jnp.bfloat16, kernel_layout: bool = False) -> ServeCache:
+        """Zeroed pools, `num_pages[i]` pages for kind i of `cache_kinds`: K at
+        the kind's q/k width and V at its v width. Counters: the expert layers'
+        `(moe_counts, moe_totals)` (ops/moe.py)."""
         c = config
-        lanes = pool_lanes if kernel_layout else (lambda d: d)
 
-        def pools(kind: str, pages: int):
-            n_kv, dq, dv, _, _ = c.attn_geometry(kind)
-            shape = (len(c.layers_of(kind)), n_kv, pages, page_size)
-            return jnp.zeros(shape + (lanes(dq),), dtype), jnp.zeros(shape + (lanes(dv),), dtype)
+        def k_and_v(kind: str):
+            (n_kv, dq, dv, _, _), layers = c.attn_geometry(kind), len(c.layers_of(kind))
+            return (layers, n_kv, dq), (layers, n_kv, dv)
 
-        gk, gv = pools(GLOBAL, num_pages[0])
-        wk, wv = pools(WINDOW, num_pages[1])
-        moe_counts, moe_totals = moe_counters_init(len(c.moe_layers), c.n_experts_held)
-        return MimoKVCache(gk=gk, gv=gv, wk=wk, wv=wv, moe_counts=moe_counts, moe_totals=moe_totals)
+        return ServeCache.zeros(FAMILY, (k_and_v(GLOBAL), k_and_v(WINDOW)), num_pages, page_size, dtype, kernel_layout,
+                                moe_counters_init(len(c.moe_layers), c.n_experts_held))
 
     kernel_sweep_whole = True  # the global layers' is the decode program's only kernel: the window layers gather
 
     @staticmethod
-    def kernel_sweep(config: MimoV2Config, cache: MimoKVCache) -> tp.Tuple[tp.Tuple[int, ...], int, int, int]:
+    def kernel_sweep(config: MimoV2Config, cache: ServeCache) -> tp.Tuple[tp.Tuple[int, ...], int, int, int]:
         """(pool shape, q rows a pool head, window, sinks) of the decode
         kernel's sweep, for the engine's block counters: the global layers'."""
-        return cache.gk.shape, config.n_head // config.n_kv_heads, 0, 0
+        return cache.pools[0][0].shape, config.n_head // config.n_kv_heads, 0, 0
 
     @staticmethod
-    def serve_counters(config: MimoV2Config, cache: MimoKVCache) -> tp.Dict[str, float]:
+    def serve_counters(config: MimoV2Config, cache: ServeCache) -> tp.Dict[str, float]:
         """The expert layers' counters (ops/moe.py `moe_serve_counters`)."""
-        return moe_serve_counters(cache.moe_counts, cache.moe_totals)
+        return moe_serve_counters(*cache.counters)
 
     @staticmethod
     def _paged_attention(c: MimoV2Config, kind: str, p: AttnParams, q: Array, k_pool: Array, v_pool: Array,
@@ -614,9 +573,9 @@ class MimoV2:
         return paged_gather_attention(q, k_pool, v_pool, li, ids, col0, counts, n_kv=n_kv, dv=dv, window=window, sink=p.sink)
 
     @staticmethod
-    def decode_step_paged(config: MimoV2Config, params: MimoV2Params, token: Array, cache: MimoKVCache,
+    def decode_step_paged(config: MimoV2Config, params: MimoV2Params, token: Array, cache: ServeCache,
                           page_table: tp.Tuple[Array, Array], lengths: Array, active: Array,
-                          attn_impl: str = "auto", mesh=None, split_k: int = 1) -> tp.Tuple[Array, MimoKVCache]:
+                          attn_impl: str = "auto", mesh=None, split_k: int = 1) -> tp.Tuple[Array, ServeCache]:
         """One decode step for B requests at B positions (GPT.decode_step_paged's
         contract). `page_table` is (global table, window table), both (B,
         pages); slot b writes its token's K/V at position lengths[b] in BOTH
@@ -634,7 +593,7 @@ class MimoV2:
         pos = lengths
         counts = jnp.maximum(active.astype(jnp.int32) * (pos + 1), 1)  # (B,)
         ropes = MimoV2._rope_tables(c)
-        pools = cache.pools()
+        pools = dict(zip((GLOBAL, WINDOW), cache.pools))
         write_pages = {
             kind: jnp.where(active, jnp.take_along_axis(t, (pos // ps)[:, None], axis=1)[:, 0], pools[kind][0].shape[2])
             for kind, t in tables.items()
@@ -644,7 +603,7 @@ class MimoV2:
         n_win = min(tables[WINDOW].shape[1], -(-W // ps) + 1)
         first = jnp.minimum(jnp.maximum(counts - W, 0) // ps, tables[WINDOW].shape[1] - n_win)
         win_ids = jnp.take_along_axis(tables[WINDOW], first[:, None] + jnp.arange(n_win, dtype=jnp.int32), axis=1)
-        moe_counts, totals = cache.moe_counts, cache.moe_totals
+        moe_counts, totals = cache.counters
         with jax.named_scope("embed"):
             x = jnp.take(params.wte, token[:, None], axis=0)  # (B, 1, D)
         n_moe = 0
@@ -676,15 +635,15 @@ class MimoV2:
                 n_moe += 1
         totals = totals.at[0].add(1)
         logits = MimoV2._head(c, params, x)[:, 0]
-        return logits, MimoKVCache.of(pools, moe_counts, totals)
+        return logits, ServeCache(pools=(pools[GLOBAL], pools[WINDOW]), counters=(moe_counts, totals))
 
     # one row a call: two page tables a slot, window pages freed per slot
     prefill_batched = False
 
     @staticmethod
     def prefill_paged_chunk(config: MimoV2Config, params: MimoV2Params, tokens: Array, start: Array,
-                            n_valid: Array, cache: MimoKVCache, page_table: tp.Tuple[Array, Array],
-                            attn_impl: str = "auto", mesh=None) -> tp.Tuple[Array, MimoKVCache]:
+                            n_valid: Array, cache: ServeCache, page_table: tp.Tuple[Array, Array],
+                            attn_impl: str = "auto", mesh=None) -> tp.Tuple[Array, ServeCache]:
         """One request's prompt chunk [start, start + n_valid) into its pages
         of both pools (GPT.prefill_paged_chunk's contract; `page_table` is the
         slot's (global row, window row), both (1, pages)). The window row's
@@ -706,7 +665,7 @@ class MimoV2:
         valid = t_idx < n_valid
         counts = jnp.minimum(positions, start + n_valid - 1) + 1  # pad rows see what the last valid row sees
         ropes = MimoV2._rope_tables(c)
-        pools = cache.pools()
+        pools = dict(zip((GLOBAL, WINDOW), cache.pools))
         write_pages = {
             kind: jnp.where(valid, jnp.take(t[0], positions // ps, axis=0), pools[kind][0].shape[2])
             for kind, t in tables.items()
@@ -715,7 +674,7 @@ class MimoV2:
         n_win = min(tables[WINDOW].shape[1], -(-(W + T) // ps) + 1)
         first = jnp.minimum(jnp.maximum(start + 1 - W, 0) // ps, tables[WINDOW].shape[1] - n_win)
         win_ids = jax.lax.dynamic_slice_in_dim(tables[WINDOW][0], first, n_win)[None]  # (1, n_win)
-        totals = cache.moe_totals
+        moe_counts, totals = cache.counters
         with jax.named_scope("embed"):
             x = jnp.take(params.wte, tokens, axis=0)  # (1, T, D)
         for i, (p, (kind, li)) in enumerate(zip(params.layers, c.pool_layers)):
@@ -734,7 +693,7 @@ class MimoV2:
                 totals = moe_count_dropped(totals, stats["dropped"])
         last = jax.lax.dynamic_slice_in_dim(x, jnp.maximum(n_valid - 1, 0), 1, axis=1)  # (1, 1, D)
         logits = MimoV2._head(c, params, last)
-        return logits, MimoKVCache.of(pools, cache.moe_counts, totals)
+        return logits, ServeCache(pools=(pools[GLOBAL], pools[WINDOW]), counters=(moe_counts, totals))
 
     @staticmethod
     def _prefill_sweep(c: MimoV2Config, kind: str, p: AttnParams, q: Array, k_pool: Array, v_pool: Array,

@@ -4,7 +4,8 @@ The linear layers keep no keys: their memory is a STATE a request, a float32
 convolution, whatever the context. The full layers are plain multi-head
 attention over the GPT pool's paged layout. SERVED (sample.py, ServeEngine):
 the serving stack's first family with a STATE kind of cache (`cache_kinds`,
-sampling/pages.py); training is refused by name (`check_training`).
+sampling/pages.py; models/gpt.py `ServeCache`: `pools` = ((K, V),) and
+`state` = (delta-rule states, convolution histories)); training is refused by name (`check_training`).
 
 Source: https://huggingface.co/allenai/Olmo-Hybrid-7B/blob/main/config.json
 (`model_type: olmo_hybrid`: 32 layers, hidden 3,840, `layer_types`
@@ -58,7 +59,7 @@ import typing as tp
 import jax
 import jax.numpy as jnp
 
-from midgpt_tpu.models.gpt import GPT, CacheKind, StateKind, _paged_write, pool_lanes
+from midgpt_tpu.models.gpt import GPT, CacheKind, ServeCache, StateKind, _paged_write
 from midgpt_tpu.ops.kda import kda_chunked, kda_step
 from midgpt_tpu.ops.moe import swiglu
 from midgpt_tpu.ops.norms import rms_norm
@@ -210,36 +211,6 @@ class OlmoHybridParams:
     full: FullLayerParams
     final_norm: Array  # (D,)
     lm_head: Array  # (V, D), untied
-
-
-@pytree_dataclass
-class HybridCache:
-    """The serving memory. PAGED: K and V pools in the GPT pool's layout,
-    (n_periods, H, pages, page_size, C), one cache layer a full layer, page 0
-    the sink, C at `pool_lanes` on the kernel path. STATE (sampling/pages.py
-    owns the rows: which slot holds which; a prompt's first chunk begins
-    from zeros, `prefill_paged_chunk`): `state` = (the
-    delta-rule states (n_linear, rows, H, d_v, d_k) float32, the convolution's
-    history (n_linear, rows, (conv_kernel - 1) * channels)), the row axis second,
-    the LAST row the sink (an empty prefill row's). `gdn_counts`: decoded
-    tokens, prefilled tokens and prefill chunks the linear layers have taken,
-    summed on the device."""
-
-    k: Array
-    v: Array
-    state: tp.Tuple[Array, Array]
-    gdn_counts: Array  # (3,) int32
-
-    def pool_arrays(self) -> tp.List[Array]:
-        return [self.k, self.v]
-
-    @property
-    def page_size(self) -> int:
-        return self.k.shape[3]
-
-    @property
-    def num_pages(self) -> int:
-        return self.k.shape[2]
 
 
 # `init` seeds W_a at this fraction of a dense matrix's scale. The gate is g = -exp(A_log) softplus(W_a x + dt_bias) with
@@ -519,38 +490,36 @@ class OlmoHybrid:
 
     @staticmethod
     def init_cache(config: OlmoHybridConfig, num_pages: tp.Sequence[int], page_size: int = 8,
-                   dtype=jnp.bfloat16, kernel_layout: bool = False) -> HybridCache:
+                   dtype=jnp.bfloat16, kernel_layout: bool = False) -> ServeCache:
         """Zeroed K and V pools of `num_pages[0]` pages and one cache layer a
-        full layer, and zeroed state arrays of `num_pages[1]` rows (the pool
-        owner's count: the slots and the sink row)."""
-        if jnp.dtype(dtype) == jnp.int8:
-            raise NotImplementedError(f"{FAMILY}: no int8 pool (no quantised write or read is wired beside the state kind)")
+        full layer, and the state kind's arrays at `num_pages[1]` rows: the
+        delta-rule states (n_linear, rows, H, d_v, d_k) float32 and the
+        convolution's history (n_linear, rows, (conv_kernel - 1) * channels).
+        Counter: `gdn_counts` (3,) int32, the decoded tokens, prefilled tokens
+        and prefill chunks the linear layers have taken."""
         c = config
-        lanes = pool_lanes(c.head_dim) if kernel_layout else c.head_dim
-        shape = (c.n_periods, c.n_head, num_pages[0], page_size, lanes)
-        state = tuple(jnp.zeros((s[0], num_pages[1], *s[1:]), d) for s, d in c.state_shapes(dtype))
-        return HybridCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype), state=state,
-                           gdn_counts=jnp.zeros((3,), jnp.int32))
+        return ServeCache.zeros(FAMILY, (((c.n_periods, c.n_head, c.head_dim),) * 2,), num_pages, page_size, dtype, kernel_layout,
+                                (jnp.zeros((3,), jnp.int32),), state_kind=OlmoHybrid.cache_kinds(c)[1])
 
     @staticmethod
-    def kernel_sweep(config: OlmoHybridConfig, cache: HybridCache):
+    def kernel_sweep(config: OlmoHybridConfig, cache: ServeCache):
         """(pool shape, q rows a pool head, window, sinks) of the decode kernel's sweep."""
-        return cache.k.shape, 1, 0, 0
+        return cache.pools[0][0].shape, 1, 0, 0
 
     @staticmethod
-    def serve_counters(config: OlmoHybridConfig, cache: HybridCache) -> tp.Dict[str, float]:
+    def serve_counters(config: OlmoHybridConfig, cache: ServeCache) -> tp.Dict[str, float]:
         """`gdn.decode_tokens` (one-token updates of an active slot, a layer
         counted once), `gdn.prefill_tokens` / `gdn.prefill_chunks` (tokens and
         slot-chunks the chunk-carrying scan has taken), and what the K/V pools
         keep of a token over the full layers, in bytes."""
-        n = [int(x) for x in jax.device_get(cache.gdn_counts)]
+        n = [int(x) for x in jax.device_get(cache.counters[0])]
         return {"gdn.decode_tokens": n[0], "gdn.prefill_tokens": n[1], "gdn.prefill_chunks": n[2],
-                f"kv.{GLOBAL}_bytes_per_token": (cache.k.nbytes + cache.v.nbytes) / (cache.num_pages * cache.page_size)}
+                f"kv.{GLOBAL}_bytes_per_token": sum(a.nbytes for a in cache.pool_arrays()) / (cache.num_pages * cache.page_size)}
 
     @staticmethod
-    def decode_step_paged(config: OlmoHybridConfig, params: OlmoHybridParams, token: Array, cache: HybridCache,
+    def decode_step_paged(config: OlmoHybridConfig, params: OlmoHybridParams, token: Array, cache: ServeCache,
                           page_table, lengths: Array, active: Array,
-                          attn_impl: str = "auto", mesh=None, split_k: int = 1) -> tp.Tuple[Array, HybridCache]:
+                          attn_impl: str = "auto", mesh=None, split_k: int = 1) -> tp.Tuple[Array, ServeCache]:
         """One decode step for the B slots at B positions (GPT.decode_step_paged's
         contract). `page_table` is (the global kind's (B, pages), the state rows
         (B,)); the batch IS the slots in order and a slot's row is the row of
@@ -596,14 +565,14 @@ class OlmoHybrid:
             return jnp.einsum("be,de->bd", o.astype(p.dtype).reshape(B, -1), p.wo), ((ck, cv), state)
 
         x, ((ck, cv), state) = OlmoHybrid._run(
-            c, params, OlmoHybrid._embed(params, token), ((cache.k, cache.v), cache.state), linear_mix, full_mix)
-        counted = cache.gdn_counts.at[0].add(jnp.sum(active.astype(jnp.int32)))
-        return OlmoHybrid._head(c, params, x), HybridCache(k=ck, v=cv, state=state, gdn_counts=counted)
+            c, params, OlmoHybrid._embed(params, token), (cache.pools[0], cache.state), linear_mix, full_mix)
+        counted = cache.counters[0].at[0].add(jnp.sum(active.astype(jnp.int32)))
+        return OlmoHybrid._head(c, params, x), ServeCache(pools=((ck, cv),), state=state, counters=(counted,))
 
     @staticmethod
     def prefill_paged_chunk(config: OlmoHybridConfig, params: OlmoHybridParams, tokens: Array, start: Array,
-                            n_valid: Array, cache: HybridCache, page_table,
-                            attn_impl: str = "auto", mesh=None) -> tp.Tuple[Array, HybridCache]:
+                            n_valid: Array, cache: ServeCache, page_table,
+                            attn_impl: str = "auto", mesh=None) -> tp.Tuple[Array, ServeCache]:
         """The prompt chunks of B requests, row b's being [start[b], start[b] +
         n_valid[b]) (GPT.prefill_paged_chunk's contract). `page_table` is (the
         global kind's (B, pages), the state rows (B,)): row b's delta-rule
@@ -672,8 +641,8 @@ class OlmoHybrid:
             return jnp.einsum("bte,de->btd", o.astype(p.dtype).reshape(B, T, -1), p.wo), ((ck, cv), state)
 
         x, ((ck, cv), state) = OlmoHybrid._run(
-            c, params, OlmoHybrid._embed(params, tokens), ((cache.k, cache.v), cache.state), linear_mix, full_mix)
+            c, params, OlmoHybrid._embed(params, tokens), (cache.pools[0], cache.state), linear_mix, full_mix)
         last = jnp.take_along_axis(x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)  # (B, 1, D)
         logits = OlmoHybrid._head(c, params, last)
-        counted = cache.gdn_counts + jnp.stack([jnp.zeros((), jnp.int32), jnp.sum(n_valid), jnp.sum((n_valid > 0).astype(jnp.int32))])
-        return (logits if one_row else logits[:, 0]), HybridCache(k=ck, v=cv, state=state, gdn_counts=counted)
+        counted = cache.counters[0] + jnp.stack([jnp.zeros((), jnp.int32), jnp.sum(n_valid), jnp.sum((n_valid > 0).astype(jnp.int32))])
+        return (logits if one_row else logits[:, 0]), ServeCache(pools=((ck, cv),), state=state, counters=(counted,))

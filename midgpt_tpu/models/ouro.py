@@ -1,6 +1,7 @@
 """Ouro: a LOOPED transformer. One stack of `n_layer` layers is applied `n_loop`
 times with the SAME parameters; the final norm and an exit gate follow every
-pass, and each pass keeps keys and values of its own, so the paged cache has
+pass, and each pass keeps keys and values of its own, so the paged cache
+(models/gpt.py `ServeCache`: `pools` = ((K, V),), the GPT pool's layout) has
 `n_loop * n_layer` layers for `n_layer` layers of weights. SERVED (sample.py,
 ServeEngine); training is refused by name (`check_training`).
 
@@ -48,7 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from midgpt_tpu.models.gpt import GPT, CacheKind, _gather_layer_kv, _paged_write, pool_lanes
+from midgpt_tpu.models.gpt import GPT, CacheKind, ServeCache, _gather_layer_kv, _paged_write
 from midgpt_tpu.ops.moe import swiglu
 from midgpt_tpu.ops.norms import rms_norm
 from midgpt_tpu.ops.rope import apply_rope_leading, rope_table
@@ -146,32 +147,6 @@ class OuroParams:
     exit_w: Array  # (D,) the exit gate, Linear(D, 1)
     exit_b: Array  # ()
     lm_head: Array  # (V, D), untied
-
-
-@pytree_dataclass
-class LoopedKVCache:
-    """The serving state: K and V pools in the GPT pool's layout, (n_loop *
-    n_layer, H, pages, page_size, C) each (row `r * n_layer + l`: pass r of
-    layer l; page 0 the sink; C at `pool_lanes` on the kernel path), and the
-    loop's counters, summed on the device by the decode steps: `loop_steps`
-    (decode steps, passes run over active slots) and `exit_mass` (n_loop sums
-    of the exit distribution over decoded tokens)."""
-
-    k: Array
-    v: Array
-    loop_steps: Array  # (2,) int32
-    exit_mass: Array  # (n_loop,) float32
-
-    def pool_arrays(self) -> tp.List[Array]:
-        return [self.k, self.v]
-
-    @property
-    def page_size(self) -> int:
-        return self.k.shape[3]
-
-    @property
-    def num_pages(self) -> int:
-        return self.k.shape[2]
 
 
 # What `init` seeds the two SANDWICH norms' gains at (the norms on a sublayer's output; every other gain: 1). A looped
@@ -370,45 +345,45 @@ class Ouro:
 
     @staticmethod
     def init_cache(config: OuroConfig, num_pages: tp.Sequence[int], page_size: int = 8,
-                   dtype=jnp.bfloat16, kernel_layout: bool = False) -> LoopedKVCache:
-        """Zeroed K and V pools of `num_pages[0]` pages and `n_loop * n_layer` layers."""
-        if jnp.dtype(dtype) == jnp.int8:
-            raise NotImplementedError(f"{FAMILY}: no int8 pool (the scale side buffers are not carried through the loops)")
+                   dtype=jnp.bfloat16, kernel_layout: bool = False) -> ServeCache:
+        """Zeroed K and V pools of `num_pages[0]` pages and `n_loop * n_layer`
+        cache layers (row `r * n_layer + l`: pass r of layer l). Counters, of
+        decoded tokens: `loop_steps` (2,) int32 (decode steps, passes run over
+        active slots) and `exit_mass` (n_loop,) float32 (sums of the exit
+        distribution)."""
         c = config
-        lanes = pool_lanes(c.head_dim) if kernel_layout else c.head_dim
-        shape = (c.cache_layers, c.n_head, num_pages[0], page_size, lanes)
-        return LoopedKVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
-                             loop_steps=jnp.zeros((2,), jnp.int32), exit_mass=jnp.zeros((c.n_loop,), jnp.float32))
+        return ServeCache.zeros(FAMILY, (((c.cache_layers, c.n_head, c.head_dim),) * 2,), num_pages, page_size, dtype, kernel_layout,
+                                (jnp.zeros((2,), jnp.int32), jnp.zeros((c.n_loop,), jnp.float32)))
 
     kernel_sweep_whole = True  # every pass of every layer is this one kernel call
 
     @staticmethod
-    def kernel_sweep(config: OuroConfig, cache: LoopedKVCache):
+    def kernel_sweep(config: OuroConfig, cache: ServeCache):
         """(pool shape, q rows a pool head, window, sinks) of the decode
         kernel's sweep, for the engine's block counters; the pool's layer dim
         says how many sweeps a step makes."""
-        return cache.k.shape, 1, 0, 0
+        return cache.pools[0][0].shape, 1, 0, 0
 
     @staticmethod
-    def serve_counters(config: OuroConfig, cache: LoopedKVCache) -> tp.Dict[str, float]:
+    def serve_counters(config: OuroConfig, cache: ServeCache) -> tp.Dict[str, float]:
         """`loop.decode_steps`; `loop.passes_run` (n_loop x steps x active slots:
         what an exit that stops early would lower); `loop.exit_mass_<r>`, the
         exit distribution summed over decoded tokens, and its mean pass
         `loop.exit_pass_expected`; and what the pools keep of a token over all
         n_loop * n_layer cache layers, in bytes."""
-        steps, mass = jax.device_get((cache.loop_steps, cache.exit_mass))
+        steps, mass = jax.device_get(cache.counters)
         mass = np.asarray(mass, np.float64)
         out = {"loop.decode_steps": int(steps[0]), "loop.passes_run": int(steps[1]),
-               f"kv.{LOOPED}_bytes_per_token": (cache.k.nbytes + cache.v.nbytes) / (cache.num_pages * cache.page_size)}
+               f"kv.{LOOPED}_bytes_per_token": sum(a.nbytes for a in cache.pool_arrays()) / (cache.num_pages * cache.page_size)}
         out.update({f"loop.exit_mass_{r + 1}": float(m) for r, m in enumerate(mass)})
         if mass.sum() > 0:
             out["loop.exit_pass_expected"] = float(np.dot(mass, np.arange(1, len(mass) + 1)) / mass.sum())
         return out
 
     @staticmethod
-    def decode_step_paged(config: OuroConfig, params: OuroParams, token: Array, cache: LoopedKVCache,
+    def decode_step_paged(config: OuroConfig, params: OuroParams, token: Array, cache: ServeCache,
                           page_table: Array, lengths: Array, active: Array,
-                          attn_impl: str = "auto", mesh=None, split_k: int = 1) -> tp.Tuple[Array, LoopedKVCache]:
+                          attn_impl: str = "auto", mesh=None, split_k: int = 1) -> tp.Tuple[Array, ServeCache]:
         """One decode step for B requests at B positions (GPT.decode_step_paged's
         contract). Slot b writes its token's K/V at position lengths[b] in all
         n_loop * n_layer cache layers, pass r of layer l attending to lengths[b]
@@ -430,16 +405,17 @@ class Ouro:
             o = paged_attention(q[:, 0], ck, cv, page_table, counts, impl=attn_impl, split_k=split_k, layer=row)
             return o[:, None], (ck, cv)
 
-        h, p, (ck, cv) = Ouro._run(c, params, _embed(params, token[:, None]), pos[:, None], (cache.k, cache.v), attend)
+        h, p, (ck, cv) = Ouro._run(c, params, _embed(params, token[:, None]), pos[:, None], cache.pools[0], attend)
         n_active = jnp.sum(active.astype(jnp.int32))
-        steps = cache.loop_steps + jnp.stack([jnp.ones((), jnp.int32), c.n_loop * n_active])
-        mass = cache.exit_mass + jnp.sum(jnp.where(active[None, :], p[:, :, 0], 0.0), axis=1)
-        return Ouro._head(params, h)[:, 0], LoopedKVCache(k=ck, v=cv, loop_steps=steps, exit_mass=mass)
+        loop_steps, exit_mass = cache.counters
+        steps = loop_steps + jnp.stack([jnp.ones((), jnp.int32), c.n_loop * n_active])
+        mass = exit_mass + jnp.sum(jnp.where(active[None, :], p[:, :, 0], 0.0), axis=1)
+        return Ouro._head(params, h)[:, 0], ServeCache(pools=((ck, cv),), counters=(steps, mass))
 
     @staticmethod
     def prefill_paged_chunk(config: OuroConfig, params: OuroParams, tokens: Array, start: Array, n_valid: Array,
-                            cache: LoopedKVCache, page_table: Array,
-                            attn_impl: str = "auto", mesh=None) -> tp.Tuple[Array, LoopedKVCache]:
+                            cache: ServeCache, page_table: Array,
+                            attn_impl: str = "auto", mesh=None) -> tp.Tuple[Array, ServeCache]:
         """The prompt chunks of B requests, row b's being [start[b], start[b] +
         n_valid[b]), into their pages in all n_loop * n_layer cache layers
         (GPT.prefill_paged_chunk's contract: written first, then each row
@@ -476,7 +452,7 @@ class Ouro:
             prob = jax.nn.softmax(jnp.where(keep, s, float("-inf")), axis=-1).astype(vg.dtype)
             return jnp.einsum("bhts,bhsc->bthc", prob, vg), (ck, cv)
 
-        h, _, (ck, cv) = Ouro._run(c, params, _embed(params, tokens), positions, (cache.k, cache.v), attend)
+        h, _, (ck, cv) = Ouro._run(c, params, _embed(params, tokens), positions, cache.pools[0], attend)
         last = jnp.take_along_axis(h, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)  # (B, 1, D)
         logits = Ouro._head(params, last)
-        return (logits if one_row else logits[:, 0]), dataclasses.replace(cache, k=ck, v=cv)
+        return (logits if one_row else logits[:, 0]), dataclasses.replace(cache, pools=((ck, cv),))

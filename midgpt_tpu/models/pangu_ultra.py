@@ -29,8 +29,8 @@ q_r and on the ONE k_r; [k_n; v] = W_kvb c a head (128 + 128); scores (q_n.k_n
 + q_r.k_r) / sqrt(192), causal softmax, values v, W_o.
 
 WHAT IS CACHED is the LATENT, once: a token's row of a layer is [c (512); k_r
-rotated (64)] = 576 values (`LatentKVCache`: one pool, one head; 640 lanes on
-the kernel path, 1,280 B in bf16), not K and V of 128 heads (81,920 B). K is
+rotated (64)] = 576 values (models/gpt.py `ServeCache`: `pools` = ((rows,),), one
+array of one head; 640 lanes on the kernel path, 1,280 B in bf16), not K and V of 128 heads (81,920 B). K is
 the whole row and V is a VIEW of its leading 512 lanes.
 
 Attention on the paged path:
@@ -61,7 +61,7 @@ import typing as tp
 import jax
 import jax.numpy as jnp
 
-from midgpt_tpu.models.gpt import CacheKind, _paged_write, pool_lanes
+from midgpt_tpu.models.gpt import CacheKind, ServeCache, _paged_write
 from midgpt_tpu.ops.moe import (
     moe_count_decode, moe_count_dropped, moe_counters_init, moe_serve_counters, moe_serving, swiglu,
 )
@@ -214,32 +214,6 @@ class PanguUltraParams:
     layers: tp.Tuple[LayerParams, ...]
     final_norm: Array  # (D,)
     lm_head: Array  # (V, D), untied
-
-
-@pytree_dataclass
-class LatentKVCache:
-    """The serving state: ONE pool, (layers, 1, pages, page_size, channels): a
-    token's row is [normed latent (kv_lora_rank); rotated shared key (rope)],
-    stored once, page 0 the sink. K is the row and V is its leading
-    `kv_lora_rank` channels: there is no second tensor. On the kernel path the
-    channel dim is `pool_lanes(latent_dim)` (576 -> 640; PagedKVCache "Layout
-    contract", rule 1). `moe_counts` / `moe_totals`: the expert layers'
-    counters (ops/moe.py), summed on the device by the decode steps."""
-
-    kv: Array
-    moe_counts: Array
-    moe_totals: Array
-
-    def pool_arrays(self) -> tp.List[Array]:
-        return [self.kv]
-
-    @property
-    def page_size(self) -> int:
-        return self.kv.shape[3]
-
-    @property
-    def num_pages(self) -> int:
-        return self.kv.shape[2]
 
 
 _F32_LEAVES = ("norm_in", "norm_post_attn", "norm_pre_mlp", "norm_post_mlp", "q_norm", "kv_norm", "final_norm", "router")
@@ -500,33 +474,31 @@ class PanguUltra:
 
     @staticmethod
     def init_cache(config: PanguUltraConfig, num_pages: tp.Sequence[int], page_size: int = 8,
-                   dtype=jnp.bfloat16, kernel_layout: bool = False) -> LatentKVCache:
-        """A zeroed pool of `num_pages[0]` pages: ONE array, a token's row stored once."""
-        if jnp.dtype(dtype) == jnp.int8:
-            raise NotImplementedError(f"{FAMILY}: no int8 pool (no quantised write or read of a latent row)")
+                   dtype=jnp.bfloat16, kernel_layout: bool = False) -> ServeCache:
+        """A zeroed pool of `num_pages[0]` pages: ONE array of one head, a
+        token's row stored once (module docstring). Counters: the expert
+        layers' `(moe_counts, moe_totals)`."""
         c = config
-        lanes = pool_lanes(c.latent_dim) if kernel_layout else c.latent_dim
-        moe_counts, moe_totals = moe_counters_init(len(c.moe_layers), c.n_experts_held)
-        return LatentKVCache(kv=jnp.zeros((c.n_layer, 1, num_pages[0], page_size, lanes), dtype),
-                             moe_counts=moe_counts, moe_totals=moe_totals)
+        return ServeCache.zeros(FAMILY, (((c.n_layer, 1, c.latent_dim),),), num_pages, page_size, dtype, kernel_layout,
+                                moe_counters_init(len(c.moe_layers), c.n_experts_held))
 
     kernel_sweep_whole = True  # every layer's absorbed decode is this one kernel call
 
     @staticmethod
-    def kernel_sweep(config: PanguUltraConfig, cache: LatentKVCache) -> tp.Tuple[tp.Tuple[int, ...], int, int, int]:
+    def kernel_sweep(config: PanguUltraConfig, cache: ServeCache) -> tp.Tuple[tp.Tuple[int, ...], int, int, int]:
         """(pool shape, q rows a pool head, window, sinks) of the decode
         kernel's sweep, for the engine's block counters: every head's query
         row against the pool's one head."""
-        return cache.kv.shape, config.n_head, 0, 0
+        return cache.pools[0][0].shape, config.n_head, 0, 0
 
     @staticmethod
-    def serve_counters(config: PanguUltraConfig, cache: LatentKVCache) -> tp.Dict[str, float]:
+    def serve_counters(config: PanguUltraConfig, cache: ServeCache) -> tp.Dict[str, float]:
         """The expert layers' counters (ops/moe.py `moe_serve_counters`), and
         what the pool keeps of a token over all layers, in bytes: `n_layer` rows
         of the pool's lanes (the latent stored ONCE; K and V of every head
         would be 64 times that)."""
-        pool = cache.kv
-        return {**moe_serve_counters(cache.moe_counts, cache.moe_totals),
+        pool = cache.pools[0][0]
+        return {**moe_serve_counters(*cache.counters),
                 "kv.latent_bytes_per_token": pool.nbytes / (pool.shape[2] * pool.shape[3])}
 
     @staticmethod
@@ -541,9 +513,9 @@ class PanguUltra:
         return jnp.einsum("bhs,bsr->bhr", prob, lat[..., :c.kv_lora_rank])
 
     @staticmethod
-    def decode_step_paged(config: PanguUltraConfig, params: PanguUltraParams, token: Array, cache: LatentKVCache,
+    def decode_step_paged(config: PanguUltraConfig, params: PanguUltraParams, token: Array, cache: ServeCache,
                           page_table: Array, lengths: Array, active: Array,
-                          attn_impl: str = "auto", mesh=None, split_k: int = 1) -> tp.Tuple[Array, LatentKVCache]:
+                          attn_impl: str = "auto", mesh=None, split_k: int = 1) -> tp.Tuple[Array, ServeCache]:
         """One decode step for B requests at B positions (GPT.decode_step_paged's
         contract). Slot b writes its token's latent row at position lengths[b]
         in every layer and attends, ABSORBED, to lengths[b] + 1 cached rows.
@@ -555,12 +527,12 @@ class PanguUltra:
             raise NotImplementedError(f"{FAMILY}: no serving mesh")
         c = config
         attn_impl = resolve_paged_impl(attn_impl)
-        ps, pool = cache.page_size, cache.kv
+        ps, pool = cache.page_size, cache.pools[0][0]
         pos = lengths
         counts = jnp.maximum(active.astype(jnp.int32) * (pos + 1), 1)  # (B,)
         rope = PanguUltra._rope(c)
         write_pages = jnp.where(active, jnp.take_along_axis(page_table, (pos // ps)[:, None], axis=1)[:, 0], pool.shape[2])
-        moe_counts, totals = cache.moe_counts, cache.moe_totals
+        moe_counts, totals = cache.counters
         x = _embed(params, token[:, None])  # (B, 1, D)
         n_moe = 0
         for i, p in enumerate(params.layers):
@@ -590,12 +562,12 @@ class PanguUltra:
                 n_moe += 1
         totals = totals.at[0].add(1)
         logits = PanguUltra._head(c, params, x)[:, 0]
-        return logits, LatentKVCache(kv=pool, moe_counts=moe_counts, moe_totals=totals)
+        return logits, ServeCache(pools=((pool,),), counters=(moe_counts, totals))
 
     @staticmethod
     def prefill_paged_chunk(config: PanguUltraConfig, params: PanguUltraParams, tokens: Array, start: Array,
-                            n_valid: Array, cache: LatentKVCache, page_table: Array,
-                            attn_impl: str = "auto", mesh=None) -> tp.Tuple[Array, LatentKVCache]:
+                            n_valid: Array, cache: ServeCache, page_table: Array,
+                            attn_impl: str = "auto", mesh=None) -> tp.Tuple[Array, ServeCache]:
         """One request's prompt chunk [start, start + n_valid) into its pages
         (the ONE-ROW call of models/__init__.py: tokens (1, T), scalar start /
         n_valid, `page_table` the slot's (1, pages) row). Returns (logits of
@@ -607,14 +579,14 @@ class PanguUltra:
         c = config
         attn_impl = resolve_paged_impl(attn_impl)
         _, T = tokens.shape
-        ps, pool = cache.page_size, cache.kv
+        ps, pool = cache.page_size, cache.pools[0][0]
         t_idx = jnp.arange(T, dtype=jnp.int32)
         positions = start + t_idx
         valid = t_idx < n_valid
         counts = jnp.minimum(positions, start + n_valid - 1) + 1  # pad rows see what the last valid row sees
         rope = PanguUltra._rope(c)
         write_pages = jnp.where(valid, jnp.take(page_table[0], positions // ps, axis=0), pool.shape[2])
-        totals = cache.moe_totals
+        moe_counts, totals = cache.counters
         x = _embed(params, tokens)  # (1, T, D)
         for i, p in enumerate(params.layers):
             with jax.named_scope("attn"), jax.named_scope("attn_latent"):
@@ -631,7 +603,7 @@ class PanguUltra:
                 totals = moe_count_dropped(totals, stats["dropped"])
         last = jax.lax.dynamic_slice_in_dim(x, jnp.maximum(n_valid - 1, 0), 1, axis=1)  # (1, 1, D)
         logits = PanguUltra._head(c, params, last)
-        return logits, LatentKVCache(kv=pool, moe_counts=cache.moe_counts, moe_totals=totals)
+        return logits, ServeCache(pools=((pool,),), counters=(moe_counts, totals))
 
     @staticmethod
     def _prefill_sweep(c: PanguUltraConfig, p: MLAParams, q: Array, pool: Array, li: int, table_row: Array,

@@ -34,10 +34,11 @@ renormalised, times `route_scale`) over the experts HELD here (`[expert_offset,
 expert_offset + n_experts_held)`: all 128 in the benchmark's cell), plus the
 shared expert, which every chip of a deployment computes alike.
 
-Serving state is `models/mimo_v2.py`'s `MimoKVCache`: a `global` pool (the
-whole context) and a `window` pool (the last `sliding_window` tokens; the
-engine frees a window page once every future query's window has passed it),
-both 4 heads of 128 lanes.
+Serving state is `models/mimo_v2.py`'s two kinds (models/gpt.py `ServeCache`:
+`pools` = ((global K, V), (window K, V))): a `global` pool (the whole context)
+and a `window` pool (the last `sliding_window` tokens; the engine frees a
+window page once every future query's window has passed it), both 4 heads of
+128 lanes.
 
 Attention on the paged path:
   decode, global   kernels/attention_template.py (TPU; groups of 8 q rows a kv
@@ -66,9 +67,9 @@ import typing as tp
 import jax
 import jax.numpy as jnp
 
-from midgpt_tpu.models.gpt import CacheKind, _paged_write, pool_lanes
+from midgpt_tpu.models.gpt import CacheKind, ServeCache, _paged_write
 from midgpt_tpu.models.mimo_v2 import (
-    GLOBAL, WINDOW, MimoKVCache, SwiGLUParams, paged_gather_attention, prefill_sweep,
+    GLOBAL, WINDOW, SwiGLUParams, paged_gather_attention, prefill_sweep,
 )
 from midgpt_tpu.ops.attention import visible_mask
 from midgpt_tpu.ops.moe import (
@@ -474,34 +475,27 @@ class Trinity:
 
     @staticmethod
     def init_cache(config: TrinityConfig, num_pages: tp.Sequence[int], page_size: int = 8,
-                   dtype=jnp.bfloat16, kernel_layout: bool = False) -> MimoKVCache:
-        """Zeroed pools, `num_pages[i]` pages for kind i of `cache_kinds`."""
-        if jnp.dtype(dtype) == jnp.int8:
-            raise NotImplementedError(f"{FAMILY}: no int8 pool (no quantised write or read over two kinds of pool)")
+                   dtype=jnp.bfloat16, kernel_layout: bool = False) -> ServeCache:
+        """Zeroed K and V pools, `num_pages[i]` pages for kind i of
+        `cache_kinds`. Counters: the expert layers' `(moe_counts, moe_totals)`."""
         c = config
-        lanes = pool_lanes(c.head_dim) if kernel_layout else c.head_dim
-
-        def pools(kind: str, pages: int):
-            shape = (len(c.layers_of(kind)), c.n_kv_heads, pages, page_size, lanes)
-            return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
-
-        moe_counts, moe_totals = moe_counters_init(len(c.moe_layers), c.n_experts_held)
-        return MimoKVCache.of({GLOBAL: pools(GLOBAL, num_pages[0]), WINDOW: pools(WINDOW, num_pages[1])},
-                              moe_counts, moe_totals)
+        k_and_v = lambda kind: ((len(c.layers_of(kind)), c.n_kv_heads, c.head_dim),) * 2
+        return ServeCache.zeros(FAMILY, (k_and_v(GLOBAL), k_and_v(WINDOW)), num_pages, page_size, dtype, kernel_layout,
+                                moe_counters_init(len(c.moe_layers), c.n_experts_held))
 
     kernel_sweep_whole = False  # the window layers run the template too, at another geometry: the sweep below is one of two
 
     @staticmethod
-    def kernel_sweep(config: TrinityConfig, cache: MimoKVCache) -> tp.Tuple[tp.Tuple[int, ...], int, int, int]:
+    def kernel_sweep(config: TrinityConfig, cache: ServeCache) -> tp.Tuple[tp.Tuple[int, ...], int, int, int]:
         """(pool shape, q rows a pool head, window, sinks) of the decode
         kernel's sweep, for the engine's block counters: the GLOBAL layers'
         (the window layers' grid is not counted)."""
-        return cache.gk.shape, config.n_head // config.n_kv_heads, 0, 0
+        return cache.pools[0][0].shape, config.n_head // config.n_kv_heads, 0, 0
 
     @staticmethod
-    def serve_counters(config: TrinityConfig, cache: MimoKVCache) -> tp.Dict[str, float]:
+    def serve_counters(config: TrinityConfig, cache: ServeCache) -> tp.Dict[str, float]:
         """The expert layers' counters (ops/moe.py `moe_serve_counters`)."""
-        return moe_serve_counters(cache.moe_counts, cache.moe_totals)
+        return moe_serve_counters(*cache.counters)
 
     @staticmethod
     def _gather_attention(c: TrinityConfig, kind: str, q, k_pool, v_pool, li, ids, col0, counts) -> Array:
@@ -509,9 +503,9 @@ class Trinity:
                                       window=c.window_of(kind))
 
     @staticmethod
-    def decode_step_paged(config: TrinityConfig, params: TrinityParams, token: Array, cache: MimoKVCache,
+    def decode_step_paged(config: TrinityConfig, params: TrinityParams, token: Array, cache: ServeCache,
                           page_table: tp.Tuple[Array, Array], lengths: Array, active: Array,
-                          attn_impl: str = "auto", mesh=None, split_k: int = 1) -> tp.Tuple[Array, MimoKVCache]:
+                          attn_impl: str = "auto", mesh=None, split_k: int = 1) -> tp.Tuple[Array, ServeCache]:
         """One decode step for B requests at B positions (GPT.decode_step_paged's
         contract). `page_table` is (global table, window table), both (B,
         pages) and LOGICAL (column j holds positions [j * ps, (j + 1) * ps));
@@ -532,7 +526,7 @@ class Trinity:
         pos = lengths
         counts = jnp.maximum(active.astype(jnp.int32) * (pos + 1), 1)  # (B,)
         rope = rope_table(c.head_dim, c.block_size, c.rope_theta)
-        pools = cache.pools()
+        pools = dict(zip((GLOBAL, WINDOW), cache.pools))
         write_pages = {
             kind: jnp.where(active, jnp.take_along_axis(t, (pos // ps)[:, None], axis=1)[:, 0], pools[kind][0].shape[2])
             for kind, t in tables.items()
@@ -542,7 +536,7 @@ class Trinity:
             n_win = min(MP, -(-W // ps) + 1)
             first = jnp.minimum(jnp.maximum(counts - W, 0) // ps, MP - n_win)
             win_ids = jnp.take_along_axis(tables[WINDOW], first[:, None] + jnp.arange(n_win, dtype=jnp.int32), axis=1)
-        moe_counts, totals = cache.moe_counts, cache.moe_totals
+        moe_counts, totals = cache.counters
         x = Trinity._embed(c, params, token[:, None])  # (B, 1, D)
         n_moe = 0
         for i, (p, (kind, li)) in enumerate(zip(params.layers, c.pool_layers)):
@@ -570,12 +564,12 @@ class Trinity:
                 n_moe += 1
         totals = totals.at[0].add(1)
         logits = Trinity._head(c, params, x)[:, 0]
-        return logits, MimoKVCache.of(pools, moe_counts, totals)
+        return logits, ServeCache(pools=(pools[GLOBAL], pools[WINDOW]), counters=(moe_counts, totals))
 
     @staticmethod
     def prefill_paged_chunk(config: TrinityConfig, params: TrinityParams, tokens: Array, start: Array,
-                            n_valid: Array, cache: MimoKVCache, page_table: tp.Tuple[Array, Array],
-                            attn_impl: str = "auto", mesh=None) -> tp.Tuple[Array, MimoKVCache]:
+                            n_valid: Array, cache: ServeCache, page_table: tp.Tuple[Array, Array],
+                            attn_impl: str = "auto", mesh=None) -> tp.Tuple[Array, ServeCache]:
         """The prompt chunks of B slots, row b's being [start[b], start[b] +
         n_valid[b]), into their pages of both pools (GPT.prefill_paged_chunk's
         contract; `page_table` is (global table, window table), both (B,
@@ -615,7 +609,7 @@ class Trinity:
         # pad rows see what the slot's last valid row sees; an empty row, nothing
         counts = jnp.minimum(positions, (start + n_valid)[:, None] - 1) + 1
         rope = rope_table(c.head_dim, c.block_size, c.rope_theta)
-        pools = cache.pools()
+        pools = dict(zip((GLOBAL, WINDOW), cache.pools))
         write_pages = {
             kind: jnp.where(valid, jnp.take_along_axis(t, positions // ps, axis=1), pools[kind][0].shape[2])
             for kind, t in tables.items()
@@ -624,7 +618,7 @@ class Trinity:
         n_win = min(MP, -(-(W + T) // ps) + 1)
         first = jnp.minimum(jnp.maximum(start + 1 - W, 0) // ps, MP - n_win)  # (B,)
         win_ids = jnp.take_along_axis(tables[WINDOW], first[:, None] + jnp.arange(n_win, dtype=jnp.int32), axis=1)
-        totals, token_rows = cache.moe_totals, valid.reshape(-1)
+        (moe_counts, totals), token_rows = cache.counters, valid.reshape(-1)
         x = Trinity._embed(c, params, tokens)  # (B, T, D)
         for i, (p, (kind, li)) in enumerate(zip(params.layers, c.pool_layers)):
             with jax.named_scope("attn"), jax.named_scope("attn_" + kind):
@@ -646,4 +640,5 @@ class Trinity:
                 totals = moe_count_dropped(totals, stats["dropped"])
         last = jnp.take_along_axis(x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)  # (B, 1, D)
         logits = Trinity._head(c, params, last)
-        return logits if one_row else logits[:, 0], MimoKVCache.of(pools, cache.moe_counts, totals)
+        cache = ServeCache(pools=(pools[GLOBAL], pools[WINDOW]), counters=(moe_counts, totals))
+        return logits if one_row else logits[:, 0], cache

@@ -1,10 +1,14 @@
 """The paged pool's one owner: its format, its size, its books, its page tables.
 
-`models/gpt.py` defines what a pool IS (`PagedKVCache`: K and V of
-(L, H, P, ps, C), int8 scale side buffers of (L, P, H, ps), page 0 the
-sink) and the kernels read it. Everything the HOST does with pages happens
-here, so that a change of layout, of the sizing rule or of the conservation
-law has one place to be made:
+`models/gpt.py` defines what a pool IS and the kernels read it: the GPT's
+`PagedKVCache` (K and V of (L, H, P, ps, C), int8 scale side buffers of
+(L, P, H, ps), page 0 the sink) and, for every other served family,
+`ServeCache` (per paged kind a tuple of arrays in that same five-axis layout,
+a state kind's rows, the family's counters). Two types: what MOVES pages
+below (`take_pages`, `adopt_pages`, `migrate`, `poison`) knows the GPT's
+alone, and the engine refuses those operations for the others by name.
+Everything the HOST does with pages happens here, so that a change of layout,
+of the sizing rule or of the conservation law has one place to be made:
 
   * the format, as far as the host moves whole pages: `take_pages` (device
     pool -> host blocks `{'k', 'v'[, 'k_scale', 'v_scale']}`),
@@ -25,7 +29,7 @@ law has one place to be made:
 State kinds. A family may also name a kind of cache that is NOT paged
 (`models/gpt.py` `StateKind`: a recurrent layer's state, models/olmo_hybrid.py):
 one ROW a slot, as large for a prompt of ten tokens as for one of ten thousand,
-held in the family's cache under `state` (arrays whose SECOND axis is the row).
+held in `ServeCache.state` (arrays whose SECOND axis is the row).
 It is this module's like the pages are: sized here (`max_slots` rows and a sink
 row, the last, which an empty place of a prefill call names), handed out here
 (`claim_state`: slot i's row is row i, so that a decode step over the slots in
@@ -196,9 +200,9 @@ def adopt_pages(mesh, cache: PagedKVCache, dst: tp.Sequence[int], blocks: Blocks
 def keep_state(new, old):
     """`new` with `old`'s state rows: what a program that must commit nothing
     hands back (`ServeEngine.next_logits`: a K/V write is repeated by the round
-    that follows, a state update applied twice is a wrong state). A cache
-    without a state kind as it is."""
-    return dataclasses.replace(new, state=old.state) if hasattr(old, "state") else new
+    that follows, a state update applied twice is a wrong state). The GPT's
+    `PagedKVCache` has no state and passes as it is."""
+    return new if isinstance(new, PagedKVCache) else dataclasses.replace(new, state=old.state)
 
 
 class PagePool:

@@ -631,7 +631,7 @@ def test_two_kind_serving_program_never_relays_out_either_pool(program, one_chip
     # 4,097 global pages: a V pool of 134 MB. At 2,049 (67 MB) the compiler may park the WHOLE toy pool in VMEM around
     # the prefill program (a copy-start / copy-done pair in one layout: no relayout, and nothing a cell's GBs can meet)
     cache = jax.tree.map(sds, jax.eval_shape(lambda: model.init_cache(mc, (4097, 673), 32, jnp.bfloat16, kernel_layout=True)))
-    assert cache.gk.shape[-1] == 256 and cache.gv.shape[-1] == 128  # K 192 at whole lanes, V 128
+    assert cache.pools[0][0].shape[-1] == 256 and cache.pools[0][1].shape[-1] == 128  # K 192 at whole lanes, V 128
     arr = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     B, T = 32, 64
     if program == "decode8":
@@ -675,7 +675,7 @@ def test_window_layers_decode_through_the_kernel_at_the_published_geometry(progr
     sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
     params = jax.tree.map(sds, jax.eval_shape(lambda k: model.cast_params(model.init(mc, k), jnp.bfloat16), jax.random.PRNGKey(0)))
     cache = jax.tree.map(sds, jax.eval_shape(lambda: model.init_cache(mc, (20481, 5185), 32, jnp.bfloat16, kernel_layout=True)))
-    assert cache.gk.shape == (1, 4, 20481, 32, 128) and cache.wk.shape == (4, 4, 5185, 32, 128)
+    assert cache.pools[0][0].shape == (1, 4, 20481, 32, 128) and cache.pools[1][0].shape == (4, 4, 5185, 32, 128)
     arr = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     B, T = 64, 512
     if program == "decode8":
@@ -1005,7 +1005,7 @@ def test_state_kind_serving_programs_keep_pools_and_state_rows_in_place(program,
     params = jax.tree.map(sds, jax.eval_shape(lambda k: model.cast_params(model.init(mc, k), jnp.bfloat16), jax.random.PRNGKey(0)))
     B = 24
     cache = jax.tree.map(sds, jax.eval_shape(lambda: model.init_cache(mc, (B * 112 + 1, B + 1), 32, jnp.bfloat16, kernel_layout=True)))
-    assert cache.k.shape == (4, 30, 2689, 32, 128) and [a.shape for a in cache.state] == [(12, 25, 30, 192, 96), (12, 25, 3 * 11520)]
+    assert cache.pools[0][0].shape == (4, 30, 2689, 32, 128) and [a.shape for a in cache.state] == [(12, 25, 30, 192, 96), (12, 25, 3 * 11520)]
     arr = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     if program == "decode8":
         lowered = serve._serve_decode_chunk.lower(

@@ -21,7 +21,8 @@ import numpy as np
 import pytest
 
 from midgpt_tpu.models import dots3
-from midgpt_tpu.models.dots3 import FULL, LATENT, SLIDING, WINDOW_LATENT, Dots3, Dots3Config, KindsKVCache
+from midgpt_tpu.models.dots3 import FULL, LATENT, SLIDING, WINDOW_LATENT, Dots3, Dots3Config
+from midgpt_tpu.models.gpt import ServeCache
 from midgpt_tpu.sampling.serve import ServeEngine
 from test_mimo_v2 import ROOT, _load, _tokens
 
@@ -307,7 +308,7 @@ def test_engine_prefill_then_decode_match_the_reference_past_topk_and_window(mod
     pages = lambda p: -(-(p + 13 - 1) // 4)  # the last sampled token is never fed
     got, seqs, eng = _serve(c, params, {p: _tokens(p, seed=p) for p in (37, 50, 11)}, 13, max_slots=2,
                             num_pages=pages(37) + pages(50) + 1)
-    assert isinstance(eng.cache, KindsKVCache) and [k.name for k in eng.kinds] == [LATENT, WINDOW_LATENT]
+    assert isinstance(eng.cache, ServeCache) and [k.name for k in eng.kinds] == [LATENT, WINDOW_LATENT]
     lat, idx, wlat = eng.cache.pool_arrays()
     assert lat.shape[:2] == idx.shape[:2] == (2, 1) and wlat.shape[:2] == (3, 1) and lat.shape[2] == idx.shape[2] != wlat.shape[2]
     assert (lat.shape[-1], idx.shape[-1], wlat.shape[-1]) == (16 + 8, 16, 32 + 8) and eng.prefill_width == 1

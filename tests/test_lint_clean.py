@@ -93,7 +93,8 @@ def test_default_roots_exclude_tests():
 def test_the_serving_stack_imports_point_one_way():
     """sampling/pages.py sits under sampling/serve.py, and both under what is
     built on the engine (the topology layers, the static analyser): an import
-    of one of those, at module level or inside a function, puts a cycle back."""
+    of one of those, at module level or inside a function, puts a cycle back.
+    No file of models/ imports sampling/."""
     import ast
 
     def imported(rel):
@@ -117,3 +118,9 @@ def test_the_serving_stack_imports_point_one_way():
         assert len(names) > 5, rel
         bad = sorted(n for n in names if any(n == b or n.startswith(b + ".") for b in banned))
         assert not bad, f"{rel} imports {bad}"
+    # models/ sits under sampling/: a family's step CONSTRUCTS the cache it returns (models/gpt.py `ServeCache`)
+    families = sorted(f for f in os.listdir(os.path.join(_repo_root(), "midgpt_tpu", "models")) if f.endswith(".py"))
+    assert len(families) >= 9, families
+    for f in families:
+        bad = sorted(n for n in imported(f"midgpt_tpu/models/{f}") if (n + ".").startswith("midgpt_tpu.sampling."))
+        assert not bad, f"midgpt_tpu/models/{f} imports {bad}"

@@ -230,9 +230,9 @@ def test_window_pool_is_sized_from_window_chunk_and_page(model):
     eng = ServeEngine(c, params, max_slots=5, page_size=4, prefill_chunk=10, decode_chunk=4, cache_dtype="float32")
     assert [k.name for k in eng.kinds] == [GLOBAL, WINDOW]
     assert eng.allocators[1].num_pages == 1 + 5 * (-(-(8 + 10) // 4) + 1)
-    assert eng.cache.wk.shape[2] == eng.allocators[1].num_pages and eng.cache.gk.shape[2] == eng.allocator.num_pages
-    assert eng.cache.gk.shape[:2] == (2, 1) and eng.cache.wk.shape[:2] == (5, 2)
-    assert eng.cache.gk.shape[-1] == 24 and eng.cache.gv.shape[-1] == 16
+    assert eng.cache.pools[1][0].shape[2] == eng.allocators[1].num_pages and eng.cache.pools[0][0].shape[2] == eng.allocator.num_pages
+    assert eng.cache.pools[0][0].shape[:2] == (2, 1) and eng.cache.pools[1][0].shape[:2] == (5, 2)
+    assert eng.cache.pools[0][0].shape[-1] == 24 and eng.cache.pools[0][1].shape[-1] == 16
 
 
 @pytest.mark.parametrize("what,kw", [

@@ -337,7 +337,7 @@ def test_a_decode_round_leaves_an_inactive_slots_row_bit_for_bit(model):
         np.testing.assert_array_equal(x[:, 1], before[:, 1])
         np.testing.assert_array_equal(x[:, 3], before[:, 3])  # the sink row: no decode step touches it
         assert not np.array_equal(x[:, 0], before[:, 0]) and not np.array_equal(x[:, 2], before[:, 2])
-    assert int(after.gdn_counts[0]) == 2
+    assert int(after.counters[0][0]) == 2
 
 
 def test_the_books_hold_the_state_kind_through_evict_and_cancel(model):

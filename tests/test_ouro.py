@@ -111,8 +111,8 @@ def test_published_preset_counts_its_parameters_and_its_cache():
     assert Ouro.count_params(shapes) == 2_667_974_657
     assert {a.shape[0] for a in jax.tree.leaves(shapes.layers)} == {48}  # every layer leaf stacked
     cache = jax.eval_shape(lambda: Ouro.init_cache(mc, (157,), 32, jnp.bfloat16, kernel_layout=True))
-    assert cache.k.shape == cache.v.shape == (192, 16, 157, 32, 128)
-    assert 2 * cache.k.size * 2 == 157 * 32 * 1_572_864  # bytes: 7.90 GB, 1,572,864 B a token
+    assert [a.shape for a in cache.pool_arrays()] == [(192, 16, 157, 32, 128)] * 2
+    assert 2 * cache.pools[0][0].size * 2 == 157 * 32 * 1_572_864  # bytes: 7.90 GB, 1,572,864 B a token
     # forward FLOPs a token at one key: 2 x (4 x (the layers' matrices + a key's scores and values + the gate) + the head)
     assert Ouro.flops_per_token(mc, 1) == 2.0 * (4 * (2_466_250_752 + 48 * 2048 + 2048) + 49152 * 2048)
     model = dataclasses.asdict(mc)
@@ -205,17 +205,17 @@ def test_a_pass_reads_its_own_cache_layers_and_no_other(model):
     L = c.n_layer
     seq = _tokens(18, seed=7)
     _, cache, table = _prefilled(c, params, seq[:17], 8, False, pages=5)  # 18 tokens: pages 1..5
-    assert cache.k.shape[0] == c.n_loop * L == 12
+    assert cache.pools[0][0].shape[0] == c.n_loop * L == 12
     step = lambda cfg, cache: np.asarray(Ouro.decode_step_paged(
         cfg, params, jnp.asarray(seq[17:18]), cache, table, jnp.asarray([17]), jnp.asarray([True]), attn_impl="gather")[0])[0]
-    poison = lambda rows: dataclasses.replace(cache, k=cache.k.at[rows].set(jnp.nan), v=cache.v.at[rows].set(jnp.nan))
+    poison = lambda rows: dataclasses.replace(cache, pools=(tuple(a.at[rows].set(jnp.nan) for a in cache.pools[0]),))
     clean = step(c, cache)
     c1 = dataclasses.replace(c, n_loop=1)
     one = step(c1, poison(slice(L, None)))
     np.testing.assert_allclose(one, np.asarray(reference.forward(params, jnp.asarray(seq), dataclasses.asdict(c), n_loop=1)[0])[17], atol=2e-5)
     for r in range(c.n_loop):
         assert np.isnan(step(c, poison(slice(r * L, (r + 1) * L)))).all(), r
-    unnamed = dataclasses.replace(cache, k=cache.k.at[:, :, 6:].set(jnp.nan), v=cache.v.at[:, :, 6:].set(jnp.nan))
+    unnamed = dataclasses.replace(cache, pools=(tuple(a.at[:, :, 6:].set(jnp.nan) for a in cache.pools[0]),))
     np.testing.assert_array_equal(step(c, unnamed), clean)
 
 
@@ -225,10 +225,10 @@ def test_inactive_slots_and_empty_rows_write_nothing(model):
     table = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
     _, after = Ouro.decode_step_paged(c, params, jnp.asarray([3, 4]), cache, table, jnp.asarray([0, 0]),
                                        jnp.asarray([False, False]), attn_impl="gather")
-    assert not np.asarray(after.k).any() and Ouro.serve_counters(c, after)["loop.passes_run"] == 0
+    assert not np.asarray(after.pools[0][0]).any() and Ouro.serve_counters(c, after)["loop.passes_run"] == 0
     _, after = Ouro.prefill_paged_chunk(c, params, jnp.zeros((2, 8), jnp.int32), jnp.asarray([0, 0]), jnp.asarray([0, 5]),
                                         cache, table, attn_impl="gather")
-    k = np.asarray(after.k)
+    k = np.asarray(after.pools[0][0])
     assert not k[:, :, 1:5].any() and k[:, :, 5:7].any() and not k[:, :, 7:].any() and not k[:, :, 0].any()
 
 

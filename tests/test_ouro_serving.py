@@ -173,7 +173,7 @@ def test_serving_programs_hold_no_pool_sized_copy_in_any_loop(program):
         else:
             low = serve._serve_prefill_chunk.lower(c, params, arr((B, 8)), arr((B,)), arr((B,)), cache, arr((B, T)), None, "gather",
                                                    0.8, None, None, key)
-        return low, "f32[%s]" % ",".join(map(str, cache.k.shape))
+        return low, "f32[%s]" % ",".join(map(str, cache.pools[0][0].shape))
 
     c = toy()
     low, pool = lowered(c)

@@ -1,7 +1,8 @@
 """sampling/pages.py, the paged pool's one owner, on its own: the page
 transport's round trip in every pool format, the page tables, the window rule
 with the conservation law on a two-kind pool, and the sizing rule at the
-serving cells' engine shapes. CPU, toy widths. The engine over it:
+serving cells' engine shapes, and the one cache class every family beside the
+GPT answers `init_cache` with. CPU, toy widths. The engine over it:
 tests/test_serving.py and the families' *_serving.py."""
 
 import dataclasses
@@ -9,12 +10,13 @@ import json
 import os
 import types
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from midgpt_tpu.config import load_config
-from midgpt_tpu.models.gpt import CacheKind, GPTConfig, PagedKVCache
+from midgpt_tpu.models.gpt import CacheKind, GPTConfig, PagedKVCache, ServeCache, pool_lanes
 from midgpt_tpu.sampling.pages import (
     PagePool,
     adopt_pages,
@@ -182,3 +184,50 @@ def test_sizing_rule_gives_the_serving_cells_their_page_counts(cell, pages, monk
     )
     assert [a.num_pages for a in pool.allocators] == pages
     assert len(pool.state_kinds) == (cell == "serve_olmo_hybrid_docchat") and len(pool.kinds) == len(pages)
+
+
+# (e) the served families beside the GPT, each at its cell's rehearsal widths (benchmarks/configs/<file>.json)
+FAMILY_FILES = {"mimo_v2": "mimo_v2_5_ep16", "trinity": "trinity_mini_pp", "pangu_ultra": "openpangu_ultra_moe_ep16",
+                "ouro": "ouro_2p6b", "dots3": "dots3_note_ep16", "olmo_hybrid": "olmo_hybrid_7b_pp2"}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILY_FILES))
+def test_every_family_answers_init_cache_with_the_one_class(family):
+    """`init_cache` returns models/gpt.py `ServeCache`: kind i of `cache_kinds`
+    gets `num_pages[i]` pages in every one of its arrays, the five-axis layout
+    with the kernel path's lanes at `pool_lanes` of the width; a state kind's
+    arrays have its shapes with the row axis second; the leaves are pools,
+    then state, then counters (the order the serving programs' parameters
+    keep, and the parent's five classes had); int8 is refused by name. Under
+    `jax.eval_shape`: no array is made and nothing compiles."""
+    with open(os.path.join(ROOT, "benchmarks", "configs", FAMILY_FILES[family] + ".json")) as f:
+        cfg = json.load(f)
+    mc = dataclasses.replace(load_config(cfg["repo_config"]).model_config, **cfg["rehearsal"]["overrides"]["model_config"])
+    model = mc.model()
+    assert model.__module__ == f"midgpt_tpu.models.{family}"
+    kinds = model.cache_kinds(mc)
+    paged = [k for k in kinds if isinstance(k, CacheKind)]
+    state_kinds = kinds[len(paged):]
+    assert paged and len(state_kinds) == (family == "olmo_hybrid")
+    pages, rows, ps = [11, 7][: len(paged)], 5, 4
+    counts = pages + [rows] * len(state_kinds)
+    cache, lanes = (jax.eval_shape(lambda kl=kl: model.init_cache(mc, counts, ps, jnp.bfloat16, kernel_layout=kl))
+                    for kl in (False, True))
+    assert type(cache) is ServeCache and type(lanes) is ServeCache
+    assert cache.page_size == ps and cache.num_pages == pages[0]
+
+    assert len(cache.pools) == len(paged) and all(len(kind) >= 1 for kind in cache.pools)
+    assert all(x is y for x, y in zip(cache.pool_arrays(), [a for kind in cache.pools for a in kind], strict=True))
+    for kind, kind_lanes, n in zip(cache.pools, lanes.pools, pages, strict=True):
+        for a, b in zip(kind, kind_lanes, strict=True):
+            assert a.ndim == 5 and a.shape[2:4] == (n, ps) and a.dtype == jnp.bfloat16
+            assert b.shape == (*a.shape[:4], pool_lanes(a.shape[4])) and b.dtype == a.dtype
+
+    shapes = [sd for k in state_kinds for sd in k.shapes(jnp.bfloat16)]
+    assert [(a.shape, a.dtype) for a in cache.state] == [((s[0], rows, *s[1:]), jnp.dtype(d)) for s, d in shapes]
+    assert isinstance(cache.state, tuple) and isinstance(cache.counters, tuple) and len(cache.counters) >= 1
+    assert all(c.ndim <= 2 for c in cache.counters)
+    assert all(x is y for x, y in zip(jax.tree.leaves(cache), [*cache.pool_arrays(), *cache.state, *cache.counters], strict=True))
+
+    with pytest.raises(NotImplementedError, match="int8"):
+        model.init_cache(mc, counts, ps, jnp.int8)

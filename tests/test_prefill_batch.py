@@ -104,7 +104,7 @@ def test_batched_rows_equal_one_row_calls_in_turn(variant):
     for a, b in zip(got_cache.pool_arrays(), cache.pool_arrays()):
         np.testing.assert_array_equal(np.asarray(a[:, :, 0]), np.asarray(b[:, :, 0]))
     if two_kinds:  # nothing dropped, and the rows that are no tokens counted nowhere
-        assert int(got_cache.moe_totals[2]) == 0 and np.isfinite(np.asarray(got)).all()
+        assert int(got_cache.counters[1][2]) == 0 and np.isfinite(np.asarray(got)).all()
 
 
 def _trinity_cell():

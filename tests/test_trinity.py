@@ -15,7 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from midgpt_tpu.models.mimo_v2 import GLOBAL, WINDOW, MimoKVCache
+from midgpt_tpu.models.gpt import ServeCache
+from midgpt_tpu.models.mimo_v2 import GLOBAL, WINDOW
 from midgpt_tpu.models.trinity import FULL, SLIDING, Trinity, TrinityConfig
 from midgpt_tpu.ops.moe import route
 from midgpt_tpu.sampling.serve import ServeEngine
@@ -157,8 +158,8 @@ def test_engine_prefill_then_decode_match_the_reference_past_the_window(model):
     first, later = {}, {}
     eng = ServeEngine(c, params, max_slots=3, page_size=4, prefill_chunk=10, decode_chunk=4, temperature=0.8, seed=5,
                       cache_dtype="float32", on_first_logits=lambda uid, row: first.setdefault(uid, np.array(row)))
-    assert isinstance(eng.cache, MimoKVCache) and [k.name for k in eng.kinds] == [GLOBAL, WINDOW]
-    assert eng.cache.gk.shape[:2] == (1, 2) and eng.cache.wk.shape[:2] == (4, 2)
+    assert isinstance(eng.cache, ServeCache) and [k.name for k in eng.kinds] == [GLOBAL, WINDOW]
+    assert eng.cache.pools[0][0].shape[:2] == (1, 2) and eng.cache.pools[1][0].shape[:2] == (4, 2)
     assert eng.prefill_width == 3 == eng.max_slots  # the toy's 16 experts at top-4 ask for 512 token rows: every slot rides
     uids = {eng.submit(_tokens(p, seed=p), 13): p for p in (37, 50, 11)}
     assert 50 > 2 * c.sliding_window + eng.prefill_chunk
@@ -219,7 +220,7 @@ def test_window_decode_through_the_kernel_never_reads_behind_the_window(model, m
     active = jnp.asarray([True, True, False])
     pools = jax.random.normal(jax.random.PRNGKey(8), (4, 4, 2, 3 * MP + 1, ps, c.head_dim))
     cache = dataclasses.replace(Trinity.init_cache(c, (3 * MP + 1, 3 * MP + 1), ps, jnp.float32),
-                                gk=pools[0, :1], gv=pools[1, :1], wk=pools[2], wv=pools[3])
+                                pools=((pools[0, :1], pools[1, :1]), (pools[2], pools[3])))
     table = 1 + np.arange(3 * MP, dtype=np.int32).reshape(3, MP)
     poisoned = table.copy()
     for b, n in enumerate((39, 36)):
