@@ -27,14 +27,15 @@ from __future__ import annotations
 import re
 import typing as tp
 
-# jit_cache_size, pool_relayouts and rotary_gathers live in utils/hlo.py (the
-# serving engine reads the first two and imports nothing of analysis/); they
-# are this module's too.
+# jit_cache_size, pool_relayouts, weight_copies and rotary_gathers live in
+# utils/hlo.py (the serving engine reads the first three and imports nothing
+# of analysis/); they are this module's too.
 from midgpt_tpu.utils.hlo import (
     hlo_computations,
     jit_cache_size,
     pool_relayouts,
     rotary_gathers,
+    weight_copies,
     while_body_names,
 )
 
@@ -785,11 +786,10 @@ def run_audit() -> tp.Dict[str, tp.Any]:
 
         smesh = make_serve_mesh(tp_size=g.tp)
         report["tp_mesh"] = budgets.tp_mesh_shape(g)
-        # head-aligned qkv shards need the split3 einsum order — the same
-        # config switch ServeEngine(mesh=...) makes (training/train.py)
-        mc3 = dataclasses.replace(mc, qkv_proj="split3")
-        mc3_scan = dataclasses.replace(mc_scan, qkv_proj="split3")
-        draft3_cfg = dataclasses.replace(draft_cfg, qkv_proj="split3")
+        # the configs as an engine is handed them: head-aligned qkv shards
+        # need the split3 einsum order, and the serving layer loop takes it
+        # under a tp > 1 mesh, scanned (`tp_verify`) or unrolled
+        # (GPT._decode_layer_loop)
 
         def _shard_abs(tree, specs):
             return jax.tree.map(
@@ -818,19 +818,19 @@ def run_audit() -> tp.Dict[str, tp.Any]:
         # One lowering per budgets.TP_PROGRAMS entry; the per-program
         # all-reduce budget comes from the manifest, not from literals here.
         tp_lowered = {
-            "tp_decode": _decode_lower(mc3, cache_tp),
-            "tp_decode_int8": _decode_lower(mc3, cache8_tp),
+            "tp_decode": _decode_lower(mc, cache_tp),
+            "tp_decode_int8": _decode_lower(mc, cache8_tp),
             # split-K under tp: the partition scan rides INSIDE each head
             # shard — the all-reduce budget must not move by a single op
-            "tp_decode_split": _decode_lower(mc3, cache_tp, split_k=g.split_k),
+            "tp_decode_split": _decode_lower(mc, cache_tp, split_k=g.split_k),
             "tp_verify": _spec_verify_chunk.lower(
-                mc3_scan, params_tp, sds((B,), i32), sds((K, B), i32),
+                mc_scan, params_tp, sds((B,), i32), sds((K, B), i32),
                 sds((K, B, mc.vocab_size), jnp.float32), cache_tp,
                 sds((B, max_pages), i32), sds((B,), i32), sds((B,), b1),
                 0.0, None, None, "gather", None, smesh,
             ).compile().as_text(),
             "tp_draft_int8": _spec_draft_chunk.lower(
-                draft3_cfg, draft_tp, sds((B,), i32), cache8_tp,
+                draft_cfg, draft_tp, sds((B,), i32), cache8_tp,
                 sds((B, max_pages), i32), sds((B,), i32), sds((B,), b1),
                 K, 0.0, None, None, "gather", None, smesh,
             ).compile().as_text(),
@@ -871,7 +871,6 @@ def run_audit() -> tp.Dict[str, tp.Any]:
             n_head=gtp.n_head,
             n_embd=gtp.n_embd,
             n_kv_heads=gtp.n_kv_heads,
-            qkv_proj="split3",
         )
         params_gtp_abs = jax.eval_shape(
             lambda k: GPT.init(mc_gtp, k), jax.random.PRNGKey(0)

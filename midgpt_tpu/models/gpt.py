@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import math
 import typing as tp
 
@@ -87,9 +88,15 @@ class GPTConfig:
     remat_policy: str = "dots"
     scan_unroll: int = 1  # unroll factor of the layer scan
     # QKV projection lowering of the (3, D, D) weight (see _project_qkv):
-    # 'fused' = one (BT,D)x(D,3D) matmul (best MXU shape, default);
-    # 'split3' = batched per-third einsum (required under tensor parallelism
-    # — auto-selected by the training runtime when mesh tp > 1).
+    # 'fused' = one (BT,D)x(D,3D) matmul over the layer reshaped flat (best
+    # MXU shape; the default, and what TRAINING runs: its scan hands the
+    # projection one layer);
+    # 'split3' = batched per-third einsum over the layer as it lies: required
+    # under tensor parallelism (selected by the training runtime when mesh
+    # tp > 1). The SERVING programs do not read this field where their layer
+    # loop is unrolled or their mesh has tp > 1: `_decode_layer_loop` takes
+    # 'split3' there (the flat reshape of a layer indexed out of the stacked
+    # parameters is a copy of it on the chip; PERF.md, PR 62).
     qkv_proj: str = "fused"
     # RoPE lowering. 'interleaved' computes the reference rotation directly
     # (reference layers.py:79-99). 'split' computes the SAME function via a
@@ -227,8 +234,10 @@ class AttentionParams:
     # (3, D, D) fused QKV projection with an explicit leading q/k/v axis.
     # This layout holds two properties at once that flat (3D, D) layouts
     # each break:
-    #   * at tp=1 it reshapes (free: contiguous) to the flat stacked (3D, D)
-    #     for ONE full-width matmul + contiguous split — the fast MXU path
+    #   * at tp=1 it reshapes (contiguous: free where the projection is
+    #     handed ONE layer, as training's scan does; a copy of the layer where
+    #     it was indexed out of the stack, GPTConfig.qkv_proj) to the flat
+    #     stacked (3D, D) for ONE full-width matmul + contiguous split
     #     (a head-major interleaved flat layout's (B,T,H,3,C) unpack slices
     #     leave 64-element lane runs at C=64; `train_124m`, ledger, runs
     #     this one);
@@ -836,17 +845,27 @@ class GPT:
 
         Two lowerings of the same (3, D, D) weight (see AttentionParams and
         GPTConfig.qkv_proj):
-          'fused'  — reshape the weight flat (free: contiguous) and run ONE
-                     (BT, D) x (D, 3D) matmul; best MXU shape, the default.
-          'split3' — batched per-third einsum: under tensor parallelism the
-                     flat reshape would mix the tp-sharded feature axis into
-                     the merged 3D axis (a reshard); the batched form keeps
+          'fused'  — reshape the weight flat and run ONE (BT, D) x (D, 3D)
+                     matmul; best MXU shape, the default, what training
+                     runs. The reshape moves nothing where the caller hands
+                     over ONE layer (the scan of GPT.hidden / GPT.prefill);
+                     of a layer indexed out of the STACKED parameters it is
+                     a copy of the layer on the chip.
+          'split3' — batched per-third einsum over the (3, D, D) layer as
+                     it lies: the weight reaches the matmul in place, also
+                     out of the stack, so the serving programs' unrolled
+                     layer loop takes it whatever the config says
+                     (`_decode_layer_loop`, which alone makes that choice).
+                     Under tensor parallelism the flat reshape would
+                     besides mix the tp-sharded feature axis into the
+                     merged 3D axis (a reshard); the batched form keeps
                      each third independently column-sharded, zero
-                     collectives. The runtime selects this when mesh tp > 1
-                     (training/train.py).
+                     collectives (training/train.py selects it when mesh
+                     tp > 1, the serving loop under a tp > 1 mesh).
 
         GQA (config.n_kv_heads set, AttentionParams.wkv) keeps the same two
-        lowerings: 'fused' concatenates the q and k/v weights into ONE
+        lowerings: 'fused' concatenates the q and k/v weights (a copy of
+        both, whoever calls) into ONE
         (D + 2*H_kv*C, D) matmul with a contiguous split; 'split3' runs the
         q einsum and the batched k/v einsum separately so each stays
         independently column-sharded at its own head count. K/V emerge at
@@ -1339,7 +1358,7 @@ class GPT:
         # (verified on compiled HLO); the unrolled DUS chain rides the
         # decode loop's carry and aliases in place. L is static and small,
         # so the unroll is cheap to trace (decode has no remat concerns).
-        def block_fn(carry, block_and_idx):
+        def block_fn(config, carry, block_and_idx):  # `config`: the loop's (`_decode_layer_loop`)
             x, ck_all, cv_all = carry  # caches (L, B, HK, S, C)
             block, i = block_and_idx
             h = rms_norm(x)
@@ -1394,8 +1413,8 @@ class GPT:
         return logits, new_cache
 
     @staticmethod
-    def _decode_layer_loop(config: GPTConfig, block_fn, carry, blocks):
-        """Drive `block_fn(carry, (layer_params, layer_idx))` over all layers.
+    def _decode_layer_loop(config: GPTConfig, block_fn, carry, blocks, mesh=None):
+        """Drive `block_fn(config, carry, (layer_params, layer_idx))` over all layers.
 
         Two lowerings, selected by `config.decode_layer_scan` (trade-off
         documented on the config field):
@@ -1417,15 +1436,33 @@ class GPT:
             amortized by the much larger per-layer compute.
 
         Both run the SAME block_fn (layer index arrives as a traced scalar
-        either way), so the two lowerings cannot drift numerically — pinned
-        by the decode_layer_scan parity test in tests/test_sampling.py."""
+        either way), so the two lowerings cannot drift beyond the rounding of
+        the projection's two spellings (below) — pinned by the
+        decode_layer_scan parity test in tests/test_sampling.py.
+
+        The loop hands block_fn the `config` it projects under, because which
+        spelling of the QKV projection (`_project_qkv`) reaches the weight in
+        place depends on how the layer is handed over, and only this function
+        knows. The unrolled loop indexes the layer out of the STACKED
+        parameters: "fused"'s flat reshape of an indexed layer made the
+        chip's compiler write every layer's `wqkv` out again in every decode
+        step and prefill call (604 MB at the XL's widths, 1.26 ms of a 7.40 ms
+        step: PERF.md section 6 PR 62; utils/hlo.py `weight_copies` counts
+        them), so it always takes the per-third einsum over the (3, D, D)
+        layer as it lies. The scan hands over one layer and keeps the config's
+        own spelling, except under a tp > 1 serving `mesh`, where the flat
+        reshape would mix the sharded feature axis into the merged one (a
+        reshard a layer; the engine made this rewrite up to PR 61). No caller
+        of a serving program sets `qkv_proj` for it."""
+        if not config.decode_layer_scan or (mesh is not None and mesh.shape["tp"] > 1):
+            config = dataclasses.replace(config, qkv_proj="split3")
         if config.decode_layer_scan:
             idx = jnp.arange(config.n_layer)
-            carry, _ = jax.lax.scan(block_fn, carry, (blocks, idx))
+            carry, _ = jax.lax.scan(functools.partial(block_fn, config), carry, (blocks, idx))
             return carry
         for i in range(config.n_layer):
             layer = jax.tree.map(lambda a: a[i], blocks)
-            carry, _ = block_fn(carry, (layer, jnp.asarray(i)))
+            carry, _ = block_fn(config, carry, (layer, jnp.asarray(i)))
         return carry
 
     # ------------------------------------------------------------------
@@ -1501,7 +1538,7 @@ class GPT:
         sin, cos = rope_table(C, config.block_size)
         positions = pos[:, None]  # (B, 1) — per-slot absolute positions
 
-        def block_fn(carry, block_and_idx):
+        def block_fn(config, carry, block_and_idx):  # `config`: the loop's (`_decode_layer_loop`)
             x, ck_all, cv_all, cks_all, cvs_all = carry  # pools (L,H,P,ps,C)
             block, i = block_and_idx
             h = rms_norm(x)
@@ -1534,6 +1571,7 @@ class GPT:
             block_fn,
             (x, cache.k, cache.v, cache.k_scale, cache.v_scale),
             params.blocks,
+            mesh,
         )
         x, k_new, v_new, ks_new, vs_new = carry
         x = rms_norm(x, eps=1e-5)
@@ -1605,7 +1643,7 @@ class GPT:
         x = jnp.take(params.wte, tokens, axis=0)  # (B, K1, D)
         sin, cos = rope_table(C, config.block_size)
 
-        def block_fn(carry, block_and_idx):
+        def block_fn(config, carry, block_and_idx):  # `config`: the loop's (`_decode_layer_loop`)
             x, ck_all, cv_all, cks_all, cvs_all = carry  # pools (L,H,P,ps,C)
             block, i = block_and_idx
             h = rms_norm(x)
@@ -1633,6 +1671,7 @@ class GPT:
             block_fn,
             (x, cache.k, cache.v, cache.k_scale, cache.v_scale),
             params.blocks,
+            mesh,
         )
         x, k_new, v_new, ks_new, vs_new = carry
         x = rms_norm(x, eps=1e-5)
@@ -1719,7 +1758,7 @@ class GPT:
             jnp.minimum(positions, (start + n_valid)[:, None] - 1) + 1, 1
         )  # (B, T_c)
 
-        def block_fn(carry, block_and_idx):
+        def block_fn(config, carry, block_and_idx):  # `config`: the loop's (`_decode_layer_loop`)
             x, ck_all, cv_all, cks_all, cvs_all = carry
             block, i = block_and_idx
             h = rms_norm(x)
@@ -1751,6 +1790,7 @@ class GPT:
             block_fn,
             (x, cache.k, cache.v, cache.k_scale, cache.v_scale),
             params.blocks,
+            mesh,
         )
         x, k_new, v_new, ks_new, vs_new = carry
         if not every_row:

@@ -195,12 +195,7 @@ def stage_hot_swap(
         )
     if config is not None:
         live_cfg = engine.config
-        cand = config
-        # Mesh engines rewrite qkv_proj to "split3" at construction
-        # (serve.py); accept the pre-rewrite spelling of the same config.
-        if getattr(cand, "qkv_proj", None) != getattr(live_cfg, "qkv_proj", None):
-            cand = dataclasses.replace(cand, qkv_proj=live_cfg.qkv_proj)
-        if cand != live_cfg:
+        if config != live_cfg:
             raise HotSwapError(
                 "hot-swap rejected: model config differs from the live "
                 "engine's — a config change is a new engine, not a swap",

@@ -154,7 +154,7 @@ from midgpt_tpu.sampling.pages import PageAllocator, PagePool, adopt_pages, join
 from midgpt_tpu.sampling.prefix_cache import PrefixCache
 from midgpt_tpu.sampling.scheduler import FCFSScheduler, Scheduler
 from midgpt_tpu.sampling.spec import speculative_accept
-from midgpt_tpu.utils.hlo import jit_cache_size, pool_relayouts
+from midgpt_tpu.utils.hlo import jit_cache_size, pool_relayouts, weight_copies
 from midgpt_tpu.utils.stack_chunk import call_on_own_chunk
 
 Array = jax.Array
@@ -182,10 +182,11 @@ class _PoolProgram:
     Calls, `lower` and `_cache_size` are the wrapped jit's own. When a call
     compiles a program, its abstract arguments are kept (`texts` hands out
     its optimized text); for those with `attn_impl` resolving to 'kernel',
-    `pool_relayouts` later counts the pool- or
+    `census` later counts the pool- or
     layer-sized copies in each such program's compiled text
     (PagedKVCache "Layout contract": 0 when the pool keeps one layout from
-    the program's parameter to its result)."""
+    the program's parameter to its result), and the instructions that write
+    a matrix of a stacked parameter out again."""
 
     def __init__(self, jitted):
         self.jit = jitted
@@ -195,11 +196,12 @@ class _PoolProgram:
         self._cache_size = jitted._cache_size
         self._sig = inspect.signature(jitted.__wrapped__)
         # label -> abstract (args, kwargs) of a kernel-path program, and
-        # label -> relayout count once `pool_relayouts` has read its text
+        # label -> relayout count once `census` has read its text
         self._compiled: tp.Dict[str, tp.Any] = {}
         self._called: tp.Set[str] = set()  # labels a call was made under
         self._kernel_path: tp.Set[str] = set()
         self._relayouts: tp.Dict[str, int] = {}
+        self._weight_copies: tp.Dict[str, int] = {}  # beside it: utils/hlo.weight_copies
         self._texts: tp.Dict[str, str] = {}  # label -> optimized text, once `texts` has read it
 
     def __call__(self, *args, **kwargs):
@@ -239,26 +241,33 @@ class _PoolProgram:
                 self._kernel_path.add(label)
         return out
 
-    def pool_relayouts(self) -> tp.Dict[str, int]:
-        """{kernel-path program as compiled: pool- or layer-sized copies
-        and transposes in its compiled text} (utils/hlo.pool_relayouts).
-        Reads each program's text once: lowering the recorded abstract
-        arguments again finds the executable the call compiled, it does not
-        compile."""
+    def census(self) -> None:
+        """Read the compiled text of every kernel-path program not read
+        yet, once: `_relayouts[label]`, the pool- or layer-sized copies and
+        transposes in it (utils/hlo.pool_relayouts), and
+        `_weight_copies[label]`, the instructions that write a matrix of a
+        STACKED parameter out again (utils/hlo.weight_copies over every
+        leaf of the params, of which it reads those of three dims or more:
+        the GPT's blocks stacked over layers, a family's experts; 0 when
+        every matmul reaches such a weight where it lies).
+        Lowering the recorded abstract arguments again finds the executable
+        the call compiled, it does not compile."""
         for label, (args, kwargs) in self._compiled.items():
             if label in self._kernel_path and label not in self._relayouts:
                 text = self.jit.lower(*args, **kwargs).compile().as_text()
-                pool = self._sig.bind(*args, **kwargs).arguments["cache"]
+                a = self._sig.bind(*args, **kwargs).arguments
                 self._relayouts[label] = pool_relayouts(
-                    text, [a.shape for a in pool.pool_arrays()]
+                    text, [p.shape for p in a["cache"].pool_arrays()]
                 )
-        return dict(self._relayouts)
+                self._weight_copies[label] = weight_copies(
+                    text, [w.shape for w in jax.tree.leaves(a["params"])]
+                )
 
     def texts(self) -> tp.Dict[str, str]:
         """{program as compiled: its optimized HLO text}, for a
         reader that joins a traced op to the scope that opened it (the v5e
         trace names an op by its instruction and carries no scope path). As
-        `pool_relayouts`: lowering the recorded arguments again finds the
+        `census`: lowering the recorded arguments again finds the
         executable the call compiled. Each program's text is read once:
         several readers of one traced run ask (a family's scopes, then its
         kernels), and a lowering is seconds."""
@@ -942,16 +951,6 @@ class ServeEngine:
                         f"{nm} n_kv_heads={c.kv_heads} not divisible by "
                         f"mesh tp={n_tp} — the pool shards whole KV heads"
                     )
-            if n_tp > 1:
-                # Head-aligned qkv shards need the split3 einsum order over
-                # the same (3, D, D) params — the identical switch training
-                # makes when its mesh has tp > 1 (training/train.py).
-                if config.qkv_proj != "split3":
-                    config = dataclasses.replace(config, qkv_proj="split3")
-                if draft_config is not None and draft_config.qkv_proj != "split3":
-                    draft_config = dataclasses.replace(
-                        draft_config, qkv_proj="split3"
-                    )
             params = _stp.put_sharded(
                 params, _stp.serve_param_specs(params, mesh), mesh
             )
@@ -1567,10 +1566,15 @@ class ServeEngine:
         `pool_relayouts` is the kernel path's layout census, per compiled
         program: pool- or layer-sized copies in its compiled text, 0 when
         the pool keeps one layout from parameter to result (PagedKVCache
-        "Layout contract"). It holds what has been read so far;
-        `census=True` (sample.py's exit table) reads the text of every
-        program compiled since, a fraction of a second each, so it is not
-        for the serving loop. Empty on the XLA path."""
+        "Layout contract"). `weight_copies`, beside it: instructions that
+        write a matrix of a stacked parameter out again, 0 when every matmul
+        reaches such a weight where it lies (utils/hlo.weight_copies; the
+        GPT's layer loop since PR 62. Its `rope_style="split"` rebuilds the q
+        and k thirds of every layer in the program, and those count: 38 in a
+        12-layer program). Both hold what has
+        been read so far; `census=True` (sample.py's exit table) reads the
+        text of every program compiled since, a fraction of a second each,
+        so it is not for the serving loop. Empty on the XLA path."""
         programs = {
             "prefill": _serve_prefill_chunk,
             "decode": _serve_decode_chunk,
@@ -1582,12 +1586,14 @@ class ServeEngine:
         stats: tp.Dict[str, tp.Any] = {
             name: jit_cache_size(p) for name, p in programs.items()
         }
+        if census:
+            for p in programs.values():
+                p.census()
         stats["pool_relayouts"] = {
-            label: n
-            for p in programs.values()
-            for label, n in (
-                p.pool_relayouts() if census else p._relayouts
-            ).items()
+            label: n for p in programs.values() for label, n in p._relayouts.items()
+        }
+        stats["weight_copies"] = {
+            label: n for p in programs.values() for label, n in p._weight_copies.items()
         }
         return stats
 

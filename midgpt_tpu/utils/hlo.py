@@ -282,6 +282,106 @@ def rotary_gathers(hlo_text: str) -> int:
     return sum(counts(*m) for m in _GATHER_RE.findall(hlo_text))
 
 
+_RESULT_RE = re.compile(r"^(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][a-z\-]*)\(")
+
+
+def hlo_instructions(lines: tp.Iterable[str]) -> tp.Iterator[tp.Tuple[str, str, tp.List[tp.Tuple[str, tp.Tuple[int, ...]]]]]:
+    """(name, opcode, [(dtype, dims) of each array of its result]) of the
+    instruction lines of one computation (`hlo_computations`). A tuple-typed
+    result gives one entry per member; the `/*index=5*/` marks a long tuple
+    type carries are dropped before it is read."""
+    for line in lines:
+        m = _RESULT_RE.match(re.sub(r"/\*.*?\*/", "", line))
+        if m is None:
+            continue
+        members = [
+            (dtype, tuple(int(d) for d in dims.split(",") if d))
+            for dtype, dims in _ARRAY_TYPE_RE.findall(m.group(2))
+        ]
+        yield m.group(1), m.group(3), members
+
+
+def result_bytes(members: tp.Iterable[tp.Tuple[str, tp.Sequence[int]]]) -> int:
+    """Bytes an instruction writes: the sum over its result's arrays
+    (`hlo_instructions`), the padding of the device's tiling not counted. An
+    element type carries its width in bits (`bf16`, `s32`, `f8e4m3fn`);
+    `pred` is a byte."""
+    def bits(dtype: str) -> int:
+        m = re.match(r"[a-z]+(\d+)", dtype)
+        return int(m.group(1)) if m else 8
+
+    return sum(math.prod(dims) * bits(dtype) // 8 for dtype, dims in members)
+
+
+# Instructions that move no weight (names for what is there, control flow; a
+# custom call is a Mosaic kernel, or the compiler's `ConcatBitcast` that joins
+# two prefetched buffers where they lie), and the prefetches the compiler
+# places itself and overlaps with compute.
+_WRITES_NOTHING = frozenset({
+    "parameter", "get-tuple-element", "tuple", "bitcast", "constant", "while",
+    "conditional", "call", "optimization-barrier", "custom-call",
+})
+_ASYNC_PREFETCH = frozenset({
+    "slice-start", "slice-done", "copy-start", "copy-done",
+    # as an attached chip's compiler prints them: `%slice-start.4 = ... async-start(...), calls=%async_computation.4`
+    "async-start", "async-update", "async-done",
+})
+_CALLS_RE = re.compile(r"calls=%?([\w.\-]+)")
+
+
+def weight_copies(
+    hlo_text: str, weight_shapes: tp.Iterable[tp.Sequence[int]]
+) -> int:
+    """Instructions of a compiled program that WRITE a layer of a stacked
+    weight matrix out again: the census of how the serving programs' matmuls
+    reach their weights (GPT._decode_layer_loop; PERF.md section 6 PR 62).
+
+    `weight_shapes` are the stacked leaves' logical shapes, layers leading
+    (`wqkv` (L, 3, D, D), `w_up` (L, 4D, D), ...: every leaf of a family's
+    params may be handed over, as the serving engine's census does; a leaf of
+    fewer than three dims is no stack of matrices, an embedding table or the
+    norms' scales, and is skipped). Read are the computations
+    that no instruction `calls=` (a fusion or an asynchronous wrapper counts
+    once, by its own result, and not by the instructions inside its
+    computation). An instruction counts when an array
+    of its result has a layer's dims, unit dims aside, as they lie or
+    flattened to two ((3, D, D) or (3D, D)), one layer or several stacked,
+    in any dtype and layout; a multi-output fusion that writes 19 layers
+    counts once. NOT counted: what moves nothing (`_WRITES_NOTHING`), and
+    the asynchronous prefetches (`slice-start` / `slice-done` / `copy-start`
+    / `copy-done`, printed with the opcode `async-start` / `async-done` by
+    an attached chip's compiler) that the compiler places itself and
+    overlaps with compute. What it found on the chip's compiler: the XL decode program that
+    indexed the stack and then reshaped the layer flat (`wqkv[i].reshape(3D,
+    D)`) wrote all 24 layers' `wqkv` in two `slice` fusions EVERY STEP, 604
+    MB read and written again, 1.46 ms of a 6.7 ms step (PR 57's reading); a
+    program whose projection contracts the `(3, D, D)` layer as it lies
+    (since PR 62 the unrolled loop's own choice) reads 0. On other backends the number is whatever that lowering does and pins
+    nothing."""
+    forms = set()
+    for shape in weight_shapes:
+        if len(shape) < 3:
+            continue
+        layer = tuple(int(d) for d in shape[1:] if int(d) != 1)
+        forms |= {layer, (math.prod(layer[:-1]), layer[-1])}
+    comps = hlo_computations(hlo_text)
+    called = set(_CALLS_RE.findall(hlo_text))
+
+    def holds_a_layer(dims: tp.Tuple[int, ...]) -> bool:
+        dims = tuple(d for d in dims if d != 1)
+        return dims in forms or dims[1:] in forms
+
+    return sum(
+        1
+        for name, lines in comps.items()
+        if name not in called
+        for _, opcode, members in hlo_instructions(lines)
+        if opcode not in _WRITES_NOTHING
+        and opcode not in _ASYNC_PREFETCH
+        and any(holds_a_layer(dims) for _, dims in members)
+    )
+
+
 def lower_abstract_train_step(config, mesh=None, eval_program=False):
     """Lower the full training step against ABSTRACT sharded inputs — or,
     with `eval_program`, the batched eval the train loop runs beside it

@@ -247,9 +247,12 @@ def main() -> None:
     print("new_tokens: " + json.dumps(new_tokens))
     if args.engine == "continuous":
         # per compiled serving program: pool- or layer-sized copies in its
-        # compiled text (0 = the pool kept one layout; empty off the TPU)
-        census = ServeEngine.compile_stats(census=True)["pool_relayouts"]
-        print("pool_relayouts: " + json.dumps(census))
+        # compiled text (0 = the pool kept one layout), and instructions that
+        # write a layer's weight matrix out again (0 = every matmul reached
+        # its weight in place); empty off the TPU
+        census = ServeEngine.compile_stats(census=True)
+        print("pool_relayouts: " + json.dumps(census["pool_relayouts"]))
+        print("weight_copies: " + json.dumps(census["weight_copies"]))
     print(cache_stats.summary())
 
 

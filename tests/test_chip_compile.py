@@ -335,8 +335,17 @@ def test_serving_program_never_relays_out_the_pool(program, heads, one_chip, com
     (`rotary_gathers` == 0; `rope_style` is the default, "interleaved"): the
     serving entry point of the rotary rolls lanes. On the parent of PR 57 it
     read 4 a layer, q's and k's even and odd channels, in every one of these
-    programs."""
-    from midgpt_tpu.analysis.hlo_audit import pool_relayouts, rotary_gathers
+    programs.
+
+    And NO instruction that writes a layer of a stacked weight matrix out
+    again (`weight_copies` == 0, at both head shapes: step 1 of PR 62 read
+    the per-third einsum faster at the 124M's widths too): the projection
+    contracts the `(3, D, D)` layer where it lies, by `GPT._decode_layer_loop`'s
+    choice and not this config's (`qkv_proj` is the default, "fused"). On the
+    parent of PR 62 (the flat reshape of the indexed layer) it read 1
+    here, one fusion of SERVE_L x `bf16[1,3,D,D]`, and 2 at the XL's 24
+    layers (19 + 5 layers, 604 MB a decode step and a prefill call)."""
+    from midgpt_tpu.analysis.hlo_audit import pool_relayouts, rotary_gathers, weight_copies
     from midgpt_tpu.sampling import serve
 
     quantized = program.endswith("int8")
@@ -369,6 +378,38 @@ def test_serving_program_never_relays_out_the_pool(program, heads, one_chip, com
     text = _compiled_text(lowered)
     assert pool_relayouts(text, [cache.k.shape, cache.v.shape]) == 0
     assert rotary_gathers(text) == 0
+    assert weight_copies(text, [a.shape for a in jax.tree.leaves(params.blocks)]) == 0
+
+
+@pytest.mark.parametrize("spelling", ["flat_reshape_of_the_indexed_layer", "the_layer_as_it_lies"])
+def test_weight_census_counts_the_flat_reshape_of_an_indexed_stack(spelling, one_chip):
+    """The census of the census: the QKV projection of a Python-unrolled
+    layer loop over STACKED `(L, 3, D, D)` parameters, compiled on its own at
+    SERVE_L layers of the XL's D = 2,048 and a decode step's 16 rows. The
+    parent of PR 62's spelling (index the stack, reshape the layer to
+    `(3D, D)`, one matmul) counts: the slice and the reshape do not fuse into
+    the matmul's operand, and one multi-output `slice` fusion writes every
+    layer's `bf16[1,3,2048,2048]`. The per-third einsum over the layer as it
+    lies (`qkv_proj="split3"`, what the serving programs' unrolled layer loop
+    takes) counts 0."""
+    from midgpt_tpu.analysis.hlo_audit import weight_copies
+
+    D = 2048
+    w = jax.ShapeDtypeStruct((SERVE_L, 3, D, D), jnp.bfloat16, sharding=one_chip)
+    h = jax.ShapeDtypeStruct((16, 1, D), jnp.bfloat16, sharding=one_chip)
+
+    def project(h, w):
+        for i in range(SERVE_L):
+            if spelling == "the_layer_as_it_lies":
+                qkv = jnp.einsum("btd,xed->btxe", h, w[i])
+                q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            else:
+                q, k, v = jnp.split(jnp.einsum("btd,ed->bte", h, w[i].reshape(3 * D, D)), 3, axis=-1)
+            h = jnp.tanh(q * k + v)
+        return h
+
+    text = jax.jit(project).lower(h, w).compile().as_text()
+    assert (weight_copies(text, [w.shape]) > 0) == (spelling != "the_layer_as_it_lies"), text
 
 
 @pytest.mark.parametrize("style", ["strided", "rolled"])

@@ -173,6 +173,66 @@ def test_rotary_gathers_counts_channel_picks_and_not_rows_by_position(case):
     assert rotary_gathers(line + "\n") == (want if case != "traced_in_rotate_interleaved" else 0)
 
 
+# Instructions of the XL decode program's while body as the v5e's compiler
+# printed them (AOT compile, PR 62; operands and metadata cut): what
+# `weight_copies` counts and what it must not. Each is read inside a
+# computation that is not fused, beside a fusion whose own computation holds
+# a weight-shaped `slice` that must not count a second time.
+_L, _D = 24, 2048
+WEIGHT_COPY_LINES = {
+    # the parent of PR 62: a multi-output `slice` fusion of five layers' wqkv, `/*index=5*/` marks and all
+    "multi_output_slice_fusion_of_five_layers": (1, "  %fusion.2020 = (bf16[1,3,2048,2048]{3,2,1,0:T(8,128)(2,1)S(1)}, bf16[1,3,2048,2048]{3,2,1,0:T(8,128)(2,1)S(1)}, bf16[1,3,2048,2048]{3,2,1,0:T(8,128)(2,1)S(1)}, bf16[1,3,2048,2048]{3,2,1,0:T(8,128)(2,1)S(1)}, bf16[1,3,2048,2048]{3,2,1,0:T(8,128)(2,1)S(1)}, /*index=5*/bf16[1,3,2048,2048]{3,2,1,0:T(8,128)(2,1)S(1)}) fusion(%get-tuple-element.695), kind=kLoop, calls=%fused_computation.289"),
+    "one_layer_sliced_in_step": (1, "  %slice.4482 = bf16[1,3,2048,2048]{3,2,1,0:T(8,128)(2,1)S(1)} slice(%bitcast.77), slice={[5:6], [0:3], [0:2048], [0:2048]}"),
+    "a_layer_flattened_to_two_dims": (1, "  %copy.12 = bf16[6144,2048]{1,0:T(8,128)(2,1)} copy(%bitcast.9)"),
+    "a_layer_of_w_up_transposed_in_a_fusion": (1, "  %fusion.7 = bf16[1,8192,2048]{2,1,0:T(8,128)(2,1)} fusion(%p), kind=kLoop, calls=%fused_computation.3"),
+    # the compiler's own prefetches, which run beside compute
+    "asynchronous_slice_start": (0, "  %slice-start = ((bf16[24,8192,2048]{2,1,0:T(8,128)(2,1)}), bf16[1,8192,2048]{2,1,0:T(8,128)(2,1)S(1)}, s32[]{:S(2)}) slice-start(%get-tuple-element.612), slice={[3:4], [0:8192], [0:2048]}"),
+    "asynchronous_slice_done": (0, "  %slice-done = bf16[1,8192,2048]{2,1,0:T(8,128)(2,1)S(1)} slice-done(%slice-start)"),
+    "asynchronous_copy_of_the_whole_stack": (0, "  %copy-start = (bf16[24,3,2048,2048]{3,2,1,0:T(8,128)(2,1)S(1)}, bf16[24,3,2048,2048]{3,2,1,0:T(8,128)(2,1)}, u32[]{:S(2)}) copy-start(%p.3)"),
+    # names for what is there
+    "the_stack_as_a_parameter": (0, "  %param.3 = bf16[24,3,2048,2048]{3,2,1,0:T(8,128)(2,1)} parameter(3)"),
+    "an_element_of_the_fusion_s_tuple": (0, "  %get-tuple-element.584 = bf16[1,3,2048,2048]{3,2,1,0:T(8,128)(2,1)S(1)} get-tuple-element(%fusion.233), index=1"),
+    "a_bitcast_of_a_layer": (0, "  %bitcast.5 = bf16[6144,2048]{1,0:T(8,128)(2,1)} bitcast(%get-tuple-element.584)"),
+    "two_prefetched_buffers_joined_where_they_lie": (0, '  %custom-call.17 = bf16[2,8192,2048]{2,1,0:T(8,128)(2,1)S(1)} custom-call(%slice-done, %slice-done.1), custom_call_target="ConcatBitcast"'),
+    # as large as a layer and larger, and not a weight
+    "the_pool_written_in_place": (0, "  %kv_write.3 = bf16[24,16,2049,8,128]{4,3,2,1,0:T(8,128)(2,1)} custom-call(%p.5, %k), custom_call_target=\"tpu_custom_call\""),
+    "logits_of_128_slots": (0, "  %fusion.1181 = f32[128,50304]{1,0:T(8,128)} fusion(%x, %lm_head), kind=kOutput, calls=%fused_computation.9"),
+    "a_prefill_call_s_activations": (0, "  %fusion.581 = bf16[16,16,6144]{2,1,0:T(8,128)(2,1)} fusion(%h, %w), kind=kOutput, calls=%fused_computation.11"),
+}
+
+
+@pytest.mark.parametrize("case", list(WEIGHT_COPY_LINES))
+def test_weight_copies_counts_a_layer_written_out_again_and_not_its_names_or_prefetches(case):
+    from midgpt_tpu.analysis.hlo_audit import weight_copies
+    from midgpt_tpu.utils.hlo import hlo_instructions, result_bytes
+
+    want, line = WEIGHT_COPY_LINES[case]
+    text = (
+        "HloModule serve\n\n%fused_computation.289 (p: bf16[24,3,2048,2048]) -> bf16[1,3,2048,2048] {\n"
+        "  %p = bf16[24,3,2048,2048]{3,2,1,0} parameter(0)\n"
+        "  ROOT %slice.1 = bf16[1,3,2048,2048]{3,2,1,0} slice(%p), slice={[5:6], [0:3], [0:2048], [0:2048]}\n}\n\n"
+        # an asynchronous slice as an ATTACHED chip's compiler prints it (the 124M decode program, my chip run, PR 62,
+        # call 4): the opcode is `async-start`, the `slice` sits in a computation of its own
+        "%async_computation.4 (p.1: bf16[3,2048,2048]) -> bf16[1,2048,2048] {\n"
+        "  %p.1 = bf16[3,2048,2048]{2,1,0} parameter(0)\n"
+        "  ROOT %slice.9 = bf16[1,2048,2048]{2,1,0} slice(%p.1), slice={[0:1], [0:2048], [0:2048]}\n}\n\n"
+        "%body (arg: (s32[])) -> (s32[]) {\n"
+        "  %in_the_fusion = bf16[1,3,2048,2048]{3,2,1,0} fusion(%q), kind=kLoop, calls=%fused_computation.289\n"
+        "  %slice-start.4 = ((bf16[3,2048,2048]{2,1,0:T(8,128)(2,1)}), bf16[1,2048,2048]{2,1,0:T(8,128)(2,1)S(1)}, s32[]{:S(2)}) async-start(%g), calls=%async_computation.4\n"
+        "  %slice-done.4 = bf16[1,2048,2048]{2,1,0:T(8,128)(2,1)S(1)} async-done(%slice-start.4)\n"
+        + line + "\n}\n"
+    )
+    # every leaf of `params.blocks`: the (L, C) scales are no matrices and are skipped
+    shapes = [(_L, 3, _D, _D), (_L, _D, _D), (_L, 128), (_L, 4 * _D, _D), (_L, _D, 4 * _D)]
+    assert weight_copies(text, shapes) == 1 + want  # `%in_the_fusion` is the 1: once, by its result
+    assert weight_copies(text, [(_L, 3, 768, 768)]) == 0  # another model's widths
+    if case == "multi_output_slice_fusion_of_five_layers":
+        ((name, opcode, members),) = hlo_instructions([line.strip()])
+        assert (name, opcode, len(members)) == ("fusion.2020", "fusion", 6)
+        assert result_bytes(members) == 6 * 3 * _D * _D * 2
+        assert result_bytes([("pred", (8,)), ("s32", ()), ("f8e4m3fn", (4, 4))]) == 8 + 4 + 16
+
+
 def test_permute_overlap_census_reads_what_stands_between_start_and_done():
     """A start/done pair with a matmul fusion between them is covered, one
     with nothing between is not; dtypes come from the start's first buffer;

@@ -300,7 +300,10 @@ def test_the_reference_blocks_its_queries_without_changing_its_result(model, mon
 # programs' rotary) rolls lanes and selects by the channel's parity over tables widened before their rows are taken (ops/rope.py),
 # where the parent sliced stride-2 channels and stacked them: a permutation and a sign, the same values to the bit
 # (tests/test_rope.py against the stride-2 spelling, which the training entry points keep; the two token goldens above pass
-# untouched). The four `openwebtext` entries (`rope_style` "split") did not move.
+# untouched). The four `openwebtext` entries (`rope_style` "split") did not move. ALL EIGHT: taken again at PR 62, whose
+# unrolled serving layer loop contracts `wqkv` by the per-third einsum whatever `config.qkv_proj` says (models/gpt.py
+# `_decode_layer_loop`): each text is what the parent (91b716c) lowers at `qkv_proj="split3"`, byte for byte (the spelling its
+# engines ran under a tp > 1 mesh; same operands and accumulation, tests/test_sampling.py holds the logits to the flat matmul's).
 GPT_PROGRAM_HASHES = json.load(open(os.path.join(os.path.dirname(__file__), "golden", "gpt_serving_programs_pr29.json")))
 
 

@@ -1,6 +1,8 @@
 """KV-cache decode parity: incremental decoding must reproduce the full
 forward pass, and generation must match a no-cache reference loop."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -200,15 +202,17 @@ def test_decode_layer_scan_matches_unrolled(params):
     """GPTConfig.decode_layer_scan swaps the decode layer loop's lowering
     (Python-unrolled DUS chain vs rolled lax.scan — compile-time/copy
     trade-off documented on the config field); both must produce the same
-    logits and cache."""
-    import dataclasses
-
-    cfg_scan = dataclasses.replace(CFG, decode_layer_scan=True)
+    logits and cache. Under ONE spelling of the QKV projection: the unrolled
+    loop always takes the per-third einsum, the scan keeps the config's (PR
+    62; the two spellings against each other:
+    test_per_third_projection_matches_the_flat_matmul_in_the_paged_programs)."""
+    cfg_unroll = dataclasses.replace(CFG, qkv_proj="split3")
+    cfg_scan = dataclasses.replace(cfg_unroll, decode_layer_scan=True)
     tokens = jax.random.randint(jax.random.PRNGKey(11), (2, 9), 0, CFG.vocab_size)
     extra = jax.random.randint(jax.random.PRNGKey(12), (2, 3), 0, CFG.vocab_size)
 
     caches = {}
-    for name, cfg in (("unroll", CFG), ("scan", cfg_scan)):
+    for name, cfg in (("unroll", cfg_unroll), ("scan", cfg_scan)):
         cache = KVCache.init(cfg, 2, dtype=jnp.float32)
         _, cache = GPT.prefill(cfg, params, tokens, cache)
         logits = []
@@ -336,3 +340,72 @@ def test_decode_chunk_has_no_in_loop_cache_copies():
         "full-cache copies inside the decode loop body — the KV cache no "
         f"longer aliases through the carry: {offenders[:2]}"
     )
+
+
+# ----------------------------------------------------------------------
+# The serving programs' QKV projection (PR 62): the per-third einsum over
+# the (3, D, D) layer as it lies, against the parent's one flat matmul.
+# ----------------------------------------------------------------------
+
+
+def _lowered_decode_step(cfg):
+    from midgpt_tpu.models.gpt import PagedKVCache
+
+    p = jax.eval_shape(lambda k: GPT.init(cfg, k), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: PagedKVCache.init(cfg, 5, 8, jnp.float32))
+    arr = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(shape, dtype)
+    step = jax.jit(GPT.decode_step_paged, static_argnums=(0,), static_argnames=("attn_impl",))
+    return step.lower(cfg, p, arr((2,)), cache, arr((2, 2)), arr((2,)), arr((2,), jnp.bool_), attn_impl="gather").as_text()
+
+
+def test_the_serving_layer_loop_chooses_the_projection_and_no_caller_does(params):
+    """`GPT._decode_layer_loop` alone decides how the serving programs
+    contract `wqkv` (PR 62): unrolled, it indexes the layer out of the stacked
+    parameters and takes the per-third einsum whatever `config.qkv_proj`
+    says, so both settings lower to one text (tests/test_chip_compile.py
+    holds `weight_copies` to 0 on the chip's compile of it); scanned, it is
+    handed one layer and keeps the config's own. The engine rewrites nothing:
+    its config, and so its jit keys, are the caller's."""
+    from midgpt_tpu.sampling.serve import ServeEngine
+
+    split3 = dataclasses.replace(CFG, qkv_proj="split3")
+    assert CFG.qkv_proj == "fused"
+    assert _lowered_decode_step(CFG) == _lowered_decode_step(split3)
+    scan = lambda c: dataclasses.replace(c, decode_layer_scan=True)
+    assert _lowered_decode_step(scan(CFG)) != _lowered_decode_step(scan(split3))
+    eng = ServeEngine(CFG, params, max_slots=2, page_size=8, prefill_chunk=8,
+                      cache_dtype=jnp.float32)
+    assert eng.config == CFG
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+@pytest.mark.parametrize("heads", ["mha", "gqa2"])
+def test_per_third_projection_matches_the_flat_matmul_in_the_paged_programs(heads, program):
+    """Same bf16 operands and float32 accumulation in another order of
+    contractions: the logits of a prefill chunk (two rows, the second
+    ragged) and of the decode step after it agree within bf16 rounding
+    between the unrolled layer loop (the per-third einsum over the indexed
+    layer, what every preset of the benchmark serves) and the scanned loop
+    at `qkv_proj="fused"` (one flat matmul, the parent's spelling in both
+    loops), for the MHA parameter set (`wqkv` (3, D, D)) and the GQA one
+    (`wqkv` (1, D, D) beside `wkv` (2, KVD, D))."""
+    from midgpt_tpu.models.gpt import PagedKVCache
+
+    cfg = dataclasses.replace(CFG, n_head=4, n_kv_heads=2 if heads == "gqa2" else None)
+    assert cfg.qkv_proj == "fused"
+    p = GPT.cast_params(GPT.init(cfg, jax.random.PRNGKey(5)), jnp.bfloat16)
+    tokens = jax.random.randint(jax.random.PRNGKey(6), (2, 8), 0, cfg.vocab_size)
+    table = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+    start, n_valid = jnp.zeros((2,), jnp.int32), jnp.asarray([8, 5], jnp.int32)
+    logits = {}
+    for spelling in ("flat", "per_third"):
+        c = dataclasses.replace(cfg, decode_layer_scan=spelling == "flat")
+        cache = PagedKVCache.init(c, 5, 8, jnp.bfloat16)
+        out, cache = GPT.prefill_paged_chunk(c, p, tokens, start, n_valid, cache, table, attn_impl="gather")
+        if program == "decode":
+            out, cache = GPT.decode_step_paged(
+                c, p, tokens[:, 0], cache, table, n_valid, jnp.ones((2,), jnp.bool_), attn_impl="gather"
+            )
+        logits[spelling] = np.asarray(out, np.float32)
+    assert logits["flat"].shape == (2, cfg.vocab_size)
+    np.testing.assert_allclose(logits["per_third"], logits["flat"], atol=3e-2, rtol=3e-2)  # tests/test_decode_attention.py's bf16 tolerance
