@@ -490,6 +490,7 @@ MODEL_FAMILIES: tp.Dict[str, str] = {
     "afmoe": "midgpt_tpu.models.trinity:TrinityConfig",
     "dots3_note": "midgpt_tpu.models.dots3:Dots3Config",
     "olmo_hybrid": "midgpt_tpu.models.olmo_hybrid:OlmoHybridConfig",
+    "granite_hybrid": "midgpt_tpu.models.granite_hybrid:GraniteHybridConfig",
 }
 
 

@@ -5,7 +5,8 @@ here, and call each without asking whether it is there. Families are
 registered in `midgpt_tpu/config.py` `MODEL_FAMILIES`.
 
 The config (`GPTConfig`, `KimiLinearConfig`, `MimoV2Config`, `PanguUltraConfig`,
-`OuroConfig`, `TrinityConfig`, `Dots3Config`, `OlmoHybridConfig`):
+`OuroConfig`, `TrinityConfig`, `Dots3Config`, `OlmoHybridConfig`,
+`GraniteHybridConfig`):
 
     block_size, vocab_size, n_layer, n_head, n_embd   fields, under these names
                                (`n_layer`: layers of WEIGHTS; how many layers
@@ -22,7 +23,7 @@ The config (`GPTConfig`, `KimiLinearConfig`, `MimoV2Config`, `PanguUltraConfig`,
                                for this family; returns None where it does
 
 The namespace (`GPT`, `KimiLinear`, `MimoV2`, `PanguUltra`, `Ouro`, `Trinity`, `Dots3`,
-`OlmoHybrid`):
+`OlmoHybrid`, `GraniteHybrid`):
 
     init(config, key) -> params
     hidden(config, params, tokens, *, key, inference, attn_fn) -> (B, T, D)
@@ -41,8 +42,8 @@ The namespace (`GPT`, `KimiLinear`, `MimoV2`, `PanguUltra`, `Ouro`, `Trinity`, `
                                counters the train loop logs at a logged step
 
 The SERVING members, of every family whose `check_serving` returns None
-(`GPT`, `MimoV2`, `PanguUltra`, `Ouro`, `Trinity`, `Dots3`, `OlmoHybrid`;
-sampling/serve.py calls them, never a family by name):
+(`GPT`, `MimoV2`, `PanguUltra`, `Ouro`, `Trinity`, `Dots3`, `OlmoHybrid`,
+`GraniteHybrid`; sampling/serve.py calls them, never a family by name):
 
     cache_kinds(config) -> (CacheKind(name, window, sinks), ..., [StateKind(name, shapes)])
                                the kinds of cache the layers need, the PAGED
@@ -151,7 +152,12 @@ sampling/serve.py calls them, never a family by name):
                                row is row b: `PagePool.tables` raises on any
                                other); an INACTIVE slot writes no key
                                and leaves its state row bit for bit (it may be
-                               in the middle of its chunked prefill)
+                               in the middle of its chunked prefill). The
+                               engine's `next_logits` hands the state rows back
+                               as they came (`pages.keep_state`): a family whose
+                               rows are large writes them OUTSIDE the loop that
+                               reads them, so that program holds no write and
+                               no copy of the array (models/granite_hybrid.py)
     verify_step_paged          the speculative verify step with decode's
                                arguments over (B, k + 1) tokens, or None where
                                the family has none (the engine then refuses a
@@ -160,9 +166,9 @@ sampling/serve.py calls them, never a family by name):
                                is a next-token-prediction layer that reads the
                                target's last hidden state, which is left out,
                                and the engine's draft model is a GPT. Ouro:
-                               none. OlmoHybrid: none (a rejected draft would
-                               need the state it started from: snapshots are
-                               not wired)
+                               none. OlmoHybrid, GraniteHybrid: none (a
+                               rejected draft would need the state it started
+                               from: snapshots are not wired)
     kernel_sweep(config, cache) -> (pool shape, q rows a pool head, window,
                                sinks) of the decode kernel's sweep, for the
                                engine's block counters
@@ -188,8 +194,10 @@ sampling/serve.py calls them, never a family by name):
                                all n_loop * n_layer cache layers; OlmoHybrid:
                                `gdn.decode_tokens`, `gdn.prefill_tokens`,
                                `gdn.prefill_chunks`, what its linear layers
-                               took; the pool owner adds `state.*`), read on
-                               demand
+                               took; GraniteHybrid: `ssm.decode_tokens`,
+                               `ssm.prefill_tokens`, `ssm.prefill_chunks`, what
+                               its mamba layers took; the pool owner adds
+                               `state.*` to both), read on demand
 """
 
 from midgpt_tpu.models.gpt import GPT, GPTConfig, GPTParams

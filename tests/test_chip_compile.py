@@ -1077,3 +1077,66 @@ def test_state_kind_serving_programs_keep_pools_and_state_rows_in_place(program,
     assert pool_relayouts(text, [a.shape for a in cache.pool_arrays()]) == 0
     assert pool_relayouts(text, [a.shape for a in cache.state]) == 0
     assert compiled.memory_analysis().temp_size_in_bytes < 0.25e9
+
+
+@pytest.mark.parametrize("program", ["decode8", "logits", "prefill512"])
+def test_state_space_serving_programs_keep_pools_and_state_rows_in_place(program, one_chip, compiled_kernels):
+    """models/granite_hybrid.py as the benchmark's cell runs it (all 40 layers at
+    the published widths: 64 mamba heads of 64 x 128, 32 query heads on 8 K/V
+    heads of 64; 64 slots and the sink row; pages of 32): the decode program
+    (8 steps, a table of 128 pages, split-K 8), the program behind
+    `ServeEngine.next_logits` (one step that commits no state) and the one-row
+    prefill program (a chunk of 512 through a table of 512 pages) compile for
+    the v5e; none copies or relays out a K/V pool OR the state arrays, and the
+    arguments are 15.65 GB (6.38 weights + 4.30 K/V at 128 lanes a 64-channel
+    head + 4.91 states + 0.06 history). The logits program holds NO write of a
+    state row: with the one-token update written inside the layers' loop it
+    kept a copy of the whole 4.9 GB state array beside it and did not fit the
+    chip (PERF.md section 6 PR 63). The prefill's attention is 16 calls of 32
+    rows an attention layer (the template folds the 4 query heads of a pool
+    head into its rows, and Mosaic refuses more than 128 of them), inside a
+    scope of their own (`prefill_attn`; the decode kernel's `attn_global` alone)."""
+    import dataclasses
+    import re
+
+    from midgpt_tpu.analysis.hlo_audit import pool_relayouts
+    from midgpt_tpu.config import load_config
+    from midgpt_tpu.sampling import serve
+
+    mc = dataclasses.replace(load_config("granite_4_0_h_micro").model_config, block_size=16384)
+    model = mc.model()
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(sds, jax.eval_shape(lambda k: model.cast_params(model.init(mc, k), jnp.bfloat16), jax.random.PRNGKey(0)))
+    B = 64
+    cache = jax.tree.map(sds, jax.eval_shape(lambda: model.init_cache(mc, (B * 128 + 1, B + 1), 32, jnp.bfloat16, kernel_layout=True)))
+    assert cache.pools[0][0].shape == (4, 8, 8193, 32, 128) and [a.shape for a in cache.state] == [(36, 65, 64, 64, 128), (36, 65, 3 * 4352)]
+    arr = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    if program == "decode8":
+        lowered = serve._serve_decode_chunk.lower(
+            mc, params, arr((B,)), cache, (arr((B, 128)), arr((B,))), arr((B,)), arr((B,), jnp.bool_), 8,
+            0.8, None, None, "kernel", arr((2,), jnp.uint32), None, 8)
+    elif program == "logits":
+        lowered = serve._serve_decode_logits.lower(
+            mc, params, arr((B,)), cache, (arr((B, 128)), arr((B,))), arr((B,)), arr((B,), jnp.bool_), "kernel", None, 8)
+    else:
+        lowered = serve._serve_prefill_chunk.lower(
+            mc, params, arr((1, 512)), arr(()), arr(()), cache, (arr((1, 512)), arr((1,))), None, "kernel",
+            0.8, None, None, arr((2,), jnp.uint32))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    paths = re.findall(r'custom-call\([^\n]*tpu_custom_call[^\n]*?op_name="([^"]*)"', text)
+    assert sum("kv_write" in p for p in paths) == 1  # one rolled period: the attention layer's in-place write
+    attention = [p for p in paths if "attn_global" in p and "kv_write" not in p]
+    named = re.findall(r"%(prefill_attn[\w.]*) = [^\n]*custom-call\(", text)
+    if program == "prefill512":
+        assert len(attention) == 16 and all("/attn_global/prefill_attn/" in p for p in attention) and len(named) == 16, (attention, named)
+    else:
+        assert len(attention) == 1 and "prefill_attn" not in attention[0] and not named
+    assert len(paths) == 1 + len(attention)  # the recurrence is XLA in both programs
+    assert pool_relayouts(text, [a.shape for a in cache.pool_arrays()]) == 0
+    assert pool_relayouts(text, [a.shape for a in cache.state]) == 0
+    # a state row's write is a dynamic-update-slice of the (36, 65, 64, 64, 128) array: the logits program has none
+    writes = len(re.findall(r"f32\[36,65,64,64,128\][^=\n]* dynamic-update-slice\(", text))
+    assert (writes == 0) if program == "logits" else (writes >= 1), writes
+    memory = compiled.memory_analysis()
+    assert 15.6e9 < memory.argument_size_in_bytes < 15.7e9 and memory.temp_size_in_bytes < 0.25e9
